@@ -114,15 +114,16 @@ def main(argv=None) -> int:
     # persistent compile cache (compile governor): the adapt programs
     # take minutes to compile cold and are identical across runs —
     # default the cache dir (env JAX_COMPILATION_CACHE_DIR wins) so
-    # repeat CLI invocations and subprocess workers start warm.
-    # set_cache_env itself declines on the forced-CPU backend, and the
-    # fallback guard below re-drops the cache when the accelerator is
-    # absent and jax silently resolves to XLA:CPU (whose AOT cache is
-    # unreliable on this image).
-    from .utils.compilecache import (drop_cache_on_cpu_fallback,
-                                     set_cache_env)
+    # repeat CLI invocations start warm.  Then resolve the backend: a
+    # missing accelerator without the JAX_PLATFORMS=cpu pin is an
+    # error here, not a quieter run on the CPU.
+    from .utils.compilecache import backend_or_fail, set_cache_env
     set_cache_env()
-    drop_cache_on_cpu_fallback()
+    try:
+        backend_or_fail()
+    except RuntimeError as e:
+        otrace.log(0, str(e), err=True)
+        return 1
 
     from .io import medit
     from .io.distributed import probe_distributed, load_distributed_mesh

@@ -79,7 +79,7 @@ class Mesh:
 
 
 # canonical field-name tuple for (de)serializing a Mesh as flat arrays
-# (npz state handoffs: scripts/scale_big.py, parallel/_polish_worker.py)
+# (npz state handoffs: scripts/scale_big.py, resilience/checkpoint.py)
 MESH_FIELDS = tuple(f.name for f in dataclasses.fields(Mesh))
 
 

@@ -145,7 +145,8 @@ def parmmg_run(pm) -> tuple[Mesh, object, AdaptStats]:
     otrace.new_run()
     otrace.set_verbosity(info.imprim)
     tim = Timers()
-    with tim("analysis"):
+    from .utils.placement import host_staging, to_device
+    with tim("analysis"), host_staging():
         mesh, met = pm._build_core_mesh()
     if info.nosurf:
         # -nosurf: no surface modification — freeze every boundary entity
@@ -161,7 +162,7 @@ def parmmg_run(pm) -> tuple[Mesh, object, AdaptStats]:
             ftag=jnp.where(bdy_f, mesh.ftag | C.MG_REQ, mesh.ftag),
             etag=jnp.where(bdy_e, mesh.etag | C.MG_REQ, mesh.etag),
             vtag=jnp.where(bdy_v, mesh.vtag | C.MG_REQ, mesh.vtag))
-    with tim("metric"):
+    with tim("metric"), host_staging():
         met = build_metric(mesh, met, info)
 
     # background snapshot for field interpolation (PMMG_create_oldGrp
@@ -203,8 +204,14 @@ def parmmg_run(pm) -> tuple[Mesh, object, AdaptStats]:
             # sub-device groups traversed with lax.map so peak HBM is one
             # group's working set (grpsplit_pmmg.c:1551 role; see
             # parallel/groups.py).  Interface seams are displaced between
-            # iterations like rank interfaces.
-            backup = (jax.tree.map(jnp.copy, mesh), jnp.copy(met))
+            # iterations like rank interfaces.  Only the group-shaped
+            # cycle blocks run on the device: everything at whole-mesh
+            # width (staging above, split/merge, the merged tail below)
+            # stays on the host (utils/placement.host_staging) — the
+            # mesh is grouped BECAUSE programs of its width are too big,
+            # for the device and for its compiler alike.
+            with host_staging():
+                backup = (jax.tree.map(jnp.copy, mesh), jnp.copy(met))
             degraded = False
             try:
                 with tim("adaptation"):
@@ -222,8 +229,8 @@ def parmmg_run(pm) -> tuple[Mesh, object, AdaptStats]:
                 degraded = True
                 ladder_step("lowfailure", site="groups.capacity")
             except RetryBudgetExhausted as e:
-                # the retry rung of the ladder is spent (chunk dispatch
-                # or polish worker kept failing): restore the conforming
+                # the retry rung of the ladder is spent (a chunk
+                # dispatch kept failing): restore the conforming
                 # backup and degrade — never die holding user data
                 mesh, met = backup
                 stats.status = C.PMMG_LOWFAILURE
@@ -244,7 +251,7 @@ def parmmg_run(pm) -> tuple[Mesh, object, AdaptStats]:
             if not degraded and not (info.noinsert and info.noswap
                                      and info.nomove):
                 from .ops.adapt import sliver_polish
-                with tim("bad-element polish"):
+                with tim("bad-element polish"), host_staging():
                     for w in range(8):
                         mesh, counts = sliver_polish(
                             mesh, met, jnp.asarray(1000 + w, jnp.int32),
@@ -257,8 +264,11 @@ def parmmg_run(pm) -> tuple[Mesh, object, AdaptStats]:
                         stats.nmoved += int(pc[2])
                         if int(pc[0]) == 0 and int(pc[1]) == 0:
                             break
-            return _finish_run(pm, mesh, met, stats, info, tim,
-                               bg_mesh, bg_fields, hausd)
+            with host_staging():
+                return _finish_run(pm, mesh, met, stats, info, tim,
+                                   bg_mesh, bg_fields, hausd)
+        # whole-mesh path: staged on the host, adapted on the device
+        mesh, met = to_device((mesh, met))
         for it in range(niter):
             # the jitted cycles DONATE their input buffers, so the
             # pre-iteration binding would be dead after a failure; keep a
@@ -293,6 +303,9 @@ def parmmg_run(pm) -> tuple[Mesh, object, AdaptStats]:
     else:
         from .parallel.dist import (distributed_adapt_multi,
                                     ShardOverflowError)
+        # the SPMD path places like the grouped one: the shard-shaped
+        # programs run on the devices, split, merge and the merged tail
+        # below at whole-mesh width stay on the host
         part = None
         niter = max(1, info.niter)
         vrb = 3 if info.imprim >= C.PMMG_VERB_ITWAVES else 0
@@ -345,7 +358,7 @@ def parmmg_run(pm) -> tuple[Mesh, object, AdaptStats]:
         if not (info.noinsert and info.noswap and info.nomove):
             from .ops.adapt import sliver_polish
             import jax.numpy as jnp
-            with tim("bad-element polish"):
+            with tim("bad-element polish"), host_staging():
                 for w in range(8):
                     mesh, counts = sliver_polish(
                         mesh, met, jnp.asarray(1000 + w, jnp.int32),
@@ -361,6 +374,9 @@ def parmmg_run(pm) -> tuple[Mesh, object, AdaptStats]:
                     if int(pc[0]) == 0 and int(pc[1]) == 0:
                         break
         pm._out_part = part          # reused by distributed output
+        with host_staging():
+            return _finish_run(pm, mesh, met, stats, info, tim, bg_mesh,
+                               bg_fields, hausd)
 
     return _finish_run(pm, mesh, met, stats, info, tim, bg_mesh,
                        bg_fields, hausd)

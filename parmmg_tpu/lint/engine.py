@@ -43,7 +43,7 @@ RULE_TITLES = {
     "R2": "host-sync (no stray device->host pulls on the hot paths)",
     "R3": "obs-routing (no bare print outside obs/; use obs.trace.log)",
     "R4": "knob-registry (PARMMG_* reads match api/knobs.py + README)",
-    "R5": "jaxcompat (version-shimmed jax symbols only via the shim)",
+    "R5": "jaxcompat (shard_map/axis_size/platform_dependent only via utils/jaxcompat)",
     "R6": "name-schemes (static dotted metric/trace/fault names)",
     "R7": "mh-allgather (no pull_host/process_allgather on the pod "
           "hot path; route band tables through pod.gather_band)",

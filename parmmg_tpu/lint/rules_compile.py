@@ -26,12 +26,11 @@ AST (these are the idioms PRs 3-5 actually converged on):
 
 Anything else is a per-call construction and gets flagged.
 
-R5 — the jax 0.4.37 shims live ONLY in ``utils/jaxcompat.py``
-(ROADMAP housekeeping): direct use of the shimmed spellings
-(``jax.experimental.shard_map``, ``jax.shard_map``,
-``jax.lax.axis_size``, ``jax.lax.platform_dependent``) anywhere else
-bypasses the one sanctioned bridge and breaks on the pinned image or
-on the next jax bump.
+R5 — ``utils/jaxcompat.py`` is the one module that names
+``jax.shard_map``, ``jax.lax.axis_size`` and
+``jax.lax.platform_dependent`` (and the retired
+``jax.experimental.shard_map``): direct use anywhere else bypasses it,
+so the next jax rename would be a many-file edit.
 """
 from __future__ import annotations
 

@@ -13,9 +13,9 @@ on:
 - **lock-held-dispatch** — a call made while holding a lock whose
   transitive summary reaches a collective or a subprocess spawn (the
   serving-loop wedge shape: the daemon RLock held across
-  ``service_once`` -> grouped pass -> polish ``subprocess.run``).
-  Where the runtime watchdog ladder (PARMMG_DEADLINE_SERVE_S,
-  PARMMG_POLISH_TIMEOUT_S) makes the hold survivable by design, the
+  ``service_once`` -> a ``subprocess.run``).
+  Where the runtime watchdog ladder (PARMMG_DEADLINE_SERVE_S)
+  makes the hold survivable by design, the
   site carries a reasoned suppression naming that watchdog — the
   static rule keeps every such hold enumerated and argued;
 - **unguarded-field** — a field of a two-thread class (PoolDaemon:

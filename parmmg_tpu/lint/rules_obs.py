@@ -6,9 +6,8 @@ R3 — no bare ``print(`` in ``parmmg_tpu/`` outside ``obs/``:
 print path, and it emits a trace record whether or not the line shows,
 so suppressed runs still reach the trace ring.  ``scripts/`` are
 exempt (artifact emitters own their stdout), and the few legitimate
-stdout contracts inside the package (the CLI's machine-readable dumps,
-the polish worker's stderr relay protocol) carry reasoned
-suppressions.
+stdout contracts inside the package (the CLI's machine-readable dumps)
+carry reasoned suppressions.
 
 R6 — metric / trace-event / faultpoint names must be STATIC
 dotted-lowercase literals: series names are the cross-artifact join

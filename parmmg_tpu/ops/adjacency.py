@@ -14,7 +14,6 @@ import jax.numpy as jnp
 
 from ..core.mesh import Mesh, tet_face_vertices
 from ..core.constants import MG_BDY
-from . import pallas_kernels as pk
 
 
 def _face_keys(mesh: Mesh):
@@ -58,16 +57,10 @@ def face_sort(mesh: Mesh):
         # matching is one of the measured per-wave hot spots
         invalid = cols[:, 0] == big
         w = jnp.where(invalid, big, cols[:, 1] * mesh.capP + cols[:, 2])
-        # major column holds vertex ids < capP <= 46340 < 2^16, so the
-        # radix engine runs 2 digit passes on it instead of 4
-        order = pk.sort_perm((cols[:, 0], w),
-                             ref=lambda ws: jnp.lexsort((ws[1], ws[0])),
-                             nbits=(16, 32))
+        order = jnp.lexsort((w, cols[:, 0]))
         return face_records_from_sorted(mesh, order, cols[order, 0],
                                         w[order])
-    order = pk.sort_perm(
-        (cols[:, 0], cols[:, 1], cols[:, 2]),
-        ref=lambda ws: jnp.lexsort((ws[2], ws[1], ws[0])))
+    order = jnp.lexsort((cols[:, 2], cols[:, 1], cols[:, 0]))
     k = cols[order]
     t = tetid[order]
     f = faceid[order]
@@ -95,7 +88,8 @@ def face_records_from_sorted(mesh: Mesh, order: jax.Array,
 def _pair_records(capT: int, k, t, f, big):
     """Twin pairing over sorted face keys (shared epilogue): matched
     twins are adjacent in sorted order."""
-    first = pk.segment_first(tuple(k[:, j] for j in range(k.shape[1])))
+    from .edges import segment_first
+    first = segment_first(tuple(k[:, j] for j in range(k.shape[1])))
     eq_next = ~first[1:] & (k[:-1, 0] != big)
     same_next = jnp.concatenate([eq_next, jnp.array([False])])
     same_prev = jnp.concatenate([jnp.array([False]), eq_next])

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 
 from ..core.mesh import Mesh, tet_edge_vertices
 from ..core.constants import IARE
-from . import pallas_kernels as pk
 
 _INT32_MAX = 2147483647
 
@@ -67,19 +66,28 @@ def sort_pairs(a: jax.Array, b: jax.Array, valid: jax.Array, capP: int):
     """
     if capP <= PACK_LIMIT:
         key = jnp.where(valid, a * capP + b, _INT32_MAX)
-        order = pk.sort_perm((key,), ref=lambda ws: jnp.argsort(ws[0]))
+        order = jnp.argsort(key)
         ks = key[order]
-        first = pk.segment_first((ks,))
+        first = segment_first((ks,))
         inv = ks == _INT32_MAX
         ka = jnp.where(inv, _INT32_MAX, ks // capP)
         kb = jnp.where(inv, _INT32_MAX, ks % capP)
         return order, ka, kb, first
     aa = jnp.where(valid, a, _INT32_MAX)
     bb = jnp.where(valid, b, _INT32_MAX)
-    order = pk.sort_perm((aa, bb), ref=lambda ws: jnp.lexsort((ws[1], ws[0])))
+    order = jnp.lexsort((bb, aa))
     ka, kb = aa[order], bb[order]
-    first = pk.segment_first((ka, kb))
+    first = segment_first((ka, kb))
     return order, ka, kb, first
+
+
+def segment_first(words) -> jax.Array:
+    """Segment-start flags over sorted columns: first[i] is True iff
+    i == 0 or any words[j][i] != words[j][i-1]."""
+    neq = words[0][1:] != words[0][:-1]
+    for w in words[1:]:
+        neq = neq | (w[1:] != w[:-1])
+    return jnp.concatenate([jnp.array([True]), neq])
 
 
 def segmented_or(first: jax.Array, values: jax.Array) -> jax.Array:
@@ -162,7 +170,7 @@ def unique_edges_from_sorted(mesh: Mesh, order: jax.Array, ks: jax.Array,
     code: tag payloads are re-gathered from the CURRENT mesh here, so
     the retained state never carries tags.  Requires
     ``capP <= PACK_LIMIT``."""
-    first = pk.segment_first((ks,))
+    first = segment_first((ks,))
     inv = ks == _INT32_MAX
     ka = jnp.where(inv, _INT32_MAX, ks // mesh.capP)
     kb = jnp.where(inv, _INT32_MAX, ks % mesh.capP)
@@ -267,7 +275,6 @@ def edge_lengths(mesh: Mesh, et: EdgeTable, met: jax.Array) -> jax.Array:
         # default may be a TPU plugin while this computation lowers for
         # CPU devices): jnp formula normally, interpreted Pallas kernel
         # when PARMMG_TPU_PALLAS=1 forces kernel numerics everywhere
-        # (jaxcompat shim: 0.4.x lowers every branch — see jaxcompat)
         from ..utils.jaxcompat import platform_dependent
         off_tpu = partial(pal, interpret=True) if pallas_forced() else ref
         return platform_dependent(
@@ -381,12 +388,8 @@ def unique_priority(score: jax.Array, mask: jax.Array) -> jax.Array:
 
 def priority_order(neg: jax.Array) -> jax.Array:
     """Stable ascending argsort of the negated-score vector — the
-    priority rank's sort leg, dispatched to the Pallas radix engine on
-    TPU (PARMMG_PALLAS_SORT).  The radix image of f32 preserves jax's
-    stable comparator order exactly (pallas_kernels.f32_sort_u32), and
-    LSD stability reproduces the documented argsort-rank tie-break (the
-    lane index is the implicit minor word)."""
-    return pk.sort_perm_f32(neg, ref=jnp.argsort)
+    priority rank's sort leg (ties break by lane index)."""
+    return jnp.argsort(neg)
 
 
 # ---------------------------------------------------------------------------

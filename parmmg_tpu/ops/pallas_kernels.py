@@ -24,7 +24,6 @@ import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..core.constants import ALPHA_TET, EPSD
 
@@ -37,7 +36,6 @@ except Exception:  # pragma: no cover
 
 _LANE = 128
 _SUB = 8
-_BLOCK = _SUB * _LANE
 
 
 def use_pallas() -> bool:
@@ -70,19 +68,6 @@ def pallas_score_enabled() -> bool:
     so CPU runs are unaffected; =0 falls back to the jnp reference on
     every backend."""
     return os.environ.get("PARMMG_PALLAS_SCORE", "") != "0"
-
-
-def pallas_sort_enabled() -> bool:
-    """PARMMG_PALLAS_SORT gate for the radix-sort/segment engine
-    (radix_sort_pallas / segment_flags_pallas, dispatched through
-    sort_perm / sort_perm_f32 / segment_first below).  Platform-aware
-    default like PARMMG_SWAP_FACESORT: unset = on iff the process
-    default backend is a TPU (off-TPU the stable jnp argsort/lexsort
-    reference is the right program); 1/0 force either way."""
-    v = os.environ.get("PARMMG_PALLAS_SORT", "")
-    if v == "":
-        return jax.default_backend() == "tpu"
-    return v != "0"
 
 
 def _pad_rows(n: int) -> int:
@@ -148,6 +133,7 @@ def edge_length_iso_pallas(p0: jax.Array, p1: jax.Array,
         grid=(rows // _SUB,),
         in_specs=[spec] * 8,
         out_specs=spec,
+        name="edge_length_iso",
         interpret=_auto_interpret(interpret),
     )(*args)
     return _from_blocks(out, n, p0.dtype)
@@ -190,6 +176,7 @@ def edge_length_ani_pallas(p0: jax.Array, p1: jax.Array,
         grid=(rows // _SUB,),
         in_specs=[spec] * 15,
         out_specs=spec,
+        name="edge_length_ani",
         interpret=_auto_interpret(interpret),
     )(*args)
     return _from_blocks(out, n, p0.dtype)
@@ -200,8 +187,8 @@ def edge_length_ani_pallas(p0: jax.Array, p1: jax.Array,
 # (numerics identical to the jnp reference in ops/edges.py:topk_prep).
 # First non-elementwise kernels in this file: the candidate COUNT (the
 # defer/budget scalar every wave computes before lax.top_k) is reduced
-# across the sequential TPU grid into a (1,1) int32 ref — one pass
-# produces both the masked-negated score vector and the reduction.
+# across the sequential TPU grid into a (1,1) int32 SMEM output — one
+# pass produces both the masked-negated score vector and the reduction.
 # ---------------------------------------------------------------------------
 def _score_kernel(m, v, out, cnt):
     i = pl.program_id(0)
@@ -228,25 +215,32 @@ def _score_min3_kernel(m, v0, v1, v2, out, cnt):
     cnt[0, 0] += jnp.sum(sel.astype(jnp.int32))
 
 
+def _score_call(kernel, name, args, rows, interpret):
+    """One pass over [rows,128] operands -> (score block, count).  The
+    count is a (1,1) int32 output kept whole in SMEM (Mosaic stores no
+    scalar to VMEM): the TPU grid is sequential, so += across steps is
+    a legal reduction."""
+    spec = pl.BlockSpec((_SUB, _LANE), lambda i: (i, 0))
+    return pl.pallas_call(
+        kernel,
+        out_shape=(jax.ShapeDtypeStruct((rows, _LANE), jnp.float32),
+                   jax.ShapeDtypeStruct((1, 1), jnp.int32)),
+        grid=(rows // _SUB,),
+        in_specs=[spec] * len(args),
+        out_specs=(spec, pl.BlockSpec(memory_space=pltpu.SMEM)),
+        name=name,
+        interpret=_auto_interpret(interpret),
+    )(*args)
+
+
 def score_count_pallas(mask: jax.Array, val: jax.Array,
                        interpret: bool | None = None):
     """Fused top-k prep: (where(mask, -val, -inf) [N], sum(mask) int32)."""
     n = mask.shape[0]
     rows = _pad_rows(n)
-    args = [_to_blocks(mask, rows), _to_blocks(val, rows)]
-    spec = pl.BlockSpec((_SUB, _LANE), lambda i: (i, 0))
-    # every grid step maps the count output to the SAME (1,1) block: the
-    # TPU grid is sequential, so += across steps is a legal reduction
-    cspec = pl.BlockSpec((1, 1), lambda i: (0, 0))
-    out, cnt = pl.pallas_call(
-        _score_kernel,
-        out_shape=(jax.ShapeDtypeStruct((rows, _LANE), jnp.float32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.int32)),
-        grid=(rows // _SUB,),
-        in_specs=[spec] * 2,
-        out_specs=(spec, cspec),
-        interpret=_auto_interpret(interpret),
-    )(*args)
+    out, cnt = _score_call(
+        _score_kernel, "score_count",
+        [_to_blocks(mask, rows), _to_blocks(val, rows)], rows, interpret)
     return _from_blocks(out, n, val.dtype), cnt[0, 0]
 
 
@@ -259,19 +253,10 @@ def score3_count_pallas(mask: jax.Array, v0: jax.Array, v1: jax.Array,
     results are bit-identical."""
     n = mask.shape[0]
     rows = _pad_rows(n)
-    args = [_to_blocks(mask, rows), _to_blocks(v0, rows),
-            _to_blocks(v1, rows), _to_blocks(v2, rows)]
-    spec = pl.BlockSpec((_SUB, _LANE), lambda i: (i, 0))
-    cspec = pl.BlockSpec((1, 1), lambda i: (0, 0))
-    out, cnt = pl.pallas_call(
-        _score_min3_kernel,
-        out_shape=(jax.ShapeDtypeStruct((rows, _LANE), jnp.float32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.int32)),
-        grid=(rows // _SUB,),
-        in_specs=[spec] * 4,
-        out_specs=(spec, cspec),
-        interpret=_auto_interpret(interpret),
-    )(*args)
+    out, cnt = _score_call(
+        _score_min3_kernel, "score3_count",
+        [_to_blocks(mask, rows), _to_blocks(v0, rows),
+         _to_blocks(v1, rows), _to_blocks(v2, rows)], rows, interpret)
     return _from_blocks(out, n, v0.dtype), cnt[0, 0]
 
 
@@ -328,10 +313,22 @@ def _qual_kernel(x0, y0, z0, x1, y1, z1, x2, y2, z2, x3, y3, z3,
 # Inclusive int32 prefix sum: the scan backbone of the incremental
 # topology merge (ops/topo_incr.merge_sorted_band) — survivor ranks and
 # band insertion shifts are both prefix sums over [6*capT]/[4*capT] flag
-# vectors.  Within a block, cumsum along lanes then across sublanes; the
-# running block total is carried across the sequential grid in SMEM.
+# vectors.  Within a block, a log-step scan along lanes then across
+# sublanes; the running block total is carried across the sequential
+# grid in SMEM.
 # Integer adds are associative, so this is bit-identical to jnp.cumsum.
 # ---------------------------------------------------------------------------
+def _scan_steps(x, axis):
+    """Inclusive Hillis-Steele scan of an (8,128) int32 block along
+    ``axis`` by log-step rotate-and-add (Mosaic lowers no cumsum)."""
+    io = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    s = 1
+    while s < x.shape[axis]:
+        x = x + jnp.where(io >= s, pltpu.roll(x, s, axis), 0)
+        s *= 2
+    return x
+
+
 def _prefix_kernel(x_ref, o_ref, carry):
     i = pl.program_id(0)
 
@@ -340,9 +337,9 @@ def _prefix_kernel(x_ref, o_ref, carry):
         carry[0] = 0
 
     x = x_ref[:]
-    c1 = jnp.cumsum(x, axis=1)                      # within-row inclusive
-    rt = c1[:, _LANE - 1:_LANE]                     # [8,1] row totals
-    roff = jnp.cumsum(rt, axis=0) - rt              # exclusive row offsets
+    c1 = _scan_steps(x, 1)                          # within-row inclusive
+    rt = jnp.broadcast_to(c1[:, _LANE - 1:_LANE], x.shape)  # row totals
+    roff = _scan_steps(rt, 0) - rt                  # exclusive row offsets
     o_ref[:] = c1 + roff + carry[0]
     carry[0] = carry[0] + jnp.sum(x)
 
@@ -371,6 +368,7 @@ def merge_prefix_pallas(x: jax.Array,
         in_specs=[spec],
         out_specs=spec,
         scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        name="merge_prefix",
         interpret=_auto_interpret(interpret),
     )(_to_blocks_i32(x, rows))
     return out.reshape(-1)[:n]
@@ -399,218 +397,7 @@ def quality_pallas(p: jax.Array, m6bar: jax.Array | None = None,
         grid=(rows // _SUB,),
         in_specs=[spec] * 18,
         out_specs=spec,
+        name="quality_ani" if aniso else "quality_iso",
         interpret=_auto_interpret(interpret),
     )(*args)
     return _from_blocks(out, n, p.dtype)
-
-
-# ---------------------------------------------------------------------------
-# Radix sort / segment engine (ISSUE 20).  A stable tiled LSD radix sort
-# over logical multi-word keys: each word is sorted least-significant
-# first in 8-bit digit passes.  One Pallas kernel per pass computes, over
-# a sequential grid of (8,128) blocks, the stable within-digit rank of
-# every element plus the per-block digit histogram; the scatter offsets
-# come from merge_prefix_pallas over the digit-major/block-minor
-# flattened histogram (the PR 18 prefix leg, reused).  Stability makes
-# the permutation bit-identical to jnp.argsort / jnp.lexsort: LSD radix
-# ties resolve by position, exactly like jax's stable comparator sort.
-# Gathers/scatters between passes stay in XLA.
-# ---------------------------------------------------------------------------
-_RADIX = 256
-_I32_MAX = 2147483647
-
-
-def _radix_pass_kernel(d_ref, rank_ref, hist_ref):
-    d = d_ref[:]
-    oh = (d[:, :, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (_SUB, _LANE, _RADIX), 2)).astype(jnp.int32)
-    c1 = jnp.cumsum(oh, axis=1)                     # within-row, per digit
-    rt = c1[:, _LANE - 1:_LANE, :]                  # [8,1,256] row totals
-    roff = jnp.cumsum(rt, axis=0) - rt              # exclusive row offsets
-    rank_ref[:] = jnp.sum((c1 + roff) * oh, axis=2) - 1
-    hist_ref[:] = jnp.sum(oh, axis=(0, 1))[None, :]
-
-
-def radix_sort_pallas(words, nbits=None, interpret=None):
-    """Stable multi-word sort permutation: argsort of the logical key
-    whose major word is words[0].  Each word holds non-negative int32
-    values (uint32 digit order == int32 order for those).  ``nbits[j]``
-    bounds word j's valid values below 2**nbits[j]; words with
-    nbits < 31 get their INT32_MAX tombstones remapped to the in-range
-    maximum (order-preserving: every valid value is strictly smaller),
-    cutting digit passes.  Tail padding uses 0xFFFFFFFF, which sorts
-    after every key; ties against real 0xFFFFFFFF keys keep real rows
-    first by stability, so the returned ``order[:n]`` is exact."""
-    n = words[0].shape[0]
-    rows = _pad_rows(n)
-    npad = rows * _LANE
-    nblocks = rows // _SUB
-    interp = _auto_interpret(interpret)
-    if nbits is None:
-        nbits = (32,) * len(words)
-    order = jnp.arange(npad, dtype=jnp.int32)
-    pos_blk = jnp.arange(npad, dtype=jnp.int32) // _BLOCK
-    spec = pl.BlockSpec((_SUB, _LANE), lambda i: (i, 0))
-    hspec = pl.BlockSpec((1, _RADIX), lambda i: (i, 0))
-    for w, bits in list(zip(words, nbits))[::-1]:   # LSD: minor word first
-        wu = w.astype(jnp.uint32)
-        if bits < 31:
-            wu = jnp.where(wu == jnp.uint32(_I32_MAX),
-                           jnp.uint32((1 << bits) - 1), wu)
-        wp = jnp.full(npad, jnp.uint32(0xFFFFFFFF)).at[:n].set(wu)
-        for shift in range(0, bits, 8):
-            g = wp[order]
-            d = ((g >> jnp.uint32(shift)) & jnp.uint32(0xFF)).astype(jnp.int32)
-            rank, hist = pl.pallas_call(
-                _radix_pass_kernel,
-                out_shape=(jax.ShapeDtypeStruct((rows, _LANE), jnp.int32),
-                           jax.ShapeDtypeStruct((nblocks, _RADIX), jnp.int32)),
-                grid=(nblocks,),
-                in_specs=[spec],
-                out_specs=(spec, hspec),
-                interpret=interp,
-            )(d.reshape(rows, _LANE))
-            flat = hist.T.reshape(-1)               # digit-major, block-minor
-            excl = merge_prefix_pallas(flat, interpret=interpret) - flat
-            dest = excl[d * nblocks + pos_blk] + rank.reshape(-1)
-            order = jnp.zeros(npad, jnp.int32).at[dest].set(
-                order, unique_indices=True)
-    return order[:n]
-
-
-def f32_sort_u32(x: jax.Array) -> jax.Array:
-    """Map float32 to uint32 so radix digit order mirrors jax's stable
-    sort comparator exactly: -0.0 == +0.0 (ties by position), all NaNs
-    equal and after +inf.  NaN maps to 0xFFFFFFFF, colliding with tail
-    padding — stability keeps real rows ahead of pads, so order[:n] is
-    still exact."""
-    x = jnp.where(x == 0.0, 0.0, x)
-    b = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
-    u = jnp.where(b >> 31 != 0, ~b, b | jnp.uint32(0x80000000))
-    return jnp.where(jnp.isnan(x), jnp.uint32(0xFFFFFFFF), u)
-
-
-def _seg_kernel(*refs, nw):
-    word_refs = refs[:nw]
-    o_ref = refs[nw]
-    carry = refs[nw + 1]
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        for j in range(nw):
-            carry[j] = 0
-
-    r_io = jax.lax.broadcasted_iota(jnp.int32, (_SUB, _LANE), 0)
-    l_io = jax.lax.broadcasted_iota(jnp.int32, (_SUB, _LANE), 1)
-    neq = jnp.zeros((_SUB, _LANE), jnp.int32)
-    for j in range(nw):
-        x = word_refs[j][:]
-        rowlast = x[:, _LANE - 1:_LANE]
-        shifted = jnp.concatenate(
-            [jnp.full((1, 1), carry[j], jnp.int32), rowlast[:-1]], axis=0)
-        prev = jnp.concatenate([shifted, x[:, :-1]], axis=1)
-        neq = neq | (x != prev).astype(jnp.int32)
-        carry[j] = jnp.sum(
-            jnp.where((r_io == _SUB - 1) & (l_io == _LANE - 1), x, 0))
-    first0 = ((i == 0) & (r_io == 0) & (l_io == 0)).astype(jnp.int32)
-    o_ref[:] = neq | first0
-
-
-def segment_flags_pallas(words, interpret=None):
-    """Boolean segment-start flags over sorted columns: first[i] is True
-    iff i == 0 or any words[j][i] != words[j][i-1].  Cross-block
-    previous elements ride an SMEM carry.  Zero tail padding only feeds
-    positions >= n, which are discarded."""
-    n = words[0].shape[0]
-    rows = _pad_rows(n)
-    nw = len(words)
-    spec = pl.BlockSpec((_SUB, _LANE), lambda i: (i, 0))
-    out = pl.pallas_call(
-        functools.partial(_seg_kernel, nw=nw),
-        out_shape=jax.ShapeDtypeStruct((rows, _LANE), jnp.int32),
-        grid=(rows // _SUB,),
-        in_specs=[spec] * nw,
-        out_specs=spec,
-        scratch_shapes=[pltpu.SMEM((nw,), jnp.int32)],
-        interpret=_auto_interpret(interpret),
-    )(*[_to_blocks_i32(w, rows) for w in words])
-    return out.reshape(-1)[:n].astype(bool)
-
-
-# -- dispatch helpers --------------------------------------------------------
-def _sort_dispatch_on() -> bool:
-    return HAVE_PALLAS and use_pallas() and pallas_sort_enabled()
-
-
-def sort_perm(words, ref, nbits=None):
-    """Sort-permutation dispatcher.  ``words`` is a tuple of int32
-    columns, major first; ``ref`` is the stable jnp reference taking the
-    same tuple.  TPU gets the radix engine; everywhere else lowers only
-    the reference (identical HLO knob-on/off), except under forced
-    Pallas where the interpreter runs for parity tests."""
-    from ..utils.jaxcompat import platform_dependent
-    words = tuple(words)
-    if not _sort_dispatch_on():
-        return ref(words)
-    krn = functools.partial(radix_sort_pallas, nbits=nbits, interpret=False)
-    if pallas_forced():
-        default = functools.partial(radix_sort_pallas, nbits=nbits,
-                                    interpret=True)
-    else:
-        default = ref
-    return platform_dependent(words, tpu=krn, default=default)
-
-
-def sort_perm_f32(x, ref):
-    """Float argsort dispatcher: the Pallas branch radix-sorts the
-    order-preserving uint32 image of x (f32_sort_u32); the reference
-    branch runs the stable jnp argsort on x itself."""
-    from ..utils.jaxcompat import platform_dependent
-    if not _sort_dispatch_on():
-        return ref(x)
-
-    def krn(v, interpret):
-        u = f32_sort_u32(v).astype(jnp.int32)
-        return radix_sort_pallas((u,), interpret=interpret)
-
-    if pallas_forced():
-        default = functools.partial(krn, interpret=True)
-    else:
-        default = ref
-    return platform_dependent(x, tpu=functools.partial(krn, interpret=False),
-                              default=default)
-
-
-def segment_first(words):
-    """Segment-start dispatcher over sorted columns; the reference is the
-    canonical concat-of-neighbour-compares the call sites used inline."""
-    from ..utils.jaxcompat import platform_dependent
-    words = tuple(words)
-
-    def ref(ws):
-        neq = ws[0][1:] != ws[0][:-1]
-        for w in ws[1:]:
-            neq = neq | (w[1:] != w[:-1])
-        return jnp.concatenate([jnp.array([True]), neq])
-
-    if not _sort_dispatch_on():
-        return ref(words)
-    krn = functools.partial(segment_flags_pallas, interpret=False)
-    if pallas_forced():
-        default = functools.partial(segment_flags_pallas, interpret=True)
-    else:
-        default = ref
-    return platform_dependent(words, tpu=krn, default=default)
-
-
-def pallas_sort_sites():
-    """Static site list the sort engine would dispatch on this backend —
-    empty unless the knob is on and the backend is TPU (or Pallas is
-    forced into the interpreter).  Feeds the bench artifact."""
-    if not _sort_dispatch_on():
-        return []
-    if jax.default_backend() != "tpu" and not pallas_forced():
-        return []
-    return ["unique_edges_sort", "unique_edges_segment", "priority_sort",
-            "face_sort", "band_sort"]

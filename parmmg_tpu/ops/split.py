@@ -26,6 +26,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.mesh import Mesh
 from ..core.constants import (
@@ -95,6 +96,10 @@ def split_wave(mesh: Mesh, met: jax.Array, lmax: float = LLONG,
     ops/edges.wave_budget formula; winners past it are deferred to the
     next wave, NOT flagged as overflow); the convergence-verification
     wide cycle passes 2.
+
+    ``prescreen``: the nomination-time degeneracy prescreen below; a
+    Python bool compiles it in or out, a traced scalar bool switches it
+    at run time inside one compiled program (the grouped cycle blocks).
 
     ``et``/``lens``: a caller-precomputed edge table + metric lengths of
     THIS mesh (adapt_cycle_impl builds one table serving both split and
@@ -214,9 +219,13 @@ def split_wave(mesh: Mesh, met: jax.Array, lmax: float = LLONG,
         # the over-veto band; the wide convergence-verification cycle
         # AND the drivers' polish cycles pass prescreen=False so any
         # still-blocked shell gets an exact re-evaluation.
-        if prescreen:
+        static = isinstance(prescreen, (bool, np.bool_))
+        if not static or prescreen:
             q_par = quality_from_points(mesh.vert[mesh.tet])
-            nominate = nominate & (q_par > 2.0 * QUAL_FLOOR)[:, None]
+            keep = q_par > 2.0 * QUAL_FLOOR
+            if not static:          # traced switch: one compiled program
+                keep = keep | ~prescreen
+            nominate = nominate & keep[:, None]
         has_nom = jnp.any(nominate, axis=1)
         loc_n = jnp.argmax(nominate, axis=1)              # [capT]
         e_n = jnp.clip(et.edge_id[ar0, loc_n], 0, capE - 1)

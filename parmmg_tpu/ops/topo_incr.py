@@ -53,7 +53,6 @@ import jax
 import jax.numpy as jnp
 
 from ..core.mesh import Mesh, tet_edge_vertices, tet_face_vertices
-from . import pallas_kernels as pk
 
 _INT32_MAX = 2147483647
 
@@ -198,12 +197,9 @@ def _lower_bound(qkeys, qslot, keys, slot):
 
 def band_order(bkeys, bslot):
     """Stable band sort permutation, ascending by (bkeys..., bslot) —
-    the slot rides as an EXPLICIT trailing radix word because band
-    record order differs from slot order (lexsort((slot, keys...)) in
-    jnp terms).  Dispatched to the Pallas radix engine on TPU
-    (PARMMG_PALLAS_SORT)."""
-    words = tuple(bkeys) + (bslot,)
-    return pk.sort_perm(words, ref=lambda ws: jnp.lexsort(ws[::-1]))
+    the slot rides as an EXPLICIT trailing sort word because band
+    record order differs from slot order."""
+    return jnp.lexsort((bslot,) + tuple(bkeys)[::-1])
 
 
 def merge_sorted_band(keys, slot, sd, bkeys, bslot):
@@ -329,8 +325,7 @@ def incr_unique_edges(mesh: Mesh, topo: TopoState, incr,
         b = jnp.maximum(ev[:, 0], ev[:, 1])
         valid = jnp.repeat(mesh.tmask, 6)
         key = jnp.where(valid, a * mesh.capP + b, _INT32_MAX)
-        order = pk.sort_perm(
-            (key,), ref=lambda ws: jnp.argsort(ws[0])).astype(jnp.int32)
+        order = jnp.argsort(key).astype(jnp.int32)
         return key[order], order
 
     def _band(_):
@@ -384,9 +379,7 @@ def incr_build_adjacency(mesh: Mesh, topo: TopoState, incr,
         invalid = cols[:, 0] == _INT32_MAX
         w = jnp.where(invalid, _INT32_MAX,
                       cols[:, 1] * mesh.capP + cols[:, 2])
-        order = pk.sort_perm(
-            (cols[:, 0], w), ref=lambda ws: jnp.lexsort((ws[1], ws[0])),
-            nbits=(16, 32)).astype(jnp.int32)
+        order = jnp.lexsort((w, cols[:, 0])).astype(jnp.int32)
         return cols[order, 0], w[order], order
 
     def _band(_):

@@ -104,12 +104,7 @@ def init_multihost(coordinator: str | None = None,
         os.environ.setdefault(
             "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
         from ..utils.compilecache import set_cache_env
-        from ..utils.jaxcompat import multiprocess_cache_key_shim
         set_cache_env(cache)
-        # without this shim worker N+1 misses every entry worker 0
-        # wrote (per-process autotune-cache mode + serialized topology
-        # poison the key — jaxcompat.multiprocess_cache_key_shim)
-        multiprocess_cache_key_shim()
     if not coordinator or num_processes <= 1:
         # single-process degenerate pod: still wire the shared cache
         # (the 1-process parity reference of multihost_run warms its

@@ -4,14 +4,12 @@ The reference's graded-failure contract (``failed_handling``,
 libparmmg1.c:974-1011) is that the library never dies holding user
 data: it degrades to ``PMMG_LOWFAILURE`` and hands back a conforming
 mesh.  This package turns the reproduction's scattered implicit
-degrade paths (driver OOM catches, the polish-worker skip, the serve
-timeout expiry) into one explicit, injectable, gated subsystem:
+degrade paths (driver OOM catches, the serve timeout expiry) into one explicit, injectable, gated subsystem:
 
 - :mod:`~parmmg_tpu.resilience.faults` — a named-faultpoint registry
   armed via ``PARMMG_FAULT=site[:trigger]``.  Each site raises its
-  REAL failure shape (``XlaRuntimeError`` for device dispatches, a
-  non-zero subprocess exit for the polish worker, ``OSError`` for
-  checkpoint IO) so the recovery code below is exercised, never
+  REAL failure shape (``XlaRuntimeError`` for device dispatches,
+  ``OSError`` for checkpoint IO) so the recovery code below is exercised, never
   simulated;
 - :mod:`~parmmg_tpu.resilience.recover` — the deadline + retry +
   exponential-backoff wrapper (``PARMMG_RETRY_MAX`` /
@@ -34,7 +32,7 @@ timeout expiry) into one explicit, injectable, gated subsystem:
 - :mod:`~parmmg_tpu.resilience.watchdog` — the HANG mirror of the
   fault registry: deadline watchdogs (``Deadline`` /
   ``run_with_deadline``, knobs ``PARMMG_DEADLINE_*``) convert a
-  wedged dispatch/exchange/subprocess/serve-step into a
+  wedged dispatch/exchange/serve-step into a
   ``WatchdogTimeout`` that enters ``retry_call`` like any injected
   fault, and per-rank heartbeat leases (``beat`` / ``stale_ranks``,
   ``PARMMG_HEARTBEAT_*``) let the pod supervisor treat a stalled

@@ -2,8 +2,7 @@
 
 PR 9's fault registry made every failure that *raises* recoverable,
 but ParMmg's production failure mode on clusters is the hang: a
-collective that never returns, a polish subprocess that sleeps
-forever, a serving step stuck mid-compile.  The LOWFAILURE contract
+collective that never returns, a serving step stuck mid-compile.  The LOWFAILURE contract
 promises a usable mesh in *bounded time* (failed_handling,
 libparmmg1.c:974-1011) — a hang breaks the "bounded" half without
 tripping a single ``except``.  This module converts hangs into the
@@ -76,8 +75,8 @@ class WatchdogTimeout(RuntimeError):
 def record_timeout(site: str, seconds: float) -> None:
     """Account one watchdog expiry (counter + trace event + log line).
     ``Deadline.check`` / ``run_with_deadline`` call it on their own
-    expiries; external enforcers that kill by other means (the polish
-    ``subprocess.run(timeout=)`` path) call it before raising
+    expiries; external enforcers that kill by other means (a
+    ``subprocess.run(timeout=)``) call it before raising
     :class:`WatchdogTimeout` so every expiry is visible in ONE
     place regardless of the killing mechanism."""
     from ..obs import trace as otrace

@@ -159,9 +159,8 @@ class PoolDaemon:
                 # lint: ok(R9) — the hold IS the design: serving and
                 # RPCs serialize on the RLock, and this exact hold is
                 # what run_with_deadline(PARMMG_DEADLINE_SERVE_S)
-                # bounds; the subprocess legs inside carry their own
-                # watchdogs (PARMMG_POLISH_TIMEOUT_S; the native-ext
-                # build is one-time and memoized)
+                # bounds; the one subprocess leg inside (the
+                # native-ext build) is one-time and memoized
                 return self.driver.service_once()
 
         while not self._stop.is_set():
@@ -363,8 +362,7 @@ class PoolDaemon:
                 # lint: ok(R9) — the ops 'step' RPC deliberately runs
                 # one synchronous serving step under the RLock (same
                 # work the loop bounds with PARMMG_DEADLINE_SERVE_S);
-                # its subprocess legs carry PARMMG_POLISH_TIMEOUT_S
-                # and the one-time native build
+                # its one subprocess leg is the one-time native build
                 st = d.service_once()
             return 200, {"state": st}, None
         if op == "shutdown" and method == "POST":

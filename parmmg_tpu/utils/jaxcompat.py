@@ -1,136 +1,19 @@
-"""jax version compatibility shims (pinned-image survival kit).
+"""The one place that names the jax symbols lint rule R5 guards.
 
-The container pins jax 0.4.37 while the code targets the current API
-surface; the differences are bridged HERE, in one module, instead of
-scattering try/except imports through every caller:
-
-- ``shard_map``: top-level ``jax.shard_map`` only exists on newer jax;
-  0.4.x ships it as ``jax.experimental.shard_map.shard_map``.  The
-  replication-check kwarg was also renamed (``check_rep`` ->
-  ``check_vma``); the shim accepts either spelling and forwards
-  whichever the installed jax understands.
-- ``axis_size``: ``jax.lax.axis_size`` is newer-jax; on 0.4.x the
-  static size of a named axis is recovered via ``lax.psum(1, name)``
-  (special-cased to a concrete int for unit literals).
-
-Callers: ``from ..utils.jaxcompat import shard_map, axis_size`` and
-use them exactly as on current jax.
+``shard_map``, ``axis_size`` and ``platform_dependent`` are plain
+aliases of the installed jax's own (``jax.shard_map(...,
+check_vma=)``, ``jax.lax.axis_size``, ``jax.lax.platform_dependent``);
+callers import them from here so a future rename is a one-file edit.
 """
 from __future__ import annotations
 
-import inspect
+import jax
 
 
-def _resolve_shard_map():
-    try:
-        from jax import shard_map as sm          # jax >= 0.6 spelling
-        # jax.shard_map may be a module in some versions — only accept
-        # a callable here
-        if callable(sm):
-            return sm
-    except ImportError:
-        pass
-    from jax.experimental.shard_map import shard_map as sm
-    return sm
+def shard_map(f, mesh, in_specs, out_specs, check_vma=True, **kw):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
-_SM = _resolve_shard_map()
-_SM_PARAMS = inspect.signature(_SM).parameters
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma=None,
-              check_rep=None, **kw):
-    """Version-portable ``shard_map``: forwards the replication-check
-    flag under whichever name (check_vma / check_rep) the installed jax
-    accepts; either spelling may be passed."""
-    flag = check_vma if check_vma is not None else check_rep
-    if flag is not None:
-        if "check_vma" in _SM_PARAMS:
-            kw["check_vma"] = flag
-        elif "check_rep" in _SM_PARAMS:
-            kw["check_rep"] = flag
-    return _SM(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               **kw)
-
-
-def axis_size(axis_name):
-    """Static size of a named mapped axis, portable across jax versions
-    (``jax.lax.axis_size`` vs the psum(1) idiom on 0.4.x)."""
-    import jax
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def _jax_version() -> tuple:
-    import jax
-    try:
-        return tuple(int(p) for p in jax.__version__.split(".")[:3])
-    except ValueError:                      # pragma: no cover - dev builds
-        return (0, 0, 0)
-
-
-def platform_dependent(*args, default=None, **platform_branches):
-    """``jax.lax.platform_dependent`` that survives jax 0.4.x.
-
-    On 0.4.x the underlying cond LOWERS EVERY branch for the target
-    platform, so an un-lowerable branch (a Pallas kernel with
-    interpret=False on the CPU backend) crashes the whole computation
-    even when that branch is unreachable — newer jax prunes branches at
-    lowering.  There, fall back to picking the branch for the process
-    default backend at TRACE time.  The known cost: a process whose
-    default is a TPU plugin but which lowers this computation for CPU
-    devices picks the TPU branch wrongly — every CPU-lowering entry
-    point in this repo pins JAX_PLATFORMS=cpu (tests/conftest.py,
-    scripts/scale_big.py orchestrator, multihost dry runs), so the
-    heuristic holds on the pinned image."""
-    import jax
-    if _jax_version() >= (0, 5, 0) and \
-            hasattr(jax.lax, "platform_dependent"):
-        return jax.lax.platform_dependent(
-            *args, default=default, **platform_branches)
-    fn = platform_branches.get(jax.default_backend(), default)
-    return fn(*args)
-
-
-def multiprocess_cache_key_shim() -> bool:
-    """Make persistent-compile-cache keys PROCESS-INVARIANT on the
-    pinned jax (0.4.37) so pod workers share one warmed cache
-    (parallel/multihost.init_multihost).
-
-    Two per-process key poisons on this jax, both empirically verified
-    to make worker N+1 MISS every entry worker 0 wrote:
-
-    - the XLA-side autotune-cache mode rides the hashed debug options
-      and is UPDATE on process 0 but READ everywhere else
-      (jax._src.compiler.get_compile_options) — disabled outright via
-      ``jax_persistent_cache_enable_xla_caches="none"`` (those caches
-      are GPU-oriented; the pod dev backend is CPU);
-    - ``cache_key._hash_accelerator_config`` hashes the SERIALIZED
-      PjRt topology, which embeds per-process structure — replaced by
-      the module's own documented fallback (device kinds + platform),
-      which is process-invariant.  Topology differences that matter
-      for compilation still key correctly through the device
-      assignment inside the hashed compile options.
-
-    Returns True when the shim applied.  Idempotent."""
-    import jax
-    try:
-        jax.config.update("jax_persistent_cache_enable_xla_caches",
-                          "none")
-    except Exception:
-        pass                        # newer jax: key already invariant
-    try:
-        from jax._src import cache_key as _ck
-        if getattr(_ck, "_parmmg_invariant_accel", False):
-            return True
-
-        def _invariant_accel(hash_obj, accelerators, backend):
-            _ck._hash_devices(hash_obj, accelerators)
-            _ck._hash_platform(hash_obj, backend)
-
-        _ck._hash_accelerator_config = _invariant_accel
-        _ck._parmmg_invariant_accel = True
-        return True
-    except Exception:               # pragma: no cover - future jax
-        return False
+axis_size = jax.lax.axis_size
+platform_dependent = jax.lax.platform_dependent

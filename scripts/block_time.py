@@ -4,7 +4,8 @@ Run: python scripts/block_time.py [N]"""
 from __future__ import annotations
 import os, sys, time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/root/repo/.jax_cache")
+from parmmg_tpu.utils.compilecache import set_cache_env  # noqa: E402
+set_cache_env()
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
 import jax, jax.numpy as jnp, numpy as np
 from parmmg_tpu.core.mesh import make_mesh

@@ -13,8 +13,7 @@ asserts the bounded-time graded-failure contract per run:
   (positive volumes summing to the cube);
 - BIT-PARITY with the fault-free oracle whenever the schedule's
   expectation is a bit-identical rung (transient retries,
-  mh_allgather, halo_dense, merged_polish-vs-polish-less, host
-  analysis) — degraded never means drifted;
+  mh_allgather, halo_dense, host analysis) — degraded never means drifted;
 - no leaked ``parmmg_*`` staging in the temp dir;
 - ZERO new ``groups.*`` compile families after the fault-free warmup
   — injected faults must never key fresh programs.
@@ -48,7 +47,6 @@ NITER = 2
 # ---------------------------------------------------------------------------
 # expectation vocabulary:
 #   parity      — bit-identical to the runner's fault-free oracle
-#   nopolish    — bit-identical to the polish-LESS pass oracle
 #   lowfailure  — driver returns PMMG_LOWFAILURE with a conforming mesh
 #   quarantine  — tenant t1 retired FAILED; cohort-mates bit-identical
 _MENU: tuple[dict, ...] = (
@@ -86,14 +84,6 @@ _MENU: tuple[dict, ...] = (
     {"runner": "dist", "site": "halo.exchange",
      "fault": "halo.exchange:nth-1",
      "env": {"PARMMG_RETRY_MAX": "2"}, "expect": "parity"},
-    {"runner": "polish", "site": "polish.worker",
-     "fault": "polish.worker",
-     "env": {"PARMMG_RETRY_MAX": "1", "PARMMG_POLISH_SUBPROC": "1"},
-     "expect": "nopolish"},
-    {"runner": "polish", "site": "polish.worker",
-     "fault": "polish.worker:hang=30",
-     "env": {"PARMMG_RETRY_MAX": "1", "PARMMG_POLISH_SUBPROC": "1",
-             "PARMMG_POLISH_TIMEOUT_S": "2"}, "expect": "nopolish"},
     {"runner": "serve", "site": "serve.slot_step",
      "fault": "serve.slot_step:key=t1;nth-1",
      "env": {"PARMMG_SERVE_MAX_RETRIES": "2"}, "expect": "parity"},
@@ -142,7 +132,8 @@ def setup_env() -> None:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + " --xla_force_host_platform_device_count=2").strip()
-    os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+    from parmmg_tpu.utils.compilecache import disable_persistent_cache
+    disable_persistent_cache()
     for k in ("PARMMG_FAULT", "PARMMG_CKPT_DIR", "PARMMG_TRACE"):
         os.environ.pop(k, None)
     os.environ["PARMMG_GROUP_CHUNK"] = "2"
@@ -183,8 +174,7 @@ def run_campaign(seed: int, runs: int, say=print) -> dict:
     from parmmg_tpu.core.mesh import MESH_FIELDS, make_mesh, tet_volumes
     from parmmg_tpu.ops.analysis import analyze_mesh
     from parmmg_tpu.parallel.dist import distributed_adapt_multi
-    from parmmg_tpu.parallel.groups import grouped_adapt, \
-        grouped_adapt_pass
+    from parmmg_tpu.parallel.groups import grouped_adapt
     from parmmg_tpu.serve.driver import ServeDriver
     from parmmg_tpu.utils.compilecache import variants_by_prefix
     from parmmg_tpu.utils.fixtures import cube_mesh
@@ -210,12 +200,6 @@ def run_campaign(seed: int, runs: int, say=print) -> dict:
         m, met = fresh_case()
         out, met_m, _ = distributed_adapt_multi(m, met, 2, niter=NITER,
                                                 cycles=CYCLES)
-        return state_bytes(out, met_m)
-
-    def run_pass(polish):
-        m, met = fresh_case()
-        out, met_m, _ = grouped_adapt_pass(m, met, 3, cycles=CYCLES,
-                                           polish=polish)
         return state_bytes(out, met_m)
 
     def staged_pm():
@@ -299,7 +283,6 @@ def run_campaign(seed: int, runs: int, say=print) -> dict:
     say(f"soak: warmup (oracles for {len(_MENU)} menu entries)")
     base_g = run_grouped()
     base_d = run_dist()
-    ref_nopol = run_pass(False)
     pm0 = staged_pm()
     rc0 = pm0.run()
     assert rc0 == C.PMMG_SUCCESS, f"warmup driver run rc={rc0}"
@@ -307,8 +290,7 @@ def run_campaign(seed: int, runs: int, say=print) -> dict:
     assert rep_a["served"] == 3, "warmup pool must serve 3"
     def live_groups():
         # drop zero-variant keys: a runner REGISTERING a governed
-        # family it never compiled (the killed polish worker leaves
-        # groups.polish_block at 0) is bookkeeping, not compile growth
+        # family it never compiled is bookkeeping, not compile growth
         return {k: v for k, v in variants_by_prefix("groups.").items()
                 if v}
 
@@ -353,11 +335,6 @@ def run_campaign(seed: int, runs: int, say=print) -> dict:
                     probs.append(f"expected PMMG_LOWFAILURE, rc={ret}")
                 elif not conforming(pm._out):
                     probs.append("LOWFAILURE output not conforming")
-            elif spec["runner"] == "polish":
-                with _env(**kv):
-                    got = run_pass(True)
-                if got != ref_nopol:
-                    probs.append("degrade != polish-less pass bits")
             elif spec["runner"] == "serve":
                 with _env(**kv):
                     rep, outs = run_pool()

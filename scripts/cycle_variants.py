@@ -12,9 +12,8 @@ import sys
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-_cache = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                      "..", ".jax_cache")
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", _cache)
+from parmmg_tpu.utils.compilecache import set_cache_env  # noqa: E402
+set_cache_env()
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
 
 import jax

@@ -61,13 +61,10 @@ fi
 # PARMMG_FAULT site provokes its REAL failure path in-process and must
 # land on its documented escalation-ladder step: recovered bit-for-bit
 # (transient dispatch fault, checkpoint/resume) or degraded with a
-# conforming mesh (retry exhaustion -> LOWFAILURE, worker death ->
-# merged polish, serve quarantine with cohort parity).  Hang drills
-# (hang=S fault action): a wedged chunk dispatch / band exchange is
-# converted by its PARMMG_DEADLINE_* watchdog into the same retry
-# ladder, and a wedged polish worker is killed by
-# PARMMG_POLISH_TIMEOUT_S into the merged_polish degrade — all
-# bit-for-bit.  Ends with a 3-run fixed-seed smoke of the seeded
+# conforming mesh (retry exhaustion -> LOWFAILURE, serve quarantine
+# with cohort parity).  Hang drills (hang=S fault action): a wedged
+# chunk dispatch / band exchange is converted by its
+# PARMMG_DEADLINE_* watchdog into the same retry ladder, bit-for-bit.  Ends with a 3-run fixed-seed smoke of the seeded
 # chaos-soak harness (scripts/chaos_soak.py; the full campaign is
 # standalone).  The zero-fault run with the resilience wiring active
 # must be bit-neutral and add ZERO new groups.* compile families.

@@ -163,14 +163,6 @@ def test_knob_readers_default_on(monkeypatch):
     assert swap_facesort_enabled() is False
     monkeypatch.setenv("PARMMG_SWAP_FACESORT", "1")
     assert swap_facesort_enabled() is True
-    # the sort engine knob has the same platform-aware contract
-    from parmmg_tpu.ops.pallas_kernels import pallas_sort_enabled
-    monkeypatch.delenv("PARMMG_PALLAS_SORT", raising=False)
-    assert pallas_sort_enabled() is (jax.default_backend() == "tpu")
-    monkeypatch.setenv("PARMMG_PALLAS_SORT", "0")
-    assert pallas_sort_enabled() is False
-    monkeypatch.setenv("PARMMG_PALLAS_SORT", "1")
-    assert pallas_sort_enabled() is True
 
 
 # ---- smoothing cadence (attack 3) -------------------------------------------
@@ -271,30 +263,3 @@ def test_pallas_forced_wave_parity(monkeypatch):
     a, b = outs
     for name, ra, rb in zip(("split", "collapse", "swap23"), a, b):
         _assert_mesh_equal(ra.mesh, rb.mesh, f"pallas-forced {name}")
-
-
-@pytest.mark.slow
-def test_pallas_sort_forced_wave_parity(monkeypatch):
-    """PARMMG_TPU_PALLAS=1 + PARMMG_PALLAS_SORT=1 routes every sort
-    site (unique_edges, priority, face sort, band sorts) through the
-    interpret-mode radix/segment kernels; a full adapt cycle must stay
-    bit-identical to the jnp-reference run."""
-    from parmmg_tpu.ops.adapt import adapt_cycle
-    m0 = _cube(2)
-    met0 = jnp.full(m0.capP, 0.5, m0.vert.dtype)
-    outs = []
-    for on in (False, True):
-        if on:
-            monkeypatch.setenv("PARMMG_TPU_PALLAS", "1")
-            monkeypatch.setenv("PARMMG_PALLAS_SORT", "1")
-        else:
-            monkeypatch.delenv("PARMMG_TPU_PALLAS", raising=False)
-            monkeypatch.setenv("PARMMG_PALLAS_SORT", "0")
-        m = jax.tree.map(jnp.copy, m0)
-        met = jnp.copy(met0)
-        m, met, cnt = adapt_cycle(m, met, jnp.asarray(0, jnp.int32))
-        outs.append((m, np.asarray(met), np.asarray(cnt)))
-    (ma, ka, ca), (mb, kb, cb) = outs
-    _assert_mesh_equal(ma, mb, "pallas-sort forced cycle")
-    assert np.array_equal(ka, kb)
-    assert np.array_equal(ca, cb)

@@ -319,7 +319,7 @@ def test_r5_shim_import_is_clean():
 # ---------------------------------------------------------------------------
 # R6 name schemes
 # ---------------------------------------------------------------------------
-FAULTS_FIXTURE = 'SITES = {"polish.worker": "exit", "halo.exchange": "xla"}\n'
+FAULTS_FIXTURE = 'SITES = {"dispatch.chunk": "xla", "halo.exchange": "xla"}\n'
 RECOVER_FIXTURE = 'LADDER = ("retry", "halo_dense", "lowfailure")\n'
 
 

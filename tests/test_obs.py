@@ -266,7 +266,7 @@ def test_make_artifact_is_canonical_and_valid(fresh_tracer):
         oart.make_artifact("NOPE", metric="m", value=0, unit="")
 
 
-@pytest.mark.parametrize("fname", ["BENCH_r03.json", "SCALE_r03.json",
+@pytest.mark.parametrize("fname", ["BENCH_r06.json", "SCALE_r03.json",
                                    "SERVE_r01.json"])
 def test_checked_in_artifacts_upgrade_and_validate(fname):
     with open(os.path.join(ROOT, fname)) as f:
@@ -334,15 +334,18 @@ def test_artifact_diff_direction_for_seconds_metrics():
 
 
 def test_artifact_diff_on_checked_in_rounds():
-    # the real r01 -> r03 bench history must not flag ledger
-    # regressions (r01 predates the ledger: compares clean)
-    with open(os.path.join(ROOT, "BENCH_r01.json")) as f:
+    # the real r04 -> r06 bench history (CPU-backend builder artifacts)
+    # must not flag ledger regressions, and must report the throughput
+    # drop those rounds carry (0.1829 -> 0.1336)
+    with open(os.path.join(ROOT, "BENCH_r04.json")) as f:
         old = json.load(f)
-    with open(os.path.join(ROOT, "BENCH_r03.json")) as f:
+    with open(os.path.join(ROOT, "BENCH_r06.json")) as f:
         new = json.load(f)
     d = oart.artifact_diff(old, new)
     assert d["ledger"] == []
-    assert d["value"] == []       # throughput went UP across rounds
+    assert len(d["value"]) == 1 and "adapt_cycle_throughput" in d["value"][0]
+    # same round against itself: nothing to report
+    assert oart.artifact_diff(new, new)["value"] == []
 
 
 def test_profiler_unarmed_is_inert(monkeypatch, fresh_tracer):

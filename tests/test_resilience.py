@@ -15,8 +15,7 @@ import numpy as np
 import pytest
 
 from parmmg_tpu.resilience.faults import (FAULTS, FaultRule,
-                                          parse_fault_spec,
-                                          subprocess_fault_env)
+                                          parse_fault_spec)
 from parmmg_tpu.resilience.recover import (LADDER, RetryBudgetExhausted,
                                            ladder_step, retry_call)
 
@@ -34,11 +33,11 @@ def _clean_faults(monkeypatch):
 # ---------------------------------------------------------------------------
 def test_fault_spec_grammar():
     r = parse_fault_spec(
-        "dispatch.chunk:nth-3,polish.worker,io.checkpoint:every-2,"
+        "dispatch.chunk:nth-3,halo.exchange,io.checkpoint:every-2,"
         "serve.slot_step:key=t7;p=0.5;seed=9")
     assert r["dispatch.chunk"].nth == 3
-    assert r["polish.worker"].nth is None \
-        and r["polish.worker"].every is None
+    assert r["halo.exchange"].nth is None \
+        and r["halo.exchange"].every is None
     assert r["io.checkpoint"].every == 2
     s = r["serve.slot_step"]
     assert (s.key, s.p, s.seed) == ("t7", 0.5, 9)
@@ -87,17 +86,15 @@ def test_trigger_key_filter_gates_counting():
     assert not r.fires("t1")
 
 
-def test_registry_reads_env_and_counts_in_parent(monkeypatch):
-    monkeypatch.setenv("PARMMG_FAULT", "polish.worker:nth-1")
+def test_registry_reads_env_and_counts_hits(monkeypatch):
+    monkeypatch.setenv("PARMMG_FAULT", "halo.exchange:nth-1")
     FAULTS.reset()
-    # the subprocess form: firing decided in the PARENT so counting
-    # survives fresh worker processes; the env overlay carries it
-    assert subprocess_fault_env("polish.worker") == \
-        {"PARMMG_FAULT_FORCE": "polish.worker"}
-    assert subprocess_fault_env("polish.worker") == {}
+    # hit counters live with the parsed spec: nth-1 fires once
+    assert FAULTS.should_fire("halo.exchange")
+    assert not FAULTS.should_fire("halo.exchange")
     # changing the knob rebuilds rules with fresh counters
-    monkeypatch.setenv("PARMMG_FAULT", "polish.worker:nth-1;seed=0")
-    assert subprocess_fault_env("polish.worker") != {}
+    monkeypatch.setenv("PARMMG_FAULT", "halo.exchange:nth-1;seed=0")
+    assert FAULTS.should_fire("halo.exchange")
 
 
 def test_faultpoint_raises_real_shapes(monkeypatch):
@@ -158,6 +155,49 @@ def test_retry_never_retries_capacity_signals():
     with pytest.raises(MemoryError):
         retry_call(oom, "t", max_retries=3, base_s=0)
     assert len(calls) == 1                # deterministic: no re-run
+
+
+def _xla_error(msg):
+    from jax.errors import JaxRuntimeError
+    return JaxRuntimeError(msg)
+
+
+@pytest.mark.parametrize("make", [
+    lambda: NotImplementedError("Unimplemented primitive in Pallas TPU "
+                                "lowering: cumsum"),
+    lambda: ValueError("Cannot store scalars to VMEM"),
+    lambda: TypeError("unexpected keyword argument"),
+    lambda: _xla_error("INVALID_ARGUMENT: shape mismatch"),
+    lambda: _xla_error("RESOURCE_EXHAUSTED: Out of memory"),
+    lambda: _xla_error("INTERNAL: Mosaic failed to compile TPU kernel"),
+], ids=["notimpl", "value", "type", "xla-invalid", "xla-oom",
+        "xla-mosaic"])
+def test_retry_passes_deterministic_failures_through(make):
+    calls = []
+
+    def bad():
+        calls.append(1)
+        raise make()
+
+    with pytest.raises(type(make())):
+        retry_call(bad, "t", max_retries=3, base_s=0)
+    assert len(calls) == 1                # a retry cannot change it
+    # ... also as the inline attempt the pipelined dispatch already lost
+    with pytest.raises(type(make())):
+        retry_call(lambda: "ok", "t", max_retries=3, base_s=0,
+                   initial_failure=make())
+
+
+def test_retry_retries_transport_status():
+    calls = []
+
+    def flaky():
+        calls.append(1)
+        if len(calls) < 2:
+            raise _xla_error("UNAVAILABLE: connection reset")
+        return "ok"
+
+    assert retry_call(flaky, "t", max_retries=2, base_s=0) == "ok"
 
 
 def test_retry_initial_failure_consumes_attempt_zero():
@@ -381,24 +421,6 @@ def test_dispatch_fault_mid_pass_recovers_bitwise(monkeypatch):
     FAULTS.reset()
     m2, met2 = _grouped_case()
     got = grouped_adapt_pass(m2, met2, 3, cycles=2)
-    assert _bytes(ref[0], ref[1]) == _bytes(got[0], got[1])
-
-
-@pytest.mark.slow
-def test_polish_worker_kill_then_retry_recovers(monkeypatch):
-    """Worker killed mid-polish (first invocation exits non-zero), the
-    retry's fresh worker succeeds: result identical to a clean
-    subprocess-polish run."""
-    from parmmg_tpu.parallel.groups import grouped_adapt_pass
-    monkeypatch.setenv("PARMMG_GROUP_CHUNK", "2")
-    monkeypatch.setenv("PARMMG_POLISH_SUBPROC", "1")
-    monkeypatch.setenv("PARMMG_RETRY_BASE_S", "0")
-    m, met = _grouped_case()
-    ref = grouped_adapt_pass(m, met, 3, cycles=2, polish=True)
-    monkeypatch.setenv("PARMMG_FAULT", "polish.worker:nth-1")
-    FAULTS.reset()
-    m2, met2 = _grouped_case()
-    got = grouped_adapt_pass(m2, met2, 3, cycles=2, polish=True)
     assert _bytes(ref[0], ref[1]) == _bytes(got[0], got[1])
 
 

@@ -156,7 +156,7 @@ def test_group_chunk_auto_env(monkeypatch):
     from parmmg_tpu.parallel.groups import group_chunk
     monkeypatch.setenv("PARMMG_GROUP_CHUNK", "auto")
     monkeypatch.setattr(sched, "_CHUNK_RECOMMENDATION", [])
-    # before any grouped pass: the backend default (CPU tests: 0)
+    # before any grouped pass: unchunked
     assert group_chunk(16) == 0
     sched.note_chunk_recommendation(4)
     assert group_chunk(16) == 4

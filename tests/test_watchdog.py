@@ -3,7 +3,7 @@ leases, the crash-loop breaker, and the ``hang=S`` fault action.
 
 Everything here is stdlib-speed — no XLA programs, no subprocesses.
 The end-to-end hang drills (a wedged grouped chunk retried to parity,
-a wedged polish worker killed by the subprocess timeout, a wedged pod
+a wedged pod
 worker killed by the heartbeat lease and resumed bit-identically) live
 in ``run_tests.sh --chaos`` / ``--multihost``; tier-1 pins the
 mechanism contracts those drills compose.
@@ -205,8 +205,8 @@ def arm(monkeypatch):
 
 
 def test_hang_grammar():
-    rules = faults.parse_fault_spec("polish.worker:hang=2.5;nth-2")
-    r = rules["polish.worker"]
+    rules = faults.parse_fault_spec("halo.exchange:hang=2.5;nth-2")
+    r = rules["halo.exchange"]
     assert r.hang == 2.5 and r.nth == 2
     with pytest.raises(ValueError, match="hang must be > 0"):
         faults.parse_fault_spec("dispatch.chunk:hang=0")
@@ -230,13 +230,12 @@ def test_fault_trigger_hang_never_flips_condition(arm):
     assert time.monotonic() - t0 >= 0.04
 
 
-def test_subprocess_fault_env_propagates_hang(arm):
-    arm("polish.worker:hang=1")
-    assert faults.subprocess_fault_env("polish.worker") == {
-        faults.FORCE_ENV: "polish.worker:hang=1"}
-    arm("polish.worker")
-    assert faults.subprocess_fault_env("polish.worker") == {
-        faults.FORCE_ENV: "polish.worker"}
+def test_fired_rule_carries_the_hang_action(arm):
+    arm("halo.exchange:hang=1")
+    assert faults.FAULTS.fired_rule("halo.exchange").hang == 1.0
+    arm("halo.exchange")
+    assert faults.FAULTS.fired_rule("halo.exchange").hang is None
+    assert faults.FAULTS.fired_rule("dispatch.chunk") is None
 
 
 # ---------------------------------------------------------------------------
@@ -260,8 +259,7 @@ def test_soak_schedule_is_pure_function_of_seed():
     for s in a:
         assert s["site"] in faults.SITES
         assert s["fault"].split(":")[0] in faults.SITES
-        assert s["expect"] in ("parity", "nopolish", "lowfailure",
-                               "quarantine")
+        assert s["expect"] in ("parity", "lowfailure", "quarantine")
     # the menu spans the FULL registry — no site escapes the soak
     assert set(soak.sites_in_menu()) == set(faults.SITES)
 
