@@ -400,9 +400,8 @@ def main() -> None:
                 else:
                     os.environ[k] = v
 
-    # one-pass phase-timing capture (scripts/profile_adapt.py --json):
-    # committed into the artifact so the next chip session can diff the
-    # SAME phase names on a real device timeline
+    # a phase-timing capture handed in by the caller (the script that
+    # wrote one, scripts/profile_adapt.py, is gone: PR 28)
     profile_phases = None
     pp = os.environ.get("BENCH_PROFILE_JSON", "")
     if pp and os.path.exists(pp):

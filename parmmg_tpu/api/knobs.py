@@ -175,12 +175,9 @@ KNOBS: dict[str, Knob] = {
         "0 = jnp reference everywhere"),
     "PARMMG_PROFILE_DIR": Knob(
         "path", "",
-        "arm a jax.profiler capture writing the xprof timeline into "
-        "this directory"),
-    "PARMMG_PROFILE_PASS": Knob(
-        "spec", "0",
-        "outer-pass capture window start[:stop] for "
-        "PARMMG_PROFILE_DIR"),
+        "hold one jax.profiler capture over each whole run "
+        "(driver.parmmg_run, staging to tail), written into this "
+        "directory"),
     "PARMMG_RESUME_MAX": Knob(
         "int", "3",
         "crash-loop breaker: resume attempts into the SAME pass of "

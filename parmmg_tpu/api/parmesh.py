@@ -884,3 +884,43 @@ class ParMesh:
     @property
     def stats(self):
         return self._out_stats
+
+
+def _count_api_seconds(cls):
+    """The setters and getters lie outside ``run`` and a span a call
+    would be too fine (``set_vertex`` in a loop): each public
+    ``set_*`` / ``get_*`` adds its seconds to ``api.set_s`` /
+    ``api.get_s`` (obs.metrics.REGISTRY).  Only the outermost call
+    counts, so a getter built on another is not counted twice."""
+    import functools
+    import threading
+    import time
+
+    from ..obs.metrics import REGISTRY
+    depth = threading.local()
+
+    def timed(fn, series):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            if getattr(depth, "n", 0):
+                return fn(*args, **kwargs)
+            depth.n = 1
+            t0 = time.perf_counter()
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                depth.n = 0
+                # looked up a call (REGISTRY.reset() drops the series)
+                # lint: ok(R6) — series is one of the two literals below
+                REGISTRY.counter(series).inc(time.perf_counter() - t0)
+        return wrapper
+
+    for name, fn in list(vars(cls).items()):
+        if callable(fn) and name.startswith("set_"):
+            setattr(cls, name, timed(fn, "api.set_s"))
+        elif callable(fn) and name.startswith("get_"):
+            setattr(cls, name, timed(fn, "api.get_s"))
+    return cls
+
+
+_count_api_seconds(ParMesh)

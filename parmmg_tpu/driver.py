@@ -133,10 +133,9 @@ def apply_local_params(mesh: Mesh, met, info):
 def parmmg_run(pm) -> tuple[Mesh, object, AdaptStats]:
     """Run the full adaptation per the staged ParMesh. Returns
     (adapted core Mesh, metric, stats)."""
-    from .utils.timers import Timers
     from .api.params import check_input_data
     from .obs import trace as otrace
-    from .resilience.recover import RetryBudgetExhausted, ladder_step
+    from .utils.timers import LEDGER
     info = pm.info
     check_input_data(info, met_is_aniso=(
         pm.met is not None and getattr(pm.met, "ndim", 1) == 2))
@@ -144,6 +143,55 @@ def parmmg_run(pm) -> tuple[Mesh, object, AdaptStats]:
     # trace record) and the process verbosity = the reference's imprim
     otrace.new_run()
     otrace.set_verbosity(info.imprim)
+    LEDGER.install_listener()
+    # ``run`` is the root of the job's span tree; every phase below is
+    # its child.  PARMMG_PROFILE_DIR holds one capture over all of it
+    with otrace.profile_capture(), \
+            otrace.span("run", ne_in=int(pm.ne_)) as root:
+        mesh, met, stats = _run_phases(pm)
+        root.set(ne_out=int(np.asarray(mesh.tmask).sum()),
+                 status=int(stats.status))
+    return mesh, met, stats
+
+
+def _merged_polish(mesh, met, info, hausd, stats, tim):
+    """Bad-element polish on a MERGED mesh, staged on the host (group
+    and shard seams breed slivers): up to eight ``sliver_polish`` waves,
+    each ended by the pull of its counts, until one applies no collapse
+    and no swap.  Returns (mesh, collapses + swaps applied)."""
+    import jax.numpy as jnp
+    from .obs import trace as otrace
+    from .obs.metrics import REGISTRY
+    from .ops.adapt import sliver_polish
+    from .utils.placement import host_staging
+    ops = 0
+    with tim("bad-element polish"), host_staging():
+        for w in range(8):
+            with otrace.span("polish wave", wave=w) as sp:
+                mesh, counts = sliver_polish(
+                    mesh, met, jnp.asarray(1000 + w, jnp.int32),
+                    do_collapse=not info.noinsert,
+                    do_swap=not info.noswap,
+                    do_smooth=not info.nomove, hausd=hausd)
+                ncol, nswap, nmoved = np.asarray(counts)[:3].tolist()
+                sp.set(collapse=ncol, swap=nswap, moved=nmoved)
+            stats.ncollapse += ncol
+            stats.nswap += nswap
+            stats.nmoved += nmoved
+            ops += ncol + nswap
+            REGISTRY.counter("tail.polish_waves").inc()
+            if ncol == 0 and nswap == 0:
+                break
+    REGISTRY.counter("tail.polish_ops").inc(ops)
+    return mesh, ops
+
+
+def _run_phases(pm) -> tuple[Mesh, object, AdaptStats]:
+    """The phases of one run, under the ``run`` span."""
+    from .utils.timers import Timers
+    from .obs import trace as otrace
+    from .resilience.recover import RetryBudgetExhausted, ladder_step
+    info = pm.info
     tim = Timers()
     from .utils.placement import host_staging, to_device
     with tim("analysis"), host_staging():
@@ -172,7 +220,8 @@ def parmmg_run(pm) -> tuple[Mesh, object, AdaptStats]:
     if bg_fields:
         import jax
         import jax.numpy as jnp
-        bg_mesh = jax.tree.map(jnp.copy, mesh)
+        with otrace.span("backup"):
+            bg_mesh = jax.tree.map(jnp.copy, mesh)
     else:
         bg_mesh = None
 
@@ -210,7 +259,7 @@ def parmmg_run(pm) -> tuple[Mesh, object, AdaptStats]:
             # stays on the host (utils/placement.host_staging) — the
             # mesh is grouped BECAUSE programs of its width are too big,
             # for the device and for its compiler alike.
-            with host_staging():
+            with otrace.span("backup"), host_staging():
                 backup = (jax.tree.map(jnp.copy, mesh), jnp.copy(met))
             degraded = False
             try:
@@ -250,20 +299,8 @@ def parmmg_run(pm) -> tuple[Mesh, object, AdaptStats]:
             # the other two paths — group seams breed slivers)
             if not degraded and not (info.noinsert and info.noswap
                                      and info.nomove):
-                from .ops.adapt import sliver_polish
-                with tim("bad-element polish"), host_staging():
-                    for w in range(8):
-                        mesh, counts = sliver_polish(
-                            mesh, met, jnp.asarray(1000 + w, jnp.int32),
-                            do_collapse=not info.noinsert,
-                            do_swap=not info.noswap,
-                            do_smooth=not info.nomove, hausd=hausd)
-                        pc = np.asarray(counts)
-                        stats.ncollapse += int(pc[0])
-                        stats.nswap += int(pc[1])
-                        stats.nmoved += int(pc[2])
-                        if int(pc[0]) == 0 and int(pc[1]) == 0:
-                            break
+                mesh, _ = _merged_polish(mesh, met, info, hausd, stats,
+                                         tim)
             with host_staging():
                 return _finish_run(pm, mesh, met, stats, info, tim,
                                    bg_mesh, bg_fields, hausd)
@@ -273,9 +310,10 @@ def parmmg_run(pm) -> tuple[Mesh, object, AdaptStats]:
             # the jitted cycles DONATE their input buffers, so the
             # pre-iteration binding would be dead after a failure; keep a
             # device-side copy for the degrade path (HBM-to-HBM, cheap)
-            backup = (jax.tree.map(jnp.copy, mesh), jnp.copy(met))
+            with otrace.span("backup"):
+                backup = (jax.tree.map(jnp.copy, mesh), jnp.copy(met))
             try:
-                with tim(f"adaptation"):
+                with tim("adaptation"):
                     mesh, met, st = adapt_mesh(
                         mesh, met,
                         verbose=3 if info.imprim >= C.PMMG_VERB_ITWAVES
@@ -356,23 +394,9 @@ def parmmg_run(pm) -> tuple[Mesh, object, AdaptStats]:
         # bad-element optimization on the merged mesh (same contract as
         # the single-device path: sliver_polish after the sizing loop)
         if not (info.noinsert and info.noswap and info.nomove):
-            from .ops.adapt import sliver_polish
-            import jax.numpy as jnp
-            with tim("bad-element polish"), host_staging():
-                for w in range(8):
-                    mesh, counts = sliver_polish(
-                        mesh, met, jnp.asarray(1000 + w, jnp.int32),
-                        do_collapse=not info.noinsert,
-                        do_swap=not info.noswap,
-                        do_smooth=not info.nomove, hausd=hausd)
-                    pc = np.asarray(counts)
-                    stats.ncollapse += int(pc[0])
-                    stats.nswap += int(pc[1])
-                    stats.nmoved += int(pc[2])
-                    if int(pc[0]) + int(pc[1]) > 0:
-                        part = None   # tet set changed: labels are stale
-                    if int(pc[0]) == 0 and int(pc[1]) == 0:
-                        break
+            mesh, ops = _merged_polish(mesh, met, info, hausd, stats, tim)
+            if ops:
+                part = None   # tet set changed: labels are stale
         pm._out_part = part          # reused by distributed output
         with host_staging():
             return _finish_run(pm, mesh, met, stats, info, tim, bg_mesh,
@@ -387,6 +411,7 @@ def _finish_run(pm, mesh, met, stats, info, tim, bg_mesh, bg_fields,
     """Common run tail: sequential sliver repair, FEM-topology
     conformity, user-field interpolation, reports.  Shared by the
     whole-mesh, grouped and distributed paths."""
+    from .obs import trace as otrace
     from .obs.trace import log as _olog
     # sequential last-resort repair: tangled sliver clusters (stacked
     # near-flat tets, typically born at former frozen interfaces) veto
@@ -414,9 +439,11 @@ def _finish_run(pm, mesh, met, stats, info, tim, bg_mesh, bg_fields,
         from .ops.adapt import fem_pass, grow_mesh_met
         with tim("fem conformity"):
             nf = 0
-            for _w in range(8):
-                mesh, met, fc = fem_pass(mesh, met)
-                nf, ovf = (int(v) for v in np.asarray(fc))
+            for w in range(8):
+                with otrace.span("fem round", wave=w) as sp:
+                    mesh, met, fc = fem_pass(mesh, met)
+                    nf, ovf = (int(v) for v in np.asarray(fc))
+                    sp.set(split=nf, overflow=ovf)
                 stats.nsplit += nf
                 if ovf:
                     mesh, met = grow_mesh_met(mesh, met, 2 * mesh.capP,

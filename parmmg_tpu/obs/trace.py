@@ -1,4 +1,4 @@
-"""Structured trace emitter + run context + profiler arming.
+"""Structured trace emitter + run context + the span primitive.
 
 One record per completed span (not begin/end pairs): replay is a plain
 per-name sum, the file stays half the size, and a crashed run loses at
@@ -8,23 +8,44 @@ most the spans still open.  Records are dicts; the run context
 folded into every record at emit time, so a trace line is
 self-describing without a join.
 
+:class:`span` is the ONE way a span is made (``utils.timers.Timers``
+scopes go through it, so every ``with tim(...)`` is a span for free).
+A span record holds
+
+``kind`` ``name`` ``dur`` ``count`` ``ts``  as ever: seconds, and the
+    wall clock at close;
+``id`` ``parent``  a process-unique int, and the ``id`` of the span
+    that was open on this thread when this one opened (absent for a
+    root), so the records of a job form a tree under its ``run`` span;
+``t0``  its start on ``time.perf_counter_ns()``'s clock (``dur`` is
+    taken from the same clock);
+``run`` and the rest of the context; ``tim`` for a Timers scope (the
+    replay filter); the counts given as fields at open or at close
+    (:meth:`span.set`): a wave's ``collapse``/``swap``/``moved``, a
+    split's ``groups``/``capT``.
+
+For as long as it is open a span also holds a
+``jax.profiler.TraceAnnotation`` of the same name, unconditionally once
+``jax`` is imported (an unarmed one costs a third of a microsecond): a
+capture started by anyone — the benchmark, ``PARMMG_PROFILE_DIR``, an
+operator's TensorBoard — then carries every program span on the
+profiler's own clock beside the device's ops, and no offset between two
+clocks is computed anywhere.  :func:`emit_span` folds in a duration
+measured elsewhere (``Timers.add``): same ``id``/``parent``/``run``,
+``ext`` where it applies, no ``t0`` and no annotation, because there is
+no interval to open.  Names are fixed strings; pass, block, chunk and
+wave numbers are fields or :func:`context`, never part of a name.
+
 Sinks: an always-on ring buffer (``PARMMG_TRACE_RING`` records, default
 4096 — the ``PMMG_ctim`` slots' bounded-memory role) and, when
 ``PARMMG_TRACE=path`` is set (or :meth:`Tracer.configure` is called), a
-JSONL file appended line-by-line.  ``utils.timers.Timers`` feeds spans
-directly — every existing ``with tim(...)`` scope is a trace span for
-free, carrying the instance's ``tim`` id so :func:`replay_totals` can
-reconstruct exactly one registry's ``report()`` from the stream.
+JSONL file appended line-by-line.  :func:`replay_totals` reconstructs
+exactly one Timers registry's ``report()`` from the stream.
 
-Device timelines: :func:`annotate` wraps
-``jax.profiler.TraceAnnotation`` (host events on the profiler timeline)
-and :func:`scope` wraps ``jax.named_scope`` (XLA op metadata), so a
-profiler capture carries the same phase names as the host trace.
-``PARMMG_PROFILE_DIR`` arms ``jax.profiler.start_trace`` over a
-requested outer-pass window (``PARMMG_PROFILE_PASS=start[:stop]``,
-default pass 0) via :func:`profile_pass_begin` / :func:`profile_pass_end`
-— called by the grouped and distributed outer loops and driven
-standalone by ``scripts/profile_adapt.py``.
+Device timelines: ``PARMMG_PROFILE_DIR=<dir>`` is the operator's one
+switch: :func:`profile_capture` (entered by ``driver.parmmg_run``)
+holds a ``jax.profiler`` capture over one whole run, staging to tail.
+:func:`scope` wraps ``jax.named_scope`` (XLA op metadata).
 
 :func:`log` is the one verbosity-gated print path (the reference's
 ``imprim`` levels, core.constants.PMMG_VERB_*): gated output AND an
@@ -33,18 +54,20 @@ cannot drift.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
 from contextlib import contextmanager, nullcontext
 
 __all__ = [
-    "TRACER", "Tracer", "annotate", "context", "current_context",
-    "emit_span", "event", "log", "new_run", "profile_pass_begin",
-    "profile_pass_end", "profiling_active", "replay_totals", "scope",
-    "set_context", "set_verbosity", "span", "verbosity",
+    "TRACER", "Tracer", "context", "current_context", "current_span",
+    "emit_span", "event", "log", "new_run", "profile_capture",
+    "replay_totals", "scope", "set_context", "set_verbosity", "span",
+    "verbosity",
 ]
 
 
@@ -68,7 +91,6 @@ def set_context(**kv) -> None:
 def new_run(backend: str | None = None) -> str:
     """Start a fresh run context: new run id, optional backend tag
     (defaulted from an already-imported jax — never imports it)."""
-    import sys
     import uuid
     if backend is None:
         jax = sys.modules.get("jax")
@@ -193,13 +215,95 @@ class Tracer:
 TRACER = Tracer()
 
 
+# ---------------------------------------------------------------------------
+# the span primitive
+# ---------------------------------------------------------------------------
+_SPAN_IDS = itertools.count(1)      # next() is atomic under the GIL
+_ANNOTATION = None                  # jax.profiler.TraceAnnotation, once seen
+
+
+def _open_spans() -> list:
+    stk = getattr(_TLS, "spans", None)
+    if stk is None:
+        stk = _TLS.spans = []
+    return stk
+
+
+def current_span() -> int | None:
+    """``id`` of the innermost span open on this thread."""
+    stk = getattr(_TLS, "spans", None)
+    return stk[-1] if stk else None
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` for ``name``, or None while
+    jax is not imported (host-only contexts stay jax-free)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        jax = sys.modules.get("jax")
+        prof = getattr(jax, "profiler", None)
+        _ANNOTATION = getattr(prof, "TraceAnnotation", None)
+        if _ANNOTATION is None:
+            return None
+    return _ANNOTATION(name)
+
+
+class span:
+    """``with span(name, **fields) as sp:`` measures, annotates the
+    profiler's timeline and emits one record at close (module
+    docstring).  ``sp.set(**fields)`` adds the counts known only at the
+    end; ``sp.dur`` holds the seconds once closed."""
+
+    __slots__ = ("name", "fields", "id", "parent", "t0", "dur", "_ann")
+
+    def __init__(self, name: str, **fields):
+        self.name = name
+        self.fields = fields
+        self.dur = 0.0
+
+    def set(self, **fields) -> None:
+        self.fields.update(fields)
+
+    def __enter__(self):
+        stk = _open_spans()
+        self.parent = stk[-1] if stk else None
+        self.id = next(_SPAN_IDS)
+        stk.append(self.id)
+        self._ann = _annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.dur = (time.perf_counter_ns() - self.t0) / 1e9
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        stk = _open_spans()
+        # a span leaked open by a generator or a thread hand-off must
+        # not become every later span's parent: unwind to this one
+        while stk and stk.pop() != self.id:
+            pass
+        rec = {"kind": "span", "name": self.name, "dur": self.dur,
+               "count": 1, "id": self.id, "t0": self.t0}
+        if self.parent is not None:
+            rec["parent"] = self.parent
+        rec.update(self.fields)
+        TRACER.emit(rec)
+        return False
+
+
 def emit_span(name: str, dur: float, count: int = 1,
               tim: int | None = None, ext: bool = False) -> None:
-    """One completed span.  ``tim``: emitting Timers instance id (the
-    replay filter); ``ext``: segment absorbed from another component's
+    """Fold in one span measured elsewhere (``Timers.add``; the SPMD
+    loop's segments).  ``tim``: emitting Timers instance id (the replay
+    filter); ``ext``: segment absorbed from another component's
     measurement (Timers.add outside any scope)."""
     rec = {"kind": "span", "name": name, "dur": round(float(dur), 9),
-           "count": int(count)}
+           "count": int(count), "id": next(_SPAN_IDS)}
+    parent = current_span()
+    if parent is not None:
+        rec["parent"] = parent
     if tim is not None:
         rec["tim"] = tim
     if ext:
@@ -207,21 +311,12 @@ def emit_span(name: str, dur: float, count: int = 1,
     TRACER.emit(rec)
 
 
-@contextmanager
-def span(name: str, **fields):
-    """Measure-and-emit convenience for code without a Timers."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        rec = {"kind": "span", "name": name,
-               "dur": round(time.perf_counter() - t0, 9), "count": 1}
-        rec.update(fields)
-        TRACER.emit(rec)
-
-
 def event(name: str, **fields) -> None:
+    """One point record; ``parent`` is the span open on this thread."""
     rec = {"kind": "event", "name": name}
+    parent = current_span()
+    if parent is not None:
+        rec["parent"] = parent
     rec.update(fields)
     TRACER.emit(rec)
 
@@ -290,136 +385,55 @@ def log(level: int, msg: str, verbose: int | None = None,
     TRACER.emit({"kind": "log", "lvl": int(level), "msg": str(msg),
                  "shown": bool(shown)})
     if shown:
-        import sys
         print(msg, file=sys.stderr if err else sys.stdout)
     return shown
 
 
 # ---------------------------------------------------------------------------
-# jax profiler integration (capture windows + timeline annotations)
+# jax profiler integration (the operator's capture + op-metadata scopes)
 # ---------------------------------------------------------------------------
-_PROFILE = {"active": False, "dir": "", "window": (0, 0)}
-
-
-def _profile_conf():
+@contextmanager
+def profile_capture():
+    """``PARMMG_PROFILE_DIR=<dir>``: hold one ``jax.profiler`` capture
+    over the block (``driver.parmmg_run`` wraps a whole run in it),
+    closed on every way out.  Yields whether a capture was started: not
+    when the variable is unset, nor when the profiler refuses (somebody
+    else's capture is already running — that one then holds the run's
+    spans all the same, since spans annotate unconditionally)."""
     d = os.environ.get("PARMMG_PROFILE_DIR", "")
     if not d:
-        return None
-    w = os.environ.get("PARMMG_PROFILE_PASS", "0")
-    if ":" in w:
-        a, b = w.split(":", 1)
-        win = (int(a or 0), int(b or a or 0))
-    else:
-        win = (int(w or 0), int(w or 0))
-    return d, win
-
-
-def profile_pass_begin(it: int) -> bool:
-    """Arm a ``jax.profiler`` capture when outer pass ``it`` enters the
-    requested window (``PARMMG_PROFILE_DIR`` + ``PARMMG_PROFILE_PASS``).
-    No-op (False) when unarmed, already capturing, or out of window."""
-    conf = _profile_conf()
-    if conf is None or _PROFILE["active"]:
-        return False
-    d, (a, b) = conf
-    if not (a <= it <= b):
-        return False
+        yield False
+        return
     try:
         import jax
         os.makedirs(d, exist_ok=True)
-        jax.profiler.start_trace(d)
+        opts = jax.profiler.ProfileOptions()
+        # host TraceMes (the spans) and device ops, no Python frames:
+        # a whole run of them makes a capture nobody can open
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(d, profiler_options=opts)
     except Exception as e:
         log(0, f"obs: profiler capture failed to arm ({e!r})", err=True)
-        return False
-    _PROFILE.update(active=True, dir=d, window=(a, b))
+        yield False
+        return
     event("profile_start", dir=d)
-    return True
-
-
-def profile_pass_end(it: int) -> bool:
-    """Close the capture once the window's last pass completed."""
-    if not _PROFILE["active"]:
-        return False
-    _a, b = _PROFILE["window"]
-    if it < b:
-        return False
     try:
-        import jax
-        jax.profiler.stop_trace()
-    except Exception:
-        pass
-    _PROFILE["active"] = False
-    event("profile_stop", dir=_PROFILE["dir"])
-    # stderr: stdout is the artifact channel of every emitting script
-    log(1, f"obs: profiler trace written to {_PROFILE['dir']}",
-        err=True)
-    return True
-
-
-def profile_abort() -> bool:
-    """Unconditionally close an active capture — the exception-unwind
-    path of the pass loops (a capture left open would both leak and
-    make every later :func:`profile_pass_begin` refuse to arm)."""
-    if not _PROFILE["active"]:
-        return False
-    try:
-        import jax
-        jax.profiler.stop_trace()
-    except Exception:
-        pass
-    _PROFILE["active"] = False
-    event("profile_abort", dir=_PROFILE["dir"])
-    return True
-
-
-def profiling_active() -> bool:
-    return _PROFILE["active"]
-
-
-def profile_guard(clear_pass: bool = False):
-    """Decorator for outer pass loops that arm capture windows: an
-    exception unwinding the loop (capacity MemoryError, device OOM,
-    ShardOverflowError degrade) must not leave a capture open (an open
-    capture makes every later arm attempt a silent no-op) — only a
-    capture the wrapped call itself armed is aborted.  ``clear_pass``
-    also drops a process-global ``pass`` context tag the loop set (the
-    scoped :func:`context` form unwinds by itself and needs nothing)."""
-    import functools
-
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapper(*args, **kwargs):
-            profiling_before = profiling_active()
-            try:
-                return fn(*args, **kwargs)
-            finally:
-                if clear_pass:
-                    set_context(**{"pass": None})
-                if not profiling_before:
-                    profile_abort()
-        return wrapper
-    return deco
-
-
-def annotate(name: str):
-    """Host-side device-timeline annotation
-    (``jax.profiler.TraceAnnotation``) — active only while a capture
-    runs, a free nullcontext otherwise (hot dispatch loops wrap every
-    chunk in this)."""
-    if not _PROFILE["active"]:
-        return nullcontext()
-    try:
-        import jax
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return nullcontext()
+        yield True
+    finally:
+        try:
+            jax.profiler.stop_trace()
+        except Exception as e:
+            log(0, f"obs: profiler capture failed to close ({e!r})",
+                err=True)
+        event("profile_stop", dir=d)
+        # stderr: stdout is the artifact channel of every emitting script
+        log(1, f"obs: profiler trace written to {d}", err=True)
 
 
 def scope(name: str):
     """``jax.named_scope`` wrapper for traced code: XLA ops inside
     carry ``name`` on the device timeline.  Nullcontext when jax is not
     imported (host-only contexts must stay jax-free)."""
-    import sys
     jax = sys.modules.get("jax")
     if jax is None:
         return nullcontext()

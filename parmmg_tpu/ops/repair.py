@@ -21,6 +21,7 @@ import numpy as np
 
 from ..core.constants import (
     IARE, IDIR, MG_BDY, MG_CRN, MG_GEO, MG_NOM, MG_PARBDY, MG_REF, MG_REQ)
+from ..obs.trace import span
 
 _FROZEN_V = MG_REQ | MG_CRN | MG_PARBDY | MG_NOM
 
@@ -319,43 +320,45 @@ def sequential_repair(vert, tet, tmask, vtag, vmask, tref, ftag, etag,
     if not (allow_collapse or allow_swap or allow_move):
         max_rounds = 0
     for _ in range(max_rounds):
-        live = np.where(tmask)[0]
-        if not len(live):
-            break
-        q = _qual(vert[tet[live]])
-        bad = live[q < q_floor]
-        if not len(bad):
-            break
-        order = bad[np.argsort(q[q < q_floor])]
-        progressed = False
-        for t in order:
-            if not tmask[t]:
-                continue
-            if _qual(vert[tet[t]][None])[0] >= q_floor:
-                continue
-            done = False
-            if allow_collapse:
-                # edges sorted by length: shortest first (the cap)
-                pts = vert[tet[t]]
-                el = [(np.linalg.norm(pts[j] - pts[i]), i, j)
-                      for i, j in IARE]
-                for _d, i, j in sorted(el):
-                    a, b = int(tet[t][i]), int(tet[t][j])
-                    if try_collapse(a, b) or try_collapse(b, a):
-                        done = True
-                        break
-            if not done and allow_swap:
-                done = try_swap23(t) or try_swap32(t)
-            if not done and allow_move:
-                for k in range(4):
-                    if try_relocate(int(tet[t][k])):
-                        done = True
-                        break
-            if done:
-                nfixed += 1
-                progressed = True
-        if not progressed:
-            break
+        # one span a round (obs/trace.py): what each costs and fixed
+        with span("repair round", bad=0, fixed=0) as sp:
+            live = np.where(tmask)[0]
+            if not len(live):
+                break
+            q = _qual(vert[tet[live]])
+            bad = live[q < q_floor]
+            if not len(bad):
+                break
+            order = bad[np.argsort(q[q < q_floor])]
+            before = nfixed
+            for t in order:
+                if not tmask[t]:
+                    continue
+                if _qual(vert[tet[t]][None])[0] >= q_floor:
+                    continue
+                done = False
+                if allow_collapse:
+                    # edges sorted by length: shortest first (the cap)
+                    pts = vert[tet[t]]
+                    el = [(np.linalg.norm(pts[j] - pts[i]), i, j)
+                          for i, j in IARE]
+                    for _d, i, j in sorted(el):
+                        a, b = int(tet[t][i]), int(tet[t][j])
+                        if try_collapse(a, b) or try_collapse(b, a):
+                            done = True
+                            break
+                if not done and allow_swap:
+                    done = try_swap23(t) or try_swap32(t)
+                if not done and allow_move:
+                    for k in range(4):
+                        if try_relocate(int(tet[t][k])):
+                            done = True
+                            break
+                if done:
+                    nfixed += 1
+            sp.set(bad=len(bad), fixed=nfixed - before)
+            if nfixed == before:
+                break
     return vert, tet, tmask, vmask, tref, ftag, etag, fref, nfixed
 
 

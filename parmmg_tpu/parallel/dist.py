@@ -740,7 +740,23 @@ def distributed_adapt(mesh: Mesh, met, n_shards: int,
     return merged, met_m, part_new
 
 
-@otrace.profile_guard(clear_pass=True)
+def _clears_pass_tag(fn):
+    """The outer loop tags every trace record with its ``pass`` in the
+    process-wide context; an exception unwinding it (ShardOverflowError
+    degrade, device OOM) must not leave the tag on the records of the
+    caller's tail."""
+    import functools
+
+    @functools.wraps(fn)
+    def wrapper(*args, **kwargs):
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            otrace.set_context(**{"pass": None})
+    return wrapper
+
+
+@_clears_pass_tag
 def distributed_adapt_multi(mesh: Mesh, met, n_shards: int,
                             niter: int = 3, cycles: int = 10,
                             dmesh: DeviceMesh | None = None,
@@ -1014,9 +1030,8 @@ def distributed_adapt_multi(mesh: Mesh, met, n_shards: int,
     # topology its cached exchange programs key on
     with pod.activate(dmesh, n_shards), hot_path():
         for it in range(it0, max(1, niter)):
-            # profiler capture window + pass tag on every trace record
-            # emitted inside this outer iteration (obs/trace.py)
-            otrace.profile_pass_begin(it)
+            # pass tag on every trace record emitted inside this outer
+            # iteration (obs/trace.py)
             otrace.set_context(**{"pass": it})
             capP_before = stacked.vert.shape[1]
             _t_seg = time.perf_counter()
@@ -1308,7 +1323,6 @@ def distributed_adapt_multi(mesh: Mesh, met, n_shards: int,
                                 "replicated it); process 0 durably "
                                 "writes, the others only needed the "
                                 "agreement"))
-            otrace.profile_pass_end(it)
     otrace.set_context(**{"pass": None})
     _t_seg = time.perf_counter()
     if multi:

@@ -337,7 +337,7 @@ def _pipeline_chunks(fn, stacked, met_s, wave, plans, tim, done=None,
             tl = None if topo is None else \
                 jax.tree.map(lambda a: jnp.asarray(a[idx]), topo)
         faultpoint("dispatch.chunk", key=str(pi))
-        with otrace.annotate(f"grp_dispatch_chunk{pi}"):
+        with otrace.span("grp dispatch chunk", chunk_index=pi):
             if topo is None:
                 m, k, cnt = fn(sl, kl, wave, act, *extra)
                 tp = None
@@ -468,11 +468,6 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     from .sched import QuietGroupScheduler
     from ..core.mesh import mesh_to_host
 
-    vert_h, tet_h, _, _, _ = mesh_to_host(mesh)
-    if part is None:
-        cent = vert_h[tet_h].mean(axis=1)
-        part = fix_contiguity(tet_h, morton_partition(cent, ngroups))
-
     # The split is staged on the host CPU backend (host_staging): it
     # runs a per-shard adjacency program and stacks the result, a
     # one-shot program whose TPU compile costs far more than its run.
@@ -485,24 +480,28 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     # once and stays there for the whole pass.
     from ..utils.placement import host_staging, to_device
     chunk = group_chunk(ngroups)
-    with host_staging():
-        stacked, met_s = split_to_shards(
-            mesh, met, part, ngroups, cap_mult=cap_mult,
-            reuse_caps=cap_state[0] if cap_state else None)
-        if chunk:
-            g_exec = -(-ngroups // chunk) * chunk
-            # np.array (copy): np.asarray of a jax array can hand back
-            # a READ-ONLY buffer, and the host state is mutated in
-            # place by the per-chunk writebacks
-            stacked = jax.tree.map(
-                lambda a: np.array(a), _pad_groups(stacked, g_exec))
-            met_s = np.array(_pad_groups(met_s, g_exec))
-    if not chunk:
-        g_exec = ngroups
-        stacked, met_s = to_device((stacked, met_s))
+    with otrace.span("grp split", groups=ngroups) as sp:
+        vert_h, tet_h, _, _, _ = mesh_to_host(mesh)
+        if part is None:
+            cent = vert_h[tet_h].mean(axis=1)
+            part = fix_contiguity(tet_h, morton_partition(cent, ngroups))
+        with host_staging():
+            stacked, met_s = split_to_shards(
+                mesh, met, part, ngroups, cap_mult=cap_mult,
+                reuse_caps=cap_state[0] if cap_state else None)
+            if chunk:
+                g_exec = -(-ngroups // chunk) * chunk
+                # np.array (copy): np.asarray of a jax array can hand
+                # back a READ-ONLY buffer, and the host state is mutated
+                # in place by the per-chunk writebacks
+                stacked = jax.tree.map(
+                    lambda a: np.array(a), _pad_groups(stacked, g_exec))
+                met_s = np.array(_pad_groups(met_s, g_exec))
+        largest = np.bincount(part).max().tolist()
+        sp.set(capP=stacked.vert.shape[1], capT=stacked.tet.shape[1],
+               largest=largest)
     otrace.log(2, f"  grp split: {ngroups} groups, largest "
-                  f"{np.bincount(part).max()} tets, "
-                  f"capacity (capP, capT) = "
+                  f"{largest} tets, capacity (capP, capT) = "
                   f"({stacked.vert.shape[1]}, {stacked.tet.shape[1]})",
                verbose=verbose)
 
@@ -515,22 +514,31 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
             return d
         jax.tree.map(w, dst_tree, src_tree)
 
-    sched = QuietGroupScheduler(ngroups, g_exec, chunk)
-    # smoothing-cadence enable as a DEVICE SCALAR: always an argument
-    # of the compiled block (like the quiet mask), so toggling
-    # PARMMG_SMOOTH_CADENCE mints zero new compile families
-    from .sched import cadence_enabled
-    cad = jnp.asarray(cadence_enabled())
-    # incremental topology engine (ops/topo_incr, PARMMG_INCR_TOPO):
-    # per-slot retained-table + dirty-band state rides the group axis —
-    # host-resident in chunk mode (rows committed by drain writebacks,
-    # same idempotent contract as the mesh state), device-resident
-    # otherwise.  The knob is a traced scalar like the cadence.
-    from ..ops.topo_incr import incr_topo_enabled, topo_init, topo_init_np
-    inc = jnp.asarray(incr_topo_enabled())
-    capT_s = stacked.tet.shape[1]
-    topo_s = topo_init_np(g_exec, capT_s) if chunk else \
-        topo_init(capT_s, stack=g_exec)
+    # everything the pass commits to the device before its first block:
+    # the stacked state (unchunked), the scheduler's scalars, the
+    # incremental-topology state
+    with otrace.span("grp upload", chunk=chunk or 0) as sp:
+        if not chunk:
+            g_exec = ngroups
+            stacked, met_s = to_device((stacked, met_s))
+            sp.set(bytes=sum(a.nbytes for a in
+                             jax.tree.leaves((stacked, met_s))))
+        sched = QuietGroupScheduler(ngroups, g_exec, chunk)
+        # smoothing-cadence enable as a DEVICE SCALAR: always an argument
+        # of the compiled block (like the quiet mask), so toggling
+        # PARMMG_SMOOTH_CADENCE mints zero new compile families
+        from .sched import cadence_enabled
+        cad = jnp.asarray(cadence_enabled())
+        # incremental topology engine (ops/topo_incr, PARMMG_INCR_TOPO):
+        # per-slot retained-table + dirty-band state rides the group axis —
+        # host-resident in chunk mode (rows committed by drain writebacks,
+        # same idempotent contract as the mesh state), device-resident
+        # otherwise.  The knob is a traced scalar like the cadence.
+        from ..ops.topo_incr import incr_topo_enabled, topo_init, topo_init_np
+        inc = jnp.asarray(incr_topo_enabled())
+        capT_s = stacked.tet.shape[1]
+        topo_s = topo_init_np(g_exec, capT_s) if chunk else \
+            topo_init(capT_s, stack=g_exec)
     # pipeline segment timers on a LOCAL registry: folded into
     # stats.sched_extra and (prefixed) into the caller's Timers at the
     # end, so the driver report shows the transfer/compute split
@@ -547,7 +555,12 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
         pres_all_on = all(pres)
         wave = jnp.asarray(c, jnp.int32)
         act, plans = sched.plan_block(pres_all_on)
-        with otrace.context(block=c, chunk=chunk or 0):
+        # one span a dispatched block, dispatch to counter pull, with
+        # the operations it applied: the ratio of useful outcomes to
+        # attempts is recorded where the work happens
+        with otrace.context(block=c, chunk=chunk or 0), \
+                otrace.span("grp block", block=c, nblk=nblk,
+                            active=len(act)) as sp:
             if chunk:
                 parts = _pipeline_chunks(step, stacked, met_s, wave,
                                          plans, ltim, extra=(cad, inc),
@@ -564,22 +577,29 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                 # unchunked: compaction cannot change the dispatch
                 # shape — the device-resident quiet mask is what skips
                 # converged groups here (lax.cond identity rows,
-                # sched.block_mask; bit-for-bit by the fixed point)
-                # "compute": dispatch to counter pull, as in the chunk
-                # pipeline (the pull is the block's only sync)
-                with ltim("compute"):
-                    stacked, met_s, counts, topo_s = step(
-                        stacked, met_s, wave,
-                        jnp.asarray(sched.block_mask(pres_all_on)), cad,
-                        inc, topo_s)
-                    counts_act = np.asarray(counts)  # [g_exec, nblk, 9]
+                # sched.block_mask; bit-for-bit by the fixed point).
+                # The pull of the counters is the block's only sync
+                stacked, met_s, counts, topo_s = step(
+                    stacked, met_s, wave,
+                    jnp.asarray(sched.block_mask(pres_all_on)), cad,
+                    inc, topo_s)
+                counts_act = np.asarray(counts)  # [g_exec, nblk, 9]
+            # quiet groups contribute exact zeros (that is what marked
+            # them)
+            cs = counts_act.sum(axis=0, dtype=np.int64)     # [nblk, 8]
+            # ONE host conversion for the whole block's counters
+            # (counts_act is already host numpy — the drain pulled it);
+            # the per-counter int() casts were R2-baselined noise
+            cs_l = cs.tolist()                              # python ints
+            sp.set(split=sum(r[0] for r in cs_l),
+                   collapse=sum(r[1] for r in cs_l),
+                   swap=sum(r[2] for r in cs_l),
+                   moved=sum(r[3] for r in cs_l))
+        if not chunk:
+            # "compute" as the chunk pipeline records it: the seconds
+            # from dispatch to counter pull
+            ltim.add("compute", sp.dur)
         sched.record_block(act, counts_act, swap_inc, pres_all_on)
-        # quiet groups contribute exact zeros (that is what marked them)
-        cs = counts_act.sum(axis=0, dtype=np.int64)     # [nblk, 8]
-        # ONE host conversion for the whole block's counters (counts_act
-        # is already host numpy — the drain pulled it); the per-counter
-        # int() casts were R2-baselined noise
-        cs_l = cs.tolist()                              # python ints
         for i in range(nblk):
             tot = cs_l[i]
             # counts[8]: dirty tets pending at each cycle start, summed
@@ -598,49 +618,51 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
         if any(row[4] != 0 for row in cs_l):
             if regrows >= 6:
                 raise MemoryError("group capacity exhausted")
-            capP = stacked.vert.shape[1]
-            capT = stacked.tet.shape[1]
-            from .distribute import regrown_capacity
-            newP, newT = regrown_capacity(capP, capT)
-            if chunk:
-                # host-resident grow (np.pad mirror of grow_shards —
-                # jnp.pad would re-materialize the state on device)
-                import dataclasses as _dc
+            with otrace.span("grp regrow") as sp:
+                capP = stacked.vert.shape[1]
+                capT = stacked.tet.shape[1]
+                from .distribute import regrown_capacity
+                newP, newT = regrown_capacity(capP, capT)
+                sp.set(capT0=capT, capT1=newT)
+                if chunk:
+                    # host-resident grow (np.pad mirror of grow_shards —
+                    # jnp.pad would re-materialize the state on device)
+                    import dataclasses as _dc
 
-                def _padP(x, fill=0):
-                    pad = [(0, 0)] * x.ndim
-                    pad[1] = (0, newP - capP)
-                    return np.pad(x, pad, constant_values=fill)
+                    def _padP(x, fill=0):
+                        pad = [(0, 0)] * x.ndim
+                        pad[1] = (0, newP - capP)
+                        return np.pad(x, pad, constant_values=fill)
 
-                def _padT(x, fill=0):
-                    pad = [(0, 0)] * x.ndim
-                    pad[1] = (0, newT - capT)
-                    return np.pad(x, pad, constant_values=fill)
+                    def _padT(x, fill=0):
+                        pad = [(0, 0)] * x.ndim
+                        pad[1] = (0, newT - capT)
+                        return np.pad(x, pad, constant_values=fill)
 
-                stacked = _dc.replace(
-                    stacked,
-                    vert=_padP(stacked.vert), vref=_padP(stacked.vref),
-                    vtag=_padP(stacked.vtag),
-                    vmask=_padP(stacked.vmask, False),
-                    tet=_padT(stacked.tet), tref=_padT(stacked.tref),
-                    tmask=_padT(stacked.tmask, False),
-                    adja=_padT(stacked.adja, -1),
-                    ftag=_padT(stacked.ftag), fref=_padT(stacked.fref),
-                    etag=_padT(stacked.etag))
-                met_s = _padP(met_s)
-            else:
-                stacked, met_s = grow_shards(stacked, met_s, newP, newT)
-            # regrow permutes tet slots (compact) and changes capT: the
-            # retained sorts are stale at the new capacity — re-init
-            # (ok=False => next derivation is a full rebuild, exact)
-            capT_s = stacked.tet.shape[1]
-            topo_s = topo_init_np(g_exec, capT_s) if chunk else \
-                topo_init(capT_s, stack=g_exec)
-            regrows += 1
-            # the wave top-K budgets scale with capT: every quiet proof
-            # is stale at the new capacity — reactivate the full set
-            # (truncated winners must rerun)
-            sched.on_regrow()
+                    stacked = _dc.replace(
+                        stacked,
+                        vert=_padP(stacked.vert), vref=_padP(stacked.vref),
+                        vtag=_padP(stacked.vtag),
+                        vmask=_padP(stacked.vmask, False),
+                        tet=_padT(stacked.tet), tref=_padT(stacked.tref),
+                        tmask=_padT(stacked.tmask, False),
+                        adja=_padT(stacked.adja, -1),
+                        ftag=_padT(stacked.ftag), fref=_padT(stacked.fref),
+                        etag=_padT(stacked.etag))
+                    met_s = _padP(met_s)
+                else:
+                    stacked, met_s = grow_shards(stacked, met_s, newP, newT)
+                # regrow permutes tet slots (compact) and changes capT: the
+                # retained sorts are stale at the new capacity — re-init
+                # (ok=False => next derivation is a full rebuild, exact)
+                capT_s = stacked.tet.shape[1]
+                topo_s = topo_init_np(g_exec, capT_s) if chunk else \
+                    topo_init(capT_s, stack=g_exec)
+                regrows += 1
+                # the wave top-K budgets scale with capT: every quiet proof
+                # is stale at the new capacity — reactivate the full set
+                # (truncated winners must rerun)
+                sched.on_regrow()
             continue        # re-run the block: truncated winners rerun
         c += nblk
         if block_converged(cs, flags, noswap):
@@ -815,8 +837,9 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     # of this pass (the reference's -distributed-output checkpoint
     # role).  ckpt_due-gated: free unless PARMMG_CKPT_DIR is armed.
     if ckpt_tag is not None:
-        from ..resilience.checkpoint import snapshot_stacked
-        snapshot_stacked(ckpt_tag, ckpt_it, stacked, ngroups)
+        from ..resilience.checkpoint import ckpt_span, snapshot_stacked
+        with ckpt_span(ckpt_it):
+            snapshot_stacked(ckpt_tag, ckpt_it, stacked, ngroups)
     if cap_state is not None:
         cap_state[:] = [(stacked.vert.shape[1], stacked.tet.shape[1])]
     # merge staged on the host like the split: merge_shards rebuilds
@@ -827,12 +850,17 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     # transfer of the stacked state for the host-staged merge
     # (merge_shards slices it per shard, which on device arrays would
     # be device programs plus a pull per field per shard)
-    stacked_h, met_h = jax.tree.map(np.asarray, (stacked, met_s))
-    with host_staging():
-        return merge_shards(stacked_h, met_h, return_part=True)
+    with otrace.span("grp pull") as sp:
+        stacked_h, met_h = jax.tree.map(np.asarray, (stacked, met_s))
+        sp.set(bytes=sum(a.nbytes for a in
+                         jax.tree.leaves((stacked_h, met_h))))
+    with otrace.span("grp merge") as sp, host_staging():
+        merged, met_m, part_m = merge_shards(stacked_h, met_h,
+                                             return_part=True)
+        sp.set(ne=len(part_m))
+    return merged, met_m, part_m
 
 
-@otrace.profile_guard()
 def grouped_adapt(mesh: Mesh, met, target_size: int, niter: int = 3,
                   cycles: int = 12, verbose: int = 0, stats=None,
                   noinsert: bool = False, noswap: bool = False,
@@ -895,9 +923,6 @@ def grouped_adapt(mesh: Mesh, met, target_size: int, niter: int = 3,
                                    "checkpoint")
                 return mesh, met
     for it in range(it0, max(1, niter)):
-        # profiler capture window (PARMMG_PROFILE_DIR over the
-        # PARMMG_PROFILE_PASS outer-pass range — obs/trace.py)
-        otrace.profile_pass_begin(it)
         with otrace.context(**{"pass": it}):
             ne = int(np.asarray(mesh.tmask).sum())
             # a displaced partition fixes the group count (its labels
@@ -912,9 +937,9 @@ def grouped_adapt(mesh: Mesh, met, target_size: int, niter: int = 3,
                 if stats is not None:
                     stats += st
                 part = None
-                ckpt.save_pass_checkpoint(ckpt_tag, it, mesh, met, part,
-                                          fingerprint=fp)
-                otrace.profile_pass_end(it)
+                with ckpt.ckpt_span(it):
+                    ckpt.save_pass_checkpoint(ckpt_tag, it, mesh, met,
+                                              part, fingerprint=fp)
                 continue
             mesh, met, part_m = grouped_adapt_pass(
                 mesh, met, ngroups, cycles=cycles, part=part,
@@ -923,14 +948,10 @@ def grouped_adapt(mesh: Mesh, met, target_size: int, niter: int = 3,
                 timers=timers, ckpt_tag=ckpt_tag, ckpt_it=it,
                 cap_state=cap_state)
             if it + 1 < max(1, niter):
-                _, tet_h, _, _, _ = mesh_to_host(mesh)
-                part = move_interfaces(tet_h, part_m, ngroups,
-                                       nlayers=ifc_layers)
-                # the checkpoint carries the DISPLACED labels: pass
-                # it+1's exact input, which is what makes resume
-                # bit-identical to the uninterrupted run
-                ckpt.save_pass_checkpoint(ckpt_tag, it, mesh, met, part,
-                                          fingerprint=fp)
+                with otrace.span("grp displace", layers=ifc_layers):
+                    _, tet_h, _, _, _ = mesh_to_host(mesh)
+                    part = move_interfaces(tet_h, part_m, ngroups,
+                                           nlayers=ifc_layers)
             else:
                 # the FINAL pass checkpoints too (part=None — there is
                 # no next pass to feed): a kill during the caller's
@@ -938,7 +959,11 @@ def grouped_adapt(mesh: Mesh, met, target_size: int, niter: int = 3,
                 # at the 1M-tet scale) must not restart the whole
                 # adaptation; resume with it0 == niter skips the loop
                 # and hands the tail this state
-                ckpt.save_pass_checkpoint(ckpt_tag, it, mesh, met,
-                                          None, fingerprint=fp)
-        otrace.profile_pass_end(it)
+                part = None
+            # a checkpoint carries the DISPLACED labels: pass it+1's
+            # exact input, which is what makes resume bit-identical to
+            # the uninterrupted run
+            with ckpt.ckpt_span(it):
+                ckpt.save_pass_checkpoint(ckpt_tag, it, mesh, met, part,
+                                          fingerprint=fp)
     return mesh, met

@@ -63,6 +63,14 @@ def ckpt_due(it: int) -> bool:
     return bool(d) and (it + 1) % every == 0
 
 
+def ckpt_span(it: int):
+    """The ``grp checkpoint`` span round a pass's checkpoint writes when
+    pass ``it`` is due; nothing is emitted when unarmed."""
+    from contextlib import nullcontext
+    from ..obs.trace import span
+    return span("grp checkpoint") if ckpt_due(it) else nullcontext()
+
+
 def _ckpt_path(d: str, tag: str, it: int) -> str:
     return os.path.join(d, f"{tag}.pass{it}.npz")
 

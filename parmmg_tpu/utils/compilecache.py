@@ -33,10 +33,18 @@ import dataclasses
 import functools
 import os
 import threading
+import time
 
 # the jax.monitoring event recorded around every XLA backend compile
-# (jax._src.dispatch.BACKEND_COMPILE_EVENT)
+# (jax._src.dispatch.BACKEND_COMPILE_EVENT); a hit in the persistent
+# cache fires it too, with the retrieval inside it
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# the other events jax 0.9.0 emits on the way to an executable: tracing
+# to a jaxpr, lowering it to MLIR, and loading from the persistent cache
+TRACE_LOWER_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                      "/jax/core/compile/jaxpr_to_mlir_module_duration")
+CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 # ---------------------------------------------------------------------------
@@ -102,6 +110,17 @@ class CompileLedger:
     compiles synchronously inside the dispatching call).  Events firing
     outside any governed scope land in the ``(ungoverned)`` aggregate,
     so total compile time stays visible even for unregistered programs.
+
+    The same listener feeds the metrics spine (``obs.metrics.REGISTRY``)
+    with what the process paid on the way to its executables —
+    ``compile.backend_n`` / ``compile.backend_s`` (backend-compile
+    events), ``compile.cache_load_s`` (retrieval from the persistent
+    cache), ``compile.trace_lower_s`` (jaxpr tracing + lowering to
+    MLIR), each in seconds no other of them counts
+    (:meth:`_exclusive`), and ``compile.cache_hits`` — and emits one
+    ``compile`` trace event per backend compile, with the program's
+    ``fun`` name, its ``dur`` and the span open at the time as
+    ``parent``: a compile inside a steady job names the step that paid.
     """
 
     UNGOVERNED = "(ungoverned)"
@@ -128,13 +147,54 @@ class CompileLedger:
         except Exception:       # pragma: no cover - jax always present
             return
         monitoring.register_event_duration_secs_listener(self._on_event)
+        monitoring.register_event_listener(self._on_count)
         self._listener_installed = True
 
-    def _on_event(self, event: str, duration: float, **_kw) -> None:
-        # jax passes extra keyword arguments (fun_name=...) to duration
-        # listeners; none is needed here
+    def _on_count(self, event: str, **_kw) -> None:
+        if event == CACHE_HIT_EVENT:
+            from ..obs.metrics import REGISTRY
+            REGISTRY.counter("compile.cache_hits").inc()
+
+    def _exclusive(self, duration: float) -> float:
+        """Seconds of an event that the events nested inside it have
+        not counted already.  jax reports an inner jit's trace inside
+        its caller's, a cache retrieval inside the backend-compile event
+        of its program, an eager op's compile inside a trace: each fires
+        before the event round it, on the same thread, so the newer
+        intervals an event covers are taken off it and the counters
+        add up to the time spent, not to a multiple of it."""
+        now = time.time()
+        start = now - duration
+        ivals = getattr(self._tls, "ivals", None)
+        if ivals is None:
+            ivals = self._tls.ivals = []
+        inner = 0.0
+        while ivals and ivals[-1][0] >= start - 1e-4:
+            s, e = ivals.pop()
+            inner += e - s
+        ivals.append((start, now))
+        del ivals[:-4096]
+        return max(0.0, duration - inner)
+
+    def _on_event(self, event: str, duration: float, **kw) -> None:
+        if event in TRACE_LOWER_EVENTS:
+            series = "compile.trace_lower_s"
+        elif event == CACHE_LOAD_EVENT:
+            series = "compile.cache_load_s"
+        elif event == BACKEND_COMPILE_EVENT:
+            series = "compile.backend_s"
+        else:
+            return
+        from ..obs.metrics import REGISTRY
+        duration = float(duration)
+        # lint: ok(R6) — series is one of the three literals above
+        REGISTRY.counter(series).inc(self._exclusive(duration))
         if event != BACKEND_COMPILE_EVENT:
             return
+        from ..obs import trace as otrace
+        REGISTRY.counter("compile.backend_n").inc()
+        otrace.event("compile", fun=str(kw.get("fun_name", "?")),
+                     dur=round(duration, 6))
         stack = getattr(self._tls, "stack", None)
         name = stack[-1][0] if stack else self.UNGOVERNED
         with self._lock:

@@ -4,9 +4,10 @@ The reference tracks per-phase times in ``PMMG_ctim[TIMEMAX]`` slots with
 verbosity-gated prints (parmmg.c:35,91; libparmmg1.c:636-948).  Here a
 small nestable timer registry with the same reporting role.
 
-Every completed scope ALSO emits a structured trace span
-(obs/trace.py) carrying this instance's ``trace_id``, so the JSONL
-trace replays to exactly this registry's totals
+Every scope IS an ``obs.trace.span`` (the one span primitive: id,
+parent, start on the profiler's clock, a timeline annotation of the
+same name) carrying this instance's ``trace_id``, so the JSONL trace
+replays to exactly this registry's totals
 (``obs.trace.replay_totals(path, tim=timers.trace_id)`` — the
 ``run_tests.sh --obs`` gate's check).  Emission is a ring-buffer append
 when no sink is armed: safe in the chunk-pipeline hot loop.
@@ -19,26 +20,12 @@ compile accounting: ``Timers.report`` for phases,
 from __future__ import annotations
 
 import itertools
-import time
 from contextlib import contextmanager
 
+from ..obs.trace import emit_span, span
 from .compilecache import (                                    # noqa: F401
     LEDGER, format_ledger, ledger_snapshot, ledger_violations,
     reset_ledger)
-
-_EMIT = None        # lazily-bound obs.trace.emit_span (False = unavailable)
-
-
-def _emit_span(path, dur, count=1, tim=None, ext=False) -> None:
-    global _EMIT
-    if _EMIT is None:
-        try:
-            from ..obs.trace import emit_span
-            _EMIT = emit_span
-        except Exception:       # pragma: no cover - obs is always present
-            _EMIT = False
-    if _EMIT:
-        _EMIT(path, dur, count=count, tim=tim, ext=ext)
 
 
 class Timers:
@@ -47,7 +34,7 @@ class Timers:
     def __init__(self):
         self.acc: dict[str, float] = {}
         self.count: dict[str, int] = {}
-        self._stack: list[tuple[str, float]] = []
+        self._stack: list[str] = []
         # paths absorbed via add() OUTSIDE any active scope: externally
         # measured segments, rendered distinctly by report()
         self.external: set[str] = set()
@@ -56,22 +43,19 @@ class Timers:
 
     @contextmanager
     def __call__(self, name: str):
-        path = "/".join([p for p, _ in self._stack] + [name])
-        t0 = time.perf_counter()
-        self._stack.append((name, t0))
+        path = "/".join(self._stack + [name])
+        self._stack.append(name)
+        sp = span(path, tim=self.trace_id)
         try:
-            yield
+            with sp:
+                yield
         finally:
             self._stack.pop()
-            # round to the ns the trace span carries (emit_span rounds
-            # its record to 9 decimals): accumulator and replayed
-            # stream then agree bit-for-bit even on kernels whose
-            # perf_counter returns sub-ns fractions (the --obs gate's
-            # replay==report contract)
-            dt = round(time.perf_counter() - t0, 9)
-            self.acc[path] = self.acc.get(path, 0.0) + dt
+            # the very float the record carries (whole ns / 1e9):
+            # accumulator and replayed stream agree bit-for-bit (the
+            # --obs gate's replay==report contract)
+            self.acc[path] = self.acc.get(path, 0.0) + sp.dur
             self.count[path] = self.count.get(path, 0) + 1
-            _emit_span(path, dt, tim=self.trace_id)
 
     def add(self, name: str, seconds: float, count: int = 1) -> None:
         """Fold an externally-measured duration into the registry at
@@ -86,15 +70,15 @@ class Timers:
         marker instead of passing it off as a phase of this registry,
         and the emitted span carries ``ext=True``."""
         ext = not self._stack
-        path = "/".join([p for p, _ in self._stack] + [name])
+        path = "/".join(self._stack + [name])
         if ext:
             self.external.add(path)
         # same ns rounding as the scope exit: acc == replayed spans
         seconds = round(float(seconds), 9)
         self.acc[path] = self.acc.get(path, 0.0) + seconds
         self.count[path] = self.count.get(path, 0) + int(count)
-        _emit_span(path, seconds, count=int(count),
-                   tim=self.trace_id, ext=ext)
+        emit_span(path, seconds, count=int(count),
+                  tim=self.trace_id, ext=ext)
 
     def report(self, min_s: float = 0.0) -> str:
         lines = []

@@ -1,13 +1,16 @@
 """Telemetry spine (parmmg_tpu/obs): trace, metrics, artifacts.
 
-All host-only — no jitted programs, so tier-1 pays zero compile time
-for this file.  The compile-family and replay-parity end-to-end gates
+Host-only but for one tiny grouped job (the ``grouped_job`` fixture:
+``cube_mesh(3)``, two groups, compiled once for the module, about a
+minute on the CPU) whose span tree, counters and operator capture the
+last tests read.  The compile-family and replay-parity end-to-end gates
 live in scripts/obs_check.py (run_tests.sh --obs); here the host
-semantics: span nesting + run-context propagation, the Timers bridge
-(emission parity, external-segment tagging), histogram bucket edges,
-Prometheus exposition round-trip, tenant namespacing riding the
-AdaptStats isolation contract, and artifact schema validation on the
-checked-in BENCH/SCALE/SERVE round artifacts.
+semantics: the span primitive (identity, parent, the shared clock) +
+run-context propagation, the Timers bridge (emission parity,
+external-segment tagging), the compile listener's attribution,
+histogram bucket edges, Prometheus exposition round-trip, tenant
+namespacing riding the AdaptStats isolation contract, and artifact
+schema validation on the checked-in BENCH/SCALE/SERVE round artifacts.
 """
 import json
 import os
@@ -348,12 +351,296 @@ def test_artifact_diff_on_checked_in_rounds():
     assert oart.artifact_diff(new, new)["value"] == []
 
 
-def test_profiler_unarmed_is_inert(monkeypatch, fresh_tracer):
-    monkeypatch.delenv("PARMMG_PROFILE_DIR", raising=False)
-    assert otrace.profile_pass_begin(0) is False
-    assert otrace.profile_pass_end(0) is False
-    assert otrace.profiling_active() is False
-    # annotate/scope degrade to free nullcontexts when inert
-    with otrace.annotate("x"):
-        with otrace.scope("y"):
-            pass
+# ---------------------------------------------------------------------------
+# the span primitive: identity, the shared clock, the job's span tree
+# ---------------------------------------------------------------------------
+def _interval(r):
+    return r["t0"], r["t0"] + round(r["dur"] * 1e9)
+
+
+def test_spans_carry_identity_and_nest_in_time(fresh_tracer):
+    rid = otrace.new_run(backend="cpu")
+    with otrace.span("outer") as outer:
+        assert otrace.current_span() == outer.id
+        with otrace.span("inner", wave=3) as inner:
+            inner.set(collapse=2, swap=1)
+        otrace.event("mark")
+    assert otrace.current_span() is None
+    ri, ro = spans(fresh_tracer)
+    assert (ri["name"], ro["name"]) == ("inner", "outer")
+    assert ri["id"] != ro["id"] and ri["parent"] == ro["id"]
+    assert "parent" not in ro                       # a root
+    assert ri["run"] == ro["run"] == rid
+    assert (ri["wave"], ri["collapse"], ri["swap"]) == (3, 2, 1)
+    (i0, i1), (o0, o1) = _interval(ri), _interval(ro)
+    assert o0 <= i0 <= i1 <= o1                     # one clock, nested
+    assert inner.dur == ri["dur"] and outer.dur == ro["dur"]
+    mark = [r for r in fresh_tracer.ring if r.get("name") == "mark"][0]
+    assert mark["parent"] == ro["id"]
+    otrace.new_run()
+
+
+def test_timers_go_through_the_span_primitive(fresh_tracer):
+    tim = Timers()
+    with otrace.span("root") as root:
+        with tim("a"):
+            with tim("b"):
+                tim.add("seg", 0.25, count=2)
+        tim.add("ext", 1.5)
+    recs = {r["name"]: r for r in spans(fresh_tracer)}
+    assert set(recs) == {"root", "a", "a/b", "a/b/seg", "ext"}
+    assert recs["a"]["parent"] == root.id
+    assert recs["a/b"]["parent"] == recs["a"]["id"]
+    assert recs["a/b/seg"]["parent"] == recs["a/b"]["id"]
+    assert recs["ext"]["parent"] == root.id
+    # scopes have a start on the clock, folded-in durations have none
+    assert "t0" in recs["a"] and "t0" in recs["a/b"]
+    assert "t0" not in recs["a/b/seg"] and "t0" not in recs["ext"]
+    assert recs["ext"].get("ext") is True and "ext" not in recs["a/b/seg"]
+    assert all(recs[n]["tim"] == tim.trace_id
+               for n in ("a", "a/b", "a/b/seg", "ext"))
+    assert len({r["id"] for r in recs.values()}) == 5
+    # and the stream still replays to the registry exactly
+    tot, cnt = otrace.replay_totals(list(fresh_tracer.ring),
+                                    tim=tim.trace_id)
+    assert tot == tim.acc and cnt == tim.count
+    assert cnt["a/b/seg"] == 2
+
+
+def test_span_records_without_jax():
+    """Host-only contexts stay jax-free: the primitive neither imports
+    jax nor needs it."""
+    import subprocess
+    import sys
+    code = (
+        "import sys\n"
+        "from parmmg_tpu.obs import trace as t\n"
+        "from parmmg_tpu.utils.timers import Timers\n"
+        "tim = Timers()\n"
+        "with t.span('a', n=1) as a:\n"
+        "    with tim('b'):\n"
+        "        pass\n"
+        "recs = [r for r in t.TRACER.ring if r['kind'] == 'span']\n"
+        "assert [r['name'] for r in recs] == ['b', 'a'], recs\n"
+        "assert recs[0]['parent'] == recs[1]['id'] == a.id\n"
+        "assert 'jax' not in sys.modules\n")
+    env = dict(os.environ, PYTHONPATH=ROOT)
+    subprocess.run([sys.executable, "-c", code], check=True, env=env,
+                   timeout=120)
+
+
+def test_compile_listener_names_the_span_that_paid(fresh_tracer):
+    import jax
+    import numpy as np
+    from parmmg_tpu.obs.metrics import REGISTRY
+    from parmmg_tpu.utils.compilecache import LEDGER
+    LEDGER.install_listener()
+
+    def count(name):
+        return REGISTRY.snapshot()["counters"].get(name, 0.0)
+
+    x = np.arange(7, dtype=np.float32)          # no eager jnp op
+    n0, s0, t0 = (count("compile.backend_n"), count("compile.backend_s"),
+                  count("compile.trace_lower_s"))
+    fresh = jax.jit(lambda v: v * 3.0 + 1.0)
+    with otrace.span("step") as step:
+        fresh(x).block_until_ready()
+    assert count("compile.backend_n") == n0 + 1
+    assert count("compile.backend_s") > s0
+    assert count("compile.trace_lower_s") > t0
+    ev = [r for r in fresh_tracer.ring if r.get("name") == "compile"]
+    assert len(ev) == 1 and ev[0]["kind"] == "event"
+    assert ev[0]["parent"] == step.id
+    assert ev[0]["fun"].startswith("jit(") and ev[0]["dur"] > 0
+    with otrace.span("again"):
+        fresh(x).block_until_ready()            # compiled: no event
+    assert count("compile.backend_n") == n0 + 1
+
+
+def test_nested_compile_events_are_not_counted_twice():
+    """jax reports an inner event inside the one round it; the counters
+    add up to the time spent."""
+    from parmmg_tpu.utils.compilecache import CompileLedger
+    led = CompileLedger()
+    assert led._exclusive(0.25) == pytest.approx(0.25)      # inner
+    import time
+    time.sleep(0.01)
+    assert led._exclusive(1.0) == pytest.approx(0.75, abs=1e-3)  # outer
+    assert led._exclusive(0.0) == 0.0                       # a later one
+
+
+# ---- one tiny grouped job through the public API (cube_mesh(3), two
+# groups, two passes): compiles once for the module, a minute on the CPU
+GROUPED_SPANS = {
+    # name: its parent's name (None: the root of the job)
+    "run": None, "analysis": "run", "metric": "run", "backup": "run",
+    "adaptation": "run", "bad-element polish": "run",
+    "sequential repair": "run", "fem conformity": "run",
+    "grp split": "adaptation", "grp upload": "adaptation",
+    "grp block": "adaptation", "grp pull": "adaptation",
+    "grp merge": "adaptation", "grp displace": "adaptation",
+    "grp checkpoint": "adaptation", "adaptation/grp compute": "adaptation",
+    "polish wave": "bad-element polish", "fem round": "fem conformity",
+}
+
+
+@pytest.fixture(scope="module")
+def grouped_job(tmp_path_factory):
+    import numpy as np
+    from parmmg_tpu.api.params import IParam
+    from parmmg_tpu.api.parmesh import ParMesh
+    from parmmg_tpu.obs.metrics import REGISTRY
+    from parmmg_tpu.utils.fixtures import cube_mesh
+    vert, tet = cube_mesh(3)
+
+    def counters():
+        return dict(REGISTRY.snapshot()["counters"])
+
+    def job():
+        otrace.TRACER.configure(path=None)
+        otrace.TRACER.reset()
+        before = counters()
+        pm = ParMesh()
+        pm.set_mesh_size(np_=len(vert), ne=len(tet))
+        pm.set_vertices(vert)
+        pm.set_tetrahedra(tet + 1)
+        pm.set_met_size(1, len(vert))
+        pm.set_scalar_mets(np.full(len(vert), 0.3))
+        pm.set_iparameter(IParam.meshSize, len(tet) // 2)   # 2 groups
+        pm.set_iparameter(IParam.niter, 2)
+        pm.set_iparameter(IParam.verbose, -1)
+        rc = pm.run()
+        out_tet, _ = pm.get_tetrahedra()
+        after = counters()
+        return {"rc": rc, "ne_in": len(tet), "ne_out": len(out_tet),
+                "records": list(otrace.TRACER.ring),
+                "summary": otrace.TRACER.summary(),
+                "counters": {k: after[k] - before.get(k, 0.0)
+                             for k in after}}
+
+    mp = pytest.MonkeyPatch()
+    try:
+        # cold, with the pass checkpoints armed
+        mp.setenv("PARMMG_CKPT_DIR", str(tmp_path_factory.mktemp("ckpt")))
+        cold = job()
+        mp.delenv("PARMMG_CKPT_DIR")
+        # warm, inside the operator's capture
+        prof = str(tmp_path_factory.mktemp("prof"))
+        mp.setenv("PARMMG_PROFILE_DIR", prof)
+        warm = job()
+    finally:
+        mp.undo()
+        otrace.TRACER.reset()
+    return {"cold": cold, "warm": warm, "profile_dir": prof}
+
+
+def _tree(records):
+    recs = [r for r in records if r.get("kind") == "span"]
+    return recs, {r["id"]: r for r in recs}
+
+
+def test_grouped_job_emits_the_span_tree(grouped_job):
+    job = grouped_job["cold"]
+    assert job["rc"] == 0
+    recs, by_id = _tree(job["records"])
+    names = {r["name"] for r in recs}
+    assert set(GROUPED_SPANS) <= names, set(GROUPED_SPANS) - names
+    for r in recs:
+        if r["name"] not in GROUPED_SPANS:
+            continue
+        want = GROUPED_SPANS[r["name"]]
+        got = by_id[r["parent"]]["name"] if "parent" in r else None
+        assert got == want, (r["name"], got)
+    assert len({r["run"] for r in recs}) == 1
+    assert len(by_id) == len(recs)                  # ids are unique
+    root = [r for r in recs if r["name"] == "run"]
+    assert len(root) == 1
+    assert root[0]["ne_in"] == job["ne_in"]
+    assert root[0]["ne_out"] == job["ne_out"] and root[0]["status"] == 0
+    # what the accepted readers count on: one folded record a pass
+    assert sum(r["name"] == "adaptation/grp compute" for r in recs) == 2
+    assert sum(r["name"] == "grp displace" for r in recs) == 1
+    split = [r for r in recs if r["name"] == "grp split"]
+    assert len(split) == 2 and all(
+        r["groups"] == 2 and r["capT"] > 0 and r["largest"] > 0
+        and r["pass"] == i for i, r in enumerate(split))
+    blocks = [r for r in recs if r["name"] == "grp block"]
+    assert job["counters"]["groups.dispatches"] == len(blocks)
+    assert sum(r["dur"] for r in blocks) == pytest.approx(
+        job["counters"]["groups.pipeline.compute_s"], rel=1e-9)
+    assert all({"split", "collapse", "swap", "moved", "nblk",
+                "active"} <= set(r) for r in blocks)
+    assert sum(r["split"] for r in blocks) > 0
+    assert job["counters"]["api.set_s"] > 0
+    assert job["counters"]["api.get_s"] > 0
+
+
+def test_polish_waves_count_what_they_applied(grouped_job):
+    job = grouped_job["cold"]
+    recs, _ = _tree(job["records"])
+    waves = [r for r in recs if r["name"] == "polish wave"]
+    assert [r["wave"] for r in waves] == list(range(len(waves)))
+    assert job["counters"]["tail.polish_waves"] == len(waves)
+    assert job["counters"]["tail.polish_ops"] == sum(
+        r["collapse"] + r["swap"] for r in waves)
+    # the loop stops on the first wave that applies nothing
+    assert all(r["collapse"] + r["swap"] > 0 for r in waves[:-1])
+    last = waves[-1]
+    assert last["collapse"] + last["swap"] == 0 or len(waves) == 8
+
+
+@pytest.mark.parametrize("parent", ["run", "adaptation",
+                                    "bad-element polish"])
+def test_children_cover_their_parent(grouped_job, parent):
+    """No host time hides between the spans: the children of a parent
+    lie inside it and cover at least 95 % of it."""
+    recs, _ = _tree(grouped_job["cold"]["records"])
+    top = [r for r in recs if r["name"] == parent][0]
+    lo, hi = _interval(top)
+    kids = sorted(_interval(r) for r in recs
+                  if r.get("parent") == top["id"] and "t0" in r)
+    covered, end = 0, lo
+    for s, e in kids:
+        assert lo <= s <= e <= hi
+        covered += max(0, e - max(s, end))
+        end = max(end, e)
+    assert covered >= 0.95 * (hi - lo), covered / (hi - lo)
+
+
+def test_ring_drops_nothing_over_a_job(grouped_job):
+    for which in ("cold", "warm"):
+        s = grouped_job[which]["summary"]
+        assert s["dropped"] == 0 and s["events"] == s["ring"] > 0
+
+
+def test_unarmed_checkpoint_emits_no_span(grouped_job):
+    cold, _ = _tree(grouped_job["cold"]["records"])
+    warm, _ = _tree(grouped_job["warm"]["records"])
+    # armed: the stacked snapshot and the pass file of each pass
+    assert sum(r["name"] == "grp checkpoint" for r in cold) == 4
+    assert not any(r["name"] == "grp checkpoint" for r in warm)
+    # a warm job compiles nothing but what recompiles every job
+    compiles = [r for r in grouped_job["warm"]["records"]
+                if r.get("name") == "compile"]
+    assert all("parent" in r for r in compiles)
+    assert grouped_job["warm"]["counters"].get(
+        "compile.backend_n", 0) == len(compiles)
+
+
+def test_operator_capture_holds_the_program_spans(grouped_job):
+    """PARMMG_PROFILE_DIR: one capture over one whole run, and the spans
+    are on the profiler's own timeline."""
+    import glob
+    from jax.profiler import ProfileData
+    found = glob.glob(os.path.join(grouped_job["profile_dir"], "plugins",
+                                   "profile", "*", "*.xplane.pb"))
+    assert len(found) == 1
+    names = set()
+    for plane in ProfileData.from_file(found[0]).planes:
+        for line in plane.lines:
+            names |= {ev.name for ev in line.events}
+    assert {"run", "analysis", "adaptation", "grp split", "grp block",
+            "grp merge", "bad-element polish", "polish wave"} <= names
+    kinds = [r["name"] for r in grouped_job["warm"]["records"]
+             if r.get("name", "").startswith("profile_")]
+    assert kinds == ["profile_start", "profile_stop"]

@@ -103,3 +103,20 @@ def test_repair_interior_cluster():
     interior = np.where(vm & (vtag == 0))[0]
     assert len(interior) >= 2
     _run(m, int(interior[0]), int(interior[1]))
+
+
+def test_repair_rounds_are_spans():
+    """Each round of the sequential loop is a ``repair round`` span
+    (obs/trace.py) carrying what it found and fixed."""
+    from parmmg_tpu.obs import trace as otrace
+    vert, tet = cube_mesh(3)
+    m = make_mesh(vert, tet, capP=2 * len(vert), capT=2 * len(tet))
+    m = analyze_mesh(m).mesh
+    interior = np.where(np.asarray(m.vmask) & (np.asarray(m.vtag) == 0))[0]
+    otrace.TRACER.reset()
+    with otrace.span("sequential repair") as tail:
+        _run(m, int(interior[0]), int(interior[1]))
+    rounds = [r for r in otrace.TRACER.ring
+              if r.get("name") == "repair round"]
+    assert rounds and all(r["parent"] == tail.id for r in rounds)
+    assert rounds[0]["bad"] > 0 and sum(r["fixed"] for r in rounds) > 0
