@@ -93,7 +93,11 @@ def make_mesh(vert: np.ndarray, tet: np.ndarray,
     Capacities default to a growth headroom of ~3x points / ~3x tets, the
     analogue of the reference memory-repartition budget
     (zaldy_pmmg.c:140-254) — adaptation inserts points, so headroom is the
-    price of static shapes.
+    price of static shapes.  The 3x is for a mesh that GROWS IN PLACE (the
+    input of the whole-mesh path): every wave sorts, gathers and scatters
+    all ``capT`` rows, dead ones included, so a caller whose mesh does not
+    grow passes its own capacities (``merge_shards`` does, shards get
+    theirs from ``shard_capacity``).
     """
     vert = np.asarray(vert, dtype=np.float64)
     tet = np.asarray(tet, dtype=np.int32)

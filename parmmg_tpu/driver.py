@@ -154,6 +154,16 @@ def parmmg_run(pm) -> tuple[Mesh, object, AdaptStats]:
     return mesh, met, stats
 
 
+def polish_budget(n_live: int) -> int:
+    """Top-K candidate budget, in rows, of each collapse and swap wave
+    of the merged polish: 1.5x the live tets the polish starts from,
+    which is what the wide divisor gave while a merged mesh was padded
+    to 3x.  Stated in rows of content it stays what it is whatever
+    capacity ``merge_shards`` chooses, and the result with it."""
+    from .ops.edges import wave_budget
+    return wave_budget(3 * n_live, 2)
+
+
 def _merged_polish(mesh, met, info, hausd, stats, tim):
     """Bad-element polish on a MERGED mesh, staged on the host (group
     and shard seams breed slivers): up to eight ``sliver_polish`` waves,
@@ -165,6 +175,12 @@ def _merged_polish(mesh, met, info, hausd, stats, tim):
     from .ops.adapt import sliver_polish
     from .utils.placement import host_staging
     ops = 0
+    # every program of the tail costs what its capacity is, not what its
+    # content is: the two counters say how much of it is padding
+    n_live = int(np.asarray(mesh.tmask).sum())
+    REGISTRY.counter("tail.rows_live").inc(n_live)
+    REGISTRY.counter("tail.rows_cap").inc(mesh.capT)
+    budget = polish_budget(n_live)
     with tim("bad-element polish"), host_staging():
         for w in range(8):
             with otrace.span("polish wave", wave=w) as sp:
@@ -172,7 +188,7 @@ def _merged_polish(mesh, met, info, hausd, stats, tim):
                     mesh, met, jnp.asarray(1000 + w, jnp.int32),
                     do_collapse=not info.noinsert,
                     do_swap=not info.noswap,
-                    do_smooth=not info.nomove, hausd=hausd)
+                    do_smooth=not info.nomove, hausd=hausd, budget=budget)
                 ncol, nswap, nmoved = np.asarray(counts)[:3].tolist()
                 sp.set(collapse=ncol, swap=nswap, moved=nmoved)
             stats.ncollapse += ncol

@@ -523,7 +523,8 @@ def default_cycle_block() -> int:
 def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
                        sliver_q: float = 0.2, do_collapse: bool = True,
                        do_swap: bool = True, do_smooth: bool = True,
-                       hausd: float | None = None, active=None):
+                       hausd: float | None = None, active=None,
+                       budget: int | None = None):
     """Bad-element optimization pass (MMG3D_opttyp analogue): quality-
     targeted collapses on tets below ``sliver_q``, then swaps and a
     smoothing wave.  Run after the sizing loop converges — length-driven
@@ -536,6 +537,13 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     row of a compacted chunk plan) returns its state unchanged with
     zero counts instead of running the collapse/swap/smooth math.
 
+    ``budget``: the top-K candidate budget of each collapse and swap
+    wave in ROWS (static).  None keeps the wide divisor, half the
+    capacity, which suits a mesh whose capacity follows its content at
+    a fixed ratio (the whole-mesh path's 3x, a group's).  A caller
+    whose capacity is no measure of its content says what it wants in
+    rows (driver.polish_budget for a merged mesh).
+
     Returns (mesh, counts[4] = [ncollapse, nswap, nmoved, live_tets]).
     """
     from .adjacency import boundary_edge_tags
@@ -544,7 +552,7 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
             return sliver_polish_impl(
                 m, met, wave, sliver_q=sliver_q,
                 do_collapse=do_collapse, do_swap=do_swap,
-                do_smooth=do_smooth, hausd=hausd)
+                do_smooth=do_smooth, hausd=hausd, budget=budget)
 
         def _skip(m):
             counts = jnp.zeros(4, jnp.int32).at[3].set(
@@ -555,28 +563,31 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     nswap = jnp.zeros((), jnp.int32)
     nmoved = jnp.zeros((), jnp.int32)
     if do_collapse:
-        # polish is off the timed sizing path: widen the compaction
-        # budget (budget_div=2) so the quality pass covers the full
-        # sliver population instead of the worst K only
+        # the polish widens the compaction budget (budget_div=2, or the
+        # caller's ``budget`` in rows) so the quality pass covers the
+        # full sliver population instead of the worst K only.  The
+        # budget is meant in rows of CONTENT: 1.5x the live tets on a
+        # mesh at 3x; dead rows are never candidates
         col = collapse_wave(mesh, met, sliver_q=sliver_q, hausd=hausd,
-                            budget_div=2)
+                            budget_div=2, budget=budget)
         mesh = jax.lax.cond(col.surface_changed, boundary_edge_tags,
                             lambda m: m, col.mesh)
         ncol = col.ncollapse
     if do_swap:
         from .swapgen import swapgen_wave
         from .swap import swap_facesort_enabled
-        sew = swap_edges_wave(mesh, met, hausd=hausd,
-                              budget_div=2)  # 3-2 + 2-2
+        sew = swap_edges_wave(mesh, met, hausd=hausd, budget_div=2,
+                              budget=budget)  # 3-2 + 2-2
         # generalized degree 4-6 ring swaps: the worst surviving tets
         # are typically gate-limited for every lower-degree op — this
         # is the class that lifts the min past the 3-2/2-3 plateau
-        sgn = swapgen_wave(sew.mesh, met, budget_div=2)
+        sgn = swapgen_wave(sew.mesh, met, budget_div=2, budget=budget)
         if swap_facesort_enabled():
-            s23 = swap23_wave(sgn.mesh, met, budget_div=2, facesort=True)
+            s23 = swap23_wave(sgn.mesh, met, budget_div=2, budget=budget,
+                              facesort=True)
         else:
             mesh = build_adjacency(sgn.mesh)    # consumed by swap23
-            s23 = swap23_wave(mesh, met, budget_div=2)
+            s23 = swap23_wave(mesh, met, budget_div=2, budget=budget)
         mesh = s23.mesh
         nswap = sew.nswap + sgn.nswap + s23.nswap
     if do_smooth:
@@ -593,8 +604,8 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
 
 sliver_polish = _governed("adapt.sliver_polish")(
     partial(jax.jit, static_argnames=(
-        "sliver_q", "do_collapse", "do_swap", "do_smooth", "hausd"),
-        donate_argnums=(0,))(sliver_polish_impl))
+        "sliver_q", "do_collapse", "do_swap", "do_smooth", "hausd",
+        "budget"), donate_argnums=(0,))(sliver_polish_impl))
 
 
 def grow_mesh_met(mesh: Mesh, met, newP: int, newT: int):

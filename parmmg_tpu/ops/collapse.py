@@ -70,7 +70,7 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
                   lmax: float = LLONG,
                   sliver_q: float | None = None,
                   hausd: float | None = None,
-                  budget_div: int = 8,
+                  budget_div: int = 8, budget: int | None = None,
                   et=None, lens=None,
                   stale_tets: jax.Array | None = None,
                   vtan: jax.Array | None = None,
@@ -186,7 +186,7 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
         # edges in sizing mode; WORST incident tet in sliver mode (the pass
         # exists to raise the min — edge length would misrank the targets)
         from .edges import wave_budget, topk_prep
-        K = min(Efull, wave_budget(capT, budget_div))
+        K = min(Efull, wave_budget(capT, budget_div, budget))
         if sliver_q is None:
             prio = lens
         else:

@@ -23,13 +23,20 @@ _INT32_MAX = 2147483647
 PACK_LIMIT = 46340     # floor(sqrt(2^31)): a*capP+b stays in int32
 
 
-def wave_budget(capT: int, div: int = 8) -> int:
+def wave_budget(capT: int, div: int = 8, rows: int | None = None) -> int:
     """Per-wave top-K compaction budget shared by every wave kernel: the
     K = max(2048, capT//div) highest-priority candidates go through the
     heavy geometry/routing/scatter machinery (cost is linear in index
     count — scripts/wave_time.py); the rest are deferred to the next
-    wave.  The untimed polish passes div=2 for full coverage."""
-    return max(2048, capT // div)
+    wave.  The polish passes div=2 for full coverage.
+
+    ``capT // div`` ties the budget to the PADDING: right for a mesh
+    whose capacity is sized for the content it will grow to (the cycle
+    blocks), wrong for one whose capacity is only a convenience.  A
+    caller that knows its content gives the budget in ``rows`` instead
+    (the merged polish: driver.polish_budget), and then the capacity can
+    change without the candidate set changing with it."""
+    return max(2048, capT // div) if rows is None else rows
 
 
 def free_rows(mask: jax.Array, K: int):

@@ -77,7 +77,7 @@ def swap_edges_wave(mesh: Mesh, met: jax.Array, enable32: bool = True,
                     enable22: bool = True,
                     flat_tol: float = 1e-5,
                     hausd: float | None = None,
-                    budget_div: int = 8,
+                    budget_div: int = 8, budget: int | None = None,
                     vact: jax.Array | None = None,
                     wwin: jax.Array | None = None) -> SwapResult:
     """Combined edge-swap wave: 3-2 interior + 2-2 boundary, ONE pass.
@@ -162,7 +162,7 @@ def swap_edges_wave(mesh: Mesh, met: jax.Array, enable32: bool = True,
         pre22 = pre22 & wok
     pre = pre32 | pre22
     from .edges import wave_budget, topk_prep3
-    K = min(Efull, wave_budget(capT, budget_div))
+    K = min(Efull, wave_budget(capT, budget_div, budget))
     # fused scoring prep (exact q_shell = min(qs0, min(qs1, qs2)) chain)
     neg, npre = topk_prep3(pre, qs0, qs1, qs2)
     defer = npre > K
@@ -570,7 +570,7 @@ def _pair_fields_facesort(mesh: Mesh, q_tet, capT, set_bdy_tags):
 
 
 def swap23_wave(mesh: Mesh, met: jax.Array,
-                budget_div: int = 8,
+                budget_div: int = 8, budget: int | None = None,
                 wwin: jax.Array | None = None,
                 facesort: bool = False,
                 set_bdy_tags: bool = True) -> SwapResult:
@@ -613,7 +613,7 @@ def swap23_wave(mesh: Mesh, met: jax.Array,
     q_pair = jnp.minimum(q_tet, jnp.where(cand_full, q_tet[t2_full],
                                           jnp.inf))
     from .edges import wave_budget, topk_prep
-    F = min(capT, wave_budget(capT, budget_div))
+    F = min(capT, wave_budget(capT, budget_div, budget))
     neg, ncand = topk_prep(cand_full, q_pair)
     defer = ncand > F
     _, sel = jax.lax.top_k(neg, F)

@@ -46,7 +46,7 @@ class SwapGenResult(NamedTuple):
 
 
 def swapgen_wave(mesh: Mesh, met: jax.Array,
-                 budget_div: int = 8,
+                 budget_div: int = 8, budget: int | None = None,
                  lmax: float | None = None) -> SwapGenResult:
     from ..core.constants import LLONG
     if lmax is None:
@@ -76,7 +76,7 @@ def swapgen_wave(mesh: Mesh, met: jax.Array,
     # positions, too heavy at [E,6] width.  Statically-doomed candidates
     # can therefore pin budget slots; this kernel runs in the
     # wide-budget polish phase where K covers the population.
-    K = min(Efull, wave_budget(capT, budget_div))
+    K = min(Efull, wave_budget(capT, budget_div, budget))
     _, selx = jax.lax.top_k(jnp.where(pre, -q_shell_f, -jnp.inf), K)
 
     ar = jnp.arange(K)
@@ -417,7 +417,7 @@ def _make_swapgen_jit():
     from functools import partial as _partial
     from ..utils.compilecache import governed
     return governed("ops.swapgen_wave", budget=4)(
-        _partial(jax.jit, static_argnames=("budget_div", "lmax"))(
+        _partial(jax.jit, static_argnames=("budget_div", "budget", "lmax"))(
             swapgen_wave))
 
 

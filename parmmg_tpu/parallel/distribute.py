@@ -399,6 +399,9 @@ def merge_shards(shards: Mesh, mets=None, return_part: bool = False):
     With ``return_part``, also returns the source-shard label of every
     merged tet (a valid partition of the merged mesh, ready for
     interface displacement).
+
+    The merged mesh is compact (live rows first) and holds 1.5x its
+    content, not ``make_mesh``'s 3x: see where the capacity is chosen.
     """
     nsh = shards.vert.shape[0]
     all_v, all_tag, all_ref, all_met = [], [], [], []
@@ -499,7 +502,30 @@ def merge_shards(shards: Mesh, mets=None, return_part: bool = False):
         if met_k is not None:
             met_k = met_k[vkeep2]
 
-    m = make_mesh(vert_k, tet, vref=vref_k, tref=tref)
+    # Capacity of a merged mesh: 1.5x its content, chosen HERE and from
+    # the live counts alone.  Nobody grows a merged mesh in place: it is
+    # re-split into shards that get their own capacity, read (interface
+    # displacement), or run through the merged tail (driver: polish,
+    # repair, fem), which shrinks it or adds a percent or two; and every
+    # wave of that tail sorts, gathers and scatters all capT rows, so
+    # make_mesh's 3x doubled the tail's cost for nothing (PERF.md, PR 29).
+    # Why 1.5x and not less:
+    # - the polish's top-K budget is (3 n_t) // 2 rows of CONTENT
+    #   (driver.polish_budget); at this capacity no top-K is wider than
+    #   the arrays it reads, and under it the K-wide machinery, not the
+    #   padding, is what a wave costs (1.25x measured no faster);
+    # - the n_t // 2 free rows are the most ONE allocating wave can take:
+    #   a 2-3 swap claims two live tets for itself and allocates one row,
+    #   a 6-ring swap claims six and allocates two (n_t // 3).  Only both
+    #   at that ceiling in one polish wave would run the pool dry, and
+    #   then the winners that find no row are deferred to the next wave,
+    #   not lost (`win & fits`, ops/swap.py and ops/swapgen.py).  Measured:
+    #   the busiest wave of a cell applies 1,386 swaps on 32,592 tets
+    #   against 16,296 free rows, the fem rounds add 1.3 % and regrow on
+    #   overflow (driver._finish_run; tests/test_merged_tail.py).
+    n_p, n_t = len(vert_k), len(tet)
+    m = make_mesh(vert_k, tet, vref=vref_k, tref=tref,
+                  capP=max(64, (3 * n_p) // 2), capT=max(64, (3 * n_t) // 2))
     vtag_full = np.zeros(m.capP, np.uint32)
     vtag_full[: len(vtag2)] = vtag2
     ftag_full = np.zeros((m.capT, 4), np.uint32)

@@ -857,7 +857,7 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     with otrace.span("grp merge") as sp, host_staging():
         merged, met_m, part_m = merge_shards(stacked_h, met_h,
                                              return_part=True)
-        sp.set(ne=len(part_m))
+        sp.set(ne=len(part_m), capT=merged.capT, capP=merged.capP)
     return merged, met_m, part_m
 
 

@@ -7,9 +7,12 @@ and in the grouped path the merged polish / repair / fem tail) run on
 the host.  The mesh is grouped BECAUSE programs of its width are too
 big, for the device and for its compiler alike: on a v5e host one
 grouped pass spent 709 s compiling such programs against seconds on
-XLA:CPU (PERF.md, PR 26), and their run time is small next to the
-cycle blocks the chip is there for.  ``chip_smoke.py`` reports the
-host and device shares of a run.
+XLA:CPU (PERF.md, PR 26).  They are placed there for their COMPILE
+time; their run time is not small: on a v5e host the merged tail was
+47 % of an iso job and 32 % of an aniso one, with the chip idle all
+through it (ledger, PR 28), and it costs what the merged mesh's
+capacity is, not what its content is (PERF.md, PR 29).
+``chip_smoke.py`` reports the host and device shares of a run.
 """
 from __future__ import annotations
 

@@ -589,6 +589,21 @@ def test_polish_waves_count_what_they_applied(grouped_job):
     assert last["collapse"] + last["swap"] == 0 or len(waves) == 8
 
 
+def test_tail_rows_are_counted_once_a_job(grouped_job):
+    """``tail.rows_live`` / ``tail.rows_cap``: the mesh the merged tail
+    is about to run on, which is the last merge's, at the capacity
+    ``merge_shards`` chose for it (1.5x its content)."""
+    for which in ("cold", "warm"):
+        job = grouped_job[which]
+        recs, _ = _tree(job["records"])
+        merges = [r for r in recs if r["name"] == "grp merge"]
+        assert len(merges) == 2
+        assert all(r["ne"] <= r["capT"] <= (3 * r["ne"]) // 2 + 64
+                   and r["capP"] > 0 for r in merges)
+        assert job["counters"]["tail.rows_live"] == merges[-1]["ne"]
+        assert job["counters"]["tail.rows_cap"] == merges[-1]["capT"]
+
+
 @pytest.mark.parametrize("parent", ["run", "adaptation",
                                     "bad-element polish"])
 def test_children_cover_their_parent(grouped_job, parent):
