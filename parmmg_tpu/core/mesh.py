@@ -30,7 +30,7 @@ from .constants import IDIR, IARE, MG_BDY, MG_CRN, MG_REQ
 
 
 @partial(jax.tree_util.register_dataclass,
-         data_fields=["vert", "vref", "vtag", "vmask",
+         data_fields=["vert", "vref", "vtag", "vmask", "vnrm",
                       "tet", "tref", "tmask", "adja",
                       "ftag", "fref", "etag",
                       "npoin", "nelem"],
@@ -47,6 +47,9 @@ class Mesh:
     vref: jax.Array   # [capP]    int32 reference
     vtag: jax.Array   # [capP]    uint32 MG_* tag bits
     vmask: jax.Array  # [capP]    bool validity
+    vnrm: jax.Array   # [capP, 3] carried unit surface normal (Mmg xPoint n1)
+    #                   of a frozen seam vertex, whose fan this mesh holds
+    #                   only in part; zero = derive it from the fan
     # -- tetrahedra ---------------------------------------------------------
     tet: jax.Array    # [capT, 4] int32 vertex ids
     tref: jax.Array   # [capT]    int32 reference (sub-domain id)
@@ -126,6 +129,7 @@ def make_mesh(vert: np.ndarray, tet: np.ndarray,
         vref=jnp.asarray(pad(vref, capP)),
         vtag=jnp.zeros(capP, jnp.uint32),
         vmask=jnp.asarray(vmask),
+        vnrm=jnp.zeros((capP, 3), dtype),
         tet=jnp.asarray(pad(tet, capT)),
         tref=jnp.asarray(pad(tref, capT)),
         tmask=jnp.asarray(tmask),
@@ -213,6 +217,7 @@ def compact(mesh: Mesh) -> Mesh:
         vref=jnp.asarray(np.asarray(mesh.vref)[vperm]),
         vtag=jnp.asarray(np.asarray(mesh.vtag)[vperm]),
         vmask=jnp.asarray(vmask[vperm]),
+        vnrm=jnp.asarray(np.asarray(mesh.vnrm)[vperm]),
         tet=jnp.asarray(tet.astype(np.int32)),
         tref=jnp.asarray(np.asarray(mesh.tref)[tperm]),
         tmask=jnp.asarray(tmask[tperm]),
@@ -241,6 +246,7 @@ def with_capacity(mesh: Mesh, capP: int, capT: int) -> Mesh:
     return Mesh(
         vert=grow(mesh.vert, capP), vref=grow(mesh.vref, capP),
         vtag=grow(mesh.vtag, capP), vmask=grow(mesh.vmask, capP, False),
+        vnrm=grow(mesh.vnrm, capP),
         tet=grow(mesh.tet, capT), tref=grow(mesh.tref, capT),
         tmask=grow(mesh.tmask, capT, False), adja=grow(mesh.adja, capT, -1),
         ftag=grow(mesh.ftag, capT), fref=grow(mesh.fref, capT),

@@ -37,8 +37,21 @@ def _auto_hmin_hmax(vert: np.ndarray, info) -> tuple[float, float]:
     return hmin, hmax
 
 
-def build_metric(mesh: Mesh, met, info):
-    """Metric synthesis path: -hsiz / -optim / user metric / default."""
+def surface_census(mesh: Mesh) -> dict:
+    """What the analysis found of the surface, as counts for its span:
+    boundary faces and ridge edges of the analysed mesh (host arrays)."""
+    tm = np.asarray(mesh.tmask)
+    bdy = (np.asarray(mesh.ftag)[tm] & C.MG_BDY) != 0
+    geo = (np.asarray(mesh.etag)[tm] & C.MG_GEO) != 0
+    ridge = np.sort(np.asarray(mesh.tet)[tm][:, C.IARE][geo], axis=1)
+    return {"bdy_faces": int(bdy.sum()),
+            "ridges": len(np.unique(ridge, axis=0))}
+
+
+def build_metric(mesh: Mesh, met, info, census: dict | None = None):
+    """Metric synthesis path: -hsiz / -optim / user metric / default.
+    ``census``, if given, receives ``bound_verts``: how many vertices'
+    sizes the hausd bound lowered."""
     import jax.numpy as jnp
 
     vert = np.asarray(mesh.vert)[np.asarray(mesh.vmask)]
@@ -55,7 +68,12 @@ def build_metric(mesh: Mesh, met, info):
     # curvature estimate blows up at corners
     if info.hausd > 0 and info.angle_detection:
         from .ops.metric import hausd_metric_bound
-        met = hausd_metric_bound(mesh, met, info.hausd, hmin)
+        bounded = hausd_metric_bound(mesh, met, info.hausd, hmin)
+        if census is not None and bounded.ndim == 1:
+            census["bound_verts"] = int(np.sum(
+                (np.asarray(bounded) < np.asarray(met))
+                & np.asarray(mesh.vmask)))
+        met = bounded
     # local bounds BEFORE gradation (Mmg defsiz-then-gradsiz order) so the
     # size jump at a ref-patch boundary is smoothed by -hgrad; re-applied
     # after, since gradation only propagates smaller sizes and may pull a
@@ -189,8 +207,12 @@ def _merged_polish(mesh, met, info, hausd, stats, tim):
                     do_collapse=not info.noinsert,
                     do_swap=not info.noswap,
                     do_smooth=not info.nomove, hausd=hausd, budget=budget)
-                ncol, nswap, nmoved = np.asarray(counts)[:3].tolist()
-                sp.set(collapse=ncol, swap=nswap, moved=nmoved)
+                ncol, nswap, nmoved, _, nhveto, nbmoved = \
+                    np.asarray(counts).tolist()
+                # a polish wave splits nothing: bsplit is 0 by what it is
+                sp.set(collapse=ncol, swap=nswap, moved=nmoved,
+                       bsplit=0, hveto=nhveto, bmoved=nbmoved)
+            stats.add_surface(hveto=nhveto, bmoved=nbmoved)
             stats.ncollapse += ncol
             stats.nswap += nswap
             stats.nmoved += nmoved
@@ -206,12 +228,14 @@ def _run_phases(pm) -> tuple[Mesh, object, AdaptStats]:
     """The phases of one run, under the ``run`` span."""
     from .utils.timers import Timers
     from .obs import trace as otrace
+    from .obs.metrics import REGISTRY
     from .resilience.recover import RetryBudgetExhausted, ladder_step
     info = pm.info
     tim = Timers()
     from .utils.placement import host_staging, to_device
-    with tim("analysis"), host_staging():
+    with tim("analysis") as sp, host_staging():
         mesh, met = pm._build_core_mesh()
+        sp.set(**surface_census(mesh))
     if info.nosurf:
         # -nosurf: no surface modification — freeze every boundary entity
         # with MG_REQ (exactly how the reference freezes parallel faces,
@@ -226,8 +250,13 @@ def _run_phases(pm) -> tuple[Mesh, object, AdaptStats]:
             ftag=jnp.where(bdy_f, mesh.ftag | C.MG_REQ, mesh.ftag),
             etag=jnp.where(bdy_e, mesh.etag | C.MG_REQ, mesh.etag),
             vtag=jnp.where(bdy_v, mesh.vtag | C.MG_REQ, mesh.vtag))
-    with tim("metric"), host_staging():
-        met = build_metric(mesh, met, info)
+    with tim("metric") as sp, host_staging():
+        # vertices whose size the hausd bound lowered: the curvature's
+        # share of the size map (0 on a flat boundary)
+        census = {"bound_verts": 0}
+        met = build_metric(mesh, met, info, census)
+        sp.set(**census)
+        REGISTRY.counter("surf.bound_verts").inc(census["bound_verts"])
 
     # background snapshot for field interpolation (PMMG_create_oldGrp
     # analogue, grpsplit_pmmg.c:207).  Deep copy: adapt_cycle donates its
@@ -458,9 +487,12 @@ def _finish_run(pm, mesh, met, stats, info, tim, bg_mesh, bg_fields,
             for w in range(8):
                 with otrace.span("fem round", wave=w) as sp:
                     mesh, met, fc = fem_pass(mesh, met)
-                    nf, ovf = (int(v) for v in np.asarray(fc))
-                    sp.set(split=nf, overflow=ovf)
+                    nf, ovf, nbs = (int(v) for v in np.asarray(fc))
+                    # a fem round collapses and moves nothing
+                    sp.set(split=nf, overflow=ovf, bsplit=nbs, hveto=0,
+                           bmoved=0)
                 stats.nsplit += nf
+                stats.add_surface(bsplit=nbs)
                 if ovf:
                     mesh, met = grow_mesh_met(mesh, met, 2 * mesh.capP,
                                               2 * mesh.capT)

@@ -249,6 +249,12 @@ def publish_stats(stats, registry: MetricsRegistry | None = None) -> None:
                     ("sched.groups_skipped", stats.groups_skipped)):
         if v:
             reg.counter(name, tenant=t).inc(v)
+    # a job's surface counts exist from its first publication on, a zero
+    # among them too: "the hausd test refused nothing" is a reading
+    for name, v in (("surf.bsplit", stats.nbsplit),
+                    ("surf.hveto", stats.nhveto),
+                    ("surf.bmoved", stats.nbmoved)):
+        reg.counter(name, tenant=t).inc(v)
     reg.gauge("adapt.status", tenant=t).set(float(stats.status))
     for k, v in stats.sched_extra.items():
         # already-tenant-namespaced keys (an aggregate's absorbed

@@ -271,6 +271,7 @@ def auto_cycle(mesh: Mesh, met, pending, okflag, wave, A: int,
             sub0, met, wave, do_swap=do_swap, do_smooth=do_smooth,
             do_insert=do_insert, final_rebuild=False, hausd=hausd,
             budget_div=narrow_budget_div, vact=d2, submesh=True)
+        counts = counts[:8]     # the auto row has columns of its own after these
         # the sub's allocated rows land in full-mesh FREE rows via the
         # back pool; a live sub row whose back target is the capT
         # sentinel means the pool ran out and the writeback would
@@ -315,6 +316,7 @@ def auto_cycle(mesh: Mesh, met, pending, okflag, wave, A: int,
             mesh, met, wave, do_swap=do_swap, do_smooth=do_smooth,
             do_insert=do_insert, final_rebuild=False, hausd=hausd,
             budget_div=budget_div, wwin=wmask)
+        counts = counts[:8]
         dn = dirty_from_diff(mesh, mesh2)
         # a full cycle (re)seeds the worklist when (a) capacity did not
         # overflow (the host regrows and restarts the worklist anyway)

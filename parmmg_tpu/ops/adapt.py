@@ -34,6 +34,13 @@ from .swap import swap_edges_wave, swap23_wave
 from .smooth import smooth_wave
 
 
+# columns of a cycle's counts row that say what the surface machinery
+# did (adapt_cycle_impl), and the column the incremental topology engine
+# appends after them
+SURF_COLS = {"bsplit": 8, "hveto": 9, "bmoved": 10}
+DIRTY_COL = 11
+
+
 @dataclass
 class AdaptStats:
     nsplit: int = 0
@@ -42,6 +49,12 @@ class AdaptStats:
     nmoved: int = 0
     cycles: int = 0
     regrows: int = 0
+    # what the surface machinery did (``SURF_COLS``; published as the
+    # ``surf.*`` counters): splits of boundary edges, collapse
+    # candidates the hausd test refused, surface vertices smoothing moved
+    nbsplit: int = 0
+    nhveto: int = 0
+    nbmoved: int = 0
     # PMMG_SUCCESS unless the run degraded (failed_handling contract:
     # PMMG_LOWFAILURE = something failed but a conforming mesh is saved)
     status: int = 0
@@ -74,6 +87,7 @@ class AdaptStats:
         self.nmoved += other.nmoved
         self.cycles += other.cycles
         self.regrows += other.regrows
+        self.add_surface(other.nbsplit, other.nhveto, other.nbmoved)
         self.status = max(self.status, other.status)
         self.group_dispatches += other.group_dispatches
         self.group_dispatches_saved += other.group_dispatches_saved
@@ -87,6 +101,11 @@ class AdaptStats:
             else:
                 self.sched_extra[kk] = self.sched_extra.get(kk, 0.0) + v
         return self
+
+    def add_surface(self, bsplit=0, hveto=0, bmoved=0) -> None:
+        self.nbsplit += bsplit
+        self.nhveto += hveto
+        self.nbmoved += bmoved
 
     def publish(self, registry=None) -> None:
         """Publish the counters into the obs metrics registry
@@ -135,7 +154,10 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
 
     Returns (mesh, met, counts) with ``counts`` = int32
     [nsplit, ncollapse, nswap, nmoved, overflow, live_tets, deferred,
-    narrow_abort] stacked in ONE device array: the host reads all
+    narrow_abort, bsplit, hveto, bmoved] stacked in ONE device array
+    (``SURF_COLS``: of the splits those of boundary edges, the collapse
+    candidates the hausd test refused, of the moves those of surface
+    vertices — what the surface machinery did): the host reads all
     per-cycle counters with a single transfer (each separate scalar pull
     costs a full round trip on a remote-device transport, and an *eager*
     count op on the host would fight the donated input buffers).
@@ -183,10 +205,10 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     adjacency through the band-merge path (bit-identical to the legacy
     rebuilds — off position, overflow and cold state all take the exact
     full sort), marks the tets each wave touched (unconditionally, so
-    both knob arms report identical counts), the counts row widens to 9
-    (``counts[8]`` = dirty tets at cycle start), and the return becomes
+    both knob arms report identical counts), the counts row gains a
+    column (``counts[DIRTY_COL]`` = dirty tets at cycle start), and the return becomes
     a 4-tuple ``(mesh, met, counts, topo)``.  ``topo=None`` is the
-    untouched legacy path (8-wide counts, 3-tuple).
+    untouched legacy path (no dirty column, 3-tuple).
     """
     from .adjacency import boundary_edge_tags
     if topo is not None:
@@ -209,13 +231,13 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
 
         def _skip(ops):
             m, k, tp = ops
-            nc = 8 if tp is None else 9
+            nc = DIRTY_COL if tp is None else DIRTY_COL + 1
             counts = jnp.zeros(nc, jnp.int32).at[5].set(
                 jnp.sum(m.tmask, dtype=jnp.int32))
             if tp is not None:
                 # an idle slot's retained tables stay valid; report its
                 # pending dirty count for the occupancy trajectory
-                counts = counts.at[8].set(
+                counts = counts.at[DIRTY_COL].set(
                     jnp.sum(tp.edirty, dtype=jnp.int32))
             return m, k, counts, tp
         m, k, counts, tp = jax.lax.cond(active, _run, _skip,
@@ -246,28 +268,35 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
         # ridge tangents once per cycle too (same sharing rationale;
         # collapse only consults non-stale candidates, whose tangent
         # fields are identical pre/post split)
-        vtan0 = None
+        # the vertex normals likewise: a collapse candidate has no
+        # endpoint in a tet the split touched, so its endpoints' fans,
+        # and the sums over them, are the pre-split mesh's
+        vtan0 = vn0 = None
         if hausd is not None:
-            from .analysis import ridge_vertex_tangents
+            from .analysis import boundary_vertex_normals, \
+                ridge_vertex_tangents
             vtan0 = ridge_vertex_tangents(mesh, et=et0)
+            vn0 = boundary_vertex_normals(mesh)
         # wide convergence-verification cycles (and the drivers' polish
         # cycles, via ``prescreen=False``) disable the approximate
         # nomination prescreen so shells it over-vetoed get one exact
         # re-evaluation before convergence is accepted (split.py)
         res = split_wave(mesh, met, hausd=hausd, budget_div=budget_div,
-                         et=et0, lens=lens0, vtan=vtan0, vact=vact,
+                         et=et0, lens=lens0, vtan=vtan0, vn=vn0,
+                         vact=vact,
                          prescreen=False if wide else prescreen)
         if topo is not None:
             topo = mark_dirty(topo, mesh.tet, mesh.tmask, res.mesh)
         mesh, met = res.mesh, res.met
         nsplit, overflow = res.nsplit, res.overflow
+        nbsplit = res.nbdy
         defer = defer | res.deferred
 
         col = collapse_wave(mesh, met, hausd=hausd,
                             budget_div=budget_div,
                             et=et0, lens=lens0,
                             stale_tets=res.modified, vtan=vtan0,
-                            vact=vact, wwin=wwin)
+                            vn=vn0, vact=vact, wwin=wwin)
         if topo is not None:
             # boundary_edge_tags below touches only tags, which the
             # retained sorts never carry — marking against col.mesh is
@@ -283,11 +312,11 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
         # the propagation pass costs a [12*capT]-index scatter
         mesh = jax.lax.cond(col.surface_changed, boundary_edge_tags,
                             lambda m: m, col.mesh)
-        ncol = col.ncollapse
+        ncol, nhveto = col.ncollapse, col.nhveto
     else:
         # -noinsert: no point insertion or deletion (Mmg contract)
-        nsplit = jnp.zeros((), jnp.int32)
-        ncol = jnp.zeros((), jnp.int32)
+        nsplit = nbsplit = jnp.zeros((), jnp.int32)
+        ncol = nhveto = jnp.zeros((), jnp.int32)
         overflow = jnp.zeros((), bool)
 
     nswap = jnp.zeros((), jnp.int32)
@@ -336,7 +365,7 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
             do_swap, _swap, lambda ops: ops + (nswap, defer_sw),
             (mesh, topo))
 
-    nmoved = jnp.zeros((), jnp.int32)
+    nmoved = jnp.zeros((2,), jnp.int32)      # [all, of them surface]
     if do_smooth:
         # in windowed mode (wwin, the ops/active.py rotation) smoothing
         # restricts to the window; in narrow mode vact (the worklist
@@ -344,12 +373,12 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
         sv = vact if vact is not None else wwin
 
         def _smooth(m):
-            nm = jnp.zeros((), jnp.int32)
+            nm = jnp.zeros((2,), jnp.int32)
             for w in range(smooth_waves):
                 sm = smooth_wave(m, met, wave=wave * smooth_waves + w,
-                                 vact=sv)
+                                 vact=sv, hausd=hausd)
                 m = sm.mesh
-                nm = nm + sm.nmoved
+                nm = nm + jnp.stack([sm.nmoved, sm.nbdy])
             return m, nm
 
         if smooth_idle is not None and sv is None:
@@ -358,7 +387,7 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
             # candidate set between cycles, so sv disables the gate
             skip = smooth_idle & ((nsplit + ncol + nswap) == 0)
             mesh, nmoved = jax.lax.cond(
-                skip, lambda m: (m, jnp.zeros((), jnp.int32)),
+                skip, lambda m: (m, jnp.zeros((2,), jnp.int32)),
                 _smooth, mesh)
         else:
             mesh, nmoved = _smooth(mesh)
@@ -370,14 +399,15 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
         else:
             mesh = build_adjacency(mesh, set_bdy_tags=not submesh)
 
-    row = [nsplit, ncol, nswap, nmoved,
+    row = [nsplit, ncol, nswap, nmoved[0],
            overflow.astype(jnp.int32),
            jnp.sum(mesh.tmask, dtype=jnp.int32),
            defer.astype(jnp.int32) + 2 * defer_sw.astype(jnp.int32),
-           jnp.zeros((), jnp.int32)]
+           jnp.zeros((), jnp.int32),
+           nbsplit, nhveto, nmoved[1]]
     if topo is None:
         return mesh, met, jnp.stack(row)
-    # counts[8]: dirty tets pending at cycle START — the dirty-band
+    # counts[DIRTY_COL]: dirty tets pending at cycle START — the dirty-band
     # occupancy trajectory the grouped drivers surface in sched_extra
     return mesh, met, jnp.stack(row + [nd0]), topo
 
@@ -399,13 +429,15 @@ def fem_pass_impl(mesh: Mesh, met: jax.Array):
     (API_functions_pmmg.c:652-658, default ``info.fem`` ON :413); run
     after the sizing/polish loop until no candidate remains.
 
-    Returns (mesh, met, counts[2] = [nsplit, overflow])."""
+    Returns (mesh, met, counts[3] = [nsplit, overflow, bsplit]); the
+    candidates are interior edges, so ``bsplit`` (splits of boundary
+    edges) reads 0 while that holds."""
     from .adjacency import boundary_edge_tags
     res = split_wave(mesh, met, fem_only=True, budget_div=2)
     mesh = boundary_edge_tags(res.mesh)
     mesh = build_adjacency(mesh)
     return mesh, res.met, jnp.stack(
-        [res.nsplit, res.overflow.astype(jnp.int32)])
+        [res.nsplit, res.overflow.astype(jnp.int32), res.nbdy])
 
 
 fem_pass = partial(jax.jit, donate_argnums=(0, 1))(fem_pass_impl)
@@ -544,7 +576,8 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     whose capacity is no measure of its content says what it wants in
     rows (driver.polish_budget for a merged mesh).
 
-    Returns (mesh, counts[4] = [ncollapse, nswap, nmoved, live_tets]).
+    Returns (mesh, counts[6] = [ncollapse, nswap, nmoved, live_tets,
+    hveto, bmoved]): the last two as in a cycle's ``SURF_COLS``.
     """
     from .adjacency import boundary_edge_tags
     if active is not None:
@@ -555,13 +588,13 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
                 do_smooth=do_smooth, hausd=hausd, budget=budget)
 
         def _skip(m):
-            counts = jnp.zeros(4, jnp.int32).at[3].set(
+            counts = jnp.zeros(6, jnp.int32).at[3].set(
                 jnp.sum(m.tmask, dtype=jnp.int32))
             return m, counts
         return jax.lax.cond(active, _run, _skip, mesh)
-    ncol = jnp.zeros((), jnp.int32)
+    ncol = nhveto = jnp.zeros((), jnp.int32)
     nswap = jnp.zeros((), jnp.int32)
-    nmoved = jnp.zeros((), jnp.int32)
+    nmoved = nbmoved = jnp.zeros((), jnp.int32)
     if do_collapse:
         # the polish widens the compaction budget (budget_div=2, or the
         # caller's ``budget`` in rows) so the quality pass covers the
@@ -572,7 +605,7 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
                             budget_div=2, budget=budget)
         mesh = jax.lax.cond(col.surface_changed, boundary_edge_tags,
                             lambda m: m, col.mesh)
-        ncol = col.ncollapse
+        ncol, nhveto = col.ncollapse, col.nhveto
     if do_swap:
         from .swapgen import swapgen_wave
         from .swap import swap_facesort_enabled
@@ -593,12 +626,14 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     if do_smooth:
         # optimal-position mode: sliver-ball vertices ascend the height
         # of their worst incident tet instead of chasing the centroid
-        sm = smooth_wave(mesh, met, wave=wave, opt_q=sliver_q)
+        sm = smooth_wave(mesh, met, wave=wave, opt_q=sliver_q,
+                         hausd=hausd)
         mesh = sm.mesh
-        nmoved = sm.nmoved
+        nmoved, nbmoved = sm.nmoved, sm.nbdy
     mesh = build_adjacency(mesh)                # exit contract
     counts = jnp.stack([ncol, nswap, nmoved,
-                        jnp.sum(mesh.tmask, dtype=jnp.int32)])
+                        jnp.sum(mesh.tmask, dtype=jnp.int32),
+                        nhveto, nbmoved])
     return mesh, counts
 
 
@@ -679,6 +714,7 @@ def adapt_mesh(mesh: Mesh, met: jax.Array, max_cycles: int = 50,
                 do_smooth=not nomove, do_insert=not noinsert, hausd=hausd,
                 budget_div=2 if wide_check else 8, wide=wide_check)
             rows = [(do_swap, np.asarray(counts))]
+            surf_rows = True
             dirty = None        # full wide pass: worklist invalid
             okflag = False
         else:
@@ -700,10 +736,14 @@ def adapt_mesh(mesh: Mesh, met: jax.Array, max_cycles: int = 50,
                 do_smooth=not nomove, do_insert=not noinsert)
             ca = np.asarray(counts_all)
             rows = [(flags[c], ca[c]) for c in range(nblk)]
+            surf_rows = False   # the auto row has columns of its own
 
         ovf_any = False
         for do_swap, cnt in rows:
             ns, nc, nw, nm, ovf = (int(v) for v in cnt[:5])
+            if surf_rows:
+                stats.add_surface(**{k: int(cnt[col])
+                                     for k, col in SURF_COLS.items()})
             stats.nsplit += ns
             stats.ncollapse += nc
             stats.nswap += nw
@@ -764,7 +804,8 @@ def adapt_mesh(mesh: Mesh, met: jax.Array, max_cycles: int = 50,
                                      do_collapse=not noinsert,
                                      do_swap=not noswap,
                                      do_smooth=not nomove, hausd=hausd)
-        nc, nw, nm, _ = (int(v) for v in np.asarray(counts))
+        nc, nw, nm, _, nhv, nbm = (int(v) for v in np.asarray(counts))
+        stats.add_surface(hveto=nhv, bmoved=nbm)
         stats.ncollapse += nc
         stats.nswap += nw
         stats.nmoved += nm

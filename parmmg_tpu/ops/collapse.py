@@ -46,6 +46,9 @@ class CollapseResult(NamedTuple):
     surface_changed: jax.Array = None
     deferred: jax.Array = None  # scalar bool: candidates exceeded the
     #                 top-K budget (see ops/active.py worklist invariant)
+    nhveto: jax.Array = None  # scalar int32: candidates the hausd test
+    #                 refused (boundary edges whose surface would move by
+    #                 more than hausd); 0 without hausd
 
 
 def _removable(vtag, other_vtag, edge_tag):
@@ -74,6 +77,7 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
                   et=None, lens=None,
                   stale_tets: jax.Array | None = None,
                   vtan: jax.Array | None = None,
+                  vn: jax.Array | None = None,
                   vact: jax.Array | None = None,
                   wwin: jax.Array | None = None) -> CollapseResult:
     """One independent-set collapse wave.
@@ -151,7 +155,8 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
         # candidates ranked past K
         from .analysis import boundary_vertex_normals, \
             ridge_vertex_tangents
-        vn = boundary_vertex_normals(mesh)
+        if vn is None:
+            vn = boundary_vertex_normals(mesh)
         on_bdy_f = (et.etag & MG_BDY) != 0
         d_f = mesh.vert[vb_f] - mesh.vert[va_f]
         na_f, nb_f = vn[va_f], vn[vb_f]
@@ -168,7 +173,11 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
         tb_l = tanv[vb_f] * jnp.sum(tanv[vb_f] * d_f, -1, keepdims=True)
         dev_l = jnp.linalg.norm(0.125 * (ta_l - tb_l), axis=-1)
         dev = jnp.where(on_line_f, dev_l, dev)
-        pre = pre & ~(on_bdy_f & (dev > hausd))
+        hveto = pre & on_bdy_f & (dev > hausd)
+        nhveto = jnp.sum(hveto, dtype=jnp.int32)
+        pre = pre & ~hveto
+    else:
+        nhveto = jnp.zeros((), jnp.int32)
 
     # Everything below (top-K sort, role derivation, tet-centric
     # validity, claims, apply) is lax.cond-skipped when NO candidate
@@ -176,7 +185,8 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
     # candidacy masks.
     def _idle(_):
         return CollapseResult(mesh, jnp.zeros((), jnp.int32),
-                              jnp.zeros((), bool), jnp.zeros((), bool))
+                              jnp.zeros((), bool), jnp.zeros((), bool),
+                              nhveto)
 
     def _act(_):
         # top-K compaction (scripts/wave_time.py cost lever): the K highest-
@@ -358,7 +368,7 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
         out = dataclasses.replace(
             mesh, tet=new_tet, tmask=tmask, vmask=vmask, ftag=ftag,
             fref=fref, etag=etag)
-        return CollapseResult(out, ncol, schg, defer)
+        return CollapseResult(out, ncol, schg, defer, nhveto)
 
     return jax.lax.cond(jnp.any(pre), _act, _idle, None)
 

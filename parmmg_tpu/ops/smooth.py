@@ -9,7 +9,8 @@ tag_pmmg.c:39-124).
 
 Wave scheme: every movable vertex proposes a new position (ball-centroid
 for interior points; tangent-plane-projected surface-centroid for regular
-boundary points on locally-flat patches); validity (ball min-quality must
+boundary points, on locally-flat patches as it is and on curved ones,
+given ``hausd``, put back onto the surface the fan describes); validity (ball min-quality must
 not decrease) is checked tet-centrically; a hash-rotated independent set
 (vertex claims all its ball tets) moves per wave so the precheck remains
 exact under simultaneous moves.
@@ -29,19 +30,29 @@ from ..core.constants import (
 from .quality import quality_from_points
 from .edges import PRI_MIN
 
-# a regular surface point only slides in its tangent plane when its
-# incident boundary faces are mutually near-parallel — the move is then
-# surface-exact; curved patches wait for hausd-driven reprojection (Mmg
-# reprojects onto the surface ball instead).  Gate: |sum of unit
-# normals| / count >= FLAT_RATIO, i.e. a single outlier face in a
-# 12-face ball may tilt ~4 deg (the old per-face min-dot gate allowed
-# 2.6 deg but cost a second full-width gather+scatter pass per wave)
+# a regular surface point slides in its tangent plane when its incident
+# boundary faces are mutually near-parallel — the move is then
+# surface-exact.  Gate: |sum of unit normals| / count >= FLAT_RATIO,
+# i.e. a single outlier face in a 12-face ball may tilt ~4 deg (the old
+# per-face min-dot gate allowed 2.6 deg but cost a second full-width
+# gather+scatter pass per wave)
 FLAT_RATIO = 0.9998
+# on a curved patch, with a surface tolerance ``hausd`` given, it slides
+# too and is then put back ON the surface (Mmg's movbdyregpt reprojects
+# onto the Bezier patch): the fan's own vertices give the surface's
+# normal curvature kappa at the point (second fundamental form, fitted
+# over the spokes), and a tangential step s costs kappa s^2 / 2 along
+# the normal — exact to O(s^4) on a sphere.  Gate: the fan's faces lie
+# in a cone round the vertex normal (ratio >= SMOOTH_RATIO, 18 deg: a
+# crease the analysis left untagged does not slide) and the step leaves
+# the old surface by no more than hausd
+SMOOTH_RATIO = 0.95
 
 
 class SmoothResult(NamedTuple):
     mesh: Mesh
     nmoved: jax.Array
+    nbdy: jax.Array = None   # of ``nmoved``, the surface vertices
 
 
 def morton_window_mask(vert: jax.Array, vmask: jax.Array, wave,
@@ -81,8 +92,14 @@ def morton_window_mask(vert: jax.Array, vmask: jax.Array, wave,
 def smooth_wave(mesh: Mesh, met: jax.Array, wave: int = 0,
                 relax: float = 1.0,
                 opt_q: float | None = None,
-                vact: jax.Array | None = None) -> SmoothResult:
+                vact: jax.Array | None = None,
+                hausd: float | None = None) -> SmoothResult:
     """One smoothing wave; see module docstring.
+
+    ``hausd``: the surface tolerance (Mmg -hausd).  With it a regular
+    surface vertex on a CURVED patch slides too and is reprojected onto
+    the surface its fan describes (``SMOOTH_RATIO`` above); without it
+    only flat patches slide, where no reprojection is needed.
 
     ``opt_q``: optimal-position mode for sliver balls — interior
     vertices whose ball min quality is below ``opt_q`` propose a move
@@ -140,39 +157,57 @@ def smooth_wave(mesh: Mesh, met: jax.Array, wave: int = 0,
     isb = ((mesh.ftag & MG_BDY) != 0) & mesh.tmask[:, None]   # [T,4]
     fv = tv[:, idir]                                       # [T,4,3] vids
     fp = mesh.vert[fv]                                     # [T,4,3,3]
-    fn = jnp.cross(fp[:, :, 1] - fp[:, :, 0],
-                   fp[:, :, 2] - fp[:, :, 0])              # [T,4,3] outward
+    ea, eb = fp[:, :, 1] - fp[:, :, 0], fp[:, :, 2] - fp[:, :, 0]
+    fn = jnp.cross(ea, eb)                                 # [T,4,3] outward
     fc = jnp.mean(fp, axis=2)                              # [T,4,3]
     farea = 0.5 * jnp.sqrt(jnp.sum(fn * fn, -1))           # [T,4]
     # all 12 (face, corner) contributions in ONE wide scatter:
-    # payload = (area-weighted normal[3], area*centroid[3], area[1],
-    #            unit normal[3], count[1]) — the unit-normal sum feeds
-    # the locally-flat gate below with no second full-width pass
+    # payload = (corner-weighted normal[3], area*centroid[3], area[1],
+    #            unit normal[3], count[1], area*(the corner's two
+    #            spokes' squared lengths)[1]) — the unit-normal sum
+    # feeds the gates below with no second full-width pass.  The corner
+    # weights are those of analysis.boundary_vertex_normals
     idx12 = jnp.concatenate(
         [jnp.where(isb[:, f], fv[:, f, k], capP)
          for f in range(4) for k in range(3)])
     w4 = jnp.where(isb, farea, 0.0)                        # [T,4]
     fn_unit = fn / (jnp.linalg.norm(fn, axis=-1, keepdims=True) + EPSD)
     pay_f = jnp.concatenate(
-        [fn, w4[..., None] * fc, w4[..., None], fn_unit,
-         jnp.ones_like(w4)[..., None]], axis=-1)           # [T,4,11]
+        [w4[..., None] * fc, w4[..., None], fn_unit,
+         jnp.ones_like(w4)[..., None]], axis=-1)           # [T,4,8]
+    from .analysis import corner_weights
+    l2, wgt = corner_weights(ea, eb)                       # [T,4,3]
+    sp2 = w4[..., None] * (l2 + jnp.roll(l2, 1, axis=-1))  # [T,4,3]
     pay12 = jnp.concatenate(
-        [pay_f[:, f] for f in range(4) for _ in range(3)])
-    sacc = jnp.zeros((capP + 1, 11), mesh.vert.dtype).at[idx12].add(
-        pay12, mode="drop")
+        [jnp.concatenate(
+            [fn[:, f] * wgt[:, f, k, None], pay_f[:, f],
+             sp2[:, f, k, None]], axis=-1)
+         for f in range(4) for k in range(3)])             # [12T,12]
+    sacc = jnp.zeros((capP + 1, 12), mesh.vert.dtype).at[idx12].add(
+        pay12, mode="drop")[:capP]
     nacc, cacc, aacc = sacc[:, :3], sacc[:, 3:6], sacc[:, 6]
-    uacc, ucnt = sacc[:, 7:10], sacc[:, 10]
-    navg = nacc[:capP] / (jnp.linalg.norm(nacc[:capP], axis=-1,
-                                          keepdims=True) + EPSD)
+    uacc, ucnt, s2acc = sacc[:, 7:10], sacc[:, 10], sacc[:, 11]
+    navg = nacc / (jnp.linalg.norm(nacc, axis=-1, keepdims=True) + EPSD)
     # locally-flat gate: |sum of unit normals| close to the face count
     # means every incident boundary face is near the common plane
-    ratio = jnp.linalg.norm(uacc[:capP], axis=-1) / \
-        jnp.maximum(ucnt[:capP], 1.0)
-    flat = (ratio >= FLAT_RATIO) & (aacc[:capP] > 0)
-    bdy_ok = reg_bdy & flat
-    cbar = cacc[:capP] / jnp.maximum(aacc[:capP, None], EPSD)
+    ratio = jnp.linalg.norm(uacc, axis=-1) / jnp.maximum(ucnt, 1.0)
+    flat = (ratio >= FLAT_RATIO) & (aacc > 0)
+    cbar = cacc / jnp.maximum(aacc[:, None], EPSD)
     dvec = cbar - mesh.vert
     dvec = dvec - jnp.sum(dvec * navg, -1, keepdims=True) * navg
+    # the surface under the tangent plane: a fan vertex at distance l
+    # stands kappa l^2 / 2 under it, so a face's centroid (l_a^2 +
+    # l_b^2) kappa / 6, and the area-weighted centroid ``cbar`` the
+    # area-weighted mean of that; 0 on a plane
+    kappa = -6.0 * jnp.sum((cacc - aacc[:, None] * mesh.vert) * navg, -1) \
+        / jnp.maximum(s2acc, EPSD)
+    drop = 0.5 * kappa * jnp.sum(dvec * dvec, -1)          # at step 1
+    if hausd is None:
+        bdy_ok = reg_bdy & flat
+    else:
+        bdy_ok = reg_bdy & (flat | ((ratio >= SMOOTH_RATIO) & (aacc > 0)
+                                    & (jnp.abs(drop) <= hausd)))
+    drop = jnp.where(bdy_ok & ~flat, drop, 0.0)[:, None] * navg
     prop = jnp.where(bdy_ok[:, None], mesh.vert + dvec, prop)
     movable = movable_int | bdy_ok
 
@@ -227,7 +262,10 @@ def smooth_wave(mesh: Mesh, met: jax.Array, wave: int = 0,
     # saving and reverted: the small step is load-bearing for final edge-
     # length conformity (test_adapt_target_lengths regressed without it)
     for step in (relax, 0.5 * relax, 0.25 * relax):
-        cand_pos = mesh.vert + step * (prop - mesh.vert)
+        # a curved patch's slide goes back onto the surface: the normal
+        # part of a step grows with its square
+        cand_pos = mesh.vert + step * (prop - mesh.vert) - \
+            (step * step) * drop
         cand_pos = jnp.where(movable[:, None], cand_pos, mesh.vert)
         newp = cand_pos[tv]                                # [T,4,3]
         variants = jnp.concatenate(
@@ -271,4 +309,5 @@ def smooth_wave(mesh: Mesh, met: jax.Array, wave: int = 0,
 
     vert = jnp.where(win[:, None], newpos, mesh.vert)
     return SmoothResult(dataclasses.replace(mesh, vert=vert),
-                        jnp.sum(win.astype(jnp.int32)))
+                        jnp.sum(win.astype(jnp.int32)),
+                        jnp.sum(win & bdy_ok, dtype=jnp.int32))

@@ -51,6 +51,9 @@ class SplitResult(NamedTuple):
     #                 capacity) — the active-scoped narrow path must see
     #                 a False here before trusting its dirty-region
     #                 worklist (ops/active.py)
+    nbdy: jax.Array = None  # scalar int32: of ``nsplit``, the splits of
+    #                 boundary edges (their midpoints are surface points:
+    #                 the ones the hausd lift places)
 
 
 def _interp_met_mid(met, va, vb):
@@ -67,6 +70,7 @@ def split_wave(mesh: Mesh, met: jax.Array, lmax: float = LLONG,
                et: EdgeTable | None = None,
                lens: jax.Array | None = None,
                vtan: jax.Array | None = None,
+               vn: jax.Array | None = None,
                vact: jax.Array | None = None,
                prescreen: bool = True) -> SplitResult:
     """One independent-set split wave. Jittable; static shapes throughout.
@@ -82,7 +86,8 @@ def split_wave(mesh: Mesh, met: jax.Array, lmax: float = LLONG,
     projected on each endpoint's tangent plane; the midpoint correction
     is (t_a - t_b)/8, exact to O(h^4) on a sphere.  Ridge/corner/required
     endpoints are excluded (their normals are multivalued — the flat
-    cube workloads are bit-for-bit unchanged).
+    cube workloads are bit-for-bit unchanged); a frozen seam vertex
+    counts as regular where the mesh carries its whole-fan normal.
 
     ``fem_only``: instead of long edges, target INTERIOR edges whose two
     endpoints both lie on the boundary — the FEM-incompatible
@@ -136,14 +141,20 @@ def split_wave(mesh: Mesh, met: jax.Array, lmax: float = LLONG,
     # the refinement phase
     lift_corr = None
     if hausd is not None:
-        from .analysis import boundary_vertex_normals, \
+        from .analysis import boundary_vertex_normals, carries_normal, \
             ridge_vertex_tangents
         from ..core.constants import MG_CRN, MG_NOM
-        vn = boundary_vertex_normals(mesh)
-        sing = MG_GEO | MG_CRN | MG_REQ | MG_PARBDY | MG_NOM | MG_REF
+        if vn is None:      # else the caller's, of THIS mesh
+            vn = boundary_vertex_normals(mesh)
+        # an endpoint has ONE normal unless it is a feature point or
+        # frozen; a frozen seam vertex has one again where the mesh
+        # carries it (its own fan is only the seam's near side)
+        one_n = ((mesh.vtag & (MG_GEO | MG_CRN | MG_NOM | MG_REF)) == 0) & \
+            (((mesh.vtag & (MG_REQ | MG_PARBDY)) == 0) |
+             carries_normal(mesh))
         regular = ((et.etag & MG_BDY) != 0) & \
             ((et.etag & (MG_GEO | MG_REQ | MG_PARBDY | MG_REF)) == 0) & \
-            ((mesh.vtag[va] & sing) == 0) & ((mesh.vtag[vb] & sing) == 0)
+            one_n[va] & one_n[vb]
         d = mesh.vert[vb] - mesh.vert[va]
         na, nb = vn[va], vn[vb]
         t_a = d - na * jnp.sum(na * d, -1, keepdims=True)
@@ -179,7 +190,8 @@ def split_wave(mesh: Mesh, met: jax.Array, lmax: float = LLONG,
     def _idle(_):
         return SplitResult(mesh, met, jnp.zeros((), jnp.int32),
                            jnp.zeros((), bool),
-                           jnp.zeros(capT, bool), jnp.zeros((), bool))
+                           jnp.zeros(capT, bool), jnp.zeros((), bool),
+                           jnp.zeros((), jnp.int32))
 
     def _act(_):
         from .quality import quality_from_points
@@ -397,7 +409,10 @@ def split_wave(mesh: Mesh, met: jax.Array, lmax: float = LLONG,
         modified = jnp.zeros(capT, bool).at[tgt1].set(
             True, mode="drop", unique_indices=True).at[tgt2].set(
             True, mode="drop", unique_indices=True)
-        return SplitResult(out, met_new, nwin, overflow, modified, defer)
+        nbdy = jnp.sum(ok & ((et.etag[wcc] & MG_BDY) != 0),
+                       dtype=jnp.int32)
+        return SplitResult(out, met_new, nwin, overflow, modified, defer,
+                           nbdy)
 
     return jax.lax.cond(jnp.any(cand), _act, _idle, None)
 

@@ -380,7 +380,10 @@ def refresh_shard_analysis_device(stacked: Mesh, comms, n_shards: int,
         ovf_host = int(ovf)
     if ovf_host != 0:
         return None
-    return dataclasses.replace(stacked, vtag=vt, etag=et)
+    # the seams moved: the normals the split carried are another
+    # seam's, so every normal comes from its shard's fan again
+    return dataclasses.replace(stacked, vtag=vt, etag=et,
+                               vnrm=jnp.zeros_like(stacked.vnrm))
 
 
 def refresh_shard_analysis(stacked: Mesh, comms, n_shards: int,
@@ -469,7 +472,8 @@ def refresh_shard_analysis(stacked: Mesh, comms, n_shards: int,
     return dataclasses.replace(
         stacked,
         vtag=jnp.asarray(np.stack(new_vtag)),
-        etag=jnp.asarray(np.stack(new_etag)))
+        etag=jnp.asarray(np.stack(new_etag)),
+        vnrm=jnp.zeros_like(stacked.vnrm))      # as in the device form
 
 
 # compiled quality-histogram programs keyed by device ids (compile

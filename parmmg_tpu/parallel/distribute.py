@@ -67,6 +67,29 @@ def regrown_capacity(capP: int, capT: int) -> tuple[int, int]:
             bucket(2 * capT, floor=64, scheme="geo"))
 
 
+def _fan_normals(vert, tet, ftag, want):
+    """[len(vert), 3] float64: the unit surface normal of each vertex in
+    ``want`` [len(vert)] bool from ALL its true-boundary faces, zero
+    elsewhere.  ``ops.analysis.boundary_vertex_normals`` in numpy (the
+    same corner weights), over the few faces that touch ``want``: the
+    split's arrays are on the host, and a device program at the merged
+    mesh's width would compile anew for every width a pass ends at."""
+    from ..core.constants import EPSD
+    acc = np.zeros((len(vert), 3))
+    for f in range(4):
+        tri = tet[((ftag[:, f] & MG_BDY) != 0)
+                  & ((ftag[:, f] & MG_PARBDY) == 0)][:, IDIR[f]]
+        tri = tri[want[tri].any(axis=1)]
+        p = vert[tri].astype(np.float64)
+        fn = np.cross(p[:, 1] - p[:, 0], p[:, 2] - p[:, 0])
+        l2 = [((p[:, (k + 1) % 3] - p[:, k]) ** 2).sum(-1) for k in range(3)]
+        for k in range(3):
+            wgt = 1.0 / np.maximum(l2[k] * l2[(k + 2) % 3], EPSD)
+            np.add.at(acc, tri[:, k], fn * wgt[:, None])
+    acc[~want] = 0.0
+    return acc / np.maximum(np.linalg.norm(acc, axis=1, keepdims=True), EPSD)
+
+
 def split_to_shards(mesh: Mesh, met, part: np.ndarray, nparts: int,
                     cap_mult: float = 3.0, return_l2g: bool = False,
                     reuse_caps: tuple | None = None):
@@ -135,6 +158,17 @@ def split_to_shards(mesh: Mesh, met, part: np.ndarray, nparts: int,
     face_is_ifc[ifc_faces] = True
     face_is_ifc = face_is_ifc.reshape(n, 4)
 
+    # a regular surface vertex on a seam keeps the normal of its WHOLE
+    # fan (Mmg's xPoint n1, which the reference agrees on across ranks,
+    # analys_pmmg.c:199-1171): inside its shard the seam cuts the fan
+    # and the near side's faces alone give a tilted one, which every
+    # Bezier lift from that vertex would follow.  Feature points have
+    # no single normal and carry none
+    from ..core.constants import MG_CRN, MG_GEO, MG_NOM, MG_REF
+    carry = ifc_vert & ((vtag & MG_BDY) != 0) & \
+        ((vtag & (MG_GEO | MG_CRN | MG_NOM | MG_REF)) == 0)
+    vn_h = _fan_normals(vert, tet, ftag_h, carry)
+
     for p in range(nparts):
         gids, ltet_g, tsel = locals_[p]
         g2l = np.full(len(vert), -1, np.int64)
@@ -180,8 +214,11 @@ def split_to_shards(mesh: Mesh, met, part: np.ndarray, nparts: int,
         setag[: len(ltet)][e_ifc_m] |= PARBDY_TAGS
         setag[: len(ltet)][e_ifc_m & pre_bdy_e] |= MG_PARBDYBDY
         setag[: len(ltet)][e_ifc_m & user_req_e] &= ~np.uint32(MG_NOSURF)
+        svnrm = np.zeros((capP, 3))
+        svnrm[: len(gids)] = vn_h[gids]
         sm = dataclasses.replace(
             sm, vtag=jnp.asarray(svtag),
+            vnrm=jnp.asarray(svnrm, mesh.dtype),
             ftag=jnp.maximum(sm.ftag, jnp.asarray(sftag)),
             etag=jnp.maximum(sm.etag, jnp.asarray(setag)),
             fref=jnp.asarray(sfref))
@@ -346,6 +383,7 @@ def grow_shards(shards: Mesh, mets, new_capP: int, new_capT: int):
         shards,
         vert=padP(shards.vert), vref=padP(shards.vref),
         vtag=padP(shards.vtag), vmask=padP(shards.vmask, False),
+        vnrm=padP(shards.vnrm),
         tet=padT(shards.tet), tref=padT(shards.tref),
         tmask=padT(shards.tmask, False), adja=padT(shards.adja, -1),
         ftag=padT(shards.ftag), fref=padT(shards.fref),

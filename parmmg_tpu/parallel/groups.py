@@ -192,7 +192,7 @@ def _group_block_program(nblk: int, nomove: bool, noinsert: bool, hausd):
                 sm_idle = ((counts[0] + counts[1] + counts[2]) == 0) & \
                     (counts[3] == 0)
                 counts_all.append(counts)
-            return m, k, jnp.stack(counts_all), tp   # counts [n, 9]
+            return m, k, jnp.stack(counts_all), tp   # counts [n, 12]
 
         n_map = stacked.vert.shape[0]            # chunk or g_exec
         waves = jnp.full(n_map, wave, jnp.int32)
@@ -200,7 +200,7 @@ def _group_block_program(nblk: int, nomove: bool, noinsert: bool, hausd):
         incs = jnp.full(n_map, incr, bool)
         m, k, counts, tp = jax.lax.map(
             body, (stacked, met_s, waves, active, cads, incs, topo))
-        return m, k, counts, tp                  # counts [G, n, 9]
+        return m, k, counts, tp                  # counts [G, n, 12]
 
     _GROUP_BLOCK_CACHE[key] = run
     return run
@@ -461,7 +461,7 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     skipped-group / saved-dispatch counters and the active-group
     trajectory, in ``stats.sched_extra``.
     """
-    from ..ops.adapt import default_cycle_block
+    from ..ops.adapt import DIRTY_COL, SURF_COLS, default_cycle_block
     from ..utils.timers import Timers
     from .partition import morton_partition, fix_contiguity
     from .distribute import split_to_shards, merge_shards, grow_shards
@@ -567,7 +567,7 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                                          topo=topo_s)
                 sched.note_plan_pads(plans)
                 counts_act = np.concatenate(parts) if parts else \
-                    np.zeros((0, nblk, 9), np.int32)
+                    np.zeros((0, nblk, DIRTY_COL + 1), np.int32)
                 if sched.enabled:
                     otrace.log(
                         2, f"  grp block {c}..{c + nblk - 1}: active "
@@ -583,18 +583,20 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                     stacked, met_s, wave,
                     jnp.asarray(sched.block_mask(pres_all_on)), cad,
                     inc, topo_s)
-                counts_act = np.asarray(counts)  # [g_exec, nblk, 9]
+                counts_act = np.asarray(counts)  # [g_exec, nblk, 12]
             # quiet groups contribute exact zeros (that is what marked
             # them)
-            cs = counts_act.sum(axis=0, dtype=np.int64)     # [nblk, 8]
+            cs = counts_act.sum(axis=0, dtype=np.int64)     # [nblk, 12]
             # ONE host conversion for the whole block's counters
             # (counts_act is already host numpy — the drain pulled it);
             # the per-counter int() casts were R2-baselined noise
             cs_l = cs.tolist()                              # python ints
+            surf = {k: sum(r[col] for r in cs_l)
+                    for k, col in SURF_COLS.items()}
             sp.set(split=sum(r[0] for r in cs_l),
                    collapse=sum(r[1] for r in cs_l),
                    swap=sum(r[2] for r in cs_l),
-                   moved=sum(r[3] for r in cs_l))
+                   moved=sum(r[3] for r in cs_l), **surf)
         if not chunk:
             # "compute" as the chunk pipeline records it: the seconds
             # from dispatch to counter pull
@@ -602,15 +604,17 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
         sched.record_block(act, counts_act, swap_inc, pres_all_on)
         for i in range(nblk):
             tot = cs_l[i]
-            # counts[8]: dirty tets pending at each cycle start, summed
-            # over groups — the band-occupancy trajectory (bench extras)
-            dirty_traj.append(tot[8])
+            # dirty tets pending at each cycle start, summed over
+            # groups — the band-occupancy trajectory (bench extras)
+            dirty_traj.append(tot[DIRTY_COL])
             if stats is not None:
                 stats.nsplit += tot[0]
                 stats.ncollapse += tot[1]
                 stats.nswap += tot[2]
                 stats.nmoved += tot[3]
                 stats.cycles += 1
+                stats.add_surface(**{k: tot[col]
+                                     for k, col in SURF_COLS.items()})
             otrace.log(3, f"  grp cycle {c + i}: split {tot[0]} "
                           f"collapse {tot[1]} swap {tot[2]} move "
                           f"{tot[3]} over {ngroups} groups",
@@ -644,6 +648,7 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                         vert=_padP(stacked.vert), vref=_padP(stacked.vref),
                         vtag=_padP(stacked.vtag),
                         vmask=_padP(stacked.vmask, False),
+                        vnrm=_padP(stacked.vnrm),
                         tet=_padT(stacked.tet), tref=_padT(stacked.tref),
                         tmask=_padT(stacked.tmask, False),
                         adja=_padT(stacked.adja, -1),
@@ -821,7 +826,7 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
         se.setdefault("active_groups_per_block", []).extend(
             sched.active_per_block)
         if dirty_traj:
-            # per-cycle dirty-band occupancy (counts[8] summed over
+            # per-cycle dirty-band occupancy (the dirty column summed over
             # groups): shows when the incremental path engages and how
             # small the decay-regime bands get (bench extra.incr_topo)
             se.setdefault("incr_dirty_per_cycle", []).extend(dirty_traj)

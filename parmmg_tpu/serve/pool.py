@@ -613,7 +613,7 @@ class SlotPool:
                         b, fn, jnp.asarray(c, jnp.int32), ids, done)
                     for i, crow in rows:
                         s = b.slots[i]
-                        cs = crow.astype(np.int64)           # [nblk, 9]
+                        cs = crow.astype(np.int64)           # [nblk, 12]
                         st = s.stats
                         for ib in range(nblk):
                             st.nsplit += int(cs[ib][0])

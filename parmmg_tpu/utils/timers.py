@@ -48,7 +48,7 @@ class Timers:
         sp = span(path, tim=self.trace_id)
         try:
             with sp:
-                yield
+                yield sp        # the caller may ``set`` fields on it
         finally:
             self._stack.pop()
             # the very float the record carries (whole ns / 1e9):

@@ -1,7 +1,16 @@
 """Curved-geometry workloads: hausd-driven surface approximation on the
 sphere, torus topology preservation, and the {1,2,4,8}-device matrix —
 the reference CI shape (cmake/testing/pmmg_tests.cmake:25-150) with
-quality-asserting gates instead of exit codes."""
+quality-asserting gates instead of exit codes.
+
+What the surface machinery does on a curved patch (the grouped path's
+own cases are in test_sphere_grouped.py): a split boundary edge's
+midpoint is lifted onto the Bezier curve through its endpoints and
+their normals, which are exact on a sphere (Max's corner weights) and,
+at a vertex on a group seam, the whole fan's; and a regular surface
+vertex slides in its tangent plane like one on a flat patch and is put
+back onto the surface by the curvature its fan shows (ops/smooth.py),
+while the step stays within hausd of the old surface."""
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -55,22 +64,41 @@ def test_sphere_hausd_keeps_surface_on_sphere():
     assert abs(vols.sum() - 4.1888) < 0.05 * 4.1888
 
 
-def test_hausd_metric_bound_refines_curved_boundary():
+@pytest.mark.parametrize("ngroups", [1, 3])
+def test_hausd_metric_bound_refines_curved_boundary(ngroups):
     """The defsiz route: even a very coarse size request refines curved
-    boundaries to sqrt(8*hausd/kappa) (unit sphere: kappa=1)."""
+    boundaries to sqrt(8*hausd/kappa) (unit sphere: kappa=1).  In a
+    group of a split mesh (``ngroups`` 3) the bound reads the same
+    curvature at every vertex it reaches: the frozen seam vertices are
+    skipped with the edges that leave them, and the others' normals
+    are exact on a sphere whatever the shapes of their fans."""
     from parmmg_tpu.ops.metric import hausd_metric_bound
     vert, tet = sphere_mesh(5)
     m = make_mesh(vert, tet, capP=2 * len(vert), capT=2 * len(tet))
     m = analyze_mesh(m).mesh
     met = jnp.full(m.capP, 1.5)                   # "no refinement please"
+    if ngroups > 1:
+        import jax
+        from parmmg_tpu.parallel.distribute import split_to_shards
+        from parmmg_tpu.parallel.partition import (fix_contiguity,
+                                                   morton_partition)
+        part = fix_contiguity(tet, morton_partition(
+            vert[tet].mean(axis=1), ngroups))
+        stacked, met_s = split_to_shards(m, met, part, ngroups)
+        m = jax.tree.map(lambda a: a[0], stacked)
+        met = met_s[0]
     met2 = hausd_metric_bound(m, met, hausd=0.005, hmin=1e-3)
     mh = np.asarray(met2)
     vtag = np.asarray(m.vtag)
     vm = np.asarray(m.vmask)
     reg_bdy = vm & ((vtag & C.MG_BDY) != 0) & \
-        ((vtag & (C.MG_GEO | C.MG_CRN)) == 0)
+        ((vtag & (C.MG_GEO | C.MG_CRN | C.MG_PARBDY)) == 0)
     target = np.sqrt(8 * 0.005 / 1.0)             # = 0.2
+    assert reg_bdy.sum() > 20
     assert np.median(mh[reg_bdy]) < 1.5 * target
+    # the curvature is the sphere's at every vertex the bound reaches,
+    # next to a seam too: none is refined far past the target
+    assert mh[reg_bdy].min() > 0.8 * target
     # interior sizes untouched
     interior = vm & ((vtag & C.MG_BDY) == 0)
     assert (mh[interior] == 1.5).all()

@@ -1,0 +1,308 @@
+"""A curved boundary through the grouped path (the path the chip runs):
+``sphere_mesh(8)`` in three groups through ``ParMesh.run``, judged by a
+float64 numpy oracle written here, and the pieces that make the job's
+surface what it says it is, each with a case that fails without it:
+
+- vertex normals weighted so that they are exact on a sphere
+  (``ops/analysis.boundary_vertex_normals``);
+- the whole fan's normal carried by a surface vertex on a group seam
+  (``Mesh.vnrm``, ``distribute.split_to_shards``);
+- the Bezier lift of a boundary edge that touches the seam
+  (``ops/split.split_wave``);
+- the slide of a surface vertex on a curved patch, put back onto the
+  surface (``ops/smooth.smooth_wave``).
+
+Two jobs are compiled for the module (the grouped one and the same mesh
+as one group), about a minute each on the CPU.
+"""
+import dataclasses
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from parmmg_tpu.api.params import DParam, IParam
+from parmmg_tpu.api.parmesh import ParMesh
+from parmmg_tpu.core import constants as C
+from parmmg_tpu.core.mesh import compact, make_mesh, with_capacity
+from parmmg_tpu.obs import trace as otrace
+from parmmg_tpu.obs.metrics import REGISTRY
+from parmmg_tpu.ops.analysis import (analyze_mesh, boundary_vertex_normals,
+                                     carries_normal)
+from parmmg_tpu.parallel.distribute import split_to_shards
+from parmmg_tpu.parallel.partition import fix_contiguity, morton_partition
+from parmmg_tpu.utils.fixtures import sphere_mesh
+
+N = 8
+HAUSD = 0.01
+H_SURF = 0.16           # the size the job is asked for on the sphere
+# A boundary edge is split when it is longer than sqrt 2 x H_SURF = 0.23
+# and none of the input is longer than 0.4.  A bare chord midpoint then
+# lies 0.23^2 / 8 = 6.4e-3 or more under the sphere; the Bezier lift with
+# exact normals leaves 3 d^4 / 128 <= 6e-4 at d = 0.4.  The limit sits
+# between the two, an order under the longest chord's sag (0.02)
+VERTEX_LIMIT = 1.5e-3
+QMIN_FLOOR = 1e-3
+BALL = 4.0 * np.pi / 3.0
+
+
+def shell_metric(vert):
+    """Finest on the sphere, coarse at the centre (iso_shell's shape)."""
+    return (H_SURF / 0.2) * (0.2 + 4.0 * np.abs(
+        1.0 - np.linalg.norm(vert, axis=1)))
+
+
+def run_sphere(mesh_size):
+    """sphere_mesh(N) under the shell metric through the public API;
+    ``mesh_size`` is the group target (tets).  Returns the output arrays
+    as float64 / 0-based, the surface counters' increase and the ring."""
+    vert, tet = sphere_mesh(N)
+    pm = ParMesh()
+    pm.set_mesh_size(np_=len(vert), ne=len(tet))
+    pm.set_vertices(vert)
+    pm.set_tetrahedra(tet + 1)
+    pm.set_met_size(1, len(vert))
+    pm.set_scalar_mets(shell_metric(vert))
+    pm.set_iparameter(IParam.meshSize, mesh_size)
+    pm.set_iparameter(IParam.niter, 2)
+    pm.set_iparameter(IParam.verbose, 0)
+    pm.set_dparameter(DParam.hausd, HAUSD)
+    otrace.TRACER.reset()
+    before = dict(REGISTRY.snapshot()["counters"])
+    assert pm.run() == C.PMMG_SUCCESS
+    after = dict(REGISTRY.snapshot()["counters"])
+    v, _ = pm.get_vertices()
+    t, _ = pm.get_tetrahedra()
+    return {"vert": np.asarray(v, np.float64),
+            "tet": np.asarray(t, np.int64) - 1,
+            "counters": {k: after[k] - before.get(k, 0.0) for k in after},
+            "spans": [r for r in otrace.TRACER.ring
+                      if r.get("kind") == "span"]}
+
+
+@pytest.fixture(scope="module")
+def grouped():
+    return run_sphere(mesh_size=1100)       # 3072 tets: 3 groups
+
+
+@pytest.fixture(scope="module")
+def one_group():
+    return run_sphere(mesh_size=100000)
+
+
+def oracle(vert, tet):
+    """What a user can check of a ball's mesh, in float64."""
+    p = vert[tet]
+    vol = np.einsum("ij,ij->i", p[:, 1] - p[:, 0], np.cross(
+        p[:, 2] - p[:, 0], p[:, 3] - p[:, 0])) / 6.0
+    faces = np.sort(np.stack([tet[:, [1, 2, 3]], tet[:, [0, 2, 3]],
+                              tet[:, [0, 1, 3]], tet[:, [0, 1, 2]]],
+                             axis=1).reshape(-1, 3), axis=1)
+    uniq, cnt = np.unique(faces, axis=0, return_counts=True)
+    skin = uniq[cnt == 1]
+    rim = np.sort(np.concatenate([skin[:, [0, 1]], skin[:, [1, 2]],
+                                  skin[:, [0, 2]]]), axis=1)
+    _, rim_cnt = np.unique(rim, axis=0, return_counts=True)
+    ed = np.stack([p[:, j] - p[:, i] for i, j in
+                   ((0, 1), (0, 2), (0, 3), (1, 2), (1, 3), (2, 3))], 1)
+    q = vol / (ed * ed).sum((1, 2)) ** 1.5 * (6 * 2 ** 0.5 * 6 ** 1.5)
+    on = np.unique(skin)
+    return {
+        "inverted": int((vol <= 0).sum()),
+        "overfull": int((cnt > 2).sum()),
+        # a closed 2-manifold skin: every rim edge has two skin faces
+        "open_rim": int((rim_cnt != 2).sum()),
+        "euler": len(on) - len(rim_cnt) + len(skin),
+        "vertex_dev": float(np.abs(
+            np.linalg.norm(vert[on], axis=1) - 1.0).max()),
+        "chord_sag": float((1.0 - np.linalg.norm(
+            vert[skin].mean(axis=1), axis=1)).max()),
+        "volume": float(vol.sum()), "qmin": float(q.min()),
+        "n_surface": len(on), "ntets": len(tet)}
+
+
+def assert_sound_ball(out):
+    o = oracle(out["vert"], out["tet"])
+    assert o["inverted"] == 0 and o["overfull"] == 0, o
+    assert o["open_rim"] == 0 and o["euler"] == 2, o
+    # one bare midpoint would read 6.4e-3 or more
+    assert o["vertex_dev"] < VERTEX_LIMIT, o
+    # inscribed polyhedron: it lacks at most the deepest chord's shell,
+    # and exceeds the ball by no more than its vertices stand out
+    assert BALL * (1 - o["chord_sag"] - o["vertex_dev"]) ** 3 \
+        <= o["volume"] <= BALL * (1 + o["vertex_dev"]) ** 3, o
+    assert o["qmin"] > QMIN_FLOOR, o
+    assert o["n_surface"] > 500 and o["ntets"] > 4500, o    # it adapted
+    return o
+
+
+def test_grouped_sphere_is_a_sound_ball(grouped):
+    assert_sound_ball(grouped)
+    assert grouped["counters"]["groups.dispatches"] > 0     # the path
+
+
+def test_the_seam_does_not_show(grouped, one_group):
+    """The same mesh as one group and as three: both surfaces meet the
+    same limits, and the grouped one is no farther from the sphere than
+    the lift's own error allows for either."""
+    a, b = assert_sound_ball(one_group), assert_sound_ball(grouped)
+    assert "groups.dispatches" not in one_group["counters"] or \
+        one_group["counters"]["groups.dispatches"] == 0
+    assert abs(a["volume"] - b["volume"]) < 2e-3 * BALL
+
+
+def test_surface_counts_reach_spans_and_counters(grouped):
+    c = grouped["counters"]
+    for name in ("surf.bsplit", "surf.hveto", "surf.bmoved",
+                 "surf.bound_verts"):
+        assert name in c, sorted(k for k in c if k.startswith("surf"))
+    # the size map is finest on the sphere: a good share of the splits
+    # are of boundary edges, and each such split is a new surface vertex
+    assert 0.15 * c["adapt.nsplit"] < c["surf.bsplit"] < c["adapt.nsplit"]
+    by = {}
+    for r in grouped["spans"]:
+        by.setdefault(r["name"].split("/")[-1], []).append(r)
+    blocks = by["grp block"]
+    assert sum(r["bsplit"] for r in blocks) + sum(
+        r["bsplit"] for r in by.get("fem round", [])) == c["surf.bsplit"]
+    assert all({"bsplit", "hveto", "bmoved"} <= set(r)
+               for r in blocks + by["polish wave"] + by["fem round"])
+    ana, = by["analysis"]
+    # 6 n^2 cube faces of two triangles each; no crease on a sphere
+    assert ana["bdy_faces"] == 12 * N * N and ana["ridges"] == 0
+    met, = by["metric"]
+    assert met["bound_verts"] == c["surf.bound_verts"]
+
+
+# ---------------------------------------------------------------------------
+# the pieces
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def analysed():
+    vert, tet = sphere_mesh(N)
+    return analyze_mesh(make_mesh(vert, tet)).mesh
+
+
+def angle_to_radial(vn, vert):
+    cosang = np.sum(vn * vert, axis=1) / np.linalg.norm(vert, axis=1)
+    return np.arccos(np.clip(cosang, -1.0, 1.0))
+
+
+def test_vertex_normals_are_exact_on_a_sphere(analysed):
+    """sphere_mesh's fans are irregular (a cube's faces bent round): the
+    area-weighted sum errs by up to 0.04 rad there, Max's weights by
+    float32's rounding."""
+    vn = np.asarray(boundary_vertex_normals(analysed), np.float64)
+    on = (np.asarray(analysed.vtag) & C.MG_BDY) != 0
+    assert on.sum() == 6 * N * N + 2
+    err = angle_to_radial(vn[on], np.asarray(analysed.vert, np.float64)[on])
+    assert err.max() < 2e-3, err.max()
+    assert np.all(vn[~on] == 0)
+
+
+@pytest.fixture(scope="module")
+def shards(analysed):
+    """The analysed sphere cut in three along the Morton curve, as the
+    grouped pass cuts it."""
+    vert = np.asarray(analysed.vert)[np.asarray(analysed.vmask)]
+    tet = np.asarray(analysed.tet)[np.asarray(analysed.tmask)]
+    part = fix_contiguity(tet, morton_partition(
+        vert[tet].mean(axis=1), 3))
+    met = jnp.asarray(shell_metric(np.asarray(analysed.vert)),
+                      analysed.vert.dtype)
+    stacked, met_s, l2g = split_to_shards(analysed, met, part, 3,
+                                          return_l2g=True)
+    return stacked, met_s, l2g
+
+
+def test_a_seam_vertex_has_the_unsplit_meshs_normal(analysed, shards):
+    stacked, _, l2g = shards
+    whole = np.asarray(boundary_vertex_normals(analysed))
+    seen = 0
+    for g, gids in enumerate(l2g):
+        shard = jax.tree.map(lambda a: a[g], stacked)
+        vtag = np.asarray(shard.vtag)[: len(gids)]
+        seam = ((vtag & C.MG_PARBDY) != 0) & ((vtag & C.MG_PARBDYBDY) != 0)
+        assert seam.sum() > 8
+        assert np.array_equal(
+            np.asarray(carries_normal(shard))[: len(gids)], seam)
+        vn = np.asarray(boundary_vertex_normals(shard))[: len(gids)]
+        assert np.abs(vn[seam] - whole[gids][seam]).max() < 2e-6
+        # what the shard's own faces give there: the near half of the
+        # fan, tilted towards it by degrees
+        bare = dataclasses.replace(shard, vnrm=jnp.zeros_like(shard.vnrm))
+        half = np.asarray(boundary_vertex_normals(bare))[: len(gids)]
+        pos = np.asarray(shard.vert, np.float64)[: len(gids)]
+        assert angle_to_radial(half[seam], pos[seam]).max() > 0.05
+        assert angle_to_radial(vn[seam], pos[seam]).max() < 2e-3
+        seen += int(seam.sum())
+    assert seen > 40
+
+
+def test_an_edge_at_the_seam_is_lifted(shards):
+    """One split wave on a shard: every surface point it inserts lies on
+    the sphere within the lift's own error, those whose edge ends on the
+    seam included; with the carried normals taken away those sag like
+    the chords they are."""
+    from parmmg_tpu.ops.split import split_wave
+    stacked, met_s, _ = shards
+
+    def new_surface_points(shard, met):
+        res = split_wave(shard, met, hausd=HAUSD)
+        new = np.asarray(res.mesh.vmask) & ~np.asarray(shard.vmask)
+        on = new & ((np.asarray(res.mesh.vtag) & C.MG_BDY) != 0)
+        assert int(res.nbdy) == on.sum()
+        return np.abs(np.linalg.norm(
+            np.asarray(res.mesh.vert, np.float64)[on], axis=1) - 1.0)
+
+    worst_with, worst_without, n = 0.0, 0.0, 0
+    for g in range(3):
+        shard = jax.tree.map(lambda a: a[g], stacked)
+        dev = new_surface_points(shard, met_s[g])
+        bare = dataclasses.replace(shard, vnrm=jnp.zeros_like(shard.vnrm))
+        worst_with = max(worst_with, dev.max())
+        worst_without = max(worst_without,
+                            new_surface_points(bare, met_s[g]).max())
+        n += len(dev)
+    assert n > 30
+    assert worst_with < VERTEX_LIMIT, worst_with
+    assert worst_without > 4 * VERTEX_LIMIT, worst_without
+
+
+def test_a_surface_vertex_slides_and_stays_on_the_sphere(analysed):
+    """With the tolerance given, regular surface vertices of a curved
+    patch move (none of a sphere's fans is flat) and land on the sphere
+    again: a slide left in the tangent plane would stand s^2 / 2 above
+    it, 1e-3 and more for the steps taken here.  Without it they wait,
+    as they always did."""
+    from parmmg_tpu.ops.smooth import smooth_wave
+    met = jnp.asarray(shell_metric(np.asarray(analysed.vert)),
+                      analysed.vert.dtype)
+    on = (np.asarray(analysed.vtag) & C.MG_BDY) != 0
+    old = np.asarray(analysed.vert, np.float64)
+    res = smooth_wave(analysed, met, hausd=HAUSD)
+    new = np.asarray(res.mesh.vert, np.float64)
+    step = np.linalg.norm(new - old, axis=1)
+    moved = on & (step > 0)
+    assert int(res.nbdy) == moved.sum() and moved.sum() > 20
+    assert int(res.nmoved) > int(res.nbdy)
+    assert step[moved].max() > 0.04                 # real steps
+    assert np.abs(np.linalg.norm(new[on], axis=1) - 1.0).max() < 1e-4
+    still = smooth_wave(analysed, met)
+    assert int(still.nbdy) == 0
+    assert np.array_equal(np.asarray(still.mesh.vert)[on],
+                          np.asarray(analysed.vert)[on])
+
+
+def test_the_carried_normal_survives_compaction_and_growth(shards):
+    stacked, _, _ = shards
+    shard = jax.tree.map(lambda a: a[0], stacked)
+    key = np.asarray(shard.vert)[np.asarray(carries_normal(shard))]
+    want = np.asarray(shard.vnrm)[np.asarray(carries_normal(shard))]
+    for other in (compact(shard),
+                  with_capacity(shard, shard.capP + 64, shard.capT + 64)):
+        has = np.asarray(carries_normal(other))
+        assert np.array_equal(np.asarray(other.vert)[has], key)
+        assert np.array_equal(np.asarray(other.vnrm)[has], want)
