@@ -1014,11 +1014,21 @@ def distributed_adapt_multi(mesh: Mesh, met, n_shards: int,
             "loop is single-controller)")
     glo_d = None
     shared_prev = None
+
+    def upload_glo():
+        """The device copy of the host numbering ``glo``, COMMITTED over
+        'shard' as the migration programs hand it back: an uncommitted
+        upload made ``device_migrate`` lower and compile a second
+        executable for the same shapes (compilecache, placement
+        variants; PERF.md, PR 31)."""
+        return shard_stacked(
+            jnp.asarray(np.stack(glo).astype(np.int32)), dmesh)
+
     if use_band:
         from .migrate_dev import (extend_ids_device, band_migrate_iteration,
                                   band_weld, session_ids_fit,
                                   dead_glo_rows)
-        glo_d = jnp.asarray(np.stack(glo).astype(np.int32))
+        glo_d = upload_glo()
         # initially-shared gids: interface vertices of the initial comms
         # (a resumed run restores the exact set its checkpoint carried)
         shared_prev = resumed_shared if resumed_shared is not None \
@@ -1053,7 +1063,7 @@ def distributed_adapt_multi(mesh: Mesh, met, n_shards: int,
             # then the DEVICE analysis refresh
             if use_band:
                 if glo_d is None:
-                    glo_d = jnp.asarray(np.stack(glo).astype(np.int32))
+                    glo_d = upload_glo()
                 KN = max(256, stacked.vert.shape[1] // 2)
                 # int32 numbering on device (documented migrate_dev limit):
                 # the monotone session counter must not wrap — if this
@@ -1097,7 +1107,7 @@ def distributed_adapt_multi(mesh: Mesh, met, n_shards: int,
                         use_band = False
                         glo_d = None
                     else:
-                        glo_d = jnp.asarray(np.stack(glo).astype(np.int32))
+                        glo_d = upload_glo()
             else:
                 # lint: ok(R7) — legacy full-view path (PARMMG_BAND_PATH=0,
                 # single-controller only); metered by pull_host
@@ -1179,7 +1189,7 @@ def distributed_adapt_multi(mesh: Mesh, met, n_shards: int,
                             stacked, met_s, 2 * capP_o, 2 * capT_o)
                         views = None    # any pre-grow pull is shape-stale
                         grow_glo(capP_o)
-                        glo_d = jnp.asarray(np.stack(glo).astype(np.int32))
+                        glo_d = upload_glo()
                         me_col = jnp.arange(n_shards,
                                             dtype=labels_d.dtype)[:, None]
                         labels_d = jnp.concatenate(
@@ -1219,8 +1229,7 @@ def distributed_adapt_multi(mesh: Mesh, met, n_shards: int,
                                 # the full weld freed host-glo rows; the
                                 # device copy must drop them too (stale
                                 # gids resurrect — see band_weld)
-                                glo_d = jnp.asarray(
-                                    np.stack(glo).astype(np.int32))
+                                glo_d = upload_glo()
                             stacked = rebuild_shards(stacked)
                             check_interface_echo(stacked, met_s, comms,
                                                  dmesh, vert_h, G=G,
@@ -1274,7 +1283,7 @@ def distributed_adapt_multi(mesh: Mesh, met, n_shards: int,
                                              vert_h, G=G,
                                              pack_state=pack_state)
                     if use_band:    # resync the device numbering copy
-                        glo_d = jnp.asarray(np.stack(glo).astype(np.int32))
+                        glo_d = upload_glo()
                         shared_prev = _shared_gids(comms, glo, n_shards)
                 if nmoved:
                     otrace.log(2, f"  it {it}: migrated {nmoved} "

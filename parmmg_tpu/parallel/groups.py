@@ -314,6 +314,7 @@ def _pipeline_chunks(fn, stacked, met_s, wave, plans, tim, done=None,
     from ..resilience.faults import faultpoint
     from ..resilience.recover import retry_call
     from ..resilience.watchdog import deadline_knob, run_with_deadline
+    from ..utils.placement import to_device
     from .sched import pad_mask
     depth = 2 if os.environ.get("PARMMG_GROUP_PIPELINE", "1") != "0" \
         else 1
@@ -328,14 +329,16 @@ def _pipeline_chunks(fn, stacked, met_s, wave, plans, tim, done=None,
 
     def dispatch(pi, idx, nreal):
         with tim("upload"):
-            sl = jax.tree.map(lambda a: jnp.asarray(a[idx]), stacked)
-            kl = jnp.asarray(met_s[idx])
+            # committed, as the unchunked pass commits its state: a
+            # chunk of a shape the unchunked path has run takes the
+            # executable that path built (compilecache, placement
+            # variants)
+            sl, kl, tl = to_device(jax.tree.map(
+                lambda a: a[idx], (stacked, met_s, topo)))
             # device quiet mask: the repeat-padded tail rows compute
             # nothing (lax.cond identity) — their results were always
             # discarded at writeback (sched.pad_mask)
             act = jnp.asarray(pad_mask(len(idx), nreal))
-            tl = None if topo is None else \
-                jax.tree.map(lambda a: jnp.asarray(a[idx]), topo)
         faultpoint("dispatch.chunk", key=str(pi))
         with otrace.span("grp dispatch chunk", chunk_index=pi):
             if topo is None:
@@ -480,6 +483,7 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     # once and stays there for the whole pass.
     from ..utils.placement import host_staging, to_device
     chunk = group_chunk(ngroups)
+    g_exec = ngroups            # chunk mode pads it to whole chunks
     with otrace.span("grp split", groups=ngroups) as sp:
         vert_h, tet_h, _, _, _ = mesh_to_host(mesh)
         if part is None:
@@ -517,12 +521,26 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     # everything the pass commits to the device before its first block:
     # the stacked state (unchunked), the scheduler's scalars, the
     # incremental-topology state
+    from ..ops.topo_incr import incr_topo_enabled, topo_init, topo_init_np
+
+    def fresh_topo(capT):
+        """The incremental-topology state of a pass at group capacity
+        ``capT``: host numpy in chunk mode (uploaded with each chunk,
+        the same way every time), else COMMITTED to the device like the
+        stacked state beside it.  The block program hands it back
+        committed, and jax keys a lowering on that: a bare ``jnp.zeros``
+        state made the first dispatch of every pass lower, compile and
+        cache a second executable of the same jaxpr (PERF.md, PR 31)."""
+        if chunk:
+            return topo_init_np(g_exec, capT)
+        return to_device(topo_init(capT, stack=g_exec))
+
     with otrace.span("grp upload", chunk=chunk or 0) as sp:
+        topo_s = fresh_topo(stacked.tet.shape[1])
         if not chunk:
-            g_exec = ngroups
             stacked, met_s = to_device((stacked, met_s))
             sp.set(bytes=sum(a.nbytes for a in
-                             jax.tree.leaves((stacked, met_s))))
+                             jax.tree.leaves((stacked, met_s, topo_s))))
         sched = QuietGroupScheduler(ngroups, g_exec, chunk)
         # smoothing-cadence enable as a DEVICE SCALAR: always an argument
         # of the compiled block (like the quiet mask), so toggling
@@ -533,12 +551,9 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
         # per-slot retained-table + dirty-band state rides the group axis —
         # host-resident in chunk mode (rows committed by drain writebacks,
         # same idempotent contract as the mesh state), device-resident
-        # otherwise.  The knob is a traced scalar like the cadence.
-        from ..ops.topo_incr import incr_topo_enabled, topo_init, topo_init_np
+        # otherwise (fresh_topo).  The knob is a traced scalar like the
+        # cadence.
         inc = jnp.asarray(incr_topo_enabled())
-        capT_s = stacked.tet.shape[1]
-        topo_s = topo_init_np(g_exec, capT_s) if chunk else \
-            topo_init(capT_s, stack=g_exec)
     # pipeline segment timers on a LOCAL registry: folded into
     # stats.sched_extra and (prefixed) into the caller's Timers at the
     # end, so the driver report shows the transfer/compute split
@@ -660,9 +675,7 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                 # regrow permutes tet slots (compact) and changes capT: the
                 # retained sorts are stale at the new capacity — re-init
                 # (ok=False => next derivation is a full rebuild, exact)
-                capT_s = stacked.tet.shape[1]
-                topo_s = topo_init_np(g_exec, capT_s) if chunk else \
-                    topo_init(capT_s, stack=g_exec)
+                topo_s = fresh_topo(stacked.tet.shape[1])
                 regrows += 1
                 # the wave top-K budgets scale with capT: every quiet proof
                 # is stale at the new capacity — reactivate the full set
@@ -740,9 +753,8 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
             # chunk polishes to ITS quiet point while resident, one
             # upload/download per chunk total
             for g0 in range(0, g_exec, chunk):
-                sl = jax.tree.map(
-                    lambda a: jnp.asarray(a[g0:g0 + chunk]), stacked)
-                kl = jnp.asarray(met_s[g0:g0 + chunk])
+                sl, kl = to_device(jax.tree.map(
+                    lambda a: a[g0:g0 + chunk], (stacked, met_s)))
                 for w in range(4):
                     sl, kl, cnt = polish_block(
                         sl, kl, jnp.asarray(2000 + w, jnp.int32),

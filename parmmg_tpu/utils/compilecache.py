@@ -41,10 +41,13 @@ import time
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # the other events jax 0.9.0 emits on the way to an executable: tracing
 # to a jaxpr, lowering it to MLIR, and loading from the persistent cache
-TRACE_LOWER_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-                      "/jax/core/compile/jaxpr_to_mlir_module_duration")
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+TRACE_LOWER_EVENTS = ("/jax/core/compile/jaxpr_trace_duration", LOWER_EVENT)
 CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+# the entry whose executables ``compile.block_programs`` counts: the
+# grouped cycle block, minutes of compile and 115 MB of cache apiece
+BLOCK_ENTRY = "groups.adapt_block"
 
 
 # ---------------------------------------------------------------------------
@@ -94,6 +97,11 @@ class EntryStats:
     keys_seen: set = dataclasses.field(default_factory=set)
     keys_compiled: set = dataclasses.field(default_factory=set)
     last_key: tuple = ()
+    # (program, static-shape key) -> the placement each argument leaf
+    # had when the program was first lowered for that key
+    # (:meth:`CompileLedger._on_lowering`)
+    lowered: dict = dataclasses.field(default_factory=dict)
+    placement_variants: int = 0
 
     @property
     def variants(self) -> int:
@@ -121,6 +129,20 @@ class CompileLedger:
     ``compile`` trace event per backend compile, with the program's
     ``fun`` name, its ``dur`` and the span open at the time as
     ``parent``: a compile inside a steady job names the step that paid.
+
+    Placement variants.  jax keys a lowering on its arguments' shardings
+    and an UNCOMMITTED argument (a fresh ``jnp.zeros``, a numpy array)
+    lowers with an unspecified one, so a governed program that takes the
+    same avals once uncommitted and once committed (what a program's own
+    output is, when any of its inputs was) is lowered, compiled and
+    cached TWICE: same jaxpr, two executables (PERF.md, PR 31: the
+    grouped cycle block did, 115 MB and minutes of compile each).  The
+    listener sees every lowering: one of a program it has lowered before
+    under the same entry, for the same shapes with another placement,
+    counts in ``compile.placement_variants``, and the ``compile`` event
+    of its executable carries ``variant="placement"`` and ``leaves``,
+    the paths of the arguments that differ.  ``compile.block_programs``
+    counts the executables of :data:`BLOCK_ENTRY`, built or loaded.
     """
 
     UNGOVERNED = "(ungoverned)"
@@ -189,24 +211,58 @@ class CompileLedger:
         duration = float(duration)
         # lint: ok(R6) — series is one of the three literals above
         REGISTRY.counter(series).inc(self._exclusive(duration))
+        stack = getattr(self._tls, "stack", None)
+        fun = str(kw.get("fun_name", "?"))
+        if event == LOWER_EVENT and stack:
+            self._on_lowering(stack[-1], fun)
         if event != BACKEND_COMPILE_EVENT:
             return
         from ..obs import trace as otrace
         REGISTRY.counter("compile.backend_n").inc()
-        otrace.event("compile", fun=str(kw.get("fun_name", "?")),
-                     dur=round(duration, 6))
-        stack = getattr(self._tls, "stack", None)
-        name = stack[-1][0] if stack else self.UNGOVERNED
+        variant = {}
+        if stack and stack[-1].variant and stack[-1].variant[0] == fun:
+            variant = {"variant": "placement",
+                       "leaves": stack[-1].variant[1]}
+            stack[-1].variant = None
+        otrace.event("compile", fun=fun, dur=round(duration, 6), **variant)
+        name = stack[-1].name if stack else self.UNGOVERNED
+        if name == BLOCK_ENTRY:
+            REGISTRY.counter("compile.block_programs").inc()
         with self._lock:
             e = self._entries.setdefault(name, EntryStats())
             e.compiles += 1
             e.compile_secs += float(duration)
             if stack:
-                e.keys_compiled.add(stack[-1][1])
+                e.keys_compiled.add(stack[-1].key)
+
+    def _on_lowering(self, scope: "_TrackScope", fun: str) -> None:
+        """A program was lowered inside the governed call ``scope``: if
+        the entry lowered ``fun`` for this static-shape key before and
+        the leaves' placement differs, this lowering exists for the
+        placement alone."""
+        places = _placement(scope.call)
+        with self._lock:
+            e = self._entries.setdefault(scope.name, EntryStats())
+            first = e.lowered.setdefault((fun, scope.key), places)
+            differ = [i for i, (a, b) in enumerate(zip(first, places))
+                      if a != b]
+            if not differ:
+                return
+            e.placement_variants += 1
+        import jax
+        paths = jax.tree_util.tree_flatten_with_path(scope.call)[0]
+        scope.variant = (fun, [
+            f"{jax.tree_util.keystr(paths[i][0])}: "
+            f"{first[i] or 'uncommitted'} -> {places[i] or 'uncommitted'}"
+            for i in differ])
+        from ..obs.metrics import REGISTRY
+        REGISTRY.counter("compile.placement_variants").inc()
 
     # -- call tracking ------------------------------------------------------
-    def track(self, name: str, key: tuple) -> "_TrackScope":
-        return _TrackScope(self, name, key)
+    def track(self, name: str, key: tuple, call=None) -> "_TrackScope":
+        """``call``: the call's ``(args, kwargs)``, read only if a
+        program is lowered inside the scope (:meth:`_on_lowering`)."""
+        return _TrackScope(self, name, key, call)
 
     # -- reporting ----------------------------------------------------------
     def snapshot(self) -> dict:
@@ -223,6 +279,7 @@ class CompileLedger:
                     "compile_s": round(e.compile_secs, 3),
                     "last_shapes": repr(e.last_key) if e.last_key else "",
                     "budget": e.budget,
+                    "placement_variants": e.placement_variants,
                 }
             return out
 
@@ -258,6 +315,8 @@ class CompileLedger:
                 e.keys_seen.clear()
                 e.keys_compiled.clear()
                 e.last_key = ()
+                e.lowered.clear()
+                e.placement_variants = 0
 
 
 class _TrackScope:
@@ -265,23 +324,28 @@ class _TrackScope:
     governed entry (one instance per call — the steady-state loop calls
     governed entries every iteration, so no per-call class creation)."""
 
-    __slots__ = ("_ledger", "_name", "_key")
+    __slots__ = ("_ledger", "name", "key", "call", "variant")
 
-    def __init__(self, ledger: CompileLedger, name: str, key: tuple):
+    def __init__(self, ledger: CompileLedger, name: str, key: tuple,
+                 call=None):
         self._ledger = ledger
-        self._name = name
-        self._key = key
+        self.name = name
+        self.key = key
+        self.call = call
+        # (program, differing leaves) of a placement-only lowering whose
+        # executable's ``compile`` event is still to come
+        self.variant = None
 
     def __enter__(self):
         led = self._ledger
         if not hasattr(led._tls, "stack"):
             led._tls.stack = []
-        led._tls.stack.append((self._name, self._key))
+        led._tls.stack.append(self)
         with led._lock:
-            e = led._entries.setdefault(self._name, EntryStats())
+            e = led._entries.setdefault(self.name, EntryStats())
             e.calls += 1
-            e.keys_seen.add(self._key)
-            e.last_key = self._key
+            e.keys_seen.add(self.key)
+            e.last_key = self.key
         return self
 
     def __exit__(self, *exc):
@@ -311,6 +375,17 @@ def _static_key(args, kwargs) -> tuple:
     return tuple(parts)
 
 
+def _placement(call) -> tuple:
+    """The placement jax keys a lowering on, leaf by leaf of a call's
+    ``(args, kwargs)``: the sharding of a committed array, and "" for
+    everything that lowers unspecified (an uncommitted array, numpy, a
+    Python scalar)."""
+    import jax
+    return tuple(str(leaf.sharding)
+                 if getattr(leaf, "_committed", False) else ""
+                 for leaf in jax.tree_util.tree_leaves(call))
+
+
 def governed(name: str, budget: int | None = None, key_fn=None):
     """Register a (usually jitted) entry point with the compile ledger.
 
@@ -328,7 +403,7 @@ def governed(name: str, budget: int | None = None, key_fn=None):
         def wrapper(*args, **kwargs):
             key = key_fn(*args, **kwargs) if key_fn is not None \
                 else _static_key(args, kwargs)
-            with LEDGER.track(name, key):
+            with LEDGER.track(name, key, (args, kwargs)):
                 return fn(*args, **kwargs)
 
         wrapper.__wrapped__ = fn
