@@ -262,15 +262,15 @@ def group_block_kernels() -> dict:
     stacked, met_s = split_to_shards(
         m, jnp.ones(m.capP, m.vert.dtype), np.zeros(len(tet), np.int32), 1)
     args = (stacked, met_s, jnp.int32(0), jnp.ones(1, bool),
-            jnp.asarray(True), jnp.asarray(False),
+            jnp.asarray(False),
             topo_init(stacked.tet.shape[1], stack=1),
-            jnp.ones(1, bool), jnp.ones(1, bool))
+            jnp.asarray(True), jnp.asarray(True))
     leaves, treedef = jax.tree_util.tree_flatten(args)
     key = LEDGER._entries["groups.adapt_block"].last_key
     if len(key) != len(leaves):
         fail("group block argument structure changed under chip_smoke")
     sds = [jax.ShapeDtypeStruct(shape, np.dtype(dt)) for shape, dt in key]
-    # one program per block length holds every kernel of the cycle
+    # the one block program holds every kernel of the cycle
     fn = next(iter(groups._GROUP_BLOCK_CACHE.values())).__wrapped__
     txt = fn.lower(*jax.tree_util.tree_unflatten(treedef, sds)).as_text()
     calls = re.findall(r'custom_call @tpu_custom_call\(.*?kernel_name = '

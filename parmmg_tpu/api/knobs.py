@@ -57,10 +57,6 @@ KNOBS: dict[str, Knob] = {
         "scatters on a geo-bucketed donor band instead of full [capT] "
         "width, bit-identical by the band coverage proof "
         "(ops/collapse.py); 0 = always full width"),
-    "PARMMG_CYCLE_BLOCK": Knob(
-        "int", "",
-        "cycles fused per compiled adapt block (ops/adapt.py); "
-        "empty = 1"),
     "PARMMG_DEADLINE_DISPATCH_S": Knob(
         "float", "0",
         "watchdog deadline on each grouped chunk dispatch/drain "
@@ -163,10 +159,6 @@ KNOBS: dict[str, Knob] = {
         "flag", "",
         "1 = raise on any hot-path process_allgather instead of only "
         "metering it (mh.hot_allgather_bytes tripwire)"),
-    "PARMMG_NARROW_DIV": Knob(
-        "int", "",
-        "narrow-row budget divisor override (ops/active.py); empty = "
-        "tuned default"),
     "PARMMG_PALLAS_SCORE": Knob(
         "flag", "1",
         "Pallas candidate-scoring kernels for the split/collapse/swap "
@@ -246,13 +238,6 @@ KNOBS: dict[str, Knob] = {
         "float", "0",
         "serve driver: per-request wall-clock timeout; the slot is "
         "reclaimed (0 = off)"),
-    "PARMMG_SMOOTH_CADENCE": Knob(
-        "flag", "1",
-        "quality-triggered smoothing cadence: skip smooth_wave on a "
-        "cycle whose topology counts are zero and whose previous "
-        "smoothing moved nothing — an exact fixed point "
-        "(ops/adapt.py); threaded as a traced scalar so toggling "
-        "mints zero compile families; 0 = smooth every cycle"),
     "PARMMG_SOAK_RUNS": Knob(
         "int", "8",
         "scripts/chaos_soak.py default campaign length (seeded runs "

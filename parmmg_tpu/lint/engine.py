@@ -334,7 +334,6 @@ class LintContext:
 # running
 # ---------------------------------------------------------------------------
 SCAN_ROOTS = ("parmmg_tpu", "scripts", "tests")
-SCAN_SINGLES = ("bench.py",)
 
 
 def collect_files(root: str) -> dict[str, SourceFile]:
@@ -352,11 +351,6 @@ def collect_files(root: str) -> dict[str, SourceFile]:
                 rel = os.path.relpath(p, root).replace(os.sep, "/")
                 with open(p, encoding="utf-8") as f:
                     files[rel] = SourceFile(rel, f.read())
-    for single in SCAN_SINGLES:
-        p = os.path.join(root, single)
-        if os.path.exists(p):
-            with open(p, encoding="utf-8") as f:
-                files[single] = SourceFile(single, f.read())
     return files
 
 

@@ -213,7 +213,7 @@ def _enclosing_stmt(fn_node, target):
 @rule("R5")
 def check_r5(ctx) -> list:
     out = []
-    for sf in ctx.iter(("parmmg_tpu/", "scripts/", "bench.py"),
+    for sf in ctx.iter(("parmmg_tpu/", "scripts/"),
                        exclude=(_SHIM_REL,)):
         if sf.tree is None:
             continue

@@ -29,7 +29,7 @@ from .engine import (KNOBS_REL, Violation, dotted, rule, str_const,
 _KNOB_RE = re.compile(r"^PARMMG_[A-Z0-9_]+$")
 _KNOB_TOKEN_RE = re.compile(r"PARMMG_[A-Z0-9_]+")
 
-_SCOPE = ("parmmg_tpu/", "scripts/", "tests/", "bench.py")
+_SCOPE = ("parmmg_tpu/", "scripts/", "tests/")
 
 _ENV_GET_ATTRS = ("get", "setdefault", "pop", "__getitem__")
 

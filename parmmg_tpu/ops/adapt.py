@@ -62,7 +62,7 @@ class AdaptStats:
     # grouped paths): chunked group-block dispatches executed / skipped
     # by the scheduler, group-block slots skipped, and the free-form
     # extra dict (active-group trajectories + pipeline segment seconds)
-    # that bench.py / scripts/scale_big.py surface in their artifacts
+    # that scripts/scale_big.py surfaces in its artifacts
     group_dispatches: int = 0
     group_dispatches_saved: int = 0
     groups_skipped: int = 0
@@ -119,13 +119,10 @@ class AdaptStats:
 def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
                      do_swap: bool = True, do_smooth: bool = True,
                      smooth_waves: int = 1, do_insert: bool = True,
-                     final_rebuild: bool = True,
                      hausd: float | None = None,
                      budget_div: int = 8,
-                     et0=None, vact=None, submesh: bool = False,
-                     wide: bool = False, wwin=None,
                      prescreen: bool = True, active=None,
-                     smooth_idle=None, topo=None, incr=None):
+                     topo=None, incr=None):
     """One adaptation cycle: split -> collapse -> [swap] -> [smooth].
 
     Pure jittable function (jitted wrapper below) — also the compile-check
@@ -136,9 +133,8 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     (face pairing) is the ONLY adja reader — split/collapse/edge-swaps/
     smooth run off the edge table or tets alone (collapse transfers dying
     tets' face tags with a keyed face join instead of the old adja
-    lookup).  ``final_rebuild`` restores the every-returned-mesh-has-
-    valid-adja contract for external callers; fused blocks skip it
-    between cycles.
+    lookup).  The rebuild at the end of the cycle keeps the
+    every-returned-mesh-has-valid-adja contract.
 
     ``do_swap`` and ``prescreen`` are Python bools where the caller
     wants them compiled in or out (the jitted ``adapt_cycle`` below), or
@@ -146,11 +142,6 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     the split prescreen under a mask, so one compiled program serves
     every (swap, prescreen) cycle class — the grouped and SPMD cycle
     blocks, where each class would otherwise be a compile of its own.
-
-    ``vact``/``submesh``: active-scoped narrow mode (ops/active.py) —
-    candidates are restricted to active vertices and the adjacency
-    rebuilds skip boundary tagging (a sub-mesh's unmatched faces are
-    cut faces, not surface).
 
     Returns (mesh, met, counts) with ``counts`` = int32
     [nsplit, ncollapse, nswap, nmoved, overflow, live_tets, deferred,
@@ -162,13 +153,10 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     costs a full round trip on a remote-device transport, and an *eager*
     count op on the host would fight the donated input buffers).
     ``deferred`` = top-K budget cuts of viable candidates, encoded as
-    2 bits: bit 0 = an INSERTION wave (split/collapse) deferred —
-    sizing-critical, the narrow path escalates to full-width on it;
-    bit 1 = a SWAP wave deferred — swap nomination pools routinely
-    exceed the sub top-K and their backlog is covered by the periodic
-    full refresh + polish, so narrow does not escalate on it
-    (ops/active.py).  ``narrow_abort`` is always 0 on this full-width
-    path.
+    2 bits: bit 0 = an INSERTION wave (split/collapse) deferred,
+    bit 1 = a SWAP wave deferred.  ``narrow_abort`` is always 0.
+    Neither column has a reader (ROADMAP D4); the row keeps its layout
+    because ``SURF_COLS`` and ``DIRTY_COL`` index it.
 
     ``active``: optional traced scalar bool — the device-resident
     quiet-mask hook of the grouped paths (parallel/sched.py).  When
@@ -181,22 +169,6 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     a zero-op state is byte-identity, so returning the input IS the
     recompute).  ``active=None`` compiles the unconditional body — the
     whole-mesh path is untouched.
-
-    ``smooth_idle``: optional traced scalar bool — the smoothing-cadence
-    carry (PARMMG_SMOOTH_CADENCE, parallel/sched.cadence_enabled): True
-    means the PREVIOUS cycle was a full no-op (zero topo ops AND zero
-    smoothing moves).  When also THIS cycle's topo counts are zero, the
-    smoothing wave is ``lax.cond``-skipped — provably an identity:
-    smooth_wave's proposals are wave-independent and its claim
-    resolution cannot rob the globally best improving vertex, so
-    nmoved == 0 ⟺ no vertex improves ⟺ the wave is the identity map,
-    and the emptiness of the improving set is wave-rotation-invariant
-    (ops/smooth.py) — re-running it on the byte-identical mesh of a
-    topo-quiet successor cycle would again move nothing.  The skipped
-    wave truthfully reports nmoved = 0, so the carry chain stays exact.
-    Like ``active``, it is a TRACED argument: toggling the cadence
-    never mints a new compile family.  Only used on the full-width path
-    (callers pass None alongside vact/wwin restrictions).
 
     ``topo``/``incr``: the incremental topology engine (ops/topo_incr).
     ``topo`` is a TopoState carrying the retained edge/face sorts and
@@ -222,11 +194,8 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
             out = adapt_cycle_impl(
                 m, k, wave, do_swap=do_swap, do_smooth=do_smooth,
                 smooth_waves=smooth_waves, do_insert=do_insert,
-                final_rebuild=final_rebuild, hausd=hausd,
-                budget_div=budget_div, et0=et0, vact=vact,
-                submesh=submesh, wide=wide, wwin=wwin,
-                prescreen=prescreen, smooth_idle=smooth_idle,
-                topo=tp, incr=incr)
+                hausd=hausd, budget_div=budget_div,
+                prescreen=prescreen, topo=tp, incr=incr)
             return out if tp is not None else out + (tp,)
 
         def _skip(ops):
@@ -253,18 +222,13 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
         # candidates whose table rows the split made stale
         from .edges import unique_edges, edge_lengths
         # slim table: split/collapse never read shell3 (only the swap
-        # kernels, which build their own) — skips a [6*capT] scatter.
-        # ``et0``: a caller-provided table of THIS mesh (the fused block
-        # reuses the previous cycle's table after a topology-quiet
-        # cycle — smoothing only moves vertices, so the table is
-        # provably identical; metric lengths ALWAYS recompute).
-        if et0 is None:
-            if topo is not None:
-                et0, topo = incr_unique_edges(mesh, topo, incr,
-                                              shell_slots=0)
-            else:
-                et0 = unique_edges(mesh, shell_slots=0)
-        lens0 = edge_lengths(mesh, et0, met)
+        # kernels, which build their own) — skips a [6*capT] scatter
+        if topo is not None:
+            et, topo = incr_unique_edges(mesh, topo, incr,
+                                         shell_slots=0)
+        else:
+            et = unique_edges(mesh, shell_slots=0)
+        lens = edge_lengths(mesh, et, met)
         # ridge tangents once per cycle too (same sharing rationale;
         # collapse only consults non-stale candidates, whose tangent
         # fields are identical pre/post split)
@@ -275,16 +239,15 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
         if hausd is not None:
             from .analysis import boundary_vertex_normals, \
                 ridge_vertex_tangents
-            vtan0 = ridge_vertex_tangents(mesh, et=et0)
+            vtan0 = ridge_vertex_tangents(mesh, et=et)
             vn0 = boundary_vertex_normals(mesh)
-        # wide convergence-verification cycles (and the drivers' polish
-        # cycles, via ``prescreen=False``) disable the approximate
-        # nomination prescreen so shells it over-vetoed get one exact
+        # ``prescreen=False`` (adapt_mesh's wide convergence check, the
+        # drivers' polish cycles) disables the approximate nomination
+        # prescreen so shells it over-vetoed get one exact
         # re-evaluation before convergence is accepted (split.py)
         res = split_wave(mesh, met, hausd=hausd, budget_div=budget_div,
-                         et=et0, lens=lens0, vtan=vtan0, vn=vn0,
-                         vact=vact,
-                         prescreen=False if wide else prescreen)
+                         et=et, lens=lens, vtan=vtan0, vn=vn0,
+                         prescreen=prescreen)
         if topo is not None:
             topo = mark_dirty(topo, mesh.tet, mesh.tmask, res.mesh)
         mesh, met = res.mesh, res.met
@@ -294,9 +257,9 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
 
         col = collapse_wave(mesh, met, hausd=hausd,
                             budget_div=budget_div,
-                            et=et0, lens=lens0,
+                            et=et, lens=lens,
                             stale_tets=res.modified, vtan=vtan0,
-                            vn=vn0, vact=vact, wwin=wwin)
+                            vn=vn0)
         if topo is not None:
             # boundary_edge_tags below touches only tags, which the
             # retained sorts never carry — marking against col.mesh is
@@ -325,30 +288,26 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
         mesh, topo = ops
         from .swap import swap_facesort_enabled
         sew = swap_edges_wave(mesh, met, hausd=hausd,
-                              budget_div=budget_div,
-                              vact=vact, wwin=wwin)  # 3-2 + 2-2
+                              budget_div=budget_div)  # 3-2 + 2-2
         if topo is not None:
             topo = mark_dirty(topo, mesh.tet, mesh.tmask, sew.mesh)
         if swap_facesort_enabled():
             # swap23 pairs directly off the face sort (bit-identical to
             # the adja path — ops/swap._pair_fields_facesort); the
             # [capT,4] adja materialization + compare leaves the cycle
-            # interior, final_rebuild restores the adja contract.
+            # interior, the rebuild at the end restores the adja contract.
             # (This mid-cycle face sort is NOT band-maintained — scope
             # cut: the facesort swap23 derives its pairing internally.)
             s23 = swap23_wave(sew.mesh, met, budget_div=budget_div,
-                              wwin=wwin, facesort=True,
-                              set_bdy_tags=not submesh)
+                              facesort=True)
             pre = sew.mesh
         else:
-            # consumed by swap23 (adja-only on a sub-mesh: cut faces are
-            # unmatched without being surface)
+            # consumed by swap23
             if topo is not None:
-                mesh, topo = incr_build_adjacency(
-                    sew.mesh, topo, incr, set_bdy_tags=not submesh)
+                mesh, topo = incr_build_adjacency(sew.mesh, topo, incr)
             else:
-                mesh = build_adjacency(sew.mesh, set_bdy_tags=not submesh)
-            s23 = swap23_wave(mesh, met, budget_div=budget_div, wwin=wwin)
+                mesh = build_adjacency(sew.mesh)
+            s23 = swap23_wave(mesh, met, budget_div=budget_div)
             pre = mesh
         if topo is not None:
             topo = mark_dirty(topo, pre.tet, pre.tmask, s23.mesh)
@@ -367,37 +326,16 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
 
     nmoved = jnp.zeros((2,), jnp.int32)      # [all, of them surface]
     if do_smooth:
-        # in windowed mode (wwin, the ops/active.py rotation) smoothing
-        # restricts to the window; in narrow mode vact (the worklist
-        # closure, itself window-derived) is the restriction
-        sv = vact if vact is not None else wwin
+        for w in range(smooth_waves):
+            sm = smooth_wave(mesh, met, wave=wave * smooth_waves + w,
+                             hausd=hausd)
+            mesh = sm.mesh
+            nmoved = nmoved + jnp.stack([sm.nmoved, sm.nbdy])
 
-        def _smooth(m):
-            nm = jnp.zeros((2,), jnp.int32)
-            for w in range(smooth_waves):
-                sm = smooth_wave(m, met, wave=wave * smooth_waves + w,
-                                 vact=sv, hausd=hausd)
-                m = sm.mesh
-                nm = nm + jnp.stack([sm.nmoved, sm.nbdy])
-            return m, nm
-
-        if smooth_idle is not None and sv is None:
-            # smoothing cadence (see docstring): skip is exact only on
-            # the full-width path — a window rotation changes the
-            # candidate set between cycles, so sv disables the gate
-            skip = smooth_idle & ((nsplit + ncol + nswap) == 0)
-            mesh, nmoved = jax.lax.cond(
-                skip, lambda m: (m, jnp.zeros((2,), jnp.int32)),
-                _smooth, mesh)
-        else:
-            mesh, nmoved = _smooth(mesh)
-
-    if final_rebuild:
-        if topo is not None:
-            mesh, topo = incr_build_adjacency(mesh, topo, incr,
-                                              set_bdy_tags=not submesh)
-        else:
-            mesh = build_adjacency(mesh, set_bdy_tags=not submesh)
+    if topo is not None:
+        mesh, topo = incr_build_adjacency(mesh, topo, incr)
+    else:
+        mesh = build_adjacency(mesh)
 
     row = [nsplit, ncol, nswap, nmoved[0],
            overflow.astype(jnp.int32),
@@ -416,8 +354,8 @@ from ..utils.compilecache import governed as _governed  # noqa: E402
 
 adapt_cycle = _governed("adapt.cycle")(
     partial(jax.jit, static_argnames=(
-        "do_swap", "do_smooth", "smooth_waves", "do_insert", "final_rebuild",
-        "hausd", "budget_div", "submesh", "wide", "prescreen"),
+        "do_swap", "do_smooth", "smooth_waves", "do_insert",
+        "hausd", "budget_div", "prescreen"),
         donate_argnums=(0, 1))(adapt_cycle_impl))
 
 
@@ -441,115 +379,6 @@ def fem_pass_impl(mesh: Mesh, met: jax.Array):
 
 
 fem_pass = partial(jax.jit, donate_argnums=(0, 1))(fem_pass_impl)
-
-
-def adapt_cycles_fused_impl(mesh: Mesh, met: jax.Array, wave0: jax.Array,
-                            n_cycles: int = 3, swap_every: int = 3,
-                            swap_offset: int = 0,
-                            hausd: float | None = None,
-                            swap_flags: tuple | None = None,
-                            do_smooth: bool = True,
-                            do_insert: bool = True,
-                            budget_div: int = 8,
-                            cadence=None, topo=None, incr=None):
-    """``n_cycles`` adaptation cycles in ONE jitted program.
-
-    On a remote-attached TPU every dispatch pays a transport round trip
-    (and the per-cycle counter pull is a host sync); fusing a block of
-    cycles amortizes both and gives XLA one big program to schedule.  The
-    swap cadence is compiled in (cycle c swaps iff c % swap_every ==
-    swap_every-1, matching the host driver — or pass ``swap_flags``, an
-    explicit per-cycle tuple overriding the cadence, which also sets
-    n_cycles); counters come back stacked [n_cycles, 6] and are read
-    with a single transfer.
-
-    Overflow safety: a capacity overflow inside the block only truncates
-    that cycle's winner set (split_wave drops the lowest-priority winners
-    that don't fit); the flag is reported per cycle so the host can regrow
-    and rerun as usual.
-
-    ``cadence``: optional traced scalar bool (PARMMG_SMOOTH_CADENCE) —
-    threads the smoothing-cadence carry across the block's cycles: after
-    a full no-op cycle (zero topo ops, zero moves), the next topo-quiet
-    cycle's smoothing wave is skipped as a proven identity (see
-    adapt_cycle_impl's ``smooth_idle``).  The carry is derived on-device
-    from each cycle's counts, so the cadence costs no extra transfer.
-
-    ``topo``/``incr``: thread the incremental topology engine through
-    the block (see adapt_cycle_impl) — the retained table + band state
-    is the carry, superseding the all-or-nothing et cache below (the
-    engine's nd==0 branch reuses the retained sort wholesale, covering
-    the same topo-quiet case AND extending it to adjacency).  Returns a
-    4-tuple ``(mesh, met, counts [n,9], topo)`` when threaded.
-    """
-    if swap_flags is None:
-        swap_flags = tuple(
-            (c + swap_offset) % swap_every == swap_every - 1
-            for c in range(n_cycles))
-    counts_all = []
-    # edge-table cache across the block: after a cycle with zero
-    # topological changes (splits/collapses/swaps), the next cycle's
-    # table rebuild is lax.cond-skipped — at steady state (smoothing
-    # churn only) this removes the largest remaining per-cycle item
-    from .edges import unique_edges
-    prev_et = None
-    prev_ok = None
-    sm_idle = None if cadence is None else jnp.zeros((), bool)
-    for c, dosw in enumerate(swap_flags):
-        et_c = None
-        if do_insert and topo is None:
-            if prev_et is None:
-                et_c = unique_edges(mesh, shell_slots=0)
-            else:
-                pe = prev_et
-
-                def _reuse(_, pe=pe):
-                    return pe
-
-                def _rebuild(_, m=mesh):
-                    return unique_edges(m, shell_slots=0)
-                et_c = jax.lax.cond(prev_ok, _reuse, _rebuild, None)
-        out = adapt_cycle_impl(
-            mesh, met, wave0 + c, do_swap=dosw,
-            do_smooth=do_smooth, do_insert=do_insert,
-            final_rebuild=(c == len(swap_flags) - 1), hausd=hausd,
-            budget_div=budget_div, et0=et_c,
-            smooth_idle=None if sm_idle is None else (cadence & sm_idle),
-            topo=topo, incr=incr)
-        if topo is None:
-            mesh, met, counts = out
-        else:
-            mesh, met, counts, topo = out
-        counts_all.append(counts)
-        if sm_idle is not None:
-            sm_idle = ((counts[0] + counts[1] + counts[2]) == 0) & \
-                (counts[3] == 0)
-        if do_insert and topo is None:
-            prev_et = et_c
-            prev_ok = (counts[0] + counts[1] + counts[2]) == 0
-    if topo is None:
-        return mesh, met, jnp.stack(counts_all)
-    return mesh, met, jnp.stack(counts_all), topo
-
-
-adapt_cycles_fused = _governed("adapt.cycles_fused")(
-    partial(jax.jit, static_argnames=(
-        "n_cycles", "swap_every", "swap_offset", "hausd", "swap_flags",
-        "do_smooth", "do_insert", "budget_div"),
-        donate_argnums=(0, 1))(adapt_cycles_fused_impl))
-
-
-def default_cycle_block() -> int:
-    """Fused cycles per dispatch for the production drivers: 1 on every
-    backend.  A dispatch to a local device costs microseconds against a
-    cycle of tens of milliseconds or more, while every fused cycle
-    multiplies the block's compile time; fusing buys nothing until a
-    chip trace shows the dispatch gap.  Convergence overshoot inside a
-    block is bounded by the zero-candidate lax.cond skips.  Override
-    with PARMMG_CYCLE_BLOCK."""
-    import os
-    v = os.environ.get("PARMMG_CYCLE_BLOCK", "")
-    return max(1, int(v)) if v else 1
 
 
 def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
@@ -657,8 +486,7 @@ def adapt_mesh(mesh: Mesh, met: jax.Array, max_cycles: int = 50,
                swap_every: int = 3, noinsert: bool = False,
                noswap: bool = False, nomove: bool = False,
                angedg: float | None = None,
-               hausd: float | None = None,
-               cycle_block: int | None = None) -> tuple:
+               hausd: float | None = None) -> tuple:
     """Host driver: run cycles until no topological change, manage capacity.
 
     Swap waves cost about as much as split+collapse+smooth combined (they
@@ -667,9 +495,7 @@ def adapt_mesh(mesh: Mesh, met: jax.Array, max_cycles: int = 50,
     between sizing passes rather than swapping continuously — and always
     once the mesh is near convergence.
 
-    Cycles are dispatched in fused blocks of ``cycle_block`` (default 1,
-    see default_cycle_block): one dispatch and one counter pull per
-    block.
+    One dispatch and one counter pull per cycle.
 
     Returns (mesh, met, AdaptStats).
     """
@@ -679,120 +505,78 @@ def adapt_mesh(mesh: Mesh, met: jax.Array, max_cycles: int = 50,
     # honor the caller's ridge-detection threshold (-ar / -nr): a default
     # re-analysis here would re-introduce MG_GEO tags the user disabled
     mesh = analyze_mesh(mesh, ANGEDG if angedg is None else angedg).mesh
-    if cycle_block is None:
-        cycle_block = default_cycle_block()
     quiet = 0
     wide_check = False
     converged = False
     cycle = 0
-    # worklist state threaded through auto blocks (ops/active.py):
-    # zeros/False = no worklist yet, first cycles run full-width
-    dirty = None                 # [capP] bool device array
-    okflag = False
     while cycle < max_cycles and not converged:
-        # capacity management before the wave block (each block can add
-        # up to block * 2*capT/8 tets; the overflow flag + regrow below
-        # catches a mid-block shortfall, winners are only deferred)
+        # capacity management before the cycle (a cycle can add up to
+        # 2*capT/8 tets; the overflow flag + regrow below catches a
+        # shortfall, winners are only deferred)
         n_p, n_t = mesh.np_counts()
         if n_p > headroom * mesh.capP or n_t > headroom * mesh.capT:
             mesh, met = grow_mesh_met(mesh, met,
                                       max(mesh.capP, int(2 * n_p)),
                                       max(mesh.capT, int(2 * n_t)))
             stats.regrows += 1
-            dirty = None        # regrow permuted slots; footprint stale
-            okflag = False
 
         was_wide = wide_check
-        # single-cycle dispatch when quiet: the quiet>0-forces-swap rule
-        # (convergence confirmation) is per-cycle state the compiled
-        # block cadence cannot see
-        if wide_check or cycle_block == 1 or quiet > 0:
-            do_swap = ((cycle % swap_every == swap_every - 1)
-                       or quiet > 0) and not noswap
-            mesh, met, counts = adapt_cycle(
-                mesh, met, jnp.asarray(cycle, jnp.int32), do_swap=do_swap,
-                do_smooth=not nomove, do_insert=not noinsert, hausd=hausd,
-                budget_div=2 if wide_check else 8, wide=wide_check)
-            rows = [(do_swap, np.asarray(counts))]
-            surf_rows = True
-            dirty = None        # full wide pass: worklist invalid
-            okflag = False
-        else:
-            # self-width-selecting fused block (ops/active.py): each
-            # cycle runs active-scoped when its worklist is valid and
-            # fits, full-width otherwise — one dispatch either way
-            from .active import adapt_cycles_auto
-            nblk = min(cycle_block, max_cycles - cycle)
-            flags = tuple(
-                (((cycle + c) % swap_every == swap_every - 1)
-                 and not noswap) for c in range(nblk))
-            if dirty is None:
-                dirty = jnp.zeros(mesh.capP, bool)
-                okflag = False
-            mesh, met, dirty, okflag, counts_all = adapt_cycles_auto(
-                mesh, met, dirty, jnp.asarray(bool(okflag)),
-                jnp.asarray(cycle, jnp.int32),
-                swap_flags=flags, hausd=hausd,
-                do_smooth=not nomove, do_insert=not noinsert)
-            ca = np.asarray(counts_all)
-            rows = [(flags[c], ca[c]) for c in range(nblk)]
-            surf_rows = False   # the auto row has columns of its own
-
-        ovf_any = False
-        for do_swap, cnt in rows:
-            ns, nc, nw, nm, ovf = (int(v) for v in cnt[:5])
-            if surf_rows:
-                stats.add_surface(**{k: int(cnt[col])
-                                     for k, col in SURF_COLS.items()})
-            stats.nsplit += ns
-            stats.ncollapse += nc
-            stats.nswap += nw
-            stats.nmoved += nm
-            stats.cycles += 1
-            otrace.log(3, f"  cycle {cycle:3d}: split {ns:6d} "
-                          f"collapse {nc:6d} swap {nw:6d} move {nm:6d}",
-                       verbose=verbose)
-            cycle += 1
-            if ovf:
-                # a capacity-truncated cycle cannot witness convergence
-                # (its winner set was cut, not exhausted) — reset the
-                # quiet state and force the regrow below
-                ovf_any = True
-                quiet = 0
-                wide_check = False
-                converged = False
-                continue
-            if converged:
-                continue        # later block rows: stats only
-            if ns == 0 and nc == 0 and (noswap or (nw == 0 and do_swap)):
-                quiet += 1
-                if quiet >= 2 or nm == 0 or nomove:
-                    if was_wide or (noinsert and noswap):
-                        # (with insertions AND swaps disabled no budget-
-                        # governed op runs — a wide cycle cannot differ)
-                        converged = True
-                        continue
-                    # Verify convergence at a wider candidate budget
-                    # before accepting it: with top-K compaction,
-                    # candidates that permanently fail the
-                    # post-compaction geometric gates (worst shell
-                    # quality = always selected) can pin every budget
-                    # slot while viable candidates ranked past K are
-                    # never attempted — counts==0 would then be
-                    # starvation, not convergence.
-                    wide_check = True
-                    quiet = 1
-            elif ns == 0 and nc == 0 and not do_swap and not noswap:
-                quiet = max(quiet, 1)    # trigger a swap-inclusive cycle
-            else:
-                quiet = 0
-                wide_check = False
-        if ovf_any:
+        # quiet > 0 forces a swap-inclusive cycle (convergence
+        # confirmation); the wide check runs at a quarter of the
+        # divisor with the split prescreen off
+        do_swap = ((cycle % swap_every == swap_every - 1)
+                   or quiet > 0) and not noswap
+        mesh, met, counts = adapt_cycle(
+            mesh, met, jnp.asarray(cycle, jnp.int32), do_swap=do_swap,
+            do_smooth=not nomove, do_insert=not noinsert, hausd=hausd,
+            budget_div=2 if wide_check else 8,
+            prescreen=not wide_check)
+        cnt = np.asarray(counts)
+        ns, nc, nw, nm, ovf = (int(v) for v in cnt[:5])
+        stats.add_surface(**{k: int(cnt[col])
+                             for k, col in SURF_COLS.items()})
+        stats.nsplit += ns
+        stats.ncollapse += nc
+        stats.nswap += nw
+        stats.nmoved += nm
+        stats.cycles += 1
+        otrace.log(3, f"  cycle {cycle:3d}: split {ns:6d} "
+                      f"collapse {nc:6d} swap {nw:6d} move {nm:6d}",
+                   verbose=verbose)
+        cycle += 1
+        if ovf:
+            # a capacity-truncated cycle cannot witness convergence
+            # (its winner set was cut, not exhausted) — reset the
+            # quiet state and regrow
+            quiet = 0
+            wide_check = False
             mesh, met = grow_mesh_met(mesh, met, 2 * mesh.capP,
                                       2 * mesh.capT)
             stats.regrows += 1
-            okflag = False
-            dirty = None
+            continue
+        if ns == 0 and nc == 0 and (noswap or (nw == 0 and do_swap)):
+            quiet += 1
+            if quiet >= 2 or nm == 0 or nomove:
+                if was_wide or (noinsert and noswap):
+                    # (with insertions AND swaps disabled no budget-
+                    # governed op runs — a wide cycle cannot differ)
+                    converged = True
+                    continue
+                # Verify convergence at a wider candidate budget
+                # before accepting it: with top-K compaction,
+                # candidates that permanently fail the
+                # post-compaction geometric gates (worst shell
+                # quality = always selected) can pin every budget
+                # slot while viable candidates ranked past K are
+                # never attempted — counts==0 would then be
+                # starvation, not convergence.
+                wide_check = True
+                quiet = 1
+        elif ns == 0 and nc == 0 and not do_swap and not noswap:
+            quiet = max(quiet, 1)    # trigger a swap-inclusive cycle
+        else:
+            quiet = 0
+            wide_check = False
 
     # bad-element optimization: the sizing loop leaves slivers whose edge
     # lengths are all in-range; polish until no sliver op applies

@@ -114,25 +114,18 @@ def bdy_tags_from_sort(mesh: Mesh, t, f, matched, valid_s):
     return dataclasses_replace(mesh, ftag=ftag)
 
 
-def build_adjacency(mesh: Mesh, set_bdy_tags: bool = True) -> Mesh:
+def build_adjacency(mesh: Mesh) -> Mesh:
     """Compute ``adja`` and mark unmatched faces as boundary (MG_BDY).
 
     In a conforming mesh every interior face appears exactly twice. After
     sorting face keys, twins are neighbors in sorted order; the pairing is
     scattered back as ``adja[t,f] = 4*t' + f'``.
-
-    ``set_bdy_tags=False`` computes adja only: on an active SUB-mesh
-    (ops/active.py) faces whose twin lies outside the sub-mesh are
-    unmatched without being boundary — tagging them MG_BDY would corrupt
-    the surface, while adja=-1 correctly excludes them from swap23.
     """
     t, f, partner, matched, _ = face_sort(mesh)
-    return adjacency_from_records(mesh, t, f, partner, matched,
-                                  set_bdy_tags=set_bdy_tags)
+    return adjacency_from_records(mesh, t, f, partner, matched)
 
 
-def adjacency_from_records(mesh: Mesh, t, f, partner, matched,
-                           set_bdy_tags: bool = True) -> Mesh:
+def adjacency_from_records(mesh: Mesh, t, f, partner, matched) -> Mesh:
     """``build_adjacency``'s scatter epilogue from face-sort records —
     shared with the incremental path (ops/topo_incr), which feeds it
     band-merged records."""
@@ -147,8 +140,6 @@ def adjacency_from_records(mesh: Mesh, t, f, partner, matched,
                              unique_indices=True)
     adja = jnp.where(mesh.tmask[:, None], adja, -1)
 
-    if not set_bdy_tags:
-        return dataclasses_replace(mesh, adja=adja)
     # boundary faces: valid tet, face has no twin
     is_bdy = (adja < 0) & mesh.tmask[:, None]
     ftag = jnp.where(is_bdy, mesh.ftag | MG_BDY, mesh.ftag)

@@ -45,7 +45,7 @@ class CollapseResult(NamedTuple):
     # lets the caller skip the boundary re-propagation pass entirely
     surface_changed: jax.Array = None
     deferred: jax.Array = None  # scalar bool: candidates exceeded the
-    #                 top-K budget (see ops/active.py worklist invariant)
+    #                 top-K budget; they wait for the next wave
     nhveto: jax.Array = None  # scalar int32: candidates the hausd test
     #                 refused (boundary edges whose surface would move by
     #                 more than hausd); 0 without hausd
@@ -77,9 +77,7 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
                   et=None, lens=None,
                   stale_tets: jax.Array | None = None,
                   vtan: jax.Array | None = None,
-                  vn: jax.Array | None = None,
-                  vact: jax.Array | None = None,
-                  wwin: jax.Array | None = None) -> CollapseResult:
+                  vn: jax.Array | None = None) -> CollapseResult:
     """One independent-set collapse wave.
 
     Normal mode: contract edges shorter than ``lmin`` (Mmg's colver over
@@ -129,20 +127,6 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
         # don't lengthen already-long edges by contracting into them
         short = et.emask & bad_edge & ~frozen_edge & (lens < lmax)
 
-    if vact is not None:
-        # narrow-path restriction (ops/active.py): both endpoints active
-        # — the removed endpoint's whole ball is then in the sub-mesh,
-        # keeping the ball-quality gate below exact
-        short = short & vact[va_f] & vact[vb_f]
-    if wwin is not None:
-        # spatial-window rotation (ops/active.py): collapse candidates
-        # restrict to the current morton window UNCONDITIONALLY — the
-        # steady-state candidate pool exceeds the top-K budget anyway
-        # (the global pass never attempts the backlog), while the
-        # window's share fits the budget, so rotation ATTEMPTS EVERY
-        # candidate within nwin cycles — strictly better coverage, and
-        # the winners' footprints stay spatially compact
-        short = short & wwin[va_f] & wwin[vb_f]
     ta_f, tb_f = mesh.vtag[va_f], mesh.vtag[vb_f]
     rem_b_f = _removable(tb_f, ta_f, et.etag)   # can delete b (keep a)
     rem_a_f = _removable(ta_f, tb_f, et.etag)

@@ -465,10 +465,8 @@ def scatter_argmax2(site: jax.Array, s: jax.Array, t: jax.Array,
 def morton_codes(pts: jax.Array, valid: jax.Array, bits: int = 10):
     """[n] int32 morton (Z-order) codes of 3D points, normalized over
     the bounding box of the ``valid`` rows; ``3*bits <= 30`` so the code
-    stays in int32.  Shared by the smoothing/worklist window rotation
-    (ops/smooth.morton_window_mask) and the device cluster assignment of
-    the graph-balancing probe (parallel/migrate_dev.graph_probe) — one
-    curve definition, one set of bit masks."""
+    stays in int32.  Used by the device cluster assignment of the
+    graph-balancing probe (parallel/migrate_dev.graph_probe)."""
     lo = jnp.min(jnp.where(valid[:, None], pts, jnp.inf), axis=0)
     hi = jnp.max(jnp.where(valid[:, None], pts, -jnp.inf), axis=0)
     u = jnp.clip((pts - lo) / jnp.maximum(hi - lo, 1e-30),

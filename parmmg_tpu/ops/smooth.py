@@ -55,44 +55,9 @@ class SmoothResult(NamedTuple):
     nbdy: jax.Array = None   # of ``nmoved``, the surface vertices
 
 
-def morton_window_mask(vert: jax.Array, vmask: jax.Array, wave,
-                       nwin: int) -> jax.Array:
-    """[capP] bool: vertices of the ``wave % nwin``-th contiguous
-    morton-curve segment.  Smoothing any independent SUBSET per wave is
-    valid (the claim scheme already rotates); choosing spatially
-    COHERENT subsets keeps each cycle's footprint a compact blob, which
-    is what lets the active-scoped narrow path (ops/active.py) hold the
-    worklist small — scattered moves have ~100-tet 2-hop stencils each,
-    a window's moves share theirs.
-
-    Windows are equal-POPULATION segments of the curve, not equal
-    code-space: an adapted mesh concentrates vertices where the metric
-    is fine (the shock slab holds most of the mesh), so code-space
-    windows made per-cycle footprints oscillate severalfold and
-    overflow the narrow row budget (measured 8k-21k active tets at
-    nwin=24; each overflow costs a discarded narrow attempt plus a
-    full-width fallback cycle).  The live-vertex histogram CDF over
-    1024 curve bins equalizes the windows to bin granularity for the
-    cost of one [capP] scatter-add.  Window boundaries therefore DRIFT
-    as the population changes; the bounded-staleness guarantee of the
-    worklist does not rest on stable boundaries but on the periodic
-    full-width refresh cycle (ops/active.py module docstring)."""
-    from .edges import morton_codes
-    code = morton_codes(vert, vmask, bits=5)   # 15-bit morton
-    b = code >> 5                              # 1024 curve bins
-    hist = jnp.zeros(1024, jnp.int32).at[b].add(
-        vmask.astype(jnp.int32), mode="drop")
-    cdf = jnp.cumsum(hist)
-    n_live = jnp.maximum(cdf[-1], 1)
-    rank0 = (cdf - hist)[b]                    # live rank at bin start
-    win = (rank0 * nwin) // n_live             # <= capP * 64 < int31
-    return win == jnp.mod(jnp.asarray(wave, jnp.int32), nwin)
-
-
 def smooth_wave(mesh: Mesh, met: jax.Array, wave: int = 0,
                 relax: float = 1.0,
                 opt_q: float | None = None,
-                vact: jax.Array | None = None,
                 hausd: float | None = None) -> SmoothResult:
     """One smoothing wave; see module docstring.
 
@@ -110,17 +75,14 @@ def smooth_wave(mesh: Mesh, met: jax.Array, wave: int = 0,
     serves this role).  The relaxation cascade and the exact ball
     min-quality gate are unchanged.
 
-    Fixed-point invariant (the smoothing-cadence contract,
-    ops/adapt.adapt_cycle_impl ``smooth_idle``): on the full-width path
-    (``vact is None``) ``nmoved == 0`` iff NO vertex has an accepted
-    improving move — the globally best improving vertex can never lose
-    a claim, so an empty accepted set means the improving set itself is
-    empty, and that emptiness is invariant under the ``wave`` rotation
-    (proposals are wave-independent; ``wave`` only rotates claim
-    tie-breaks among winners).  A zero-move wave is therefore an exact
-    identity on the mesh, and skipping the NEXT wave after a fully
-    quiet cycle (no topology changes either) is bit-exact, not an
-    approximation.
+    Fixed-point invariant (the quiet-group scheduler's proof rests on
+    it, parallel/sched.py): ``nmoved == 0`` iff NO vertex has an
+    accepted improving move — the globally best improving vertex can
+    never lose a claim, so an empty accepted set means the improving
+    set itself is empty, and that emptiness is invariant under the
+    ``wave`` rotation (proposals are wave-independent; ``wave`` only
+    rotates claim tie-breaks among winners).  A zero-move wave is
+    therefore an exact identity on the mesh.
     """
     capT, capP = mesh.capT, mesh.capP
     movable_int = mesh.vmask & ((mesh.vtag &
@@ -129,12 +91,6 @@ def smooth_wave(mesh: Mesh, met: jax.Array, wave: int = 0,
     reg_bdy = mesh.vmask & ((mesh.vtag & MG_BDY) != 0) & \
         ((mesh.vtag & (MG_REQ | MG_CRN | MG_PARBDY | MG_GEO | MG_NOM |
                        MG_REF)) == 0)
-    if vact is not None:
-        # narrow-path restriction (ops/active.py): only active vertices
-        # may move — their full ball is in the sub-mesh, so proposal and
-        # gate stay exact
-        movable_int = movable_int & vact
-        reg_bdy = reg_bdy & vact
 
     tv = mesh.tet
     vpos = mesh.vert[tv]                                   # [T,4,3]

@@ -48,9 +48,7 @@ class SplitResult(NamedTuple):
     #                 when both ops share one pre-split edge table)
     deferred: jax.Array = None  # scalar bool: viable winners were dropped
     #                 by the top-K / shell budgets (NOT by gates or
-    #                 capacity) — the active-scoped narrow path must see
-    #                 a False here before trusting its dirty-region
-    #                 worklist (ops/active.py)
+    #                 capacity); they wait for the next wave
     nbdy: jax.Array = None  # scalar int32: of ``nsplit``, the splits of
     #                 boundary edges (their midpoints are surface points:
     #                 the ones the hausd lift places)
@@ -71,7 +69,6 @@ def split_wave(mesh: Mesh, met: jax.Array, lmax: float = LLONG,
                lens: jax.Array | None = None,
                vtan: jax.Array | None = None,
                vn: jax.Array | None = None,
-               vact: jax.Array | None = None,
                prescreen: bool = True) -> SplitResult:
     """One independent-set split wave. Jittable; static shapes throughout.
 
@@ -109,12 +106,6 @@ def split_wave(mesh: Mesh, met: jax.Array, lmax: float = LLONG,
     ``et``/``lens``: a caller-precomputed edge table + metric lengths of
     THIS mesh (adapt_cycle_impl builds one table serving both split and
     collapse — the tables are a measured hot spot of every wave).
-
-    ``vact``: optional [capP] bool active-vertex mask (the narrow path,
-    ops/active.py): only edges with BOTH endpoints active are candidates
-    — on a sub-mesh holding exactly the tets that touch active vertices,
-    such edges have their complete shell present, so shell counts and
-    the whole-shell nomination rule stay exact.
     """
     capT, capP = mesh.capT, mesh.capP
     if et is None:
@@ -133,12 +124,6 @@ def split_wave(mesh: Mesh, met: jax.Array, lmax: float = LLONG,
             ~frozen_edge
     else:
         cand = et.emask & (lens > lmax) & ~frozen_edge
-    if vact is not None:
-        cand = cand & vact[va] & vact[vb]
-    # NOTE splits are deliberately NOT window-restricted (unlike
-    # collapse/swap/smooth, ops/active.py): their steady-state count is
-    # ~zero (no footprint problem) while windowing them measurably slows
-    # the refinement phase
     lift_corr = None
     if hausd is not None:
         from .analysis import boundary_vertex_normals, carries_normal, \
@@ -269,8 +254,7 @@ def split_wave(mesh: Mesh, met: jax.Array, lmax: float = LLONG,
         toff0 = jnp.cumsum(sh0) - sh0
         shell_fit = (toff0 + sh0) <= KH
         # budget deferral (top-K or shell-budget cut of VIABLE winners —
-        # gate/capacity drops are flagged elsewhere): the narrow path's
-        # worklist invariant needs to see this
+        # gate/capacity drops are flagged elsewhere)
         defer = (nwin > KW) | jnp.any(wv & ~shell_fit)
         wv = wv & shell_fit
 

@@ -38,7 +38,7 @@ class SwapResult(NamedTuple):
     mesh: Mesh
     nswap: jax.Array
     deferred: jax.Array = None  # scalar bool: candidates exceeded the
-    #                 top-K budget (see ops/active.py worklist invariant)
+    #                 top-K budget; they wait for the next wave
 
 
 def _met6(met):
@@ -77,9 +77,8 @@ def swap_edges_wave(mesh: Mesh, met: jax.Array, enable32: bool = True,
                     enable22: bool = True,
                     flat_tol: float = 1e-5,
                     hausd: float | None = None,
-                    budget_div: int = 8, budget: int | None = None,
-                    vact: jax.Array | None = None,
-                    wwin: jax.Array | None = None) -> SwapResult:
+                    budget_div: int = 8,
+                    budget: int | None = None) -> SwapResult:
     """Combined edge-swap wave: 3-2 interior + 2-2 boundary, ONE pass.
 
     Both swaps share the same cavity shape — edge (a,b) is replaced by two
@@ -147,19 +146,6 @@ def swap_edges_wave(mesh: Mesh, met: jax.Array, enable32: bool = True,
             ((et.etag & MG_BDY) != 0) & ~frozen22 & pair_ok_f
     else:
         pre22 = jnp.zeros(Efull, bool)
-    if vact is not None:
-        # narrow-path restriction (ops/active.py): both endpoints active
-        # keeps the cavity fully inside the sub-mesh
-        vok = vact[jnp.clip(et.ev[:, 0], 0, capP - 1)] & \
-            vact[jnp.clip(et.ev[:, 1], 0, capP - 1)]
-        pre32 = pre32 & vok
-        pre22 = pre22 & vok
-    if wwin is not None:
-        # spatial-window rotation (ops/active.py): see collapse_wave
-        wok = wwin[jnp.clip(et.ev[:, 0], 0, capP - 1)] & \
-            wwin[jnp.clip(et.ev[:, 1], 0, capP - 1)]
-        pre32 = pre32 & wok
-        pre22 = pre22 & wok
     pre = pre32 | pre22
     from .edges import wave_budget, topk_prep3
     K = min(Efull, wave_budget(capT, budget_div, budget))
@@ -517,7 +503,7 @@ def _pair_fields_adja(mesh: Mesh, q_tet, capT):
     return fstar, t2_full, f2_full, cand_full
 
 
-def _pair_fields_facesort(mesh: Mesh, q_tet, capT, set_bdy_tags):
+def _pair_fields_facesort(mesh: Mesh, q_tet, capT):
     """Swap23 pairing DIRECTLY off the face-sort records — no [capT,4]
     ``adja`` materialization, no per-tet [T,4] argmin machinery.
 
@@ -541,7 +527,7 @@ def _pair_fields_facesort(mesh: Mesh, q_tet, capT, set_bdy_tags):
       route masked rows to the drop sentinel), so the applied mesh is
       bit-identical — asserted by tests/test_hotloop.py.
 
-    When ``set_bdy_tags`` the MG_BDY face tagging of the legacy
+    The MG_BDY face tagging of the legacy
     ``build_adjacency`` call is applied from the same sort records, so
     the ftag this function reads AND returns matches the legacy
     sequence's exactly.  Returns (mesh', fstar, t2_full, f2_full,
@@ -549,8 +535,7 @@ def _pair_fields_facesort(mesh: Mesh, q_tet, capT, set_bdy_tags):
     from .adjacency import face_sort, bdy_tags_from_sort
     from .edges import scatter_argmax2
     t, f, partner, matched, valid_s = face_sort(mesh)
-    if set_bdy_tags:
-        mesh = bdy_tags_from_sort(mesh, t, f, matched, valid_s)
+    mesh = bdy_tags_from_sort(mesh, t, f, matched, valid_s)
     tp = t[partner]
     fp = f[partner]
     own_s = matched & (t < tp) & (mesh.ftag[t, f] == 0) & \
@@ -571,9 +556,7 @@ def _pair_fields_facesort(mesh: Mesh, q_tet, capT, set_bdy_tags):
 
 def swap23_wave(mesh: Mesh, met: jax.Array,
                 budget_div: int = 8, budget: int | None = None,
-                wwin: jax.Array | None = None,
-                facesort: bool = False,
-                set_bdy_tags: bool = True) -> SwapResult:
+                facesort: bool = False) -> SwapResult:
     """2-to-3 swap: interior faces whose two tets improve as an edge fan.
 
     Tets T1, T2 share interior face (p,q,r) with apexes a (in T1) and b (in
@@ -584,8 +567,7 @@ def swap23_wave(mesh: Mesh, met: jax.Array,
     directly from the face-sort records (ops/adjacency.face_sort) instead
     of requiring a ``build_adjacency`` call between swap_edges_wave and
     this wave — the caller passes the post-edge-swap mesh as-is and
-    ``set_bdy_tags`` replays the legacy rebuild's MG_BDY tagging from the
-    same sort.  Bit-for-bit identical to the legacy sequence (see
+    the legacy rebuild's MG_BDY tagging is replayed from the same sort.  Bit-for-bit identical to the legacy sequence (see
     _pair_fields_facesort); ``adja`` is left stale, which is sound
     because this pairing is its only cycle-interior reader (the cycle
     exit contract rebuilds it).
@@ -602,14 +584,10 @@ def swap23_wave(mesh: Mesh, met: jax.Array,
         mesh.vert[mesh.tet], None if m6 is None else m6[mesh.tet])
     if facesort:
         mesh, fstar, t2_full, f2_full, cand_full = _pair_fields_facesort(
-            mesh, q_tet, capT, set_bdy_tags)
+            mesh, q_tet, capT)
     else:
         fstar, t2_full, f2_full, cand_full = _pair_fields_adja(
             mesh, q_tet, capT)
-    if wwin is not None:
-        # spatial-window rotation (ops/active.py): see collapse_wave
-        cand_full = cand_full & jnp.all(
-            wwin[jnp.clip(mesh.tet, 0, capP - 1)], axis=1)
     q_pair = jnp.minimum(q_tet, jnp.where(cand_full, q_tet[t2_full],
                                           jnp.inf))
     from .edges import wave_budget, topk_prep

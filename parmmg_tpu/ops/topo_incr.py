@@ -355,8 +355,7 @@ def incr_unique_edges(mesh: Mesh, topo: TopoState, incr,
     return et, topo
 
 
-def incr_build_adjacency(mesh: Mesh, topo: TopoState, incr,
-                         set_bdy_tags: bool = True):
+def incr_build_adjacency(mesh: Mesh, topo: TopoState, incr):
     """Adjacency (and boundary tags) via the retained face sort — the
     incremental form of ops/adjacency.build_adjacency, re-deriving
     twins only where the band touched (merged face records feed the
@@ -367,7 +366,7 @@ def incr_build_adjacency(mesh: Mesh, topo: TopoState, incr,
                             build_adjacency, face_records_from_sorted)
     capT = mesh.capT
     if mesh.capP > PACK_LIMIT:
-        return (build_adjacency(mesh, set_bdy_tags=set_bdy_tags),
+        return (build_adjacency(mesh),
                 topo._replace(fok=jnp.zeros((), bool),
                               fdirty=jnp.zeros(capT, bool)))
     B = incr_band_width(capT)
@@ -399,8 +398,7 @@ def incr_build_adjacency(mesh: Mesh, topo: TopoState, incr,
     k0, kw, order = jax.lax.cond(use_band, _band, _full, None)
     t, f, partner, matched, valid_s = face_records_from_sorted(
         mesh, order, k0, kw)
-    mesh = adjacency_from_records(mesh, t, f, partner, matched,
-                                  set_bdy_tags=set_bdy_tags)
+    mesh = adjacency_from_records(mesh, t, f, partner, matched)
     topo = topo._replace(fk0=k0, fkw=kw, fslot=order,
                          fok=jnp.ones((), bool),
                          fdirty=jnp.zeros(capT, bool))

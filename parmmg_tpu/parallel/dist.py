@@ -82,15 +82,13 @@ def shard_stacked(stacked, dmesh: DeviceMesh):
     return jax.tree.map(lambda x: jax.device_put(x, sh), stacked)
 
 
-def dist_adapt_block(dmesh: DeviceMesh, swap_flags: tuple,
+def dist_adapt_block(dmesh: DeviceMesh, swap: bool,
                      do_smooth: bool = True, do_insert: bool = True,
                      hausd: float | None = None, G: int = 1,
-                     pre_flags: tuple | None = None,
+                     prescreen: bool = True,
                      swap_inclusive: bool | None = None):
-    """SPMD fused cycle block: ``len(swap_flags)`` adapt cycles in ONE
-    jitted shard_map program — the production analogue of
-    ops.adapt.adapt_cycles_fused.  One dispatch + one psum'd counter
-    pull per block instead of per cycle.
+    """SPMD cycle block: one adapt cycle in ONE jitted shard_map
+    program, one dispatch + one psum'd counter pull.
 
     ``G`` > 1 is the groups x shards composition (the reference's
     rank-level x group-level two-level loop, grpsplit_pmmg.c:1551-1614,
@@ -101,13 +99,13 @@ def dist_adapt_block(dmesh: DeviceMesh, swap_flags: tuple,
     group states plus a single group's wave working set — the bound
     that makes meshes far beyond one group's HBM feasible per chip.
 
-    Returns fn(stacked_mesh, stacked_met, wave0, quiet_lvl[S*G]) ->
-      (stacked_mesh, stacked_met, global_counts[n,4],
-       active_groups[n], any_overflow, quiet_lvl'[S*G]).
+    Returns fn(stacked_mesh, stacked_met, wave, quiet_lvl[S*G]) ->
+      (stacked_mesh, stacked_met, global_counts[4],
+       active_groups, any_overflow, quiet_lvl'[S*G]).
 
-    ``active_groups[i]`` = number of LOGICAL shards that posted a
-    nonzero split+collapse+swap in cycle i (psum'd like the counters):
-    the per-group convergence signal is kept instead of being summed
+    ``active_groups`` = number of LOGICAL shards that posted a nonzero
+    split+collapse+swap in the cycle (psum'd like the counters): the
+    per-group convergence signal is kept instead of being summed
     away, so :func:`run_adapt_cycles` can drive its early-exit and its
     verbose "active g/G" trajectory from per-group data — the SPMD
     mirror of the quiet-group scheduler on the single-device grouped
@@ -122,69 +120,62 @@ def dist_adapt_block(dmesh: DeviceMesh, swap_flags: tuple,
     DEVICE (the same frozen-seam + deterministic-wave fixed-point
     proof, the same two prescreen levels; sched module docstring).
     Zero host syncs are added: the level array never leaves the device.
-    ``swap_inclusive`` must be passed as ``any(flags) or noswap`` by
+    ``swap_inclusive`` must be passed as ``swap or noswap`` by
     callers that honor -noswap (a noswap run's blocks are trivially
-    swap-inclusive); it defaults to ``any(swap_flags)``.  The caller
+    swap-inclusive); it defaults to ``swap``.  The caller
     opts out of skipping by discarding the returned level and passing
     zeros each block (run_adapt_cycles under PARMMG_DEVICE_MASK=0 /
     PARMMG_GROUP_SCHED=0) — same compiled program either way.
     """
     return DistSteps(dmesh, do_smooth=do_smooth, do_insert=do_insert,
-                     hausd=hausd, G=G).get(swap_flags, pre_flags,
+                     hausd=hausd, G=G).get(swap, prescreen,
                                            swap_inclusive)
 
 
-def _dist_block_program(dmesh: DeviceMesh, nblk: int, do_smooth: bool,
+def _dist_block_program(dmesh: DeviceMesh, do_smooth: bool,
                         do_insert: bool, hausd, G: int):
-    """The compiled program behind :func:`dist_adapt_block`: ONE per
-    block length.  Which cycles swap (``sw`` [nblk]), which bypass the
-    split prescreen (``pr`` [nblk]) and whether the block is
-    swap-inclusive (``inc``) are traced, replicated arguments — the
-    cycle classes of a run share one multi-minute SPMD compile."""
+    """The compiled program behind :func:`dist_adapt_block`.  Whether
+    the cycle swaps (``sw``), whether it bypasses the split prescreen
+    (``pr``) and whether the block is swap-inclusive (``inc``) are
+    traced, replicated scalar arguments — the cycle classes of a run
+    share one multi-minute SPMD compile."""
     from ..ops.adapt import adapt_cycle_impl
     spec = P("shard")
 
-    def one_shard(mesh: Mesh, met, wave0, act, sw, pr):
-        counts_all = []
-        for c in range(nblk):
-            mesh, met, counts = adapt_cycle_impl(
-                mesh, met, wave0 + c, do_swap=sw[c], do_smooth=do_smooth,
-                do_insert=do_insert, smooth_waves=2, hausd=hausd,
-                final_rebuild=(c == nblk - 1),
-                prescreen=pr[c], active=act)
-            counts_all.append(counts)
-        return mesh, met, jnp.stack(counts_all)            # [n, 8]
+    def one_shard(mesh: Mesh, met, wave, act, sw, pr):
+        return adapt_cycle_impl(
+            mesh, met, wave, do_swap=sw, do_smooth=do_smooth,
+            do_insert=do_insert, smooth_waves=2, hausd=hausd,
+            prescreen=pr, active=act)                      # counts [11]
 
-    def local_block(mesh_s: Mesh, met_s, wave0, lvl_s, sw, pr, inc):
+    def local_block(mesh_s: Mesh, met_s, wave, lvl_s, sw, pr, inc):
         # the level this block skips at == the level it can prove
-        # (sched.LEVEL_PRE under an all-prescreen-ON block, LEVEL_FULL
+        # (sched.LEVEL_PRE under a prescreen-ON cycle, LEVEL_FULL
         # once a prescreen-OFF cycle ran — numerically 1 and 2)
-        skip_lvl = jnp.where(jnp.all(pr), jnp.int8(1), jnp.int8(2))
+        skip_lvl = jnp.where(pr, jnp.int8(1), jnp.int8(2))
         act_in = lvl_s < skip_lvl                          # [G] bool
         if G == 1:
             mesh, met, cs = one_shard(_unstack(mesh_s), met_s[0],
-                                      wave0, act_in[0], sw, pr)
+                                      wave, act_in[0], sw, pr)
             mesh_s, met_s = _restack(mesh), met[None]
-            cs_g = cs[None]                                # [1, n, 8]
-            act = (jnp.sum(cs[:, :3], axis=1) > 0).astype(jnp.int32)
+            cs_g = cs[None]                                # [1, 11]
         else:
             def body(args):
                 m, k, a = args
-                return one_shard(m, k, wave0, a, sw, pr)
+                return one_shard(m, k, wave, a, sw, pr)
             mesh_s, met_s, cs_g = jax.lax.map(
                 body, (mesh_s, met_s, act_in))
-            act = jnp.sum((jnp.sum(cs_g[:, :, :3], axis=2) > 0
-                           ).astype(jnp.int32), axis=0)    # [n]
+        act = jnp.sum((jnp.sum(cs_g[:, :3], axis=1) > 0
+                       ).astype(jnp.int32))
         # quiet marking on device, on a swap-inclusive block —
         # sched.quiet_rows' rule: the WHOLE block a no-op (zero
         # split+collapse+swap+move AND zero overflow; a truncated
         # winner set witnesses nothing)
-        nG = cs_g.shape[0]
-        blk_zero = jnp.sum(cs_g[:, :, :5].reshape(nG, -1), axis=1) == 0
+        blk_zero = jnp.sum(cs_g[:, :5], axis=1) == 0
         lvl_s = jnp.maximum(
             lvl_s, jnp.where(blk_zero & inc, skip_lvl, jnp.int8(0)))
-        ovf = jax.lax.pmax(jnp.max(cs_g[:, :, 4]), "shard")
-        counts = jax.lax.psum(jnp.sum(cs_g[:, :, :4], axis=0), "shard")
+        ovf = jax.lax.pmax(jnp.max(cs_g[:, 4]), "shard")
+        counts = jax.lax.psum(jnp.sum(cs_g[:, :4], axis=0), "shard")
         nact = jax.lax.psum(act, "shard")
         return mesh_s, met_s, counts, nact, ovf, lvl_s
 
@@ -196,8 +187,8 @@ def _dist_block_program(dmesh: DeviceMesh, nblk: int, do_smooth: bool,
 
 
 class DistSteps:
-    """Per-driver-invocation cache of the compiled SPMD block programs,
-    one per block length.  jax.jit caches by function identity, so a
+    """Per-driver-invocation cache of the compiled SPMD block program.
+    jax.jit caches by function identity, so a
     fresh shard_map per outer iteration would recompile the
     multi-minute SPMD graph every time; the multi-iteration drivers
     build ONE of these and reuse it."""
@@ -205,28 +196,19 @@ class DistSteps:
     def __init__(self, dmesh: DeviceMesh, do_smooth: bool = True,
                  do_insert: bool = True, hausd: float | None = None,
                  G: int = 1):
-        self.dmesh = dmesh
-        self.kw = dict(do_smooth=do_smooth, do_insert=do_insert,
-                       hausd=hausd, G=G)
-        self._cache: dict = {}
+        self._prog = _dist_block_program(dmesh, do_smooth, do_insert,
+                                         hausd, G)
 
-    def get(self, flags: tuple, pre_flags: tuple | None = None,
+    def get(self, swap: bool, prescreen: bool = True,
             swap_inclusive: bool | None = None):
-        flags = tuple(bool(f) for f in flags)
-        if pre_flags is None:
-            pre_flags = (True,) * len(flags)
         if swap_inclusive is None:
-            swap_inclusive = any(flags)
-        nblk = len(flags)
-        if nblk not in self._cache:
-            self._cache[nblk] = _dist_block_program(
-                self.dmesh, nblk, **self.kw)
-        prog = self._cache[nblk]
-        sw = jnp.asarray(flags, bool)
-        pr = jnp.asarray(tuple(bool(f) for f in pre_flags), bool)
+            swap_inclusive = swap
+        prog = self._prog
+        sw = jnp.asarray(bool(swap))
+        pr = jnp.asarray(bool(prescreen))
         inc = jnp.asarray(bool(swap_inclusive))
-        return lambda mesh_s, met_s, wave0, lvl_s: prog(
-            mesh_s, met_s, wave0, lvl_s, sw, pr, inc)
+        return lambda mesh_s, met_s, wave, lvl_s: prog(
+            mesh_s, met_s, wave, lvl_s, sw, pr, inc)
 
 
 def dist_interface_check(dmesh: DeviceMesh, G: int = 1,
@@ -554,8 +536,7 @@ def check_interface_echo(stacked, met_s, comms, dmesh, vert_h, G: int = 1,
 
 def run_adapt_cycles(stacked, met_s, steps: DistSteps, cycles,
                      dmesh, stats=None, verbose=0, on_grow=None,
-                     regrow_state=None, label="dist", noswap=False,
-                     block=None):
+                     regrow_state=None, label="dist", noswap=False):
     """Shared SPMD cycle loop: swap cadence (every 3rd cycle + the final
     two), psum'd counter accounting, and the in-place overflow regrow
     (zaldy_pmmg.c:140-254 analogue — slot ids preserved so comm tables
@@ -563,20 +544,17 @@ def run_adapt_cycles(stacked, met_s, steps: DistSteps, cycles,
     ShardOverflowError carrying the conforming merged state
     (failed_handling, libparmmg1.c:974-1011).
 
-    Cycles dispatch in fused blocks (default_cycle_block) — one
-    dispatch + one counter pull per block.
+    One dispatch + one counter pull per cycle.
 
     ``on_grow(old_capP)`` lets the caller grow its side tables (global
     numbering) in lockstep; ``regrow_state`` is a 1-element mutable list
     carried across calls so repeated passes share the regrow budget.
     """
     from .distribute import merge_shards, grow_shards
+    from .groups import block_schedule
     from .sched import device_mask_enabled, sched_enabled
-    from ..ops.adapt import default_cycle_block
     if regrow_state is None:
         regrow_state = [0]
-    if block is None:
-        block = default_cycle_block()
     # device-resident quiet levels (the sched.py proof pushed into the
     # compiled block — dist_adapt_block docstring): int8 per logical
     # shard, never pulled to host.  With masking disabled the SAME
@@ -587,17 +565,10 @@ def run_adapt_cycles(stacked, met_s, steps: DistSteps, cycles,
     lvl = shard_stacked(jnp.zeros(n_logical, jnp.int8), dmesh)
     c = 0
     while c < cycles:
-        nblk = min(block, cycles - c)
-        # swaps every 3rd cycle (see ops.adapt.adapt_mesh) and on the
-        # final two (quality polish before the merge/migration); those
-        # polish cycles also bypass the approximate split prescreen so
-        # near-floor shells it over-vetoed get one exact re-evaluation
-        # (ops/split.py, ADVICE r3)
-        flags = tuple((cc % 3 == 2 or cc >= cycles - 2) and not noswap
-                      for cc in range(c, c + nblk))
-        pres = tuple(cc < cycles - 2 for cc in range(c, c + nblk))
-        step = steps.get(flags, pres,
-                         swap_inclusive=any(flags) or noswap)
+        # the grouped path's schedule: swaps every 3rd cycle and on the
+        # final two, which also bypass the split prescreen
+        swap, pre = block_schedule(c, cycles, noswap)
+        step = steps.get(swap, pre, swap_inclusive=swap or noswap)
         stacked, met_s, counts, nact, ovf, lvl2 = step(
             stacked, met_s, jnp.asarray(c, jnp.int32), lvl)
         if mask_on:
@@ -605,25 +576,23 @@ def run_adapt_cycles(stacked, met_s, steps: DistSteps, cycles,
         # ONE host pull per array per block (the blessed .tolist()
         # idiom): the per-field int() casts each forced their own
         # device sync
-        ca = counts.tolist()                     # [nblk][4]
-        na = nact.tolist()                       # [nblk] active groups
+        cs = counts.tolist()                     # [4]
+        na = nact.tolist()                       # active groups
         n_logical = stacked.tmask.shape[0]
-        for i in range(nblk):
-            cs = ca[i]
-            if stats is not None:        # psum'd global counters
-                stats.nsplit += cs[0]
-                stats.ncollapse += cs[1]
-                stats.nswap += cs[2]
-                stats.nmoved += cs[3]
-                stats.cycles += 1
-                # per-group convergence trajectory (the SPMD mirror of
-                # the grouped path's active_groups_per_block)
-                stats.sched_extra.setdefault(
-                    "active_shards_per_cycle", []).append(na[i])
-            otrace.log(3, f"  {label} cycle {c + i}: split {cs[0]} "
-                          f"collapse {cs[1]} swap {cs[2]} move {cs[3]} "
-                          f"active {na[i]}/{n_logical} grp",
-                       verbose=verbose)
+        if stats is not None:        # psum'd global counters
+            stats.nsplit += cs[0]
+            stats.ncollapse += cs[1]
+            stats.nswap += cs[2]
+            stats.nmoved += cs[3]
+            stats.cycles += 1
+            # per-group convergence trajectory (the SPMD mirror of
+            # the grouped path's active_groups_per_block)
+            stats.sched_extra.setdefault(
+                "active_shards_per_cycle", []).append(na)
+        otrace.log(3, f"  {label} cycle {c}: split {cs[0]} "
+                      f"collapse {cs[1]} swap {cs[2]} move {cs[3]} "
+                      f"active {na}/{n_logical} grp",
+                   verbose=verbose)
         if ovf.tolist() != 0:
             if regrow_state[0] >= MAX_SHARD_REGROWS:
                 m_, k_, p_ = merge_shards(stacked, met_s,
@@ -642,13 +611,12 @@ def run_adapt_cycles(stacked, met_s, steps: DistSteps, cycles,
             # wave budgets scale with capT) — sched.on_regrow's rule
             lvl = shard_stacked(jnp.zeros(n_logical, jnp.int8), dmesh)
             continue        # re-run the block: truncated winners rerun
-        c += nblk
+        c += 1
         # convergence: a swap-inclusive (or noswap) cycle on which
         # EVERY logical group posted zero topological ops ends the pass
         # (active_groups == 0 is exactly the summed-zero rule, read
         # from the per-group counts instead of the psum'd total)
-        if any((flags[i] or noswap) and na[i] == 0
-               for i in range(nblk)):
+        if (swap or noswap) and na == 0:
             break
     return stacked, met_s
 

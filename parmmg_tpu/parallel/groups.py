@@ -77,65 +77,60 @@ def group_chunk(ngroups: int) -> int:
     return 0 if c >= ngroups else c
 
 
-def block_schedule(c0: int, nblk: int, cycles: int, noswap: bool):
-    """(flags, pres) for the cycle block starting at global cycle
-    ``c0`` — THE block signature of the grouped cycle scheduler: swap
-    every 3rd cycle plus the final-two polish cycles (swap-inclusive
-    AND exact split veto via prescreen bypass — ops/split.py, ADVICE
-    r3).  Factored out so the serving pool (serve/pool.py) runs
-    byte-identical block sequences: same signature => same cached
-    compiled program (_group_block key)."""
-    flags = tuple((cc % 3 == 2 or cc >= cycles - 2) and not noswap
-                  for cc in range(c0, c0 + nblk))
-    pres = tuple(cc < cycles - 2 for cc in range(c0, c0 + nblk))
-    return flags, pres
+def block_schedule(c: int, cycles: int, noswap: bool):
+    """(swap, prescreen) of global cycle ``c`` of ``cycles``, one block
+    each — THE schedule of the grouped cycle loop: swap every 3rd cycle
+    plus the final-two polish cycles (swap-inclusive AND exact split
+    veto via prescreen bypass — ops/split.py, ADVICE r3).  Factored
+    out so the serving pool (serve/pool.py) runs byte-identical block
+    sequences through the same compiled program."""
+    return ((c % 3 == 2 or c >= cycles - 2) and not noswap,
+            c < cycles - 2)
 
 
-# lint: ok(R2) — cs is host numpy (the per-block counters the drain
+# lint: ok(R2) — cs is host numpy (the block's counters the drain
 # already pulled); the early-exit decision is pure host bookkeeping
-def block_converged(cs: np.ndarray, flags: tuple, noswap: bool) -> bool:
+def block_converged(cs: np.ndarray, swap: bool, noswap: bool) -> bool:
     """The grouped loop's early-exit rule on a block's summed counts
-    ``cs`` [nblk, >=3]: any swap-inclusive cycle posting zero
+    row ``cs`` [>=3]: a swap-inclusive cycle posting zero
     split+collapse+swap ends the sizing loop.  Shared with the serving
     pool, where it is evaluated per tenant (a tenant IS one group, so
     the per-tenant rule equals the standalone ngroups=1 rule — the
     serving parity contract)."""
-    return any((flags[i] or noswap) and
-               int(cs[i][0]) + int(cs[i][1]) + int(cs[i][2]) == 0
-               for i in range(len(flags)))
+    return bool(swap or noswap) and \
+        int(cs[0]) + int(cs[1]) + int(cs[2]) == 0
 
 
 # module-level compiled-block caches (compile governor): the builders
 # below close only over hashable knobs, and jax.jit caches by function
 # IDENTITY — per-pass local builders recompiled the group programs
 # every outer iteration even at identical shapes.  Bounded: a handful
-# of (flags, pres, knobs) combos per session.
+# of knob combos per session.
 _GROUP_BLOCK_CACHE: dict = {}
 _POLISH_BLOCK_CACHE: dict = {}
 
 
-def _group_block(flags: tuple, pres: tuple, nomove: bool,
-                 noinsert: bool, hausd):
-    """The cycle block for one (flags, pres) block signature: the
-    compiled program of :func:`_group_block_program` with the per-cycle
-    swap and prescreen switches bound as device arrays."""
-    run = _group_block_program(len(flags), nomove, noinsert, hausd)
-    sw, pr = jnp.asarray(flags, bool), jnp.asarray(pres, bool)
+def _group_block(swap: bool, pre: bool, nomove: bool, noinsert: bool,
+                 hausd):
+    """The cycle block of one (swap, prescreen) cycle class: the
+    compiled program of :func:`_group_block_program` with the two
+    switches bound as device scalars."""
+    run = _group_block_program(nomove, noinsert, hausd)
+    sw, pr = jnp.asarray(bool(swap)), jnp.asarray(bool(pre))
     return lambda *args: run(*args, sw, pr)
 
 
-def _group_block_program(nblk: int, nomove: bool, noinsert: bool, hausd):
-    """Fused cycle block for the group axis (lax.map body): one
-    dispatch + one counter pull per block per outer step (ops.adapt
-    adapt_cycles_fused analogue).  Cached by knobs so repeat passes
-    reuse the compiled program.
+def _group_block_program(nomove: bool, noinsert: bool, hausd):
+    """The cycle block for the group axis: ONE adapt cycle per
+    ``lax.map`` row, one dispatch + one counter pull per cycle.
+    Cached by knobs so repeat passes reuse the compiled program.
 
-    ONE program per block length: which cycles of the block swap and
-    which bypass the split prescreen (:func:`block_schedule`) are the
-    traced bool vectors ``sw``/``pr`` [nblk], its last two arguments —
-    the three cycle classes of a run (sizing, swap-inclusive sizing,
-    final polish) would otherwise be three compiles of the same waves,
-    and a cold compile of one costs minutes on a TPU (PERF.md, PR 26).
+    ONE program a job shape: whether the cycle swaps and whether it
+    bypasses the split prescreen (:func:`block_schedule`) are the
+    traced scalar bools ``sw``/``pr``, its last two arguments — the
+    three cycle classes of a run (sizing, swap-inclusive sizing, final
+    polish) would otherwise be three compiles of the same waves, and a
+    cold compile of one costs minutes on a TPU (PERF.md, PR 26).
 
     The compiled program takes a per-slot ``active`` bool mask (the
     device-resident quiet mask, parallel/sched.py): inactive slots —
@@ -146,15 +141,6 @@ def _group_block_program(nblk: int, nomove: bool, noinsert: bool, hausd):
     all-true mask when masking is off), so toggling it mints zero new
     compile families — the grouped_sched_gate contract.
 
-    ``cadence`` (last argument of the compiled program) is the
-    smoothing-cadence enable (PARMMG_SMOOTH_CADENCE via
-    sched.cadence_enabled): like the quiet mask it is ALWAYS a traced
-    argument, so toggling it mints zero new compile families
-    (the hotloop_knob_gate contract).  The per-slot idle carry is
-    derived on-device from each cycle's counts inside the map body —
-    a cycle following a full no-op cycle skips its smoothing wave as a
-    proven identity (ops/adapt.py ``smooth_idle``).
-
     ``incr``/``topo`` (PARMMG_INCR_TOPO, ops/topo_incr): per-slot
     retained-sort + dirty-band state rides the group axis through the
     SAME compiled program — the knob scalar and the state are ALWAYS
@@ -164,43 +150,31 @@ def _group_block_program(nblk: int, nomove: bool, noinsert: bool, hausd):
     (an idle slot's retained tables stay valid)."""
     from ..ops.adapt import adapt_cycle_impl
     from ..utils.compilecache import governed
-    key = (nblk, nomove, noinsert, hausd)
+    key = (nomove, noinsert, hausd)
     if key in _GROUP_BLOCK_CACHE:
         return _GROUP_BLOCK_CACHE[key]
 
-    # variant budget: one program per block length and shape family —
-    # the chunked dispatch pads every chunk to ONE shape, regrows and
-    # regrouping add a few; growth past this is recompile churn
+    # variant budget: one program per shape family — the chunked
+    # dispatch pads every chunk to ONE shape, regrows and regrouping
+    # add a few; growth past this is recompile churn
     @governed("groups.adapt_block", budget=6)
     @jax.jit
-    def run(stacked, met_s, wave, active, cadence, incr, topo, sw, pr):
+    def run(stacked, met_s, wave, active, incr, topo, sw, pr):
         def body(args):
-            m, k, wave, act, cad, inc, tp = args
-            counts_all = []
-            sm_idle = jnp.zeros((), bool)
-            for cc in range(nblk):
-                # named_scope: XLA ops of each unrolled cycle carry the
-                # phase name on a profiler's device timeline
-                # (obs/trace.py)
-                with otrace.scope(f"grp_cycle{cc}"):
-                    m, k, counts, tp = adapt_cycle_impl(
-                        m, k, wave + cc, do_swap=sw[cc],
-                        do_smooth=not nomove, do_insert=not noinsert,
-                        hausd=hausd, final_rebuild=(cc == nblk - 1),
-                        prescreen=pr[cc], active=act,
-                        smooth_idle=cad & sm_idle, topo=tp, incr=inc)
-                sm_idle = ((counts[0] + counts[1] + counts[2]) == 0) & \
-                    (counts[3] == 0)
-                counts_all.append(counts)
-            return m, k, jnp.stack(counts_all), tp   # counts [n, 12]
+            m, k, wave, act, inc, tp = args
+            # named_scope: the cycle's XLA ops carry the phase name on a
+            # profiler's device timeline (obs/trace.py)
+            with otrace.scope("grp_cycle0"):
+                return adapt_cycle_impl(
+                    m, k, wave, do_swap=sw, do_smooth=not nomove,
+                    do_insert=not noinsert, hausd=hausd, prescreen=pr,
+                    active=act, topo=tp, incr=inc)
 
         n_map = stacked.vert.shape[0]            # chunk or g_exec
         waves = jnp.full(n_map, wave, jnp.int32)
-        cads = jnp.full(n_map, cadence, bool)
         incs = jnp.full(n_map, incr, bool)
-        m, k, counts, tp = jax.lax.map(
-            body, (stacked, met_s, waves, active, cads, incs, topo))
-        return m, k, counts, tp                  # counts [G, n, 12]
+        return jax.lax.map(                      # counts [G, 12]
+            body, (stacked, met_s, waves, active, incs, topo))
 
     _GROUP_BLOCK_CACHE[key] = run
     return run
@@ -257,8 +231,7 @@ def _pipeline_chunks(fn, stacked, met_s, wave, plans, tim, done=None,
 
     ``extra``: additional positional device scalars appended to each
     ``fn`` dispatch after the active mask (the adapt block's traced
-    cadence enable + incremental-topology knob; empty for the polish
-    block).
+    incremental-topology knob; empty for the polish block).
 
     ``topo``: optional host-numpy TopoState [g_exec, ...]
     (ops/topo_incr.topo_init_np) — the per-slot retained-table state of
@@ -350,7 +323,7 @@ def _pipeline_chunks(fn, stacked, met_s, wave, plans, tim, done=None,
 
     # lint: ok(R2) — the pipeline's ONE designed sync point: chunked
     # mode keeps the pass state host-resident, so the drain downloads
-    # O(chunk) tables + [chunk,nblk,9] counters while chunk k+1 is
+    # O(chunk) tables + [chunk,12] counters while chunk k+1 is
     # already dispatched (PR-5 double buffering; segments timed)
     def drain(p):
         pi, idx, nreal, m, k, cnt, tp = p
@@ -464,7 +437,7 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     skipped-group / saved-dispatch counters and the active-group
     trajectory, in ``stats.sched_extra``.
     """
-    from ..ops.adapt import DIRTY_COL, SURF_COLS, default_cycle_block
+    from ..ops.adapt import DIRTY_COL, SURF_COLS
     from ..utils.timers import Timers
     from .partition import morton_partition, fix_contiguity
     from .distribute import split_to_shards, merge_shards, grow_shards
@@ -542,50 +515,43 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
             sp.set(bytes=sum(a.nbytes for a in
                              jax.tree.leaves((stacked, met_s, topo_s))))
         sched = QuietGroupScheduler(ngroups, g_exec, chunk)
-        # smoothing-cadence enable as a DEVICE SCALAR: always an argument
-        # of the compiled block (like the quiet mask), so toggling
-        # PARMMG_SMOOTH_CADENCE mints zero new compile families
-        from .sched import cadence_enabled
-        cad = jnp.asarray(cadence_enabled())
         # incremental topology engine (ops/topo_incr, PARMMG_INCR_TOPO):
         # per-slot retained-table + dirty-band state rides the group axis —
         # host-resident in chunk mode (rows committed by drain writebacks,
         # same idempotent contract as the mesh state), device-resident
-        # otherwise (fresh_topo).  The knob is a traced scalar like the
-        # cadence.
+        # otherwise (fresh_topo).  The knob is a traced scalar, always an
+        # argument of the compiled block (like the quiet mask): toggling
+        # it mints no new compile family.
         inc = jnp.asarray(incr_topo_enabled())
     # pipeline segment timers on a LOCAL registry: folded into
     # stats.sched_extra and (prefixed) into the caller's Timers at the
     # end, so the driver report shows the transfer/compute split
     ltim = Timers()
-    block = default_cycle_block()
     c = 0
     regrows = 0
     dirty_traj: list[int] = []
     while c < cycles:
-        nblk = min(block, cycles - c)
-        flags, pres = block_schedule(c, nblk, cycles, noswap)
-        step = _group_block(flags, pres, nomove, noinsert, hausd)
-        swap_inc = any(flags) or noswap
-        pres_all_on = all(pres)
+        swap, pre = block_schedule(c, cycles, noswap)
+        step = _group_block(swap, pre, nomove, noinsert, hausd)
+        swap_inc = swap or noswap
         wave = jnp.asarray(c, jnp.int32)
-        act, plans = sched.plan_block(pres_all_on)
+        act, plans = sched.plan_block(pre)
         # one span a dispatched block, dispatch to counter pull, with
         # the operations it applied: the ratio of useful outcomes to
         # attempts is recorded where the work happens
         with otrace.context(block=c, chunk=chunk or 0), \
-                otrace.span("grp block", block=c, nblk=nblk,
+                otrace.span("grp block", block=c,
                             active=len(act)) as sp:
             if chunk:
                 parts = _pipeline_chunks(step, stacked, met_s, wave,
-                                         plans, ltim, extra=(cad, inc),
+                                         plans, ltim, extra=(inc,),
                                          topo=topo_s)
                 sched.note_plan_pads(plans)
                 counts_act = np.concatenate(parts) if parts else \
-                    np.zeros((0, nblk, DIRTY_COL + 1), np.int32)
+                    np.zeros((0, DIRTY_COL + 1), np.int32)
                 if sched.enabled:
                     otrace.log(
-                        2, f"  grp block {c}..{c + nblk - 1}: active "
+                        2, f"  grp block {c}: active "
                            f"{len(act)}/{g_exec} groups, {len(plans)} "
                            "dispatches", verbose=verbose)
             else:
@@ -596,45 +562,37 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                 # The pull of the counters is the block's only sync
                 stacked, met_s, counts, topo_s = step(
                     stacked, met_s, wave,
-                    jnp.asarray(sched.block_mask(pres_all_on)), cad,
-                    inc, topo_s)
-                counts_act = np.asarray(counts)  # [g_exec, nblk, 12]
+                    jnp.asarray(sched.block_mask(pre)), inc, topo_s)
+                counts_act = np.asarray(counts)         # [g_exec, 12]
             # quiet groups contribute exact zeros (that is what marked
             # them)
-            cs = counts_act.sum(axis=0, dtype=np.int64)     # [nblk, 12]
-            # ONE host conversion for the whole block's counters
-            # (counts_act is already host numpy — the drain pulled it);
-            # the per-counter int() casts were R2-baselined noise
-            cs_l = cs.tolist()                              # python ints
-            surf = {k: sum(r[col] for r in cs_l)
-                    for k, col in SURF_COLS.items()}
-            sp.set(split=sum(r[0] for r in cs_l),
-                   collapse=sum(r[1] for r in cs_l),
-                   swap=sum(r[2] for r in cs_l),
-                   moved=sum(r[3] for r in cs_l), **surf)
+            cs = counts_act.sum(axis=0, dtype=np.int64)         # [12]
+            # ONE host conversion for the block's counters (counts_act
+            # is already host numpy — the drain pulled it)
+            tot = cs.tolist()                           # python ints
+            surf = {k: tot[col] for k, col in SURF_COLS.items()}
+            sp.set(split=tot[0], collapse=tot[1], swap=tot[2],
+                   moved=tot[3], **surf)
         if not chunk:
             # "compute" as the chunk pipeline records it: the seconds
             # from dispatch to counter pull
             ltim.add("compute", sp.dur)
-        sched.record_block(act, counts_act, swap_inc, pres_all_on)
-        for i in range(nblk):
-            tot = cs_l[i]
-            # dirty tets pending at each cycle start, summed over
-            # groups — the band-occupancy trajectory (bench extras)
-            dirty_traj.append(tot[DIRTY_COL])
-            if stats is not None:
-                stats.nsplit += tot[0]
-                stats.ncollapse += tot[1]
-                stats.nswap += tot[2]
-                stats.nmoved += tot[3]
-                stats.cycles += 1
-                stats.add_surface(**{k: tot[col]
-                                     for k, col in SURF_COLS.items()})
-            otrace.log(3, f"  grp cycle {c + i}: split {tot[0]} "
-                          f"collapse {tot[1]} swap {tot[2]} move "
-                          f"{tot[3]} over {ngroups} groups",
-                       verbose=verbose)
-        if any(row[4] != 0 for row in cs_l):
+        sched.record_block(act, counts_act, swap_inc, pre)
+        # dirty tets pending at the cycle's start, summed over groups —
+        # the band-occupancy trajectory (sched_extra)
+        dirty_traj.append(tot[DIRTY_COL])
+        if stats is not None:
+            stats.nsplit += tot[0]
+            stats.ncollapse += tot[1]
+            stats.nswap += tot[2]
+            stats.nmoved += tot[3]
+            stats.cycles += 1
+            stats.add_surface(**surf)
+        otrace.log(3, f"  grp cycle {c}: split {tot[0]} "
+                      f"collapse {tot[1]} swap {tot[2]} move "
+                      f"{tot[3]} over {ngroups} groups",
+                   verbose=verbose)
+        if tot[4] != 0:
             if regrows >= 6:
                 raise MemoryError("group capacity exhausted")
             with otrace.span("grp regrow") as sp:
@@ -682,8 +640,8 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                 # (truncated winners must rerun)
                 sched.on_regrow()
             continue        # re-run the block: truncated winners rerun
-        c += nblk
-        if block_converged(cs, flags, noswap):
+        c += 1
+        if block_converged(cs, swap, noswap):
             break
     pol_traj: list[int] = []
     if polish and not (noinsert and noswap and nomove):
@@ -783,7 +741,7 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                 if tot[0] == 0 and tot[1] == 0:
                     break
     # fold the scheduler instrumentation: counters + the active-group
-    # trajectory into AdaptStats.sched_extra (bench/SCALE artifacts),
+    # trajectory into AdaptStats.sched_extra (SCALE artifacts),
     # the pipeline segment times into the caller's Timers (driver
     # report) under a "grp <segment>" prefix
     # chunk auto-tune (ROADMAP 1b, lightweight): fold this pass's
@@ -840,7 +798,7 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
         if dirty_traj:
             # per-cycle dirty-band occupancy (the dirty column summed over
             # groups): shows when the incremental path engages and how
-            # small the decay-regime bands get (bench extra.incr_topo)
+            # small the decay-regime bands get
             se.setdefault("incr_dirty_per_cycle", []).extend(dirty_traj)
         if pol_traj:
             se.setdefault("polish_active_per_wave", []).extend(pol_traj)

@@ -101,21 +101,6 @@ def device_mask_enabled() -> bool:
     return os.environ.get("PARMMG_DEVICE_MASK", "1") != "0"
 
 
-def cadence_enabled() -> bool:
-    """PARMMG_SMOOTH_CADENCE knob (default on): quality-triggered
-    smoothing cadence — adapt_cycle_impl skips ``smooth_wave`` on a
-    cycle whose topology counts are all zero AND whose previous cycle's
-    smoothing already moved nothing (an exact fixed point: the claim
-    resolution in smooth_wave guarantees nmoved==0 iff no vertex can
-    improve, and that emptiness is wave-rotation-invariant; see the
-    adapt_cycle_impl docstring for the full argument).  The enable is
-    threaded as a TRACED device scalar (like the quiet mask), so
-    toggling it mints zero new ``groups.*`` compile families —
-    asserted by the ``run_tests.sh --ledger`` hotloop_knob_gate."""
-    import os
-    return os.environ.get("PARMMG_SMOOTH_CADENCE", "") != "0"
-
-
 def pad_mask(chunk: int, nreal: int) -> np.ndarray:
     """[chunk] bool device-mask for a compacted chunk plan: the first
     ``nreal`` rows are real, the repeat-padded tail rows are masked off
@@ -134,9 +119,8 @@ def pad_mask(chunk: int, nreal: int) -> np.ndarray:
 def quiet_rows(counts: np.ndarray) -> np.ndarray:
     """Per-row fixed-point witness from a dispatched block's counts.
 
-    ``counts``: [n, nblk, >=5] — reads ONLY columns 0..4, so the
-    9-wide rows of the topo-threaded block (col 8 = dirty-tet count,
-    ops/topo_incr) satisfy the contract unchanged.  Row ``i`` is quiet
+    ``counts``: [n, >=5], a block's (one cycle's) row per group —
+    reads ONLY columns 0..4.  Row ``i`` is quiet
     when the WHOLE block was a no-op for it — zero
     split+collapse+swap+move AND zero overflow (a truncated winner set
     witnesses nothing).  Shared by
@@ -144,10 +128,8 @@ def quiet_rows(counts: np.ndarray) -> np.ndarray:
     the serving pool (serve/pool.py, tenant granularity): one rule, one
     exactness argument (module docstring)."""
     # host-by-contract: the drain already pulled the block counters to
-    # numpy ([n, nblk, >=5]) — no conversion, no possible device sync
-    n = counts.shape[0]
-    return counts[..., :5].reshape(n, -1).sum(axis=1,
-                                              dtype=np.int64) == 0
+    # numpy ([n, >=5]) — no conversion, no possible device sync
+    return counts[:, :5].sum(axis=1, dtype=np.int64) == 0
 
 
 def chunk_plans(act: np.ndarray, chunk: int) -> list:
@@ -266,7 +248,7 @@ class QuietGroupScheduler:
                      swap_inclusive: bool, pres_all_on: bool) -> None:
         """Mark groups quiet from a dispatched block's per-group counts.
 
-        ``counts``: [n_act, nblk, >=5] (split, collapse, swap, moved,
+        ``counts``: [n_act, >=5] (split, collapse, swap, moved,
         overflow, ...).  A group is quiet only when the WHOLE block was
         a no-op for it — including moves (the fixed-point requirement)
         and overflow (a truncated winner set witnesses nothing) — and

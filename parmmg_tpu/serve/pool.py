@@ -17,7 +17,7 @@ programs — zero new ``groups.*`` compile-ledger families (gated by
 
 Scheduling: per step, active (admitted, unconverged) slots of each
 bucket are cohorted by cycle index — slots in the same cohort share
-``(flags, pres, wave)`` and are compacted into dense ``[chunk, ...]``
+``(swap, prescreen, wave)`` and are compacted into dense ``[chunk, ...]``
 dispatches with ``parallel.sched.chunk_plans``, ridden through the
 double-buffered ``groups._pipeline_chunks`` pipeline.  A tenant
 retires at its own fixed point (``groups.block_converged`` — the
@@ -400,8 +400,7 @@ class SlotPool:
         already COMMITTED during the fast path (the ``done`` contract
         of ``_pipeline_chunks``) keep their results — their slots
         advanced, and re-dispatching them would apply the cycle wave
-        twice.  Returns [(slot index, counts row [nblk, >=8; 9 with
-        the topo-threaded block: col 8 = dirty-tet count])] for
+        twice.  Returns [(slot index, the cycle's counts row [12])] for
         slots that ran; faulting slots are accounted via
         :meth:`_note_slot_fault` (retried next step, or quarantined
         into ``done``)."""
@@ -409,14 +408,13 @@ class SlotPool:
         from ..obs.metrics import REGISTRY
         import jax.numpy as jnp
         from ..parallel.groups import _pipeline_chunks
-        from ..parallel.sched import cadence_enabled, chunk_plans
+        from ..parallel.sched import chunk_plans
         from ..resilience.faults import FAULTS, faultpoint
         from ..ops.topo_incr import incr_topo_enabled, topo_init_np
         plans = chunk_plans(np.asarray(ids), self.chunk)
-        # smoothing-cadence + incremental-topology enables ride along as
-        # traced scalars (the hotloop_knob_gate contract): same compiled
-        # programs either way
-        cad = jnp.asarray(cadence_enabled())
+        # the incremental-topology enable rides along as a traced scalar
+        # (the hotloop_knob_gate contract): same compiled program either
+        # way
         inc = jnp.asarray(incr_topo_enabled())
         if b.topo is None:
             b.topo = topo_init_np(b.nslots, b.capT)
@@ -427,7 +425,7 @@ class SlotPool:
                     faultpoint("serve.slot_step", key=b.slots[i].tenant)
             parts = _pipeline_chunks(fn, b.stacked, b.met, wave, plans,
                                      self.timers, done=committed,
-                                     extra=(cad, inc), topo=b.topo)
+                                     extra=(inc,), topo=b.topo)
             self.dispatches += len(plans)
             REGISTRY.counter("serve.dispatches").inc(len(plans))
             return list(zip(ids, np.concatenate(parts)))
@@ -452,8 +450,7 @@ class SlotPool:
                     plans1 = chunk_plans(np.asarray([i]), self.chunk)
                     parts1 = _pipeline_chunks(fn, b.stacked, b.met,
                                               wave, plans1, self.timers,
-                                              extra=(cad, inc),
-                                              topo=b.topo)
+                                              extra=(inc,), topo=b.topo)
                     self.dispatches += len(plans1)
                     REGISTRY.counter("serve.dispatches").inc(len(plans1))
                     out.append((i, np.concatenate(parts1)[0]))
@@ -545,9 +542,9 @@ class SlotPool:
         tenants that reached a terminal state (converged/failed) this
         step.
 
-        Slots of one bucket at the same cycle index share (flags, pres,
-        wave) and ride compacted [chunk, ...] dispatches of the SAME
-        cached compiled programs the batch grouped path uses.
+        Slots of one bucket at the same cycle index share (swap,
+        prescreen, wave) and ride compacted [chunk, ...] dispatches of
+        the SAME cached compiled program the batch grouped path uses.
 
         ``on_retire`` (streaming admission, serve/admission.py): when
         given, it is called with each cohort's newly-retired tenants AS
@@ -567,13 +564,11 @@ class SlotPool:
         import jax.numpy as jnp
         from ..obs import trace as otrace
         from ..obs.metrics import REGISTRY
-        from ..ops.adapt import default_cycle_block
         from ..parallel.groups import (_group_block, block_converged,
                                        block_schedule)
 
         self.steps += 1
         done: list[str] = []
-        block = default_cycle_block()
         stepped: set[str] = set()       # tenants dispatched this step
         while True:
             progressed = False
@@ -602,10 +597,9 @@ class SlotPool:
                 for c in sorted(cohorts):
                     ids = cohorts[c]
                     n_done0 = len(done)
-                    nblk = min(block, self.cycles - c)
-                    flags, pres = block_schedule(c, nblk, self.cycles,
-                                                 self.noswap)
-                    fn = _group_block(flags, pres, self.nomove,
+                    swap, pre = block_schedule(c, self.cycles,
+                                               self.noswap)
+                    fn = _group_block(swap, pre, self.nomove,
                                       self.noinsert, self.hausd)
                     stepped.update(b.slots[i].tenant for i in ids)
                     progressed = True
@@ -613,19 +607,18 @@ class SlotPool:
                         b, fn, jnp.asarray(c, jnp.int32), ids, done)
                     for i, crow in rows:
                         s = b.slots[i]
-                        cs = crow.astype(np.int64)           # [nblk, 12]
+                        cs = crow.astype(np.int64)           # [12]
                         st = s.stats
-                        for ib in range(nblk):
-                            st.nsplit += int(cs[ib][0])
-                            st.ncollapse += int(cs[ib][1])
-                            st.nswap += int(cs[ib][2])
-                            st.nmoved += int(cs[ib][3])
-                            st.cycles += 1
+                        st.nsplit += int(cs[0])
+                        st.ncollapse += int(cs[1])
+                        st.nswap += int(cs[2])
+                        st.nmoved += int(cs[3])
+                        st.cycles += 1
                         st.group_dispatches += 1
                         st.sched_extra.setdefault(
                             "ops_per_block", []).append(
-                            int(cs[:, :4].sum()))
-                        if int(cs[:, 4].max()) != 0:
+                            int(cs[:4].sum()))
+                        if int(cs[4]) != 0:
                             # batch regrow semantics: promote the
                             # post-run state, re-run the SAME block
                             # next step
@@ -635,8 +628,8 @@ class SlotPool:
                                 s.failed = str(e)
                                 done.append(s.tenant)
                             continue
-                        s.c = c + nblk
-                        if block_converged(cs, flags, self.noswap) \
+                        s.c = c + 1
+                        if block_converged(cs, swap, self.noswap) \
                                 or s.c >= self.cycles:
                             s.converged = True
                             done.append(s.tenant)

@@ -18,8 +18,8 @@ stack bounds and observes its compile count; this module is that layer:
   backend-compile event, it maintains a process-wide **compile
   ledger**: per entry point, the distinct static-shape variants that
   actually compiled, the compile count, cumulative compile seconds and
-  the last static shapes — printed by bench.py / scripts/scale_big.py
-  so churn regressions are visible in every BENCH artifact, and
+  the last static shapes — printed by scripts/scale_big.py
+  so churn regressions are visible in every SCALE artifact, and
   enforced by ``scripts/run_tests.sh --ledger`` via per-entry variant
   budgets;
 - :func:`set_cache_env` / :func:`enable_persistent_cache` — the
@@ -421,8 +421,8 @@ def ledger_diff(old: dict, new: dict) -> list[str]:
     "<worker>/" prefix and compared per worker.  Entries only in
     ``new`` are NOT regressions (fresh programs carry their own
     budgets); a grown variant count on a shared entry is the churn
-    signature bench.py and scripts/scale_big.py flag against the
-    previous BENCH/SCALE artifact."""
+    signature scripts/scale_big.py flags against the previous SCALE
+    artifact."""
     def flatten(d: dict, prefix: str = "") -> dict:
         out = {}
         for k, v in (d or {}).items():
@@ -464,8 +464,8 @@ def extract_artifact_ledger(doc) -> dict:
 def regressions_vs_latest_artifact(root: str, pattern: str,
                                    ledger: dict) -> list[str]:
     """Diff ``ledger`` against the NEWEST round artifact matching
-    ``pattern`` (e.g. "BENCH_r*.json") under ``root`` — the shared
-    bench-side regression check of bench.py / scripts/scale_big.py.
+    ``pattern`` (e.g. "SCALE_r*.json") under ``root`` — the shared
+    regression check of scripts/scale_big.py and scripts/serve_bench.py.
     Artifacts without a ledger compare clean (the first governed round
     seeds the baseline)."""
     import glob

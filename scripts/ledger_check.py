@@ -112,7 +112,7 @@ def grouped_sched_gate() -> int:
     def grp_variants():
         return variants_by_prefix("groups.")
 
-    # save/restore the operator's knob values (bench.py does the same)
+    # save/restore the operator's knob values
     prev = {k: os.environ.get(k)
             for k in ("PARMMG_GROUP_CHUNK", "PARMMG_GROUP_SCHED",
                       "PARMMG_DEVICE_MASK")}
@@ -157,13 +157,13 @@ def grouped_sched_gate() -> int:
 
 def hotloop_knob_gate() -> int:
     """Hot-loop knob compile-family gate (the cycle-cost demolition
-    attacks, README "Hot-loop cycle costs"): flipping the smoothing
-    cadence, the facesort swap pairing, the donor-band collapse apply,
-    the Pallas scoring prep or the Pallas sort engine may not mint a
-    single new ``groups.*``
+    attacks, README "Hot-loop cycle costs"): flipping the incremental
+    topology engine, the facesort swap pairing, the donor-band collapse
+    apply, the Pallas scoring prep or the Pallas sort engine may not
+    mint a single new ``groups.*``
     compile family in a warm process.  Two distinct mechanisms back
-    this: PARMMG_SMOOTH_CADENCE and PARMMG_INCR_TOPO are TRACED device
-    scalars of the compiled block (like the quiet mask — toggling
+    this: PARMMG_INCR_TOPO is a TRACED device
+    scalar of the compiled block (like the quiet mask — toggling
     changes an input value, never the program; the incremental path's
     band/table shapes are capT-static ladder rungs, so the knob-on arm
     adds no shape families either), while the facesort / band / score /
@@ -181,9 +181,8 @@ def hotloop_knob_gate() -> int:
                                                variants_by_prefix)
     from parmmg_tpu.utils.fixtures import cube_mesh
 
-    KNOBS = ("PARMMG_SMOOTH_CADENCE", "PARMMG_SWAP_FACESORT",
-             "PARMMG_COLLAPSE_BAND", "PARMMG_PALLAS_SCORE",
-             "PARMMG_INCR_TOPO")
+    KNOBS = ("PARMMG_SWAP_FACESORT", "PARMMG_COLLAPSE_BAND",
+             "PARMMG_PALLAS_SCORE", "PARMMG_INCR_TOPO")
 
     def run(setting: str):
         for k in KNOBS:
@@ -217,7 +216,7 @@ def hotloop_knob_gate() -> int:
     assert v0.get("groups.adapt_block", 0) >= 1, \
         "hot-loop knob scenario no longer exercises groups.adapt_block"
     print("--- hot-loop knob scenario "
-          "(cadence/facesort/band/score/sort)")
+          "(facesort/band/score/sort/incr topo)")
     if v1 != v0:
         print("HOT-LOOP KNOB COMPILE-FAMILY REGRESSIONS (knobs-on run "
               f"added variants vs knobs-off): {v0} -> {v1}",
@@ -231,7 +230,7 @@ def hotloop_knob_gate() -> int:
             print(f"  {v}", file=sys.stderr)
         return 1
     print(f"hot-loop knobs OK: zero new compile families ({v1}; "
-          "cadence, facesort, collapse band, pallas score, incr topo, "
+          "facesort, collapse band, pallas score, incr topo, "
           "pallas sort)")
     return 0
 
@@ -404,7 +403,7 @@ def main() -> int:
     # quiet-group scheduler gate: compaction must reuse the compiled
     # [chunk, ...] group program — zero new families with it enabled
     rc = max(rc, grouped_sched_gate())
-    # hot-loop knob gate: cadence/facesort/band/score toggles add zero
+    # hot-loop knob gate: facesort/band/score/incr-topo toggles add zero
     # groups.* families in a warm process (traced-scalar + warm-cache
     # contracts — see hotloop_knob_gate)
     rc = max(rc, hotloop_knob_gate())

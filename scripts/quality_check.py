@@ -1,8 +1,8 @@
-"""Deterministic CPU quality check of the bench workload (small N).
+"""Deterministic CPU quality check of a shock-metric cube (small N).
 
-Runs the same shock-metric cube adaptation as bench.py at a reduced size
-on the CPU backend and prints final qmin/qmean/ntets — used to compare
-wave-scheduling changes (claim orders, swap cadence) for quality impact.
+Runs whole-mesh adapt cycles (swap every third) under the planar shock
+size map on the CPU backend and prints final qmin/qmean/ntets — used to
+compare wave-scheduling changes (claim orders, swap cadence) for quality impact.
 Run: python scripts/quality_check.py [N] [cycles]
 """
 from __future__ import annotations
@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from parmmg_tpu.core.mesh import make_mesh
-from parmmg_tpu.ops.adapt import adapt_cycles_fused
+from parmmg_tpu.ops.adapt import adapt_cycle
 from parmmg_tpu.ops.analysis import analyze_mesh
 from parmmg_tpu.ops.quality import tet_quality
 from parmmg_tpu.utils.fixtures import cube_mesh, analytic_iso_metric
@@ -37,14 +37,12 @@ def main():
         jnp.asarray(h, mesh.vert.dtype)).at[len(h):].set(1.0)
 
     m, k = mesh, met
-    for b in range(0, cycles, 3):
-        nc = min(3, cycles - b)
-        m, k, counts = adapt_cycles_fused(m, k, jnp.asarray(b, jnp.int32),
-                                          n_cycles=nc, swap_every=3)
-        cs = np.asarray(counts)
-        for r in cs:
-            print(f"  cycle: split {r[0]:6d} collapse {r[1]:6d} "
-                  f"swap {r[2]:6d} move {r[3]:6d} live {r[5]:6d}")
+    for c in range(cycles):
+        m, k, counts = adapt_cycle(m, k, jnp.asarray(c, jnp.int32),
+                                   do_swap=c % 3 == 2)
+        r = np.asarray(counts)
+        print(f"  cycle: split {r[0]:6d} collapse {r[1]:6d} "
+              f"swap {r[2]:6d} move {r[3]:6d} live {r[5]:6d}")
     q = np.asarray(tet_quality(m, k))
     tm = np.asarray(m.tmask)
     qs = np.sort(q[tm])

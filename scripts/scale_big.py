@@ -6,7 +6,7 @@ slots keeps the working set (and the O(n log^2 n) wave sorts) at GROUP
 size while HOST RAM holds the whole mesh (parallel/groups.py, the
 grpsplit_pmmg.c:1551 role).  This script runs grouped adaptation passes
 on a >=1M-tet shock cube and reports per-phase timings + the grouped
-throughput as ONE JSON line (same shape as bench.py).
+throughput as ONE JSON line.
 
 Process layout: each grouped PASS runs in its own subprocess
 (SCALE_WORKER=1 re-entry), with the merged mesh handed over via .npz.
@@ -397,7 +397,7 @@ def main():
     q = np.asarray(tet_quality(mesh2, met2))[tm]
     phases["quality_pull"] = time.perf_counter() - t0
 
-    # throughput accounting mirrors bench.py: live tets examined per
+    # throughput accounting: live tets examined per
     # cycle / adapt wall seconds.  Worker numbers INCLUDE the one-time
     # compiles (reported separately in phases_s as passN_adapt vs
     # passN_total = adapt + state IO + process start).

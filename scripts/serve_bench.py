@@ -32,7 +32,7 @@ p50/p99 latency and queue-depth/occupancy trajectories — the serving
 stack exercised end-to-end as a service, with the same parity + ledger
 gates as the batch-queue mode.
 
-Prints ONE JSON line (bench.py shape) and writes it to SERVE_r<NN>.json
+Prints ONE JSON line and writes it to SERVE_r<NN>.json
 (next free round number; SERVE_OUT overrides).  Knobs: SERVE_TENANTS
 (default 8), SERVE_CYCLES (default 3), SERVE_SLOTS (slots/bucket,
 default 2 so slot recycling is exercised), SERVE_CHUNK (default 1),

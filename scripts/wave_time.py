@@ -1,6 +1,6 @@
 """Per-wave phase timing on the TPU (or CPU).
 
-The cycle-level numbers (block_time.py) say ~600 ms/cycle at bench shapes
+The cycle-level numbers said ~600 ms/cycle at bench shapes
 but the known primitives (adjacency 42 ms, edge table 14 ms, scatters
 ~9 ms) sum to a fraction of that — this script closes the attribution gap
 by timing each WAVE KERNEL separately, K reps fused in one jitted

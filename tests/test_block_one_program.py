@@ -12,9 +12,13 @@ counts for ``groups.adapt_block``, the job's output against the
 parent's, and the detector that names such a variant wherever a
 governed program meets one (``compile.placement_variants``).
 
-The three grouped runs compile once for the module (about a minute and
-a half), each with an empty block cache, so no other module's programs
-are met or left behind.
+A block is one cycle (PR 32): a pass of ``cycles`` cycles dispatches
+``cycles`` blocks through that one executable whichever of them swap or
+bypass the split prescreen, and under ``-noswap`` none swaps.
+
+The five grouped runs compile once for the module (about two minutes),
+each with an empty block cache, so no other module's programs are met
+or left behind.
 """
 import hashlib
 
@@ -63,7 +67,8 @@ def digest(mesh, met):
 def counters():
     snap = REGISTRY.snapshot()["counters"]
     return {k: snap.get(k, 0.0) for k in ("compile.block_programs",
-                                          "compile.placement_variants")}
+                                          "compile.placement_variants",
+                                          "groups.dispatches")}
 
 
 @pytest.fixture(scope="module")
@@ -101,15 +106,16 @@ def runs():
                          and "variant" in r],
         }
 
-    def one_pass():
+    def one_pass(noswap=False):
         m, met, _ = toy(0.3)
-        mesh, met, _ = groups.grouped_adapt_pass(m, met, 2, cycles=3)
+        mesh, met, _ = groups.grouped_adapt_pass(m, met, 2, cycles=3,
+                                                 noswap=noswap)
         return digest(mesh, met)
 
-    def job():
+    def job(noswap=False):
         m, met, ne = toy(0.3)
         mesh, met = groups.grouped_adapt(m, met, target_size=ne // 2,
-                                         niter=2, cycles=3)
+                                         niter=2, cycles=3, noswap=noswap)
         return digest(mesh, met)
 
     def regrown_pass():
@@ -127,6 +133,8 @@ def runs():
     try:
         measured("pass", one_pass)
         measured("job", job)
+        measured("pass-noswap", lambda: one_pass(noswap=True))
+        measured("job-noswap", lambda: job(noswap=True))
         measured("regrow", regrown_pass)
     finally:
         mp.undo()
@@ -134,16 +142,27 @@ def runs():
     return out
 
 
+@pytest.mark.parametrize("noswap", [False, True])
 @pytest.mark.parametrize("which,dispatches", [("pass", 3), ("job", 6)])
-def test_one_executable_a_job_shape(runs, which, dispatches):
-    """Three dispatches of a pass, and the six of a two-pass job whose
-    second pass keeps the first one's capacity, run ONE executable (the
-    parent built two: dispatch 0 of a pass met an uncommitted state)."""
-    run = runs[which]
+def test_one_executable_a_job_shape(runs, which, dispatches, noswap):
+    """A block is one cycle: the three cycles of a pass, and the six of
+    a two-pass job whose second pass keeps the first one's capacity,
+    are as many dispatches of ONE executable (PR 31's parent built two:
+    dispatch 0 of a pass met an uncommitted state), whether a cycle
+    swaps (the last two of a pass) or not, bypasses the split prescreen
+    (the same two) or not, and under ``-noswap``, where none swaps."""
+    run = runs[which + ("-noswap" if noswap else "")]
     assert len(run["blocks"]) == run["ledger"]["calls"] == dispatches
+    assert run["counters"]["groups.dispatches"] == dispatches
+    assert [r["block"] for r in run["blocks"]] == \
+        list(range(3)) * (dispatches // 3)
+    assert all("nblk" not in r for r in run["blocks"])
     assert run["ledger"]["shapes_seen"] == 1
     assert run["ledger"]["compiles"] == 1
     assert run["counters"]["compile.block_programs"] == 1
+    assert run["counters"]["compile.placement_variants"] == 0
+    if noswap:
+        assert sum(r["swap"] for r in run["blocks"]) == 0
 
 
 def test_a_regrown_pass_builds_one_executable_a_capacity(runs):

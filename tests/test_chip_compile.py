@@ -138,12 +138,12 @@ def test_adapt_block_compiles_for_v5e(one_chip, no_cache, monkeypatch):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     monkeypatch.setattr(groups, "_GROUP_BLOCK_CACHE", {})
-    fn = groups._group_block_program(1, False, False, 0.01).__wrapped__
+    fn = groups._group_block_program(False, False, 0.01).__wrapped__
     compiled = fn.lower(
         jax.tree.map(sds, stacked), sds(met_s), arr((), jnp.int32),
-        arr((2,), jnp.bool_), arr((), jnp.bool_), arr((), jnp.bool_),
+        arr((2,), jnp.bool_), arr((), jnp.bool_),
         jax.tree.map(sds, topo_init(stacked.tet.shape[1], stack=2)),
-        arr((1,), jnp.bool_), arr((1,), jnp.bool_),
+        arr((), jnp.bool_), arr((), jnp.bool_),
     ).compile()
     txt = compiled.as_text()
     for kernel in ("edge_length_iso", "score_count", "score3_count",

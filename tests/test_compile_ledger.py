@@ -78,8 +78,8 @@ def test_session_id_guard_and_multiway_run_guard():
 
 
 def test_ledger_diff_flags_variant_growth():
-    """The bench-side regression comparison (ledger_check.py --diff /
-    bench.py vs the previous BENCH artifact): growth on a shared entry
+    """The regression comparison (ledger_check.py --diff, scale_big.py
+    vs the previous SCALE artifact): growth on a shared entry
     is flagged, new entries and equal counts are not, and the nested
     per-worker shape scale_big emits is flattened per worker."""
     old = {"a": {"variants": 1}, "b": {"variants": 2}}

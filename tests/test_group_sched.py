@@ -28,11 +28,11 @@ from parmmg_tpu.parallel.sched import (
 # ---------------------------------------------------------------------------
 # host-side state machine (tier-1: no compiles)
 # ---------------------------------------------------------------------------
-def _counts(n_act, nblk=1, at=None):
-    """Zero count block [n_act, nblk, 8]; at={(g, cycle, col): v}."""
-    c = np.zeros((n_act, nblk, 8), np.int32)
-    for (g, i, col), v in (at or {}).items():
-        c[g, i, col] = v
+def _counts(n_act, at=None):
+    """A block's zero counts [n_act, 8]; at={(g, col): v}."""
+    c = np.zeros((n_act, 8), np.int32)
+    for (g, col), v in (at or {}).items():
+        c[g, col] = v
     return c
 
 
@@ -44,7 +44,7 @@ def test_sched_marks_skips_and_compacts():
     assert [(list(i), n) for i, n in plans] == [([0, 1], 2), ([2, 3], 2)]
     assert s.dispatches == 2 and s.saved_dispatches == 1
     # swap-inclusive prescreen-on block: groups 1 and 3 all-zero
-    s.record_block(act, _counts(4, 2, {(0, 0, 0): 5, (2, 1, 2): 1}),
+    s.record_block(act, _counts(4, {(0, 0): 5, (2, 2): 1}),
                    swap_inclusive=True, pres_all_on=True)
     assert list(s.level[:4]) == [0, LEVEL_PRE, 0, LEVEL_PRE]
     # prescreen-on block skips PRE groups; compaction stays dense
@@ -76,11 +76,11 @@ def test_sched_needs_swap_and_clean_overflow():
     assert (s.level[:2] == 0).all()
     # overflow (col 4) vetoes quietness: a truncated winner set is not
     # a convergence witness
-    s.record_block(act, _counts(2, at={(0, 0, 4): 1}), True, True)
+    s.record_block(act, _counts(2, at={(0, 4): 1}), True, True)
     assert s.level[0] == 0 and s.level[1] == LEVEL_PRE
     # moves (col 3) veto quietness too: smoothing is part of the fixed
     # point
-    s.record_block(act, _counts(2, at={(0, 0, 3): 7}), True, True)
+    s.record_block(act, _counts(2, at={(0, 3): 7}), True, True)
     assert s.level[0] == 0
 
 
@@ -405,16 +405,15 @@ def test_sched_saves_dispatches_and_quiet_fixed_point(monkeypatch):
     stacked, met_s = split_to_shards(m2, met2, part, n, cap_mult=3.0)
     calm = jax.tree.map(lambda a: a[1:2], stacked)
     kcalm = met_s[1:2]
-    step = _group_block((True,), (False,), True, False, None)
+    step = _group_block(True, False, True, False, None)
     on = jnp.ones(1, bool)
-    cad = jnp.asarray(True)
     from parmmg_tpu.ops.topo_incr import topo_init
     inc = jnp.asarray(False)
     tp = topo_init(calm.tet.shape[1], stack=1)
-    m1, k1, c1, tp = step(calm, kcalm, jnp.asarray(0, jnp.int32), on, cad,
+    m1, k1, c1, tp = step(calm, kcalm, jnp.asarray(0, jnp.int32), on,
                           inc, tp)
     assert int(np.asarray(c1)[..., :5].sum()) == 0, np.asarray(c1)
-    m2_, k2, c2, _ = step(m1, k1, jnp.asarray(1, jnp.int32), on, cad,
+    m2_, k2, c2, _ = step(m1, k1, jnp.asarray(1, jnp.int32), on,
                           inc, tp)
     assert int(np.asarray(c2)[..., :5].sum()) == 0
     for f in MESH_FIELDS:

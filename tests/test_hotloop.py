@@ -2,9 +2,8 @@
 
 Tier-1 (fast) coverage: the face-pair-from-sort table against the
 legacy ``adja`` pairing, the donor-band width math, the fused top-k
-scoring prep (jnp reference AND interpret-mode Pallas kernels), and
-the smoothing-cadence parity on a fused block.  The slow marks re-run
-the bit-parity claims through the full waves per knob — including the
+scoring prep (jnp reference AND interpret-mode Pallas kernels).  The
+slow marks re-run the bit-parity claims through the full waves per knob — including the
 polish pass — exactly as the production drivers call them.
 """
 import os
@@ -124,7 +123,7 @@ def test_face_pairs_match_adja():
         q_tet = quality_from_points(
             m.vert[m.tet], None if m6 is None else m6[m.tet])
         ref = _pair_fields_adja(m, q_tet, m.capT)
-        m2, *got = _pair_fields_facesort(m, q_tet, m.capT, True)
+        m2, *got = _pair_fields_facesort(m, q_tet, m.capT)
         # the candidate set must agree EVERYWHERE; t2/f2 carry dead
         # fill on non-candidate rows (different fill per path, never
         # consumed: every downstream read in swap23_wave is gated by
@@ -145,15 +144,12 @@ def test_face_pairs_match_adja():
 def test_knob_readers_default_on(monkeypatch):
     from parmmg_tpu.ops.pallas_kernels import pallas_score_enabled
     from parmmg_tpu.ops.swap import swap_facesort_enabled
-    from parmmg_tpu.parallel.sched import cadence_enabled
-    for name, fn in (("PARMMG_SMOOTH_CADENCE", cadence_enabled),
-                     ("PARMMG_PALLAS_SCORE", pallas_score_enabled)):
-        monkeypatch.delenv(name, raising=False)
-        assert fn() is True, f"{name} must default on"
-        monkeypatch.setenv(name, "0")
-        assert fn() is False
-        monkeypatch.setenv(name, "1")
-        assert fn() is True
+    monkeypatch.delenv("PARMMG_PALLAS_SCORE", raising=False)
+    assert pallas_score_enabled() is True, "must default on"
+    monkeypatch.setenv("PARMMG_PALLAS_SCORE", "0")
+    assert pallas_score_enabled() is False
+    monkeypatch.setenv("PARMMG_PALLAS_SCORE", "1")
+    assert pallas_score_enabled() is True
     # facesort defaults platform-aware: on iff the backend is a TPU
     # (the CPU sort costs more than the adja rebuild it replaces);
     # explicit 1/0 force either path on any backend
@@ -163,30 +159,6 @@ def test_knob_readers_default_on(monkeypatch):
     assert swap_facesort_enabled() is False
     monkeypatch.setenv("PARMMG_SWAP_FACESORT", "1")
     assert swap_facesort_enabled() is True
-
-
-# ---- smoothing cadence (attack 3) -------------------------------------------
-
-def test_fused_cadence_parity():
-    """cadence-on vs cadence-off over a fused block is bit-identical:
-    the skip only ever fires where smoothing is a proven identity."""
-    from parmmg_tpu.ops.adapt import adapt_cycles_fused_impl
-    m = _cube(2)
-    met = jnp.full(m.capP, 0.75, m.vert.dtype)
-    w0 = jnp.asarray(0, jnp.int32)
-
-    run_off = jax.jit(partial(adapt_cycles_fused_impl, n_cycles=3))
-    run_on = jax.jit(lambda mm, kk, ww, cad: adapt_cycles_fused_impl(
-        mm, kk, ww, n_cycles=3, cadence=cad))
-    m_off, k_off, c_off = run_off(m, met, w0)
-    m_on, k_on, c_on = run_on(m, met, w0, jnp.asarray(True))
-    _assert_mesh_equal(m_off, m_on, "cadence")
-    assert (np.asarray(k_off) == np.asarray(k_on)).all()
-    assert (np.asarray(c_off) == np.asarray(c_on)).all()
-    # cadence=False through the SAME gated program is the off arm too
-    m_f, k_f, c_f = run_on(m, met, w0, jnp.asarray(False))
-    _assert_mesh_equal(m_off, m_f, "cadence=False scalar")
-    assert (np.asarray(c_off) == np.asarray(c_f)).all()
 
 
 # ---- slow per-knob wave parity ----------------------------------------------

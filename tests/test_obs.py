@@ -568,7 +568,7 @@ def test_grouped_job_emits_the_span_tree(grouped_job):
     assert job["counters"]["groups.dispatches"] == len(blocks)
     assert sum(r["dur"] for r in blocks) == pytest.approx(
         job["counters"]["groups.pipeline.compute_s"], rel=1e-9)
-    assert all({"split", "collapse", "swap", "moved", "nblk",
+    assert all({"split", "collapse", "swap", "moved", "block",
                 "active"} <= set(r) for r in blocks)
     assert sum(r["split"] for r in blocks) > 0
     assert job["counters"]["api.set_s"] > 0

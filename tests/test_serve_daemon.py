@@ -308,7 +308,7 @@ def test_stream_midstep_rerent(monkeypatch):
     def fake_dispatch(self, b, fn, wave, ids, done):
         calls.append([b.slots[i].tenant for i in ids])
         self.dispatches += len(ids)
-        return [(i, np.zeros((1, 8), np.int64)) for i in ids]
+        return [(i, np.zeros(8, np.int64)) for i in ids]
 
     monkeypatch.setattr(SlotPool, "_dispatch_cohort", fake_dispatch)
     vert, tet, met = _stub_mesh()

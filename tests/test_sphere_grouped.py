@@ -13,9 +13,12 @@ surface what it says it is, each with a case that fails without it:
   surface (``ops/smooth.smooth_wave``).
 
 Two jobs are compiled for the module (the grouped one and the same mesh
-as one group), about a minute each on the CPU.
+as one group), about a minute each on the CPU.  The one-group job is the
+suite's whole-mesh ``adapt_mesh`` run: its output is also held to the
+parent's, to the bit (PR 32).
 """
 import dataclasses
+import hashlib
 
 import numpy as np
 import pytest
@@ -29,6 +32,7 @@ from parmmg_tpu.core import constants as C
 from parmmg_tpu.core.mesh import compact, make_mesh, with_capacity
 from parmmg_tpu.obs import trace as otrace
 from parmmg_tpu.obs.metrics import REGISTRY
+from parmmg_tpu.ops import adapt
 from parmmg_tpu.ops.analysis import (analyze_mesh, boundary_vertex_normals,
                                      carries_normal)
 from parmmg_tpu.parallel.distribute import split_to_shards
@@ -46,6 +50,13 @@ H_SURF = 0.16           # the size the job is asked for on the sphere
 VERTEX_LIMIT = 1.5e-3
 QMIN_FLOOR = 1e-3
 BALL = 4.0 * np.pi / 3.0
+# the one-group job below at the parent commit 3856255, whose wide
+# convergence check passed ``wide=True`` (CPU, my run, PR 32)
+PARENT_ONE_GROUP = {
+    "ntets": 5979, "nverts": 1391, "wide_checks": 3,
+    "sha256": ("4a7668c3feb84fca00093c6ee959b62f"
+               "aa61229391b3e5680bdf2f1b6fb23a3c"),
+}
 
 
 def shell_metric(vert):
@@ -89,7 +100,19 @@ def grouped():
 
 @pytest.fixture(scope="module")
 def one_group():
-    return run_sphere(mesh_size=100000)
+    """The same mesh as ONE group: ``adapt_mesh`` on the whole of it,
+    with the keywords of every cycle it dispatched."""
+    cycles = []
+    cycle = adapt.adapt_cycle
+
+    def spy(*args, **kw):
+        cycles.append(kw)
+        return cycle(*args, **kw)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(adapt, "adapt_cycle", spy)
+        out = run_sphere(mesh_size=100000)
+    out["cycles"] = cycles
+    return out
 
 
 def oracle(vert, tet):
@@ -151,6 +174,24 @@ def test_the_seam_does_not_show(grouped, one_group):
     assert "groups.dispatches" not in one_group["counters"] or \
         one_group["counters"]["groups.dispatches"] == 0
     assert abs(a["volume"] - b["volume"]) < 2e-3 * BALL
+
+
+def test_the_whole_mesh_job_equals_the_parents(one_group):
+    """``adapt_mesh`` checks convergence once more at a quarter of the
+    budget divisor with the split prescreen off before it accepts it;
+    that is all the parent's ``wide=True`` meant, and the job hands
+    back the parent's mesh to the bit."""
+    wide = [kw for kw in one_group["cycles"] if kw["budget_div"] == 2]
+    assert len(wide) == PARENT_ONE_GROUP["wide_checks"]
+    assert all(kw["prescreen"] is False and kw["do_swap"] for kw in wide)
+    assert all(kw["prescreen"] is True for kw in one_group["cycles"]
+               if kw["budget_div"] == 8)
+    h = hashlib.sha256()
+    h.update(np.ascontiguousarray(one_group["tet"]).tobytes())
+    h.update(np.ascontiguousarray(one_group["vert"]).tobytes())
+    assert {"ntets": len(one_group["tet"]),
+            "nverts": len(one_group["vert"]), "wide_checks": len(wide),
+            "sha256": h.hexdigest()} == PARENT_ONE_GROUP
 
 
 def test_surface_counts_reach_spans_and_counters(grouped):

@@ -198,7 +198,7 @@ def test_merge_prefix_pallas_interpret_parity():
 @pytest.mark.slow
 def test_grouped_incr_knob_parity(monkeypatch):
     """PARMMG_INCR_TOPO on/off through the full grouped pass — waves,
-    fused blocks, regrows AND the sliver polish phase — is bit-for-bit
+    cycle blocks, regrows AND the sliver polish phase — is bit-for-bit
     identical, with identical op counters."""
     from parmmg_tpu.ops.adapt import AdaptStats
     from parmmg_tpu.ops.analysis import analyze_mesh
