@@ -192,7 +192,7 @@ def _merged_polish(mesh, met, info, hausd, stats, tim):
     from .obs.metrics import REGISTRY
     from .ops.adapt import sliver_polish
     from .utils.placement import host_staging
-    ops = 0
+    ops = col_skipped = adj_skipped = 0
     # every program of the tail costs what its capacity is, not what its
     # content is: the two counters say how much of it is padding
     n_live = int(np.asarray(mesh.tmask).sum())
@@ -207,11 +207,17 @@ def _merged_polish(mesh, met, info, hausd, stats, tim):
                     do_collapse=not info.noinsert,
                     do_swap=not info.noswap,
                     do_smooth=not info.nomove, hausd=hausd, budget=budget)
-                ncol, nswap, nmoved, _, nhveto, nbmoved = \
+                ncol, nswap, nmoved, _, nhveto, nbmoved, nbad, col, adj = \
                     np.asarray(counts).tolist()
-                # a polish wave splits nothing: bsplit is 0 by what it is
+                # a polish wave splits nothing: bsplit is 0 by what it is;
+                # bad: tets under the sliver threshold at the wave's
+                # entry; col, adj: did the collapse stage and the exit
+                # adjacency run (a stage without an input does not)
                 sp.set(collapse=ncol, swap=nswap, moved=nmoved,
-                       bsplit=0, hveto=nhveto, bmoved=nbmoved)
+                       bsplit=0, hveto=nhveto, bmoved=nbmoved,
+                       bad=nbad, col=col, adj=adj)
+            col_skipped += int(not info.noinsert and not col)
+            adj_skipped += int(not adj)
             stats.add_surface(hveto=nhveto, bmoved=nbmoved)
             stats.ncollapse += ncol
             stats.nswap += nswap
@@ -221,6 +227,9 @@ def _merged_polish(mesh, met, info, hausd, stats, tim):
             if ncol == 0 and nswap == 0:
                 break
     REGISTRY.counter("tail.polish_ops").inc(ops)
+    # stages the waves skipped for want of an input, zeros too
+    REGISTRY.counter("tail.collapse_skipped").inc(col_skipped)
+    REGISTRY.counter("tail.exit_adj_skipped").inc(adj_skipped)
     return mesh, ops
 
 

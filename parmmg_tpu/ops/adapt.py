@@ -405,8 +405,22 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     whose capacity is no measure of its content says what it wants in
     rows (driver.polish_budget for a merged mesh).
 
-    Returns (mesh, counts[6] = [ncollapse, nswap, nmoved, live_tets,
-    hveto, bmoved]): the last two as in a cycle's ``SURF_COLS``.
+    A stage runs only when the wave can see that it has an input.  The
+    collapse stage (edge table, lengths, normals, tangents, candidacy)
+    is ``lax.cond``-skipped when no live tet is under ``sliver_q``: its
+    candidates are edges of such tets, so it could apply and veto
+    nothing.  The exit ``build_adjacency`` is skipped when ``swap23``
+    applied no swap: only ``swap23`` and the smoothing wave (coordinates
+    alone) run after the build ``swap23`` consumed, so that adjacency is
+    still the mesh's (with the swaps off, or ``swap23`` paired off the
+    face sort, the wave built none: the exit builds it).  Both are exact,
+    and ``mesh.adja`` is valid on return either way.
+
+    Returns (mesh, counts[9] = [ncollapse, nswap, nmoved, live_tets,
+    hveto, bmoved, bad, col, adj]): ``hveto``, ``bmoved`` as in a
+    cycle's ``SURF_COLS``; ``bad`` the live tets under ``sliver_q`` at
+    entry (0 with ``do_collapse`` off: not counted), ``col`` 1 when the
+    collapse stage ran, ``adj`` 1 when the exit adjacency was rebuilt.
     """
     from .adjacency import boundary_edge_tags
     if active is not None:
@@ -417,24 +431,33 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
                 do_smooth=do_smooth, hausd=hausd, budget=budget)
 
         def _skip(m):
-            counts = jnp.zeros(6, jnp.int32).at[3].set(
+            counts = jnp.zeros(9, jnp.int32).at[3].set(
                 jnp.sum(m.tmask, dtype=jnp.int32))
             return m, counts
         return jax.lax.cond(active, _run, _skip, mesh)
-    ncol = nhveto = jnp.zeros((), jnp.int32)
-    nswap = jnp.zeros((), jnp.int32)
-    nmoved = nbmoved = jnp.zeros((), jnp.int32)
+    zero = jnp.zeros((), jnp.int32)
+    ncol = nhveto = nbad = nswap = nmoved = nbmoved = zero
+    rebuild = None      # traced bool once the wave holds an adjacency
     if do_collapse:
-        # the polish widens the compaction budget (budget_div=2, or the
-        # caller's ``budget`` in rows) so the quality pass covers the
-        # full sliver population instead of the worst K only.  The
-        # budget is meant in rows of CONTENT: 1.5x the live tets on a
-        # mesh at 3x; dead rows are never candidates
-        col = collapse_wave(mesh, met, sliver_q=sliver_q, hausd=hausd,
-                            budget_div=2, budget=budget)
-        mesh = jax.lax.cond(col.surface_changed, boundary_edge_tags,
-                            lambda m: m, col.mesh)
-        ncol, nhveto = col.ncollapse, col.nhveto
+        from .quality import quality_from_points
+        q_tet = quality_from_points(
+            mesh.vert[mesh.tet], None if met.ndim == 1 else met[mesh.tet])
+        nbad = jnp.sum(mesh.tmask & (q_tet < sliver_q), dtype=jnp.int32)
+
+        def _collapse(m):
+            # the polish widens the compaction budget (budget_div=2, or
+            # the caller's ``budget`` in rows) so the quality pass covers
+            # the full sliver population instead of the worst K only.
+            # The budget is meant in rows of CONTENT: 1.5x the live tets
+            # on a mesh at 3x; dead rows are never candidates
+            col = collapse_wave(m, met, sliver_q=sliver_q, hausd=hausd,
+                                budget_div=2, budget=budget, q_tet=q_tet)
+            m = jax.lax.cond(col.surface_changed, boundary_edge_tags,
+                             lambda m: m, col.mesh)
+            return m, col.ncollapse, col.nhveto
+
+        mesh, ncol, nhveto = jax.lax.cond(
+            nbad > 0, _collapse, lambda m: (m, zero, zero), mesh)
     if do_swap:
         from .swapgen import swapgen_wave
         from .swap import swap_facesort_enabled
@@ -450,6 +473,9 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
         else:
             mesh = build_adjacency(sgn.mesh)    # consumed by swap23
             s23 = swap23_wave(mesh, met, budget_div=2, budget=budget)
+            # without a winner swap23 hands back the mesh it was given,
+            # adjacency and all
+            rebuild = s23.nswap > 0
         mesh = s23.mesh
         nswap = sew.nswap + sgn.nswap + s23.nswap
     if do_smooth:
@@ -459,10 +485,15 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
                          hausd=hausd)
         mesh = sm.mesh
         nmoved, nbmoved = sm.nmoved, sm.nbdy
-    mesh = build_adjacency(mesh)                # exit contract
+    if rebuild is None:                         # exit contract
+        mesh, rebuild = build_adjacency(mesh), jnp.ones((), bool)
+    else:
+        mesh = jax.lax.cond(rebuild, build_adjacency, lambda m: m, mesh)
     counts = jnp.stack([ncol, nswap, nmoved,
                         jnp.sum(mesh.tmask, dtype=jnp.int32),
-                        nhveto, nbmoved])
+                        nhveto, nbmoved, nbad,
+                        (nbad > 0).astype(jnp.int32),
+                        rebuild.astype(jnp.int32)])
     return mesh, counts
 
 
@@ -588,7 +619,7 @@ def adapt_mesh(mesh: Mesh, met: jax.Array, max_cycles: int = 50,
                                      do_collapse=not noinsert,
                                      do_swap=not noswap,
                                      do_smooth=not nomove, hausd=hausd)
-        nc, nw, nm, _, nhv, nbm = (int(v) for v in np.asarray(counts))
+        nc, nw, nm, _, nhv, nbm = (int(v) for v in np.asarray(counts)[:6])
         stats.add_surface(hveto=nhv, bmoved=nbm)
         stats.ncollapse += nc
         stats.nswap += nw

@@ -77,7 +77,8 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
                   et=None, lens=None,
                   stale_tets: jax.Array | None = None,
                   vtan: jax.Array | None = None,
-                  vn: jax.Array | None = None) -> CollapseResult:
+                  vn: jax.Array | None = None,
+                  q_tet: jax.Array | None = None) -> CollapseResult:
     """One independent-set collapse wave.
 
     Normal mode: contract edges shorter than ``lmin`` (Mmg's colver over
@@ -95,6 +96,10 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
     tet is deferred to the next wave (its table row describes pre-split
     geometry).  Validity/quality below run against the CURRENT (post-
     split) mesh arrays, which are identical on every unmodified slot.
+
+    ``q_tet``: sliver mode only, the per-tet quality of ``mesh`` in
+    ``met`` where the caller has it already (sliver_polish_impl computes
+    it to decide whether the wave has an input at all).
     """
     capT, capP = mesh.capT, mesh.capP
     if et is None:
@@ -116,10 +121,11 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
                 jnp.repeat(stale_tets, 4), mode="drop")[:capP]
             short = short & ~stale_v[va_f] & ~stale_v[vb_f]
     else:
-        from .quality import quality_from_points
-        q_tet = quality_from_points(
-            mesh.vert[mesh.tet],
-            None if met.ndim == 1 else met[mesh.tet])
+        if q_tet is None:
+            from .quality import quality_from_points
+            q_tet = quality_from_points(
+                mesh.vert[mesh.tet],
+                None if met.ndim == 1 else met[mesh.tet])
         bad_tet = mesh.tmask & (q_tet < sliver_q)
         bad_edge = jnp.zeros(et.ev.shape[0], bool).at[
             et.edge_id.reshape(-1)].max(

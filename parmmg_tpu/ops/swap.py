@@ -58,18 +58,18 @@ def swap_facesort_enabled() -> bool:
     paths produce the same bits, so a stale jit cache entry is only a
     perf choice, never a correctness one.
 
-    Platform-aware default (like the Pallas scoring dispatch): unset
-    means on for TPU, off elsewhere — the CPU backend's sort is slow
-    enough that the face re-sort costs more than the adja rebuild it
-    replaces (measured ~+7% s/cycle on the grouped bench), while on
-    TPU the sort amortizes and the rebuild's gather/compare does not.
-    ``1``/``0`` force the path on any backend (the parity tests and
-    the ledger gate force both arms on CPU)."""
+    Unset means on where the program being traced is PLACED on a TPU,
+    off elsewhere: the CPU backend's sort is slow enough that the face
+    re-sort costs more than the adja rebuild it replaces (~+7% s/cycle),
+    and a TPU process places its tail on the host's CPU backend
+    (``host_staging``), where the process default chose wrongly.  ``1``/
+    ``0`` force the path on any backend (the parity tests do, on CPU)."""
     import os
     v = os.environ.get("PARMMG_SWAP_FACESORT", "")
     if v == "":
         import jax
-        return jax.default_backend() == "tpu"
+        dev = jax.config.jax_default_device or jax.default_backend()
+        return getattr(dev, "platform", dev) == "tpu"
     return v != "0"
 
 

@@ -1,0 +1,239 @@
+"""Where a merged polish wave's seconds go, stage by stage.
+
+Runs one job of a benchmark cell through ``ParMesh.run``, keeps the merged
+mesh as it stands at the entry of ``driver._merged_polish``, and replays
+the polish on it as SEVEN programs a wave (the stages of
+``ops/adapt.sliver_polish_impl``, every one run whatever its input), each
+timed from dispatch to ``block_until_ready``, on the host's CPU backend
+where the driver stages the tail.  A diagnostic, not a contract: it
+mirrors the wave's composition as of PR 33.
+
+    python scripts/polish_stages.py --cell iso-growth --seed 21 \
+        [--save DIR] [--from DIR] [--out FILE.json]
+
+``--save DIR`` also writes the captured mesh (``DIR/<cell>-<seed>.npz``);
+``--from DIR`` replays such a file without running a job, so a mesh made
+on one machine can be timed on another's host.  The job's line holds the
+sha256 of the output's vertices, tets and metric: two checkouts that
+print the same three made the same mesh.
+"""
+from __future__ import annotations
+
+import argparse
+import hashlib
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+sys.path[:0] = [os.path.join(ROOT, "benchmarks"), ROOT]
+
+import numpy as np  # noqa: E402
+
+STAGES = ("collapse", "swap_edges", "swapgen", "adjacency", "swap23",
+          "smooth", "exit_adjacency")
+
+
+def say(*a):
+    print(*a, file=sys.stderr, flush=True)
+
+
+def load_json(*parts):
+    with open(os.path.join(*parts)) as f:
+        return json.load(f)
+
+
+def cell_config(name: str) -> dict:
+    bench = load_json(ROOT, "BENCHMARK.json")
+    cell = next(w for w in bench["workloads"] if w["name"] == name)
+    return load_json(ROOT, next(
+        c for c in bench["configs"] if c["name"] == cell["config"])["file"])
+
+
+def capture_job(cell: str, seed: int) -> tuple[dict, dict]:
+    """One job of ``cell``; returns (what the merged polish was handed,
+    the job's digest)."""
+    import jax
+    import inputs
+    import job as jobmod
+    from parmmg_tpu import driver
+    from parmmg_tpu.obs.trace import TRACER
+    config = cell_config(cell)
+    seen = {}
+    inner = driver._merged_polish
+
+    def spy(mesh, met, info, hausd, stats, tim):
+        seen.update(mesh=jax.tree.map(np.array, mesh), met=np.array(met),
+                    hausd=hausd)
+        return inner(mesh, met, info, hausd, stats, tim)
+
+    driver._merged_polish = spy
+    try:
+        res = jobmod.run_job(inputs.build_input(config, seed),
+                             config["options"])
+    finally:
+        driver._merged_polish = inner
+    sha = {k: hashlib.sha256(np.ascontiguousarray(res[k]).tobytes())
+           .hexdigest() for k in ("vert", "tet", "met")}
+    waves = [{k: r[k] for k in ("collapse", "swap", "moved", "bad", "col",
+                                "adj", "dur") if k in r}
+             for r in TRACER.ring if r.get("name") == "polish wave"]
+    digest = {"rc": res["rc"], "seconds": res["seconds"],
+              "ntets": len(res["tet"]), "sha256": sha, "waves": waves,
+              "counters": {k: v for k, v in res["counters"].items()
+                           if k.startswith("tail.")}}
+    return seen, digest
+
+
+def save(seen: dict, path: str) -> None:
+    import dataclasses
+    leaves = {f"mesh.{f.name}": getattr(seen["mesh"], f.name)
+              for f in dataclasses.fields(seen["mesh"])
+              if isinstance(getattr(seen["mesh"], f.name), np.ndarray)}
+    np.savez_compressed(
+        path, met=seen["met"], hausd=np.float64(
+            np.nan if seen["hausd"] is None else seen["hausd"]), **leaves)
+
+
+def restore(path: str) -> dict:
+    import dataclasses
+    from parmmg_tpu.core.mesh import Mesh
+    z = np.load(path)
+    fields = {f.name: z[f"mesh.{f.name}"] for f in dataclasses.fields(Mesh)
+              if f"mesh.{f.name}" in z}
+    hausd = float(z["hausd"])
+    return {"mesh": Mesh(**fields), "met": z["met"],
+            "hausd": None if np.isnan(hausd) else hausd}
+
+
+def replay(seen: dict, waves: int = 8, sliver_q: float = 0.2) -> list[dict]:
+    """The polish on ``seen`` as seven programs a wave; per wave, each
+    stage's seconds and what it applied, and the tets under ``sliver_q``
+    at the wave's entry.  Wave 0 pays the compiles."""
+    import jax
+    import jax.numpy as jnp
+    from functools import partial
+    from parmmg_tpu.driver import polish_budget
+    from parmmg_tpu.ops.adjacency import (boundary_edge_tags,
+                                          build_adjacency)
+    from parmmg_tpu.ops.collapse import collapse_wave
+    from parmmg_tpu.ops.quality import quality_from_points
+    from parmmg_tpu.ops.smooth import smooth_wave
+    from parmmg_tpu.ops.swap import swap23_wave, swap_edges_wave
+    from parmmg_tpu.ops.swapgen import swapgen_wave
+    from parmmg_tpu.utils.placement import host_staging
+    hausd = seen["hausd"]
+    n_live = int(seen["mesh"].tmask.sum())
+    budget = polish_budget(n_live)
+    kw = dict(budget_div=2, budget=budget)
+
+    def collapse(m, k):
+        col = collapse_wave(m, k, sliver_q=sliver_q, hausd=hausd, **kw)
+        m = jax.lax.cond(col.surface_changed, boundary_edge_tags,
+                         lambda m: m, col.mesh)
+        return m, col.ncollapse
+
+    def count_bad(m, k):
+        q = quality_from_points(
+            m.vert[m.tet], None if k.ndim == 1 else k[m.tet])
+        return jnp.sum(m.tmask & (q < sliver_q), dtype=jnp.int32)
+
+    def of(wave_fn, count, **kws):
+        def run(m, k, *a):
+            r = wave_fn(m, k, *a, **kws)
+            return r.mesh, getattr(r, count)
+        return jax.jit(run)
+
+    adjacency = jax.jit(lambda m, k: (build_adjacency(m), jnp.int32(0)))
+    programs = {
+        "collapse": jax.jit(collapse),
+        "swap_edges": of(swap_edges_wave, "nswap", hausd=hausd, **kw),
+        "swapgen": of(swapgen_wave, "nswap", **kw),
+        "adjacency": adjacency,
+        "swap23": of(swap23_wave, "nswap", **kw),
+        "smooth": of(partial(smooth_wave, opt_q=sliver_q, hausd=hausd),
+                     "nmoved"),
+        "exit_adjacency": adjacency,
+    }
+    count_bad = jax.jit(count_bad)
+    rows = []
+    with host_staging():
+        mesh = jax.tree.map(jnp.asarray, seen["mesh"])
+        met = jnp.asarray(seen["met"])
+        say(f"replay: {n_live} live tets at capT {mesh.capT}, budget "
+            f"{budget}, hausd {hausd}")
+        for w in range(waves):
+            row = {"wave": w, "bad": int(count_bad(mesh, met)),
+                   "s": {}, "n": {}}
+            for name in STAGES:
+                args = (jnp.asarray(1000 + w, jnp.int32),) \
+                    if name == "smooth" else ()
+                t0 = time.perf_counter()
+                mesh, n = programs[name](mesh, met, *args)
+                jax.block_until_ready((mesh, n))
+                row["s"][name] = time.perf_counter() - t0
+                row["n"][name] = int(n)
+            say(f"  wave {w}: bad {row['bad']:5d}  " + "  ".join(
+                f"{k} {row['s'][k]:.3f}s/{row['n'][k]}" for k in row["s"]))
+            rows.append(row)
+            if sum(row["n"][k] for k in STAGES[:5]) == 0:
+                break       # the driver's loop ends here too
+    return rows
+
+
+def summary(rows: list[dict]) -> dict:
+    """Mean seconds and share of each stage over the waves after the
+    first (which pays the compiles)."""
+    warm = rows[1:] or rows
+    mean = {k: sum(r["s"][k] for r in warm) / len(warm)
+            for k in warm[0]["s"]}
+    total = sum(mean.values())
+    return {"waves_meaned": len(warm), "wave_s": total,
+            "mean_s": mean,
+            "share_pct": {k: 100.0 * v / total for k, v in mean.items()},
+            "bad_by_wave": [r["bad"] for r in rows],
+            "applied_by_wave": {k: [r["n"][k] for r in rows]
+                                for k in rows[0]["n"]}}
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--cell", required=True)
+    ap.add_argument("--seed", type=int, default=21)
+    ap.add_argument("--save", help="directory to write the captured mesh to")
+    ap.add_argument("--from", dest="src",
+                    help="directory holding a captured mesh: run no job")
+    ap.add_argument("--no-replay", action="store_true",
+                    help="run the job and print its digest only")
+    ap.add_argument("--out", help="write the result here as well")
+    args = ap.parse_args()
+    name = f"{args.cell}-{args.seed}.npz"
+    result = {"cell": args.cell, "seed": args.seed}
+    if args.src:
+        seen = restore(os.path.join(args.src, name))
+    else:
+        seen, result["job"] = capture_job(args.cell, args.seed)
+        say(f"job: {json.dumps(result['job'])}")
+        if args.save:
+            os.makedirs(args.save, exist_ok=True)
+            save(seen, os.path.join(args.save, name))
+    if not args.no_replay:
+        import jax
+        result["platform"] = jax.default_backend()
+        result["host_cores"] = os.cpu_count()
+        result["rows_live"] = int(seen["mesh"].tmask.sum())
+        result["rows_cap"] = int(seen["mesh"].tmask.shape[0])
+        result["waves"] = replay(seen)
+        result["summary"] = summary(result["waves"])
+    line = json.dumps(result)
+    if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+        with open(args.out, "w") as f:
+            f.write(line + "\n")
+    print(line, flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -589,6 +589,23 @@ def test_polish_waves_count_what_they_applied(grouped_job):
     assert last["collapse"] + last["swap"] == 0 or len(waves) == 8
 
 
+def test_polish_waves_say_which_stages_ran(grouped_job):
+    """``bad``, ``col``, ``adj`` on every ``polish wave`` (PR 33): the
+    collapse stage runs iff a tet is under the threshold at the wave's
+    entry, and the two skip counters are the waves' own sums."""
+    for which in ("cold", "warm"):
+        job = grouped_job[which]
+        recs, _ = _tree(job["records"])
+        waves = [r for r in recs if r["name"] == "polish wave"]
+        assert all({"bad", "col", "adj"} <= set(r) for r in waves)
+        assert all(r["col"] == int(r["bad"] > 0) for r in waves)
+        assert all(r["collapse"] == 0 for r in waves if not r["col"])
+        assert job["counters"]["tail.collapse_skipped"] == sum(
+            1 - r["col"] for r in waves)
+        assert job["counters"]["tail.exit_adj_skipped"] == sum(
+            1 - r["adj"] for r in waves)
+
+
 def test_tail_rows_are_counted_once_a_job(grouped_job):
     """``tail.rows_live`` / ``tail.rows_cap``: the mesh the merged tail
     is about to run on, which is the last merge's, at the capacity
