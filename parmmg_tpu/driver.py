@@ -50,8 +50,10 @@ def surface_census(mesh: Mesh) -> dict:
 
 def build_metric(mesh: Mesh, met, info, census: dict | None = None):
     """Metric synthesis path: -hsiz / -optim / user metric / default.
-    ``census``, if given, receives ``bound_verts``: how many vertices'
-    sizes the hausd bound lowered."""
+    ``census``, if given, receives ``bound_verts``, how many vertices'
+    sizes or tensors the hausd bound changed, and what the bound itself
+    reports (``bdy_verts``, how many regular boundary vertices it
+    examined, and ``kappa_max``)."""
     import jax.numpy as jnp
 
     vert = np.asarray(mesh.vert)[np.asarray(mesh.vmask)]
@@ -67,12 +69,18 @@ def build_metric(mesh: Mesh, met, info, census: dict | None = None):
     # sharp edge is indistinguishable from smooth curvature and the
     # curvature estimate blows up at corners
     if info.hausd > 0 and info.angle_detection:
+        from .obs import trace as otrace
         from .ops.metric import hausd_metric_bound
-        bounded = hausd_metric_bound(mesh, met, info.hausd, hmin)
-        if census is not None and bounded.ndim == 1:
-            census["bound_verts"] = int(np.sum(
-                (np.asarray(bounded) < np.asarray(met))
-                & np.asarray(mesh.vmask)))
+        seen = census if census is not None else {}
+        with otrace.span("hausd bound") as sp:
+            bounded = hausd_metric_bound(mesh, met, info.hausd, hmin,
+                                         hmax, census=seen)
+            changed = np.asarray(bounded) != np.asarray(met)
+            if changed.ndim == 2:
+                changed = changed.any(axis=-1)
+            seen["bound_verts"] = int(np.sum(
+                changed & np.asarray(mesh.vmask)))
+            sp.set(**seen)
         met = bounded
     # local bounds BEFORE gradation (Mmg defsiz-then-gradsiz order) so the
     # size jump at a ref-patch boundary is smoothed by -hgrad; re-applied
@@ -262,10 +270,11 @@ def _run_phases(pm) -> tuple[Mesh, object, AdaptStats]:
     with tim("metric") as sp, host_staging():
         # vertices whose size the hausd bound lowered: the curvature's
         # share of the size map (0 on a flat boundary)
-        census = {"bound_verts": 0}
+        census = {"bound_verts": 0, "bdy_verts": 0}
         met = build_metric(mesh, met, info, census)
-        sp.set(**census)
+        sp.set(bound_verts=census["bound_verts"])
         REGISTRY.counter("surf.bound_verts").inc(census["bound_verts"])
+        REGISTRY.counter("surf.bdy_verts").inc(census["bdy_verts"])
 
     # background snapshot for field interpolation (PMMG_create_oldGrp
     # analogue, grpsplit_pmmg.c:207).  Deep copy: adapt_cycle donates its

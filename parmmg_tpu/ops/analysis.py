@@ -37,6 +37,10 @@ from ..core.constants import (
 from .adjacency import build_adjacency
 from .edges import unique_edges
 
+# weight of the second-form fit's priors beside the fan's data, whose
+# normal matrix is scaled to entries of the order of 1
+RIDGE = 1e-3
+
 _IDIR_J = jnp.asarray(IDIR)
 _FACE_EDGES_J = jnp.asarray(FACE_EDGES)
 
@@ -66,7 +70,7 @@ def boundary_vertex_normals(mesh: Mesh) -> jax.Array:
     fp = mesh.vert[fv]                                     # [T,4,3,3]
     ea, eb = fp[:, :, 1] - fp[:, :, 0], fp[:, :, 2] - fp[:, :, 0]
     fn = jnp.cross(ea, eb)
-    wgt = corner_weights(ea, eb)[1]
+    wgt = corner_weights(ea, eb)
     idx12 = jnp.concatenate(
         [jnp.where(isb[:, f], fv[:, f, k], capP)
          for f in range(4) for k in range(3)])
@@ -84,10 +88,9 @@ def boundary_vertex_normals(mesh: Mesh) -> jax.Array:
 def corner_weights(ea: jax.Array, eb: jax.Array):
     """For triangles (p0, p1, p2) given by their edge vectors ``ea`` =
     p1 - p0 and ``eb`` = p2 - p0 [..., 3], the ones the face normal
-    ``ea x eb`` is made of: (``l2`` [..., 3], the squared length of the
-    edge from corner k to corner k + 1; ``wgt`` [..., 3], the weight
-    1 / (|a|^2 |b|^2) of the triangle's normal at corner k, a and b its
-    two edges there).  Max 1999, "Weights for computing
+    ``ea x eb`` is made of: [..., 3], the weight 1 / (|a|^2 |b|^2) of
+    the triangle's normal at corner k, a and b its two edges there.
+    Max 1999, "Weights for computing
     vertex normals from facet normals": summed with these the facet
     normals give the exact normal wherever the fan's vertices lie on a
     sphere, for any triangle shapes, where the area-weighted sum errs
@@ -97,10 +100,162 @@ def corner_weights(ea: jax.Array, eb: jax.Array):
     from ..core.constants import EPSD
     aa, bb = jnp.sum(ea * ea, -1), jnp.sum(eb * eb, -1)
     cc = aa + bb - 2.0 * jnp.sum(ea * eb, -1)       # |p2 - p1|^2
-    l2 = jnp.stack([aa, cc, bb], axis=-1)
-    wgt = 1.0 / jnp.maximum(
+    return 1.0 / jnp.maximum(
         jnp.stack([aa * bb, aa * cc, bb * cc], axis=-1), EPSD)
-    return l2, wgt
+
+
+def tangent_basis(vn: jax.Array):
+    """Two unit vectors (e1, e2) [..., 3] spanning the plane normal to
+    the unit vectors ``vn`` [..., 3], a function of ``vn`` alone: e1 is
+    the coordinate axis ``vn`` is least along, made orthogonal to it."""
+    from ..core.constants import EPSD
+    axis = jax.nn.one_hot(jnp.argmin(jnp.abs(vn), axis=-1), 3,
+                          dtype=vn.dtype)
+    e1 = axis - jnp.sum(axis * vn, -1, keepdims=True) * vn
+    e1 = e1 / (jnp.linalg.norm(e1, axis=-1, keepdims=True) + EPSD)
+    return e1, jnp.cross(vn, e1)
+
+
+class SecondForm(NamedTuple):
+    form: jax.Array       # [capP, 3] (a, b, c) of II in (e1, e2)
+    e1: jax.Array         # [capP, 3] tangent_basis of the normal given
+    e2: jax.Array
+    normal: jax.Array     # [capP, 3] the normal the fit corrects, unit
+    spokes: jax.Array     # [capP] boundary edges of the fan (a seam
+    #                       vertex's: of the faces this mesh holds)
+
+    def along(self, t: jax.Array) -> jax.Array:
+        """II(t, t) [capP] of vectors ``t`` [capP, 3]."""
+        u, v = jnp.sum(t * self.e1, -1), jnp.sum(t * self.e2, -1)
+        return (self.form[:, 0] * u * u + 2.0 * self.form[:, 1] * u * v
+                + self.form[:, 2] * v * v)
+
+
+def boundary_second_form(mesh: Mesh, vn: jax.Array,
+                         isb: jax.Array | None = None):
+    """The surface's second fundamental form at every boundary vertex,
+    fitted over its fan, and the normal the fit corrects: a
+    ``SecondForm`` (``form`` [capP, 3] = (a, b, c), ``e1``, ``e2``,
+    ``normal`` [capP, 3], ``spokes`` [capP])
+    with II(t, t) = a u^2 + 2 b u v + c v^2 for a tangent
+    t = u e1 + v e2, (e1, e2) = ``tangent_basis(vn)``; ``vn`` [capP, 3]
+    the unit vertex normals (outward) the caller has, ``isb`` [capT, 4]
+    the faces that make the surface (default: the true-boundary faces
+    of ``boundary_vertex_normals``).
+
+    A spoke d of length l from the vertex to a fan neighbour shows the
+    normal curvature of its direction, kappa = -2 d.n / l^2 (the circle
+    through both points that is normal to n at the vertex: exact on a
+    sphere, where every spoke reads 1 / R whatever its length; positive
+    where the surface bends away from the outward normal).  A normal
+    that is off by a small tangent vector delta (a weighted sum of
+    facet normals is exact on a sphere only: on a torus's irregular
+    fans it errs by up to 0.05 rad) adds 2 delta.t / l to the reading
+    of the unit direction t, 0.6 for a spoke of 0.1 where the
+    curvatures are 2.5 and 0.7.  So the fit has five unknowns, the
+    form and delta: least squares over the fan's spokes of
+    l kappa ~ l II(t, t) + 2 delta.t, each boundary edge counted once a
+    face it bounds.  Two weak priors (RIDGE of the normal matrix, which
+    is scaled to the fan's own size) say "no tilt" and "isotropic":
+    they decide what a fan of fewer than five directions leaves open
+    and cost a sound fan a thousandth of its anisotropy; on a sphere
+    they agree with the data and the fit stays exact.  ``normal`` is
+    ``vn`` + delta, unit.  The fit's error is of the order of the
+    surface's third derivative times the fan's asymmetry times a
+    spoke's length.  One gather of ``vn`` at the tets' corners and one
+    scatter of the fit's moments.
+    """
+    from ..core.constants import EPSD, MG_PARBDY
+    capP = mesh.capP
+    if isb is None:
+        isb = ((mesh.ftag & MG_BDY) != 0) & \
+            ((mesh.ftag & MG_PARBDY) == 0) & mesh.tmask[:, None]
+    tv = mesh.tet
+    p = mesh.vert[tv]                                      # [T,4,3]
+    n = vn[tv]                                             # [T,4,3]
+    e1, e2 = tangent_basis(n)
+    # the spoke from corner k to corner j bounds the tet's faces f
+    # other than k and j: as many readings as of those are surface
+    nb = isb.astype(mesh.vert.dtype)
+    off = 1.0 - jnp.eye(4, dtype=mesh.vert.dtype)
+    wkj = (jnp.sum(nb, -1)[:, None, None] - nb[:, :, None]
+           - nb[:, None, :]) * off                         # [T,4,4]
+    d = p[:, None, :, :] - p[:, :, None, :]                # [T,k,j,3]
+    ll = jnp.maximum(jnp.sum(d * d, -1), EPSD)             # [T,4,4]
+    ln = jnp.sqrt(ll)
+    y = -2.0 * jnp.sum(d * n[:, :, None, :], -1) / ln      # l kappa
+    u = jnp.sum(d * e1[:, :, None, :], -1)
+    v = jnp.sum(d * e2[:, :, None, :], -1)
+    lt = jnp.sqrt(u * u + v * v) + EPSD
+    u, v = u / lt, v / lt
+    phi = (ln * u * u, 2.0 * ln * u * v, ln * v * v, 2.0 * u, 2.0 * v)
+    mom = [phi[i] * phi[j] for i in range(5) for j in range(i, 5)] + \
+        [f * y for f in phi] + [ll, jnp.ones_like(ll)]
+    pay = jnp.sum(wkj[..., None] * jnp.stack(mom, -1), axis=2)  # [T,4,22]
+    idx4 = jnp.where(mesh.tmask[:, None], tv, capP).reshape(-1)
+    acc = jnp.zeros((capP + 1, 22), mesh.vert.dtype).at[idx4].add(
+        pay.reshape(-1, 22), mode="drop")[:capP]
+    cnt = jnp.maximum(acc[:, 21], 1.0)
+    # the fan's own length scale makes the five columns alike in size
+    # (1 where there is no fan: zero moments then give the zero form
+    # and ``vn`` back, not 0 / 0)
+    scale = jnp.where(acc[:, 21] > 0, jnp.sqrt(acc[:, 20] / cnt), 1.0)
+    sc = (scale, scale, scale, 1.0, 1.0)
+    pairs = [(i, j) for i in range(5) for j in range(i, 5)]
+    A = [[None] * 5 for _ in range(5)]
+    for k, (i, j) in enumerate(pairs):
+        A[i][j] = A[j][i] = acc[:, k] / (cnt * sc[i] * sc[j])
+    rhs = [acc[:, 15 + i] / (cnt * sc[i]) for i in range(5)]
+    # priors: isotropic ((a - c)^2 + (2 b)^2 small), no tilt
+    A[0][0], A[2][2] = A[0][0] + RIDGE, A[2][2] + RIDGE
+    A[0][2] = A[2][0] = A[0][2] - RIDGE
+    A[1][1] = A[1][1] + 4.0 * RIDGE
+    A[3][3], A[4][4] = A[3][3] + RIDGE, A[4][4] + RIDGE
+    # Gaussian elimination of the SPD system, unrolled and elementwise
+    # (no batched factorisation inside a wave)
+    for i in range(5):
+        piv = 1.0 / jnp.maximum(A[i][i], EPSD)
+        for r in range(i + 1, 5):
+            f = A[r][i] * piv
+            for c in range(i, 5):
+                A[r][c] = A[r][c] - f * A[i][c]
+            rhs[r] = rhs[r] - f * rhs[i]
+    x = [None] * 5
+    for i in reversed(range(5)):
+        acc_i = rhs[i]
+        for c in range(i + 1, 5):
+            acc_i = acc_i - A[i][c] * x[c]
+        x[i] = acc_i / jnp.maximum(A[i][i], EPSD)
+    form = jnp.stack([x[0], x[1], x[2]], -1) / scale[:, None]
+    ve1, ve2 = tangent_basis(vn)
+    vn1 = vn + x[3][:, None] * ve1 + x[4][:, None] * ve2
+    vn1 = vn1 / (jnp.linalg.norm(vn1, axis=-1, keepdims=True) + EPSD)
+    return SecondForm(form, ve1, ve2, vn1, 0.5 * acc[:, 21])
+
+
+def face_depth(p: jax.Array, n: jax.Array, new_edge: jax.Array):
+    """How far the surface stands from a flat triangle, as its corners'
+    normals describe it: ``p`` [..., 3, 3] the corners, ``n``
+    [..., 3, 3] the unit normals there, ``new_edge`` [..., 3] bool the
+    edges (0-1, 1-2, 0-2) to judge beside the centroid.  The cubic
+    patch through the corners that is normal to ``n`` there (Vlachos'
+    PN triangle, the one a split's lift puts its midpoints on) has the
+    control point (2 p_i + p_j - w_ij n_i) / 3 on the edge i-j next to
+    i, w_ij = (p_j - p_i).n_i.  The patch's centre stands
+    |sum_ij w_ij n_i| / 18 from the triangle's centroid (a^2 / (6 R)
+    on a sphere) and the middle of the edge i-j |w_ij n_i + w_ji n_j|
+    / 8 from the chord's (l^2 / (8 R): the collapse's own test of the
+    edge it removes).  Returns the largest of those [...]."""
+    d = p[..., None, :, :] - p[..., :, None, :]            # [..,i,j,3]
+    w = jnp.sum(d * n[..., :, None, :], -1)                # [..,i,j]
+    wn = w[..., None] * n[..., :, None, :]                 # w_ij n_i
+    centre = jnp.linalg.norm(jnp.sum(wn, axis=(-3, -2)), axis=-1) / 18.0
+    pairs = ((0, 1), (1, 2), (0, 2))
+    edges = jnp.stack([jnp.linalg.norm(
+        wn[..., i, j, :] + wn[..., j, i, :], axis=-1) for i, j in pairs],
+        axis=-1) / 8.0
+    return jnp.maximum(centre, jnp.max(
+        jnp.where(new_edge, edges, 0.0), axis=-1))
 
 
 def carries_normal(mesh: Mesh) -> jax.Array:

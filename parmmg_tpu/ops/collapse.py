@@ -38,6 +38,12 @@ from .edges import (unique_edges, edge_lengths, claim_channels,
 _IDIR_J = jnp.asarray(IDIR)
 
 
+# the simulated quality of a ball row whose collapse would leave a
+# surface face farther than hausd from the surface, and is sound
+# otherwise: below every quality, above the -inf of an invalid row
+DEEP_FACE = -1e30
+
+
 class CollapseResult(NamedTuple):
     mesh: Mesh
     ncollapse: jax.Array
@@ -48,7 +54,9 @@ class CollapseResult(NamedTuple):
     #                 top-K budget; they wait for the next wave
     nhveto: jax.Array = None  # scalar int32: candidates the hausd test
     #                 refused (boundary edges whose surface would move by
-    #                 more than hausd); 0 without hausd
+    #                 more than hausd, and collapses that would leave a
+    #                 surface face farther than hausd from the surface);
+    #                 0 without hausd
 
 
 def _removable(vtag, other_vtag, edge_tag):
@@ -260,6 +268,16 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
         d3 = p[:, 3] - p[:, 0]
         vol = jnp.einsum("ti,ti->t", d1, jnp.cross(d2, d3)) / 6.0
         bad = vol <= EPSD
+        if hausd is not None:
+            from .analysis import face_depth
+            sing_v = (mesh.vtag & (MG_GEO | MG_CRN | MG_REF | MG_NOM)) != 0
+            # the simulated tet's corner normals, and which corners are
+            # singular (ridge, corner, reference line: no one normal)
+            kept_c = jnp.clip(kept_v, 0, capP - 1)
+            nrm_c = jnp.where(oh[..., None], vn[kept_c][:, None, :],
+                              vn[tv])                          # [T,4,3]
+            sing_c = jnp.where(oh, sing_v[kept_c][:, None], sing_v[tv])
+            deep = jnp.zeros(capT, bool)
         # fold-over: boundary faces containing the claimed corner must
         # keep their orientation
         for f in range(4):
@@ -271,6 +289,24 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
             isb = (mesh.ftag[:, f] & MG_BDY) != 0
             flip = jnp.sum(n_old * n_new, -1) <= 0
             bad = bad | (isb & flip & (kc != f))
+            if hausd is not None:
+                # the surface face this collapse LEAVES (the removed
+                # corner at the kept vertex): its centroid and its two
+                # new edges stand within hausd of the surface its
+                # corners' normals describe.  The test of the removed
+                # edge above cannot see them: they are up to twice as
+                # long.  A singular corner takes the face's own normal,
+                # so a flat face reads 0 whatever bounds it
+                nf = n_new / (jnp.linalg.norm(
+                    n_new, axis=-1, keepdims=True) + EPSD)
+                nrm = jnp.where(sing_c[..., None], nf[:, None, :], nrm_c)
+                at_kept = oh[:, list(idx)]                     # [T,3]
+                touches = jnp.stack(
+                    [at_kept[:, 0] | at_kept[:, 1],
+                     at_kept[:, 1] | at_kept[:, 2],
+                     at_kept[:, 0] | at_kept[:, 2]], axis=-1)
+                deep = deep | (isb & (kc != f) & (face_depth(
+                    p[:, list(idx)], nrm[:, list(idx)], touches) > hausd))
         # overlong new edges from the kept vertex to the other corners
         if met.ndim == 1:
             from .quality import edge_length_iso
@@ -300,6 +336,10 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
             mq)
         qv = quality_from_points(p, mq_cl)                     # [T]
         row_val = jnp.where(bad, -jnp.inf, qv)
+        if hausd is not None:
+            # refused for the surface and for nothing else: a value of
+            # its own, so that the ball's minimum says which it was
+            row_val = jnp.where(deep & ~bad, DEEP_FACE, row_val)
         # contested rows: a corner holding a target that is NOT the tet's
         # claim max kills that target via a -inf contribution
         mism4 = jnp.concatenate(
@@ -339,6 +379,9 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
         # contested balls are already folded into geombad via -inf rows
         win = cand & is_top & ~geombad[rm] & claim_ok
         ncol = jnp.sum(win.astype(jnp.int32))
+        nveto = nhveto if hausd is None else nhveto + jnp.sum(
+            cand & is_top & claim_ok & (ballq_new[rm] == DEEP_FACE),
+            dtype=jnp.int32)
 
         # --- apply: vertex remap + dead shell tets ---------------------------
         # the whole apply phase (remap gather, dup detection, keyed tag
@@ -358,7 +401,7 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
         out = dataclasses.replace(
             mesh, tet=new_tet, tmask=tmask, vmask=vmask, ftag=ftag,
             fref=fref, etag=etag)
-        return CollapseResult(out, ncol, schg, defer, nhveto)
+        return CollapseResult(out, ncol, schg, defer, nveto)
 
     return jax.lax.cond(jnp.any(pre), _act, _idle, None)
 

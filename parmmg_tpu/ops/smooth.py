@@ -40,13 +40,26 @@ FLAT_RATIO = 0.9998
 # on a curved patch, with a surface tolerance ``hausd`` given, it slides
 # too and is then put back ON the surface (Mmg's movbdyregpt reprojects
 # onto the Bezier patch): the fan's own vertices give the surface's
-# normal curvature kappa at the point (second fundamental form, fitted
-# over the spokes), and a tangential step s costs kappa s^2 / 2 along
-# the normal — exact to O(s^4) on a sphere.  Gate: the fan's faces lie
-# in a cone round the vertex normal (ratio >= SMOOTH_RATIO, 18 deg: a
-# crease the analysis left untagged does not slide) and the step leaves
-# the old surface by no more than hausd
+# second fundamental form II at the point and the normal it corrects
+# (analysis.boundary_second_form, five unknowns fitted over the spokes),
+# and a step s t in THAT tangent plane leaves the surface by
+# II(t, t) s^2 / 2 along the normal, the normal curvature of the step's
+# OWN direction: on a torus that is 1 / r round the tube and
+# cos(theta) / rho along the ring, of the other sign on the inner half,
+# and one number for both leaves every slide off the surface; on a
+# sphere II is isotropic and the step is exact to O(s^4).  Gates: the
+# fan's faces lie in a cone round the vertex normal (ratio >=
+# SMOOTH_RATIO, 18 deg: a crease the analysis left untagged does not
+# slide), the fan has as many spokes as the fit has unknowns
+# (SLIDE_SPOKES: the priors decide what four spokes leave open, and
+# such a fan's normal stood up to 0.07 rad off a torus's), and the step
+# leaves the old surface by no more than hausd.  What no gate bounds: a
+# slide puts the vertex on the surface its NEIGHBOURS describe, so the
+# fits' errors add up over a job's moves (a surface vertex moves twice
+# on average); on a torus of tube radius 0.4 the farthest vertex of a
+# job stands 5e-4 to 2.4e-3 off it, a lifted midpoint alone 5e-4
 SMOOTH_RATIO = 0.95
+SLIDE_SPOKES = 5
 
 
 class SmoothResult(NamedTuple):
@@ -63,7 +76,8 @@ def smooth_wave(mesh: Mesh, met: jax.Array, wave: int = 0,
 
     ``hausd``: the surface tolerance (Mmg -hausd).  With it a regular
     surface vertex on a CURVED patch slides too and is reprojected onto
-    the surface its fan describes (``SMOOTH_RATIO`` above); without it
+    the surface its fan describes, by the curvature of the direction it
+    moved in (``SMOOTH_RATIO``, ``SLIDE_SPOKES`` above); without it
     only flat patches slide, where no reprojection is needed.
 
     ``opt_q``: optimal-position mode for sliver balls — interior
@@ -119,8 +133,7 @@ def smooth_wave(mesh: Mesh, met: jax.Array, wave: int = 0,
     farea = 0.5 * jnp.sqrt(jnp.sum(fn * fn, -1))           # [T,4]
     # all 12 (face, corner) contributions in ONE wide scatter:
     # payload = (corner-weighted normal[3], area*centroid[3], area[1],
-    #            unit normal[3], count[1], area*(the corner's two
-    #            spokes' squared lengths)[1]) — the unit-normal sum
+    #            unit normal[3], count[1]) — the unit-normal sum
     # feeds the gates below with no second full-width pass.  The corner
     # weights are those of analysis.boundary_vertex_normals
     idx12 = jnp.concatenate(
@@ -131,18 +144,17 @@ def smooth_wave(mesh: Mesh, met: jax.Array, wave: int = 0,
     pay_f = jnp.concatenate(
         [w4[..., None] * fc, w4[..., None], fn_unit,
          jnp.ones_like(w4)[..., None]], axis=-1)           # [T,4,8]
-    from .analysis import corner_weights
-    l2, wgt = corner_weights(ea, eb)                       # [T,4,3]
-    sp2 = w4[..., None] * (l2 + jnp.roll(l2, 1, axis=-1))  # [T,4,3]
+    from .analysis import (SecondForm, boundary_second_form,
+                           corner_weights)
+    wgt = corner_weights(ea, eb)                           # [T,4,3]
     pay12 = jnp.concatenate(
         [jnp.concatenate(
-            [fn[:, f] * wgt[:, f, k, None], pay_f[:, f],
-             sp2[:, f, k, None]], axis=-1)
-         for f in range(4) for k in range(3)])             # [12T,12]
-    sacc = jnp.zeros((capP + 1, 12), mesh.vert.dtype).at[idx12].add(
+            [fn[:, f] * wgt[:, f, k, None], pay_f[:, f]], axis=-1)
+         for f in range(4) for k in range(3)])             # [12T,11]
+    sacc = jnp.zeros((capP + 1, 11), mesh.vert.dtype).at[idx12].add(
         pay12, mode="drop")[:capP]
     nacc, cacc, aacc = sacc[:, :3], sacc[:, 3:6], sacc[:, 6]
-    uacc, ucnt, s2acc = sacc[:, 7:10], sacc[:, 10], sacc[:, 11]
+    uacc, ucnt = sacc[:, 7:10], sacc[:, 10]
     navg = nacc / (jnp.linalg.norm(nacc, axis=-1, keepdims=True) + EPSD)
     # locally-flat gate: |sum of unit normals| close to the face count
     # means every incident boundary face is near the common plane
@@ -150,19 +162,30 @@ def smooth_wave(mesh: Mesh, met: jax.Array, wave: int = 0,
     flat = (ratio >= FLAT_RATIO) & (aacc > 0)
     cbar = cacc / jnp.maximum(aacc[:, None], EPSD)
     dvec = cbar - mesh.vert
+    if hausd is not None:
+        # a curved patch: the surface's second form over the fan, and
+        # the normal it corrects (the weighted sum of facet normals is
+        # exact on a sphere only, and a tangent plane that is tilted by
+        # delta leaves the surface by delta s, in the first order of
+        # the step).  A mesh with no curved patch to slide on (a box)
+        # skips the fit, its gather and its scatter
+        curved = reg_bdy & ~flat & (ratio >= SMOOTH_RATIO) & (aacc > 0)
+        zero3 = jnp.zeros((capP, 3), mesh.vert.dtype)
+        sf = jax.lax.cond(
+            jnp.any(curved),
+            lambda: boundary_second_form(mesh, navg, isb),
+            lambda: SecondForm(zero3, zero3, zero3, navg, zero3[:, 0]))
+        navg = jnp.where(flat[:, None], navg, sf.normal)
     dvec = dvec - jnp.sum(dvec * navg, -1, keepdims=True) * navg
-    # the surface under the tangent plane: a fan vertex at distance l
-    # stands kappa l^2 / 2 under it, so a face's centroid (l_a^2 +
-    # l_b^2) kappa / 6, and the area-weighted centroid ``cbar`` the
-    # area-weighted mean of that; 0 on a plane
-    kappa = -6.0 * jnp.sum((cacc - aacc[:, None] * mesh.vert) * navg, -1) \
-        / jnp.maximum(s2acc, EPSD)
-    drop = 0.5 * kappa * jnp.sum(dvec * dvec, -1)          # at step 1
     if hausd is None:
         bdy_ok = reg_bdy & flat
+        drop = jnp.zeros(capP, mesh.vert.dtype)
     else:
-        bdy_ok = reg_bdy & (flat | ((ratio >= SMOOTH_RATIO) & (aacc > 0)
-                                    & (jnp.abs(drop) <= hausd)))
+        # the surface under the tangent plane along the step (at step 1)
+        drop = 0.5 * sf.along(dvec)
+        bdy_ok = reg_bdy & (flat | (
+            curved & (sf.spokes >= SLIDE_SPOKES)
+            & (jnp.abs(drop) <= hausd)))
     drop = jnp.where(bdy_ok & ~flat, drop, 0.0)[:, None] * navg
     prop = jnp.where(bdy_ok[:, None], mesh.vert + dvec, prop)
     movable = movable_int | bdy_ok
