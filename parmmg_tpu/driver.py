@@ -194,13 +194,17 @@ def _merged_polish(mesh, met, info, hausd, stats, tim):
     """Bad-element polish on a MERGED mesh, staged on the host (group
     and shard seams breed slivers): up to eight ``sliver_polish`` waves,
     each ended by the pull of its counts, until one applies no collapse
-    and no swap.  Returns (mesh, collapses + swaps applied)."""
+    and no swap.  The waves hand each other the swap kernels' worklist
+    (``ops/worklist``): from the second on, a kernel judges only the
+    candidates whose shell changed since it last looked.  Returns (mesh,
+    collapses + swaps applied)."""
     import jax.numpy as jnp
     from .obs import trace as otrace
     from .obs.metrics import REGISTRY
     from .ops.adapt import sliver_polish
+    from .ops.worklist import all_dirty
     from .utils.placement import host_staging
-    ops = col_skipped = adj_skipped = 0
+    ops = col_skipped = adj_skipped = cand_rows = wl_rows = 0
     # every program of the tail costs what its capacity is, not what its
     # content is: the two counters say how much of it is padding
     n_live = int(np.asarray(mesh.tmask).sum())
@@ -208,24 +212,31 @@ def _merged_polish(mesh, met, info, hausd, stats, tim):
     REGISTRY.counter("tail.rows_cap").inc(mesh.capT)
     budget = polish_budget(n_live)
     with tim("bad-element polish"), host_staging():
+        worklist = all_dirty(mesh)
         for w in range(8):
             with otrace.span("polish wave", wave=w) as sp:
-                mesh, counts = sliver_polish(
+                mesh, counts, worklist = sliver_polish(
                     mesh, met, jnp.asarray(1000 + w, jnp.int32),
                     do_collapse=not info.noinsert,
                     do_swap=not info.noswap,
-                    do_smooth=not info.nomove, hausd=hausd, budget=budget)
-                ncol, nswap, nmoved, _, nhveto, nbmoved, nbad, col, adj = \
-                    np.asarray(counts).tolist()
+                    do_smooth=not info.nomove, hausd=hausd, budget=budget,
+                    worklist=worklist)
+                (ncol, nswap, nmoved, _, nhveto, nbmoved, nbad, col, adj,
+                 cand, wl) = np.asarray(counts).tolist()
                 # a polish wave splits nothing: bsplit is 0 by what it is;
                 # bad: tets under the sliver threshold at the wave's
                 # entry; col, adj: did the collapse stage and the exit
-                # adjacency run (a stage without an input does not)
+                # adjacency run (a stage without an input does not);
+                # cand: candidate rows the ring and edge swaps' top-K
+                # selected, wl: those of them whose shell changed since
+                # the kernel last looked, which is what it judged
                 sp.set(collapse=ncol, swap=nswap, moved=nmoved,
                        bsplit=0, hveto=nhveto, bmoved=nbmoved,
-                       bad=nbad, col=col, adj=adj)
+                       bad=nbad, col=col, adj=adj, wl=wl, cand=cand)
             col_skipped += int(not info.noinsert and not col)
             adj_skipped += int(not adj)
+            cand_rows += cand
+            wl_rows += wl
             stats.add_surface(hveto=nhveto, bmoved=nbmoved)
             stats.ncollapse += ncol
             stats.nswap += nswap
@@ -238,6 +249,8 @@ def _merged_polish(mesh, met, info, hausd, stats, tim):
     # stages the waves skipped for want of an input, zeros too
     REGISTRY.counter("tail.collapse_skipped").inc(col_skipped)
     REGISTRY.counter("tail.exit_adj_skipped").inc(adj_skipped)
+    REGISTRY.counter("tail.candidate_rows").inc(cand_rows)
+    REGISTRY.counter("tail.worklist_rows").inc(wl_rows)
     return mesh, ops
 
 

@@ -385,7 +385,7 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
                        sliver_q: float = 0.2, do_collapse: bool = True,
                        do_swap: bool = True, do_smooth: bool = True,
                        hausd: float | None = None, active=None,
-                       budget: int | None = None):
+                       budget: int | None = None, worklist=None):
     """Bad-element optimization pass (MMG3D_opttyp analogue): quality-
     targeted collapses on tets below ``sliver_q``, then swaps and a
     smoothing wave.  Run after the sizing loop converges — length-driven
@@ -416,14 +416,29 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     face sort, the wave built none: the exit builds it).  Both are exact,
     and ``mesh.adja`` is valid on return either way.
 
-    Returns (mesh, counts[9] = [ncollapse, nswap, nmoved, live_tets,
-    hveto, bmoved, bad, col, adj]): ``hveto``, ``bmoved`` as in a
-    cycle's ``SURF_COLS``; ``bad`` the live tets under ``sliver_q`` at
-    entry (0 with ``do_collapse`` off: not counted), ``col`` 1 when the
-    collapse stage ran, ``adj`` 1 when the exit adjacency was rebuilt.
+    ``worklist``: an ``ops/worklist.PolishList`` (``all_dirty`` before
+    the first wave), carried from wave to wave by a caller that runs
+    several on one mesh: the ring and edge swap kernels then judge only
+    the candidates whose shell changed since they last looked, and the
+    wave hands the list back as a third result.  Exact: mesh and counts
+    are what the wave gives without one.
+
+    Returns (mesh, counts[11] = [ncollapse, nswap, nmoved, live_tets,
+    hveto, bmoved, bad, col, adj, cand, wl]): ``hveto``, ``bmoved`` as
+    in a cycle's ``SURF_COLS``; ``bad`` the live tets under ``sliver_q``
+    at entry (0 with ``do_collapse`` off: not counted), ``col`` 1 when
+    the collapse stage ran, ``adj`` 1 when the exit adjacency was
+    rebuilt; ``cand`` the candidate rows the two kernels' top-K selected
+    and ``wl`` those of them on the list (both 0 without a worklist: not
+    counted).
     """
     from .adjacency import boundary_edge_tags
+    from . import worklist as wlist
     if active is not None:
+        if worklist is not None:
+            raise ValueError("a worklist rides one mesh from wave to wave:"
+                             " not under the quiet mask")
+
         def _run(m):
             return sliver_polish_impl(
                 m, met, wave, sliver_q=sliver_q,
@@ -431,13 +446,18 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
                 do_smooth=do_smooth, hausd=hausd, budget=budget)
 
         def _skip(m):
-            counts = jnp.zeros(9, jnp.int32).at[3].set(
+            counts = jnp.zeros(11, jnp.int32).at[3].set(
                 jnp.sum(m.tmask, dtype=jnp.int32))
             return m, counts
         return jax.lax.cond(active, _run, _skip, mesh)
     zero = jnp.zeros((), jnp.int32)
-    ncol = nhveto = nbad = nswap = nmoved = nbmoved = zero
+    ncol = nhveto = nbad = nswap = nmoved = nbmoved = ncand = nlist = zero
     rebuild = None      # traced bool once the wave holds an adjacency
+    wl = worklist
+
+    def note(wl, before, after):
+        return None if wl is None else wlist.noted(wl, before, after)
+
     if do_collapse:
         from .quality import quality_from_points
         q_tet = quality_from_points(
@@ -456,17 +476,28 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
                              lambda m: m, col.mesh)
             return m, col.ncollapse, col.nhveto
 
+        before = mesh
         mesh, ncol, nhveto = jax.lax.cond(
             nbad > 0, _collapse, lambda m: (m, zero, zero), mesh)
+        wl = note(wl, before, mesh)
     if do_swap:
         from .swapgen import swapgen_wave
         from .swap import swap_facesort_enabled
         sew = swap_edges_wave(mesh, met, hausd=hausd, budget_div=2,
-                              budget=budget)  # 3-2 + 2-2
+                              budget=budget,      # 3-2 + 2-2
+                              worklist=None if wl is None else wl.edges)
+        if wl is not None:
+            wl = note(wl._replace(edges=wlist.looked(wl.edges, sew.keep)),
+                      mesh, sew.mesh)
         # generalized degree 4-6 ring swaps: the worst surviving tets
         # are typically gate-limited for every lower-degree op — this
         # is the class that lifts the min past the 3-2/2-3 plateau
-        sgn = swapgen_wave(sew.mesh, met, budget_div=2, budget=budget)
+        sgn = swapgen_wave(sew.mesh, met, budget_div=2, budget=budget,
+                           worklist=None if wl is None else wl.rings)
+        if wl is not None:
+            wl = note(wl._replace(rings=wlist.looked(wl.rings, sgn.keep)),
+                      sew.mesh, sgn.mesh)
+            ncand, nlist = sew.ncand + sgn.ncand, sew.nlist + sgn.nlist
         if swap_facesort_enabled():
             s23 = swap23_wave(sgn.mesh, met, budget_div=2, budget=budget,
                               facesort=True)
@@ -478,6 +509,7 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
             rebuild = s23.nswap > 0
         mesh = s23.mesh
         nswap = sew.nswap + sgn.nswap + s23.nswap
+    after_rings = sgn.mesh if do_swap else mesh
     if do_smooth:
         # optimal-position mode: sliver-ball vertices ascend the height
         # of their worst incident tet instead of chasing the centroid
@@ -493,8 +525,11 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
                         jnp.sum(mesh.tmask, dtype=jnp.int32),
                         nhveto, nbmoved, nbad,
                         (nbad > 0).astype(jnp.int32),
-                        rebuild.astype(jnp.int32)])
-    return mesh, counts
+                        rebuild.astype(jnp.int32), ncand, nlist])
+    if worklist is None:
+        return mesh, counts
+    # swap23, the smoothing and the adjacencies' boundary tags, in one
+    return mesh, counts, note(wl, after_rings, mesh)
 
 
 sliver_polish = _governed("adapt.sliver_polish")(

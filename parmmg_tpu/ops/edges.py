@@ -344,17 +344,21 @@ def topk_prep3(cand: jax.Array, v0: jax.Array, v1: jax.Array,
     return ref(cand, v0, v1, v2)
 
 
-def claim_shells(score, cand, shells, capT):
+def claim_shells(score, cand, shells, capT, pos=None):
     """Exclusive multi-slot claims: winner must be the two-channel
     (score, tie-hash) max at EVERY shell slot it touches.  Winners are
     pairwise shell-disjoint: two winners sharing a slot would both be
     that slot's pooled (s,t)-max — impossible, t is unique.  Shared by
     the swap kernels (each candidate claims its 2-3 cavity tets).
 
+    ``pos``: the slot index each candidate's tie hash is taken of, for
+    a caller that has permuted its candidates (ops/worklist) and must
+    break ties as the unpermuted array would.
+
     All shells are claimed in ONE concatenated scatter per channel and
     checked with one stacked gather — per-op overhead dominates
     scatter/gather cost on this device (scripts/tpu_microbench.py)."""
-    ps, pt = claim_channels(score, cand)
+    ps, pt = claim_channels(score, cand, pos=pos)
     k = len(shells)
     shs = jnp.stack(shells)                               # [k, E]
     idx = jnp.where(cand[None, :], shs, capT).reshape(-1)
@@ -418,11 +422,14 @@ PRI_MIN = jnp.int32(-2147483648)     # tie-channel sentinel (< every hash)
 NEG_INF = jnp.float32(-jnp.inf)      # score-channel sentinel
 
 
-def tie_hash(n: int, salt: int = 0) -> jax.Array:
+def tie_hash(n: int, salt: int = 0, pos=None) -> jax.Array:
     """Unique pseudo-random int32 per slot: a bijective avalanche mix of
     the index (odd multiplications and xor-shifts are invertible mod
-    2^32), so distinct slots NEVER collide — the total order is exact."""
-    x = jnp.arange(n, dtype=jnp.uint32) + jnp.uint32(salt) * jnp.uint32(
+    2^32), so distinct slots NEVER collide — the total order is exact.
+    ``pos`` [n]: hash these (distinct) indices instead of 0..n-1."""
+    idx = jnp.arange(n, dtype=jnp.uint32) if pos is None \
+        else pos.astype(jnp.uint32)
+    x = idx + jnp.uint32(salt) * jnp.uint32(
         2246822519)
     x = x * jnp.uint32(2654435761)
     x = x ^ (x >> 16)
@@ -433,11 +440,12 @@ def tie_hash(n: int, salt: int = 0) -> jax.Array:
     return x.astype(jnp.int32)
 
 
-def claim_channels(score: jax.Array, mask: jax.Array, salt: int = 0):
+def claim_channels(score: jax.Array, mask: jax.Array, salt: int = 0,
+                   pos=None):
     """(s, t) channels for the two-channel claim scheme: masked slots get
     (-inf, PRI_MIN) and lose every comparison."""
     s = jnp.where(mask, score.astype(jnp.float32), NEG_INF)
-    t = jnp.where(mask, tie_hash(score.shape[0], salt), PRI_MIN)
+    t = jnp.where(mask, tie_hash(score.shape[0], salt, pos), PRI_MIN)
     return s, t
 
 

@@ -22,7 +22,7 @@ from ..core.constants import (
     EPSD, QUAL_FLOOR, MG_BDY, MG_GEO, MG_NOM, MG_OPNBDY, MG_PARBDY,
     MG_REF, MG_REQ)
 from .edges import (unique_edges, claim_channels, claim_shells, NEG_INF,
-                    PRI_MIN)
+                    PACK_LIMIT, PRI_MIN)
 from .quality import quality_from_points
 
 SWAP_GAIN = 1.053
@@ -39,6 +39,34 @@ class SwapResult(NamedTuple):
     nswap: jax.Array
     deferred: jax.Array = None  # scalar bool: candidates exceeded the
     #                 top-K budget; they wait for the next wave
+    # with a worklist only (ops/worklist; swap_edges_wave):
+    keep: jax.Array = None      # [capT] bool, rows that stay on the list
+    ncand: jax.Array = None     # candidate rows the top-K selected
+    nlist: jax.Array = None     # of them on the list: what was judged
+
+
+class _EdgeRows(NamedTuple):
+    """Per candidate row of swap_edges_wave, what the claims, the
+    duplicate veto and the apply read."""
+    cand: jax.Array     # [k] passed every gate
+    viable: jax.Array   # [k] passed every gate that reads its shell alone
+    base32: jax.Array
+    base22: jax.Array
+    q_old: jax.Array
+    q_new: jax.Array
+    s0: jax.Array       # [k] shell rows (s2 only of a 3-2 candidate)
+    s1: jax.Array
+    s2: jax.Array
+    x0: jax.Array       # [k] the new edge's ends
+    x1: jax.Array
+    new_a: jax.Array    # [k, 4] the two new tets, then their tags
+    new_b: jax.Array
+    ftag_a: jax.Array
+    fref_a: jax.Array
+    etag_a: jax.Array
+    ftag_b: jax.Array
+    fref_b: jax.Array
+    etag_b: jax.Array
 
 
 def _met6(met):
@@ -78,7 +106,8 @@ def swap_edges_wave(mesh: Mesh, met: jax.Array, enable32: bool = True,
                     flat_tol: float = 1e-5,
                     hausd: float | None = None,
                     budget_div: int = 8,
-                    budget: int | None = None) -> SwapResult:
+                    budget: int | None = None,
+                    worklist=None) -> SwapResult:
     """Combined edge-swap wave: 3-2 interior + 2-2 boundary, ONE pass.
 
     Both swaps share the same cavity shape — edge (a,b) is replaced by two
@@ -110,6 +139,13 @@ def swap_edges_wave(mesh: Mesh, met: jax.Array, enable32: bool = True,
     past the budget are simply deferred to the next wave (waves repeat
     until quiet, and swaps exist to fix the worst elements first — the
     same prioritization Mmg's quality-driven sweeps apply).
+
+    ``worklist``: an ``ops/worklist.Dirty``, what changed since this
+    kernel last judged the mesh.  Only the candidates it lists go
+    through the candidate stage, in chunks as wide as the list; the
+    result is the full evaluation's to the bit while the caller keeps
+    the list by that module's rules.  None (the cycle block) traces the
+    stage once over all K rows, the program it always was.
     """
     capT, capP = mesh.capT, mesh.capP
     et = unique_edges(mesh)
@@ -155,281 +191,322 @@ def swap_edges_wave(mesh: Mesh, met: jax.Array, enable32: bool = True,
     # top-K worst shells without a full-width argsort
     _, sel = jax.lax.top_k(neg, K)
 
-    # ---- compacted columns ----------------------------------------------
-    ev_c = et.ev[sel]
-    shell3_c = et.shell3[sel]
-    E = K
-    ar = jnp.arange(E)
-    false_e = jnp.zeros(E, bool)
+    def stage(sel):
+        """The candidate stage on compacted rows, each row by itself:
+        roles, positions, gates, qualities and tag routing.  Every value
+        read is one of the row's shell (its tets' vertex ids, tags and
+        references, their vertices' coordinates and metric) but the 2-2
+        swap's ``exists`` probe, which asks the whole edge table."""
+        # ---- compacted columns ----------------------------------------------
+        ev_c = et.ev[sel]
+        shell3_c = et.shell3[sel]
+        E = sel.shape[0]
+        ar = jnp.arange(E)
+        false_e = jnp.zeros(E, bool)
 
-    t0, t1, t2 = shell3_c[:, 0], shell3_c[:, 1], shell3_c[:, 2]
-    s0 = jnp.clip(t0, 0, capT - 1)
-    s1 = jnp.clip(t1, 0, capT - 1)
-    s2 = jnp.clip(t2, 0, capT - 1)
-    a = jnp.clip(ev_c[:, 0], 0, capP - 1)
-    b = jnp.clip(ev_c[:, 1], 0, capP - 1)
-    tv0 = mesh.tet[s0]
-    tv1 = mesh.tet[s1]
+        t0, t1, t2 = shell3_c[:, 0], shell3_c[:, 1], shell3_c[:, 2]
+        s0 = jnp.clip(t0, 0, capT - 1)
+        s1 = jnp.clip(t1, 0, capT - 1)
+        s2 = jnp.clip(t2, 0, capT - 1)
+        a = jnp.clip(ev_c[:, 0], 0, capP - 1)
+        b = jnp.clip(ev_c[:, 1], 0, capP - 1)
+        tv0 = mesh.tet[s0]
+        tv1 = mesh.tet[s1]
 
-    # pair/tref gates already folded into the pre-masks (full width)
-    base32 = pre32[sel] if enable32 else false_e
-    base22 = pre22[sel] if enable22 else false_e
+        # pair/tref gates already folded into the pre-masks (full width)
+        base32 = pre32[sel] if enable32 else false_e
+        base22 = pre22[sel] if enable22 else false_e
 
-    # ---- role derivation -------------------------------------------------
-    # s0's two non-(a,b) corners y1, y2
-    is_ab0 = (tv0 == a[:, None]) | (tv0 == b[:, None])
-    ordr = jnp.argsort(is_ab0.astype(jnp.int32), axis=1, stable=True)
-    y1 = tv0[ar, ordr[:, 0]]
-    y2 = tv0[ar, ordr[:, 1]]
-    # 2-2 roles: c = the one shared with T2, p = the other, q = T2's 4th
-    y1_in1 = jnp.any(tv1 == y1[:, None], axis=1)
-    y2_in1 = jnp.any(tv1 == y2[:, None], axis=1)
-    c22 = jnp.where(y1_in1, y1, y2)
-    p22 = jnp.where(y1_in1, y2, y1)
-    is_abc1 = (tv1 == a[:, None]) | (tv1 == b[:, None]) | \
-        (tv1 == c22[:, None])
-    q22 = tv1[ar, jnp.argmax(~is_abc1, axis=1)]
-    # degenerate shells (edge shared without a shared face) rejected
-    base22 = base22 & (y1_in1 ^ y2_in1) & \
-        (jnp.sum(is_abc1.astype(jnp.int32), axis=1) == 3)
-    # 3-2 roles: ring (p,q) from s0, r from s1; relabel (s1,s2) as
-    # (t_pr, t_qr) by which one contains p
-    p32, q32 = y1, y2
-    is_pq1 = (tv1 == p32[:, None]) | (tv1 == q32[:, None])
-    r32 = tv1[ar, jnp.argmax(~(is_abc1 | is_pq1), axis=1)]
-    s1_has_p = jnp.any(tv1 == p32[:, None], axis=1)
-    t_pr = jnp.where(s1_has_p, s1, s2)
-    t_qr = jnp.where(s1_has_p, s2, s1)
+        # ---- role derivation -------------------------------------------------
+        # s0's two non-(a,b) corners y1, y2
+        is_ab0 = (tv0 == a[:, None]) | (tv0 == b[:, None])
+        ordr = jnp.argsort(is_ab0.astype(jnp.int32), axis=1, stable=True)
+        y1 = tv0[ar, ordr[:, 0]]
+        y2 = tv0[ar, ordr[:, 1]]
+        # 2-2 roles: c = the one shared with T2, p = the other, q = T2's 4th
+        y1_in1 = jnp.any(tv1 == y1[:, None], axis=1)
+        y2_in1 = jnp.any(tv1 == y2[:, None], axis=1)
+        c22 = jnp.where(y1_in1, y1, y2)
+        p22 = jnp.where(y1_in1, y2, y1)
+        is_abc1 = (tv1 == a[:, None]) | (tv1 == b[:, None]) | \
+            (tv1 == c22[:, None])
+        q22 = tv1[ar, jnp.argmax(~is_abc1, axis=1)]
+        # degenerate shells (edge shared without a shared face) rejected
+        base22 = base22 & (y1_in1 ^ y2_in1) & \
+            (jnp.sum(is_abc1.astype(jnp.int32), axis=1) == 3)
+        # 3-2 roles: ring (p,q) from s0, r from s1; relabel (s1,s2) as
+        # (t_pr, t_qr) by which one contains p
+        p32, q32 = y1, y2
+        is_pq1 = (tv1 == p32[:, None]) | (tv1 == q32[:, None])
+        r32 = tv1[ar, jnp.argmax(~(is_abc1 | is_pq1), axis=1)]
+        s1_has_p = jnp.any(tv1 == p32[:, None], axis=1)
+        t_pr = jnp.where(s1_has_p, s1, s2)
+        t_qr = jnp.where(s1_has_p, s2, s1)
 
-    # unified roles: new tets A=(x0,x1,x2,a), B=(x0,x1,x2,b); tag sources
-    # u1 (holds x0,x2 faces/edges) and u2 (holds x1,x2)
-    x0 = jnp.where(base32, p32, p22)
-    x1 = jnp.where(base32, q32, q22)
-    x2 = jnp.where(base32, r32, c22)
-    u1 = jnp.where(base32, t_pr, s0)
-    u2 = jnp.where(base32, t_qr, s1)
-    tu1 = mesh.tet[u1]
-    tu2 = mesh.tet[u2]
+        # unified roles: new tets A=(x0,x1,x2,a), B=(x0,x1,x2,b); tag sources
+        # u1 (holds x0,x2 faces/edges) and u2 (holds x1,x2)
+        x0 = jnp.where(base32, p32, p22)
+        x1 = jnp.where(base32, q32, q22)
+        x2 = jnp.where(base32, r32, c22)
+        u1 = jnp.where(base32, t_pr, s0)
+        u2 = jnp.where(base32, t_qr, s1)
+        tu1 = mesh.tet[u1]
+        tu2 = mesh.tet[u2]
 
-    # ---- batched positions of (a, b, x0, x1, x2) in s0/u1/u2 -------------
-    tgt = jnp.stack([a, b, x0, x1, x2], axis=1)            # [E,5]
+        # ---- batched positions of (a, b, x0, x1, x2) in s0/u1/u2 -------------
+        tgt = jnp.stack([a, b, x0, x1, x2], axis=1)            # [E,5]
 
-    def pos5(tv):
-        eqm = tv[:, None, :] == tgt[:, :, None]            # [E,5,4]
-        return (jnp.argmax(eqm, axis=2).astype(jnp.int32),
-                jnp.any(eqm, axis=2))
+        def pos5(tv):
+            eqm = tv[:, None, :] == tgt[:, :, None]            # [E,5,4]
+            return (jnp.argmax(eqm, axis=2).astype(jnp.int32),
+                    jnp.any(eqm, axis=2))
 
-    P0, in0 = pos5(tv0)
-    P1, in1 = pos5(tu1)
-    P2, in2 = pos5(tu2)
-    # 3-2 ring sanity: u1 must hold {x0,x2}, u2 {x1,x2}
-    ring_ok = in1[:, 2] & in1[:, 4] & in2[:, 3] & in2[:, 4]
-    base32 = base32 & ring_ok
-    base22 = base22 & ring_ok          # holds by construction; belt+braces
+        P0, in0 = pos5(tv0)
+        P1, in1 = pos5(tu1)
+        P2, in2 = pos5(tu2)
+        # 3-2 ring sanity: u1 must hold {x0,x2}, u2 {x1,x2}
+        ring_ok = in1[:, 2] & in1[:, 4] & in2[:, 3] & in2[:, 4]
+        base32 = base32 & ring_ok
+        base22 = base22 & ring_ok          # holds by construction; belt+braces
 
-    # ---- gathered tag/ref rows (all routing reads go through these) ------
-    et0, et1r, et2r = mesh.etag[s0], mesh.etag[u1], mesh.etag[u2]
-    ft0, ft1r, ft2r = mesh.ftag[s0], mesh.ftag[u1], mesh.ftag[u2]
-    fr0, fr1r, fr2r = mesh.fref[s0], mesh.fref[u1], mesh.fref[u2]
+        # ---- gathered tag/ref rows (all routing reads go through these) ------
+        et0, et1r, et2r = mesh.etag[s0], mesh.etag[u1], mesh.etag[u2]
+        ft0, ft1r, ft2r = mesh.ftag[s0], mesh.ftag[u1], mesh.ftag[u2]
+        fr0, fr1r, fr2r = mesh.fref[s0], mesh.fref[u1], mesh.fref[u2]
 
-    def ecol(rows, pi, pj):
-        return jnp.take_along_axis(rows, eof[pi, pj][:, None], axis=1)[:, 0]
+        def ecol(rows, pi, pj):
+            return jnp.take_along_axis(rows, eof[pi, pj][:, None], axis=1)[:, 0]
 
-    def fcol(rows, pi):
-        return jnp.take_along_axis(rows, pi[:, None], axis=1)[:, 0]
+        def fcol(rows, pi):
+            return jnp.take_along_axis(rows, pi[:, None], axis=1)[:, 0]
 
-    # ---- 2-2 gates: boundary faces, planarity, area, duplicate edge ------
-    if enable22:
-        ft_bdy1 = fcol(ft0, P0[:, 4])          # T1 face opposite c
-        ft_bdy2 = fcol(ft2r, P2[:, 4])         # T2 face opposite c
-        fr_bdy1 = fcol(fr0, P0[:, 4])
-        fr_bdy2 = fcol(fr2r, P2[:, 4])
-        bad_face_bits = MG_REQ | MG_PARBDY | MG_NOM | MG_OPNBDY
-        base22 = base22 & ((ft_bdy1 & MG_BDY) != 0) & \
-            ((ft_bdy2 & MG_BDY) != 0) & \
-            (((ft_bdy1 | ft_bdy2) & bad_face_bits) == 0) & \
-            (ft_bdy1 == ft_bdy2) & (fr_bdy1 == fr_bdy2) & \
-            (fcol(ft0, P0[:, 2]) == 0) & (fcol(ft2r, P2[:, 3]) == 0)
-        newf = ft_bdy1
-        newfr = fr_bdy1
-        newe22 = jnp.uint32(MG_BDY) | (newf & MG_REF)
+        # ---- 2-2 gates: boundary faces, planarity, area, duplicate edge ------
+        if enable22:
+            ft_bdy1 = fcol(ft0, P0[:, 4])          # T1 face opposite c
+            ft_bdy2 = fcol(ft2r, P2[:, 4])         # T2 face opposite c
+            fr_bdy1 = fcol(fr0, P0[:, 4])
+            fr_bdy2 = fcol(fr2r, P2[:, 4])
+            bad_face_bits = MG_REQ | MG_PARBDY | MG_NOM | MG_OPNBDY
+            base22 = base22 & ((ft_bdy1 & MG_BDY) != 0) & \
+                ((ft_bdy2 & MG_BDY) != 0) & \
+                (((ft_bdy1 | ft_bdy2) & bad_face_bits) == 0) & \
+                (ft_bdy1 == ft_bdy2) & (fr_bdy1 == fr_bdy2) & \
+                (fcol(ft0, P0[:, 2]) == 0) & (fcol(ft2r, P2[:, 3]) == 0)
+            newf = ft_bdy1
+            newfr = fr_bdy1
+            newe22 = jnp.uint32(MG_BDY) | (newf & MG_REF)
 
-        pa_, pb_ = mesh.vert[a], mesh.vert[b]
-        pp_, pq_ = mesh.vert[x0], mesh.vert[x1]
-        pc_ = mesh.vert[x2]
-        n_abp = jnp.cross(pb_ - pa_, pp_ - pa_)
-        n_abq = jnp.cross(pq_ - pa_, pb_ - pa_)
-        nn = jnp.sqrt(jnp.sum(n_abp * n_abp, -1)) + EPSD
-        hloc = jnp.sqrt(jnp.maximum(jnp.maximum(
-            jnp.sum((pb_ - pa_) ** 2, -1), jnp.sum((pp_ - pa_) ** 2, -1)),
-            jnp.sum((pq_ - pa_) ** 2, -1)))
-        eps_c = jnp.finfo(mesh.vert.dtype).eps
-        cmax = jnp.max(jnp.stack([jnp.max(jnp.abs(pt_), -1) for pt_ in
-                                  (pa_, pb_, pc_, pp_, pq_)]), axis=0)
-        off_plane = jnp.abs(jnp.sum(n_abp * (pq_ - pa_), -1)) / nn
-        noise_op = 32.0 * eps_c * cmax * hloc * hloc / nn
-        # hausd relaxes the surface-exactness requirement to the Mmg
-        # approximation tolerance: the flip changes the surface by at
-        # most the quad's out-of-plane deviation
-        tol_op = flat_tol * hloc + noise_op
-        if hausd is not None:
-            tol_op = jnp.maximum(tol_op, hausd)
-        base22 = base22 & (off_plane <= tol_op)
-        area = lambda nv: 0.5 * jnp.sqrt(jnp.sum(nv * nv, -1))
-        a_old = area(n_abp) + area(n_abq)
-        a_new = area(jnp.cross(pq_ - pp_, pa_ - pp_)) + \
-            area(jnp.cross(pq_ - pp_, pb_ - pp_))
-        noise_ar = 32.0 * eps_c * cmax * hloc
-        tol_ar = 1e-5 * (a_old + EPSD) + noise_ar
-        if hausd is not None:
-            # area may legitimately change by ~ hausd * perimeter when
-            # the quad is curved within tolerance
-            tol_ar = jnp.maximum(tol_ar, hausd * hloc)
-        base22 = base22 & (jnp.abs(a_old - a_new) <= tol_ar)
-        # the flipped diagonal must not already exist (duplicate edge =>
-        # non-manifold surface).  Packed int32 binary search when ids fit
-        # (edges.PACK_LIMIT); sort-join fallback otherwise (no x64).
-        from .edges import PACK_LIMIT, sort_pairs, segmented_or
-        kmin = jnp.minimum(x0, x1)
-        kmax = jnp.maximum(x0, x1)
-        if capP <= PACK_LIMIT:
-            i32max = jnp.iinfo(jnp.int32).max
-            # the table's internal sort already produced ascending packed
-            # keys (duplicates included — harmless for the existence
-            # probe); reuse them instead of re-sorting [6*capT] keys
-            if et.skey.shape[0] == Efull:
-                ekey = et.skey
+            pa_, pb_ = mesh.vert[a], mesh.vert[b]
+            pp_, pq_ = mesh.vert[x0], mesh.vert[x1]
+            pc_ = mesh.vert[x2]
+            n_abp = jnp.cross(pb_ - pa_, pp_ - pa_)
+            n_abq = jnp.cross(pq_ - pa_, pb_ - pa_)
+            nn = jnp.sqrt(jnp.sum(n_abp * n_abp, -1)) + EPSD
+            hloc = jnp.sqrt(jnp.maximum(jnp.maximum(
+                jnp.sum((pb_ - pa_) ** 2, -1), jnp.sum((pp_ - pa_) ** 2, -1)),
+                jnp.sum((pq_ - pa_) ** 2, -1)))
+            eps_c = jnp.finfo(mesh.vert.dtype).eps
+            cmax = jnp.max(jnp.stack([jnp.max(jnp.abs(pt_), -1) for pt_ in
+                                      (pa_, pb_, pc_, pp_, pq_)]), axis=0)
+            off_plane = jnp.abs(jnp.sum(n_abp * (pq_ - pa_), -1)) / nn
+            noise_op = 32.0 * eps_c * cmax * hloc * hloc / nn
+            # hausd relaxes the surface-exactness requirement to the Mmg
+            # approximation tolerance: the flip changes the surface by at
+            # most the quad's out-of-plane deviation
+            tol_op = flat_tol * hloc + noise_op
+            if hausd is not None:
+                tol_op = jnp.maximum(tol_op, hausd)
+            base22 = base22 & (off_plane <= tol_op)
+            area = lambda nv: 0.5 * jnp.sqrt(jnp.sum(nv * nv, -1))
+            a_old = area(n_abp) + area(n_abq)
+            a_new = area(jnp.cross(pq_ - pp_, pa_ - pp_)) + \
+                area(jnp.cross(pq_ - pp_, pb_ - pp_))
+            noise_ar = 32.0 * eps_c * cmax * hloc
+            tol_ar = 1e-5 * (a_old + EPSD) + noise_ar
+            if hausd is not None:
+                # area may legitimately change by ~ hausd * perimeter when
+                # the quad is curved within tolerance
+                tol_ar = jnp.maximum(tol_ar, hausd * hloc)
+            base22 = base22 & (jnp.abs(a_old - a_new) <= tol_ar)
+            # the flipped diagonal must not already exist (duplicate edge =>
+            # non-manifold surface).  Packed int32 binary search when ids fit
+            # (edges.PACK_LIMIT); sort-join fallback otherwise (no x64).
+            from .edges import sort_pairs, segmented_or
+            kmin = jnp.minimum(x0, x1)
+            kmax = jnp.maximum(x0, x1)
+            if capP <= PACK_LIMIT:
+                i32max = jnp.iinfo(jnp.int32).max
+                # the table's internal sort already produced ascending packed
+                # keys (duplicates included — harmless for the existence
+                # probe); reuse them instead of re-sorting [6*capT] keys
+                if et.skey.shape[0] == Efull:
+                    ekey = et.skey
+                else:
+                    ekey = jnp.sort(jnp.where(
+                        et.emask, et.ev[:, 0] * capP + et.ev[:, 1], i32max))
+                pkey = kmin * capP + kmax
+                loc = jnp.searchsorted(ekey, pkey)
+                exists = ekey[jnp.clip(loc, 0, Efull - 1)] == pkey
             else:
-                ekey = jnp.sort(jnp.where(
-                    et.emask, et.ev[:, 0] * capP + et.ev[:, 1], i32max))
-            pkey = kmin * capP + kmax
-            loc = jnp.searchsorted(ekey, pkey)
-            exists = ekey[jnp.clip(loc, 0, Efull - 1)] == pkey
+                # sort-join over full table + the K compacted candidates
+                aa = jnp.concatenate([jnp.where(et.emask, et.ev[:, 0], 0),
+                                      kmin])
+                bb = jnp.concatenate([jnp.where(et.emask, et.ev[:, 1], 0),
+                                      kmax])
+                vv = jnp.concatenate([et.emask, base22])
+                n_all = Efull + E
+                order, _, _, first = sort_pairs(aa, bb, vv, capP)
+                is_edge = (order < Efull) & vv[order]
+                has_edge = segmented_or(first, is_edge.astype(jnp.uint32))
+                is_last = jnp.concatenate([first[1:], jnp.array([True])])
+                seg = jax.lax.associative_scan(
+                    jnp.maximum, jnp.where(first, jnp.arange(n_all), 0))
+                total = jnp.zeros(n_all, jnp.uint32).at[
+                    jnp.where(is_last, seg, n_all)].set(
+                    has_edge, mode="drop", unique_indices=True)
+                exists = jnp.zeros(E, bool).at[
+                    jnp.where(order >= Efull, order - Efull, E)].set(
+                    total[seg] > 0, mode="drop")
+            base22x = base22        # every gate but this one reads the
+            base22 = base22 & ~exists   # shell alone (ops/worklist)
         else:
-            # sort-join over full table + the K compacted candidates
-            aa = jnp.concatenate([jnp.where(et.emask, et.ev[:, 0], 0),
-                                  kmin])
-            bb = jnp.concatenate([jnp.where(et.emask, et.ev[:, 1], 0),
-                                  kmax])
-            vv = jnp.concatenate([et.emask, base22])
-            n_all = Efull + E
-            order, _, _, first = sort_pairs(aa, bb, vv, capP)
-            is_edge = (order < Efull) & vv[order]
-            has_edge = segmented_or(first, is_edge.astype(jnp.uint32))
-            is_last = jnp.concatenate([first[1:], jnp.array([True])])
-            seg = jax.lax.associative_scan(
-                jnp.maximum, jnp.where(first, jnp.arange(n_all), 0))
-            total = jnp.zeros(n_all, jnp.uint32).at[
-                jnp.where(is_last, seg, n_all)].set(
-                has_edge, mode="drop", unique_indices=True)
-            exists = jnp.zeros(E, bool).at[
-                jnp.where(order >= Efull, order - Efull, E)].set(
-                total[seg] > 0, mode="drop")
-        base22 = base22 & ~exists
+            newf = jnp.zeros(E, jnp.uint32)
+            newfr = jnp.zeros(E, jnp.int32)
+            newe22 = jnp.zeros(E, jnp.uint32)
+            base22x = base22
+
+        # ---- 3-2 gate: the vanishing interior faces must be untagged ---------
+        if enable32:
+            from ..core.constants import EDGE_FACES
+            cfaces = jnp.asarray(EDGE_FACES)     # faces containing IARE edge
+            face_clean = jnp.ones(E, bool)
+            for rows, Pm in ((ft0, P0), (ft1r, P1), (ft2r, P2)):
+                lae = eof[Pm[:, 0], Pm[:, 1]]
+                for k in range(2):
+                    face_clean = face_clean & \
+                        (fcol(rows, cfaces[lae, k]) == 0)
+            base32 = base32 & face_clean
+
+        cand = base32 | base22
+
+        # ---- geometric validity: a, b astride the new interior plane ---------
+        def signed_vol(v0, v1, v2, v3):
+            q0, q1, q2, q3 = (mesh.vert[v0], mesh.vert[v1], mesh.vert[v2],
+                              mesh.vert[v3])
+            return jnp.sum((q1 - q0) * jnp.cross(q2 - q0, q3 - q0), -1)
+
+        sv_a = signed_vol(x0, x1, x2, a)
+        sv_b = signed_vol(x0, x1, x2, b)
+        cand = cand & (sv_a * sv_b < 0) & (jnp.abs(sv_a) > EPSD) & \
+            (jnp.abs(sv_b) > EPSD)
+        flip_a = sv_a < 0
+        flip_b = sv_b < 0
+
+        def orient(v0, v1, v2, v3, flip):
+            w0 = jnp.where(flip, v1, v0)
+            w1 = jnp.where(flip, v0, v1)
+            return jnp.stack([w0, w1, v2, v3], axis=1)
+
+        new_a = orient(x0, x1, x2, a, flip_a)
+        new_b = orient(x0, x1, x2, b, flip_b)
+
+        # ---- quality gate: one stacked call for both new tets ----------------
+        # (q_tet computed once above, at the priority step)
+        q_old = jnp.minimum(q_tet[s0], q_tet[s1])
+        q_old = jnp.minimum(q_old, jnp.where(base32, q_tet[s2], jnp.inf))
+        new_ab = jnp.concatenate([new_a, new_b])
+        q_ab = quality_from_points(
+            mesh.vert[new_ab], None if m6 is None else m6[new_ab])
+        q_new = jnp.minimum(q_ab[:E], q_ab[E:])
+        cand = cand & (q_new > jnp.maximum(SWAP_GAIN * q_old, QUAL_FLOOR))
+        if worklist is not None:
+            viable = (base32 | base22x) & (sv_a * sv_b < 0) & \
+                (jnp.abs(sv_a) > EPSD) & (jnp.abs(sv_b) > EPSD) & \
+                (q_new > jnp.maximum(SWAP_GAIN * q_old, QUAL_FLOOR))
+
+        # ---- tag routing (base corner order (x0,x1,x2,y)) --------------------
+        # faces: col0 (opp x0) <- u2 opposite the vanished vertex; col1 <- u1;
+        # col2 <- s0 for 3-2 / the NEW boundary face for 2-2; col3 interior.
+        # edges (IARE): (x0x1, x0x2, x0y, x1x2, x1y, x2y).  A flip of
+        # (x0,x1) permutes face cols (0,1) and edge cols (0,3,4,1,2,5).
+        zero_u = jnp.zeros(E, jnp.uint32)
+        zero_i = jnp.zeros(E, jnp.int32)
+
+        def route_f(col0, col1, col2, zero, flip):
+            w0 = jnp.where(flip, col1, col0)
+            w1 = jnp.where(flip, col0, col1)
+            return jnp.stack([w0, w1, col2, zero], axis=1)
+
+        def route_e(cols, flip):
+            flipped = [cols[0], cols[3], cols[4], cols[1], cols[2], cols[5]]
+            return jnp.stack([jnp.where(flip, f, n)
+                              for n, f in zip(cols, flipped)], axis=1)
+
+        def routed(y_idx):
+            """Face/edge/ref routing for new tet (x0,x1,x2,y); y_idx: 0=a 1=b.
+
+            Inherited faces are the old faces OPPOSITE the vanished endpoint
+            (tet A keeps the faces that b vanished from), so face columns use
+            the other endpoint's positions; edges incident to y use y's own.
+            """
+            py0, py1, py2 = P0[:, y_idx], P1[:, y_idx], P2[:, y_idx]
+            po0, po1, po2 = (P0[:, 1 - y_idx], P1[:, 1 - y_idx],
+                             P2[:, 1 - y_idx])
+            ftag_n = route_f(
+                fcol(ft2r, po2), fcol(ft1r, po1),
+                jnp.where(base32, fcol(ft0, po0), newf), zero_u,
+                flip_a if y_idx == 0 else flip_b)
+            fref_n = route_f(
+                fcol(fr2r, po2), fcol(fr1r, po1),
+                jnp.where(base32, fcol(fr0, po0), newfr), zero_i,
+                flip_a if y_idx == 0 else flip_b)
+            e0 = jnp.where(base32, ecol(et0, P0[:, 2], P0[:, 3]), newe22)
+            e1 = ecol(et1r, P1[:, 2], P1[:, 4])
+            e2 = ecol(et0, P0[:, 2], py0)
+            e3 = ecol(et2r, P2[:, 3], P2[:, 4])
+            e4 = jnp.where(base32, ecol(et0, P0[:, 3], py0),
+                           ecol(et2r, P2[:, 3], py2))
+            e5 = ecol(et2r, P2[:, 4], py2) | \
+                jnp.where(base22, ecol(et0, P0[:, 4], py0), 0)
+            etag_n = route_e([e0, e1, e2, e3, e4, e5],
+                             flip_a if y_idx == 0 else flip_b)
+            return ftag_n, fref_n, etag_n
+
+        ftag_a, fref_a, etag_a = routed(0)
+        ftag_b, fref_b, etag_b = routed(1)
+
+        return _EdgeRows(
+            cand, viable if worklist is not None else cand, base32, base22,
+            q_old, q_new, s0, s1, s2, x0, x1, new_a, new_b,
+            ftag_a, fref_a, etag_a, ftag_b, fref_b, etag_b)
+
+    pos = None
+    if worklist is None:
+        rows = stage(sel)
     else:
-        newf = jnp.zeros(E, jnp.uint32)
-        newfr = jnp.zeros(E, jnp.int32)
-        newe22 = jnp.zeros(E, jnp.uint32)
-
-    # ---- 3-2 gate: the vanishing interior faces must be untagged ---------
-    if enable32:
-        from ..core.constants import EDGE_FACES
-        cfaces = jnp.asarray(EDGE_FACES)     # faces containing IARE edge
-        face_clean = jnp.ones(E, bool)
-        for rows, Pm in ((ft0, P0), (ft1r, P1), (ft2r, P2)):
-            lae = eof[Pm[:, 0], Pm[:, 1]]
-            for k in range(2):
-                face_clean = face_clean & \
-                    (fcol(rows, cfaces[lae, k]) == 0)
-        base32 = base32 & face_clean
-
-    cand = base32 | base22
-
-    # ---- geometric validity: a, b astride the new interior plane ---------
-    def signed_vol(v0, v1, v2, v3):
-        q0, q1, q2, q3 = (mesh.vert[v0], mesh.vert[v1], mesh.vert[v2],
-                          mesh.vert[v3])
-        return jnp.sum((q1 - q0) * jnp.cross(q2 - q0, q3 - q0), -1)
-
-    sv_a = signed_vol(x0, x1, x2, a)
-    sv_b = signed_vol(x0, x1, x2, b)
-    cand = cand & (sv_a * sv_b < 0) & (jnp.abs(sv_a) > EPSD) & \
-        (jnp.abs(sv_b) > EPSD)
-    flip_a = sv_a < 0
-    flip_b = sv_b < 0
-
-    def orient(v0, v1, v2, v3, flip):
-        w0 = jnp.where(flip, v1, v0)
-        w1 = jnp.where(flip, v0, v1)
-        return jnp.stack([w0, w1, v2, v3], axis=1)
-
-    new_a = orient(x0, x1, x2, a, flip_a)
-    new_b = orient(x0, x1, x2, b, flip_b)
-
-    # ---- quality gate: one stacked call for both new tets ----------------
-    # (q_tet computed once above, at the priority step)
-    q_old = jnp.minimum(q_tet[s0], q_tet[s1])
-    q_old = jnp.minimum(q_old, jnp.where(base32, q_tet[s2], jnp.inf))
-    new_ab = jnp.concatenate([new_a, new_b])
-    q_ab = quality_from_points(
-        mesh.vert[new_ab], None if m6 is None else m6[new_ab])
-    q_new = jnp.minimum(q_ab[:E], q_ab[E:])
-    cand = cand & (q_new > jnp.maximum(SWAP_GAIN * q_old, QUAL_FLOOR))
-
-    # ---- tag routing (base corner order (x0,x1,x2,y)) --------------------
-    # faces: col0 (opp x0) <- u2 opposite the vanished vertex; col1 <- u1;
-    # col2 <- s0 for 3-2 / the NEW boundary face for 2-2; col3 interior.
-    # edges (IARE): (x0x1, x0x2, x0y, x1x2, x1y, x2y).  A flip of
-    # (x0,x1) permutes face cols (0,1) and edge cols (0,3,4,1,2,5).
-    zero_u = jnp.zeros(E, jnp.uint32)
-    zero_i = jnp.zeros(E, jnp.int32)
-
-    def route_f(col0, col1, col2, zero, flip):
-        w0 = jnp.where(flip, col1, col0)
-        w1 = jnp.where(flip, col0, col1)
-        return jnp.stack([w0, w1, col2, zero], axis=1)
-
-    def route_e(cols, flip):
-        flipped = [cols[0], cols[3], cols[4], cols[1], cols[2], cols[5]]
-        return jnp.stack([jnp.where(flip, f, n)
-                          for n, f in zip(cols, flipped)], axis=1)
-
-    def routed(y_idx):
-        """Face/edge/ref routing for new tet (x0,x1,x2,y); y_idx: 0=a 1=b.
-
-        Inherited faces are the old faces OPPOSITE the vanished endpoint
-        (tet A keeps the faces that b vanished from), so face columns use
-        the other endpoint's positions; edges incident to y use y's own.
-        """
-        py0, py1, py2 = P0[:, y_idx], P1[:, y_idx], P2[:, y_idx]
-        po0, po1, po2 = (P0[:, 1 - y_idx], P1[:, 1 - y_idx],
-                         P2[:, 1 - y_idx])
-        ftag_n = route_f(
-            fcol(ft2r, po2), fcol(ft1r, po1),
-            jnp.where(base32, fcol(ft0, po0), newf), zero_u,
-            flip_a if y_idx == 0 else flip_b)
-        fref_n = route_f(
-            fcol(fr2r, po2), fcol(fr1r, po1),
-            jnp.where(base32, fcol(fr0, po0), newfr), zero_i,
-            flip_a if y_idx == 0 else flip_b)
-        e0 = jnp.where(base32, ecol(et0, P0[:, 2], P0[:, 3]), newe22)
-        e1 = ecol(et1r, P1[:, 2], P1[:, 4])
-        e2 = ecol(et0, P0[:, 2], py0)
-        e3 = ecol(et2r, P2[:, 3], P2[:, 4])
-        e4 = jnp.where(base32, ecol(et0, P0[:, 3], py0),
-                       ecol(et2r, P2[:, 3], py2))
-        e5 = ecol(et2r, P2[:, 4], py2) | \
-            jnp.where(base22, ecol(et0, P0[:, 4], py0), 0)
-        etag_n = route_e([e0, e1, e2, e3, e4, e5],
-                         flip_a if y_idx == 0 else flip_b)
-        return ftag_n, fref_n, etag_n
-
-    ftag_a, fref_a, etag_a = routed(0)
-    ftag_b, fref_b, etag_b = routed(1)
+        from . import worklist as wl
+        # the compaction line: ahead of it the wave costs its capacity,
+        # after it what the list holds
+        sh0 = et.shell3[sel]
+        listed = pre[sel] & wl.on_list(
+            worklist, jnp.clip(sh0, 0, capT - 1), sh0 >= 0,
+            jnp.clip(et.ev[sel, 0], 0, capP - 1),
+            jnp.clip(et.ev[sel, 1], 0, capP - 1))
+        pos, nlist = wl.listed_first(listed)
+        sel = sel[pos]
+        # past PACK_LIMIT the duplicate-diagonal probe is a sort-join
+        # over the whole edge table: one chunk, so it runs once
+        rows = wl.staged(stage, sel, nlist,
+                         wl.CHUNKS if capP <= PACK_LIMIT else 1)
+        on = jnp.arange(K) < nlist
+        rows = rows._replace(cand=rows.cand & on, viable=rows.viable & on)
+    (cand, viable, base32, base22, q_old, q_new, s0, s1, s2, x0, x1,
+     new_a, new_b, ftag_a, fref_a, etag_a, ftag_b, fref_b, etag_b) = rows
+    E = K
 
     # ---- claims: s0, s1 (+ s2 for 3-2), exclusively ----------------------
     s2eff = jnp.where(base32, s2, s0)        # duplicate claim is harmless
-    win = claim_shells(q_new - q_old, cand, (s0, s1, s2eff), capT)
+    win = claim_shells(q_new - q_old, cand, (s0, s1, s2eff), capT, pos=pos)
 
     if enable22:
         # same-wave duplicate-diagonal veto: two 2-2 winners flipping to
@@ -463,7 +540,14 @@ def swap_edges_wave(mesh: Mesh, met: jax.Array, enable32: bool = True,
     nsw = jnp.sum(win.astype(jnp.int32))
     out = dataclasses.replace(mesh, tet=tet, tmask=tmask, ftag=ftag,
                               fref=fref, etag=etag, nelem=mesh.nelem)
-    return SwapResult(out, nsw, defer)
+    if worklist is None:
+        return SwapResult(out, nsw, defer)
+    # what stays on the list passed every gate of its own shell and did
+    # not apply: a claim loser, a duplicate diagonal, one ``exists`` alone
+    # refused (a swap elsewhere can remove that edge)
+    return SwapResult(
+        out, nsw, defer, wl.keep_rows(viable & ~win, s0, npre, capT),
+        jnp.minimum(npre, K), nlist)
 
 
 def swap32_wave(mesh: Mesh, met: jax.Array) -> SwapResult:

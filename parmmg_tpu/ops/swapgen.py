@@ -43,20 +43,29 @@ NT_NEW = 2 * NTRI       # new tets per fan (padded)
 class SwapGenResult(NamedTuple):
     mesh: Mesh
     nswap: jax.Array
+    # with a worklist only (ops/worklist):
+    keep: jax.Array = None      # [capT] bool, rows that stay on the list
+    ncand: jax.Array = None     # candidate rows the top-K selected
+    nlist: jax.Array = None     # of them on the list: what was judged
 
 
 def swapgen_wave(mesh: Mesh, met: jax.Array,
                  budget_div: int = 8, budget: int | None = None,
-                 lmax: float | None = None) -> SwapGenResult:
+                 lmax: float | None = None,
+                 worklist=None) -> SwapGenResult:
+    """``worklist``: an ``ops/worklist.Dirty``, what changed since this
+    kernel last judged the mesh.  Only the candidates it lists are
+    evaluated, in chunks as wide as the list; the result is the full
+    evaluation's to the bit while the caller keeps the list by that
+    module's rules.  None evaluates every candidate."""
     from ..core.constants import LLONG
+    from . import worklist as wl
     if lmax is None:
         lmax = LLONG
     capT, capP = mesh.capT, mesh.capP
     et = unique_edges(mesh, shell_slots=RING_MAX)
     m6 = None if met.ndim == 1 else met
     Efull = et.ev.shape[0]
-    eof = jnp.asarray(_EDGE_OF)
-    efaces = jnp.asarray(EDGE_FACES)
 
     # ---- full-width candidacy + worst-shell priority --------------------
     q_tet = quality_from_points(
@@ -79,16 +88,141 @@ def swapgen_wave(mesh: Mesh, met: jax.Array,
     K = min(Efull, wave_budget(capT, budget_div, budget))
     _, selx = jax.lax.top_k(jnp.where(pre, -q_shell_f, -jnp.inf), K)
 
-    ar = jnp.arange(K)
-    cand = pre[selx]
-    n = et.nshell[selx]                                  # [K]
-    sh = sh_f[selx]                                      # [K, 6] slots
-    shc = jnp.clip(sh, 0, capT - 1)
-    slot_valid = (sh >= 0) & (jnp.arange(RING_MAX)[None, :] < n[:, None])
-    a = jnp.clip(et.ev[selx, 0], 0, capP - 1)
-    b = jnp.clip(et.ev[selx, 1], 0, capP - 1)
-    q_old = q_shell_f[selx]
+    def shell_of(sel):
+        """A selection's compacted shell: slots [k, 6] clipped, which of
+        them count, the edge's ends."""
+        n = et.nshell[sel]
+        sh = sh_f[sel]
+        slot_valid = (sh >= 0) & \
+            (jnp.arange(RING_MAX)[None, :] < n[:, None])
+        a = jnp.clip(et.ev[sel, 0], 0, capP - 1)
+        b = jnp.clip(et.ev[sel, 1], 0, capP - 1)
+        return n, jnp.clip(sh, 0, capT - 1), slot_valid, a, b
 
+    def stage(sel):
+        """The candidate stage, row by row: ring chain, the six fans,
+        the best fan's rows and tags."""
+        return _ring_stage(mesh, met, lmax, pre[sel], q_shell_f[sel],
+                           *shell_of(sel))
+
+    pos = None
+    if worklist is None:
+        rows = stage(selx)
+    else:
+        # the compaction line: ahead of it the wave costs its capacity,
+        # after it what the list holds
+        _, shc0, valid0, a0, b0 = shell_of(selx)
+        listed = pre[selx] & wl.on_list(worklist, shc0, valid0, a0, b0)
+        pos, nlist = wl.listed_first(listed)
+        selx = selx[pos]
+        rows = wl.staged(stage, selx, nlist)
+        rows = rows._replace(cand=rows.cand & (jnp.arange(K) < nlist))
+    cand, n, shc, slot_valid = rows.cand, rows.n, rows.shc, rows.slot_valid
+    q_old, q_new = rows.q_old, rows.q_new
+
+    # ---- claims ----------------------------------------------------------
+    sh_eff = tuple(
+        jnp.where(slot_valid[:, k], shc[:, k], shc[:, 0])
+        for k in range(RING_MAX))
+    win = claim_shells(q_new - q_old, cand, sh_eff, capT, pos=pos)
+
+    # ---- allocation of the extra (n-4) slots -----------------------------
+    # slot-reusing pool (edges.free_rows): each winner takes up to
+    # RING_MAX-4 consecutive POOL entries, not consecutive slots
+    from .edges import free_rows
+    LF = 2 * K
+    frow_t, nfree_t = free_rows(mesh.tmask, LF)
+    extra = jnp.where(win, n - 4, 0)
+    off = jnp.cumsum(extra) - extra
+    fits = (off + extra) <= jnp.minimum(nfree_t, LF)
+    win = win & fits
+    extra = jnp.where(win, n - 4, 0)
+    off = jnp.cumsum(extra) - extra
+
+    # ---- write: m < n reuses shell slots, m >= n allocates ---------------
+    nsw = jnp.sum(win.astype(jnp.int32))
+
+    def _apply(_):
+        tet_o = mesh.tet
+        ftag_o = mesh.ftag
+        fref_o = mesh.fref
+        etag_o = mesh.etag
+        tmask_o = mesh.tmask
+        tref_o = mesh.tref
+        idx_all = []
+        for m in range(NT_NEW):
+            valid_m = win & (m < 2 * (n - 2))
+            tgt = jnp.where(
+                m < n, shc[:, min(m, RING_MAX - 1)],
+                frow_t[jnp.clip(off + jnp.maximum(m - n, 0), 0, LF - 1)])
+            idx_all.append(jnp.where(valid_m, tgt, capT))
+        idx_cat = jnp.concatenate(idx_all)
+        tet_o = tet_o.at[idx_cat].set(
+            rows.tets.transpose(1, 0, 2).reshape(NT_NEW * K, 4),
+            mode="drop")
+        ftag_o = ftag_o.at[idx_cat].set(
+            rows.ftag.transpose(1, 0, 2).reshape(NT_NEW * K, 4),
+            mode="drop")
+        fref_o = fref_o.at[idx_cat].set(
+            rows.fref.transpose(1, 0, 2).reshape(NT_NEW * K, 4),
+            mode="drop")
+        etag_o = etag_o.at[idx_cat].set(
+            rows.etag.transpose(1, 0, 2).reshape(NT_NEW * K, 6),
+            mode="drop")
+        tmask_o = tmask_o.at[idx_cat].set(True, mode="drop")
+        tref_o = tref_o.at[idx_cat].set(
+            jnp.tile(tref0[selx], NT_NEW), mode="drop")
+        return tet_o, ftag_o, fref_o, etag_o, tmask_o, tref_o
+
+    def _skip(_):
+        return (mesh.tet, mesh.ftag, mesh.fref, mesh.etag, mesh.tmask,
+                mesh.tref)
+
+    tet_o, ftag_o, fref_o, etag_o, tmask_o, tref_o = jax.lax.cond(
+        nsw > 0, _apply, _skip, None)
+    used_hi = jnp.where(extra > 0,
+                        frow_t[jnp.clip(off + extra - 1, 0, LF - 1)] + 1, 0)
+    nelem = jnp.maximum(mesh.nelem, jnp.max(used_hi))
+    out = dataclasses.replace(
+        mesh, tet=tet_o, tmask=tmask_o, tref=tref_o, ftag=ftag_o,
+        fref=fref_o, etag=etag_o, nelem=nelem.astype(jnp.int32))
+    if worklist is None:
+        return SwapGenResult(out, nsw)
+    # every gate of the stage reads the shell alone, so what stays on the
+    # list is what passed them all and lost a claim or found no free row
+    npre = jnp.sum(pre, dtype=jnp.int32)
+    return SwapGenResult(
+        out, nsw, wl.keep_rows(cand & ~win, shc[:, 0], npre, capT),
+        jnp.minimum(npre, K), nlist)
+
+
+class _RingRows(NamedTuple):
+    """Per candidate row, what the claims and the apply read."""
+    cand: jax.Array         # [k] passed every gate (all read the shell)
+    n: jax.Array            # [k] shell degree
+    shc: jax.Array          # [k, 6] shell rows, clipped
+    slot_valid: jax.Array   # [k, 6]
+    q_old: jax.Array
+    q_new: jax.Array
+    tets: jax.Array         # [k, NT_NEW, 4] the best fan's tets
+    ftag: jax.Array
+    fref: jax.Array
+    etag: jax.Array         # [k, NT_NEW, 6]
+
+
+def _ring_stage(mesh: Mesh, met, lmax, cand, q_old, n, shc,
+                slot_valid, a, b) -> _RingRows:
+    """swapgen_wave's candidate stage on compacted rows, each row
+    by itself: every value read is one of the row's shell (its tets'
+    vertex ids, tags and references, their vertices' coordinates and
+    metric), which is what lets a worklist skip a row whose shell did not
+    change."""
+    capP = mesh.capP
+    m6 = None if met.ndim == 1 else met
+    nrow = cand.shape[0]
+    ar = jnp.arange(nrow)
+    eof = jnp.asarray(_EDGE_OF)
+    efaces = jnp.asarray(EDGE_FACES)
     tvs = mesh.tet[shc]                                  # [K,6,4]
     is_a = tvs == a[:, None, None]
     is_b = tvs == b[:, None, None]
@@ -115,11 +249,11 @@ def swapgen_wave(mesh: Mesh, met: jax.Array,
     # the unused shell tet containing the chain head; the final unused
     # tet must close the cycle.  A ring vertex belongs to exactly 2
     # shell tets in a valid ring, so the chain is deterministic.
-    ring = jnp.zeros((K, RING_MAX), jnp.int32)
-    tet_of_pair = jnp.zeros((K, RING_MAX), jnp.int32)    # shell SLOT idx
+    ring = jnp.zeros((nrow, RING_MAX), jnp.int32)
+    tet_of_pair = jnp.zeros((nrow, RING_MAX), jnp.int32)    # shell SLOT idx
     ring = ring.at[:, 0].set(x[:, 0])
     ring = ring.at[:, 1].set(y[:, 0])
-    used = jnp.zeros((K, RING_MAX), bool).at[:, 0].set(True)
+    used = jnp.zeros((nrow, RING_MAX), bool).at[:, 0].set(True)
     used = used | ~slot_valid                            # pad slots "used"
     cur = y[:, 0]
     for step in range(2, RING_MAX):
@@ -203,11 +337,11 @@ def swapgen_wave(mesh: Mesh, met: jax.Array,
         vols_a = []
         vols_b = []
         tris = []
-        diag_long = jnp.zeros((K,), bool)
+        diag_long = jnp.zeros((nrow,), bool)
         for k in range(NTRI):
-            i_i = ring_at(jnp.full((K,), c, jnp.int32))
-            i_j = ring_at(c + k + 1 + jnp.zeros((K,), jnp.int32))
-            i_k = ring_at(c + k + 2 + jnp.zeros((K,), jnp.int32))
+            i_i = ring_at(jnp.full((nrow,), c, jnp.int32))
+            i_j = ring_at(c + k + 1 + jnp.zeros((nrow,), jnp.int32))
+            i_k = ring_at(c + k + 2 + jnp.zeros((nrow,), jnp.int32))
             pi = ringp[ar, i_i]
             pj = ringp[ar, i_j]
             pk = ringp[ar, i_k]
@@ -249,9 +383,9 @@ def swapgen_wave(mesh: Mesh, met: jax.Array,
             tet_rows.append(jnp.stack([w0b, w1b, gk, b], 1))
         rows = jnp.stack(tet_rows, 1)                    # [K, NT_NEW, 4]
         qf = quality_from_points(
-            mesh.vert[rows.reshape(K * NT_NEW, 4)],
-            None if m6 is None else m6[rows.reshape(K * NT_NEW, 4)])
-        qf = qf.reshape(K, NT_NEW)
+            mesh.vert[rows.reshape(nrow * NT_NEW, 4)],
+            None if m6 is None else m6[rows.reshape(nrow * NT_NEW, 4)])
+        qf = qf.reshape(nrow, NT_NEW)
         mvalid = jnp.repeat(kvalid, 2, axis=1)           # [K, NT_NEW]
         fan_q.append(jnp.min(jnp.where(mvalid, qf, jnp.inf), axis=1))
         fan_ok.append(active_fan & ok)
@@ -265,25 +399,6 @@ def swapgen_wave(mesh: Mesh, met: jax.Array,
     q_new = fq_m[ar, best_c]
     cand = cand & jnp.any(fok, axis=1) & \
         (q_new > jnp.maximum(SWAP_GAIN * q_old, QUAL_FLOOR))
-
-    # ---- claims ----------------------------------------------------------
-    sh_eff = tuple(
-        jnp.where(slot_valid[:, k], shc[:, k], shc[:, 0])
-        for k in range(RING_MAX))
-    win = claim_shells(q_new - q_old, cand, sh_eff, capT)
-
-    # ---- allocation of the extra (n-4) slots -----------------------------
-    # slot-reusing pool (edges.free_rows): each winner takes up to
-    # RING_MAX-4 consecutive POOL entries, not consecutive slots
-    from .edges import free_rows
-    LF = 2 * K
-    frow_t, nfree_t = free_rows(mesh.tmask, LF)
-    extra = jnp.where(win, n - 4, 0)
-    off = jnp.cumsum(extra) - extra
-    fits = (off + extra) <= jnp.minimum(nfree_t, LF)
-    win = win & fits
-    extra = jnp.where(win, n - 4, 0)
-    off = jnp.cumsum(extra) - extra
 
     # ---- gather the winning fan's rows + route tags ----------------------
     tets_best = jnp.stack(fan_tets, 1)[ar, best_c]       # [K, NT_NEW, 4]
@@ -305,8 +420,8 @@ def swapgen_wave(mesh: Mesh, met: jax.Array,
         f_src = face_a if apex_is_a else face_b
         fr_src = fref_a if apex_is_a else fref_b
         sp_src = spoke_a if apex_is_a else spoke_b
-        zero_u = jnp.zeros(K, jnp.uint32)
-        zero_i = jnp.zeros(K, jnp.int32)
+        zero_u = jnp.zeros(nrow, jnp.uint32)
+        zero_i = jnp.zeros(nrow, jnp.int32)
         is_first = k == 0                                # (pi,pj) ring pair
         nlast = (k == (n - 3))                           # (pi,pk) ring pair
         pair_c = ring_at(c_arr)                          # pair index c
@@ -356,54 +471,8 @@ def swapgen_wave(mesh: Mesh, met: jax.Array,
     fref_new = jnp.stack(fref_rows, 1)
     etag_new = jnp.stack(etag_rows, 1)                   # [K, NT_NEW, 6]
 
-    # ---- write: m < n reuses shell slots, m >= n allocates ---------------
-    nsw = jnp.sum(win.astype(jnp.int32))
-
-    def _apply(_):
-        tet_o = mesh.tet
-        ftag_o = mesh.ftag
-        fref_o = mesh.fref
-        etag_o = mesh.etag
-        tmask_o = mesh.tmask
-        tref_o = mesh.tref
-        idx_all = []
-        for m in range(NT_NEW):
-            valid_m = win & (m < 2 * (n - 2))
-            tgt = jnp.where(
-                m < n, shc[:, min(m, RING_MAX - 1)],
-                frow_t[jnp.clip(off + jnp.maximum(m - n, 0), 0, LF - 1)])
-            idx_all.append(jnp.where(valid_m, tgt, capT))
-        idx_cat = jnp.concatenate(idx_all)
-        tet_o = tet_o.at[idx_cat].set(
-            tets_best.transpose(1, 0, 2).reshape(NT_NEW * K, 4),
-            mode="drop")
-        ftag_o = ftag_o.at[idx_cat].set(
-            ftag_new.transpose(1, 0, 2).reshape(NT_NEW * K, 4),
-            mode="drop")
-        fref_o = fref_o.at[idx_cat].set(
-            fref_new.transpose(1, 0, 2).reshape(NT_NEW * K, 4),
-            mode="drop")
-        etag_o = etag_o.at[idx_cat].set(
-            etag_new.transpose(1, 0, 2).reshape(NT_NEW * K, 6),
-            mode="drop")
-        tmask_o = tmask_o.at[idx_cat].set(True, mode="drop")
-        tref_o = tref_o.at[idx_cat].set(
-            jnp.tile(tref0[selx], NT_NEW), mode="drop")
-        return tet_o, ftag_o, fref_o, etag_o, tmask_o, tref_o
-
-    def _skip(_):
-        return (mesh.tet, mesh.ftag, mesh.fref, mesh.etag, mesh.tmask,
-                mesh.tref)
-
-    tet_o, ftag_o, fref_o, etag_o, tmask_o, tref_o = jax.lax.cond(
-        nsw > 0, _apply, _skip, None)
-    used_hi = jnp.where(extra > 0,
-                        frow_t[jnp.clip(off + extra - 1, 0, LF - 1)] + 1, 0)
-    nelem = jnp.maximum(mesh.nelem, jnp.max(used_hi))
-    out = dataclasses.replace(
-        mesh, tet=tet_o, tmask=tmask_o, tref=tref_o, ftag=ftag_o,
-        fref=fref_o, etag=etag_o, nelem=nelem.astype(jnp.int32))
-    return SwapGenResult(out, nsw)
+    return _RingRows(cand, n, shc, slot_valid, q_old, q_new, tets_best,
+                     ftag_new, fref_new, etag_new)
 
 
 # eager entry point: ONE module-level jitted object + compile-ledger

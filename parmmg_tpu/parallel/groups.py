@@ -682,7 +682,7 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                         polish_block, stacked, met_s,
                         jnp.asarray(2000 + w, jnp.int32), plans, ltim)
                     sched.note_plan_pads(plans)
-                    cnts = np.concatenate(parts)      # [n_act, 9]
+                    cnts = np.concatenate(parts)      # [n_act, 11]
                     pol_traj.append(len(pol_act))
                     tot = cnts.sum(axis=0, dtype=np.int64).tolist()
                     otrace.log(2, f"  grp polish w{w}: collapse "

@@ -2,11 +2,19 @@
 
 Runs one job of a benchmark cell through ``ParMesh.run``, keeps the merged
 mesh as it stands at the entry of ``driver._merged_polish``, and replays
-the polish on it as SEVEN programs a wave (the stages of
+the polish on it as seven programs a wave (the stages of
 ``ops/adapt.sliver_polish_impl``, every one run whatever its input), each
 timed from dispatch to ``block_until_ready``, on the host's CPU backend
-where the driver stages the tail.  A diagnostic, not a contract: it
-mirrors the wave's composition as of PR 33.
+where the driver stages the tail.  The two swap kernels that take the
+polish's worklist (``ops/worklist``) are split at the compaction line:
+``*_head`` is the kernel on an EMPTY list (edge table, qualities, top-K,
+then claims and apply over K rows with no candidate: what no list can
+shorten), ``*_rows`` the rest of its seconds on the list the waves have
+kept (the candidate stage, as wide as the list); ``list`` is the
+bookkeeping between the stages.  Each wave's row says how many candidate
+rows the kernels' top-K selected (``cand``) and how many of them were on
+the list (``wl``).  A diagnostic, not a contract: it mirrors the wave's
+composition as of PR 35.
 
     python scripts/polish_stages.py --cell iso-growth --seed 21 \
         [--save DIR] [--from DIR] [--out FILE.json]
@@ -31,8 +39,13 @@ sys.path[:0] = [os.path.join(ROOT, "benchmarks"), ROOT]
 
 import numpy as np  # noqa: E402
 
-STAGES = ("collapse", "swap_edges", "swapgen", "adjacency", "swap23",
-          "smooth", "exit_adjacency")
+STAGES = ("collapse", "swap_edges_head", "swap_edges_rows", "swapgen_head",
+          "swapgen_rows", "adjacency", "swap23", "smooth", "exit_adjacency",
+          "list")
+# what each stage applied is counted under these (the driver's loop ends
+# on a wave whose first five apply nothing)
+APPLIED = ("collapse", "swap_edges", "swapgen", "adjacency", "swap23",
+           "smooth", "exit_adjacency")
 
 
 def say(*a):
@@ -77,7 +90,7 @@ def capture_job(cell: str, seed: int) -> tuple[dict, dict]:
     sha = {k: hashlib.sha256(np.ascontiguousarray(res[k]).tobytes())
            .hexdigest() for k in ("vert", "tet", "met")}
     waves = [{k: r[k] for k in ("collapse", "swap", "moved", "bad", "col",
-                                "adj", "dur") if k in r}
+                                "adj", "wl", "cand", "dur") if k in r}
              for r in TRACER.ring if r.get("name") == "polish wave"]
     digest = {"rc": res["rc"], "seconds": res["seconds"],
               "ntets": len(res["tet"]), "sha256": sha, "waves": waves,
@@ -108,13 +121,15 @@ def restore(path: str) -> dict:
 
 
 def replay(seen: dict, waves: int = 8, sliver_q: float = 0.2) -> list[dict]:
-    """The polish on ``seen`` as seven programs a wave; per wave, each
-    stage's seconds and what it applied, and the tets under ``sliver_q``
-    at the wave's entry.  Wave 0 pays the compiles."""
+    """The polish on ``seen``, stage by stage; per wave, each stage's
+    seconds and what it applied, the tets under ``sliver_q`` at the
+    wave's entry, and the two listed kernels' ``cand`` and ``wl`` rows.
+    Wave 0 pays the compiles."""
     import jax
     import jax.numpy as jnp
     from functools import partial
     from parmmg_tpu.driver import polish_budget
+    from parmmg_tpu.ops import worklist as wlist
     from parmmg_tpu.ops.adjacency import (boundary_edge_tags,
                                           build_adjacency)
     from parmmg_tpu.ops.collapse import collapse_wave
@@ -145,39 +160,81 @@ def replay(seen: dict, waves: int = 8, sliver_q: float = 0.2) -> list[dict]:
             return r.mesh, getattr(r, count)
         return jax.jit(run)
 
+    def listed(wave_fn, **kws):
+        """A kernel that takes a list: (mesh, applied, keep, cand, wl)."""
+        def run(m, k, dirty):
+            r = wave_fn(m, k, worklist=dirty, **kws)
+            return r.mesh, r.nswap, r.keep, r.ncand, r.nlist
+        return jax.jit(run)
+
     adjacency = jax.jit(lambda m, k: (build_adjacency(m), jnp.int32(0)))
     programs = {
         "collapse": jax.jit(collapse),
-        "swap_edges": of(swap_edges_wave, "nswap", hausd=hausd, **kw),
-        "swapgen": of(swapgen_wave, "nswap", **kw),
         "adjacency": adjacency,
         "swap23": of(swap23_wave, "nswap", **kw),
         "smooth": of(partial(smooth_wave, opt_q=sliver_q, hausd=hausd),
                      "nmoved"),
         "exit_adjacency": adjacency,
     }
+    kernels = {     # stage -> (its program, its field of the PolishList)
+        "swap_edges": (listed(swap_edges_wave, hausd=hausd, **kw), "edges"),
+        "swapgen": (listed(swapgen_wave, **kw), "rings"),
+    }
     count_bad = jax.jit(count_bad)
+    noted = jax.jit(wlist.noted)
     rows = []
+
+    def timed(fn, *a):
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(fn(*a))
+        return out, time.perf_counter() - t0
+
     with host_staging():
         mesh = jax.tree.map(jnp.asarray, seen["mesh"])
         met = jnp.asarray(seen["met"])
+        wl = wlist.all_dirty(mesh)
+        nothing = jax.tree.map(jnp.zeros_like, wl.edges)
         say(f"replay: {n_live} live tets at capT {mesh.capT}, budget "
             f"{budget}, hausd {hausd}")
         for w in range(waves):
             row = {"wave": w, "bad": int(count_bad(mesh, met)),
-                   "s": {}, "n": {}}
-            for name in STAGES:
-                args = (jnp.asarray(1000 + w, jnp.int32),) \
-                    if name == "smooth" else ()
-                t0 = time.perf_counter()
-                mesh, n = programs[name](mesh, met, *args)
-                jax.block_until_ready((mesh, n))
-                row["s"][name] = time.perf_counter() - t0
+                   "s": dict.fromkeys(STAGES, 0.0), "n": {},
+                   "cand": {}, "wl": {}}
+
+            def note(wl, before, after):
+                wl, s = timed(noted, wl, before, after)
+                row["s"]["list"] += s
+                return wl
+
+            since = mesh        # what the lists last took note of
+            for name in APPLIED:
+                if name in kernels:
+                    fn, field = kernels[name]
+                    if since is not mesh:
+                        wl = note(wl, since, mesh)
+                    _, row["s"][name + "_head"] = timed(
+                        fn, mesh, met, nothing)
+                    (after, n, keep, cand, nl), s = timed(
+                        fn, mesh, met, getattr(wl, field))
+                    row["s"][name + "_rows"] = s - row["s"][name + "_head"]
+                    row["cand"][name], row["wl"][name] = int(cand), int(nl)
+                    wl = note(wl._replace(**{field: wlist.looked(
+                        getattr(wl, field), keep)}), mesh, after)
+                    mesh = since = after
+                else:
+                    args = (jnp.asarray(1000 + w, jnp.int32),) \
+                        if name == "smooth" else ()
+                    (mesh, n), row["s"][name] = timed(
+                        programs[name], mesh, met, *args)
                 row["n"][name] = int(n)
+            wl = note(wl, since, mesh)
             say(f"  wave {w}: bad {row['bad']:5d}  " + "  ".join(
-                f"{k} {row['s'][k]:.3f}s/{row['n'][k]}" for k in row["s"]))
+                f"{k} {row['s'][k]:.3f}s" for k in STAGES) + "  applied "
+                + "/".join(str(row["n"][k]) for k in APPLIED) + "  wl/cand "
+                + "  ".join(f"{k} {row['wl'][k]}/{row['cand'][k]}"
+                            for k in kernels))
             rows.append(row)
-            if sum(row["n"][k] for k in STAGES[:5]) == 0:
+            if sum(row["n"][k] for k in APPLIED[:5]) == 0:
                 break       # the driver's loop ends here too
     return rows
 
@@ -194,7 +251,14 @@ def summary(rows: list[dict]) -> dict:
             "share_pct": {k: 100.0 * v / total for k, v in mean.items()},
             "bad_by_wave": [r["bad"] for r in rows],
             "applied_by_wave": {k: [r["n"][k] for r in rows]
-                                for k in rows[0]["n"]}}
+                                for k in rows[0]["n"]},
+            "cand_by_wave": {k: [r["cand"][k] for r in rows]
+                             for k in rows[0]["cand"]},
+            "wl_by_wave": {k: [r["wl"][k] for r in rows]
+                           for k in rows[0]["wl"]},
+            "worklist_share_pct": 100.0 * sum(
+                sum(r["wl"].values()) for r in rows) / max(1, sum(
+                    sum(r["cand"].values()) for r in rows))}
 
 
 def main() -> int:

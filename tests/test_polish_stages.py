@@ -128,10 +128,10 @@ def test_exit_adjacency_is_the_returned_meshes_own(case):
 
 @pytest.mark.parametrize("case", list(CASES))
 def test_counts_say_which_stage_ran(case):
-    rows = [tuple(int(v) for v in c[7:]) for (_, c), _ in two_waves(case)]
+    rows = [tuple(int(v) for v in c[7:9]) for (_, c), _ in two_waves(case)]
     assert rows == CASES[case][2]
     for (_, c), _ in two_waves(case):
-        assert len(c) == 9
+        assert len(c) == 11 and c[9:].tolist() == [0, 0]  # no list: not counted
         assert int(c[7]) == int(c[6] > 0)   # collapse runs iff a tet is bad
         if not c[7]:
             assert c[0] == 0 and c[4] == 0
@@ -145,7 +145,7 @@ def test_without_swaps_the_exit_adjacency_is_still_built():
                                  do_swap=False, hausd=HAUSD)
     counts = np.asarray(counts).tolist()
     assert counts[0] > 0 and counts[1] == 0
-    assert counts[6] > 0 and counts[7:] == [1, 1]
+    assert counts[6] > 0 and counts[7:] == [1, 1, 0, 0]
     built = build_adjacency(mesh)
     assert np.array_equal(np.asarray(mesh.adja), np.asarray(built.adja))
     # and it is not the adjacency the wave was handed
@@ -160,7 +160,7 @@ def test_a_stage_switched_off_leaves_no_cond_behind():
     mesh, met = fixture("slivers")
     shapes = jax.eval_shape(lambda m, k: sliver_polish_impl(
         m, k, jnp.asarray(0, jnp.int32), do_collapse=False), mesh, met)
-    assert shapes[1].shape == (9,)
+    assert shapes[1].shape == (11,)
     jaxpr = jax.make_jaxpr(lambda m, k: sliver_polish_impl(
         m, k, jnp.asarray(0, jnp.int32), do_collapse=False,
         do_swap=False, do_smooth=False)[1])(mesh, met)
@@ -169,9 +169,9 @@ def test_a_stage_switched_off_leaves_no_cond_behind():
 
 def test_an_inactive_slot_hands_back_a_row_of_the_same_width():
     """The grouped polish's quiet mask: both branches of the ``active``
-    cond give nine columns (a mismatch would not trace)."""
+    cond give eleven columns (a mismatch would not trace)."""
     mesh, met = fixture("clean")
     shapes = jax.eval_shape(lambda m, k, act: sliver_polish_impl(
         m, k, jnp.asarray(0, jnp.int32), hausd=HAUSD, active=act),
         mesh, met, jnp.asarray(False))
-    assert shapes[1].shape == (9,) and shapes[1].dtype == jnp.int32
+    assert shapes[1].shape == (11,) and shapes[1].dtype == jnp.int32
