@@ -46,6 +46,7 @@ class IParam(enum.IntEnum):
     nomoveMode = 26
     fem = 27
     opnbdy = 28
+    contiguousMode = 29     # force / don't force the groups' contiguity
 
 
 class DParam(enum.IntEnum):
@@ -75,6 +76,12 @@ class Info:
     repartitioning: int = C.REPART_IFC_DISPLACEMENT
     loadbalancing: int = C.LB_METIS
     ifc_layers: int = C.MVIFCS_NLAYERS
+    # upstream partitions "with METIS_OPTION_CONTIG if requested"
+    # (PMMG_part_meshElts2metis, metis_pmmg.c:1271); this is the
+    # request: True forces every group of a fresh cut into one piece,
+    # False keeps the cut even where that would tip it
+    # (parallel/groups.fresh_cut)
+    contiguous_mode: bool = False
     grps_ratio: float = C.GRPS_RATIO
     target_mesh_size: int = C.TARGET_MESH_SIZE_SENTINEL
     metis_ratio: int = C.RATIO_MMG_METIS_SENTINEL
@@ -152,6 +159,7 @@ class Info:
             IParam.meshSize: ("target_mesh_size", int),
             IParam.metisRatio: ("metis_ratio", int),
             IParam.ifcLayers: ("ifc_layers", int),
+            IParam.contiguousMode: ("contiguous_mode", bool),
             IParam.APImode: ("api_mode", int),
             IParam.globalNum: ("compute_glonum", bool),
             IParam.niter: ("niter", int),

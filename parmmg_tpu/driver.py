@@ -347,7 +347,8 @@ def _run_phases(pm) -> tuple[Mesh, object, AdaptStats]:
                         noinsert=info.noinsert, noswap=info.noswap,
                         nomove=info.nomove, hausd=hausd,
                         ifc_layers=info.ifc_layers, timers=tim,
-                        resume=getattr(info, "resume", False))
+                        resume=getattr(info, "resume", False),
+                        contiguous=info.contiguous_mode)
             except MemoryError:
                 mesh, met = backup
                 stats.status = C.PMMG_LOWFAILURE

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from ..core.mesh import Mesh
 from ..core.constants import (
     IDIR, LSHRT, LLONG, EPSD, MG_BDY, MG_CRN, MG_GEO, MG_NOM, MG_REF,
-    MG_REQ, MG_PARBDY, QUAL_FLOOR)
+    MG_REQ, MG_PARBDY, MG_PARBDYBDY, QUAL_FLOOR)
 from .edges import (unique_edges, edge_lengths, claim_channels,
                     scatter_argmax2, NEG_INF, PRI_MIN)
 
@@ -63,8 +63,16 @@ def _removable(vtag, other_vtag, edge_tag):
     """May vertex v (tags vtag) be deleted by collapsing along this edge?"""
     free = (vtag & (MG_REQ | MG_CRN | MG_PARBDY | MG_NOM)) == 0
     on_bdy = (vtag & MG_BDY) != 0
-    bdy_ok = ~on_bdy | (((edge_tag & MG_BDY) != 0) &
-                        ((other_vtag & MG_BDY) != 0))
+    # the target is a TRUE boundary vertex: a seam vertex carries MG_BDY
+    # with its freeze (PARBDY_TAGS) whether or not it lies on the
+    # surface, and is true boundary only with MG_PARBDYBDY.  A bare
+    # MG_BDY that a pass left on one slot of an interior edge otherwise
+    # takes a surface vertex onto an interior seam vertex (PERF.md
+    # section 6, PR 37: a cube's face pulled into the volume)
+    other_bdy = ((other_vtag & MG_BDY) != 0) & (
+        ((other_vtag & MG_PARBDY) == 0) |
+        ((other_vtag & MG_PARBDYBDY) != 0))
+    bdy_ok = ~on_bdy | (((edge_tag & MG_BDY) != 0) & other_bdy)
     on_geo = (vtag & MG_GEO) != 0
     # a ridge point may slide along its ridge onto another ridge point or
     # onto the corner terminating the ridge (Mmg chkcol_bdy semantics)

@@ -52,6 +52,30 @@ def how_many_groups(ne: int, target: int) -> int:
     return max(1, min((ne + target - 1) // target, C.REMESHER_NGRPS_MAX))
 
 
+def fresh_cut(vert_h: np.ndarray, tet_h: np.ndarray, ngroups: int,
+              contiguous: bool = False) -> np.ndarray:
+    """The cut of a pass that was handed none: ``ngroups`` even parts
+    along the Morton curve of the centroids, each part's stray blobs
+    handed to a neighbour (``fix_contiguity``) -- unless that leaves the
+    cut uneven and the caller did not ask for groups in one piece
+    (``contiguous``, ``IParam.contiguousMode``).  The curve jumps
+    between octants that share no face, so a part astride a jump (the
+    middle one of three, two of six) is two blobs joined by a neck at
+    best, and ``fix_contiguity`` then moves half a group into a
+    neighbour.  Capacity follows the LARGEST group
+    (``distribute.shard_capacity``), and a group half as large again is
+    the next rung of the block program: 11 minutes of compile against 3
+    at the benchmark's size (PERF.md section 6, PR 37).  A group in two
+    blobs costs nothing: its seams are frozen faces either way."""
+    from .partition import fix_contiguity, morton_partition
+    even = morton_partition(vert_h[tet_h].mean(axis=1), ngroups)
+    part = fix_contiguity(tet_h, even)
+    if not contiguous and \
+            np.bincount(part).max() > 1.04 * len(part) / ngroups:
+        return even
+    return part
+
+
 def group_chunk(ngroups: int) -> int:
     """Groups per dispatch (0 = all in one ``lax.map``, the default on
     every backend: the stacked state stays on the device and one
@@ -402,9 +426,13 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                        nomove: bool = False, hausd: float | None = None,
                        polish: bool = False, cap_mult: float = 3.0,
                        timers=None, ckpt_tag: str | None = None,
-                       ckpt_it: int = 0, cap_state: list | None = None):
+                       ckpt_it: int = 0, cap_state: list | None = None,
+                       contiguous: bool = False):
     """One outer pass: split into groups, run adapt cycles with lax.map
     over the group axis, merge.  Returns (mesh, met, part_of_merged).
+
+    ``contiguous``: what a pass that is handed no ``part`` asks of its
+    own cut (``fresh_cut``).
 
     ``cap_state``: a 1-element mutable list carried across the passes
     of one run (the ``regrow_state`` idiom of dist.run_adapt_cycles):
@@ -439,7 +467,6 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     """
     from ..ops.adapt import DIRTY_COL, SURF_COLS
     from ..utils.timers import Timers
-    from .partition import morton_partition, fix_contiguity
     from .distribute import split_to_shards, merge_shards, grow_shards
     from .sched import QuietGroupScheduler
     from ..core.mesh import mesh_to_host
@@ -460,8 +487,7 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     with otrace.span("grp split", groups=ngroups) as sp:
         vert_h, tet_h, _, _, _ = mesh_to_host(mesh)
         if part is None:
-            cent = vert_h[tet_h].mean(axis=1)
-            part = fix_contiguity(tet_h, morton_partition(cent, ngroups))
+            part = fresh_cut(vert_h, tet_h, ngroups, contiguous)
         with host_staging():
             stacked, met_s = split_to_shards(
                 mesh, met, part, ngroups, cap_mult=cap_mult,
@@ -539,6 +565,7 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
         # one span a dispatched block, dispatch to counter pull, with
         # the operations it applied: the ratio of useful outcomes to
         # attempts is recorded where the work happens
+        skipped0 = sched.cond_skipped
         with otrace.context(block=c, chunk=chunk or 0), \
                 otrace.span("grp block", block=c,
                             active=len(act)) as sp:
@@ -571,8 +598,11 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
             # is already host numpy — the drain pulled it)
             tot = cs.tolist()                           # python ints
             surf = {k: tot[col] for k, col in SURF_COLS.items()}
+            # quiet: the row executions the device mask skipped in this
+            # dispatch (a job's sum of them is groups.cond_skipped)
             sp.set(split=tot[0], collapse=tot[1], swap=tot[2],
-                   moved=tot[3], **surf)
+                   moved=tot[3], quiet=sched.cond_skipped - skipped0,
+                   **surf)
         if not chunk:
             # "compute" as the chunk pipeline records it: the seconds
             # from dispatch to counter pull
@@ -841,7 +871,8 @@ def grouped_adapt(mesh: Mesh, met, target_size: int, niter: int = 3,
                   noinsert: bool = False, noswap: bool = False,
                   nomove: bool = False, hausd: float | None = None,
                   ifc_layers: int = 2, timers=None,
-                  resume: bool = False, ckpt_tag: str = "grouped"):
+                  resume: bool = False, ckpt_tag: str = "grouped",
+                  contiguous: bool = False):
     """The two-level outer loop on one device: grouped passes with
     interface displacement between them (the rank-level loop of
     libparmmg1.c:636-948 collapsed onto one device, groups as the only
@@ -868,7 +899,7 @@ def grouped_adapt(mesh: Mesh, met, target_size: int, niter: int = 3,
     if resume or ckpt.ckpt_config()[0]:
         fp = ckpt.run_fingerprint(mesh, met, target_size, niter, cycles,
                                   noinsert, noswap, nomove, hausd,
-                                  ifc_layers)
+                                  ifc_layers, contiguous)
     if resume:
         found = ckpt.latest_pass_checkpoint(ckpt_tag, fingerprint=fp)
         if found is not None:
@@ -921,7 +952,7 @@ def grouped_adapt(mesh: Mesh, met, target_size: int, niter: int = 3,
                 verbose=verbose, stats=stats, noinsert=noinsert,
                 noswap=noswap, nomove=nomove, hausd=hausd,
                 timers=timers, ckpt_tag=ckpt_tag, ckpt_it=it,
-                cap_state=cap_state)
+                cap_state=cap_state, contiguous=contiguous)
             if it + 1 < max(1, niter):
                 with otrace.span("grp displace", layers=ifc_layers):
                     _, tet_h, _, _, _ = mesh_to_host(mesh)
