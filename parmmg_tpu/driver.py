@@ -196,15 +196,20 @@ def _merged_polish(mesh, met, info, hausd, stats, tim):
     each ended by the pull of its counts, until one applies no collapse
     and no swap.  The waves hand each other the swap kernels' worklist
     (``ops/worklist``): from the second on, a kernel judges only the
-    candidates whose shell changed since it last looked.  Returns (mesh,
+    candidates whose shell changed since it last looked; and the edge
+    and face sorts behind their tables (``ops/topo_incr``): from the
+    second on, a wave merges the rows the last stage changed into the
+    sort it was handed where it sorted the whole mesh.  Returns (mesh,
     collapses + swaps applied)."""
     import jax.numpy as jnp
     from .obs import trace as otrace
     from .obs.metrics import REGISTRY
     from .ops.adapt import sliver_polish
+    from .ops.topo_incr import topo_init
     from .ops.worklist import all_dirty
     from .utils.placement import host_staging
     ops = col_skipped = adj_skipped = cand_rows = wl_rows = 0
+    tables = tables_merged = 0
     # every program of the tail costs what its capacity is, not what its
     # content is: the two counters say how much of it is padding
     n_live = int(np.asarray(mesh.tmask).sum())
@@ -213,30 +218,37 @@ def _merged_polish(mesh, met, info, hausd, stats, tim):
     budget = polish_budget(n_live)
     with tim("bad-element polish"), host_staging():
         worklist = all_dirty(mesh)
+        topo = topo_init(mesh.capT)     # nothing retained: wave 0 sorts
         for w in range(8):
             with otrace.span("polish wave", wave=w) as sp:
-                mesh, counts, worklist = sliver_polish(
+                mesh, counts, worklist, topo = sliver_polish(
                     mesh, met, jnp.asarray(1000 + w, jnp.int32),
                     do_collapse=not info.noinsert,
                     do_swap=not info.noswap,
                     do_smooth=not info.nomove, hausd=hausd, budget=budget,
-                    worklist=worklist)
+                    worklist=worklist, topo=topo)
                 (ncol, nswap, nmoved, _, nhveto, nbmoved, nbad, col, adj,
-                 cand, wl) = np.asarray(counts).tolist()
+                 cand, wl, tab, inc) = np.asarray(counts).tolist()
                 # a polish wave splits nothing: bsplit is 0 by what it is;
                 # bad: tets under the sliver threshold at the wave's
                 # entry; col, adj: did the collapse stage and the exit
                 # adjacency run (a stage without an input does not);
                 # cand: candidate rows the ring and edge swaps' top-K
                 # selected, wl: those of them whose shell changed since
-                # the kernel last looked, which is what it judged
+                # the kernel last looked, which is what it judged;
+                # tab: edge tables and adjacencies the wave derived,
+                # inc: those of them merged into (or taken as) the sort
+                # the last derivation left, not sorted in full
                 sp.set(collapse=ncol, swap=nswap, moved=nmoved,
                        bsplit=0, hveto=nhveto, bmoved=nbmoved,
-                       bad=nbad, col=col, adj=adj, wl=wl, cand=cand)
+                       bad=nbad, col=col, adj=adj, wl=wl, cand=cand,
+                       tab=tab, inc=inc)
             col_skipped += int(not info.noinsert and not col)
             adj_skipped += int(not adj)
             cand_rows += cand
             wl_rows += wl
+            tables += tab
+            tables_merged += inc
             stats.add_surface(hveto=nhveto, bmoved=nbmoved)
             stats.ncollapse += ncol
             stats.nswap += nswap
@@ -251,6 +263,8 @@ def _merged_polish(mesh, met, info, hausd, stats, tim):
     REGISTRY.counter("tail.exit_adj_skipped").inc(adj_skipped)
     REGISTRY.counter("tail.candidate_rows").inc(cand_rows)
     REGISTRY.counter("tail.worklist_rows").inc(wl_rows)
+    REGISTRY.counter("tail.tables").inc(tables)
+    REGISTRY.counter("tail.tables_merged").inc(tables_merged)
     return mesh, ops
 
 

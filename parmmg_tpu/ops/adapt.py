@@ -385,7 +385,8 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
                        sliver_q: float = 0.2, do_collapse: bool = True,
                        do_swap: bool = True, do_smooth: bool = True,
                        hausd: float | None = None, active=None,
-                       budget: int | None = None, worklist=None):
+                       budget: int | None = None, worklist=None,
+                       topo=None):
     """Bad-element optimization pass (MMG3D_opttyp analogue): quality-
     targeted collapses on tets below ``sliver_q``, then swaps and a
     smoothing wave.  Run after the sizing loop converges — length-driven
@@ -423,6 +424,17 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     wave hands the list back as a third result.  Exact: mesh and counts
     are what the wave gives without one.
 
+    ``topo``: an ``ops/topo_incr.TopoState`` (``topo_init`` before the
+    first wave), carried like the worklist: every table the wave derives
+    (the collapse stage's edge table, the edge swaps' and the ring swaps',
+    ``swap23``'s adjacency and the exit adjacency) then comes off the
+    edge and face sorts the state retains, by a merge of the rows the
+    stages dirtied since the table's last derivation, or by the full sort
+    where there is no retained sort yet or the dirty rows outnumber the
+    widest band (``topo_incr.polish_bands``).  Bit-identical either way
+    (that module's docstring); the counts row gains two columns and the
+    state is handed back as the last result.
+
     Returns (mesh, counts[11] = [ncollapse, nswap, nmoved, live_tets,
     hveto, bmoved, bad, col, adj, cand, wl]): ``hveto``, ``bmoved`` as
     in a cycle's ``SURF_COLS``; ``bad`` the live tets under ``sliver_q``
@@ -430,14 +442,16 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     the collapse stage ran, ``adj`` 1 when the exit adjacency was
     rebuilt; ``cand`` the candidate rows the two kernels' top-K selected
     and ``wl`` those of them on the list (both 0 without a worklist: not
-    counted).
+    counted).  With ``topo``, counts[13]: then ``tab``, the tables the
+    wave derived, and ``inc``, those of them taken off the retained sort
+    (merge or reuse) and not by a full sort.
     """
     from .adjacency import boundary_edge_tags
     from . import worklist as wlist
     if active is not None:
-        if worklist is not None:
-            raise ValueError("a worklist rides one mesh from wave to wave:"
-                             " not under the quiet mask")
+        if worklist is not None or topo is not None:
+            raise ValueError("a worklist and a retained sort ride one mesh"
+                             " from wave to wave: not under the quiet mask")
 
         def _run(m):
             return sliver_polish_impl(
@@ -454,9 +468,42 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     ncol = nhveto = nbad = nswap = nmoved = nbmoved = ncand = nlist = zero
     rebuild = None      # traced bool once the wave holds an adjacency
     wl = worklist
+    # [tab, inc], counted with ``topo`` only
+    tables = None if topo is None else jnp.zeros(2, jnp.int32)
 
     def note(wl, before, after):
         return None if wl is None else wlist.noted(wl, before, after)
+
+    if topo is None:
+        def edge_table(m, tp, tables, slots):
+            return None, tp, tables     # the kernel builds its own
+
+        def adjacency(m, tp, tables):
+            return build_adjacency(m), tp, tables
+
+        def dirtied(tp, before, after):
+            return tp
+    else:
+        from .topo_incr import (mark_dirty, polish_bands,
+                                polish_build_adjacency, polish_unique_edges)
+        band = polish_bands(mesh.capT)
+
+        def derived(tables, merged):
+            return tables + jnp.stack([1, merged.astype(jnp.int32)])
+
+        def edge_table(m, tp, tables, slots):
+            et, tp, merged = polish_unique_edges(m, tp, shell_slots=slots,
+                                                 band=band)
+            return et, tp, derived(tables, merged)
+
+        def adjacency(m, tp, tables):
+            m, tp, merged = polish_build_adjacency(m, tp, band=band)
+            return m, tp, derived(tables, merged)
+
+        def dirtied(tp, before, after):
+            # the sorts carry keys of (tet, tmask) alone: a stage that
+            # moves vertices or sets tags dirties nothing
+            return mark_dirty(tp, before.tet, before.tmask, after)
 
     if do_collapse:
         from .quality import quality_from_points
@@ -464,49 +511,63 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
             mesh.vert[mesh.tet], None if met.ndim == 1 else met[mesh.tet])
         nbad = jnp.sum(mesh.tmask & (q_tet < sliver_q), dtype=jnp.int32)
 
-        def _collapse(m):
+        def _collapse(ops):
             # the polish widens the compaction budget (budget_div=2, or
             # the caller's ``budget`` in rows) so the quality pass covers
             # the full sliver population instead of the worst K only.
             # The budget is meant in rows of CONTENT: 1.5x the live tets
             # on a mesh at 3x; dead rows are never candidates
+            m, tp, tables = ops
+            et, tp, tables = edge_table(m, tp, tables, 3)
             col = collapse_wave(m, met, sliver_q=sliver_q, hausd=hausd,
-                                budget_div=2, budget=budget, q_tet=q_tet)
+                                budget_div=2, budget=budget, q_tet=q_tet,
+                                et=et)
             m = jax.lax.cond(col.surface_changed, boundary_edge_tags,
                              lambda m: m, col.mesh)
-            return m, col.ncollapse, col.nhveto
+            return (m, tp, tables), col.ncollapse, col.nhveto
 
         before = mesh
-        mesh, ncol, nhveto = jax.lax.cond(
-            nbad > 0, _collapse, lambda m: (m, zero, zero), mesh)
+        (mesh, topo, tables), ncol, nhveto = jax.lax.cond(
+            nbad > 0, _collapse, lambda ops: (ops, zero, zero),
+            (mesh, topo, tables))
         wl = note(wl, before, mesh)
+        topo = dirtied(topo, before, mesh)
     if do_swap:
-        from .swapgen import swapgen_wave
+        from .swapgen import swapgen_wave, RING_MAX
         from .swap import swap_facesort_enabled
+        et, topo, tables = edge_table(mesh, topo, tables, 3)
         sew = swap_edges_wave(mesh, met, hausd=hausd, budget_div=2,
                               budget=budget,      # 3-2 + 2-2
-                              worklist=None if wl is None else wl.edges)
+                              worklist=None if wl is None else wl.edges,
+                              et=et)
         if wl is not None:
             wl = note(wl._replace(edges=wlist.looked(wl.edges, sew.keep)),
                       mesh, sew.mesh)
+        topo = dirtied(topo, mesh, sew.mesh)
         # generalized degree 4-6 ring swaps: the worst surviving tets
         # are typically gate-limited for every lower-degree op — this
         # is the class that lifts the min past the 3-2/2-3 plateau
+        et, topo, tables = edge_table(sew.mesh, topo, tables, RING_MAX)
         sgn = swapgen_wave(sew.mesh, met, budget_div=2, budget=budget,
-                           worklist=None if wl is None else wl.rings)
+                           worklist=None if wl is None else wl.rings,
+                           et=et)
         if wl is not None:
             wl = note(wl._replace(rings=wlist.looked(wl.rings, sgn.keep)),
                       sew.mesh, sgn.mesh)
             ncand, nlist = sew.ncand + sgn.ncand, sew.nlist + sgn.nlist
+        topo = dirtied(topo, sew.mesh, sgn.mesh)
         if swap_facesort_enabled():
-            s23 = swap23_wave(sgn.mesh, met, budget_div=2, budget=budget,
+            mesh = sgn.mesh
+            s23 = swap23_wave(mesh, met, budget_div=2, budget=budget,
                               facesort=True)
         else:
-            mesh = build_adjacency(sgn.mesh)    # consumed by swap23
+            # consumed by swap23
+            mesh, topo, tables = adjacency(sgn.mesh, topo, tables)
             s23 = swap23_wave(mesh, met, budget_div=2, budget=budget)
             # without a winner swap23 hands back the mesh it was given,
             # adjacency and all
             rebuild = s23.nswap > 0
+        topo = dirtied(topo, mesh, s23.mesh)
         mesh = s23.mesh
         nswap = sew.nswap + sgn.nswap + s23.nswap
     after_rings = sgn.mesh if do_swap else mesh
@@ -518,18 +579,22 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
         mesh = sm.mesh
         nmoved, nbmoved = sm.nmoved, sm.nbdy
     if rebuild is None:                         # exit contract
-        mesh, rebuild = build_adjacency(mesh), jnp.ones((), bool)
+        mesh, topo, tables = adjacency(mesh, topo, tables)
+        rebuild = jnp.ones((), bool)
     else:
-        mesh = jax.lax.cond(rebuild, build_adjacency, lambda m: m, mesh)
-    counts = jnp.stack([ncol, nswap, nmoved,
-                        jnp.sum(mesh.tmask, dtype=jnp.int32),
-                        nhveto, nbmoved, nbad,
-                        (nbad > 0).astype(jnp.int32),
-                        rebuild.astype(jnp.int32), ncand, nlist])
-    if worklist is None:
-        return mesh, counts
-    # swap23, the smoothing and the adjacencies' boundary tags, in one
-    return mesh, counts, note(wl, after_rings, mesh)
+        mesh, topo, tables = jax.lax.cond(
+            rebuild, lambda ops: adjacency(*ops), lambda ops: ops,
+            (mesh, topo, tables))
+    row = [ncol, nswap, nmoved, jnp.sum(mesh.tmask, dtype=jnp.int32),
+           nhveto, nbmoved, nbad, (nbad > 0).astype(jnp.int32),
+           rebuild.astype(jnp.int32), ncand, nlist]
+    if topo is not None:
+        row += [tables[0], tables[1]]
+    out = (mesh, jnp.stack(row))
+    if worklist is not None:
+        # swap23, the smoothing and the adjacencies' boundary tags, in one
+        out += (note(wl, after_rings, mesh),)
+    return out if topo is None else out + (topo,)
 
 
 sliver_polish = _governed("adapt.sliver_polish")(

@@ -107,7 +107,7 @@ def swap_edges_wave(mesh: Mesh, met: jax.Array, enable32: bool = True,
                     hausd: float | None = None,
                     budget_div: int = 8,
                     budget: int | None = None,
-                    worklist=None) -> SwapResult:
+                    worklist=None, et=None) -> SwapResult:
     """Combined edge-swap wave: 3-2 interior + 2-2 boundary, ONE pass.
 
     Both swaps share the same cavity shape — edge (a,b) is replaced by two
@@ -146,9 +146,15 @@ def swap_edges_wave(mesh: Mesh, met: jax.Array, enable32: bool = True,
     result is the full evaluation's to the bit while the caller keeps
     the list by that module's rules.  None (the cycle block) traces the
     stage once over all K rows, the program it always was.
+
+    ``et``: the mesh's edge table (``unique_edges(mesh)``: three shell
+    slots) where the caller has it, as ``collapse_wave`` takes one: the
+    merged polish derives it from the sort it carries (ops/topo_incr).
+    None builds it here.
     """
     capT, capP = mesh.capT, mesh.capP
-    et = unique_edges(mesh)
+    if et is None:
+        et = unique_edges(mesh)
     m6 = _met6(met)
     Efull = et.ev.shape[0]
     eof = jnp.asarray(_EDGE_OF)

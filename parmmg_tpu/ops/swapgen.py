@@ -52,18 +52,23 @@ class SwapGenResult(NamedTuple):
 def swapgen_wave(mesh: Mesh, met: jax.Array,
                  budget_div: int = 8, budget: int | None = None,
                  lmax: float | None = None,
-                 worklist=None) -> SwapGenResult:
+                 worklist=None, et=None) -> SwapGenResult:
     """``worklist``: an ``ops/worklist.Dirty``, what changed since this
     kernel last judged the mesh.  Only the candidates it lists are
     evaluated, in chunks as wide as the list; the result is the full
     evaluation's to the bit while the caller keeps the list by that
-    module's rules.  None evaluates every candidate."""
+    module's rules.  None evaluates every candidate.
+
+    ``et``: the mesh's edge table with ``RING_MAX`` shell slots where
+    the caller has it (the merged polish, off the sort it carries:
+    ops/topo_incr); None builds it here."""
     from ..core.constants import LLONG
     from . import worklist as wl
     if lmax is None:
         lmax = LLONG
     capT, capP = mesh.capT, mesh.capP
-    et = unique_edges(mesh, shell_slots=RING_MAX)
+    if et is None:
+        et = unique_edges(mesh, shell_slots=RING_MAX)
     m6 = None if met.ndim == 1 else met
     Efull = et.ev.shape[0]
 

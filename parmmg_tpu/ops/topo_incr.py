@@ -43,6 +43,12 @@ families (the hotloop_knob_gate contract).  The prefix-sum backbone of
 the merge lowers to a Pallas kernel on TPU
 (ops/pallas_kernels.merge_prefix_pallas, 8x128-tiled, SMEM carry); the
 CPU reference is ``jnp.cumsum`` — integer adds, bit-identical.
+
+The merged polish (driver._merged_polish -> ops/adapt.sliver_polish_impl,
+on the host) carries one ``TopoState`` from wave to wave with no knob:
+there ``incr`` is a constant true, the band has two rungs
+(``polish_bands``: a derivation merges at the narrowest that holds its
+dirty set) and ``told`` says which tables came off the retained sort.
 """
 from __future__ import annotations
 
@@ -78,6 +84,18 @@ def incr_band_width(capT: int) -> int:
         return max(1, min(int(v), capT))
     from ..utils.compilecache import bucket
     return bucket(max(1, capT // 16), floor=1024, scheme="geo", cap=capT)
+
+
+def polish_bands(capT: int) -> tuple[int, ...]:
+    """The bands the merged polish asks for (ops/adapt.sliver_polish_impl
+    with a state), a function of the capacity alone: no setting.  Two
+    rungs, because a merge costs what its band is wide, not what it
+    holds: a sixteenth of the capacity for the tens to hundreds of rows
+    a late wave dirties, a quarter for the thousands the first waves
+    leave (iso-growth's second wave meets 5,563 of 47,895; a quarter's
+    merge is still under half a full sort: PERF.md, PR 38)."""
+    return tuple(sorted({min(capT, max(1024, capT // d))
+                         for d in (16, 4)}))
 
 
 class TopoState(NamedTuple):
@@ -171,15 +189,17 @@ def _prefix_i32(x: jax.Array) -> jax.Array:
     return ref(x)
 
 
-def _lower_bound(qkeys, qslot, keys, slot):
+def _lower_bound(qkeys, qslot, keys, slot, rolled: bool = False):
     """Lexicographic lower bound of each (qkeys..., qslot) query in the
     dense ascending (keys..., slot) table: the first index whose entry
     compares >= the query.  Static ``bit_length`` iteration count —
-    O(log n) gathers per query, no data-dependent control flow."""
+    O(log n) gathers per query, no data-dependent control flow.
+    ``rolled`` runs the iterations as a ``fori_loop`` where the blocks
+    unroll them: the same steps, a program a step long."""
     n = slot.shape[0]
-    lo = jnp.zeros(qslot.shape, jnp.int32)
-    hi = jnp.full(qslot.shape, n, jnp.int32)
-    for _ in range(max(1, int(n).bit_length())):
+
+    def step(_, lohi):
+        lo, hi = lohi
         mid = (lo + hi) >> 1
         mc = jnp.clip(mid, 0, n - 1)
         less = jnp.zeros(qslot.shape, bool)
@@ -190,9 +210,16 @@ def _lower_bound(qkeys, qslot, keys, slot):
             eq = eq & (kv == qk)
         kv = slot[mc]
         less = less | (eq & (kv < qslot))
-        lo = jnp.where(less, mid + 1, lo)
-        hi = jnp.where(less, hi, mid)
-    return lo
+        return jnp.where(less, mid + 1, lo), jnp.where(less, hi, mid)
+
+    lohi = (jnp.zeros(qslot.shape, jnp.int32),
+            jnp.full(qslot.shape, n, jnp.int32))
+    steps = max(1, int(n).bit_length())
+    if rolled:
+        return jax.lax.fori_loop(0, steps, step, lohi)[0]
+    for i in range(steps):
+        lohi = step(i, lohi)
+    return lohi[0]
 
 
 def band_order(bkeys, bslot):
@@ -202,8 +229,9 @@ def band_order(bkeys, bslot):
     return jnp.lexsort((bslot,) + tuple(bkeys)[::-1])
 
 
-def merge_sorted_band(keys, slot, sd, bkeys, bslot):
-    """Merge a re-keyed dirty band into a retained stable sort.
+def merge_sorted_band(keys, slot, sd, bkeys, bslot, rolled: bool = False):
+    """Merge a re-keyed dirty band into a retained stable sort
+    (``rolled``: :func:`_lower_bound`'s).
 
     ``keys`` (tuple of [n] int32 columns) + ``slot`` [n] are the
     retained sorted table (ascending by (keys..., slot) — what a stable
@@ -238,7 +266,7 @@ def merge_sorted_band(keys, slot, sd, bkeys, bslot):
     border = band_order(bkeys, bslot)
     bks = [bk[border] for bk in bkeys]
     bs = bslot[border]
-    pos = _lower_bound(bks, bs, skeys, sslot)                 # [m]
+    pos = _lower_bound(bks, bs, skeys, sslot, rolled)         # [m]
     # survivor shift = inclusive prefix of the insertion histogram
     # (pad entries are parked at bin n and excluded from the prefix)
     real = bs != _INT32_MAX
@@ -299,13 +327,42 @@ def face_band_records(mesh: Mesh, dt: jax.Array):
 # table derivations (band-merged or full, one lax.cond each)
 # ---------------------------------------------------------------------------
 
+def _rungs(band, capT: int) -> tuple[int, ...]:
+    """The band widths a derivation may merge at, ascending: the
+    caller's (one width or several), else ``incr_band_width``."""
+    if band is None:
+        return (incr_band_width(capT),)
+    return (band,) if isinstance(band, int) else tuple(band)
+
+
+def _narrowest(nd, rungs, merge_at):
+    """The merge at the narrowest rung that holds ``nd`` dirty tets
+    (``nd`` <= the widest: the caller's gate).  One rung is that merge
+    itself, the program a single band always traced."""
+    if len(rungs) == 1:
+        return merge_at(rungs[0])
+    which = sum((nd > b).astype(jnp.int32) for b in rungs[:-1])
+    return lambda _: jax.lax.switch(
+        which, [merge_at(b) for b in rungs], None)
+
+
 def incr_unique_edges(mesh: Mesh, topo: TopoState, incr,
-                      shell_slots: int = 0):
+                      shell_slots: int = 0,
+                      band: int | tuple[int, ...] | None = None,
+                      told: bool = False, rolled: bool = False):
     """EdgeTable via the retained sort: band-merge when the knob is on,
     the state is valid and the dirty set fits the band; otherwise the
     full packed sort (bit-identical to ops/edges.unique_edges either
     way — both feed the SAME shared epilogue).  Consumes ``edirty``.
-    Returns (EdgeTable, new TopoState)."""
+    ``band``: the band's width in tets where the caller sizes it, or
+    several ascending widths, of which a derivation merges at the
+    narrowest that holds its dirty set (the merged polish:
+    ``polish_bands``); None is ``incr_band_width``.  ``rolled``: the
+    merge's binary search as a loop, not unrolled (a host program that
+    every process compiles in its set-up holds ten merges: rolled it
+    is a fifth smaller and runs the same steps).
+    Returns (EdgeTable, new TopoState), and with ``told`` a third
+    result: did the table come off the retained sort (merge or reuse)."""
     from .edges import PACK_LIMIT, unique_edges, unique_edges_from_sorted
     capT = mesh.capT
     n6 = capT * 6
@@ -313,11 +370,12 @@ def incr_unique_edges(mesh: Mesh, topo: TopoState, incr,
         # the merge needs single-int32 packed keys; oversized id spaces
         # keep the exact legacy path (never reached at group shapes)
         et = unique_edges(mesh, shell_slots=shell_slots)
-        return et, topo._replace(eok=jnp.zeros((), bool),
-                                 edirty=jnp.zeros(capT, bool))
-    B = incr_band_width(capT)
+        topo = topo._replace(eok=jnp.zeros((), bool),
+                             edirty=jnp.zeros(capT, bool))
+        return (et, topo, jnp.zeros((), bool)) if told else (et, topo)
+    rungs = _rungs(band, capT)
     nd = jnp.sum(topo.edirty, dtype=jnp.int32)
-    use_band = jnp.asarray(incr) & topo.eok & (nd <= B)
+    use_band = jnp.asarray(incr) & topo.eok & (nd <= rungs[-1])
 
     def _full(_):
         ev = tet_edge_vertices(mesh.tet).reshape(n6, 2)
@@ -336,15 +394,18 @@ def incr_unique_edges(mesh: Mesh, topo: TopoState, incr,
             # the old all-or-nothing et-cache to adjacency too
             return topo.ekey, topo.eslot
 
-        def _merge(_):
-            sd = topo.edirty[topo.eslot // 6]
-            dt = jnp.nonzero(topo.edirty, size=B,
-                             fill_value=capT)[0].astype(jnp.int32)
-            bkey, bslot = edge_band_records(mesh, dt)
-            (ks,), order = merge_sorted_band(
-                (topo.ekey,), topo.eslot, sd, (bkey,), bslot)
-            return ks, order
-        return jax.lax.cond(nd == 0, _reuse, _merge, None)
+        def _merge_at(B):
+            def _merge(_):
+                sd = topo.edirty[topo.eslot // 6]
+                dt = jnp.nonzero(topo.edirty, size=B,
+                                 fill_value=capT)[0].astype(jnp.int32)
+                bkey, bslot = edge_band_records(mesh, dt)
+                (ks,), order = merge_sorted_band(
+                    (topo.ekey,), topo.eslot, sd, (bkey,), bslot, rolled)
+                return ks, order
+            return _merge
+        return jax.lax.cond(nd == 0, _reuse,
+                            _narrowest(nd, rungs, _merge_at), None)
 
     ks, order = jax.lax.cond(use_band, _band, _full, None)
     et = unique_edges_from_sorted(mesh, order, ks,
@@ -352,26 +413,30 @@ def incr_unique_edges(mesh: Mesh, topo: TopoState, incr,
     topo = topo._replace(ekey=ks, eslot=order,
                          eok=jnp.ones((), bool),
                          edirty=jnp.zeros(capT, bool))
-    return et, topo
+    return (et, topo, use_band) if told else (et, topo)
 
 
-def incr_build_adjacency(mesh: Mesh, topo: TopoState, incr):
+def incr_build_adjacency(mesh: Mesh, topo: TopoState, incr,
+                         band: int | tuple[int, ...] | None = None,
+                         told: bool = False, rolled: bool = False):
     """Adjacency (and boundary tags) via the retained face sort — the
     incremental form of ops/adjacency.build_adjacency, re-deriving
     twins only where the band touched (merged face records feed the
-    SAME pairing epilogue).  Consumes ``fdirty``.  Returns
-    (mesh with adja/ftag, new TopoState)."""
+    SAME pairing epilogue).  Consumes ``fdirty``.  ``band``, ``told``,
+    ``rolled``: as :func:`incr_unique_edges`.  Returns (mesh with adja/ftag, new
+    TopoState[, off the retained sort?])."""
     from .edges import PACK_LIMIT
     from .adjacency import (_face_keys, adjacency_from_records,
                             build_adjacency, face_records_from_sorted)
     capT = mesh.capT
     if mesh.capP > PACK_LIMIT:
-        return (build_adjacency(mesh),
-                topo._replace(fok=jnp.zeros((), bool),
-                              fdirty=jnp.zeros(capT, bool)))
-    B = incr_band_width(capT)
+        mesh = build_adjacency(mesh)
+        topo = topo._replace(fok=jnp.zeros((), bool),
+                             fdirty=jnp.zeros(capT, bool))
+        return (mesh, topo, jnp.zeros((), bool)) if told else (mesh, topo)
+    rungs = _rungs(band, capT)
     nd = jnp.sum(topo.fdirty, dtype=jnp.int32)
-    use_band = jnp.asarray(incr) & topo.fok & (nd <= B)
+    use_band = jnp.asarray(incr) & topo.fok & (nd <= rungs[-1])
 
     def _full(_):
         cols, _, _ = _face_keys(mesh)
@@ -385,15 +450,19 @@ def incr_build_adjacency(mesh: Mesh, topo: TopoState, incr):
         def _reuse(_):
             return topo.fk0, topo.fkw, topo.fslot
 
-        def _merge(_):
-            sd = topo.fdirty[topo.fslot // 4]
-            dt = jnp.nonzero(topo.fdirty, size=B,
-                             fill_value=capT)[0].astype(jnp.int32)
-            bk0, bkw, bslot = face_band_records(mesh, dt)
-            (k0, kw), order = merge_sorted_band(
-                (topo.fk0, topo.fkw), topo.fslot, sd, (bk0, bkw), bslot)
-            return k0, kw, order
-        return jax.lax.cond(nd == 0, _reuse, _merge, None)
+        def _merge_at(B):
+            def _merge(_):
+                sd = topo.fdirty[topo.fslot // 4]
+                dt = jnp.nonzero(topo.fdirty, size=B,
+                                 fill_value=capT)[0].astype(jnp.int32)
+                bk0, bkw, bslot = face_band_records(mesh, dt)
+                (k0, kw), order = merge_sorted_band(
+                    (topo.fk0, topo.fkw), topo.fslot, sd, (bk0, bkw),
+                    bslot, rolled)
+                return k0, kw, order
+            return _merge
+        return jax.lax.cond(nd == 0, _reuse,
+                            _narrowest(nd, rungs, _merge_at), None)
 
     k0, kw, order = jax.lax.cond(use_band, _band, _full, None)
     t, f, partner, matched, valid_s = face_records_from_sorted(
@@ -402,4 +471,16 @@ def incr_build_adjacency(mesh: Mesh, topo: TopoState, incr):
     topo = topo._replace(fk0=k0, fkw=kw, fslot=order,
                          fok=jnp.ones((), bool),
                          fdirty=jnp.zeros(capT, bool))
-    return mesh, topo
+    return (mesh, topo, use_band) if told else (mesh, topo)
+
+
+# the merged polish's derivations (ops/adapt.sliver_polish_impl with a
+# state): always on, told, the search rolled.  A jitted function a kind
+# of table, because a wave derives an edge table in three places and an
+# adjacency in two, and then traces, lowers and holds each kind once
+polish_unique_edges = jax.jit(
+    partial(incr_unique_edges, incr=True, told=True, rolled=True),
+    static_argnames=("shell_slots", "band"))
+polish_build_adjacency = jax.jit(
+    partial(incr_build_adjacency, incr=True, told=True, rolled=True),
+    static_argnames=("band",))

@@ -2,19 +2,32 @@
 
 Runs one job of a benchmark cell through ``ParMesh.run``, keeps the merged
 mesh as it stands at the entry of ``driver._merged_polish``, and replays
-the polish on it as seven programs a wave (the stages of
-``ops/adapt.sliver_polish_impl``, every one run whatever its input), each
-timed from dispatch to ``block_until_ready``, on the host's CPU backend
-where the driver stages the tail.  The two swap kernels that take the
-polish's worklist (``ops/worklist``) are split at the compaction line:
-``*_head`` is the kernel on an EMPTY list (edge table, qualities, top-K,
-then claims and apply over K rows with no candidate: what no list can
-shorten), ``*_rows`` the rest of its seconds on the list the waves have
-kept (the candidate stage, as wide as the list); ``list`` is the
-bookkeeping between the stages.  Each wave's row says how many candidate
-rows the kernels' top-K selected (``cand``) and how many of them were on
-the list (``wl``).  A diagnostic, not a contract: it mirrors the wave's
-composition as of PR 35.
+the polish on it as the wave now is (``ops/adapt.sliver_polish_impl`` with
+the worklist and the retained sorts), a program a stage, each timed from
+dispatch to ``block_until_ready``, on the host's CPU backend where the
+driver stages the tail.  A stage the wave skips for want of an input (the
+collapse stage with no tet under the threshold, the exit adjacency when
+``swap23`` applied nothing) is skipped here too and reads 0.
+
+The tables are stages of their own (``*_table``, ``adjacency``,
+``exit_adjacency``): each is derived off the sort the polish carries
+(``ops/topo_incr``: a merge of the rows dirtied since its last
+derivation, the retained sort as it is when there are none, the full
+sort where nothing is retained yet or the rows outnumber the band) and
+handed to its kernel (``et=``); ``dirty`` says how many rows it met,
+``merged`` whether it came off the retained sort, and ``full_s`` what
+the same table costs by the full sort on the same mesh (timed beside the
+wave, not part of it).  The two swap kernels that take the polish's
+worklist (``ops/worklist``) are split at the compaction line: ``*_head``
+is the kernel on an EMPTY list with its table given (qualities, top-K,
+then claims and apply over K rows with no candidate: what neither the
+list nor the merge shortens), ``*_rows`` the rest of its seconds on the
+list the waves have kept (the candidate stage, as wide as the list);
+``list`` is the bookkeeping between the stages, the lists' and the dirty
+masks'.  Each wave's row says how many candidate rows the kernels' top-K
+selected (``cand``) and how many of them were on the list (``wl``).  A
+diagnostic, not a contract: it mirrors the wave's composition as of
+PR 38.
 
     python scripts/polish_stages.py --cell iso-growth --seed 21 \
         [--save DIR] [--from DIR] [--out FILE.json]
@@ -39,13 +52,15 @@ sys.path[:0] = [os.path.join(ROOT, "benchmarks"), ROOT]
 
 import numpy as np  # noqa: E402
 
-STAGES = ("collapse", "swap_edges_head", "swap_edges_rows", "swapgen_head",
-          "swapgen_rows", "adjacency", "swap23", "smooth", "exit_adjacency",
-          "list")
+TABLES = ("collapse_table", "swap_edges_table", "swapgen_table",
+          "adjacency", "exit_adjacency")
+STAGES = ("collapse_table", "collapse", "swap_edges_table",
+          "swap_edges_head", "swap_edges_rows", "swapgen_table",
+          "swapgen_head", "swapgen_rows", "adjacency", "swap23", "smooth",
+          "exit_adjacency", "list")
 # what each stage applied is counted under these (the driver's loop ends
-# on a wave whose first five apply nothing)
-APPLIED = ("collapse", "swap_edges", "swapgen", "adjacency", "swap23",
-           "smooth", "exit_adjacency")
+# on a wave whose first four apply nothing)
+APPLIED = ("collapse", "swap_edges", "swapgen", "swap23", "smooth")
 
 
 def say(*a):
@@ -90,7 +105,8 @@ def capture_job(cell: str, seed: int) -> tuple[dict, dict]:
     sha = {k: hashlib.sha256(np.ascontiguousarray(res[k]).tobytes())
            .hexdigest() for k in ("vert", "tet", "met")}
     waves = [{k: r[k] for k in ("collapse", "swap", "moved", "bad", "col",
-                                "adj", "wl", "cand", "dur") if k in r}
+                                "adj", "wl", "cand", "tab", "inc", "dur")
+              if k in r}
              for r in TRACER.ring if r.get("name") == "polish wave"]
     digest = {"rc": res["rc"], "seconds": res["seconds"],
               "ntets": len(res["tet"]), "sha256": sha, "waves": waves,
@@ -123,28 +139,33 @@ def restore(path: str) -> dict:
 def replay(seen: dict, waves: int = 8, sliver_q: float = 0.2) -> list[dict]:
     """The polish on ``seen``, stage by stage; per wave, each stage's
     seconds and what it applied, the tets under ``sliver_q`` at the
-    wave's entry, and the two listed kernels' ``cand`` and ``wl`` rows.
-    Wave 0 pays the compiles."""
+    wave's entry, the two listed kernels' ``cand`` and ``wl`` rows, and
+    per table the dirty rows it met, whether it came off the retained
+    sort, and the full sort's seconds.  Wave 0 pays the compiles."""
     import jax
     import jax.numpy as jnp
     from functools import partial
     from parmmg_tpu.driver import polish_budget
+    from parmmg_tpu.ops import topo_incr as ti
     from parmmg_tpu.ops import worklist as wlist
     from parmmg_tpu.ops.adjacency import (boundary_edge_tags,
                                           build_adjacency)
     from parmmg_tpu.ops.collapse import collapse_wave
+    from parmmg_tpu.ops.edges import unique_edges
     from parmmg_tpu.ops.quality import quality_from_points
     from parmmg_tpu.ops.smooth import smooth_wave
     from parmmg_tpu.ops.swap import swap23_wave, swap_edges_wave
-    from parmmg_tpu.ops.swapgen import swapgen_wave
+    from parmmg_tpu.ops.swapgen import RING_MAX, swapgen_wave
     from parmmg_tpu.utils.placement import host_staging
     hausd = seen["hausd"]
     n_live = int(seen["mesh"].tmask.sum())
     budget = polish_budget(n_live)
+    band = ti.polish_bands(int(seen["mesh"].tmask.shape[0]))
     kw = dict(budget_div=2, budget=budget)
 
-    def collapse(m, k):
-        col = collapse_wave(m, k, sliver_q=sliver_q, hausd=hausd, **kw)
+    def collapse(m, k, et):
+        col = collapse_wave(m, k, sliver_q=sliver_q, hausd=hausd, et=et,
+                            **kw)
         m = jax.lax.cond(col.surface_changed, boundary_edge_tags,
                          lambda m: m, col.mesh)
         return m, col.ncollapse
@@ -161,20 +182,36 @@ def replay(seen: dict, waves: int = 8, sliver_q: float = 0.2) -> list[dict]:
         return jax.jit(run)
 
     def listed(wave_fn, **kws):
-        """A kernel that takes a list: (mesh, applied, keep, cand, wl)."""
-        def run(m, k, dirty):
-            r = wave_fn(m, k, worklist=dirty, **kws)
+        """A kernel that takes a list and its table: (mesh, applied,
+        keep, cand, wl)."""
+        def run(m, k, dirty, et):
+            r = wave_fn(m, k, worklist=dirty, et=et, **kws)
             return r.mesh, r.nswap, r.keep, r.ncand, r.nlist
         return jax.jit(run)
 
-    adjacency = jax.jit(lambda m, k: (build_adjacency(m), jnp.int32(0)))
+    def edges(slots):
+        """(the table off the carried sort, the state, merged?, dirty
+        rows met) and the same table by the full sort."""
+        def merged(m, tp):
+            nd = jnp.sum(tp.edirty, dtype=jnp.int32)
+            return ti.polish_unique_edges(m, tp, shell_slots=slots,
+                                          band=band) + (nd,)
+        return jax.jit(merged), jax.jit(
+            lambda m: unique_edges(m, shell_slots=slots))
+
+    def faces(m, tp):
+        nd = jnp.sum(tp.fdirty, dtype=jnp.int32)
+        return ti.polish_build_adjacency(m, tp, band=band) + (nd,)
+
+    adjacency = (jax.jit(faces), jax.jit(build_adjacency))
+    tables = {"collapse_table": edges(3), "swap_edges_table": edges(3),
+              "swapgen_table": edges(RING_MAX), "adjacency": adjacency,
+              "exit_adjacency": adjacency}
     programs = {
         "collapse": jax.jit(collapse),
-        "adjacency": adjacency,
         "swap23": of(swap23_wave, "nswap", **kw),
         "smooth": of(partial(smooth_wave, opt_q=sliver_q, hausd=hausd),
                      "nmoved"),
-        "exit_adjacency": adjacency,
     }
     kernels = {     # stage -> (its program, its field of the PolishList)
         "swap_edges": (listed(swap_edges_wave, hausd=hausd, **kw), "edges"),
@@ -182,6 +219,8 @@ def replay(seen: dict, waves: int = 8, sliver_q: float = 0.2) -> list[dict]:
     }
     count_bad = jax.jit(count_bad)
     noted = jax.jit(wlist.noted)
+    mark = jax.jit(lambda tp, before, after: ti.mark_dirty(
+        tp, before.tet, before.tmask, after))
     rows = []
 
     def timed(fn, *a):
@@ -193,62 +232,106 @@ def replay(seen: dict, waves: int = 8, sliver_q: float = 0.2) -> list[dict]:
         mesh = jax.tree.map(jnp.asarray, seen["mesh"])
         met = jnp.asarray(seen["met"])
         wl = wlist.all_dirty(mesh)
+        topo = ti.topo_init(mesh.capT)
         nothing = jax.tree.map(jnp.zeros_like, wl.edges)
         say(f"replay: {n_live} live tets at capT {mesh.capT}, budget "
-            f"{budget}, hausd {hausd}")
+            f"{budget}, band {band}, hausd {hausd}")
         for w in range(waves):
             row = {"wave": w, "bad": int(count_bad(mesh, met)),
                    "s": dict.fromkeys(STAGES, 0.0), "n": {},
-                   "cand": {}, "wl": {}}
+                   "cand": {}, "wl": {}, "dirty": {}, "merged": {},
+                   "full_s": {}}
 
-            def note(wl, before, after):
-                wl, s = timed(noted, wl, before, after)
+            def table(name, m, tp):
+                """The table ``name`` of ``m``: (table or mesh with its
+                adjacency, state)."""
+                merged, full = tables[name]
+                _, row["full_s"][name] = timed(full, m)
+                (out, tp, inc, nd), row["s"][name] = timed(merged, m, tp)
+                row["dirty"][name], row["merged"][name] = int(nd), bool(inc)
+                return out, tp
+
+            def note(fn, state, before, after):
+                """``noted`` on the lists, ``mark`` on the sorts."""
+                state, s = timed(fn, state, before, after)
                 row["s"]["list"] += s
-                return wl
+                return state
 
-            since = mesh        # what the lists last took note of
-            for name in APPLIED:
-                if name in kernels:
-                    fn, field = kernels[name]
-                    if since is not mesh:
-                        wl = note(wl, since, mesh)
-                    _, row["s"][name + "_head"] = timed(
-                        fn, mesh, met, nothing)
-                    (after, n, keep, cand, nl), s = timed(
-                        fn, mesh, met, getattr(wl, field))
-                    row["s"][name + "_rows"] = s - row["s"][name + "_head"]
-                    row["cand"][name], row["wl"][name] = int(cand), int(nl)
-                    wl = note(wl._replace(**{field: wlist.looked(
-                        getattr(wl, field), keep)}), mesh, after)
-                    mesh = since = after
-                else:
-                    args = (jnp.asarray(1000 + w, jnp.int32),) \
-                        if name == "smooth" else ()
-                    (mesh, n), row["s"][name] = timed(
-                        programs[name], mesh, met, *args)
+            row["n"] = dict.fromkeys(APPLIED, 0)
+            if row["bad"] > 0:      # the wave's own rule
+                et, topo = table("collapse_table", mesh, topo)
+                (after, n), row["s"]["collapse"] = timed(
+                    programs["collapse"], mesh, met, et)
+                row["n"]["collapse"] = int(n)
+                wl = note(noted, wl, mesh, after)
+                topo = note(mark, topo, mesh, after)
+                mesh = after
+            for name, (fn, field) in kernels.items():
+                et, topo = table(name + "_table", mesh, topo)
+                _, row["s"][name + "_head"] = timed(
+                    fn, mesh, met, nothing, et)
+                (after, n, keep, cand, nl), s = timed(
+                    fn, mesh, met, getattr(wl, field), et)
+                row["s"][name + "_rows"] = s - row["s"][name + "_head"]
+                row["cand"][name], row["wl"][name] = int(cand), int(nl)
                 row["n"][name] = int(n)
-            wl = note(wl, since, mesh)
+                wl = note(noted, wl._replace(**{field: wlist.looked(
+                    getattr(wl, field), keep)}), mesh, after)
+                topo = note(mark, topo, mesh, after)
+                mesh = after
+            before, topo = table("adjacency", mesh, topo)
+            (mesh, n), row["s"]["swap23"] = timed(
+                programs["swap23"], before, met)
+            row["n"]["swap23"] = int(n)
+            topo = note(mark, topo, before, mesh)
+            (mesh, n), row["s"]["smooth"] = timed(
+                programs["smooth"], mesh, met,
+                jnp.asarray(1000 + w, jnp.int32))
+            row["n"]["smooth"] = int(n)
+            if row["n"]["swap23"] > 0:      # the wave's own rule
+                mesh, topo = table("exit_adjacency", mesh, topo)
+            # swap23, the smoothing and the adjacencies' tags, in one
+            wl = note(noted, wl, before, mesh)
             say(f"  wave {w}: bad {row['bad']:5d}  " + "  ".join(
                 f"{k} {row['s'][k]:.3f}s" for k in STAGES) + "  applied "
                 + "/".join(str(row["n"][k]) for k in APPLIED) + "  wl/cand "
                 + "  ".join(f"{k} {row['wl'][k]}/{row['cand'][k]}"
-                            for k in kernels))
+                            for k in kernels) + "  tables "
+                + "  ".join(f"{k} {row['dirty'][k]}"
+                            f"{'m' if row['merged'][k] else 'F'}"
+                            f" {row['full_s'][k]:.3f}s"
+                            for k in row["dirty"]))
             rows.append(row)
-            if sum(row["n"][k] for k in APPLIED[:5]) == 0:
+            if sum(row["n"][k] for k in APPLIED[:4]) == 0:
                 break       # the driver's loop ends here too
     return rows
 
 
 def summary(rows: list[dict]) -> dict:
     """Mean seconds and share of each stage over the waves after the
-    first (which pays the compiles)."""
+    first (which pays the compiles); per table, over those waves, what
+    it cost off the carried sort and by the full sort in the waves that
+    derived it."""
     warm = rows[1:] or rows
     mean = {k: sum(r["s"][k] for r in warm) / len(warm)
             for k in warm[0]["s"]}
     total = sum(mean.values())
+
+    def over(name, field):
+        vals = [r[field][name] for r in warm if name in r["dirty"]]
+        return sum(vals) / len(vals) if vals else None
+
     return {"waves_meaned": len(warm), "wave_s": total,
             "mean_s": mean,
             "share_pct": {k: 100.0 * v / total for k, v in mean.items()},
+            "table_s": {k: over(k, "s") for k in TABLES},
+            "table_full_s": {k: over(k, "full_s") for k in TABLES},
+            "tables": sum(len(r["dirty"]) for r in rows),
+            "tables_merged": sum(sum(r["merged"].values()) for r in rows),
+            "dirty_by_wave": {k: [r["dirty"].get(k) for r in rows]
+                              for k in TABLES},
+            "merged_by_wave": {k: [r["merged"].get(k) for r in rows]
+                               for k in TABLES},
             "bad_by_wave": [r["bad"] for r in rows],
             "applied_by_wave": {k: [r["n"][k] for r in rows]
                                 for k in rows[0]["n"]},

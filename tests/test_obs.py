@@ -606,6 +606,25 @@ def test_polish_waves_say_which_stages_ran(grouped_job):
             1 - r["adj"] for r in waves)
 
 
+def test_polish_waves_say_how_their_tables_were_made(grouped_job):
+    """``tab``, ``inc`` on every ``polish wave`` (PR 38): the tables the
+    wave derived and those of them taken off the retained sort; a job's
+    first edge table and first adjacency have nothing to merge into, and
+    the two counters are the waves' own sums."""
+    for which in ("cold", "warm"):
+        job = grouped_job[which]
+        recs, _ = _tree(job["records"])
+        waves = [r for r in recs if r["name"] == "polish wave"]
+        assert all({"tab", "inc"} <= set(r) for r in waves)
+        assert all(r["tab"] == r["col"] + 3 + r["adj"] for r in waves)
+        assert waves[0]["inc"] == waves[0]["tab"] - 2
+        assert all(r["inc"] == r["tab"] for r in waves[1:])
+        assert job["counters"]["tail.tables"] == sum(
+            r["tab"] for r in waves)
+        assert job["counters"]["tail.tables_merged"] == sum(
+            r["inc"] for r in waves)
+
+
 def test_tail_rows_are_counted_once_a_job(grouped_job):
     """``tail.rows_live`` / ``tail.rows_cap``: the mesh the merged tail
     is about to run on, which is the last merge's, at the capacity

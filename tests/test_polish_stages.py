@@ -137,6 +137,27 @@ def test_counts_say_which_stage_ran(case):
             assert c[0] == 0 and c[4] == 0
 
 
+def test_with_the_retained_sorts_the_wave_is_still_that_wave():
+    """PR 38: handed the sorts behind its tables (``ops/topo_incr``) and
+    nothing else, the wave merges where it sorted, skips what it skipped
+    and gives what the wave that runs every stage gives; the row's two
+    new columns count the tables it derived."""
+    from parmmg_tpu.ops.topo_incr import topo_init
+    mesh, met = fixture("tensor")
+    topo = topo_init(mesh.capT)
+    for w, (_, (ref, ref_counts)) in enumerate(two_waves("tensor")):
+        mesh, counts, topo = sliver_polish(
+            mesh, met, jnp.asarray(1000 + w, jnp.int32), hausd=HAUSD,
+            topo=topo)
+        for a, b in zip(jax.tree.leaves(mesh), jax.tree.leaves(ref)):
+            assert a.dtype == b.dtype and np.array_equal(a, b)
+        counts = np.asarray(counts).tolist()
+        assert counts[:6] == ref_counts.tolist()
+        assert tuple(counts[7:9]) == CASES["tensor"][2][w]
+        assert len(counts) == 13 and counts[9:11] == [0, 0]   # no list
+        assert counts[11] == 5 and counts[12] == (3 if w == 0 else 5)
+
+
 def test_without_swaps_the_exit_adjacency_is_still_built():
     """``do_swap=False``: no adjacency was built inside the wave, and the
     collapses changed the topology."""
