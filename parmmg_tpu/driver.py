@@ -25,6 +25,7 @@ from .core import constants as C
 from .core.mesh import Mesh, mesh_to_host
 from .ops.adapt import adapt_mesh, AdaptStats
 from .ops.metric import metric_hsiz, metric_optim, clamp_metric, gradation
+from .utils.compilecache import LEDGER
 
 
 def _auto_hmin_hmax(vert: np.ndarray, info) -> tuple[float, float]:
@@ -238,11 +239,14 @@ def _merged_polish(mesh, met, info, hausd, stats, tim):
                 # the kernel last looked, which is what it judged;
                 # tab: edge tables and adjacencies the wave derived,
                 # inc: those of them merged into (or taken as) the sort
-                # the last derivation left, not sorted in full
+                # the last derivation left, not sorted in full;
+                # prog: which of the polish programs this process
+                # lowered the wave ran (obs/devtime picks its map by it)
                 sp.set(collapse=ncol, swap=nswap, moved=nmoved,
                        bsplit=0, hveto=nhveto, bmoved=nbmoved,
                        bad=nbad, col=col, adj=adj, wl=wl, cand=cand,
-                       tab=tab, inc=inc)
+                       tab=tab, inc=inc,
+                       prog=LEDGER.program_index("adapt.sliver_polish"))
             col_skipped += int(not info.noinsert and not col)
             adj_skipped += int(not adj)
             cand_rows += cand
@@ -536,7 +540,8 @@ def _finish_run(pm, mesh, met, stats, info, tim, bg_mesh, bg_fields,
                     nf, ovf, nbs = (int(v) for v in np.asarray(fc))
                     # a fem round collapses and moves nothing
                     sp.set(split=nf, overflow=ovf, bsplit=nbs, hveto=0,
-                           bmoved=0)
+                           bmoved=0,
+                           prog=LEDGER.program_index("adapt.fem_pass"))
                 stats.nsplit += nf
                 stats.add_surface(bsplit=nbs)
                 if ovf:

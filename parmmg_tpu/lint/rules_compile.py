@@ -15,7 +15,7 @@ AST (these are the idioms PRs 3-5 actually converged on):
 - an ``functools.lru_cache``-ed builder;
 - a builder that stores into a module-level CAPS cache
   (``_GROUP_BLOCK_CACHE[key] = run``, ``_QPROBE.append(probe)``, or a
-  ``global`` rebind — the _EXTRACT_PROBE idiom);
+  ``global`` rebind);
 - an instance cache (``self.x = ...`` — the DistSteps pattern);
 - a ``governed(...)``-wrapped construction in the same statement (the
   ledger then bounds the variant count at runtime even if the caller

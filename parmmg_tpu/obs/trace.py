@@ -44,8 +44,13 @@ exactly one Timers registry's ``report()`` from the stream.
 
 Device timelines: ``PARMMG_PROFILE_DIR=<dir>`` is the operator's one
 switch: :func:`profile_capture` (entered by ``driver.parmmg_run``)
-holds a ``jax.profiler`` capture over one whole run, staging to tail.
-:func:`scope` wraps ``jax.named_scope`` (XLA op metadata).
+holds a ``jax.profiler`` capture over one whole run, staging to tail,
+and when it closes hands the capture to ``obs.devtime``, which joins the
+device's op events with the scopes the executable carries and emits one
+``device_phases`` event: device seconds by phase of the cycle.
+:func:`scope` wraps ``jax.named_scope`` (XLA op metadata): every stage
+of a cycle, of a polish wave and of a fem pass sits under one
+(``cyc.*``, ``pol.*``, ``fem.*``; the table makers under ``tab.*``).
 
 :func:`log` is the one verbosity-gated print path (the reference's
 ``imprim`` levels, core.constants.PMMG_VERB_*): gated output AND an
@@ -428,6 +433,14 @@ def profile_capture():
         event("profile_stop", dir=d)
         # stderr: stdout is the artifact channel of every emitting script
         log(1, f"obs: profiler trace written to {d}", err=True)
+        # the program's own digest of the capture it made: device
+        # seconds by phase, ONE ``device_phases`` event (obs/devtime).
+        # Only here: a run without the variable never imports the module
+        try:
+            from . import devtime
+            devtime.digest_run(d)
+        except Exception as e:
+            log(0, f"obs: no digest of the capture ({e!r})", err=True)
 
 
 def scope(name: str):

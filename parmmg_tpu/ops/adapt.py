@@ -223,12 +223,13 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
         from .edges import unique_edges, edge_lengths
         # slim table: split/collapse never read shell3 (only the swap
         # kernels, which build their own) — skips a [6*capT] scatter
-        if topo is not None:
-            et, topo = incr_unique_edges(mesh, topo, incr,
-                                         shell_slots=0)
-        else:
-            et = unique_edges(mesh, shell_slots=0)
-        lens = edge_lengths(mesh, et, met)
+        with otrace.scope("cyc.table"):
+            if topo is not None:
+                et, topo = incr_unique_edges(mesh, topo, incr,
+                                             shell_slots=0)
+            else:
+                et = unique_edges(mesh, shell_slots=0)
+            lens = edge_lengths(mesh, et, met)
         # ridge tangents once per cycle too (same sharing rationale;
         # collapse only consults non-stale candidates, whose tangent
         # fields are identical pre/post split)
@@ -239,32 +240,36 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
         if hausd is not None:
             from .analysis import boundary_vertex_normals, \
                 ridge_vertex_tangents
-            vtan0 = ridge_vertex_tangents(mesh, et=et)
-            vn0 = boundary_vertex_normals(mesh)
+            with otrace.scope("cyc.normals"):
+                vtan0 = ridge_vertex_tangents(mesh, et=et)
+                vn0 = boundary_vertex_normals(mesh)
         # ``prescreen=False`` (adapt_mesh's wide convergence check, the
         # drivers' polish cycles) disables the approximate nomination
         # prescreen so shells it over-vetoed get one exact
         # re-evaluation before convergence is accepted (split.py)
-        res = split_wave(mesh, met, hausd=hausd, budget_div=budget_div,
-                         et=et, lens=lens, vtan=vtan0, vn=vn0,
-                         prescreen=prescreen)
-        if topo is not None:
-            topo = mark_dirty(topo, mesh.tet, mesh.tmask, res.mesh)
+        with otrace.scope("cyc.split"):
+            res = split_wave(mesh, met, hausd=hausd,
+                             budget_div=budget_div,
+                             et=et, lens=lens, vtan=vtan0, vn=vn0,
+                             prescreen=prescreen)
+            if topo is not None:
+                topo = mark_dirty(topo, mesh.tet, mesh.tmask, res.mesh)
         mesh, met = res.mesh, res.met
         nsplit, overflow = res.nsplit, res.overflow
         nbsplit = res.nbdy
         defer = defer | res.deferred
 
-        col = collapse_wave(mesh, met, hausd=hausd,
-                            budget_div=budget_div,
-                            et=et, lens=lens,
-                            stale_tets=res.modified, vtan=vtan0,
-                            vn=vn0)
-        if topo is not None:
-            # boundary_edge_tags below touches only tags, which the
-            # retained sorts never carry — marking against col.mesh is
-            # exact (ops/topo_incr module docstring)
-            topo = mark_dirty(topo, mesh.tet, mesh.tmask, col.mesh)
+        with otrace.scope("cyc.collapse"):
+            col = collapse_wave(mesh, met, hausd=hausd,
+                                budget_div=budget_div,
+                                et=et, lens=lens,
+                                stale_tets=res.modified, vtan=vtan0,
+                                vn=vn0)
+            if topo is not None:
+                # boundary_edge_tags below touches only tags, which the
+                # retained sorts never carry — marking against col.mesh
+                # is exact (ops/topo_incr module docstring)
+                topo = mark_dirty(topo, mesh.tet, mesh.tmask, col.mesh)
         defer = defer | col.deferred
         # collapse rewires the surface (dying tets' face tags transfer to
         # the surviving neighbors); re-propagate MG_BDY from faces to
@@ -273,8 +278,9 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
         # midpoints become "movable" and smoothing dents the surface.
         # Skipped when no dying tet donated tags (interior collapses):
         # the propagation pass costs a [12*capT]-index scatter
-        mesh = jax.lax.cond(col.surface_changed, boundary_edge_tags,
-                            lambda m: m, col.mesh)
+        with otrace.scope("cyc.bdytags"):
+            mesh = jax.lax.cond(col.surface_changed, boundary_edge_tags,
+                                lambda m: m, col.mesh)
         ncol, nhveto = col.ncollapse, col.nhveto
     else:
         # -noinsert: no point insertion or deletion (Mmg contract)
@@ -287,30 +293,34 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     def _swap(ops):
         mesh, topo = ops
         from .swap import swap_facesort_enabled
-        sew = swap_edges_wave(mesh, met, hausd=hausd,
-                              budget_div=budget_div)  # 3-2 + 2-2
-        if topo is not None:
-            topo = mark_dirty(topo, mesh.tet, mesh.tmask, sew.mesh)
-        if swap_facesort_enabled():
-            # swap23 pairs directly off the face sort (bit-identical to
-            # the adja path — ops/swap._pair_fields_facesort); the
-            # [capT,4] adja materialization + compare leaves the cycle
-            # interior, the rebuild at the end restores the adja contract.
-            # (This mid-cycle face sort is NOT band-maintained — scope
-            # cut: the facesort swap23 derives its pairing internally.)
-            s23 = swap23_wave(sew.mesh, met, budget_div=budget_div,
-                              facesort=True)
-            pre = sew.mesh
-        else:
-            # consumed by swap23
+        with otrace.scope("cyc.swap_edges"):
+            sew = swap_edges_wave(mesh, met, hausd=hausd,
+                                  budget_div=budget_div)  # 3-2 + 2-2
             if topo is not None:
-                mesh, topo = incr_build_adjacency(sew.mesh, topo, incr)
+                topo = mark_dirty(topo, mesh.tet, mesh.tmask, sew.mesh)
+        with otrace.scope("cyc.swap23"):
+            if swap_facesort_enabled():
+                # swap23 pairs directly off the face sort (bit-identical
+                # to the adja path — ops/swap._pair_fields_facesort); the
+                # [capT,4] adja materialization + compare leaves the
+                # cycle interior, the rebuild at the end restores the
+                # adja contract.  (This mid-cycle face sort is NOT
+                # band-maintained — scope cut: the facesort swap23
+                # derives its pairing internally.)
+                s23 = swap23_wave(sew.mesh, met, budget_div=budget_div,
+                                  facesort=True)
+                pre = sew.mesh
             else:
-                mesh = build_adjacency(sew.mesh)
-            s23 = swap23_wave(mesh, met, budget_div=budget_div)
-            pre = mesh
-        if topo is not None:
-            topo = mark_dirty(topo, pre.tet, pre.tmask, s23.mesh)
+                # consumed by swap23
+                if topo is not None:
+                    mesh, topo = incr_build_adjacency(sew.mesh, topo,
+                                                      incr)
+                else:
+                    mesh = build_adjacency(sew.mesh)
+                s23 = swap23_wave(mesh, met, budget_div=budget_div)
+                pre = mesh
+            if topo is not None:
+                topo = mark_dirty(topo, pre.tet, pre.tmask, s23.mesh)
         return (s23.mesh, topo, sew.nswap + s23.nswap,
                 sew.deferred | s23.deferred)
 
@@ -327,15 +337,17 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     nmoved = jnp.zeros((2,), jnp.int32)      # [all, of them surface]
     if do_smooth:
         for w in range(smooth_waves):
-            sm = smooth_wave(mesh, met, wave=wave * smooth_waves + w,
-                             hausd=hausd)
+            with otrace.scope("cyc.smooth"):
+                sm = smooth_wave(mesh, met, wave=wave * smooth_waves + w,
+                                 hausd=hausd)
             mesh = sm.mesh
             nmoved = nmoved + jnp.stack([sm.nmoved, sm.nbdy])
 
-    if topo is not None:
-        mesh, topo = incr_build_adjacency(mesh, topo, incr)
-    else:
-        mesh = build_adjacency(mesh)
+    with otrace.scope("cyc.adjacency"):
+        if topo is not None:
+            mesh, topo = incr_build_adjacency(mesh, topo, incr)
+        else:
+            mesh = build_adjacency(mesh)
 
     row = [nsplit, ncol, nswap, nmoved[0],
            overflow.astype(jnp.int32),
@@ -371,14 +383,20 @@ def fem_pass_impl(mesh: Mesh, met: jax.Array):
     candidates are interior edges, so ``bsplit`` (splits of boundary
     edges) reads 0 while that holds."""
     from .adjacency import boundary_edge_tags
-    res = split_wave(mesh, met, fem_only=True, budget_div=2)
-    mesh = boundary_edge_tags(res.mesh)
-    mesh = build_adjacency(mesh)
+    with otrace.scope("fem.split"):
+        res = split_wave(mesh, met, fem_only=True, budget_div=2)
+    with otrace.scope("fem.bdytags"):
+        mesh = boundary_edge_tags(res.mesh)
+    with otrace.scope("fem.adjacency"):
+        mesh = build_adjacency(mesh)
     return mesh, res.met, jnp.stack(
         [res.nsplit, res.overflow.astype(jnp.int32), res.nbdy])
 
 
-fem_pass = partial(jax.jit, donate_argnums=(0, 1))(fem_pass_impl)
+# governed so that the ledger keeps the signature it lowered from:
+# ``obs.devtime`` reads the round's stages off its executable
+fem_pass = _governed("adapt.fem_pass")(
+    partial(jax.jit, donate_argnums=(0, 1))(fem_pass_impl))
 
 
 def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
@@ -471,8 +489,13 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     # [tab, inc], counted with ``topo`` only
     tables = None if topo is None else jnp.zeros(2, jnp.int32)
 
+    # the bookkeeping between the stages (the worklist's diffs, the dirty
+    # masks of the retained sorts) is a phase of its own: ``pol.list``
     def note(wl, before, after):
-        return None if wl is None else wlist.noted(wl, before, after)
+        if wl is None:
+            return None
+        with otrace.scope("pol.list"):
+            return wlist.noted(wl, before, after)
 
     if topo is None:
         def edge_table(m, tp, tables, slots):
@@ -503,13 +526,11 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
         def dirtied(tp, before, after):
             # the sorts carry keys of (tet, tmask) alone: a stage that
             # moves vertices or sets tags dirties nothing
-            return mark_dirty(tp, before.tet, before.tmask, after)
+            with otrace.scope("pol.list"):
+                return mark_dirty(tp, before.tet, before.tmask, after)
 
     if do_collapse:
         from .quality import quality_from_points
-        q_tet = quality_from_points(
-            mesh.vert[mesh.tet], None if met.ndim == 1 else met[mesh.tet])
-        nbad = jnp.sum(mesh.tmask & (q_tet < sliver_q), dtype=jnp.int32)
 
         def _collapse(ops):
             # the polish widens the compaction budget (budget_div=2, or
@@ -527,19 +548,26 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
             return (m, tp, tables), col.ncollapse, col.nhveto
 
         before = mesh
-        (mesh, topo, tables), ncol, nhveto = jax.lax.cond(
-            nbad > 0, _collapse, lambda ops: (ops, zero, zero),
-            (mesh, topo, tables))
+        with otrace.scope("pol.collapse"):
+            q_tet = quality_from_points(
+                mesh.vert[mesh.tet],
+                None if met.ndim == 1 else met[mesh.tet])
+            nbad = jnp.sum(mesh.tmask & (q_tet < sliver_q),
+                           dtype=jnp.int32)
+            (mesh, topo, tables), ncol, nhveto = jax.lax.cond(
+                nbad > 0, _collapse, lambda ops: (ops, zero, zero),
+                (mesh, topo, tables))
         wl = note(wl, before, mesh)
         topo = dirtied(topo, before, mesh)
     if do_swap:
         from .swapgen import swapgen_wave, RING_MAX
         from .swap import swap_facesort_enabled
-        et, topo, tables = edge_table(mesh, topo, tables, 3)
-        sew = swap_edges_wave(mesh, met, hausd=hausd, budget_div=2,
-                              budget=budget,      # 3-2 + 2-2
-                              worklist=None if wl is None else wl.edges,
-                              et=et)
+        with otrace.scope("pol.swap_edges"):
+            et, topo, tables = edge_table(mesh, topo, tables, 3)
+            sew = swap_edges_wave(
+                mesh, met, hausd=hausd, budget_div=2,
+                budget=budget,      # 3-2 + 2-2
+                worklist=None if wl is None else wl.edges, et=et)
         if wl is not None:
             wl = note(wl._replace(edges=wlist.looked(wl.edges, sew.keep)),
                       mesh, sew.mesh)
@@ -547,26 +575,29 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
         # generalized degree 4-6 ring swaps: the worst surviving tets
         # are typically gate-limited for every lower-degree op — this
         # is the class that lifts the min past the 3-2/2-3 plateau
-        et, topo, tables = edge_table(sew.mesh, topo, tables, RING_MAX)
-        sgn = swapgen_wave(sew.mesh, met, budget_div=2, budget=budget,
-                           worklist=None if wl is None else wl.rings,
-                           et=et)
+        with otrace.scope("pol.swapgen"):
+            et, topo, tables = edge_table(sew.mesh, topo, tables,
+                                          RING_MAX)
+            sgn = swapgen_wave(
+                sew.mesh, met, budget_div=2, budget=budget,
+                worklist=None if wl is None else wl.rings, et=et)
         if wl is not None:
             wl = note(wl._replace(rings=wlist.looked(wl.rings, sgn.keep)),
                       sew.mesh, sgn.mesh)
             ncand, nlist = sew.ncand + sgn.ncand, sew.nlist + sgn.nlist
         topo = dirtied(topo, sew.mesh, sgn.mesh)
-        if swap_facesort_enabled():
-            mesh = sgn.mesh
-            s23 = swap23_wave(mesh, met, budget_div=2, budget=budget,
-                              facesort=True)
-        else:
-            # consumed by swap23
-            mesh, topo, tables = adjacency(sgn.mesh, topo, tables)
-            s23 = swap23_wave(mesh, met, budget_div=2, budget=budget)
-            # without a winner swap23 hands back the mesh it was given,
-            # adjacency and all
-            rebuild = s23.nswap > 0
+        with otrace.scope("pol.swap23"):
+            if swap_facesort_enabled():
+                mesh = sgn.mesh
+                s23 = swap23_wave(mesh, met, budget_div=2, budget=budget,
+                                  facesort=True)
+            else:
+                # consumed by swap23
+                mesh, topo, tables = adjacency(sgn.mesh, topo, tables)
+                s23 = swap23_wave(mesh, met, budget_div=2, budget=budget)
+                # without a winner swap23 hands back the mesh it was
+                # given, adjacency and all
+                rebuild = s23.nswap > 0
         topo = dirtied(topo, mesh, s23.mesh)
         mesh = s23.mesh
         nswap = sew.nswap + sgn.nswap + s23.nswap
@@ -574,17 +605,19 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     if do_smooth:
         # optimal-position mode: sliver-ball vertices ascend the height
         # of their worst incident tet instead of chasing the centroid
-        sm = smooth_wave(mesh, met, wave=wave, opt_q=sliver_q,
-                         hausd=hausd)
+        with otrace.scope("pol.smooth"):
+            sm = smooth_wave(mesh, met, wave=wave, opt_q=sliver_q,
+                             hausd=hausd)
         mesh = sm.mesh
         nmoved, nbmoved = sm.nmoved, sm.nbdy
-    if rebuild is None:                         # exit contract
-        mesh, topo, tables = adjacency(mesh, topo, tables)
-        rebuild = jnp.ones((), bool)
-    else:
-        mesh, topo, tables = jax.lax.cond(
-            rebuild, lambda ops: adjacency(*ops), lambda ops: ops,
-            (mesh, topo, tables))
+    with otrace.scope("pol.adjacency"):
+        if rebuild is None:                         # exit contract
+            mesh, topo, tables = adjacency(mesh, topo, tables)
+            rebuild = jnp.ones((), bool)
+        else:
+            mesh, topo, tables = jax.lax.cond(
+                rebuild, lambda ops: adjacency(*ops), lambda ops: ops,
+                (mesh, topo, tables))
     row = [ncol, nswap, nmoved, jnp.sum(mesh.tmask, dtype=jnp.int32),
            nhveto, nbmoved, nbad, (nbad > 0).astype(jnp.int32),
            rebuild.astype(jnp.int32), ncand, nlist]

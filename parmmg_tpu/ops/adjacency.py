@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from ..core.mesh import Mesh, tet_face_vertices
 from ..core.constants import MG_BDY
+from ..obs import trace as otrace
 
 
 def _face_keys(mesh: Mesh):
@@ -48,23 +49,25 @@ def face_sort(mesh: Mesh):
     ``adja`` matrix.
     """
     from .edges import PACK_LIMIT
-    capT = mesh.capT
-    big = jnp.iinfo(jnp.int32).max
-    cols, tetid, faceid = _face_keys(mesh)
-    if mesh.capP <= PACK_LIMIT:
-        # pack the two minor columns into one int32 (ids < capP <=
-        # sqrt(2^31)): the 3-pass lexsort becomes 2 passes — face
-        # matching is one of the measured per-wave hot spots
-        invalid = cols[:, 0] == big
-        w = jnp.where(invalid, big, cols[:, 1] * mesh.capP + cols[:, 2])
-        order = jnp.lexsort((w, cols[:, 0]))
-        return face_records_from_sorted(mesh, order, cols[order, 0],
-                                        w[order])
-    order = jnp.lexsort((cols[:, 2], cols[:, 1], cols[:, 0]))
-    k = cols[order]
-    t = tetid[order]
-    f = faceid[order]
-    return _pair_records(capT, k, t, f, big)
+    with otrace.scope("tab.adjacency"):
+        capT = mesh.capT
+        big = jnp.iinfo(jnp.int32).max
+        cols, tetid, faceid = _face_keys(mesh)
+        if mesh.capP <= PACK_LIMIT:
+            # pack the two minor columns into one int32 (ids < capP <=
+            # sqrt(2^31)): the 3-pass lexsort becomes 2 passes — face
+            # matching is one of the measured per-wave hot spots
+            invalid = cols[:, 0] == big
+            w = jnp.where(invalid, big,
+                          cols[:, 1] * mesh.capP + cols[:, 2])
+            order = jnp.lexsort((w, cols[:, 0]))
+            return face_records_from_sorted(mesh, order, cols[order, 0],
+                                            w[order])
+        order = jnp.lexsort((cols[:, 2], cols[:, 1], cols[:, 0]))
+        k = cols[order]
+        t = tetid[order]
+        f = faceid[order]
+        return _pair_records(capT, k, t, f, big)
 
 
 def face_records_from_sorted(mesh: Mesh, order: jax.Array,
@@ -121,8 +124,9 @@ def build_adjacency(mesh: Mesh) -> Mesh:
     sorting face keys, twins are neighbors in sorted order; the pairing is
     scattered back as ``adja[t,f] = 4*t' + f'``.
     """
-    t, f, partner, matched, _ = face_sort(mesh)
-    return adjacency_from_records(mesh, t, f, partner, matched)
+    with otrace.scope("tab.adjacency"):
+        t, f, partner, matched, _ = face_sort(mesh)
+        return adjacency_from_records(mesh, t, f, partner, matched)
 
 
 def adjacency_from_records(mesh: Mesh, t, f, partner, matched) -> Mesh:

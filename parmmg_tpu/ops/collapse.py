@@ -195,7 +195,7 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
                               nhveto)
 
     def _act(_):
-        # top-K compaction (scripts/wave_time.py cost lever): the K highest-
+        # top-K compaction (the wave's cost lever, PERF.md section 5): the K highest-
         # priority candidates go through the heavy machinery; claims stay
         # exact (they resolve against global vertex/tet pools) and deferred
         # candidates are picked up by the next wave.  Priority: shortest
@@ -242,7 +242,7 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
         # each tet's single CLAIMED corner — [T]-width instead of the old
         # [4T] stacked variants, with the contested/invalid cases folded
         # into the same ball-quality scatter as -inf rows
-        # (scripts/split_stage_time.py: validity+ballq was ~28 ms).
+        # (validity+ballq was ~28 ms; a block by phase: PERF.md section 5).
         tv = mesh.tet                                          # [T,4]
         vpos = mesh.vert[tv]                                   # [T,4,3]
         vs_c = v_s[tv]                                         # [T,4] score max

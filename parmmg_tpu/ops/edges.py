@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from ..core.mesh import Mesh, tet_edge_vertices
 from ..core.constants import IARE
+from ..obs import trace as otrace
 
 _INT32_MAX = 2147483647
 
@@ -27,7 +28,7 @@ def wave_budget(capT: int, div: int = 8, rows: int | None = None) -> int:
     """Per-wave top-K compaction budget shared by every wave kernel: the
     K = max(2048, capT//div) highest-priority candidates go through the
     heavy geometry/routing/scatter machinery (cost is linear in index
-    count — scripts/wave_time.py); the rest are deferred to the next
+    count — PERF.md section 5); the rest are deferred to the next
     wave.  The polish passes div=2 for full coverage.
 
     ``capT // div`` ties the budget to the PADDING: right for a mesh
@@ -154,16 +155,17 @@ def unique_edges(mesh: Mesh, shell_slots: int = 3) -> EdgeTable:
     """``shell_slots=0`` skips the shell-tet-id scatter entirely (returns
     ``shell3`` with zero columns) — split/collapse never read it, only the
     swap kernels do, and every scatter at [6*capT] width is a measured
-    multi-ms item on this device (scripts/tpu_microbench.py,
-    scripts/split_stage_time.py)."""
-    capT = mesh.capT
-    n6 = capT * 6
-    ev = tet_edge_vertices(mesh.tet).reshape(n6, 2)
-    a = jnp.minimum(ev[:, 0], ev[:, 1])
-    b = jnp.maximum(ev[:, 0], ev[:, 1])
-    valid = jnp.repeat(mesh.tmask, 6)
-    order, ka, kb, first = sort_pairs(a, b, valid, mesh.capP)
-    return _edges_epilogue(mesh, order, ka, kb, first, shell_slots)
+    multi-ms item on this device (scripts/tpu_microbench.py; a block by
+    phase: PERF.md section 5)."""
+    with otrace.scope("tab.edges"):
+        capT = mesh.capT
+        n6 = capT * 6
+        ev = tet_edge_vertices(mesh.tet).reshape(n6, 2)
+        a = jnp.minimum(ev[:, 0], ev[:, 1])
+        b = jnp.maximum(ev[:, 0], ev[:, 1])
+        valid = jnp.repeat(mesh.tmask, 6)
+        order, ka, kb, first = sort_pairs(a, b, valid, mesh.capP)
+        return _edges_epilogue(mesh, order, ka, kb, first, shell_slots)
 
 
 def unique_edges_from_sorted(mesh: Mesh, order: jax.Array, ks: jax.Array,

@@ -237,8 +237,8 @@ def split_wave(mesh: Mesh, met: jax.Array, lmax: float = LLONG,
 
         # --- budget: top-K winners by priority (longest edges first) ---------
         # replaces a full-width argsort + 6 full-width cumsums with ONE
-        # top_k and [KW]-width prefix sums (scripts/split_stage_time.py:
-        # the budget/offset stage was ~30 ms of the wave)
+        # top_k and [KW]-width prefix sums (the budget/offset stage was
+        # ~30 ms of the wave; a block by phase: PERF.md section 5)
         KW = min(wave_budget(capT, budget_div), capE)
         KH = min(2 * wave_budget(capT, budget_div), capT)
         # fused scoring prep (ops/edges.topk_prep wants smallest-first,

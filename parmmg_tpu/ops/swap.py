@@ -130,7 +130,7 @@ def swap_edges_wave(mesh: Mesh, met: jax.Array, enable32: bool = True,
     eps32*|coords|, which swamps a purely relative tolerance on exactly
     the thin quads this swap targets).
 
-    Top-K compaction (the wave's cost lever, scripts/wave_time.py): the
+    Top-K compaction (the wave's cost lever, PERF.md section 5): the
     cheap candidacy masks are computed at full [6*capT] width, then only
     the K = capT/``budget_div`` candidates with the WORST current shell
     quality go through the heavy role-derivation / gate / routing /

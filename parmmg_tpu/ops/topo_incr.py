@@ -59,6 +59,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.mesh import Mesh, tet_edge_vertices, tet_face_vertices
+from ..obs import trace as otrace
 
 _INT32_MAX = 2147483647
 
@@ -364,56 +365,57 @@ def incr_unique_edges(mesh: Mesh, topo: TopoState, incr,
     Returns (EdgeTable, new TopoState), and with ``told`` a third
     result: did the table come off the retained sort (merge or reuse)."""
     from .edges import PACK_LIMIT, unique_edges, unique_edges_from_sorted
-    capT = mesh.capT
-    n6 = capT * 6
-    if mesh.capP > PACK_LIMIT:
-        # the merge needs single-int32 packed keys; oversized id spaces
-        # keep the exact legacy path (never reached at group shapes)
-        et = unique_edges(mesh, shell_slots=shell_slots)
-        topo = topo._replace(eok=jnp.zeros((), bool),
+    with otrace.scope("tab.edges"):
+        capT = mesh.capT
+        n6 = capT * 6
+        if mesh.capP > PACK_LIMIT:
+            # the merge needs single-int32 packed keys; oversized id spaces
+            # keep the exact legacy path (never reached at group shapes)
+            et = unique_edges(mesh, shell_slots=shell_slots)
+            topo = topo._replace(eok=jnp.zeros((), bool),
+                                 edirty=jnp.zeros(capT, bool))
+            return (et, topo, jnp.zeros((), bool)) if told else (et, topo)
+        rungs = _rungs(band, capT)
+        nd = jnp.sum(topo.edirty, dtype=jnp.int32)
+        use_band = jnp.asarray(incr) & topo.eok & (nd <= rungs[-1])
+
+        def _full(_):
+            ev = tet_edge_vertices(mesh.tet).reshape(n6, 2)
+            a = jnp.minimum(ev[:, 0], ev[:, 1])
+            b = jnp.maximum(ev[:, 0], ev[:, 1])
+            valid = jnp.repeat(mesh.tmask, 6)
+            key = jnp.where(valid, a * mesh.capP + b, _INT32_MAX)
+            order = jnp.argsort(key).astype(jnp.int32)
+            return key[order], order
+
+        def _band(_):
+            def _reuse(_):
+                # zero dirty tets since the last derivation: the retained
+                # sort IS the fresh sort (keys depend only on tet/tmask) —
+                # the decay-regime steady state, and the generalization of
+                # the old all-or-nothing et-cache to adjacency too
+                return topo.ekey, topo.eslot
+
+            def _merge_at(B):
+                def _merge(_):
+                    sd = topo.edirty[topo.eslot // 6]
+                    dt = jnp.nonzero(topo.edirty, size=B,
+                                     fill_value=capT)[0].astype(jnp.int32)
+                    bkey, bslot = edge_band_records(mesh, dt)
+                    (ks,), order = merge_sorted_band(
+                        (topo.ekey,), topo.eslot, sd, (bkey,), bslot, rolled)
+                    return ks, order
+                return _merge
+            return jax.lax.cond(nd == 0, _reuse,
+                                _narrowest(nd, rungs, _merge_at), None)
+
+        ks, order = jax.lax.cond(use_band, _band, _full, None)
+        et = unique_edges_from_sorted(mesh, order, ks,
+                                      shell_slots=shell_slots)
+        topo = topo._replace(ekey=ks, eslot=order,
+                             eok=jnp.ones((), bool),
                              edirty=jnp.zeros(capT, bool))
-        return (et, topo, jnp.zeros((), bool)) if told else (et, topo)
-    rungs = _rungs(band, capT)
-    nd = jnp.sum(topo.edirty, dtype=jnp.int32)
-    use_band = jnp.asarray(incr) & topo.eok & (nd <= rungs[-1])
-
-    def _full(_):
-        ev = tet_edge_vertices(mesh.tet).reshape(n6, 2)
-        a = jnp.minimum(ev[:, 0], ev[:, 1])
-        b = jnp.maximum(ev[:, 0], ev[:, 1])
-        valid = jnp.repeat(mesh.tmask, 6)
-        key = jnp.where(valid, a * mesh.capP + b, _INT32_MAX)
-        order = jnp.argsort(key).astype(jnp.int32)
-        return key[order], order
-
-    def _band(_):
-        def _reuse(_):
-            # zero dirty tets since the last derivation: the retained
-            # sort IS the fresh sort (keys depend only on tet/tmask) —
-            # the decay-regime steady state, and the generalization of
-            # the old all-or-nothing et-cache to adjacency too
-            return topo.ekey, topo.eslot
-
-        def _merge_at(B):
-            def _merge(_):
-                sd = topo.edirty[topo.eslot // 6]
-                dt = jnp.nonzero(topo.edirty, size=B,
-                                 fill_value=capT)[0].astype(jnp.int32)
-                bkey, bslot = edge_band_records(mesh, dt)
-                (ks,), order = merge_sorted_band(
-                    (topo.ekey,), topo.eslot, sd, (bkey,), bslot, rolled)
-                return ks, order
-            return _merge
-        return jax.lax.cond(nd == 0, _reuse,
-                            _narrowest(nd, rungs, _merge_at), None)
-
-    ks, order = jax.lax.cond(use_band, _band, _full, None)
-    et = unique_edges_from_sorted(mesh, order, ks,
-                                  shell_slots=shell_slots)
-    topo = topo._replace(ekey=ks, eslot=order,
-                         eok=jnp.ones((), bool),
-                         edirty=jnp.zeros(capT, bool))
-    return (et, topo, use_band) if told else (et, topo)
+        return (et, topo, use_band) if told else (et, topo)
 
 
 def incr_build_adjacency(mesh: Mesh, topo: TopoState, incr,
@@ -428,50 +430,51 @@ def incr_build_adjacency(mesh: Mesh, topo: TopoState, incr,
     from .edges import PACK_LIMIT
     from .adjacency import (_face_keys, adjacency_from_records,
                             build_adjacency, face_records_from_sorted)
-    capT = mesh.capT
-    if mesh.capP > PACK_LIMIT:
-        mesh = build_adjacency(mesh)
-        topo = topo._replace(fok=jnp.zeros((), bool),
+    with otrace.scope("tab.adjacency"):
+        capT = mesh.capT
+        if mesh.capP > PACK_LIMIT:
+            mesh = build_adjacency(mesh)
+            topo = topo._replace(fok=jnp.zeros((), bool),
+                                 fdirty=jnp.zeros(capT, bool))
+            return (mesh, topo, jnp.zeros((), bool)) if told else (mesh, topo)
+        rungs = _rungs(band, capT)
+        nd = jnp.sum(topo.fdirty, dtype=jnp.int32)
+        use_band = jnp.asarray(incr) & topo.fok & (nd <= rungs[-1])
+
+        def _full(_):
+            cols, _, _ = _face_keys(mesh)
+            invalid = cols[:, 0] == _INT32_MAX
+            w = jnp.where(invalid, _INT32_MAX,
+                          cols[:, 1] * mesh.capP + cols[:, 2])
+            order = jnp.lexsort((w, cols[:, 0])).astype(jnp.int32)
+            return cols[order, 0], w[order], order
+
+        def _band(_):
+            def _reuse(_):
+                return topo.fk0, topo.fkw, topo.fslot
+
+            def _merge_at(B):
+                def _merge(_):
+                    sd = topo.fdirty[topo.fslot // 4]
+                    dt = jnp.nonzero(topo.fdirty, size=B,
+                                     fill_value=capT)[0].astype(jnp.int32)
+                    bk0, bkw, bslot = face_band_records(mesh, dt)
+                    (k0, kw), order = merge_sorted_band(
+                        (topo.fk0, topo.fkw), topo.fslot, sd, (bk0, bkw),
+                        bslot, rolled)
+                    return k0, kw, order
+                return _merge
+            return jax.lax.cond(nd == 0, _reuse,
+                                _narrowest(nd, rungs, _merge_at), None)
+
+        k0, kw, order = jax.lax.cond(use_band, _band, _full, None)
+        t, f, partner, matched, valid_s = face_records_from_sorted(
+            mesh, order, k0, kw)
+        mesh = adjacency_from_records(mesh, t, f, partner, matched)
+        topo = topo._replace(fk0=k0, fkw=kw, fslot=order,
+                             fok=jnp.ones((), bool),
                              fdirty=jnp.zeros(capT, bool))
-        return (mesh, topo, jnp.zeros((), bool)) if told else (mesh, topo)
-    rungs = _rungs(band, capT)
-    nd = jnp.sum(topo.fdirty, dtype=jnp.int32)
-    use_band = jnp.asarray(incr) & topo.fok & (nd <= rungs[-1])
-
-    def _full(_):
-        cols, _, _ = _face_keys(mesh)
-        invalid = cols[:, 0] == _INT32_MAX
-        w = jnp.where(invalid, _INT32_MAX,
-                      cols[:, 1] * mesh.capP + cols[:, 2])
-        order = jnp.lexsort((w, cols[:, 0])).astype(jnp.int32)
-        return cols[order, 0], w[order], order
-
-    def _band(_):
-        def _reuse(_):
-            return topo.fk0, topo.fkw, topo.fslot
-
-        def _merge_at(B):
-            def _merge(_):
-                sd = topo.fdirty[topo.fslot // 4]
-                dt = jnp.nonzero(topo.fdirty, size=B,
-                                 fill_value=capT)[0].astype(jnp.int32)
-                bk0, bkw, bslot = face_band_records(mesh, dt)
-                (k0, kw), order = merge_sorted_band(
-                    (topo.fk0, topo.fkw), topo.fslot, sd, (bk0, bkw),
-                    bslot, rolled)
-                return k0, kw, order
-            return _merge
-        return jax.lax.cond(nd == 0, _reuse,
-                            _narrowest(nd, rungs, _merge_at), None)
-
-    k0, kw, order = jax.lax.cond(use_band, _band, _full, None)
-    t, f, partner, matched, valid_s = face_records_from_sorted(
-        mesh, order, k0, kw)
-    mesh = adjacency_from_records(mesh, t, f, partner, matched)
-    topo = topo._replace(fk0=k0, fkw=kw, fslot=order,
-                         fok=jnp.ones((), bool),
-                         fdirty=jnp.zeros(capT, bool))
-    return (mesh, topo, use_band) if told else (mesh, topo)
+        return (mesh, topo, use_band) if told else (mesh, topo)
 
 
 # the merged polish's derivations (ops/adapt.sliver_polish_impl with a

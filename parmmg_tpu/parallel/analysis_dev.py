@@ -51,8 +51,7 @@ the pack phase also computes the local verdicts and carries the
 per-record bits ([G, 12*capT] uint32 + head bool — 5 bytes/record)
 across the map, and the tail re-derives only the cheap endpoint/slot
 gathers instead of re-running the normals + global-id extraction (the
-``extract2x_s`` decision input that priced this, retired in favor of
-the bench's ``extract1x_s`` single-extraction timing).
+``extract2x_s`` decision input that priced this, retired with it).
 """
 from __future__ import annotations
 
@@ -435,7 +434,7 @@ def shard_analysis_body_grouped(mesh_s: Mesh, glo_s, node_idx_s, nbr_s,
     predecessor extracted twice to keep the cross-map intermediate at
     [G, KS]; the ``extract2x_s`` probe priced that redundant second
     extraction at ~G x one extraction per refresh, which bought this
-    trade (bench ``extract1x_s`` = the measured per-group saving).
+    trade.
 
     Returns (vtag_new [G, capP], etag_new [G, capT, 6], overflow bool).
     """
@@ -520,56 +519,6 @@ def shard_analysis_body_grouped(mesh_s: Mesh, glo_s, node_idx_s, nbr_s,
     acc = jax.vmap(acc_one)(flat, recv)                       # [G,capP,4]
     vtag_new = _vtag_from_payload(mesh_s.vtag, mesh_s.vmask, payload, acc)
     return vtag_new, etag_new, ovf
-
-
-_EXTRACT_PROBE = None
-
-
-def extract_probe_seconds(mesh_g: Mesh, glo_g, repeats: int = 3) -> float:
-    """Wall-seconds for ONE [12*capT] record-table extraction, jitted
-    standalone (compile excluded; median of ``repeats`` runs).
-
-    PR 5 surfaced this as ``extract2x_s``, the decision input pricing
-    :func:`dist_analysis_grouped`'s redundant SECOND extraction (~G x
-    this number per refresh).  PR 12 fused the double extraction into
-    one pass (ROADMAP 4a) — the probe now prices what the fusion
-    REMOVED: before = 2x this per group per refresh, after = 1x plus
-    cheap endpoint gathers.  Surfaced as ``extract1x_s`` in the bench
-    extra (the measured before/after of the fusion).
-
-    The probe reduces every record field to scalars so the measurement
-    covers the full extraction (gathers + cross products + the
-    interface classification) without paying an [R]-wide device->host
-    pull."""
-    import time
-    from ..utils.compilecache import governed
-
-    global _EXTRACT_PROBE
-    if _EXTRACT_PROBE is None:
-        @governed("analysis.extract_probe", budget=2)
-        @jax.jit
-        def _probe(m, g):
-            # scalar sinks only (keeps every extraction field live
-            # against DCE without an [R]-wide pull; int32 wrap is fine
-            # for a timing sink)
-            rec = _extract_records(m, g)
-            return (jnp.sum(rec.g_lo) + jnp.sum(rec.g_hi),
-                    jnp.sum(rec.nu), jnp.sum(rec.frf),
-                    jnp.sum(rec.loc_rec), jnp.sum(rec.sh_rec))
-        _EXTRACT_PROBE = _probe
-    out = _EXTRACT_PROBE(mesh_g, glo_g)
-    jax.block_until_ready(out)                   # compile + warm
-    ts = []
-    for _ in range(max(1, int(repeats))):
-        t0 = time.perf_counter()
-        jax.block_until_ready(_EXTRACT_PROBE(mesh_g, glo_g))
-        ts.append(time.perf_counter() - t0)
-    sec = float(np.median(ts))
-    # obs spine: the fused-extraction timing rides the metrics registry
-    # too (the per-group per-refresh seconds the PR-12 fusion saves)
-    from ..obs.metrics import REGISTRY
-    REGISTRY.gauge("analysis.extract1x_s").set(sec)
-    return sec
 
 
 def dist_analysis(dmesh, angedg: float, KS: int):

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 from ..core.mesh import Mesh
 from ..core import constants as C
 from ..obs import trace as otrace
+from ..utils.compilecache import BLOCK_ENTRY, LEDGER
 
 
 def how_many_groups(ne: int, target: int) -> int:
@@ -181,18 +182,17 @@ def _group_block_program(nomove: bool, noinsert: bool, hausd):
     # variant budget: one program per shape family — the chunked
     # dispatch pads every chunk to ONE shape, regrows and regrouping
     # add a few; growth past this is recompile churn
-    @governed("groups.adapt_block", budget=6)
+    @governed(BLOCK_ENTRY, budget=6)
     @jax.jit
     def run(stacked, met_s, wave, active, incr, topo, sw, pr):
         def body(args):
             m, k, wave, act, inc, tp = args
-            # named_scope: the cycle's XLA ops carry the phase name on a
-            # profiler's device timeline (obs/trace.py)
-            with otrace.scope("grp_cycle0"):
-                return adapt_cycle_impl(
-                    m, k, wave, do_swap=sw, do_smooth=not nomove,
-                    do_insert=not noinsert, hausd=hausd, prescreen=pr,
-                    active=act, topo=tp, incr=inc)
+            # the cycle names its own phases (``cyc.*`` scopes: XLA op
+            # metadata, which obs/devtime reads off the executable)
+            return adapt_cycle_impl(
+                m, k, wave, do_swap=sw, do_smooth=not nomove,
+                do_insert=not noinsert, hausd=hausd, prescreen=pr,
+                active=act, topo=tp, incr=inc)
 
         n_map = stacked.vert.shape[0]            # chunk or g_exec
         waves = jnp.full(n_map, wave, jnp.int32)
@@ -600,9 +600,12 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
             surf = {k: tot[col] for k, col in SURF_COLS.items()}
             # quiet: the row executions the device mask skipped in this
             # dispatch (a job's sum of them is groups.cond_skipped)
+            # prog: which of the block programs this process lowered
+            # the dispatch ran (obs/devtime joins a capture's device ops
+            # with that program's scope map)
             sp.set(split=tot[0], collapse=tot[1], swap=tot[2],
                    moved=tot[3], quiet=sched.cond_skipped - skipped0,
-                   **surf)
+                   prog=LEDGER.program_index(BLOCK_ENTRY), **surf)
         if not chunk:
             # "compute" as the chunk pipeline records it: the seconds
             # from dispatch to counter pull
@@ -798,16 +801,9 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     # caller threaded a stats/timers object through
     from ..obs.metrics import REGISTRY
     REGISTRY.counter("groups.dispatches").inc(sched.dispatches)
-    REGISTRY.counter("groups.dispatches_saved").inc(
-        sched.saved_dispatches)
-    REGISTRY.counter("groups.group_blocks_skipped").inc(
-        sched.skipped_group_blocks)
     # group-slot executions the device-resident quiet mask cond-skipped
     # (unchunked quiet slots + padded tail rows of chunk plans)
     REGISTRY.counter("groups.cond_skipped").inc(sched.cond_skipped)
-    REGISTRY.gauge("groups.chunk_recommendation").set(chunk_rec)
-    if overhead is not None:
-        REGISTRY.gauge("groups.chunk_overhead_units").set(overhead)
     for k, v in ltim.acc.items():
         # lint: ok(R6) — k ranges over the fixed _pipeline_chunks
         # segment set (upload/compute/download/writeback): bounded

@@ -29,6 +29,7 @@ stack bounds and observes its compile count; this module is that layer:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import os
@@ -95,13 +96,21 @@ class EntryStats:
     compiles: int = 0              # backend-compile events attributed here
     compile_secs: float = 0.0
     keys_seen: set = dataclasses.field(default_factory=set)
-    keys_compiled: set = dataclasses.field(default_factory=set)
+    # static-shape key -> backend-compile seconds credited to calls of
+    # that key (its keys are the keys that compiled)
+    keys_compiled: dict = dataclasses.field(default_factory=dict)
     last_key: tuple = ()
     # (program, static-shape key) -> the placement each argument leaf
     # had when the program was first lowered for that key
     # (:meth:`CompileLedger._on_lowering`)
     lowered: dict = dataclasses.field(default_factory=dict)
     placement_variants: int = 0
+    # static-shape key -> (the entry's function, the abstract signature
+    # of the call a program was lowered inside, the default device it
+    # was lowered under), in the order the keys were first lowered: what
+    # ``obs.devtime.scope_map`` lowers again to reach the executable
+    # that ran.  Kept once a lowering, never on a later call
+    signatures: dict = dataclasses.field(default_factory=dict)
 
     @property
     def variants(self) -> int:
@@ -143,6 +152,14 @@ class CompileLedger:
     of its executable carries ``variant="placement"`` and ``leaves``,
     the paths of the arguments that differ.  ``compile.block_programs``
     counts the executables of :data:`BLOCK_ENTRY`, built or loaded.
+
+    Kept signatures.  At a lowering, and never on a later call, the
+    entry keeps the call's abstract signature under its static-shape key
+    (:func:`_abstract`: shapes, dtypes, shardings, statics) beside the
+    function it called: ``obs.devtime.scope_map`` lowers from it again
+    to reach the executable that ran, and :meth:`program_index` says
+    which of an entry's programs a call ran (a ``grp block`` span's
+    ``prog``).
     """
 
     UNGOVERNED = "(ungoverned)"
@@ -233,16 +250,25 @@ class CompileLedger:
             e.compiles += 1
             e.compile_secs += float(duration)
             if stack:
-                e.keys_compiled.add(stack[-1].key)
+                key = stack[-1].key
+                e.keys_compiled[key] = \
+                    e.keys_compiled.get(key, 0.0) + float(duration)
 
     def _on_lowering(self, scope: "_TrackScope", fun: str) -> None:
         """A program was lowered inside the governed call ``scope``: if
         the entry lowered ``fun`` for this static-shape key before and
         the leaves' placement differs, this lowering exists for the
         placement alone."""
+        import jax
         places = _placement(scope.call)
+        # the default device is part of what jax lowers for: a program
+        # staged on the host (utils/placement.host_staging) takes
+        # uncommitted arguments and runs where the context says
+        kept = (scope.fn, _abstract(scope.call),
+                jax.config.jax_default_device)
         with self._lock:
             e = self._entries.setdefault(scope.name, EntryStats())
+            e.signatures[scope.key] = kept
             first = e.lowered.setdefault((fun, scope.key), places)
             differ = [i for i, (a, b) in enumerate(zip(first, places))
                       if a != b]
@@ -259,10 +285,61 @@ class CompileLedger:
         REGISTRY.counter("compile.placement_variants").inc()
 
     # -- call tracking ------------------------------------------------------
-    def track(self, name: str, key: tuple, call=None) -> "_TrackScope":
-        """``call``: the call's ``(args, kwargs)``, read only if a
-        program is lowered inside the scope (:meth:`_on_lowering`)."""
-        return _TrackScope(self, name, key, call)
+    def track(self, name: str, key: tuple, call=None,
+              fn=None) -> "_TrackScope":
+        """``call``: the call's ``(args, kwargs)``, and ``fn`` the
+        function it calls: read only if a program is lowered inside the
+        scope (:meth:`_on_lowering`)."""
+        return _TrackScope(self, name, key, call, fn)
+
+    def signature(self, name: str, key: tuple | None = None):
+        """(key, function, abstract ``(args, kwargs)``, default device)
+        kept when ``name`` lowered a program for ``key`` (its last
+        call's key by default, the last lowered one if that call lowered
+        nothing)."""
+        with self._lock:
+            e = self._entries.get(name)
+            if e is None or not e.signatures:
+                raise KeyError(f"{name}: no program lowered in this process")
+            if key is None:
+                key = e.last_key if e.last_key in e.signatures \
+                    else next(reversed(e.signatures))
+            return (key,) + e.signatures[key]
+
+    def compile_seconds(self, name: str, key: tuple) -> float:
+        """Backend-compile seconds credited to ``name``'s calls of
+        ``key``: what building that program again would cost."""
+        with self._lock:
+            e = self._entries.get(name)
+            return e.keys_compiled.get(key, 0.0) if e is not None else 0.0
+
+    def lowered_keys(self, name: str) -> list:
+        """The static-shape keys ``name`` lowered a program for, in the
+        order :meth:`program_index` counts them."""
+        with self._lock:
+            e = self._entries.get(name)
+            return list(e.signatures) if e is not None else []
+
+    def program_index(self, name: str) -> int | None:
+        """Which of the programs ``name`` lowered its last call ran: the
+        index of that call's key among the lowered keys, in the order
+        they were first lowered (None: the call lowered none)."""
+        with self._lock:
+            e = self._entries.get(name)
+            if e is None or e.last_key not in e.signatures:
+                return None
+            return list(e.signatures).index(e.last_key)
+
+    @contextlib.contextmanager
+    def ungoverned(self):
+        """No governed entry on this thread for the block: a compile
+        inside it is credited to none (``obs.devtime.scope_map``)."""
+        stack = getattr(self._tls, "stack", None)
+        self._tls.stack = []
+        try:
+            yield
+        finally:
+            self._tls.stack = stack if stack is not None else []
 
     # -- reporting ----------------------------------------------------------
     def snapshot(self) -> dict:
@@ -317,6 +394,9 @@ class CompileLedger:
                 e.last_key = ()
                 e.lowered.clear()
                 e.placement_variants = 0
+                # ``signatures`` stay: they say what jax's caches hold,
+                # which a reset of the accounting does not drop, and a
+                # later call of a program lowers nothing to keep anew
 
 
 class _TrackScope:
@@ -324,14 +404,15 @@ class _TrackScope:
     governed entry (one instance per call — the steady-state loop calls
     governed entries every iteration, so no per-call class creation)."""
 
-    __slots__ = ("_ledger", "name", "key", "call", "variant")
+    __slots__ = ("_ledger", "name", "key", "call", "fn", "variant")
 
     def __init__(self, ledger: CompileLedger, name: str, key: tuple,
-                 call=None):
+                 call=None, fn=None):
         self._ledger = ledger
         self.name = name
         self.key = key
         self.call = call
+        self.fn = fn
         # (program, differing leaves) of a placement-only lowering whose
         # executable's ``compile`` event is still to come
         self.variant = None
@@ -386,6 +467,22 @@ def _placement(call) -> tuple:
                  for leaf in jax.tree_util.tree_leaves(call))
 
 
+def _abstract(call):
+    """A call's ``(args, kwargs)`` with every array leaf as what jax keys
+    a lowering on (shape, dtype, weak type, and the sharding of a
+    committed array); static leaves stay what they are."""
+    import jax
+
+    def leaf(x):
+        if not (hasattr(x, "shape") and hasattr(x, "dtype")):
+            return x
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype,
+            sharding=x.sharding if getattr(x, "_committed", False) else None,
+            weak_type=bool(getattr(x, "weak_type", False)))
+    return jax.tree_util.tree_map(leaf, call)
+
+
 def governed(name: str, budget: int | None = None, key_fn=None):
     """Register a (usually jitted) entry point with the compile ledger.
 
@@ -403,7 +500,7 @@ def governed(name: str, budget: int | None = None, key_fn=None):
         def wrapper(*args, **kwargs):
             key = key_fn(*args, **kwargs) if key_fn is not None \
                 else _static_key(args, kwargs)
-            with LEDGER.track(name, key, (args, kwargs)):
+            with LEDGER.track(name, key, (args, kwargs), fn):
                 return fn(*args, **kwargs)
 
         wrapper.__wrapped__ = fn

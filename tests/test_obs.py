@@ -518,16 +518,31 @@ def grouped_job(tmp_path_factory):
                 "counters": {k: after[k] - before.get(k, 0.0)
                              for k in after}}
 
+    # what the map and the digest cost a job that did not ask for them:
+    # count the calls of both (obs/devtime)
+    from parmmg_tpu.obs import devtime
+    asked = {"scope_map": 0, "digest": 0}
+
+    def counting(name, inner):
+        def call(*a, **k):
+            asked[name] += 1
+            return inner(*a, **k)
+        return call
+
     mp = pytest.MonkeyPatch()
     try:
+        for name in asked:
+            mp.setattr(devtime, name, counting(name, getattr(devtime, name)))
         # cold, with the pass checkpoints armed
         mp.setenv("PARMMG_CKPT_DIR", str(tmp_path_factory.mktemp("ckpt")))
         cold = job()
+        cold["asked"] = dict(asked)
         mp.delenv("PARMMG_CKPT_DIR")
         # warm, inside the operator's capture
         prof = str(tmp_path_factory.mktemp("prof"))
         mp.setenv("PARMMG_PROFILE_DIR", prof)
         warm = job()
+        warm["asked"] = dict(asked)
     finally:
         mp.undo()
         otrace.TRACER.reset()
@@ -695,3 +710,101 @@ def test_operator_capture_holds_the_program_spans(grouped_job):
     kinds = [r["name"] for r in grouped_job["warm"]["records"]
              if r.get("name", "").startswith("profile_")]
     assert kinds == ["profile_start", "profile_stop"]
+
+
+def _device_phases(records):
+    return [r for r in records if r.get("name") == "device_phases"]
+
+
+def test_a_job_without_the_capture_asks_for_no_map_and_no_digest(
+        grouped_job):
+    """``scope_map`` and ``digest`` cost nothing unless called, and no
+    job calls them unless ``PARMMG_PROFILE_DIR`` armed the capture."""
+    assert grouped_job["cold"]["asked"] == {"scope_map": 0, "digest": 0}
+    assert _device_phases(grouped_job["cold"]["records"]) == []
+    assert grouped_job["warm"]["asked"]["digest"] == 1
+    assert grouped_job["warm"]["asked"]["scope_map"] >= 1
+    # the maps came out of jax's own caches: no compile was theirs
+    stop = next(i for i, r in enumerate(grouped_job["warm"]["records"])
+                if r.get("name") == "profile_stop")
+    assert not [r for r in grouped_job["warm"]["records"][stop:]
+                if r.get("name") == "compile"]
+
+
+def test_the_capture_is_digested_into_one_device_phases_event(grouped_job):
+    recs, by_id = _tree(grouped_job["warm"]["records"])
+    events = _device_phases(grouped_job["warm"]["records"])
+    assert len(events) == 1
+    ev = events[0]
+    assert by_id[ev["parent"]]["name"] == "run"
+    assert ev["phases"] and all(p.startswith("cyc.") for p in ev["phases"])
+    assert {"cyc.table", "cyc.split", "cyc.collapse", "cyc.smooth",
+            "cyc.adjacency"} <= set(ev["phases"])
+    assert sum(ev["phases"].values()) + ev["unscoped"] == \
+        pytest.approx(ev["block_s"])
+    assert ev["block_s"] > 0 and ev["busy_s"] > 0
+    # a table's seconds are part of the phase they ran in
+    assert set(ev["tables"]) == {"tab.edges", "tab.adjacency"}
+    for rows in ev["tables"].values():
+        assert all(0 < s <= ev["phases"][p] for p, s in rows.items())
+    # the merged polish and the fem rounds run on the same backend
+    # here: their stages too, and a row a wave, a row a round
+    for field, prefix, span in (("polish", "pol.", "polish wave"),
+                                ("fem", "fem.", "fem round")):
+        part = ev[field]
+        assert part["phases"] and all(p.startswith(prefix)
+                                      for p in part["phases"])
+        spans = [r for r in recs if r["name"] == span]
+        assert len(part["rows"]) == len(spans) > 0
+        assert [r["wave"] for r in part["rows"]] == \
+            [r["wave"] for r in spans]
+        assert sum(r["device_s"] for r in part["rows"]) + \
+            part["outside"] == pytest.approx(part["total"])
+    assert set(ev["fem"]["phases"]) == {"fem.split", "fem.bdytags",
+                                        "fem.adjacency"}
+    assert ev["digest_s"] > 0
+
+
+def test_the_digest_has_a_row_a_block_and_the_rows_add_up(grouped_job):
+    recs, _ = _tree(grouped_job["warm"]["records"])
+    blocks = [r for r in recs if r["name"] == "grp block"]
+    ev = _device_phases(grouped_job["warm"]["records"])[0]
+    assert len(ev["blocks"]) == len(blocks) > 0
+    for row, span in zip(ev["blocks"], blocks):
+        assert (row["pass"], row["block"]) == (span["pass"], span["block"])
+        assert row["prog"] == span["prog"]
+        # (no bound by the span's seconds here: the CPU backend runs a
+        # program's ops on several threads, and their seconds add up)
+        assert row["device_s"] > 0
+    assert sum(r["device_s"] for r in ev["blocks"]) == \
+        pytest.approx(ev["block_s"])
+    # a swap-inclusive block has the swap phases, a plain one has not
+    with_swap = [r for r in ev["blocks"] if "cyc.swap_edges" in r["phases"]]
+    assert 0 < len(with_swap) < len(blocks)
+
+
+def test_a_block_span_says_which_program_it_ran(grouped_job):
+    """``prog``: the index of the lowered key a dispatch ran; the second
+    pass of this job runs groups of another capacity, so another one.
+    A polish wave and a fem round say so too."""
+    for which in ("cold", "warm"):
+        recs, _ = _tree(grouped_job[which]["records"])
+        progs = [r["prog"] for r in recs if r["name"] == "grp block"]
+        assert progs and all(isinstance(p, int) for p in progs)
+        # (an index among ALL the block programs this process lowered:
+        # 0 and 1 in a process of its own)
+        assert progs == sorted(progs) and progs[-1] == progs[0] + 1
+        for span in ("polish wave", "fem round"):
+            progs = [r["prog"] for r in recs if r["name"] == span]
+            assert progs and all(isinstance(p, int) for p in progs)
+
+
+def test_a_kept_capture_prints_the_same_table(grouped_job, capsys):
+    """``python3 -m parmmg_tpu.obs.devtime <dir>``: the program left its
+    maps beside the capture."""
+    from parmmg_tpu.obs import devtime
+    assert devtime.main([grouped_job["profile_dir"]]) == 0
+    out = capsys.readouterr().out
+    assert "device seconds by phase, jit_run" in out
+    assert "cyc.adjacency" in out and "tab.edges" in out
+    assert devtime.main([grouped_job["profile_dir"] + "/nothing"]) == 1
