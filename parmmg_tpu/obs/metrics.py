@@ -253,7 +253,9 @@ def publish_stats(stats, registry: MetricsRegistry | None = None) -> None:
     # among them too: "the hausd test refused nothing" is a reading
     for name, v in (("surf.bsplit", stats.nbsplit),
                     ("surf.hveto", stats.nhveto),
-                    ("surf.bmoved", stats.nbmoved)):
+                    ("surf.bmoved", stats.nbmoved),
+                    ("surf.listed", stats.nlisted),
+                    ("surf.list_full", stats.nlist_full)):
         reg.counter(name, tenant=t).inc(v)
     reg.gauge("adapt.status", tenant=t).set(float(stats.status))
     for k, v in stats.sched_extra.items():

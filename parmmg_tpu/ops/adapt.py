@@ -35,10 +35,29 @@ from .smooth import smooth_wave
 
 
 # columns of a cycle's counts row that say what the surface machinery
-# did (adapt_cycle_impl), and the column the incremental topology engine
-# appends after them
+# did (adapt_cycle_impl), the column the incremental topology engine
+# appends after them, and the one that holds the live updates the cycle's
+# surface lists held (ops/surflist; 0 where the scatters ran full width)
 SURF_COLS = {"bsplit": 8, "hveto": 9, "bmoved": 10}
 DIRTY_COL = 11
+LISTED_COL = 7
+
+
+def surface_scatter_width(capT: int, insert: bool = True,
+                          smooth: bool = True, hausd=None) -> int:
+    """The indices a cycle's surface scatters have at full width on a
+    mesh of ``capT`` rows, the ones a cycle may skip (the boundary tags
+    after a collapse that changed no surface, the second form of a mesh
+    with no curved patch) included: what ``counts[LISTED_COL]`` is a
+    share of.  12 ``capT`` each for the vertex normals, the ridge
+    tangents (two ends of 6 ``capT`` edge rows), the boundary tags and
+    the smoother's surface sums, 4 ``capT`` for the second form."""
+    n = 0
+    if insert:
+        n += 12 + (24 if hausd is not None else 0)
+    if smooth:
+        n += 12 + (4 if hausd is not None else 0)
+    return n * capT
 
 
 @dataclass
@@ -55,6 +74,11 @@ class AdaptStats:
     nbsplit: int = 0
     nhveto: int = 0
     nbmoved: int = 0
+    # the live updates the cycles' surface lists held, and the indices
+    # the same scatters have at full width (``surface_scatter_width`` a
+    # cycle that listed); both 0 where no program listed
+    nlisted: int = 0
+    nlist_full: int = 0
     # PMMG_SUCCESS unless the run degraded (failed_handling contract:
     # PMMG_LOWFAILURE = something failed but a conforming mesh is saved)
     status: int = 0
@@ -87,7 +111,8 @@ class AdaptStats:
         self.nmoved += other.nmoved
         self.cycles += other.cycles
         self.regrows += other.regrows
-        self.add_surface(other.nbsplit, other.nhveto, other.nbmoved)
+        self.add_surface(other.nbsplit, other.nhveto, other.nbmoved,
+                         other.nlisted, other.nlist_full)
         self.status = max(self.status, other.status)
         self.group_dispatches += other.group_dispatches
         self.group_dispatches_saved += other.group_dispatches_saved
@@ -102,10 +127,13 @@ class AdaptStats:
                 self.sched_extra[kk] = self.sched_extra.get(kk, 0.0) + v
         return self
 
-    def add_surface(self, bsplit=0, hveto=0, bmoved=0) -> None:
+    def add_surface(self, bsplit=0, hveto=0, bmoved=0, listed=0,
+                    list_full=0) -> None:
         self.nbsplit += bsplit
         self.nhveto += hveto
         self.nbmoved += bmoved
+        self.nlisted += listed
+        self.nlist_full += list_full
 
     def publish(self, registry=None) -> None:
         """Publish the counters into the obs metrics registry
@@ -122,7 +150,7 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
                      hausd: float | None = None,
                      budget_div: int = 8,
                      prescreen: bool = True, active=None,
-                     topo=None, incr=None):
+                     topo=None, incr=None, surf_list: bool | None = None):
     """One adaptation cycle: split -> collapse -> [swap] -> [smooth].
 
     Pure jittable function (jitted wrapper below) — also the compile-check
@@ -145,7 +173,7 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
 
     Returns (mesh, met, counts) with ``counts`` = int32
     [nsplit, ncollapse, nswap, nmoved, overflow, live_tets, deferred,
-    narrow_abort, bsplit, hveto, bmoved] stacked in ONE device array
+    listed, bsplit, hveto, bmoved] stacked in ONE device array
     (``SURF_COLS``: of the splits those of boundary edges, the collapse
     candidates the hausd test refused, of the moves those of surface
     vertices — what the surface machinery did): the host reads all
@@ -154,9 +182,16 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     count op on the host would fight the donated input buffers).
     ``deferred`` = top-K budget cuts of viable candidates, encoded as
     2 bits: bit 0 = an INSERTION wave (split/collapse) deferred,
-    bit 1 = a SWAP wave deferred.  ``narrow_abort`` is always 0.
-    Neither column has a reader (ROADMAP D4); the row keeps its layout
-    because ``SURF_COLS`` and ``DIRTY_COL`` index it.
+    bit 1 = a SWAP wave deferred; it has no reader (ROADMAP D4), and
+    the row keeps its layout because ``SURF_COLS``, ``LISTED_COL`` and
+    ``DIRTY_COL`` index it.  ``listed`` (``LISTED_COL``): the live
+    updates the cycle's surface lists held.
+
+    ``surf_list``: whether the surface scatters (vertex normals, ridge
+    tangents, boundary tags, the smoother's surface sums and second
+    form) run over lists of their live updates (ops/surflist).  None
+    observes it: they do where the program is placed on a TPU.  Static;
+    the outputs are the full-width scatters' either way.
 
     ``active``: optional traced scalar bool — the device-resident
     quiet-mask hook of the grouped paths (parallel/sched.py).  When
@@ -195,7 +230,8 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
                 m, k, wave, do_swap=do_swap, do_smooth=do_smooth,
                 smooth_waves=smooth_waves, do_insert=do_insert,
                 hausd=hausd, budget_div=budget_div,
-                prescreen=prescreen, topo=tp, incr=incr)
+                prescreen=prescreen, topo=tp, incr=incr,
+                surf_list=surf_list)
             return out if tp is not None else out + (tp,)
 
         def _skip(ops):
@@ -212,6 +248,8 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
         m, k, counts, tp = jax.lax.cond(active, _run, _skip,
                                         (mesh, met, topo))
         return (m, k, counts) if topo is None else (m, k, counts, tp)
+    from . import surflist
+    lists = surflist.Tally(surf_list)
     defer = jnp.zeros((), bool)
     defer_sw = jnp.zeros((), bool)
     nd0 = (jnp.zeros((), jnp.int32) if topo is None
@@ -241,8 +279,8 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
             from .analysis import boundary_vertex_normals, \
                 ridge_vertex_tangents
             with otrace.scope("cyc.normals"):
-                vtan0 = ridge_vertex_tangents(mesh, et=et)
-                vn0 = boundary_vertex_normals(mesh)
+                vtan0 = ridge_vertex_tangents(mesh, et=et, lists=lists)
+                vn0 = boundary_vertex_normals(mesh, lists=lists)
         # ``prescreen=False`` (adapt_mesh's wide convergence check, the
         # drivers' polish cycles) disables the approximate nomination
         # prescreen so shells it over-vetoed get one exact
@@ -279,8 +317,17 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
         # Skipped when no dying tet donated tags (interior collapses):
         # the propagation pass costs a [12*capT]-index scatter
         with otrace.scope("cyc.bdytags"):
-            mesh = jax.lax.cond(col.surface_changed, boundary_edge_tags,
-                                lambda m: m, col.mesh)
+            if lists.on:
+                mesh, ntags = jax.lax.cond(
+                    col.surface_changed,
+                    partial(surflist.counted, boundary_edge_tags),
+                    lambda m: (m, jnp.zeros((), jnp.int32)), col.mesh)
+                lists.note(ntags)
+            else:
+                mesh = jax.lax.cond(
+                    col.surface_changed,
+                    partial(boundary_edge_tags, lists=lists),
+                    lambda m: m, col.mesh)
         ncol, nhveto = col.ncollapse, col.nhveto
     else:
         # -noinsert: no point insertion or deletion (Mmg contract)
@@ -339,7 +386,7 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
         for w in range(smooth_waves):
             with otrace.scope("cyc.smooth"):
                 sm = smooth_wave(mesh, met, wave=wave * smooth_waves + w,
-                                 hausd=hausd)
+                                 hausd=hausd, lists=lists)
             mesh = sm.mesh
             nmoved = nmoved + jnp.stack([sm.nmoved, sm.nbdy])
 
@@ -353,7 +400,7 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
            overflow.astype(jnp.int32),
            jnp.sum(mesh.tmask, dtype=jnp.int32),
            defer.astype(jnp.int32) + 2 * defer_sw.astype(jnp.int32),
-           jnp.zeros((), jnp.int32),
+           jnp.asarray(lists.listed, jnp.int32),
            nbsplit, nhveto, nmoved[1]]
     if topo is None:
         return mesh, met, jnp.stack(row)
@@ -367,7 +414,7 @@ from ..utils.compilecache import governed as _governed  # noqa: E402
 adapt_cycle = _governed("adapt.cycle")(
     partial(jax.jit, static_argnames=(
         "do_swap", "do_smooth", "smooth_waves", "do_insert",
-        "hausd", "budget_div", "prescreen"),
+        "hausd", "budget_div", "prescreen", "surf_list"),
         donate_argnums=(0, 1))(adapt_cycle_impl))
 
 
@@ -697,8 +744,12 @@ def adapt_mesh(mesh: Mesh, met: jax.Array, max_cycles: int = 50,
             prescreen=not wide_check)
         cnt = np.asarray(counts)
         ns, nc, nw, nm, ovf = (int(v) for v in cnt[:5])
-        stats.add_surface(**{k: int(cnt[col])
-                             for k, col in SURF_COLS.items()})
+        listed = int(cnt[LISTED_COL])
+        stats.add_surface(
+            **{k: int(cnt[col]) for k, col in SURF_COLS.items()},
+            listed=listed, list_full=surface_scatter_width(
+                mesh.capT, not noinsert, not nomove, hausd
+            ) if listed else 0)
         stats.nsplit += ns
         stats.ncollapse += nc
         stats.nswap += nw

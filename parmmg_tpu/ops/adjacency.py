@@ -178,9 +178,15 @@ def check_adjacency(mesh: Mesh) -> dict:
     return {"asymmetric": int(sym_bad), "face_mismatch": int(face_bad)}
 
 
-def boundary_edge_tags(mesh: Mesh) -> Mesh:
-    """Propagate MG_BDY from boundary faces to their edges and vertices."""
+def boundary_edge_tags(mesh: Mesh, lists=None) -> Mesh:
+    """Propagate MG_BDY from boundary faces to their edges and vertices.
+
+    ``lists``: an ``ops/surflist.Tally`` (default: one that observes
+    where the program is placed); where it is on, the vertex scatter
+    runs over the listed boundary faces alone."""
     from ..core.constants import FACE_EDGES
+    from . import surflist
+    lists = surflist.Tally() if lists is None else lists
     fe = jnp.asarray(FACE_EDGES)                     # [4,3]
     is_bdy_face = (mesh.ftag & MG_BDY) != 0          # [T,4]
     # edges of boundary faces get MG_BDY
@@ -197,11 +203,27 @@ def boundary_edge_tags(mesh: Mesh) -> Mesh:
     from ..core.constants import IDIR
     vtag = mesh.vtag
     capP = mesh.capP
-    vids_all = jnp.concatenate(
-        [mesh.tet[:, jnp.asarray(IDIR[f])].reshape(-1) for f in range(4)])
-    m_all = jnp.concatenate(
-        [jnp.repeat(is_bdy_face[:, f] & mesh.tmask, 3) for f in range(4)])
-    hit = jnp.zeros(capP + 1, bool).at[
-        jnp.where(m_all, vids_all, capP)].max(m_all, mode="drop")
+    if lists.on:
+        # a face's three vertices go together: a max takes any order
+        live = surflist.Live(jnp.concatenate(
+            [is_bdy_face[:, f] & mesh.tmask for f in range(4)]))
+        lists.note(3 * live.count)
+
+        def updates(p, ok):
+            fv = surflist.face_vertices(mesh.tet[p % mesh.capT],
+                                        p // mesh.capT)
+            idx = jnp.where(ok[:, None], fv, capP).reshape(-1)
+            return idx, jnp.ones(idx.shape, bool)
+        hit = surflist.staged_scatter(jnp.zeros(capP + 1, bool), live,
+                                      updates, op="max")
+    else:
+        vids_all = jnp.concatenate(
+            [mesh.tet[:, jnp.asarray(IDIR[f])].reshape(-1)
+             for f in range(4)])
+        m_all = jnp.concatenate(
+            [jnp.repeat(is_bdy_face[:, f] & mesh.tmask, 3)
+             for f in range(4)])
+        hit = jnp.zeros(capP + 1, bool).at[
+            jnp.where(m_all, vids_all, capP)].max(m_all, mode="drop")
     vtag = jnp.where(hit[:capP], vtag | MG_BDY, vtag)
     return dataclasses_replace(mesh, etag=etag, vtag=vtag)

@@ -50,7 +50,7 @@ class AnalysisResult(NamedTuple):
     vnormal: jax.Array    # [capP, 3] unit vertex normals (0 off-surface)
 
 
-def boundary_vertex_normals(mesh: Mesh) -> jax.Array:
+def boundary_vertex_normals(mesh: Mesh, lists=None) -> jax.Array:
     """[capP,3] unit outward vertex normals from true-boundary faces.
 
     Weighted average over incident MG_BDY (non-PARBDY) faces via ONE
@@ -59,30 +59,81 @@ def boundary_vertex_normals(mesh: Mesh) -> jax.Array:
     collapse candidate; Mmg instead stores xPoint normals, norver).
     Zeros off-surface.  Where the mesh carries a normal (a surface
     vertex on a frozen seam) that one stands.
+
+    ``lists``: an ``ops/surflist.Tally`` (default: one that observes
+    where the program is placed); where it is on, the scatter and the
+    face geometry run over the listed (face, corner) records alone.
     """
     import jax.numpy as jnp
     from ..core.constants import IDIR, MG_BDY, MG_PARBDY, EPSD
+    from . import surflist
+    lists = surflist.Tally() if lists is None else lists
     capP = mesh.capP
     idir = jnp.asarray(IDIR)
     isb = ((mesh.ftag & MG_BDY) != 0) & ((mesh.ftag & MG_PARBDY) == 0) & \
         mesh.tmask[:, None]
-    fv = mesh.tet[:, idir]                                 # [T,4,3]
-    fp = mesh.vert[fv]                                     # [T,4,3,3]
-    ea, eb = fp[:, :, 1] - fp[:, :, 0], fp[:, :, 2] - fp[:, :, 0]
-    fn = jnp.cross(ea, eb)
-    wgt = corner_weights(ea, eb)
-    idx12 = jnp.concatenate(
-        [jnp.where(isb[:, f], fv[:, f, k], capP)
-         for f in range(4) for k in range(3)])
-    pay12 = jnp.concatenate([fn[:, f] * wgt[:, f, k, None]
-                             for f in range(4) for k in range(3)])
-    nacc = jnp.zeros((capP + 1, 3), mesh.vert.dtype).at[idx12].add(
-        pay12, mode="drop")[:capP]
+    if lists.on:
+        live = face_corner_list(isb, lists)
+
+        def updates(p, ok):
+            fc = FaceCorner(mesh, p)
+            fn = jnp.cross(fc.ea, fc.eb)
+            return jnp.where(ok, fc.vid, capP), fn * fc.weight[:, None]
+        nacc = surflist.staged_scatter(
+            jnp.zeros((capP + 1, 3), mesh.vert.dtype), live,
+            updates)[:capP]
+    else:
+        fv = mesh.tet[:, idir]                             # [T,4,3]
+        fp = mesh.vert[fv]                                 # [T,4,3,3]
+        ea, eb = fp[:, :, 1] - fp[:, :, 0], fp[:, :, 2] - fp[:, :, 0]
+        fn = jnp.cross(ea, eb)
+        wgt = corner_weights(ea, eb)
+        idx12 = jnp.concatenate(
+            [jnp.where(isb[:, f], fv[:, f, k], capP)
+             for f in range(4) for k in range(3)])
+        pay12 = jnp.concatenate([fn[:, f] * wgt[:, f, k, None]
+                                 for f in range(4) for k in range(3)])
+        nacc = jnp.zeros((capP + 1, 3), mesh.vert.dtype).at[idx12].add(
+            pay12, mode="drop")[:capP]
     vn = nacc / (jnp.linalg.norm(nacc, axis=-1, keepdims=True) + EPSD)
     # a frozen seam vertex's fan is cut by the seam: this mesh holds the
     # faces of one side only and their sum is tilted towards it; the
     # split carried the whole fan's normal (distribute.split_to_shards)
     return jnp.where(carries_normal(mesh)[:, None], mesh.vnrm, vn)
+
+
+def face_corner_list(isb: jax.Array, lists):
+    """The ``ops/surflist.Live`` list of the (face, corner) records of
+    the faces ``isb`` [capT, 4], in the order of the 12-fold
+    concatenation the full-width scatters use: position
+    (3 f + k) capT + t for corner k of face f of tet t.  Counted into
+    ``lists``."""
+    from . import surflist
+    live = surflist.Live(jnp.concatenate(
+        [isb[:, f] for f in range(4) for _ in range(3)]))
+    lists.note(live.count)
+    return live
+
+
+class FaceCorner:
+    """What a chunk of :func:`face_corner_list` positions ``p`` [c]
+    names, computed at the chunk's width: tet ``t``, face ``f``, corner
+    ``k`` [c]; the face's vertex ids ``fv`` [c, 3] and points ``fp``
+    [c, 3, 3]; its edge vectors ``ea``, ``eb`` [c, 3] (the normal is
+    ``ea x eb``); the corner's vertex ``vid`` and its
+    :func:`corner_weights` ``weight`` [c]."""
+
+    def __init__(self, mesh: Mesh, p: jax.Array):
+        from . import surflist
+        j, self.t = p // mesh.capT, p % mesh.capT
+        self.f, self.k = j // 3, j % 3
+        self.fv = surflist.face_vertices(mesh.tet[self.t], self.f)
+        self.fp = mesh.vert[self.fv]                       # [c,3,3]
+        self.ea = self.fp[:, 1] - self.fp[:, 0]
+        self.eb = self.fp[:, 2] - self.fp[:, 0]
+        self.vid = surflist.take(self.fv, self.k)
+        self.weight = surflist.take(corner_weights(self.ea, self.eb),
+                                    self.k)
 
 
 def corner_weights(ea: jax.Array, eb: jax.Array):
@@ -131,8 +182,35 @@ class SecondForm(NamedTuple):
                 + self.form[:, 2] * v * v)
 
 
+def _second_form_moments(p: jax.Array, n: jax.Array, isb: jax.Array):
+    """[T, 4, 22] the moments of :func:`boundary_second_form`'s fit that
+    each tet adds at each of its corners: ``p``, ``n`` [T, 4, 3] the
+    corners' points and unit normals, ``isb`` [T, 4] the tet's faces
+    that make the surface."""
+    from ..core.constants import EPSD
+    e1, e2 = tangent_basis(n)
+    # the spoke from corner k to corner j bounds the tet's faces f
+    # other than k and j: as many readings as of those are surface
+    nb = isb.astype(p.dtype)
+    off = 1.0 - jnp.eye(4, dtype=p.dtype)
+    wkj = (jnp.sum(nb, -1)[:, None, None] - nb[:, :, None]
+           - nb[:, None, :]) * off                         # [T,4,4]
+    d = p[:, None, :, :] - p[:, :, None, :]                # [T,k,j,3]
+    ll = jnp.maximum(jnp.sum(d * d, -1), EPSD)             # [T,4,4]
+    ln = jnp.sqrt(ll)
+    y = -2.0 * jnp.sum(d * n[:, :, None, :], -1) / ln      # l kappa
+    u = jnp.sum(d * e1[:, :, None, :], -1)
+    v = jnp.sum(d * e2[:, :, None, :], -1)
+    lt = jnp.sqrt(u * u + v * v) + EPSD
+    u, v = u / lt, v / lt
+    phi = (ln * u * u, 2.0 * ln * u * v, ln * v * v, 2.0 * u, 2.0 * v)
+    mom = [phi[i] * phi[j] for i in range(5) for j in range(i, 5)] + \
+        [f * y for f in phi] + [ll, jnp.ones_like(ll)]
+    return jnp.sum(wkj[..., None] * jnp.stack(mom, -1), axis=2)
+
+
 def boundary_second_form(mesh: Mesh, vn: jax.Array,
-                         isb: jax.Array | None = None):
+                         isb: jax.Array | None = None, lists=None):
     """The surface's second fundamental form at every boundary vertex,
     fitted over its fan, and the normal the fit corrects: a
     ``SecondForm`` (``form`` [capP, 3] = (a, b, c), ``e1``, ``e2``,
@@ -163,38 +241,36 @@ def boundary_second_form(mesh: Mesh, vn: jax.Array,
     ``vn`` + delta, unit.  The fit's error is of the order of the
     surface's third derivative times the fan's asymmetry times a
     spoke's length.  One gather of ``vn`` at the tets' corners and one
-    scatter of the fit's moments.
+    scatter of the fit's moments; with ``lists`` on (an
+    ``ops/surflist.Tally``, default: one that observes where the program
+    is placed) both over the tets that hold a face of ``isb`` alone: a
+    tet that holds none adds zeros.
     """
     from ..core.constants import EPSD, MG_PARBDY
     capP = mesh.capP
     if isb is None:
         isb = ((mesh.ftag & MG_BDY) != 0) & \
             ((mesh.ftag & MG_PARBDY) == 0) & mesh.tmask[:, None]
-    tv = mesh.tet
-    p = mesh.vert[tv]                                      # [T,4,3]
-    n = vn[tv]                                             # [T,4,3]
-    e1, e2 = tangent_basis(n)
-    # the spoke from corner k to corner j bounds the tet's faces f
-    # other than k and j: as many readings as of those are surface
-    nb = isb.astype(mesh.vert.dtype)
-    off = 1.0 - jnp.eye(4, dtype=mesh.vert.dtype)
-    wkj = (jnp.sum(nb, -1)[:, None, None] - nb[:, :, None]
-           - nb[:, None, :]) * off                         # [T,4,4]
-    d = p[:, None, :, :] - p[:, :, None, :]                # [T,k,j,3]
-    ll = jnp.maximum(jnp.sum(d * d, -1), EPSD)             # [T,4,4]
-    ln = jnp.sqrt(ll)
-    y = -2.0 * jnp.sum(d * n[:, :, None, :], -1) / ln      # l kappa
-    u = jnp.sum(d * e1[:, :, None, :], -1)
-    v = jnp.sum(d * e2[:, :, None, :], -1)
-    lt = jnp.sqrt(u * u + v * v) + EPSD
-    u, v = u / lt, v / lt
-    phi = (ln * u * u, 2.0 * ln * u * v, ln * v * v, 2.0 * u, 2.0 * v)
-    mom = [phi[i] * phi[j] for i in range(5) for j in range(i, 5)] + \
-        [f * y for f in phi] + [ll, jnp.ones_like(ll)]
-    pay = jnp.sum(wkj[..., None] * jnp.stack(mom, -1), axis=2)  # [T,4,22]
-    idx4 = jnp.where(mesh.tmask[:, None], tv, capP).reshape(-1)
-    acc = jnp.zeros((capP + 1, 22), mesh.vert.dtype).at[idx4].add(
-        pay.reshape(-1, 22), mode="drop")[:capP]
+    from . import surflist
+    lists = surflist.Tally() if lists is None else lists
+    if lists.on:
+        live = surflist.Live(mesh.tmask & jnp.any(isb, axis=1))
+        lists.note(4 * live.count)
+
+        def updates(t, ok):
+            tv = mesh.tet[t]
+            pay = _second_form_moments(mesh.vert[tv], vn[tv], isb[t])
+            return (jnp.where(ok[:, None], tv, capP).reshape(-1),
+                    pay.reshape(-1, 22))
+        acc = surflist.staged_scatter(
+            jnp.zeros((capP + 1, 22), mesh.vert.dtype), live,
+            updates)[:capP]
+    else:
+        tv = mesh.tet
+        pay = _second_form_moments(mesh.vert[tv], vn[tv], isb)
+        idx4 = jnp.where(mesh.tmask[:, None], tv, capP).reshape(-1)
+        acc = jnp.zeros((capP + 1, 22), mesh.vert.dtype).at[idx4].add(
+            pay.reshape(-1, 22), mode="drop")[:capP]
     cnt = jnp.maximum(acc[:, 21], 1.0)
     # the fan's own length scale makes the five columns alike in size
     # (1 where there is no fan: zero moments then give the zero form
@@ -333,7 +409,7 @@ def ridge_vertex_normals(mesh: Mesh):
     return n1, n2
 
 
-def ridge_vertex_tangents(mesh: Mesh, et=None) -> jax.Array:
+def ridge_vertex_tangents(mesh: Mesh, et=None, lists=None) -> jax.Array:
     """[capP, 3] unit tangent of the feature (ridge/ref) line at each
     MG_GEO/MG_REF vertex; zeros elsewhere.
 
@@ -344,24 +420,50 @@ def ridge_vertex_tangents(mesh: Mesh, et=None) -> jax.Array:
     PRODUCT of the incident special-edge directions per vertex (sign-
     free) and take the principal eigenvector by a few power iterations —
     exact for <=2 incident feature edges (the ridge-point case).
+
+    ``lists``: an ``ops/surflist.Tally`` (default: one that observes
+    where the program is placed); where it is on, the special edges are
+    listed and the sum runs over them alone, first ends then second, as
+    the concatenated scatter adds them.
     """
     from ..core.constants import MG_GEO, MG_REF
+    from . import surflist
+    lists = surflist.Tally() if lists is None else lists
     capP = mesh.capP
     if et is None:      # callers on the hot path pass their shared table
         et = unique_edges(mesh)
     special = et.emask & ((et.etag & (MG_GEO | MG_REF)) != 0)
-    va = jnp.clip(et.ev[:, 0], 0, capP - 1)
-    vb = jnp.clip(et.ev[:, 1], 0, capP - 1)
-    d = mesh.vert[vb] - mesh.vert[va]
-    d = d / jnp.maximum(jnp.linalg.norm(d, axis=-1, keepdims=True),
-                        1e-30)
-    outer = d[:, :, None] * d[:, None, :]                 # [E,3,3]
-    pay = jnp.where(special[:, None, None], outer, 0.0).reshape(-1, 9)
-    idx2 = jnp.concatenate([jnp.where(special, va, capP),
-                            jnp.where(special, vb, capP)])
-    M = jnp.zeros((capP + 1, 9), mesh.vert.dtype).at[idx2].add(
-        jnp.concatenate([pay, pay]), mode="drop")[:capP].reshape(
-        capP, 3, 3)
+
+    def outer9(va, vb):
+        d = mesh.vert[vb] - mesh.vert[va]
+        d = d / jnp.maximum(jnp.linalg.norm(d, axis=-1, keepdims=True),
+                            1e-30)
+        return d[:, :, None] * d[:, None, :]              # [E,3,3]
+
+    if lists.on:
+        live = surflist.Live(special)
+        lists.note(2 * live.count)
+
+        def end(side):
+            def updates(e, ok):
+                ev = jnp.clip(et.ev[e], 0, capP - 1)      # [c,2]
+                return (jnp.where(ok, ev[:, side], capP),
+                        outer9(ev[:, 0], ev[:, 1]).reshape(-1, 9))
+            return updates
+        M = jnp.zeros((capP + 1, 9), mesh.vert.dtype)
+        for side in range(2):
+            M = surflist.staged_scatter(M, live, end(side))
+        M = M[:capP].reshape(capP, 3, 3)
+    else:
+        va = jnp.clip(et.ev[:, 0], 0, capP - 1)
+        vb = jnp.clip(et.ev[:, 1], 0, capP - 1)
+        outer = outer9(va, vb)
+        pay = jnp.where(special[:, None, None], outer, 0.0).reshape(-1, 9)
+        idx2 = jnp.concatenate([jnp.where(special, va, capP),
+                                jnp.where(special, vb, capP)])
+        M = jnp.zeros((capP + 1, 9), mesh.vert.dtype).at[idx2].add(
+            jnp.concatenate([pay, pay]), mode="drop")[:capP].reshape(
+            capP, 3, 3)
     has = jnp.trace(M, axis1=1, axis2=2) > 1e-12
     # principal eigenvector by power iteration (M is PSD; 4 steps are
     # plenty for the 2-edge spectrum).  Init with the column under the

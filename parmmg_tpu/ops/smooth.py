@@ -71,7 +71,7 @@ class SmoothResult(NamedTuple):
 def smooth_wave(mesh: Mesh, met: jax.Array, wave: int = 0,
                 relax: float = 1.0,
                 opt_q: float | None = None,
-                hausd: float | None = None) -> SmoothResult:
+                hausd: float | None = None, lists=None) -> SmoothResult:
     """One smoothing wave; see module docstring.
 
     ``hausd``: the surface tolerance (Mmg -hausd).  With it a regular
@@ -88,6 +88,12 @@ def smooth_wave(mesh: Mesh, met: jax.Array, wave: int = 0,
     the min needs lifting (Mmg's bad-element relocation in MMG3D_opttyp
     serves this role).  The relaxation cascade and the exact ball
     min-quality gate are unchanged.
+
+    ``lists``: an ``ops/surflist.Tally`` (default: one that observes
+    where the program is placed); where it is on, the surface sums and
+    the face geometry under them run over the listed (face, corner)
+    records of the boundary faces alone, and the second form's moments
+    over the tets that hold one; the lists' updates are counted into it.
 
     Fixed-point invariant (the quiet-group scheduler's proof rests on
     it, parallel/sched.py): ``nmoved == 0`` iff NO vertex has an
@@ -123,36 +129,56 @@ def smooth_wave(mesh: Mesh, met: jax.Array, wave: int = 0,
     prop = acc4[:capP, :3] / jnp.maximum(acc4[:capP, 3:], 1.0)
 
     # --- surface proposals (movbdyregpt): tangential move on flat patch --
+    from . import surflist
+    from .analysis import (FaceCorner, SecondForm, boundary_second_form,
+                           corner_weights, face_corner_list)
+    lists = surflist.Tally() if lists is None else lists
     idir = jnp.asarray(IDIR)
     isb = ((mesh.ftag & MG_BDY) != 0) & mesh.tmask[:, None]   # [T,4]
-    fv = tv[:, idir]                                       # [T,4,3] vids
-    fp = mesh.vert[fv]                                     # [T,4,3,3]
-    ea, eb = fp[:, :, 1] - fp[:, :, 0], fp[:, :, 2] - fp[:, :, 0]
-    fn = jnp.cross(ea, eb)                                 # [T,4,3] outward
-    fc = jnp.mean(fp, axis=2)                              # [T,4,3]
-    farea = 0.5 * jnp.sqrt(jnp.sum(fn * fn, -1))           # [T,4]
+
     # all 12 (face, corner) contributions in ONE wide scatter:
     # payload = (corner-weighted normal[3], area*centroid[3], area[1],
     #            unit normal[3], count[1]) — the unit-normal sum
     # feeds the gates below with no second full-width pass.  The corner
     # weights are those of analysis.boundary_vertex_normals
-    idx12 = jnp.concatenate(
-        [jnp.where(isb[:, f], fv[:, f, k], capP)
-         for f in range(4) for k in range(3)])
-    w4 = jnp.where(isb, farea, 0.0)                        # [T,4]
-    fn_unit = fn / (jnp.linalg.norm(fn, axis=-1, keepdims=True) + EPSD)
-    pay_f = jnp.concatenate(
-        [w4[..., None] * fc, w4[..., None], fn_unit,
-         jnp.ones_like(w4)[..., None]], axis=-1)           # [T,4,8]
-    from .analysis import (SecondForm, boundary_second_form,
-                           corner_weights)
-    wgt = corner_weights(ea, eb)                           # [T,4,3]
-    pay12 = jnp.concatenate(
-        [jnp.concatenate(
-            [fn[:, f] * wgt[:, f, k, None], pay_f[:, f]], axis=-1)
-         for f in range(4) for k in range(3)])             # [12T,11]
-    sacc = jnp.zeros((capP + 1, 11), mesh.vert.dtype).at[idx12].add(
-        pay12, mode="drop")[:capP]
+    if lists.on:
+        live = face_corner_list(isb, lists)
+
+        def updates(p, ok):
+            c = FaceCorner(mesh, p)
+            fn = jnp.cross(c.ea, c.eb)
+            farea = 0.5 * jnp.sqrt(jnp.sum(fn * fn, -1))[:, None]
+            fn_unit = fn / (jnp.linalg.norm(fn, axis=-1, keepdims=True)
+                            + EPSD)
+            pay = jnp.concatenate(
+                [fn * c.weight[:, None], farea * jnp.mean(c.fp, axis=1),
+                 farea, fn_unit, jnp.ones_like(farea)], axis=-1)
+            return jnp.where(ok, c.vid, capP), pay
+        sacc = surflist.staged_scatter(
+            jnp.zeros((capP + 1, 11), mesh.vert.dtype), live,
+            updates)[:capP]
+    else:
+        fv = tv[:, idir]                                   # [T,4,3] vids
+        fp = mesh.vert[fv]                                 # [T,4,3,3]
+        ea, eb = fp[:, :, 1] - fp[:, :, 0], fp[:, :, 2] - fp[:, :, 0]
+        fn = jnp.cross(ea, eb)                             # [T,4,3] outward
+        fc = jnp.mean(fp, axis=2)                          # [T,4,3]
+        farea = 0.5 * jnp.sqrt(jnp.sum(fn * fn, -1))       # [T,4]
+        idx12 = jnp.concatenate(
+            [jnp.where(isb[:, f], fv[:, f, k], capP)
+             for f in range(4) for k in range(3)])
+        w4 = jnp.where(isb, farea, 0.0)                    # [T,4]
+        fn_unit = fn / (jnp.linalg.norm(fn, axis=-1, keepdims=True) + EPSD)
+        pay_f = jnp.concatenate(
+            [w4[..., None] * fc, w4[..., None], fn_unit,
+             jnp.ones_like(w4)[..., None]], axis=-1)       # [T,4,8]
+        wgt = corner_weights(ea, eb)                       # [T,4,3]
+        pay12 = jnp.concatenate(
+            [jnp.concatenate(
+                [fn[:, f] * wgt[:, f, k, None], pay_f[:, f]], axis=-1)
+             for f in range(4) for k in range(3)])         # [12T,11]
+        sacc = jnp.zeros((capP + 1, 11), mesh.vert.dtype).at[idx12].add(
+            pay12, mode="drop")[:capP]
     nacc, cacc, aacc = sacc[:, :3], sacc[:, 3:6], sacc[:, 6]
     uacc, ucnt = sacc[:, 7:10], sacc[:, 10]
     navg = nacc / (jnp.linalg.norm(nacc, axis=-1, keepdims=True) + EPSD)
@@ -171,10 +197,21 @@ def smooth_wave(mesh: Mesh, met: jax.Array, wave: int = 0,
         # skips the fit, its gather and its scatter
         curved = reg_bdy & ~flat & (ratio >= SMOOTH_RATIO) & (aacc > 0)
         zero3 = jnp.zeros((capP, 3), mesh.vert.dtype)
-        sf = jax.lax.cond(
-            jnp.any(curved),
-            lambda: boundary_second_form(mesh, navg, isb),
-            lambda: SecondForm(zero3, zero3, zero3, navg, zero3[:, 0]))
+
+        def no_fit():
+            return SecondForm(zero3, zero3, zero3, navg, zero3[:, 0])
+        if lists.on:
+            sf, nfit = jax.lax.cond(
+                jnp.any(curved),
+                lambda: surflist.counted(boundary_second_form, mesh, navg,
+                                         isb),
+                lambda: (no_fit(), jnp.zeros((), jnp.int32)))
+            lists.note(nfit)
+        else:
+            sf = jax.lax.cond(
+                jnp.any(curved),
+                lambda: boundary_second_form(mesh, navg, isb, lists=lists),
+                no_fit)
         navg = jnp.where(flat[:, None], navg, sf.normal)
     dvec = dvec - jnp.sum(dvec * navg, -1, keepdims=True) * navg
     if hausd is None:

@@ -95,9 +95,8 @@ def swap_facesort_enabled() -> bool:
     import os
     v = os.environ.get("PARMMG_SWAP_FACESORT", "")
     if v == "":
-        import jax
-        dev = jax.config.jax_default_device or jax.default_backend()
-        return getattr(dev, "platform", dev) == "tpu"
+        from ..utils.placement import placed_on_tpu
+        return placed_on_tpu()
     return v != "0"
 
 

@@ -44,6 +44,7 @@ from ..core.mesh import Mesh
 from ..core import constants as C
 from ..obs import trace as otrace
 from ..utils.compilecache import BLOCK_ENTRY, LEDGER
+from ..utils.placement import placed_on_tpu
 
 
 def how_many_groups(ne: int, target: int) -> int:
@@ -172,10 +173,16 @@ def _group_block_program(nomove: bool, noinsert: bool, hausd):
     traced arguments, so toggling the incremental path mints zero new
     compile families (the hotloop_knob_gate contract).  Quiet/pad slots
     pass through the ``active`` lax.cond with their state untouched
-    (an idle slot's retained tables stay valid)."""
+    (an idle slot's retained tables stay valid).
+
+    Whether the cycle's surface scatters run over lists of their live
+    updates (ops/surflist) is observed here, where the program is
+    built, and is part of its key: they do where it is placed on a
+    TPU."""
     from ..ops.adapt import adapt_cycle_impl
     from ..utils.compilecache import governed
-    key = (nomove, noinsert, hausd)
+    surf_list = placed_on_tpu()
+    key = (nomove, noinsert, hausd, surf_list)
     if key in _GROUP_BLOCK_CACHE:
         return _GROUP_BLOCK_CACHE[key]
 
@@ -192,7 +199,7 @@ def _group_block_program(nomove: bool, noinsert: bool, hausd):
             return adapt_cycle_impl(
                 m, k, wave, do_swap=sw, do_smooth=not nomove,
                 do_insert=not noinsert, hausd=hausd, prescreen=pr,
-                active=act, topo=tp, incr=inc)
+                active=act, topo=tp, incr=inc, surf_list=surf_list)
 
         n_map = stacked.vert.shape[0]            # chunk or g_exec
         waves = jnp.full(n_map, wave, jnp.int32)
@@ -465,7 +472,8 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     skipped-group / saved-dispatch counters and the active-group
     trajectory, in ``stats.sched_extra``.
     """
-    from ..ops.adapt import DIRTY_COL, SURF_COLS
+    from ..ops.adapt import (DIRTY_COL, LISTED_COL, SURF_COLS,
+                             surface_scatter_width)
     from ..utils.timers import Timers
     from .distribute import split_to_shards, merge_shards, grow_shards
     from .sched import QuietGroupScheduler
@@ -598,6 +606,13 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
             # is already host numpy — the drain pulled it)
             tot = cs.tolist()                           # python ints
             surf = {k: tot[col] for k, col in SURF_COLS.items()}
+            # the rows whose surface scatters ran over lists (every row
+            # that ran, or none: a mesh has boundary faces), and what
+            # those scatters are at full width
+            surf["listed"] = tot[LISTED_COL]
+            list_full = surface_scatter_width(
+                stacked.tet.shape[1], not noinsert, not nomove, hausd
+            ) * np.count_nonzero(counts_act[:, LISTED_COL])
             # quiet: the row executions the device mask skipped in this
             # dispatch (a job's sum of them is groups.cond_skipped)
             # prog: which of the block programs this process lowered
@@ -620,7 +635,7 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
             stats.nswap += tot[2]
             stats.nmoved += tot[3]
             stats.cycles += 1
-            stats.add_surface(**surf)
+            stats.add_surface(**surf, list_full=list_full)
         otrace.log(3, f"  grp cycle {c}: split {tot[0]} "
                       f"collapse {tot[1]} swap {tot[2]} move "
                       f"{tot[3]} over {ngroups} groups",

@@ -35,6 +35,19 @@ def host_staging():
         yield
 
 
+def placed_on_tpu() -> bool:
+    """Whether the program being traced is PLACED on a TPU: the default
+    device where a context set one (``host_staging`` in a process that
+    holds a chip), else the process's default backend.  What decides
+    between two renderings of the same result whose costs differ by
+    backend (``ops/swap.swap_facesort_enabled``, ``ops/surflist.Tally``):
+    a TPU process places its whole-mesh tail on the host, where the
+    process default chose wrongly (PERF.md, PRs 26, 33)."""
+    import jax
+    dev = jax.config.jax_default_device or jax.default_backend()
+    return getattr(dev, "platform", dev) == "tpu"
+
+
 def to_device(tree):
     """Commit a staged pytree to the first device of the default
     backend (the inverse of :func:`host_staging`)."""
