@@ -489,6 +489,8 @@ def phase_four_chips(args, device) -> None:
     with placed:
         rc, rec = run_cli(
             ["-in", mesh_p, "-sol", sol_p, "-out", out4, "-ndev", "4",
+             "-mesh-size", str(MESH_SIZE),      # ranks x groups, as the
+             # cell spmd4-iso-growth: IParam.nDevices 4, two groups a rank
              "-niter", str(NITER), "-v", "5", "-bench-json"])
     wall = time.perf_counter() - t1
     if rc != 0:

@@ -47,6 +47,7 @@ class IParam(enum.IntEnum):
     fem = 27
     opnbdy = 28
     contiguousMode = 29     # force / don't force the groups' contiguity
+    nDevices = 30           # ranks: one a device (-ndev); the repo's own
 
 
 class DParam(enum.IntEnum):
@@ -168,6 +169,10 @@ class Info:
             IParam.repartitioningMode: ("repartitioning", int),
             IParam.opnbdy: ("opnbdy", bool),
             IParam.fem: ("fem", bool),
+            # upstream takes the rank count from the communicator given
+            # to PMMG_Init_parMesh; a library on one host has none, so
+            # the count is a parameter (checked at run(): check_devices)
+            IParam.nDevices: ("n_devices", int),
         }
         if key not in m:
             raise KeyError(f"unsupported iparam {key}")
@@ -209,6 +214,16 @@ def check_input_data(info: Info, met_is_aniso: bool = False) -> None:
                          "metric")
 
 
+def check_devices(info: Info, available: int) -> None:
+    """The rank count against the devices jax has: a run on fewer
+    devices than asked for is refused, never made in silence."""
+    if info.n_devices < 1:
+        raise InputError(f"nDevices {info.n_devices}: at least one device")
+    if info.n_devices > available:
+        raise InputError(f"nDevices {info.n_devices} asked for, jax has "
+                         f"{available}")
+
+
 def resolve_target_mesh_size(info: Info, ne_global: int, n_devices: int)\
         -> int:
     """Group/shard target size with sentinel semantics
@@ -217,3 +232,19 @@ def resolve_target_mesh_size(info: Info, ne_global: int, n_devices: int)\
     if t < 0:
         t = abs(C.TARGET_MESH_SIZE_SENTINEL)
     return max(C.REDISTR_NELEM_MIN, min(t, max(1, ne_global // n_devices)))
+
+
+def groups_per_rank(ne_global: int, n_devices: int, mesh_size: int) -> int:
+    """Groups a rank cuts its share of the mesh into: upstream's
+    two-level decomposition (grpsplit_pmmg.c:1551-1614) with one rank a
+    device.  A rank holds ceil(ne / n_devices) tets and splits them into
+    groups of ``mesh_size`` (``IParam.meshSize``, sentinels as
+    ``resolve_target_mesh_size`` reads them); a target at or over a
+    rank's whole share, the default's case, is one group.  The SPMD
+    path runs ``n_devices`` x this many shards."""
+    from ..parallel.groups import how_many_groups
+    target = resolve_target_mesh_size(Info(target_mesh_size=mesh_size),
+                                      ne_global, n_devices)
+    if target >= ne_global // n_devices:
+        return 1
+    return how_many_groups(-(-ne_global // n_devices), target)

@@ -250,7 +250,7 @@ def main(argv=None) -> int:
     info.ifc_layers = args.nlayers
     info.grps_ratio = args.groups_ratio
     info.nobalancing = args.nobalance
-    info.n_devices = args.ndev
+    pm.set_iparameter(IParam.nDevices, args.ndev)
     info.hmin, info.hmax = args.hmin, args.hmax
     info.hsiz = args.hsiz
     info.hausd = args.hausd

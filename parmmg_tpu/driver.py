@@ -160,12 +160,14 @@ def apply_local_params(mesh: Mesh, met, info):
 def parmmg_run(pm) -> tuple[Mesh, object, AdaptStats]:
     """Run the full adaptation per the staged ParMesh. Returns
     (adapted core Mesh, metric, stats)."""
-    from .api.params import check_input_data
+    import jax
+    from .api.params import check_devices, check_input_data
     from .obs import trace as otrace
     from .utils.timers import LEDGER
     info = pm.info
     check_input_data(info, met_is_aniso=(
         pm.met is not None and getattr(pm.met, "ndim", 1) == 2))
+    check_devices(info, len(jax.devices()))
     # telemetry spine: fresh run context (run id + backend tag on every
     # trace record) and the process verbosity = the reference's imprim
     otrace.new_run()
@@ -434,6 +436,7 @@ def _run_phases(pm) -> tuple[Mesh, object, AdaptStats]:
                 break
             stats += st
     else:
+        import jax
         from .parallel.dist import (distributed_adapt_multi,
                                     ShardOverflowError)
         # the SPMD path places like the grouped one: the shard-shaped
@@ -458,23 +461,29 @@ def _run_phases(pm) -> tuple[Mesh, object, AdaptStats]:
         # libparmmg.c:206-329); the dedup at load time kept tet order
         in_part = getattr(pm, "_in_part", None)
         n_t0 = int(np.asarray(mesh.tmask).sum())
-        # the shard COUNT must equal the device count: fewer shards
-        # would leave devices permanently empty (the flood never
-        # populates a shard that shares no interface)
+        # ranks x groups (grpsplit_pmmg.c:1551-1614): a rank a device,
+        # and a rank cuts its share into groups of -mesh-size as one
+        # device does on the grouped path, G rows of the SPMD block a
+        # device.  The shard COUNT is a multiple of the device count:
+        # fewer shards would leave devices permanently empty (the flood
+        # never populates a shard that shares no interface)
+        from .api.params import groups_per_rank
+        n_shards = info.n_devices * groups_per_rank(
+            n_t0, info.n_devices, info.target_mesh_size)
         if in_part is not None and (
                 len(in_part) != n_t0
-                or int(in_part.max()) + 1 != info.n_devices):
+                or int(in_part.max()) + 1 != n_shards):
             in_part = None
         try:
             with tim("adaptation"):
                 mesh, met, part = distributed_adapt_multi(
-                    mesh, met, info.n_devices, niter=niter,
+                    mesh, met, n_shards, niter=niter,
                     verbose=vrb, stats=stats,
                     noinsert=info.noinsert, noswap=info.noswap,
                     nomove=info.nomove, angedg=angedg, hausd=hausd,
                     ifc_layers=info.ifc_layers,
                     nobalancing=info.nobalancing, part=in_part,
-                    mode=mode)
+                    mode=mode, n_devices=info.n_devices, timers=tim)
         except ShardOverflowError as e:
             # degrade to LOWFAILURE with the conforming merged state
             # (failed_handling, libparmmg1.c:974-1011)
@@ -486,13 +495,25 @@ def _run_phases(pm) -> tuple[Mesh, object, AdaptStats]:
                   "  ## Warning: shard capacity exhausted; saving the "
                   "last conforming mesh (LOWFAILURE).",
                   verbose=info.imprim, err=True)
+        # a job whose shards did not end on the devices it asked for did
+        # not run where the caller was told it would: the mesh is sound,
+        # the status says so
+        held = stats.sched_extra.get("dist_devices", info.n_devices)
+        if held < info.n_devices and jax.process_count() == 1:
+            stats.status = C.PMMG_LOWFAILURE
+            ladder_step("lowfailure", site="dist.devices",
+                        detail=f"live shards on {held} of "
+                               f"{info.n_devices} devices")
         # bad-element optimization on the merged mesh (same contract as
         # the single-device path: sliver_polish after the sizing loop)
         if not (info.noinsert and info.noswap and info.nomove):
             mesh, ops = _merged_polish(mesh, met, info, hausd, stats, tim)
             if ops:
                 part = None   # tet set changed: labels are stale
-        pm._out_part = part          # reused by distributed output
+        # reused by distributed output, a file a RANK: a tet's label is
+        # its shard's, and a rank holds n_shards / nDevices of them in a row
+        pm._out_part = None if part is None \
+            else part // (n_shards // info.n_devices)
         with host_staging():
             return _finish_run(pm, mesh, met, stats, info, tim, bg_mesh,
                                bg_fields, hausd)

@@ -42,7 +42,7 @@ import re
 import time
 from typing import NamedTuple
 
-from ..utils.compilecache import BLOCK_ENTRY, LEDGER
+from ..utils.compilecache import BLOCK_ENTRY, DIST_BLOCK_ENTRY, LEDGER
 
 # the governed entries whose programs a run's capture is joined with
 # (the cycle block on the device; the merged polish and the fem round
@@ -50,6 +50,7 @@ from ..utils.compilecache import BLOCK_ENTRY, LEDGER
 # ``prog`` says which of the entry's programs that dispatch ran; the
 # field of the ``device_phases`` event its seconds go under)
 ENTRIES = {BLOCK_ENTRY: ("grp block", "block"),
+           DIST_BLOCK_ENTRY: ("dist block", "dist"),
            "adapt.sliver_polish": ("polish wave", "polish"),
            "adapt.fem_pass": ("fem round", "fem")}
 PHASE_PREFIXES = ("cyc.", "pol.", "fem.")

@@ -18,8 +18,8 @@ static-shape device path).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-import time
 from functools import partial
 
 import numpy as np
@@ -31,11 +31,21 @@ from ..utils.jaxcompat import shard_map
 
 from ..core.mesh import Mesh
 from ..obs import trace as otrace
+from ..obs.metrics import REGISTRY
 from ..ops.quality import tet_quality, quality_histogram
-from ..utils.compilecache import bucket, governed
+from ..utils.compilecache import DIST_BLOCK_ENTRY, LEDGER, bucket, governed
+from ..utils.placement import placed_on_tpu
 
 
 MAX_SHARD_REGROWS = 6
+# the SPMD block's counts row: columns 0-10 are the cycle's own row
+# (ops/adapt: operations, overflow, live tets, deferrals, LISTED_COL,
+# SURF_COLS) summed over every logical shard, then the rows whose
+# surface scatters ran over lists, and the live tets and the fullest
+# shard at the block's ENTRY
+LISTED_ROWS_COL = 11
+LIVE_IN_COL = 12
+LARGEST_IN_COL = 13
 
 
 class ShardOverflowError(RuntimeError):
@@ -50,6 +60,19 @@ class ShardOverflowError(RuntimeError):
         self.mesh = mesh
         self.met = met
         self.part = part
+
+
+# compiled SPMD programs that close over a device mesh, a process:
+# the cycle block by (device ids, knobs), the analysis refresh by
+# (device ids, its static budget)
+_DIST_BLOCK_CACHE: dict = {}
+_ANALYSIS_CACHE: dict = {}
+
+
+def _device_ids(dmesh: DeviceMesh) -> tuple:
+    # lint: ok(R2) — device-id METADATA (dmesh.devices is a host numpy
+    # object array), no device sync
+    return tuple(d.id for d in np.asarray(dmesh.devices).flat)
 
 
 def _unstack(pytree):
@@ -100,8 +123,10 @@ def dist_adapt_block(dmesh: DeviceMesh, swap: bool,
     that makes meshes far beyond one group's HBM feasible per chip.
 
     Returns fn(stacked_mesh, stacked_met, wave, quiet_lvl[S*G]) ->
-      (stacked_mesh, stacked_met, global_counts[4],
-       active_groups, any_overflow, quiet_lvl'[S*G]).
+      (stacked_mesh, stacked_met, global_counts[14],
+       active_groups, any_overflow, quiet_lvl'[S*G]); the counts row is
+    the cycle's own, summed over the logical shards, and three columns
+    more (``LISTED_ROWS_COL``, ``LIVE_IN_COL``, ``LARGEST_IN_COL``).
 
     ``active_groups`` = number of LOGICAL shards that posted a nonzero
     split+collapse+swap in the cycle (psum'd like the counters): the
@@ -139,21 +164,36 @@ def _dist_block_program(dmesh: DeviceMesh, do_smooth: bool,
     (``pr``) and whether the block is swap-inclusive (``inc``) are
     traced, replicated scalar arguments — the cycle classes of a run
     share one multi-minute SPMD compile."""
-    from ..ops.adapt import adapt_cycle_impl
+    from ..ops.adapt import LISTED_COL, adapt_cycle_impl
     spec = P("shard")
+    # observed here, where the program is built, as the grouped block
+    # does: the surface scatters run over lists where it is placed on a
+    # TPU (ops/surflist)
+    surf_list = placed_on_tpu()
+    # one program a process and knob set (the grouped block's
+    # ``_GROUP_BLOCK_CACHE``): jit keeps a traced program by the
+    # function's identity, so a fresh ``shard_map`` a job traced,
+    # lowered and loaded the block again in every job of a process
+    key = (_device_ids(dmesh), do_smooth, do_insert, hausd, G, surf_list)
+    if key in _DIST_BLOCK_CACHE:
+        return _DIST_BLOCK_CACHE[key]
 
     def one_shard(mesh: Mesh, met, wave, act, sw, pr):
         return adapt_cycle_impl(
             mesh, met, wave, do_swap=sw, do_smooth=do_smooth,
             do_insert=do_insert, smooth_waves=2, hausd=hausd,
-            prescreen=pr, active=act)                      # counts [11]
+            prescreen=pr, active=act, surf_list=surf_list)  # counts [11]
 
-    def local_block(mesh_s: Mesh, met_s, wave, lvl_s, sw, pr, inc):
+    # ``run``: the profile names the program's module after it, and a
+    # capture's reduction finds a cycle block by the grouped block's
+    # name (``jit_run``)
+    def run(mesh_s: Mesh, met_s, wave, lvl_s, sw, pr, inc):
         # the level this block skips at == the level it can prove
         # (sched.LEVEL_PRE under a prescreen-ON cycle, LEVEL_FULL
         # once a prescreen-OFF cycle ran — numerically 1 and 2)
         skip_lvl = jnp.where(pr, jnp.int8(1), jnp.int8(2))
         act_in = lvl_s < skip_lvl                          # [G] bool
+        live_in = jnp.sum(mesh_s.tmask, axis=1, dtype=jnp.int32)   # [G]
         if G == 1:
             mesh, met, cs = one_shard(_unstack(mesh_s), met_s[0],
                                       wave, act_in[0], sw, pr)
@@ -175,27 +215,34 @@ def _dist_block_program(dmesh: DeviceMesh, do_smooth: bool,
         lvl_s = jnp.maximum(
             lvl_s, jnp.where(blk_zero & inc, skip_lvl, jnp.int8(0)))
         ovf = jax.lax.pmax(jnp.max(cs_g[:, 4]), "shard")
-        counts = jax.lax.psum(jnp.sum(cs_g[:, :4], axis=0), "shard")
+        listed_rows = jnp.sum((cs_g[:, LISTED_COL] > 0).astype(jnp.int32))
+        counts = jnp.concatenate([
+            jax.lax.psum(
+                jnp.concatenate([jnp.sum(cs_g, axis=0), listed_rows[None],
+                                 jnp.sum(live_in)[None]]), "shard"),
+            jax.lax.pmax(jnp.max(live_in), "shard")[None]])
         nact = jax.lax.psum(act, "shard")
         return mesh_s, met_s, counts, nact, ovf, lvl_s
 
-    fn = shard_map(local_block, mesh=dmesh,
+    fn = shard_map(run, mesh=dmesh,
                    in_specs=(spec, spec, P(), spec, P(), P(), P()),
                    out_specs=(spec, spec, P(), P(), P(), spec),
                    check_vma=False)
-    return governed("dist.adapt_block")(jax.jit(fn))
+    prog = governed(DIST_BLOCK_ENTRY)(jax.jit(fn))
+    _DIST_BLOCK_CACHE[key] = prog
+    return prog
 
 
 class DistSteps:
-    """Per-driver-invocation cache of the compiled SPMD block program.
-    jax.jit caches by function identity, so a
-    fresh shard_map per outer iteration would recompile the
-    multi-minute SPMD graph every time; the multi-iteration drivers
-    build ONE of these and reuse it."""
+    """The compiled SPMD block program of one driver invocation, with
+    its knobs (the program itself is cached a process:
+    :func:`_dist_block_program`)."""
 
     def __init__(self, dmesh: DeviceMesh, do_smooth: bool = True,
                  do_insert: bool = True, hausd: float | None = None,
                  G: int = 1):
+        self.do_smooth, self.do_insert, self.hausd = \
+            do_smooth, do_insert, hausd
         self._prog = _dist_block_program(dmesh, do_smooth, do_insert,
                                          hausd, G)
 
@@ -268,7 +315,6 @@ def dist_interface_check(dmesh: DeviceMesh, G: int = 1,
 
 def refresh_shard_analysis_device(stacked: Mesh, comms, n_shards: int,
                                   angedg: float, glo, dmesh,
-                                  cache: dict | None = None,
                                   pack_state: dict | None = None):
     """Device-resident analysis refresh (parallel/analysis_dev.py): the
     sort/segment reductions of the host path run jitted under shard_map,
@@ -314,8 +360,10 @@ def refresh_shard_analysis_device(stacked: Mesh, comms, n_shards: int,
     # (hysteresis; the multi-iteration driver threads one dict through)
     Mp = packed_halo_rows(comms.nbr, G, state=pack_state) \
         if G > 1 else None
-    key = (angedg, KS, n_shards, G, Mp)
-    if cache is not None and key in cache:
+    cache = _ANALYSIS_CACHE
+    ids = _device_ids(dmesh)
+    key = (ids, angedg, KS, n_shards, G, Mp)
+    if key in cache:
         fn = cache[key]
     else:
         if G > 1:
@@ -324,8 +372,7 @@ def refresh_shard_analysis_device(stacked: Mesh, comms, n_shards: int,
         else:
             fn = governed("dist.analysis", budget=2)(
                 dist_analysis(dmesh, angedg, KS))
-        if cache is not None:
-            cache[key] = fn
+        cache[key] = fn
     args = (stacked,
             shard_stacked(jnp.asarray(glo_np.astype(np.int32)), dmesh),
             shard_stacked(jnp.asarray(comms.node_idx), dmesh),
@@ -349,15 +396,14 @@ def refresh_shard_analysis_device(stacked: Mesh, comms, n_shards: int,
         # is left alone so a healthy next iteration can re-pick packed.
         from ..resilience.recover import ladder_step
         ladder_step("halo_dense", site="halo.exchange", detail=repr(e))
-        dkey = (angedg, KS, n_shards, G, None)
-        if cache is not None and dkey in cache:
+        dkey = (ids, angedg, KS, n_shards, G, None)
+        if dkey in cache:
             fn = cache[dkey]
         else:
             fn = governed("dist.analysis_grouped", budget=2)(
                 dist_analysis_grouped(dmesh, angedg, KS, G,
                                       packed_M=None))
-            if cache is not None:
-                cache[dkey] = fn
+            cache[dkey] = fn
         vt, et, ovf = fn(*args)
         ovf_host = int(ovf)
     if ovf_host != 0:
@@ -536,7 +582,8 @@ def check_interface_echo(stacked, met_s, comms, dmesh, vert_h, G: int = 1,
 
 def run_adapt_cycles(stacked, met_s, steps: DistSteps, cycles,
                      dmesh, stats=None, verbose=0, on_grow=None,
-                     regrow_state=None, label="dist", noswap=False):
+                     regrow_state=None, label="dist", noswap=False,
+                     it: int = 0, tally: dict | None = None):
     """Shared SPMD cycle loop: swap cadence (every 3rd cycle + the final
     two), psum'd counter accounting, and the in-place overflow regrow
     (zaldy_pmmg.c:140-254 analogue — slot ids preserved so comm tables
@@ -549,6 +596,9 @@ def run_adapt_cycles(stacked, met_s, steps: DistSteps, cycles,
     ``on_grow(old_capP)`` lets the caller grow its side tables (global
     numbering) in lockstep; ``regrow_state`` is a 1-element mutable list
     carried across calls so repeated passes share the regrow budget.
+    ``it`` names the outer iteration on the ``dist block`` spans;
+    ``tally`` (:func:`_new_tally`) takes the dispatches, their seconds
+    and the live tets and fullest shard the iteration started from.
     """
     from .distribute import merge_shards, grow_shards
     from .groups import block_schedule
@@ -560,6 +610,7 @@ def run_adapt_cycles(stacked, met_s, steps: DistSteps, cycles,
     # shard, never pulled to host.  With masking disabled the SAME
     # program runs with an all-zeros level every block (no skipping, no
     # new compile family).
+    from ..ops.adapt import LISTED_COL, SURF_COLS, surface_scatter_width
     n_logical = stacked.tmask.shape[0]
     mask_on = sched_enabled() and device_mask_enabled()
     lvl = shard_stacked(jnp.zeros(n_logical, jnp.int8), dmesh)
@@ -569,15 +620,31 @@ def run_adapt_cycles(stacked, met_s, steps: DistSteps, cycles,
         # final two, which also bypass the split prescreen
         swap, pre = block_schedule(c, cycles, noswap)
         step = steps.get(swap, pre, swap_inclusive=swap or noswap)
-        stacked, met_s, counts, nact, ovf, lvl2 = step(
-            stacked, met_s, jnp.asarray(c, jnp.int32), lvl)
-        if mask_on:
-            lvl = lvl2
-        # ONE host pull per array per block (the blessed .tolist()
-        # idiom): the per-field int() casts each forced their own
-        # device sync
-        cs = counts.tolist()                     # [4]
-        na = nact.tolist()                       # active groups
+        # one span a dispatched block, dispatch to counter pull, with
+        # the operations it applied (the grouped path's ``grp block``)
+        with otrace.span("dist block", it=it, cycle=c) as sp:
+            stacked, met_s, counts, nact, ovf, lvl2 = step(
+                stacked, met_s, jnp.asarray(c, jnp.int32), lvl)
+            if mask_on:
+                lvl = lvl2
+            # ONE host pull per array per block (the blessed .tolist()
+            # idiom): the per-field int() casts each forced their own
+            # device sync
+            cs = counts.tolist()                     # [14]
+            na = nact.tolist()                       # active groups
+            surf = {k: cs[col] for k, col in SURF_COLS.items()}
+            surf["listed"] = cs[LISTED_COL]
+            sp.set(active=na, split=cs[0], collapse=cs[1], swap=cs[2],
+                   moved=cs[3], live=cs[LIVE_IN_COL],
+                   largest=cs[LARGEST_IN_COL],
+                   prog=LEDGER.program_index(DIST_BLOCK_ENTRY), **surf)
+        if tally is not None:
+            tally["dispatches"] += 1
+            tally["compute_s"] += sp.dur
+            if c == 0:
+                # the imbalance the iteration starts from
+                tally["live_tets"] = cs[LIVE_IN_COL]
+                tally["largest_shard"] = cs[LARGEST_IN_COL]
         n_logical = stacked.tmask.shape[0]
         if stats is not None:        # psum'd global counters
             stats.nsplit += cs[0]
@@ -585,6 +652,10 @@ def run_adapt_cycles(stacked, met_s, steps: DistSteps, cycles,
             stats.nswap += cs[2]
             stats.nmoved += cs[3]
             stats.cycles += 1
+            stats.add_surface(
+                **surf, list_full=cs[LISTED_ROWS_COL] * surface_scatter_width(
+                    stacked.tet.shape[1], steps.do_insert, steps.do_smooth,
+                    steps.hausd))
             # per-group convergence trajectory (the SPMD mirror of
             # the grouped path's active_groups_per_block)
             stats.sched_extra.setdefault(
@@ -743,8 +814,26 @@ def distributed_adapt_multi(mesh: Mesh, met, n_shards: int,
                             mode: str = "ifc",
                             n_devices: int | None = None,
                             ckpt_tag: str | None = None,
-                            resume: bool = False):
+                            resume: bool = False, timers=None):
     """Shard-resident multi-iteration adaptation (host driver).
+
+    What runs BETWEEN two iterations is staged on the host's CPU backend
+    where the job is placed on a TPU, in one process, and no handoff is
+    asked for (``host_between``; utils/placement.py's rule, for the same
+    reason: compile time).  Each of those programs runs once an
+    iteration, and
+    the TPU's compiler takes 280 s for the grouped analysis, 115 s for
+    the flood's three and 100-180 s for the migration's four at the
+    benchmark's shard shape (PERF.md, PR 41) where XLA:CPU takes seconds
+    for all of them: the shards are pulled after an iteration's blocks,
+    analysed (the numpy form, ``refresh_shard_analysis``), displaced and
+    migrated there by the same programs on one host device, and go back
+    to their devices for the echo check and the next blocks.  Observed
+    (``placed_on_tpu``, ``pod.handoff_enabled``), not asked for.
+
+    ``timers``: the driver's Timers; every iteration folds its blocks'
+    dispatch-to-pull seconds in as ``grp compute``, as a grouped pass
+    does (a rank's groups are what the blocks compute here too).
 
     ``n_devices``: groups x shards composition (default = ``n_shards``,
     i.e. one logical shard per device).  With ``n_devices`` <
@@ -822,35 +911,39 @@ def distributed_adapt_multi(mesh: Mesh, met, n_shards: int,
         dmesh = make_device_mesh(n_devices)
     ang = ANGEDG if angedg is None else angedg
 
-    vert_h, tet_h, vref_h, tref_h, vtag_h = mesh_to_host(mesh)
-    if part is None:
-        cent = vert_h[tet_h].mean(axis=1)
-        if partitioner == "morton":
-            part = morton_partition(cent, n_shards)
-        else:
-            part = greedy_partition(tet_h, cent, n_shards)
-        part = fix_contiguity(tet_h, part)
-        methost = np.asarray(met)[np.asarray(mesh.vmask)]
-        wd = metric_edge_weights(tet_h, vert_h, methost)
-        part = fix_contiguity(tet_h, refine_partition(
-            part, n_shards, wd["pairs"], wd["w"]))
-
-    # split and merge run at whole-mesh width: staged on the host like
-    # the grouped pass's (utils/placement.py); the shards go to their
-    # devices from there
     from ..utils.placement import host_staging
-    with host_staging():
-        s0, ms0, l2g = split_to_shards(mesh, met, part, n_shards,
-                                       cap_mult=3.0, return_l2g=True)
-    stacked = shard_stacked(s0, dmesh)
-    met_s = shard_stacked(ms0, dmesh)
-    capP0 = stacked.vert.shape[1]
-    g2l = []
-    for s_ in range(n_shards):
-        mmap = np.full(len(vert_h), -1, np.int64)
-        mmap[l2g[s_]] = np.arange(len(l2g[s_]))
-        g2l.append(mmap)
-    comms = build_interface_comms(tet_h, part, n_shards, l2g, g2l)
+    tally = _new_tally()
+    with otrace.span("dist split", shards=n_shards, G=G) as sp:
+        vert_h, tet_h, vref_h, tref_h, vtag_h = mesh_to_host(mesh)
+        if part is None:
+            cent = vert_h[tet_h].mean(axis=1)
+            if partitioner == "morton":
+                part = morton_partition(cent, n_shards)
+            else:
+                part = greedy_partition(tet_h, cent, n_shards)
+            part = fix_contiguity(tet_h, part)
+            methost = np.asarray(met)[np.asarray(mesh.vmask)]
+            wd = metric_edge_weights(tet_h, vert_h, methost)
+            part = fix_contiguity(tet_h, refine_partition(
+                part, n_shards, wd["pairs"], wd["w"]))
+
+        # split and merge run at whole-mesh width: staged on the host
+        # like the grouped pass's (utils/placement.py); the shards go to
+        # their devices from there
+        with host_staging():
+            s0, ms0, l2g = split_to_shards(mesh, met, part, n_shards,
+                                           cap_mult=3.0, return_l2g=True)
+        stacked = shard_stacked(s0, dmesh)
+        met_s = shard_stacked(ms0, dmesh)
+        capP0 = stacked.vert.shape[1]
+        g2l = []
+        for s_ in range(n_shards):
+            mmap = np.full(len(vert_h), -1, np.int64)
+            mmap[l2g[s_]] = np.arange(len(l2g[s_]))
+            g2l.append(mmap)
+        comms = build_interface_comms(tet_h, part, n_shards, l2g, g2l)
+        sp.set(capP=capP0, capT=stacked.tet.shape[1],
+               largest=np.bincount(part, minlength=n_shards).max().tolist())
     # persistent global vertex numbering: split-time ids, extended with
     # fresh ids for adapt-created vertices each pass (the
     # PMMG_Compute_verticesGloNum role, libparmmg.c:923)
@@ -953,8 +1046,36 @@ def distributed_adapt_multi(mesh: Mesh, met, n_shards: int,
     # rebuilds (comms.packed_halo_rows hysteresis): ONE state dict
     # threaded through every packed-layout decision of this run
     pack_state: dict = {}
-    check_interface_echo(stacked, met_s, comms, dmesh, vert_h, G=G,
-                         pack_state=pack_state)
+    # a vertex's coordinates and metric, float32, a valid slot
+    echo_width = 4 * (3 + int(np.prod(met_s.shape[2:], dtype=np.int64)))
+    host_between = placed_on_tpu() and not multi \
+        and not pod.handoff_enabled()
+    between = host_staging if host_between else contextlib.nullcontext
+    host0 = jax.local_devices(backend="cpu")[0]
+    # the shards staged on the host and their last copy on the devices:
+    # (the staged Mesh it is a copy of, stacked, met_s)
+    staged, uploaded = [False], [None]
+
+    def on_devices():
+        """``(stacked, met_s)`` on the device mesh."""
+        if not staged[0]:
+            return stacked, met_s
+        if uploaded[0] is None or uploaded[0][0] is not stacked:
+            uploaded[0] = (stacked, shard_stacked(stacked, dmesh),
+                           shard_stacked(met_s, dmesh))
+        return uploaded[0][1:]
+
+    def place_between(x):
+        """``x`` where the programs between two iterations run."""
+        return jax.device_put(x, host0) if host_between \
+            else shard_stacked(x, dmesh)
+
+    def echo_check():
+        check_interface_echo(*on_devices(), comms, dmesh, vert_h, G=G,
+                             pack_state=pack_state)
+        tally["exchange_bytes"] += _halo_bytes(comms, echo_width)
+
+    echo_check()
 
     steps = DistSteps(dmesh, do_smooth=not nomove,
                       do_insert=not noinsert, hausd=hausd, G=G)
@@ -984,13 +1105,13 @@ def distributed_adapt_multi(mesh: Mesh, met, n_shards: int,
     shared_prev = None
 
     def upload_glo():
-        """The device copy of the host numbering ``glo``, COMMITTED over
-        'shard' as the migration programs hand it back: an uncommitted
+        """The copy of the host numbering ``glo`` the migration programs
+        take, COMMITTED as they hand it back (over 'shard', or to the
+        host device they are staged on): an uncommitted
         upload made ``device_migrate`` lower and compile a second
         executable for the same shapes (compilecache, placement
         variants; PERF.md, PR 31)."""
-        return shard_stacked(
-            jnp.asarray(np.stack(glo).astype(np.int32)), dmesh)
+        return place_between(np.stack(glo).astype(np.int32))
 
     if use_band:
         from .migrate_dev import (extend_ids_device, band_migrate_iteration,
@@ -1003,7 +1124,6 @@ def distributed_adapt_multi(mesh: Mesh, met, n_shards: int,
             else _shared_gids(comms, glo, n_shards)
 
     regrow_state = [regrow0]
-    ana_cache: dict = {}
     # ---- the pod hot path -------------------------------------------
     # every iteration body runs inside multihost.hot_path(): a stray
     # process_allgather in there is metered on mh.hot_allgather_bytes
@@ -1016,262 +1136,311 @@ def distributed_adapt_multi(mesh: Mesh, met, n_shards: int,
             # iteration (obs/trace.py)
             otrace.set_context(**{"pass": it})
             capP_before = stacked.vert.shape[1]
-            _t_seg = time.perf_counter()
+            done = (tally["compute_s"], tally["dispatches"])
             stacked, met_s = run_adapt_cycles(
                 stacked, met_s, steps, cycles, dmesh,
                 stats=stats, verbose=verbose, on_grow=grow_glo,
                 regrow_state=regrow_state, label=f"dist it {it}",
-                noswap=noswap)
-            otrace.emit_span("dist.adapt", time.perf_counter() - _t_seg)
-            _t_seg = time.perf_counter()
-            if use_band and stacked.vert.shape[1] != capP_before:
-                glo_d = None          # regrown: rebuild the device copy
-            # extend the session numbering (device on the band path, with a
-            # band-sized fresh-id pull; vmask-pull host path otherwise),
-            # then the DEVICE analysis refresh
-            if use_band:
-                if glo_d is None:
-                    glo_d = upload_glo()
-                KN = max(256, stacked.vert.shape[1] // 2)
-                # int32 numbering on device (documented migrate_dev limit):
-                # the monotone session counter must not wrap — if this
-                # iteration could hand out ids past int31, take the host
-                # path (which re-derives a compact numbering) instead of
-                # silently aliasing device ids
-                ids_fit = session_ids_fit(top, n_shards, KN)
-                oke = False
-                if ids_fit:
-                    # newly-dead delta FIRST: the pre-extend numbering
-                    # still carries the dying rows' ids, so (glo >= 0 &
-                    # ~vmask) is exactly the band-sized kill list the host
-                    # mirror needs — the O(mesh) vmask allgather of the
-                    # pre-pod path is gone (migrate_dev.dead_glo_rows)
-                    d_rows, d_cnt, d_ok = dead_glo_rows(
-                        glo_d, stacked.vmask, KD=KN)
-                    glo_d2, top_d, f_rows, f_gids, oke = extend_ids_device(
-                        glo_d, stacked.vmask, jnp.asarray(top, jnp.int32),
-                        KN=KN)
-                    oke = bool(oke) and bool(d_ok)
-                if ids_fit and oke:
-                    glo_d = glo_d2
-                    top = int(top_d)
-                    # ONE packed band exchange replicates the compacted
-                    # fresh-id + dead-delta tables to every process
-                    f_rows, f_gids, d_rows, d_cnt = pod.gather_band(
-                        f_rows, f_gids, d_rows, d_cnt, what="extend")
-                    apply_fresh_ids(glo, f_rows, f_gids)
-                    kill_glo_rows(glo, d_rows, d_cnt)
-                else:               # fresh-id/dead budget blown: host extend
-                    # lint: ok(R7) — documented escape hatch (budget
-                    # overflow): the O(mesh) mask pull is metered by
-                    # pull_host and visible on mh.allgather_bytes
-                    vmask_h = _pull(stacked.vmask, what="host_extend")
-                    top = extend_global_ids_from_vmask(glo, vmask_h, top)
-                    if top >= 2 ** 31:
-                        # the int32 device numbering can no longer represent
-                        # the session ids: permanently leave the band path
-                        # (the host path carries int64 ids) instead of
-                        # wrapping them on the next device cast
-                        use_band = False
-                        glo_d = None
-                    else:
-                        glo_d = upload_glo()
-            else:
-                # lint: ok(R7) — legacy full-view path (PARMMG_BAND_PATH=0,
-                # single-controller only); metered by pull_host
-                vmask_h = _pull(stacked.vmask, what="legacy_extend")
-                top = extend_global_ids_from_vmask(glo, vmask_h, top)
-            # device analysis refresh: per-device shard_map for G=1, the
-            # grouped lax.map program for G>1 (analysis_dev) — the host
-            # path below is the KS-budget-overflow fallback ONLY, so the
-            # steady-state G>1 loop performs zero O(mesh) host pulls
-            st2 = refresh_shard_analysis_device(
-                stacked, comms, n_shards, ang, glo, dmesh, cache=ana_cache,
-                pack_state=pack_state)
-            views = None
-            if st2 is not None:
-                stacked = st2
-            else:
-                if multi:
-                    # no ladder event here: the fallback is NOT taken on
-                    # the multi-process path — recording host_analysis and
-                    # then dying would log a recovery that never happened
-                    raise NotImplementedError(
-                        "analysis host fallback needs a full-view pull — "
-                        "not distributed; raise the KS budget or run "
-                        "single-process")
-                # host fallback (shared-record budget overflow) — the
-                # "host_analysis" escalation-ladder rung
-                from ..resilience.recover import ladder_step
-                ladder_step("host_analysis", site="analysis.ks_overflow")
-                views = pull_views(stacked, met_s)
-                stacked = refresh_shard_analysis(
-                    stacked, comms, n_shards, ang, glo=glo, views=views)
-            otrace.emit_span("dist.refresh", time.perf_counter() - _t_seg)
-            _t_seg = time.perf_counter()
-            if it + 1 < max(1, niter) and not nobalancing:
-                nmoved = 0
-                band_done = False
-                if use_band:
-                    from .migrate_dev import (repair_flood_labels,
-                                              graph_repartition_labels_band)
-                    if mode == "graph":
-                        # cluster-graph rebalance from device tables (the
-                        # metis_pmmg.c:845-1550 gather-only-the-graph role);
-                        # depth 0 everywhere — the donor floor still bounds
-                        # per-shard departures, order within a shard is
-                        # immaterial for cluster moves
-                        labels_d = graph_repartition_labels_band(
-                            stacked, comms, n_shards, verbose=verbose)
-                        depth_d = jnp.zeros(stacked.tmask.shape, jnp.int32)
-                        if labels_d is None:
-                            labels_d = jnp.broadcast_to(
-                                jnp.arange(n_shards, dtype=jnp.int32)[:, None],
-                                stacked.tmask.shape)
-                    else:
-                        sizes = jnp.sum(stacked.tmask, axis=1,
-                                        dtype=jnp.int32)
-                        labels_d, depth_d = flood_labels(
-                            stacked, jnp.asarray(comms.node_idx),
-                            jnp.asarray(comms.nbr), sizes, n_shards,
-                            nlayers=ifc_layers)
-                        # contiguity/reachability repair on the displaced
-                        # partition (moveinterfaces_pmmg.c:475-720 role)
-                        labels_d, _nfix = repair_flood_labels(
-                            stacked, labels_d, depth_d, n_shards,
-                            verbose=verbose)
-                    res = band_migrate_iteration(
-                        stacked, met_s, glo_d, glo, labels_d, depth_d,
-                        shared_prev, n_shards, verbose=verbose)
-                    # capacity/budget overflow: slot-stable grow (the full
-                    # path's migrate_shards grow loop analogue) raises both
-                    # the free slots AND the capacity-scaled band budgets;
-                    # bounded retries before the full-view fallback
-                    for _retry in range(3):
-                        if res is not None:
-                            break
-                        from .distribute import grow_shards
-                        capP_o = stacked.vert.shape[1]
-                        capT_o = stacked.tet.shape[1]
-                        stacked, met_s = grow_shards(
-                            stacked, met_s, 2 * capP_o, 2 * capT_o)
-                        views = None    # any pre-grow pull is shape-stale
-                        grow_glo(capP_o)
-                        glo_d = upload_glo()
-                        me_col = jnp.arange(n_shards,
-                                            dtype=labels_d.dtype)[:, None]
-                        labels_d = jnp.concatenate(
-                            [labels_d, jnp.broadcast_to(
-                                me_col, (n_shards, capT_o))], axis=1)
-                        depth_d = jnp.concatenate(
-                            [depth_d, jnp.zeros((n_shards, capT_o),
-                                                depth_d.dtype)], axis=1)
-                        res = band_migrate_iteration(
-                            stacked, met_s, glo_d, glo, labels_d, depth_d,
-                            shared_prev, n_shards, verbose=verbose)
-                    if res is not None:
-                        (stacked, met_s, glo_d, comms2, shared_prev,
-                         nmoved, arr_slots) = res
-                        band_done = True
-                        if nmoved:
-                            comms = comms2
-                            # weld the arrival neighborhoods (region-scoped)
-                            stacked, glo_d, nweld = band_weld(
-                                stacked, met_s, glo_d, glo, arr_slots,
-                                n_shards, verbose=verbose)
-                            if nweld < 0:     # region budget blown: full weld
-                                if multi:
-                                    # fail loudly (the designed
-                                    # contract) instead of the opaque
-                                    # non-addressable fetch error
-                                    # pull_views would raise
-                                    raise NotImplementedError(
-                                        "full-region weld fallback is "
-                                        "single-controller; band_weld'"
-                                        "s escalating probe must hold "
-                                        "on a multi-process run")
-                                views_w = pull_views(stacked, met_s)
-                                stacked, _ = weld_shard_bands(
-                                    stacked, views_w, glo, n_shards,
-                                    verbose=verbose)
-                                # the full weld freed host-glo rows; the
-                                # device copy must drop them too (stale
-                                # gids resurrect — see band_weld)
+                noswap=noswap, it=it, tally=tally)
+            # the devices that hold a live shard: a job whose shards ended
+            # on fewer than it asked for did not run there
+            tally["devices"] = len({
+                sh.device.id for sh in stacked.tmask.addressable_shards
+                if np.asarray(sh.data).any()})
+            if timers is not None:
+                timers.add("grp compute", tally["compute_s"] - done[0],
+                           tally["dispatches"] - done[1])
+            # where the programs between two iterations run: on the
+            # devices, or staged on the host (``host_between``)
+            with between():
+                with otrace.span("dist refresh", it=it) as span_ref:
+                    if host_between:
+                        # ONE pull of every shard; the copies stay
+                        # committed to the host's device
+                        stacked, met_s = jax.device_put(
+                            jax.device_get((stacked, met_s)), host0)
+                        staged[0] = True
+                        span_ref.set(pull_bytes=sum(
+                            a.nbytes for a in
+                            jax.tree.leaves((stacked, met_s))))
+                    if use_band and stacked.vert.shape[1] != capP_before:
+                        glo_d = None          # regrown: rebuild the device copy
+                    # extend the session numbering (device on the band path, with a
+                    # band-sized fresh-id pull; vmask-pull host path otherwise),
+                    # then the DEVICE analysis refresh
+                    if use_band:
+                        if glo_d is None:
+                            glo_d = upload_glo()
+                        KN = max(256, stacked.vert.shape[1] // 2)
+                        # int32 numbering on device (documented migrate_dev limit):
+                        # the monotone session counter must not wrap — if this
+                        # iteration could hand out ids past int31, take the host
+                        # path (which re-derives a compact numbering) instead of
+                        # silently aliasing device ids
+                        ids_fit = session_ids_fit(top, n_shards, KN)
+                        oke = False
+                        if ids_fit:
+                            # newly-dead delta FIRST: the pre-extend numbering
+                            # still carries the dying rows' ids, so (glo >= 0 &
+                            # ~vmask) is exactly the band-sized kill list the host
+                            # mirror needs — the O(mesh) vmask allgather of the
+                            # pre-pod path is gone (migrate_dev.dead_glo_rows)
+                            d_rows, d_cnt, d_ok = dead_glo_rows(
+                                glo_d, stacked.vmask, KD=KN)
+                            glo_d2, top_d, f_rows, f_gids, oke = extend_ids_device(
+                                glo_d, stacked.vmask, jnp.asarray(top, jnp.int32),
+                                KN=KN)
+                            oke = bool(oke) and bool(d_ok)
+                        if ids_fit and oke:
+                            glo_d = glo_d2
+                            top = int(top_d)
+                            # ONE packed band exchange replicates the compacted
+                            # fresh-id + dead-delta tables to every process
+                            f_rows, f_gids, d_rows, d_cnt = pod.gather_band(
+                                f_rows, f_gids, d_rows, d_cnt, what="extend")
+                            apply_fresh_ids(glo, f_rows, f_gids)
+                            kill_glo_rows(glo, d_rows, d_cnt)
+                        else:               # fresh-id/dead budget blown: host extend
+                            # lint: ok(R7) — documented escape hatch (budget
+                            # overflow): the O(mesh) mask pull is metered by
+                            # pull_host and visible on mh.allgather_bytes
+                            vmask_h = _pull(stacked.vmask, what="host_extend")
+                            top = extend_global_ids_from_vmask(glo, vmask_h, top)
+                            if top >= 2 ** 31:
+                                # the int32 device numbering can no longer represent
+                                # the session ids: permanently leave the band path
+                                # (the host path carries int64 ids) instead of
+                                # wrapping them on the next device cast
+                                use_band = False
+                                glo_d = None
+                            else:
                                 glo_d = upload_glo()
-                            stacked = rebuild_shards(stacked)
-                            check_interface_echo(stacked, met_s, comms,
-                                                 dmesh, vert_h, G=G,
-                                                 pack_state=pack_state)
                     else:
-                        otrace.log(1, f"  it {it}: band budgets exceeded — "
-                                      "falling back to the full-view path",
-                                   verbose=verbose)
-                if not band_done:
-                    if multi:
-                        raise NotImplementedError(
-                            "full-view migration fallback is "
-                            "single-controller; band budgets must hold on "
-                            "a multi-process run")
-                    if views is None:
+                        # lint: ok(R7) — legacy full-view path (PARMMG_BAND_PATH=0,
+                        # single-controller only); metered by pull_host
+                        vmask_h = _pull(stacked.vmask, what="legacy_extend")
+                        top = extend_global_ids_from_vmask(glo, vmask_h, top)
+                    # device analysis refresh: per-device shard_map for G=1, the
+                    # grouped lax.map program for G>1 (analysis_dev) — the host
+                    # path below is the KS-budget-overflow fallback ONLY, so the
+                    # steady-state G>1 loop performs zero O(mesh) host pulls
+                    views = None
+                    st2 = None if host_between else \
+                        refresh_shard_analysis_device(
+                            stacked, comms, n_shards, ang, glo, dmesh,
+                            pack_state=pack_state)
+                    if st2 is not None:
+                        stacked = st2
+                    elif host_between:
+                        # staged: the numpy form, by design and not as a
+                        # rung of the ladder
                         views = pull_views(stacked, met_s)
-                    if mode == "graph":
-                        labels = graph_repartition_labels(views, glo,
-                                                          n_shards)
-                        labels = enforce_ne_min(labels, views.tmask,
-                                                n_shards)
+                        stacked = refresh_shard_analysis(
+                            stacked, comms, n_shards, ang, glo=glo,
+                            views=views)
                     else:
-                        from .migrate_dev import repair_flood_labels
-                        sizes = jnp.asarray(
-                            views.tmask.sum(axis=1).astype(np.int32))
-                        labels_d, depth_d = flood_labels(
-                            stacked, jnp.asarray(comms.node_idx),
-                            jnp.asarray(comms.nbr), sizes, n_shards,
-                            nlayers=ifc_layers)
-                        labels_d, _nfix = repair_flood_labels(
-                            stacked, labels_d, depth_d, n_shards,
-                            verbose=verbose)
-                        labels = np.asarray(labels_d)
-                        labels = enforce_ne_min(labels, views.tmask,
-                                                n_shards,
-                                                depth=np.asarray(depth_d))
-                    touched = sorted({int(r) for s_ in range(n_shards)
-                                      for r in np.unique(
-                                          labels[s_][views.tmask[s_]])
-                                      if int(r) != s_})
-                    stacked, met_s, comms2, nmoved = migrate_shards(
-                        stacked, met_s, views, glo, labels, n_shards,
-                        verbose=verbose)
-                    if nmoved:
-                        comms = comms2
-                        stacked, _ = weld_shard_bands(
-                            stacked, views, glo, n_shards,
-                            touched=touched, verbose=verbose)
-                        stacked = rebuild_shards(stacked)
-                        check_interface_echo(stacked, met_s, comms, dmesh,
-                                             vert_h, G=G,
-                                             pack_state=pack_state)
-                    if use_band:    # resync the device numbering copy
-                        glo_d = upload_glo()
-                        shared_prev = _shared_gids(comms, glo, n_shards)
-                if nmoved:
-                    otrace.log(2, f"  it {it}: migrated {nmoved} "
-                                  "interface-band tets", verbose=verbose)
-                # host-to-host group handoff (pod runtime, opt-in knob
-                # PARMMG_MH_HANDOFF): when device loads skew past the
-                # imbalance threshold, whole logical shards move to other
-                # devices — and thereby other processes — as one compiled
-                # permutation; comm tables + numbering mirrors remap in
-                # lockstep (parallel/pod.py).  gids are unchanged under a
-                # permutation, so shared_prev needs no update.
-                if pod.handoff_enabled() and use_band and glo_d is not None:
-                    (stacked, met_s, glo_d, glo, comms,
-                     nmv_h) = pod.maybe_handoff(stacked, met_s, glo_d, glo,
-                                                comms, verbose=verbose)
-                    if nmv_h:
-                        check_interface_echo(stacked, met_s, comms, dmesh,
-                                             vert_h, G=G,
-                                             pack_state=pack_state)
-            otrace.emit_span("dist.migrate", time.perf_counter() - _t_seg)
+                        if multi:
+                            # no ladder event here: the fallback is NOT taken on
+                            # the multi-process path — recording host_analysis and
+                            # then dying would log a recovery that never happened
+                            raise NotImplementedError(
+                                "analysis host fallback needs a full-view pull — "
+                                "not distributed; raise the KS budget or run "
+                                "single-process")
+                        # host fallback (shared-record budget overflow) — the
+                        # "host_analysis" escalation-ladder rung
+                        from ..resilience.recover import ladder_step
+                        ladder_step("host_analysis", site="analysis.ks_overflow")
+                        views = pull_views(stacked, met_s)
+                        stacked = refresh_shard_analysis(
+                            stacked, comms, n_shards, ang, glo=glo, views=views)
+                    # the refresh's exchange: four words a valid slot
+                    # (staged, the shards meet on the host and exchange
+                    # nothing)
+                    if not host_between:
+                        tally["exchange_bytes"] += _halo_bytes(comms, 16)
+                if it + 1 < max(1, niter) and not nobalancing:
+                    nmoved = 0
+                    band_done = False
+                    if use_band:
+                        with otrace.span("dist displace", it=it,
+                                         layers=ifc_layers):
+                            from .migrate_dev import (repair_flood_labels,
+                                                      graph_repartition_labels_band)
+                            if mode == "graph":
+                                # cluster-graph rebalance from device tables (the
+                                # metis_pmmg.c:845-1550 gather-only-the-graph role);
+                                # depth 0 everywhere — the donor floor still bounds
+                                # per-shard departures, order within a shard is
+                                # immaterial for cluster moves
+                                labels_d = graph_repartition_labels_band(
+                                    stacked, comms, n_shards, verbose=verbose)
+                                depth_d = jnp.zeros(stacked.tmask.shape, jnp.int32)
+                                if labels_d is None:
+                                    labels_d = jnp.broadcast_to(
+                                        jnp.arange(n_shards, dtype=jnp.int32)[:, None],
+                                        stacked.tmask.shape)
+                            else:
+                                sizes = jnp.sum(stacked.tmask, axis=1,
+                                                dtype=jnp.int32)
+                                labels_d, depth_d = flood_labels(
+                                    stacked, jnp.asarray(comms.node_idx),
+                                    jnp.asarray(comms.nbr), sizes, n_shards,
+                                    nlayers=ifc_layers)
+                                # contiguity/reachability repair on the displaced
+                                # partition (moveinterfaces_pmmg.c:475-720 role)
+                                labels_d, _nfix = repair_flood_labels(
+                                    stacked, labels_d, depth_d, n_shards,
+                                    verbose=verbose)
+                    mh_band = REGISTRY.counter("mh.band_exchange_bytes")
+                    mh0 = mh_band.value
+                    with otrace.span("dist migrate", it=it) as span_mig:
+                        if use_band:
+                            res = band_migrate_iteration(
+                                stacked, met_s, glo_d, glo, labels_d, depth_d,
+                                shared_prev, n_shards, verbose=verbose)
+                            # capacity/budget overflow: slot-stable grow (the full
+                            # path's migrate_shards grow loop analogue) raises both
+                            # the free slots AND the capacity-scaled band budgets;
+                            # bounded retries before the full-view fallback
+                            for _retry in range(3):
+                                if res is not None:
+                                    break
+                                from .distribute import grow_shards
+                                capP_o = stacked.vert.shape[1]
+                                capT_o = stacked.tet.shape[1]
+                                stacked, met_s = grow_shards(
+                                    stacked, met_s, 2 * capP_o, 2 * capT_o)
+                                views = None    # any pre-grow pull is shape-stale
+                                grow_glo(capP_o)
+                                glo_d = upload_glo()
+                                me_col = jnp.arange(n_shards,
+                                                    dtype=labels_d.dtype)[:, None]
+                                labels_d = jnp.concatenate(
+                                    [labels_d, jnp.broadcast_to(
+                                        me_col, (n_shards, capT_o))], axis=1)
+                                depth_d = jnp.concatenate(
+                                    [depth_d, jnp.zeros((n_shards, capT_o),
+                                                        depth_d.dtype)], axis=1)
+                                res = band_migrate_iteration(
+                                    stacked, met_s, glo_d, glo, labels_d, depth_d,
+                                    shared_prev, n_shards, verbose=verbose)
+                            if res is not None:
+                                (stacked, met_s, glo_d, comms2, shared_prev,
+                                 nmoved, arr_slots) = res
+                                band_done = True
+                                if nmoved:
+                                    comms = comms2
+                                    # weld the arrival neighborhoods (region-scoped)
+                                    stacked, glo_d, nweld = band_weld(
+                                        stacked, met_s, glo_d, glo, arr_slots,
+                                        n_shards, verbose=verbose)
+                                    if nweld < 0:     # region budget blown: full weld
+                                        if multi:
+                                            # fail loudly (the designed
+                                            # contract) instead of the opaque
+                                            # non-addressable fetch error
+                                            # pull_views would raise
+                                            raise NotImplementedError(
+                                                "full-region weld fallback is "
+                                                "single-controller; band_weld'"
+                                                "s escalating probe must hold "
+                                                "on a multi-process run")
+                                        views_w = pull_views(stacked, met_s)
+                                        stacked, _ = weld_shard_bands(
+                                            stacked, views_w, glo, n_shards,
+                                            verbose=verbose)
+                                        # the full weld freed host-glo rows; the
+                                        # device copy must drop them too (stale
+                                        # gids resurrect — see band_weld)
+                                        glo_d = upload_glo()
+                                    stacked = rebuild_shards(stacked)
+                                    echo_check()
+                            else:
+                                otrace.log(1, f"  it {it}: band budgets exceeded — "
+                                              "falling back to the full-view path",
+                                           verbose=verbose)
+                        if not band_done:
+                            if multi:
+                                raise NotImplementedError(
+                                    "full-view migration fallback is "
+                                    "single-controller; band budgets must hold on "
+                                    "a multi-process run")
+                            if views is None:
+                                views = pull_views(stacked, met_s)
+                            if mode == "graph":
+                                labels = graph_repartition_labels(views, glo,
+                                                                  n_shards)
+                                labels = enforce_ne_min(labels, views.tmask,
+                                                        n_shards)
+                            else:
+                                from .migrate_dev import repair_flood_labels
+                                sizes = jnp.asarray(
+                                    views.tmask.sum(axis=1).astype(np.int32))
+                                labels_d, depth_d = flood_labels(
+                                    stacked, jnp.asarray(comms.node_idx),
+                                    jnp.asarray(comms.nbr), sizes, n_shards,
+                                    nlayers=ifc_layers)
+                                labels_d, _nfix = repair_flood_labels(
+                                    stacked, labels_d, depth_d, n_shards,
+                                    verbose=verbose)
+                                labels = np.asarray(labels_d)
+                                labels = enforce_ne_min(labels, views.tmask,
+                                                        n_shards,
+                                                        depth=np.asarray(depth_d))
+                            touched = sorted({int(r) for s_ in range(n_shards)
+                                              for r in np.unique(
+                                                  labels[s_][views.tmask[s_]])
+                                              if int(r) != s_})
+                            stacked, met_s, comms2, nmoved = migrate_shards(
+                                stacked, met_s, views, glo, labels, n_shards,
+                                verbose=verbose)
+                            if nmoved:
+                                comms = comms2
+                                stacked, _ = weld_shard_bands(
+                                    stacked, views, glo, n_shards,
+                                    touched=touched, verbose=verbose)
+                                stacked = rebuild_shards(stacked)
+                                echo_check()
+                            if use_band:    # resync the device numbering copy
+                                glo_d = upload_glo()
+                                shared_prev = _shared_gids(comms, glo, n_shards)
+                        if nmoved:
+                            otrace.log(2, f"  it {it}: migrated {nmoved} "
+                                          "interface-band tets", verbose=verbose)
+                        # host-to-host group handoff (pod runtime, opt-in knob
+                        # PARMMG_MH_HANDOFF): when device loads skew past the
+                        # imbalance threshold, whole logical shards move to other
+                        # devices — and thereby other processes — as one compiled
+                        # permutation; comm tables + numbering mirrors remap in
+                        # lockstep (parallel/pod.py).  gids are unchanged under a
+                        # permutation, so shared_prev needs no update.
+                        if pod.handoff_enabled() and use_band and glo_d is not None:
+                            (stacked, met_s, glo_d, glo, comms,
+                             nmv_h) = pod.maybe_handoff(stacked, met_s, glo_d, glo,
+                                                        comms, verbose=verbose)
+                            if nmv_h:
+                                echo_check()
+                        # what crossed between shards: every moved tet with its
+                        # four vertices' rows, and the band tables the hosts
+                        # exchanged (pod.gather_band)
+                        moved_bytes = nmoved * _moved_tet_bytes(met_s) + int(
+                            mh_band.value - mh0)
+                        span_mig.set(moved_tets=nmoved, bytes=moved_bytes,
+                                     band_rows=int((comms.node_idx >= 0).sum()))
+                        tally["migrated_tets"] += nmoved
+                        tally["exchange_bytes"] += moved_bytes
+                        if staged[0]:
+                            # back to their devices for the next blocks
+                            span_mig.set(push_bytes=sum(
+                                a.nbytes for a in
+                                jax.tree.leaves((stacked, met_s))))
+                            stacked, met_s = on_devices()
+                            staged[0] = False
+                if staged[0] and it + 1 < max(1, niter):
+                    # nothing migrated them back (``nobalancing``)
+                    stacked, met_s = on_devices()
+                    staged[0] = False
             if ckpt_tag is not None:
                 from ..core.mesh import MESH_FIELDS
                 from ..resilience.checkpoint import (ckpt_due,
@@ -1305,29 +1474,67 @@ def distributed_adapt_multi(mesh: Mesh, met, n_shards: int,
                                 "writes, the others only needed the "
                                 "agreement"))
     otrace.set_context(**{"pass": None})
-    _t_seg = time.perf_counter()
-    if multi:
-        # final output: replicate the (end-state) shards to every
-        # process and merge identically everywhere — the
-        # centralized-output analogue of PMMG_parmmglib_centralized's
-        # gather (the distributed-output entry, io.distributed, writes
-        # per-process rank files instead and never pays this gather).
-        # OUTSIDE the hot path: this is the one designed O(mesh)
-        # replication of a centralized run, visible on
-        # mh.allgather_bytes but never on the hot counter.
-        # lint: ok(R7) — the documented final-output gather
-        stacked = jax.tree.map(_pull, stacked)
-        # lint: ok(R7) — same final-output gather
-        met_s = _pull(met_s)
-    else:
-        # one pull per leaf (merge_shards slices per shard, which on
-        # sharded device arrays is a program plus a pull per field)
-        stacked, met_s = jax.tree.map(np.asarray, (stacked, met_s))
-    with host_staging():
-        merged, met_m, part_new = merge_shards(stacked, met_s,
-                                               return_part=True)
-    otrace.emit_span("dist.merge", time.perf_counter() - _t_seg)
+    with otrace.span("dist merge") as sp:
+        if multi:
+            # final output: replicate the (end-state) shards to every
+            # process and merge identically everywhere — the
+            # centralized-output analogue of PMMG_parmmglib_centralized's
+            # gather (the distributed-output entry, io.distributed, writes
+            # per-process rank files instead and never pays this gather).
+            # OUTSIDE the hot path: this is the one designed O(mesh)
+            # replication of a centralized run, visible on
+            # mh.allgather_bytes but never on the hot counter.
+            # lint: ok(R7) — the documented final-output gather
+            stacked = jax.tree.map(_pull, stacked)
+            # lint: ok(R7) — same final-output gather
+            met_s = _pull(met_s)
+        else:
+            # one pull per leaf (merge_shards slices per shard, which on
+            # sharded device arrays is a program plus a pull per field)
+            stacked, met_s = jax.tree.map(np.asarray, (stacked, met_s))
+        with host_staging():
+            merged, met_m, part_new = merge_shards(stacked, met_s,
+                                                   return_part=True)
+        sp.set(ne=len(part_new))
+    _publish_tally(tally, stats)
     return merged, met_m, part_new
+
+
+def _new_tally() -> dict:
+    """What one SPMD job counts for its ``dist.*`` counters."""
+    return {"dispatches": 0, "compute_s": 0.0, "exchange_bytes": 0,
+            "migrated_tets": 0, "live_tets": 0, "largest_shard": 0,
+            "devices": 0}
+
+
+def _publish_tally(tally: dict, stats=None) -> None:
+    """A job's ``dist.*`` counters, zeros too (the grouped path's
+    ``groups.*``): block dispatches and their dispatch-to-pull seconds,
+    bytes that crossed between shards (halo exchanges, band migration,
+    the hosts' band tables), tets the displacement moved, the live tets
+    and the fullest shard at the last iteration's entry, and the
+    distinct devices that held a live shard after the last blocks."""
+    REGISTRY.counter("dist.dispatches").inc(tally["dispatches"])
+    REGISTRY.counter("dist.pipeline.compute_s").inc(tally["compute_s"])
+    for k in ("exchange_bytes", "migrated_tets", "live_tets",
+              "largest_shard", "devices"):
+        # lint: ok(R6) — k ranges over the five literals above
+        REGISTRY.counter(f"dist.{k}").inc(tally[k])
+    if stats is not None:
+        stats.sched_extra["dist_devices"] = tally["devices"]
+
+
+def _halo_bytes(comms, width: int) -> int:
+    """Bytes one halo exchange moves: ``width`` a valid interface slot."""
+    return int((np.asarray(comms.node_idx) >= 0).sum()) * width
+
+
+def _moved_tet_bytes(met_s) -> int:
+    """Bytes ``device_migrate`` ships a moved tet: its global vertex
+    ids, reference and face and edge tags and references (19 words),
+    and the coordinates, tags, references and metric of four vertices."""
+    m = int(np.prod(met_s.shape[2:], dtype=np.int64))
+    return 4 * (19 + 4 * (3 + 2 + m))
 
 
 def _shared_gids(comms, glo, n_shards: int) -> np.ndarray:

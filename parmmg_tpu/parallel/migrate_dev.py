@@ -246,8 +246,11 @@ def device_migrate(stacked: Mesh, met_s, glo_d, labels, depth,
 
     # ---- liveness + watermarks ------------------------------------------
     tid = jnp.where(tmask2[..., None], tet2, capP)
+    # (row index [S, 1] against [S, 4 capT]: one more axis on it would
+    # broadcast shard against shard and mark every shard's rows live in
+    # every other)
     ref = jnp.zeros((S, capP + 1), bool).at[
-        sidx[..., None], tid.reshape(S, -1)].max(
+        sidx, tid.reshape(S, -1)].max(
         True, mode="drop")[:, :capP]
     vmask2 = ref
     glo2 = jnp.where(ref, glo2, -1)
@@ -486,7 +489,7 @@ def band_region_probe(stacked: Mesh, glo_d, seed_tets, KW: int, KWp: int):
     seed_ok = (seed_tets < capT)[..., None]                # [S,KB,1]
     seed_vids = jnp.where(seed_ok, stacked.tet[sidx, seedc], capP)
     vmark = jnp.zeros((S, capP + 1), bool).at[
-        sidx[..., None], seed_vids.reshape(S, -1)].max(
+        sidx, seed_vids.reshape(S, -1)].max(
         True, mode="drop")[:, :capP]
     tc = jnp.clip(stacked.tet, 0, capP - 1)
 

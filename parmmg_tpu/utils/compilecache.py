@@ -49,6 +49,8 @@ CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 # the entry whose executables ``compile.block_programs`` counts: the
 # grouped cycle block, minutes of compile and 115 MB of cache apiece
 BLOCK_ENTRY = "groups.adapt_block"
+# the same cycle under ``shard_map`` (parallel/dist): as long a compile
+DIST_BLOCK_ENTRY = "dist.adapt_block"
 
 
 # ---------------------------------------------------------------------------
@@ -151,7 +153,8 @@ class CompileLedger:
     counts in ``compile.placement_variants``, and the ``compile`` event
     of its executable carries ``variant="placement"`` and ``leaves``,
     the paths of the arguments that differ.  ``compile.block_programs``
-    counts the executables of :data:`BLOCK_ENTRY`, built or loaded.
+    counts the executables of :data:`BLOCK_ENTRY` and
+    :data:`DIST_BLOCK_ENTRY`, built or loaded.
 
     Kept signatures.  At a lowering, and never on a later call, the
     entry keeps the call's abstract signature under its static-shape key
@@ -243,7 +246,7 @@ class CompileLedger:
             stack[-1].variant = None
         otrace.event("compile", fun=fun, dur=round(duration, 6), **variant)
         name = stack[-1].name if stack else self.UNGOVERNED
-        if name == BLOCK_ENTRY:
+        if name in (BLOCK_ENTRY, DIST_BLOCK_ENTRY):
             REGISTRY.counter("compile.block_programs").inc()
         with self._lock:
             e = self._entries.setdefault(name, EntryStats())

@@ -177,7 +177,7 @@ def worker() -> None:
     if pid == 0:
         # canonical schema-versioned artifact (obs/artifact.py) — the
         # legacy result dict rides in extra; per-phase spans ride the
-        # trace digest (dist.adapt/refresh/migrate/merge)
+        # trace digest (dist split/block/refresh/displace/migrate/merge)
         from parmmg_tpu.obs.artifact import make_artifact
         print(json.dumps(make_artifact(
             "MULTIHOST", metric="multihost_adapt",

@@ -67,7 +67,7 @@ def test_api_multidevice():
     pm.set_met_size(1, len(vert))
     pm.set_scalar_mets(np.full(len(vert), 0.3))
     pm.set_iparameter(IParam.niter, 2)
-    pm.info.n_devices = 4
+    pm.set_iparameter(IParam.nDevices, 4)
     assert pm.run() == C.PMMG_SUCCESS
     v, _ = pm.get_vertices()
     t, _ = pm.get_tetrahedra()

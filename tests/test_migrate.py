@@ -103,7 +103,7 @@ def test_driver_uses_shard_resident_path():
         pm.set_met_size(1, len(vert))
         pm.set_scalar_mets(np.full(len(vert), 0.35))
         pm.set_iparameter(IParam.niter, 2)
-        pm.info.n_devices = 4
+        pm.set_iparameter(IParam.nDevices, 4)
         assert pm.run() == C.PMMG_SUCCESS
     finally:
         distribute.merge_shards = orig
