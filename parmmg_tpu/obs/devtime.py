@@ -37,6 +37,7 @@ from __future__ import annotations
 import bisect
 import glob
 import json
+import math
 import os
 import re
 import time
@@ -81,6 +82,10 @@ _CALLEE = re.compile(r"\b(?:body|condition|to_apply|calls|true_computation"
                      r"|false_computation)=%?([\w.\-]+)")
 _BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
 _MODULE_ID = re.compile(r"\(\d+\)$")
+# `%name = f32[8516,3]{1,0:T(8,128)} opcode(`: an array result's type and
+# dimensions (a tuple's has no such head and reads None)
+_RESULT = re.compile(r" = (\w+)\[([\d,]*)\]")
+_SHAPE = re.compile(r"\w+\[[\d,]*\](?:\{[^}]*\})?")
 
 
 class ScopeMap(NamedTuple):
@@ -92,7 +97,9 @@ class ScopeMap(NamedTuple):
                  (outside fused computations; ``while`` / ``conditional``
                  / ``call`` and what never executes left out), ``scoped``
                  those of them under a phase, ``sorts`` the ``sort``
-                 instructions among them, ``sorts_by_phase``;
+                 instructions among them, ``sorts_by_phase``; where the
+                 program's capacities are known, ``scalar_gathers`` and
+                 ``scalar_gathers_by_phase`` (:func:`map_from_text`);
     ``control``  the control-flow instructions: their events span their
                  bodies', so no sum counts them;
     ``module``   the name of the program's module events in a capture.
@@ -118,9 +125,26 @@ def scopes_of(op_name: str) -> tuple:
     return phase, table
 
 
+class Instruction(NamedTuple):
+    name: str
+    opcode: str
+    op_name: str
+    callees: list
+    dims: tuple | None      # an array result's dimensions
+    operands: list          # the operands' names
+
+
+def _operands(line: str, start: int) -> list:
+    """The names between the ``(`` at ``start`` and the ``)`` that closes
+    it; an operand may carry its shape in front (XLA:CPU's text does)."""
+    rest = _SHAPE.sub("", line[start + 1:])
+    return [tok.strip().lstrip("%")
+            for tok in rest[:rest.find(")")].split(",") if tok.strip()]
+
+
 def parse_hlo(text: str):
-    """(module name, entry computation, {computation: [(instruction,
-    opcode, op_name, callees)]}) of an optimised module's text."""
+    """(module name, entry computation, {computation: [Instruction]}) of
+    an optimised module's text."""
     module, entry, comps, cur = "", None, {}, None
     for line in text.splitlines():
         if not line:
@@ -143,25 +167,54 @@ def parse_hlo(text: str):
         callees = _CALLEE.findall(line)
         for group in _BRANCHES.findall(line):
             callees += [c.strip().lstrip("%") for c in group.split(",")]
-        cur.append((m.group(1), m.group(2), op.group(1) if op else "",
-                    callees))
+        res = _RESULT.search(line, 0, m.end())
+        dims = None if res is None else tuple(
+            int(d) for d in res.group(2).split(",") if d)
+        cur.append(Instruction(m.group(1), m.group(2),
+                               op.group(1) if op else "", callees, dims,
+                               _operands(line, m.end() - 1)))
     return module, entry, comps
 
 
-def map_from_text(text: str) -> ScopeMap:
+def map_from_text(text: str, capP: int | None = None,
+                  capT: int | None = None) -> ScopeMap:
     """The scope map of an optimised module's text.  An instruction whose
     own path names no phase takes the phase of the instruction that
     calls its computation (a ``while`` body, a ``conditional`` branch, a
     ``call``), else ``unscoped`` (the ``lax.map`` row's glue, and the
     copies the compiler puts between two stages: 3.2 % of an
-    ``iso-growth`` block's device seconds, ``PERF.md`` section 5)."""
+    ``iso-growth`` block's device seconds, ``PERF.md`` section 5).
+
+    With the program's capacities (``capP`` vertices, ``capT`` tets a
+    mesh) the counts also hold ``scalar_gathers``: the ``gather``
+    instructions, inside fused computations too, that fetch ONE scalar
+    an index out of a per-vertex vector at a tet table's width or over
+    (operand of rank 1 and at most ``capP + 1`` elements, result of at
+    least ``capT`` elements), the dear kind of fetch on the chip
+    (``ops/rowpack``; PERF.md section 5, PR 42); and
+    ``scalar_gathers_by_phase``."""
     module, entry, comps = parse_hlo(text)
     phases: dict = {}
     control = set()
     counts = {"ops": 0, "scoped": 0, "sorts": 0, "sorts_by_phase": {}}
+    if capP is not None and capT is not None:
+        counts.update(scalar_gathers=0, scalar_gathers_by_phase={})
+    dims_of = {i.name: i.dims for body in comps.values() for i in body}
+
+    def count(key, phase):
+        counts[key] += 1
+        by = counts[key + "_by_phase"]
+        by[phase or UNSCOPED] = by.get(phase or UNSCOPED, 0) + 1
+
+    def scalar_gather(i) -> bool:
+        table = dims_of.get(i.operands[0]) if i.operands else None
+        return i.opcode == "gather" and i.dims is not None \
+            and table is not None and len(table) == 1 \
+            and table[0] <= capP + 1 and math.prod(i.dims) >= capT
 
     def walk(comp, inherited, depth=0):
-        for name, opcode, op_name, callees in comps.get(comp, ()):
+        for instr in comps.get(comp, ()):
+            name, opcode, op_name, callees = instr[:4]
             phase, table = scopes_of(op_name)
             phase, table = phase or inherited[0], table or inherited[1]
             phases[name] = (phase or UNSCOPED, table)
@@ -177,9 +230,13 @@ def map_from_text(text: str) -> ScopeMap:
             counts["ops"] += 1
             counts["scoped"] += phase is not None
             if opcode == "sort":
-                counts["sorts"] += 1
-                by = counts["sorts_by_phase"]
-                by[phase or UNSCOPED] = by.get(phase or UNSCOPED, 0) + 1
+                count("sorts", phase)
+            if "scalar_gathers" in counts:
+                fused = [i for c in callees for i in comps.get(c, ())] \
+                    if opcode == "fusion" else []
+                for i in [instr] + fused:
+                    if scalar_gather(i):
+                        count("scalar_gathers", phase)
 
     if entry is not None:
         walk(entry, (None, None))
@@ -220,8 +277,20 @@ def scope_map(entry: str = BLOCK_ENTRY, key: tuple | None = None
         # backend
         with LEDGER.ungoverned(), jax.default_device(device):
             text = fn.lower(*args, **kwargs).compile().as_text()
-        _MAPS[ident] = map_from_text(text)
+        _MAPS[ident] = map_from_text(text, *_capacities(args))
     return _MAPS[ident]
+
+
+def _capacities(args) -> tuple:
+    """(capP, capT) of the mesh a governed program's signature holds, a
+    row of it where the meshes are stacked; (None, None) without one."""
+    import jax
+    from ..core.mesh import Mesh
+    for leaf in jax.tree_util.tree_leaves(
+            args, is_leaf=lambda x: isinstance(x, Mesh)):
+        if isinstance(leaf, Mesh):
+            return leaf.vert.shape[-2], leaf.tet.shape[-2]
+    return None, None
 
 
 # ---------------------------------------------------------------------------

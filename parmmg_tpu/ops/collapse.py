@@ -34,6 +34,7 @@ from ..core.constants import (
     MG_REQ, MG_PARBDY, MG_PARBDYBDY, QUAL_FLOOR)
 from .edges import (unique_edges, edge_lengths, claim_channels,
                     scatter_argmax2, NEG_INF, PRI_MIN)
+from . import rowpack
 
 _IDIR_J = jnp.asarray(IDIR)
 
@@ -126,16 +127,34 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
     va_f = jnp.clip(et.ev[:, 0], 0, capP - 1)
     vb_f = jnp.clip(et.ev[:, 1], 0, capP - 1)
 
+    # what the candidacy reads of an endpoint rides in ONE row a vertex
+    # (ops/rowpack: a row gather costs a third of one scalar's): the tag
+    # word, the staleness flag and, under hausd, the coordinates, the
+    # normal and the line tangent
+    stale = sliver_q is None and stale_tets is not None
+    cols = {"tag": mesh.vtag}
+    if stale:
+        # staleness veto: vertices of any tet the split modified
+        cols["stale"] = jnp.zeros(capP + 1, bool).at[
+            jnp.where(stale_tets[:, None], mesh.tet, capP)
+            .reshape(-1)].max(
+            jnp.repeat(stale_tets, 4), mode="drop")[:capP]
+    if hausd is not None:
+        from .analysis import boundary_vertex_normals, \
+            ridge_vertex_tangents
+        if vn is None:
+            vn = boundary_vertex_normals(mesh)
+        tanv = vtan if vtan is not None \
+            else ridge_vertex_tangents(mesh, et=et)
+        cols.update(p=mesh.vert, n=vn, tan=tanv)
+    ends = rowpack.pack(**cols)
+    end_a, end_b = ends.take(va_f), ends.take(vb_f)
+
     frozen_edge = (et.etag & (MG_REQ | MG_PARBDY)) != 0
     if sliver_q is None:
         short = et.emask & (lens < lmin) & ~frozen_edge
-        if stale_tets is not None:
-            # staleness veto: vertices of any tet the split modified
-            stale_v = jnp.zeros(capP + 1, bool).at[
-                jnp.where(stale_tets[:, None], mesh.tet, capP)
-                .reshape(-1)].max(
-                jnp.repeat(stale_tets, 4), mode="drop")[:capP]
-            short = short & ~stale_v[va_f] & ~stale_v[vb_f]
+        if stale:
+            short = short & ~end_a["stale"] & ~end_b["stale"]
     else:
         if q_tet is None:
             from .quality import quality_from_points
@@ -149,7 +168,7 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
         # don't lengthen already-long edges by contracting into them
         short = et.emask & bad_edge & ~frozen_edge & (lens < lmax)
 
-    ta_f, tb_f = mesh.vtag[va_f], mesh.vtag[vb_f]
+    ta_f, tb_f = end_a["tag"], end_b["tag"]
     rem_b_f = _removable(tb_f, ta_f, et.etag)   # can delete b (keep a)
     rem_a_f = _removable(ta_f, tb_f, et.etag)
     pre = short & (rem_a_f | rem_b_f)
@@ -159,24 +178,19 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
         # the top-K cut: a post-cut veto would let permanently-vetoed
         # boundary edges pin budget slots every wave, starving legal
         # candidates ranked past K
-        from .analysis import boundary_vertex_normals, \
-            ridge_vertex_tangents
-        if vn is None:
-            vn = boundary_vertex_normals(mesh)
+        na_f, nb_f = end_a["n"], end_b["n"]
+        tana_f, tanb_f = end_a["tan"], end_b["tan"]
         on_bdy_f = (et.etag & MG_BDY) != 0
-        d_f = mesh.vert[vb_f] - mesh.vert[va_f]
-        na_f, nb_f = vn[va_f], vn[vb_f]
+        d_f = end_b["p"] - end_a["p"]
         t_a = d_f - na_f * jnp.sum(na_f * d_f, -1, keepdims=True)
         t_b = d_f - nb_f * jnp.sum(nb_f * d_f, -1, keepdims=True)
         dev = jnp.linalg.norm(0.125 * (t_a - t_b), axis=-1)
         # feature-line edges: curvature deviation along the LINE
         # tangent, not the (multivalued) surface normal — matches the
         # tangent-circle lift in split_wave
-        tanv = vtan if vtan is not None \
-            else ridge_vertex_tangents(mesh, et=et)
         on_line_f = (et.etag & (MG_GEO | MG_REF)) != 0
-        ta_l = tanv[va_f] * jnp.sum(tanv[va_f] * d_f, -1, keepdims=True)
-        tb_l = tanv[vb_f] * jnp.sum(tanv[vb_f] * d_f, -1, keepdims=True)
+        ta_l = tana_f * jnp.sum(tana_f * d_f, -1, keepdims=True)
+        tb_l = tanb_f * jnp.sum(tanb_f * d_f, -1, keepdims=True)
         dev_l = jnp.linalg.norm(0.125 * (ta_l - tb_l), axis=-1)
         dev = jnp.where(on_line_f, dev_l, dev)
         hveto = pre & on_bdy_f & (dev > hausd)
@@ -243,10 +257,21 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
         # [4T] stacked variants, with the contested/invalid cases folded
         # into the same ball-quality scatter as -inf rows
         # (validity+ballq was ~28 ms; a block by phase: PERF.md section 5).
+        # a corner reads its coordinates, its two claim maxima and, where
+        # the stage wants them, its size, its normal and whether it is
+        # singular in ONE row (ops/rowpack)
         tv = mesh.tet                                          # [T,4]
-        vpos = mesh.vert[tv]                                   # [T,4,3]
-        vs_c = v_s[tv]                                         # [T,4] score max
-        vt_c = v_t[tv]                                         # [T,4] tie max
+        cols = {"p": mesh.vert, "s": v_s[:capP], "t": v_t[:capP]}
+        if met.ndim == 1:
+            cols["h"] = met
+        if hausd is not None:
+            cols.update(n=vn, sing=(mesh.vtag & (
+                MG_GEO | MG_CRN | MG_REF | MG_NOM)) != 0)
+        at_vertex = rowpack.pack(**cols)
+        corner = at_vertex.take(tv)
+        vpos = corner["p"]                                     # [T,4,3]
+        vs_c = corner["s"]                                     # [T,4] score max
+        vt_c = corner["t"]                                     # [T,4] tie max
         has_c = jnp.isfinite(vs_c)        # corner is a top-removal target
         tmax_s = jnp.max(jnp.where(mesh.tmask[:, None], vs_c, NEG_INF), axis=1)
         selc = (vs_c == tmax_s[:, None]) & jnp.isfinite(tmax_s)[:, None]
@@ -258,8 +283,13 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
         kc = jnp.argmax(claimed, axis=1)                       # [T]
         ar0 = jnp.arange(capT)
         rm_v = tv[ar0, kc]                                     # claimed target
-        kept_v = kept_of[jnp.clip(rm_v, 0, capP - 1)]          # its kept vtx
-        kept_p = mesh.vert[jnp.clip(kept_v, 0, capP - 1)]      # [T,3]
+        # its kept vertex, and that vertex's own row, composed a vertex
+        # ([capP] wide) so that a tet fetches both in one row
+        of_kept = at_vertex.take(jnp.clip(kept_of, 0, capP - 1))
+        del of_kept["s"], of_kept["t"]
+        kept = rowpack.pack(v=kept_of, **of_kept).take(
+            jnp.clip(rm_v, 0, capP - 1))
+        kept_v, kept_p = kept["v"], kept["p"]                  # [T], [T,3]
         # does this tet also contain the kept vertex? then it dies with the
         # collapse — it drops out of the surviving ball, no checks needed
         contains_kept = jnp.zeros(capT, bool)
@@ -278,13 +308,11 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
         bad = vol <= EPSD
         if hausd is not None:
             from .analysis import face_depth
-            sing_v = (mesh.vtag & (MG_GEO | MG_CRN | MG_REF | MG_NOM)) != 0
             # the simulated tet's corner normals, and which corners are
             # singular (ridge, corner, reference line: no one normal)
-            kept_c = jnp.clip(kept_v, 0, capP - 1)
-            nrm_c = jnp.where(oh[..., None], vn[kept_c][:, None, :],
-                              vn[tv])                          # [T,4,3]
-            sing_c = jnp.where(oh, sing_v[kept_c][:, None], sing_v[tv])
+            nrm_c = jnp.where(oh[..., None], kept["n"][:, None, :],
+                              corner["n"])                     # [T,4,3]
+            sing_c = jnp.where(oh, kept["sing"][:, None], corner["sing"])
             deep = jnp.zeros(capT, bool)
         # fold-over: boundary faces containing the claimed corner must
         # keep their orientation
@@ -319,9 +347,8 @@ def collapse_wave(mesh: Mesh, met: jax.Array, lmin: float = LSHRT,
         if met.ndim == 1:
             from .quality import edge_length_iso
             for j in range(4):
-                lnew = edge_length_iso(kept_p, p[:, j],
-                                       met[jnp.clip(kept_v, 0, capP - 1)],
-                                       met[tv[:, j]])
+                lnew = edge_length_iso(kept_p, p[:, j], kept["h"],
+                                       corner["h"][:, j])
                 bad = bad | ((lnew > lmax) & (kc != j))
 
         # --- ball-quality gate ----------------------------------------------
@@ -420,7 +447,7 @@ def _collapse_apply(mesh: Mesh, met, win, rm, kp, capT, capP):
     remap = jnp.arange(capP, dtype=jnp.int32)
     remap = remap.at[jnp.where(win, rm, capP)].set(
         kp, mode="drop", unique_indices=True)   # winners exclusive at rm
-    new_tet = remap[mesh.tet]
+    new_tet = rowpack.take(remap, mesh.tet)     # remap[tet], by rows
     # dead = any duplicated vertex pair (tet contained rm and kp)
     dup = jnp.zeros(capT, bool)
     for i in range(4):
@@ -603,7 +630,7 @@ def _collapse_tag_joins(mesh: Mesh, new_tet, dead, tmask, capT, capP):
     rv = jnp.zeros(capP + 1, bool).at[
         jnp.where(dead[:, None], new_tet, capP).reshape(-1)].max(
         jnp.repeat(dead, 4), mode="drop")[:capP]
-    band = dead | (tmask & jnp.any(rv[new_tet], axis=1))
+    band = dead | (tmask & jnp.any(rowpack.take(rv, new_tet), axis=1))
     nband = jnp.sum(band.astype(jnp.int32))
 
     def _banded(_):

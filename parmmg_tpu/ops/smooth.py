@@ -29,6 +29,7 @@ from ..core.constants import (
     EPSD, QUAL_FLOOR)
 from .quality import quality_from_points
 from .edges import PRI_MIN
+from . import rowpack
 
 # a regular surface point slides in its tangent plane when its incident
 # boundary faces are mutually near-parallel — the move is then
@@ -250,6 +251,8 @@ def smooth_wave(mesh: Mesh, met: jax.Array, wave: int = 0,
         vworst = jnp.full(capP + 1, -jnp.inf, mesh.vert.dtype).at[
             idx4].max(jnp.tile(sworst, 4), mode="drop")[:capP]
         dacc = jnp.zeros((capP + 1, 4), mesh.vert.dtype)
+        # one row a corner (ops/rowpack), not four 1-D gathers
+        vworst_c = rowpack.take(vworst, tv)               # [T,4]
         for k in range(4):
             fidx = idir[k]                                 # face opp k
             p0 = vpos[:, fidx[0]]
@@ -257,7 +260,7 @@ def smooth_wave(mesh: Mesh, met: jax.Array, wave: int = 0,
             n2 = jnp.maximum(jnp.sum(nrm * nrm, -1, keepdims=True), EPSD)
             d = nrm * (jnp.sum((vpos[:, k] - p0) * nrm, -1,
                                keepdims=True) / n2)        # [T,3]
-            is_w = mesh.tmask & (sworst >= vworst[tv[:, k]])
+            is_w = mesh.tmask & (sworst >= vworst_c[:, k])
             pay = jnp.concatenate(
                 [jnp.where(is_w[:, None], d, 0.0),
                  is_w[:, None].astype(mesh.vert.dtype)], axis=1)
@@ -315,11 +318,15 @@ def smooth_wave(mesh: Mesh, met: jax.Array, wave: int = 0,
     h = h * jnp.uint32(2654435761)
     h = h ^ (h >> 13)
     vpri = jnp.where(improves, h.astype(jnp.int32), PRI_MIN)
-    tclaim = jnp.max(jnp.where(mesh.tmask[:, None], vpri[tv], PRI_MIN),
+    # a corner's priority and whether it improves ride in ONE row
+    # (ops/rowpack: a row gather costs a third of one scalar's)
+    corner = rowpack.pack(pri=vpri, improves=improves).take(tv)
+    vpri_c = corner["pri"]                                 # [T,4]
+    tclaim = jnp.max(jnp.where(mesh.tmask[:, None], vpri_c, PRI_MIN),
                      axis=1)
-    vpri_c = vpri[tv]                                      # [T,4]
     mism4 = jnp.concatenate(
-        [improves[tv[:, k]] & (tclaim != vpri_c[:, k]) for k in range(4)])
+        [corner["improves"][:, k] & (tclaim != vpri_c[:, k])
+         for k in range(4)])
     lost = jnp.zeros(capP + 1, bool).at[idx4].max(mism4, mode="drop")
     win = improves & ~lost[:capP]
 

@@ -34,6 +34,7 @@ from ..core.constants import (
     MG_PARBDY, MG_REF)
 from .edges import (EdgeTable, unique_edges, edge_lengths, claim_channels,
                     NEG_INF, PRI_MIN)
+from .rowpack import pack
 
 _IARE_J = jnp.asarray(IARE)
 
@@ -117,31 +118,43 @@ def split_wave(mesh: Mesh, met: jax.Array, lmax: float = LLONG,
     va = jnp.clip(et.ev[:, 0], 0, capP - 1)
     vb = jnp.clip(et.ev[:, 1], 0, capP - 1)
     frozen_edge = (et.etag & (MG_REQ | MG_PARBDY)) != 0
-    if fem_only:
-        both_bdy = ((mesh.vtag[va] & MG_BDY) != 0) & \
-            ((mesh.vtag[vb] & MG_BDY) != 0)
-        cand = et.emask & ((et.etag & MG_BDY) == 0) & both_bdy & \
-            ~frozen_edge
-    else:
-        cand = et.emask & (lens > lmax) & ~frozen_edge
-    lift_corr = None
+    # what the candidacy reads of an endpoint rides in ONE row a vertex
+    # (ops/rowpack: a row gather costs a third of one scalar's)
+    cols = {}
+    if fem_only or hausd is not None:
+        cols["tag"] = mesh.vtag
     if hausd is not None:
         from .analysis import boundary_vertex_normals, carries_normal, \
             ridge_vertex_tangents
         from ..core.constants import MG_CRN, MG_NOM
         if vn is None:      # else the caller's, of THIS mesh
             vn = boundary_vertex_normals(mesh)
+        tan = vtan if vtan is not None \
+            else ridge_vertex_tangents(mesh, et=et)
         # an endpoint has ONE normal unless it is a feature point or
         # frozen; a frozen seam vertex has one again where the mesh
         # carries it (its own fan is only the seam's near side)
         one_n = ((mesh.vtag & (MG_GEO | MG_CRN | MG_NOM | MG_REF)) == 0) & \
             (((mesh.vtag & (MG_REQ | MG_PARBDY)) == 0) |
              carries_normal(mesh))
+        cols.update(one_n=one_n, p=mesh.vert, n=vn, tan=tan)
+    if cols:
+        ends = pack(**cols)
+        end_a, end_b = ends.take(va), ends.take(vb)
+    if fem_only:
+        both_bdy = ((end_a["tag"] & MG_BDY) != 0) & \
+            ((end_b["tag"] & MG_BDY) != 0)
+        cand = et.emask & ((et.etag & MG_BDY) == 0) & both_bdy & \
+            ~frozen_edge
+    else:
+        cand = et.emask & (lens > lmax) & ~frozen_edge
+    lift_corr = None
+    if hausd is not None:
         regular = ((et.etag & MG_BDY) != 0) & \
             ((et.etag & (MG_GEO | MG_REQ | MG_PARBDY | MG_REF)) == 0) & \
-            one_n[va] & one_n[vb]
-        d = mesh.vert[vb] - mesh.vert[va]
-        na, nb = vn[va], vn[vb]
+            end_a["one_n"] & end_b["one_n"]
+        d = end_b["p"] - end_a["p"]
+        na, nb = end_a["n"], end_b["n"]
         t_a = d - na * jnp.sum(na * d, -1, keepdims=True)
         t_b = d - nb * jnp.sum(nb * d, -1, keepdims=True)
         corr = 0.125 * (t_a - t_b)                     # Bezier mid offset
@@ -157,15 +170,13 @@ def split_wave(mesh: Mesh, met: jax.Array, lmax: float = LLONG,
         # and maintains them across ranks, analys_pmmg.c:199-1171).
         # Without this, curved ridges (torus equator class) stay
         # piecewise-linear no matter how fine the metric.
-        tan = vtan if vtan is not None \
-            else ridge_vertex_tangents(mesh, et=et)
         hard = MG_CRN | MG_REQ | MG_PARBDY | MG_NOM
         on_line = ((et.etag & (MG_GEO | MG_REF)) != 0) & \
             ((et.etag & (MG_REQ | MG_PARBDY)) == 0) & \
-            ((mesh.vtag[va] & hard) == 0) & \
-            ((mesh.vtag[vb] & hard) == 0)
-        ta_l = tan[va] * jnp.sum(tan[va] * d, -1, keepdims=True)
-        tb_l = tan[vb] * jnp.sum(tan[vb] * d, -1, keepdims=True)
+            ((end_a["tag"] & hard) == 0) & \
+            ((end_b["tag"] & hard) == 0)
+        ta_l = end_a["tan"] * jnp.sum(end_a["tan"] * d, -1, keepdims=True)
+        tb_l = end_b["tan"] * jnp.sum(end_b["tan"] * d, -1, keepdims=True)
         corr_l = 0.125 * (ta_l - tb_l)
         lift_corr = jnp.where(on_line[:, None], corr_l, lift_corr)
     # Everything below (nomination, degeneracy veto, winner

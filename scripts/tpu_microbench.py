@@ -15,6 +15,10 @@ Primitives measured at bench-like shapes (capT=73728, capE=6*capT):
   adjacency     : full build_adjacency on the bench mesh
   edge_table    : full unique_edges on the bench mesh
 
+Sections (arguments; ``main`` alone by default): ``main`` the list above,
+``payload`` the scatter's and the gather's cost by payload width,
+``gathers`` the two prices of a gather at the cells' own shapes (PR 42).
+
 Run ON TPU (no JAX_PLATFORMS override):  python scripts/tpu_microbench.py
 Run on CPU for comparison:               JAX_PLATFORMS=cpu python ...
 """
@@ -120,10 +124,6 @@ def main():
     timed("edge_table", loop(et_body), mesh)
 
 
-if __name__ == "__main__":
-    main()
-
-
 def payload_scaling():
     """Does scatter cost scale with payload width?  If ~flat, narrow
     scatters should be BATCHED (one wide scatter replaces N narrow)."""
@@ -155,3 +155,155 @@ def payload_scaling():
 
         timed(f"gather_w{w}", loop(body),
               jax.random.uniform(key, (N_E,)))
+
+
+# the cells' own shapes (BENCHMARK.json: a group of `iso-growth` is
+# capP 8516, capT 43118; the edge table is 6 x capT wide)
+CELL_P = 8516
+CELL_T = 43118
+GATHER_REPS = int(os.environ.get("MB_GATHER_REPS", "200"))
+# run only the gather cases whose name starts with this
+GATHER_ONLY = os.environ.get("MB_GATHER_ONLY", "")
+
+
+def gather_prices():
+    """The two prices of a gather on this chip (PR 42): ONE scalar out of
+    a 1-D per-vertex table against a ROW out of a ``[capP, k]`` table at
+    the same indices, at the cells' own shapes.  Each case is
+    GATHER_REPS gathers chained through the index vector in one
+    ``fori_loop``; ``chain`` is the loop with no gather in it (the
+    elementwise pass that carries the dependency), to be subtracted.
+
+    Read on one v5e (PR 42's first chip call; ms a gather, sorted ``_e``
+    and random ``_r`` indices alike to 0.002; ``chain`` 0.005):
+
+    =========================================  =======  ===========
+    258,708 indices (6 x capT) into capP rows  ms       ns an index
+    =========================================  =======  ===========
+    ``u32[8516]`` / ``f32``, 1-D               1.728    6.7
+    ``pred[8516]``, 1-D                        1.976    7.6
+    ``u32[8516, 1]``                           1.728    6.7
+    rows of 2, 4, 8, 12, 16 ``u32`` / ``f32``  0.541-3  2.1
+    the same table stacked in the program      0.537-2  2.1
+    =========================================  =======  ===========
+
+    ``[43118, 4]`` indices: 1.313 (``pred`` 1.480) against 0.406 for a
+    row of 3, 4 or 8; ``[43118]``: 0.291 against 0.090; a per-TET
+    ``u32[43118]`` at 258,708 indices: 1.729 against 1.209 for a row of
+    four.  So a row of a per-vertex table costs 0.31 of one scalar
+    whatever it holds up to sixteen words, a row of ONE word costs the
+    scalar's price (``ops/rowpack`` gives a lone column its own copy for
+    company), and a per-tet table has no cheap row.  The table
+    TRANSPOSED, ``[k, capP]`` gathered into ``[k, 258708]``: 0.536 for
+    k = 4 and for k = 11 (PR 42's call 5): the row's price, with the
+    indices on the lanes."""
+    global K
+    K = GATHER_REPS
+    # dozens of small programs: keep them out of the machine's capped
+    # compile cache, where they could evict a block program
+    jax.config.update("jax_enable_compilation_cache", False)
+    P, T = CELL_P, CELL_T
+    print(f"\ngather prices (backend={jax.default_backend()} capP={P} "
+          f"capT={T} reps={K})")
+    rng = np.random.default_rng(0)
+    # an edge table's endpoints are sorted by (a, b); a tet's corners
+    # are not
+    idx_e = jnp.asarray(np.sort(rng.integers(0, P, 6 * T)), jnp.int32)
+    idx_r = jnp.asarray(rng.integers(0, P, 6 * T), jnp.int32)
+    idx_t4 = jnp.asarray(rng.integers(0, P, (T, 4)), jnp.int32)
+    idx_t = jnp.asarray(rng.integers(0, P, T), jnp.int32)
+    idx_pt = jnp.asarray(rng.integers(0, T, 6 * T), jnp.int32)
+    cols = [jnp.asarray(rng.integers(0, 2 ** 32, P, dtype=np.uint64)
+                        .astype(np.uint32)) for _ in range(16)]
+    colsT = [jnp.asarray(rng.integers(0, 2 ** 32, T, dtype=np.uint64)
+                         .astype(np.uint32)) for _ in range(4)]
+    u32 = jnp.uint32
+    f32 = jnp.float32
+    bc = jax.lax.bitcast_convert_type
+
+    def fold(g, ndim):
+        """One word a result row: every column is used."""
+        if g.dtype == jnp.bool_:
+            return g.astype(u32)
+        if g.dtype != u32:
+            g = bc(g, u32)
+        if g.ndim > ndim:
+            g = jax.lax.reduce(g, u32(0), jax.lax.bitwise_xor,
+                               (g.ndim - 1,))
+        return g
+
+    def case(name, fetch, idx, rows=P):
+        """``fetch(i, idx)`` chained through ``idx``, indices into a
+        table of ``rows``."""
+        if not name.startswith(GATHER_ONLY):
+            return
+        # 0xFFFFFFFF xor-folds out of random words about never: the
+        # index stays what it was, and the compiler cannot know
+        def body(i, idx):
+            w = fold(fetch(i, idx), idx.ndim)
+            return jnp.where(w == u32(0xFFFFFFFF), idx + 1, idx) \
+                % jnp.int32(rows)
+        dt = timed(name, loop(body), idx)
+        print(f"{'':14s} {dt * 1e9 / idx.size:9.2f} ns an index")
+
+    def table(k, dtype=u32):
+        t = jnp.stack(cols[:k], axis=1)
+        return t if dtype == u32 else bc(t, dtype)
+
+    for tag, idx in (("e", idx_e), ("r", idx_r)):
+        case(f"chain_{tag}", lambda i, x: x.astype(u32), idx)
+        case(f"u32_1d_{tag}", lambda i, x: cols[0][x], idx)
+        case(f"pred_1d_{tag}", lambda i, x: (cols[0] > 7)[x], idx)
+        case(f"f32_1d_{tag}", lambda i, x: bc(cols[0], f32)[x], idx)
+        case(f"u32_k1_{tag}", lambda i, x: cols[0][:, None][x], idx)
+        for k in (2, 4, 8, 12, 16):
+            tk = table(k)
+            case(f"u32_k{k}_{tag}", lambda i, x, tk=tk: tk[x], idx)
+        for k in (4, 8):
+            tk = table(k, f32)
+            case(f"f32_k{k}_{tag}", lambda i, x, tk=tk: tk[x], idx)
+        # the table made INSIDE the timed program (the layout the
+        # compiler gives a table it builds itself), from columns that
+        # change every repetition
+        for k in (4, 8):
+            case(f"stack_k{k}_{tag}", lambda i, x, k=k: jnp.stack(
+                [c + i.astype(u32) for c in cols[:k]], axis=1)[x], idx)
+            case(f"stackf_k{k}_{tag}", lambda i, x, k=k: bc(jnp.stack(
+                [c + i.astype(u32) for c in cols[:k]], axis=1), f32)[x],
+                idx)
+    # a tet's four corners ([T, 4] indices) and one vertex a tet ([T])
+    case("chain_t4", lambda i, x: x.astype(u32), idx_t4)
+    case("u32_1d_t4", lambda i, x: cols[0][x], idx_t4)
+    case("pred_1d_t4", lambda i, x: (cols[0] > 7)[x], idx_t4)
+    for k in (3, 4, 8):
+        tk = table(k)
+        case(f"u32_k{k}_t4", lambda i, x, tk=tk: tk[x], idx_t4)
+    case("chain_t", lambda i, x: x.astype(u32), idx_t)
+    case("u32_1d_t", lambda i, x: cols[0][x], idx_t)
+    for k in (4, 8, 16):
+        tk = table(k)
+        case(f"u32_k{k}_t", lambda i, x, tk=tk: tk[x], idx_t)
+    # the table transposed, ``[k, capP]`` gathered along its rows: the
+    # result ``[k, N]`` has the indices on the lanes, where a row
+    # result ``[N, k]`` pads k to 128 of them (PERF.md section 7, after
+    # PR 42)
+    for k in (4, 11):
+        tk = table(k).T
+
+        def columns(i, x, tk=tk):
+            g = tk[:, x]
+            return jax.lax.reduce(g, u32(0), jax.lax.bitwise_xor, (0,))
+        case(f"rowsT_k{k}_e", columns, idx_e)
+    # per-TET tables at the edge table's width
+    case("chain_pt", lambda i, x: x.astype(u32), idx_pt, rows=T)
+    case("tet_1d_pt", lambda i, x: colsT[0][x], idx_pt, rows=T)
+    tT = jnp.stack(colsT, axis=1)
+    case("tet_k4_pt", lambda i, x: tT[x], idx_pt, rows=T)
+
+
+SECTIONS = {"main": main, "payload": payload_scaling,
+            "gathers": gather_prices}
+
+if __name__ == "__main__":
+    for section in sys.argv[1:] or ["main"]:
+        SECTIONS[section]()

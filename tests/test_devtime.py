@@ -191,6 +191,81 @@ def test_static_counts_leave_out_control_flow_and_what_never_runs(handmade):
     assert "lt.9" not in handmade.phases and "mul.1" not in handmade.phases
 
 
+# a block's gathers as the TPU's compiler writes them (``kind=kCustom``
+# fusions of a table and an index vector) and as XLA:CPU does (bare, the
+# result with a trailing 1), at ``capP`` 8516, ``capT`` 43118
+GATHERS = '''HloModule jit_run, is_scheduled=true
+
+%fused_computation.1 (param_0.1: u32[8516], param_1.1: s32[258708,1]) -> u32[258708] {
+  %param_0.1 = u32[8516]{0:T(1024)} parameter(0)
+  %param_1.1 = s32[258708,1]{0,1:T(1024)} parameter(1)
+  ROOT %gather.1 = u32[258708]{0:T(1024)} gather(%param_0.1, %param_1.1), offset_dims={}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1}
+}
+
+%fused_computation.2 (param_0.2: f32[8516,4], param_1.2: s32[258708,1]) -> f32[258708,4] {
+  %param_0.2 = f32[8516,4]{1,0:T(8,128)} parameter(0)
+  %param_1.2 = s32[258708,1]{0,1:T(1024)} parameter(1)
+  ROOT %gather.2 = f32[258708,4]{1,0:T(8,128)} gather(%param_0.2, %param_1.2), offset_dims={1}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1,4}
+}
+
+%fused_computation.3 (param_0.3: f32[8517], param_1.3: s32[5389,1]) -> f32[5389] {
+  %param_0.3 = f32[8517]{0:T(1024)} parameter(0)
+  %param_1.3 = s32[5389,1]{0,1:T(1024)} parameter(1)
+  ROOT %gather.3 = f32[5389]{0:T(1024)} gather(%param_0.3, %param_1.3), offset_dims={}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1}
+}
+
+%fused_computation.4 (param_0.4: s32[258708], param_1.4: s32[258708,1]) -> s32[258708] {
+  %param_0.4 = s32[258708]{0:T(1024)} parameter(0)
+  %param_1.4 = s32[258708,1]{0,1:T(1024)} parameter(1)
+  ROOT %gather.4 = s32[258708]{0:T(1024)} gather(%param_0.4, %param_1.4), offset_dims={}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1}
+}
+
+ENTRY %main.9 (tag: u32[8516], xyzh: f32[8516,4], score: f32[8517], perm: s32[258708], flag: pred[8517], ends: s32[258708,1], top: s32[5389,1], tets: s32[43118,1]) -> (u32[258708], f32[258708,4], f32[5389], s32[258708], pred[43118,1], s32[43118]) {
+  %tag = u32[8516]{0} parameter(0)
+  %xyzh = f32[8516,4]{1,0} parameter(1)
+  %score = f32[8517]{0} parameter(2)
+  %perm = s32[258708]{0} parameter(3)
+  %flag = pred[8517]{0} parameter(4)
+  %ends = s32[258708,1]{1,0} parameter(5)
+  %top = s32[5389,1]{1,0} parameter(6)
+  %tets = s32[43118,1]{1,0} parameter(7)
+  %fusion.1 = u32[258708]{0} fusion(%tag, %ends), kind=kCustom, calls=%fused_computation.1, metadata={op_name="jit(run)/while/body/cyc.collapse/gather"}
+  %fusion.2 = f32[258708,4]{1,0} fusion(%xyzh, %ends), kind=kCustom, calls=%fused_computation.2, metadata={op_name="jit(run)/while/body/cyc.collapse/gather"}
+  %fusion.3 = f32[5389]{0} fusion(%score, %top), kind=kCustom, calls=%fused_computation.3, metadata={op_name="jit(run)/while/body/cyc.collapse/gather"}
+  %fusion.4 = s32[258708]{0} fusion(%perm, %ends), kind=kCustom, calls=%fused_computation.4, metadata={op_name="jit(run)/while/body/cyc.table/gather"}
+  %gather.5 = pred[43118,1]{1,0} gather(pred[8517]{0} %flag, s32[43118,1]{1,0} %tets), offset_dims={1}, collapsed_slice_dims={}, start_index_map={0}, index_vector_dim=1, slice_sizes={1}, metadata={op_name="jit(run)/while/body/cyc.smooth/gather"}
+  %gather.6 = s32[43118]{0} gather(s32[258708]{0} %perm, s32[43118,1]{1,0} %tets), offset_dims={}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1}
+  ROOT %out = (u32[258708]{0}, f32[258708,4]{1,0}, f32[5389]{0}, s32[258708]{0}, pred[43118,1]{1,0}, s32[43118]{0}) tuple(%fusion.1, %fusion.2, %fusion.3, %fusion.4, %gather.5, %gather.6)
+}
+'''
+
+
+def test_scalar_gathers_are_the_per_vertex_vectors_at_a_tet_tables_width():
+    """A rank-1 operand of at most ``capP + 1`` elements and a result of
+    at least ``capT`` count, inside a fused computation (under its
+    fusion's phase) and bare (XLA:CPU's, the operands' shapes in front);
+    a row out of ``[capP, 4]``, a K-wide result and a table as long as
+    its index do not."""
+    counts = devtime.map_from_text(GATHERS, capP=8516, capT=43118).counts
+    assert counts["scalar_gathers"] == 2
+    assert counts["scalar_gathers_by_phase"] == {"cyc.collapse": 1,
+                                                 "cyc.smooth": 1}
+    assert counts["ops"] == 6 and counts["sorts"] == 0
+    # the bounds are the capacities': a vertex fewer and the flag vector
+    # (8517, with its drop row) is no per-vertex table, two fewer and the
+    # tag vector is none; a tet more and the smoother's result is not wide
+    for capP, capT, left in ((8515, 43118, 1), (8514, 43118, 0),
+                             (8516, 43119, 1), (8516, 258709, 0)):
+        assert devtime.map_from_text(GATHERS, capP, capT).counts[
+            "scalar_gathers"] == left
+
+
+def test_without_the_capacities_nothing_is_counted_as_a_scalar_gather(
+        handmade):
+    assert "scalar_gathers" not in devtime.map_from_text(GATHERS).counts
+    assert "scalar_gathers" not in handmade.counts
+
+
 # ---------------------------------------------------------------------------
 # by_phase
 # ---------------------------------------------------------------------------
@@ -406,6 +481,25 @@ def test_scope_map_names_every_sort_of_a_block_with_a_phase(ran):
     assert sum(counts["sorts_by_phase"].values()) == counts["sorts"]
     assert all(p.startswith("cyc.") for p in counts["sorts_by_phase"])
     assert ran["warm"].module == "jit_run"
+
+
+# what ``map_from_text`` counts on the parent's program at this shape
+# (64, 97), commit cb0d304, my CPU run, PR 42: split 8, collapse 23,
+# smooth 5
+PARENT_SCALAR_GATHERS = 36
+
+
+def test_scope_map_counts_the_scalar_gathers_the_row_packs_left(ran):
+    """The capacities come from the signature's mesh.  At a toy shape a
+    wave's top-K is as wide as a tet table, so what is left are the
+    claims' K-wide fetches (5,389 wide at the cells' shape: not
+    counted there): the split's four, the collapse's eight; the
+    smoother has none."""
+    counts = ran["warm"].counts
+    assert counts["scalar_gathers"] == 12 <= PARENT_SCALAR_GATHERS // 3
+    assert counts["scalar_gathers_by_phase"] == {"cyc.split": 4,
+                                                 "cyc.collapse": 8}
+    assert ran["cold"].counts["scalar_gathers"] == 12
 
 
 def test_scope_map_puts_most_instructions_under_a_phase(ran):
