@@ -17,6 +17,7 @@ adapt operator (ops/adapt.py) leaves the interface untouched.
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import numpy as np
 import jax
@@ -48,14 +49,28 @@ def shard_capacity(maxP: int, maxT: int, cap_mult: float = 3.0,
     collapsing them onto O(log n) shapes.  ``keep``: the capacity an
     earlier split of this run compiled its programs for; it stands
     while every shard still fits in it with REUSE_SLACK to grow (a
-    later pass splits a mesh that is already near its metric).  An
-    overflow regrows either way (``regrown_capacity``)."""
+    later pass splits a mesh that is already near its metric), and a
+    column that no longer fits goes to the lowest rung that does hold
+    its largest shard with that slack, the next one as a rule -- not
+    to ``cap_mult`` times a shard that a displacement filled (three
+    rungs up, a program nobody compiled: ROADMAP B11).  An overflow
+    regrows either way (``regrown_capacity``)."""
     from ..utils.compilecache import bucket
-    if keep is not None and REUSE_SLACK * maxP <= keep[0] and \
-            REUSE_SLACK * maxT <= keep[1]:
-        return keep
+    if keep is not None:
+        return tuple(max(kept, bucket(math.ceil(REUSE_SLACK * n),
+                                      floor=64, scheme="geo"))
+                     for kept, n in zip(keep, (maxP, maxT)))
     return (bucket(int(cap_mult * maxP), floor=64, scheme="geo"),
             bucket(int(cap_mult * maxT), floor=64, scheme="geo"))
+
+
+def capacity_headroom(maxP: int, maxT: int, capP: int, capT: int) -> float:
+    """Per cent of (capP, capT) between shards whose largest holds
+    ``maxP`` vertices and ``maxT`` tets and the edge where
+    ``shard_capacity`` stops keeping that capacity, the lesser of the
+    two columns: at 0 the largest shard just fits with REUSE_SLACK,
+    under it a later split takes the next rung."""
+    return 100.0 * (1.0 - REUSE_SLACK * max(maxP / capP, maxT / capT))
 
 
 def regrown_capacity(capP: int, capT: int) -> tuple[int, int]:
@@ -92,7 +107,8 @@ def _fan_normals(vert, tet, ftag, want):
 
 def split_to_shards(mesh: Mesh, met, part: np.ndarray, nparts: int,
                     cap_mult: float = 3.0, return_l2g: bool = False,
-                    reuse_caps: tuple | None = None):
+                    reuse_caps: tuple | None = None,
+                    cut: dict | None = None):
     """Split a host-resident Mesh into ``nparts`` shard Meshes (stacked).
 
     Returns (shards: Mesh with leading axis [nparts, ...], met stacked),
@@ -100,6 +116,16 @@ def split_to_shards(mesh: Mesh, met, part: np.ndarray, nparts: int,
     input to build_interface_comms).  All shards share one capacity (max
     over shards * cap_mult / nparts-balance) so they stack into one
     pytree for shard_map.
+
+    ``cut``: a dict the caller hands in to learn what the cut asks of the
+    pass that runs it, from what the split computes on its way (the face
+    pairs, each part's vertices): ``verts`` the vertices of any tet,
+    ``seam_verts`` those of two or more parts (the pass freezes them),
+    ``junction_verts`` those of three or more (where seams meet),
+    ``pieces`` the face-connected pieces summed over the parts
+    (``nparts`` when every part is in one), ``maxP`` / ``maxT`` the
+    vertices and tets of the fullest part, which the capacity follows.
+    Only the pieces are labelled for it (``partition.cut_pieces``).
     """
     vert, tet, vref, tref, vtag = mesh_to_host(mesh)
     methost = np.asarray(met)
@@ -140,11 +166,13 @@ def split_to_shards(mesh: Mesh, met, part: np.ndarray, nparts: int,
     shards_met = []
     maxP = maxT = 0
     locals_ = []
+    nof = np.zeros(len(vert), np.int32)      # parts a vertex is in
     for p in range(nparts):
         sel = part == p
         ltet_g = tet[sel]
         used = np.zeros(len(vert), bool)
         used[ltet_g.reshape(-1)] = True
+        nof += used
         g2l = np.full(len(vert), -1, np.int64)
         gids = np.where(used)[0]
         g2l[gids] = np.arange(len(gids))
@@ -153,6 +181,16 @@ def split_to_shards(mesh: Mesh, met, part: np.ndarray, nparts: int,
         maxT = max(maxT, len(ltet_g))
 
     capP, capT = shard_capacity(maxP, maxT, cap_mult, keep=reuse_caps)
+    if cut is not None:
+        from .partition import cut_pieces
+        # host numpy throughout: count_nonzero and tolist give python ints
+        cut.update(
+            verts=np.count_nonzero(nof),
+            seam_verts=np.count_nonzero(nof >= 2),
+            junction_verts=np.count_nonzero(nof >= 3),
+            pieces=(cut_pieces(tet, part, (fA // 4, fB // 4)).max()
+                    + 1).tolist(),
+            maxP=maxP, maxT=maxT)
 
     face_is_ifc = np.zeros(n * 4, bool)
     face_is_ifc[ifc_faces] = True

@@ -475,7 +475,8 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     from ..ops.adapt import (DIRTY_COL, LISTED_COL, SURF_COLS,
                              surface_scatter_width)
     from ..utils.timers import Timers
-    from .distribute import split_to_shards, merge_shards, grow_shards
+    from .distribute import (capacity_headroom, split_to_shards,
+                             merge_shards, grow_shards)
     from .sched import QuietGroupScheduler
     from ..core.mesh import mesh_to_host
 
@@ -496,10 +497,13 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
         vert_h, tet_h, _, _, _ = mesh_to_host(mesh)
         if part is None:
             part = fresh_cut(vert_h, tet_h, ngroups, contiguous)
+        # what the cut asks of the pass (seams, junctions, pieces), as
+        # the split counts it on its way
+        cut = {}
         with host_staging():
             stacked, met_s = split_to_shards(
                 mesh, met, part, ngroups, cap_mult=cap_mult,
-                reuse_caps=cap_state[0] if cap_state else None)
+                reuse_caps=cap_state[0] if cap_state else None, cut=cut)
             if chunk:
                 g_exec = -(-ngroups // chunk) * chunk
                 # np.array (copy): np.asarray of a jax array can hand
@@ -508,13 +512,17 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                 stacked = jax.tree.map(
                     lambda a: np.array(a), _pad_groups(stacked, g_exec))
                 met_s = np.array(_pad_groups(met_s, g_exec))
-        largest = np.bincount(part).max().tolist()
-        sp.set(capP=stacked.vert.shape[1], capT=stacked.tet.shape[1],
-               largest=largest)
+        # how far the fullest group stands from the edge of the capacity
+        # a later split may keep (shard_capacity ``keep``): under 0 the
+        # next pass would leave this block program
+        most_verts, largest = cut.pop("maxP"), cut.pop("maxT")
+        capP, capT = stacked.vert.shape[1], stacked.tet.shape[1]
+        sp.set(capP=capP, capT=capT, largest=largest,
+               headroom=capacity_headroom(most_verts, largest, capP, capT),
+               **cut)
     otrace.log(2, f"  grp split: {ngroups} groups, largest "
                   f"{largest} tets, capacity (capP, capT) = "
-                  f"({stacked.vert.shape[1]}, {stacked.tet.shape[1]})",
-               verbose=verbose)
+                  f"({capP}, {capT})", verbose=verbose)
 
     def _assign(dst_tree, src_tree, g0):
         """Write a chunk's device results back into the host state
@@ -816,6 +824,10 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     # caller threaded a stats/timers object through
     from ..obs.metrics import REGISTRY
     REGISTRY.counter("groups.dispatches").inc(sched.dispatches)
+    # rows those dispatches ran (a block runs every row of its stack,
+    # the quiet ones as lax.cond identities): what a block's seconds
+    # divide by
+    REGISTRY.counter("groups.rows").inc(sched.rows)
     # group-slot executions the device-resident quiet mask cond-skipped
     # (unchunked quiet slots + padded tail rows of chunk plans)
     REGISTRY.counter("groups.cond_skipped").inc(sched.cond_skipped)
@@ -965,10 +977,15 @@ def grouped_adapt(mesh: Mesh, met, target_size: int, niter: int = 3,
                 timers=timers, ckpt_tag=ckpt_tag, ckpt_it=it,
                 cap_state=cap_state, contiguous=contiguous)
             if it + 1 < max(1, niter):
-                with otrace.span("grp displace", layers=ifc_layers):
+                with otrace.span("grp displace", layers=ifc_layers) as sp:
                     _, tet_h, _, _, _ = mesh_to_host(mesh)
                     part = move_interfaces(tet_h, part_m, ngroups,
                                            nlayers=ifc_layers)
+                    # what the displacement (and its fix_contiguity)
+                    # did to the cut the next pass splits by
+                    sp.set(moved=np.count_nonzero(part != part_m),
+                           largest=np.bincount(part).max().tolist(),
+                           mean=len(part) / ngroups)
             else:
                 # the FINAL pass checkpoints too (part=None — there is
                 # no next pass to feed): a kill during the caller's

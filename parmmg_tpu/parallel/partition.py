@@ -58,8 +58,9 @@ def morton_partition(centroids: np.ndarray, nparts: int,
     return part
 
 
-def build_dual_graph(tet: np.ndarray):
-    """Tet-tet adjacency as CSR (host), via sorted faces."""
+def face_pairs(tet: np.ndarray):
+    """The tets on either side of every interior face: (i, j), one
+    entry a face that two tets share (host), via sorted faces."""
     n = len(tet)
     faces = np.sort(tet[:, [[1, 2, 3], [0, 3, 2], [0, 1, 3], [0, 2, 1]]]
                     .reshape(n * 4, 3), axis=1)
@@ -68,8 +69,14 @@ def build_dual_graph(tet: np.ndarray):
     order = np.argsort(key, kind="stable")
     ks = key[order]
     same = ks[1:] == ks[:-1]
-    i = order[:-1][same] // 4
-    j = order[1:][same] // 4
+    return order[:-1][same] // 4, order[1:][same] // 4
+
+
+def build_dual_graph(tet: np.ndarray, pairs=None):
+    """Tet-tet adjacency as CSR (host), via sorted faces (``pairs``:
+    ``face_pairs(tet)`` where the caller has it)."""
+    n = len(tet)
+    i, j = face_pairs(tet) if pairs is None else pairs
     src = np.concatenate([i, j])
     dst = np.concatenate([j, i])
     o = np.argsort(src, kind="stable")
@@ -358,37 +365,49 @@ def partition_metrics(tet: np.ndarray, part: np.ndarray,
             "counts": counts.tolist()}
 
 
+def cut_pieces(tet: np.ndarray, part: np.ndarray, pairs=None) -> np.ndarray:
+    """The face-connected pieces of a cut's parts: a piece id a tet,
+    pieces numbered by their first tet (the order a scan over the tets
+    meets them in).  Components of the dual graph without the faces a
+    seam cuts, by numpy: every tet takes the least label among its
+    neighbours, then its label's label, until nothing moves."""
+    i, j = face_pairs(tet) if pairs is None else pairs
+    inside = part[i] == part[j]
+    i, j = i[inside], j[inside]
+    lab = np.arange(len(tet))
+    while True:
+        low = np.minimum(lab[i], lab[j])
+        new = lab.copy()
+        np.minimum.at(new, i, low)
+        np.minimum.at(new, j, low)
+        new = new[new]
+        if np.array_equal(new, lab):
+            # a piece's label is its first tet: ranks are scan order
+            return np.unique(lab, return_inverse=True)[1]
+        lab = new
+
+
 def fix_contiguity(tet: np.ndarray, part: np.ndarray) -> np.ndarray:
     """Relabel all but the largest connected blob of each color into a
     neighboring color (reference PMMG_fix_contiguity semantics,
     moveinterfaces_pmmg.c:475)."""
-    n = len(tet)
-    xadj, adj = build_dual_graph(tet)
+    pairs = face_pairs(tet)
     part = part.copy()
-    # connected components within colors
-    comp = np.full(n, -1, np.int64)
-    ncomp = 0
-    from collections import deque
-    for s in range(n):
-        if comp[s] != -1:
-            continue
-        comp[s] = ncomp
-        dq = deque([s])
-        while dq:
-            t = dq.popleft()
-            for v in adj[xadj[t]:xadj[t + 1]]:
-                if comp[v] == -1 and part[v] == part[t]:
-                    comp[v] = ncomp
-                    dq.append(v)
-        ncomp += 1
+    # connected components within colors, numbered by their first tet
+    comp = cut_pieces(tet, part, pairs)
+    first = np.unique(comp, return_index=True)[1]
+    ncomp = len(first)
     sizes = np.bincount(comp, minlength=ncomp)
     # biggest component per color keeps it
     keep = {}
     for cid in range(ncomp):
-        col = part[np.argmax(comp == cid)]
+        col = part[first[cid]]
         if col not in keep or sizes[cid] > sizes[keep[col]]:
             keep[col] = cid
     keepset = set(keep.values())
+    if len(keepset) == ncomp:       # every color in one piece
+        return part
+    xadj, adj = build_dual_graph(tet, pairs)
     for cid in range(ncomp):
         if cid in keepset:
             continue

@@ -175,6 +175,7 @@ class QuietGroupScheduler:
         self.level = np.zeros(self.g_exec, np.int8)
         self.level[self.ngroups:] = LEVEL_FULL     # dead pad groups
         self.dispatches = 0
+        self.rows = 0           # rows of the dispatched stacks
         self.saved_dispatches = 0
         self.skipped_group_blocks = 0
         # group-slot executions skipped ON DEVICE by the lax.cond mask
@@ -210,6 +211,7 @@ class QuietGroupScheduler:
             base = 1
             plans = [(act, len(act))] if len(act) else []
         self.dispatches += len(plans)
+        self.rows += sum(len(idx) for idx, _ in plans)
         # saved vs the always-dispatch baseline, which ships the dead
         # pad groups too — skipping those IS a real dispatch saving
         self.saved_dispatches += base - len(plans)

@@ -147,7 +147,7 @@ def test_shard_capacity_rule():
     """distribute.shard_capacity / regrown_capacity — the one capacity
     rule: fresh capacities sit on compilecache.bucket's geometric
     ladder, a kept capacity stands exactly while every shard fits in it
-    with REUSE_SLACK to grow, and a regrow lands on a ladder rung at or
+    with REUSE_SLACK to grow and then moves up by the rung, and a regrow lands on a ladder rung at or
     above twice the old capacity (so it can meet a fresh split)."""
     from parmmg_tpu.parallel.distribute import (
         REUSE_SLACK, regrown_capacity, shard_capacity)
@@ -164,11 +164,13 @@ def test_shard_capacity_rule():
     # kept: the largest shard fits with the slack, in both dimensions
     fits = int(capT / REUSE_SLACK)
     assert shard_capacity(100, fits, keep=(capP, capT)) == (capP, capT)
-    # not kept: one tet more, or too many vertices -> the fresh rule
+    # not kept: one tet more, or too many vertices -> that column goes
+    # to the next rung, the other stands (tests/test_capacity_keep.py
+    # has the edges at the benchmark's own capacity)
     assert shard_capacity(100, fits + 1, keep=(capP, capT)) == \
-        shard_capacity(100, fits + 1)
+        (capP, bucket(capT + 1, floor=64, scheme="geo"))
     assert shard_capacity(capP, 100, keep=(capP, capT)) == \
-        shard_capacity(capP, 100)
+        (bucket(capP + 1, floor=64, scheme="geo"), capT)
     newP, newT = regrown_capacity(capP, capT)
     assert rung(newP) and rung(newT)
     assert 2 * capP <= newP <= 3 * capP + 2 and 2 * capT <= newT <= 3 * capT + 2
