@@ -203,7 +203,10 @@ def _merged_polish(mesh, met, info, hausd, stats, tim):
     and face sorts behind their tables (``ops/topo_incr``): from the
     second on, a wave merges the rows the last stage changed into the
     sort it was handed where it sorted the whole mesh.  Returns (mesh,
-    collapses + swaps applied)."""
+    collapses + swaps applied, the ``TopoState`` the last wave left):
+    the sorts are the mesh's own, less the rows the state marks dirty,
+    so the tail's next consumer of whole-mesh tables (``_finish_run``'s
+    fem rounds) merges into them where it would sort again."""
     import jax.numpy as jnp
     from .obs import trace as otrace
     from .obs.metrics import REGISTRY
@@ -271,7 +274,7 @@ def _merged_polish(mesh, met, info, hausd, stats, tim):
     REGISTRY.counter("tail.worklist_rows").inc(wl_rows)
     REGISTRY.counter("tail.tables").inc(tables)
     REGISTRY.counter("tail.tables_merged").inc(tables_merged)
-    return mesh, ops
+    return mesh, ops, topo
 
 
 def _run_phases(pm) -> tuple[Mesh, object, AdaptStats]:
@@ -394,13 +397,14 @@ def _run_phases(pm) -> tuple[Mesh, object, AdaptStats]:
                             detail=str(e)[:200])
             # bad-element polish on the merged mesh (the same contract as
             # the other two paths — group seams breed slivers)
+            topo = None
             if not degraded and not (info.noinsert and info.noswap
                                      and info.nomove):
-                mesh, _ = _merged_polish(mesh, met, info, hausd, stats,
-                                         tim)
+                mesh, _, topo = _merged_polish(mesh, met, info, hausd,
+                                               stats, tim)
             with host_staging():
                 return _finish_run(pm, mesh, met, stats, info, tim,
-                                   bg_mesh, bg_fields, hausd)
+                                   bg_mesh, bg_fields, hausd, topo=topo)
         # whole-mesh path: staged on the host, adapted on the device
         mesh, met = to_device((mesh, met))
         for it in range(niter):
@@ -506,8 +510,10 @@ def _run_phases(pm) -> tuple[Mesh, object, AdaptStats]:
                                f"{info.n_devices} devices")
         # bad-element optimization on the merged mesh (same contract as
         # the single-device path: sliver_polish after the sizing loop)
+        topo = None
         if not (info.noinsert and info.noswap and info.nomove):
-            mesh, ops = _merged_polish(mesh, met, info, hausd, stats, tim)
+            mesh, ops, topo = _merged_polish(mesh, met, info, hausd, stats,
+                                             tim)
             if ops:
                 part = None   # tet set changed: labels are stale
         # reused by distributed output, a file a RANK: a tet's label is
@@ -516,19 +522,31 @@ def _run_phases(pm) -> tuple[Mesh, object, AdaptStats]:
             else part // (n_shards // info.n_devices)
         with host_staging():
             return _finish_run(pm, mesh, met, stats, info, tim, bg_mesh,
-                               bg_fields, hausd)
+                               bg_fields, hausd, topo=topo)
 
     return _finish_run(pm, mesh, met, stats, info, tim, bg_mesh,
                        bg_fields, hausd)
 
 
 def _finish_run(pm, mesh, met, stats, info, tim, bg_mesh, bg_fields,
-                hausd):
+                hausd, topo=None):
     """Common run tail: sequential sliver repair, FEM-topology
     conformity, user-field interpolation, reports.  Shared by the
-    whole-mesh, grouped and distributed paths."""
+    whole-mesh, grouped and distributed paths.
+
+    ``topo``: the ``ops/topo_incr.TopoState`` the merged polish ended
+    with (the grouped and the distributed path; both run this tail on
+    the host).  The fem rounds then take their edge table and adjacency
+    off its sorts (``ops/adapt.fem_pass_impl``) and hand it from round
+    to round; what changes the mesh outside them keeps it true: a repair
+    that rewrote rows marks them, a regrow (the rows are permuted and
+    the capacity changes) drops it, and the next round sorts in full.
+    The whole-mesh path carries none and runs the rounds as they were,
+    on the device."""
     from .obs import trace as otrace
+    from .obs.metrics import REGISTRY
     from .obs.trace import log as _olog
+    from .ops.topo_incr import mark_dirty, topo_init
     # sequential last-resort repair: tangled sliver clusters (stacked
     # near-flat tets, typically born at former frozen interfaces) veto
     # every BATCHED fix — each parallel op inverts a neighbor — while the
@@ -537,9 +555,13 @@ def _finish_run(pm, mesh, met, stats, info, tim, bg_mesh, bg_fields,
     if not (info.noinsert and info.noswap and info.nomove):
         from .ops.repair import repair_mesh
         with tim("sequential repair"):
+            before = mesh
             mesh, nrep = repair_mesh(
                 mesh, met, allow_collapse=not info.noinsert,
                 allow_swap=not info.noswap, allow_move=not info.nomove)
+            if topo is not None and mesh is not before:
+                # numpy rewrote rows: the diff the polish's stages take
+                topo = mark_dirty(topo, before.tet, before.tmask, mesh)
             if nrep:
                 _olog(C.PMMG_VERB_STEPS,
                       f"  sequential repair: {nrep} cluster ops",
@@ -551,14 +573,27 @@ def _finish_run(pm, mesh, met, stats, info, tim, bg_mesh, bg_fields,
     # with two faces / all four vertices (ops.split.split_wave fem_only).
     # AFTER the repair pass — a repair collapse could otherwise resurrect
     # a bdy-bdy interior edge the fem pass just removed.
+    # (tables, tables_merged: the tables the rounds derived with a state
+    # carried and those of them taken off its sorts, counters once a job)
+    tables = tables_merged = 0
     if info.fem and not info.noinsert:
         from .ops.adapt import fem_pass, grow_mesh_met
         with tim("fem conformity"):
             nf = 0
             for w in range(8):
                 with otrace.span("fem round", wave=w) as sp:
-                    mesh, met, fc = fem_pass(mesh, met)
-                    nf, ovf, nbs = (int(v) for v in np.asarray(fc))
+                    if topo is None:
+                        mesh, met, fc = fem_pass(mesh, met)
+                        nf, ovf, nbs = np.asarray(fc).tolist()
+                    else:
+                        mesh, met, fc, topo = fem_pass(mesh, met, topo)
+                        nf, ovf, nbs, tab, inc = np.asarray(fc).tolist()
+                        # tab: the round's edge table and its adjacency,
+                        # inc: those of them merged into (or taken as)
+                        # the sort the state carries, as a polish wave's
+                        sp.set(tab=tab, inc=inc)
+                        tables += tab
+                        tables_merged += inc
                     # a fem round collapses and moves nothing
                     sp.set(split=nf, overflow=ovf, bsplit=nbs, hveto=0,
                            bmoved=0,
@@ -568,6 +603,10 @@ def _finish_run(pm, mesh, met, stats, info, tim, bg_mesh, bg_fields,
                 if ovf:
                     mesh, met = grow_mesh_met(mesh, met, 2 * mesh.capP,
                                               2 * mesh.capT)
+                    if topo is not None:
+                        # rows permuted, capacity doubled: nothing of the
+                        # sorts holds, the next round sorts in full
+                        topo = topo_init(mesh.capT)
                     stats.regrows += 1
                     continue
                 if nf == 0:
@@ -578,6 +617,9 @@ def _finish_run(pm, mesh, met, stats, info, tim, bg_mesh, bg_fields,
                       f"converge ({nf} edges remain); output may "
                       "contain elements with two boundary faces.",
                       verbose=info.imprim, err=True)
+
+    REGISTRY.counter("tail.fem_tables").inc(tables)
+    REGISTRY.counter("tail.fem_tables_merged").inc(tables_merged)
 
     # interpolate user fields old mesh -> new mesh
     if bg_fields:

@@ -418,7 +418,7 @@ adapt_cycle = _governed("adapt.cycle")(
         donate_argnums=(0, 1))(adapt_cycle_impl))
 
 
-def fem_pass_impl(mesh: Mesh, met: jax.Array):
+def fem_pass_impl(mesh: Mesh, met: jax.Array, topo=None):
     """One FEM-conformity wave: split interior edges whose endpoints are
     both boundary points (the configuration that lets an element touch
     the boundary with two faces or all four vertices).  This is the
@@ -426,18 +426,49 @@ def fem_pass_impl(mesh: Mesh, met: jax.Array):
     (API_functions_pmmg.c:652-658, default ``info.fem`` ON :413); run
     after the sizing/polish loop until no candidate remains.
 
+    ``topo``: the ``ops/topo_incr.TopoState`` the merged polish ended
+    with (driver._finish_run hands it from round to round): the round's
+    edge table and its adjacency then come off the sorts the state
+    retains, as a polish wave's do (``sliver_polish_impl``), by a merge
+    of the rows changed since each table's last derivation, as they are
+    where none changed (the round that finds no candidate), or by the
+    full sort where nothing is retained or the rows outnumber the widest
+    band.  Bit-identical either way (that module's docstring).  Without
+    a state the round sorts both in full: the whole-mesh path's, which
+    runs on the device, where the retained sort loses (ROADMAP D1).
+
     Returns (mesh, met, counts[3] = [nsplit, overflow, bsplit]); the
     candidates are interior edges, so ``bsplit`` (splits of boundary
-    edges) reads 0 while that holds."""
+    edges) reads 0 while that holds.  With ``topo``, counts[5]: then
+    ``tab``, the tables the round derived (2), and ``inc``, those of
+    them taken off the retained sort; the state is the last result."""
     from .adjacency import boundary_edge_tags
+    et = None           # the split wave then builds its own
+    if topo is not None:
+        from .topo_incr import (mark_dirty, polish_bands,
+                                polish_build_adjacency, polish_unique_edges)
+        band = polish_bands(mesh.capT)
     with otrace.scope("fem.split"):
-        res = split_wave(mesh, met, fem_only=True, budget_div=2)
+        if topo is not None:
+            # shell_slots: what split_wave's own ``unique_edges`` asks for
+            et, topo, emerged = polish_unique_edges(
+                mesh, topo, shell_slots=3, band=band)
+        res = split_wave(mesh, met, fem_only=True, budget_div=2, et=et)
+        if topo is not None:
+            topo = mark_dirty(topo, mesh.tet, mesh.tmask, res.mesh)
     with otrace.scope("fem.bdytags"):
         mesh = boundary_edge_tags(res.mesh)
     with otrace.scope("fem.adjacency"):
-        mesh = build_adjacency(mesh)
-    return mesh, res.met, jnp.stack(
-        [res.nsplit, res.overflow.astype(jnp.int32), res.nbdy])
+        if topo is None:
+            mesh = build_adjacency(mesh)
+        else:
+            mesh, topo, fmerged = polish_build_adjacency(mesh, topo,
+                                                         band=band)
+    row = [res.nsplit, res.overflow.astype(jnp.int32), res.nbdy]
+    if topo is None:
+        return mesh, res.met, jnp.stack(row)
+    inc = emerged.astype(jnp.int32) + fmerged.astype(jnp.int32)
+    return mesh, res.met, jnp.stack(row + [jnp.full_like(inc, 2), inc]), topo
 
 
 # governed so that the ledger keeps the signature it lowered from:

@@ -44,11 +44,18 @@ the merge lowers to a Pallas kernel on TPU
 (ops/pallas_kernels.merge_prefix_pallas, 8x128-tiled, SMEM carry); the
 CPU reference is ``jnp.cumsum`` — integer adds, bit-identical.
 
-The merged polish (driver._merged_polish -> ops/adapt.sliver_polish_impl,
-on the host) carries one ``TopoState`` from wave to wave with no knob:
-there ``incr`` is a constant true, the band has two rungs
-(``polish_bands``: a derivation merges at the narrowest that holds its
-dirty set) and ``told`` says which tables came off the retained sort.
+The merged tail (on the host) carries one ``TopoState`` through all its
+consumers of whole-mesh tables with no knob: the merged polish from wave
+to wave (driver._merged_polish -> ops/adapt.sliver_polish_impl), which
+hands the state it ends with to driver._finish_run, whose fem rounds
+(ops/adapt.fem_pass_impl) derive their edge table and adjacency off it
+and hand it from round to round.  There ``incr`` is a constant true, the
+band has two rungs (``polish_bands``: a derivation merges at the
+narrowest that holds its dirty set) and ``told`` says which tables came
+off the retained sort.  Whatever rewrites rows between two consumers
+keeps the state true or drops it: the numpy repair is diffed like a
+stage (``mark_dirty``), a regrow permutes the rows and changes the
+capacity, so the state starts again (``topo_init``).
 """
 from __future__ import annotations
 

@@ -25,9 +25,19 @@ list nor the merge shortens), ``*_rows`` the rest of its seconds on the
 list the waves have kept (the candidate stage, as wide as the list);
 ``list`` is the bookkeeping between the stages, the lists' and the dirty
 masks'.  Each wave's row says how many candidate rows the kernels' top-K
-selected (``cand``) and how many of them were on the list (``wl``).  A
+selected (``cand``) and how many of them were on the list (``wl``).
+
+After the waves, the tail's other consumer of whole-mesh tables the same
+way (PR 44): the repair, then the fem rounds of ``driver._finish_run`` on
+the mesh and the sorts the waves left, a program a stage (``edge_table``
+and ``adjacency`` off the carried sort with the dirty rows each met and
+whether it was merged, ``full_s`` the same table by the full sort, which
+is what a round without the state runs; ``split`` the split wave with its
+table given, ``bdytags``, ``list`` the dirty marks), until a round finds
+no candidate.  The rounds are run twice from the same state and the
+second run is the one reported, so no round's seconds hold a compile.  A
 diagnostic, not a contract: it mirrors the wave's composition as of
-PR 38.
+PR 38 and the round's as of PR 44.
 
     python scripts/polish_stages.py --cell iso-growth --seed 21 \
         [--save DIR] [--from DIR] [--out FILE.json]
@@ -54,6 +64,7 @@ import numpy as np  # noqa: E402
 
 TABLES = ("collapse_table", "swap_edges_table", "swapgen_table",
           "adjacency", "exit_adjacency")
+FEM_STAGES = ("edge_table", "split", "bdytags", "adjacency", "list")
 STAGES = ("collapse_table", "collapse", "swap_edges_table",
           "swap_edges_head", "swap_edges_rows", "swapgen_table",
           "swapgen_head", "swapgen_rows", "adjacency", "swap23", "smooth",
@@ -104,12 +115,16 @@ def capture_job(cell: str, seed: int) -> tuple[dict, dict]:
         driver._merged_polish = inner
     sha = {k: hashlib.sha256(np.ascontiguousarray(res[k]).tobytes())
            .hexdigest() for k in ("vert", "tet", "met")}
-    waves = [{k: r[k] for k in ("collapse", "swap", "moved", "bad", "col",
-                                "adj", "wl", "cand", "tab", "inc", "dur")
-              if k in r}
-             for r in TRACER.ring if r.get("name") == "polish wave"]
+    def spans(name, *keys):
+        return [{k: r[k] for k in keys if k in r}
+                for r in TRACER.ring if r.get("name") == name]
+
+    waves = spans("polish wave", "collapse", "swap", "moved", "bad", "col",
+                  "adj", "wl", "cand", "tab", "inc", "dur")
+    fem = spans("fem round", "split", "overflow", "tab", "inc", "dur")
     digest = {"rc": res["rc"], "seconds": res["seconds"],
               "ntets": len(res["tet"]), "sha256": sha, "waves": waves,
+              "fem": fem,
               "counters": {k: v for k, v in res["counters"].items()
                            if k.startswith("tail.")}}
     return seen, digest
@@ -136,22 +151,57 @@ def restore(path: str) -> dict:
             "hausd": None if np.isnan(hausd) else hausd}
 
 
-def replay(seen: dict, waves: int = 8, sliver_q: float = 0.2) -> list[dict]:
+def timed(fn, *a):
+    import jax
+    t0 = time.perf_counter()
+    out = jax.block_until_ready(fn(*a))
+    return out, time.perf_counter() - t0
+
+
+def table_programs(capT: int):
+    """``edges(slots)`` -> (an edge table off the carried sort: (table,
+    state, merged?, dirty rows met); the same table by the full sort),
+    ``adjacency`` the same pair for the face sort, ``mark`` the dirty
+    marks of the rows a stage changed; at the bands of ``capT``."""
+    import jax
+    import jax.numpy as jnp
+    from parmmg_tpu.ops import topo_incr as ti
+    from parmmg_tpu.ops.adjacency import build_adjacency
+    from parmmg_tpu.ops.edges import unique_edges
+    band = ti.polish_bands(capT)
+
+    def edges(slots):
+        def merged(m, tp):
+            nd = jnp.sum(tp.edirty, dtype=jnp.int32)
+            return ti.polish_unique_edges(m, tp, shell_slots=slots,
+                                          band=band) + (nd,)
+        return jax.jit(merged), jax.jit(
+            lambda m: unique_edges(m, shell_slots=slots))
+
+    def faces(m, tp):
+        nd = jnp.sum(tp.fdirty, dtype=jnp.int32)
+        return ti.polish_build_adjacency(m, tp, band=band) + (nd,)
+
+    mark = jax.jit(lambda tp, before, after: ti.mark_dirty(
+        tp, before.tet, before.tmask, after))
+    return edges, (jax.jit(faces), jax.jit(build_adjacency)), mark
+
+
+def replay(seen: dict, waves: int = 8, sliver_q: float = 0.2):
     """The polish on ``seen``, stage by stage; per wave, each stage's
     seconds and what it applied, the tets under ``sliver_q`` at the
     wave's entry, the two listed kernels' ``cand`` and ``wl`` rows, and
     per table the dirty rows it met, whether it came off the retained
-    sort, and the full sort's seconds.  Wave 0 pays the compiles."""
+    sort, and the full sort's seconds.  Wave 0 pays the compiles.
+    Returns (the rows, what the waves left: mesh, metric, state)."""
     import jax
     import jax.numpy as jnp
     from functools import partial
     from parmmg_tpu.driver import polish_budget
     from parmmg_tpu.ops import topo_incr as ti
     from parmmg_tpu.ops import worklist as wlist
-    from parmmg_tpu.ops.adjacency import (boundary_edge_tags,
-                                          build_adjacency)
+    from parmmg_tpu.ops.adjacency import boundary_edge_tags
     from parmmg_tpu.ops.collapse import collapse_wave
-    from parmmg_tpu.ops.edges import unique_edges
     from parmmg_tpu.ops.quality import quality_from_points
     from parmmg_tpu.ops.smooth import smooth_wave
     from parmmg_tpu.ops.swap import swap23_wave, swap_edges_wave
@@ -189,21 +239,8 @@ def replay(seen: dict, waves: int = 8, sliver_q: float = 0.2) -> list[dict]:
             return r.mesh, r.nswap, r.keep, r.ncand, r.nlist
         return jax.jit(run)
 
-    def edges(slots):
-        """(the table off the carried sort, the state, merged?, dirty
-        rows met) and the same table by the full sort."""
-        def merged(m, tp):
-            nd = jnp.sum(tp.edirty, dtype=jnp.int32)
-            return ti.polish_unique_edges(m, tp, shell_slots=slots,
-                                          band=band) + (nd,)
-        return jax.jit(merged), jax.jit(
-            lambda m: unique_edges(m, shell_slots=slots))
-
-    def faces(m, tp):
-        nd = jnp.sum(tp.fdirty, dtype=jnp.int32)
-        return ti.polish_build_adjacency(m, tp, band=band) + (nd,)
-
-    adjacency = (jax.jit(faces), jax.jit(build_adjacency))
+    edges, adjacency, mark = table_programs(
+        int(seen["mesh"].tmask.shape[0]))
     tables = {"collapse_table": edges(3), "swap_edges_table": edges(3),
               "swapgen_table": edges(RING_MAX), "adjacency": adjacency,
               "exit_adjacency": adjacency}
@@ -219,14 +256,7 @@ def replay(seen: dict, waves: int = 8, sliver_q: float = 0.2) -> list[dict]:
     }
     count_bad = jax.jit(count_bad)
     noted = jax.jit(wlist.noted)
-    mark = jax.jit(lambda tp, before, after: ti.mark_dirty(
-        tp, before.tet, before.tmask, after))
     rows = []
-
-    def timed(fn, *a):
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(fn(*a))
-        return out, time.perf_counter() - t0
 
     with host_staging():
         mesh = jax.tree.map(jnp.asarray, seen["mesh"])
@@ -304,7 +334,99 @@ def replay(seen: dict, waves: int = 8, sliver_q: float = 0.2) -> list[dict]:
             rows.append(row)
             if sum(row["n"][k] for k in APPLIED[:4]) == 0:
                 break       # the driver's loop ends here too
-    return rows
+    return rows, (mesh, met, topo)
+
+
+def replay_fem(left, rounds: int = 8) -> dict:
+    """The repair and the fem rounds of ``driver._finish_run`` on what
+    the waves ``left`` (mesh, metric, state), stage by stage: per round
+    each stage's seconds, the splits it applied, and per table the dirty
+    rows it met, whether it came off the carried sort, and the full
+    sort's seconds.  Run twice from the same state; the second run is
+    the one returned."""
+    import jax
+    import jax.numpy as jnp
+    from parmmg_tpu.ops import topo_incr as ti
+    from parmmg_tpu.ops.adapt import grow_mesh_met
+    from parmmg_tpu.ops.adjacency import boundary_edge_tags
+    from parmmg_tpu.ops.repair import repair_mesh
+    from parmmg_tpu.ops.split import split_wave
+    from parmmg_tpu.utils.placement import host_staging
+
+    def split(m, k, et):
+        r = split_wave(m, k, fem_only=True, budget_div=2, et=et)
+        return r.mesh, r.met, r.nsplit, r.overflow
+
+    split, bdytags = jax.jit(split), jax.jit(boundary_edge_tags)
+    made = {}
+
+    def programs(capT):
+        """The table makers at ``capT``, the same ones in both runs."""
+        if capT not in made:
+            edges, (faces, full_faces), mark = table_programs(capT)
+            # 3: the slots of split_wave's own table
+            made[capT] = edges(3) + (faces, full_faces, mark)
+        return made[capT]
+
+    def once(mesh, met, topo):
+        table, full_table, faces, full_faces, mark = programs(mesh.capT)
+        out = {"rounds": []}
+        (after, nrep), out["repair_s"] = timed(
+            lambda: repair_mesh(mesh, met))
+        out["repaired"] = int(nrep)
+        if after is not mesh:
+            topo = mark(topo, mesh, after)
+        mesh = after
+        for w in range(rounds):
+            row = {"round": w, "s": dict.fromkeys(FEM_STAGES, 0.0),
+                   "dirty": {}, "merged": {}, "full_s": {}}
+            _, row["full_s"]["edge_table"] = timed(full_table, mesh)
+            (et, topo, inc, nd), row["s"]["edge_table"] = timed(
+                table, mesh, topo)
+            row["dirty"]["edge_table"] = int(nd)
+            row["merged"]["edge_table"] = bool(inc)
+            (after, met, n, ovf), row["s"]["split"] = timed(
+                split, mesh, met, et)
+            topo, row["s"]["list"] = timed(mark, topo, mesh, after)
+            mesh, row["s"]["bdytags"] = timed(bdytags, after)
+            _, row["full_s"]["adjacency"] = timed(full_faces, mesh)
+            (mesh, topo, inc, nd), row["s"]["adjacency"] = timed(
+                faces, mesh, topo)
+            row["dirty"]["adjacency"] = int(nd)
+            row["merged"]["adjacency"] = bool(inc)
+            row["split"], row["overflow"] = int(n), bool(ovf)
+            out["rounds"].append(row)
+            if row["overflow"]:     # the driver's rule: regrow, no state
+                mesh, met = grow_mesh_met(mesh, met, 2 * mesh.capP,
+                                          2 * mesh.capT)
+                topo = ti.topo_init(mesh.capT)
+                table, full_table, faces, full_faces, mark = programs(
+                    mesh.capT)
+                continue
+            if row["split"] == 0:
+                break
+        return out
+
+    with host_staging():
+        mesh, met, topo = jax.tree.map(jnp.asarray, left)
+        once(mesh, met, topo)       # the compiles
+        out = once(mesh, met, topo)
+    for row in out["rounds"]:
+        say(f"  fem round {row['round']}: split {row['split']:4d}  "
+            + "  ".join(f"{k} {row['s'][k]:.4f}s" for k in FEM_STAGES)
+            + "  tables " + "  ".join(
+                f"{k} {row['dirty'][k]}{'m' if row['merged'][k] else 'F'}"
+                f" {row['full_s'][k]:.4f}s" for k in row["dirty"]))
+    # a job's rounds with the state carried, and with both tables of
+    # every round sorted in full (what the rounds cost without one)
+    out["rounds_s"] = sum(sum(r["s"].values()) for r in out["rounds"])
+    out["rounds_full_s"] = out["rounds_s"] + sum(
+        r["full_s"][k] - r["s"][k] for r in out["rounds"]
+        for k in r["full_s"])
+    out["tables"] = sum(len(r["dirty"]) for r in out["rounds"])
+    out["tables_merged"] = sum(sum(r["merged"].values())
+                               for r in out["rounds"])
+    return out
 
 
 def summary(rows: list[dict]) -> dict:
@@ -371,8 +493,9 @@ def main() -> int:
         result["host_cores"] = os.cpu_count()
         result["rows_live"] = int(seen["mesh"].tmask.sum())
         result["rows_cap"] = int(seen["mesh"].tmask.shape[0])
-        result["waves"] = replay(seen)
+        result["waves"], left = replay(seen)
         result["summary"] = summary(result["waves"])
+        result["fem"] = replay_fem(left)
     line = json.dumps(result)
     if args.out:
         os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)

@@ -586,6 +586,31 @@ def test_a_fem_round_has_a_map_of_its_own(small):
     assert set(smap.counts["sorts_by_phase"]) <= set(FEM)
 
 
+def test_a_fem_round_with_a_state_keeps_its_stages_and_tables(small):
+    """With the polish's ``TopoState`` handed on (PR 44) the round's two
+    tables are ``polish_unique_edges`` / ``polish_build_adjacency``, jitted
+    functions of their own: the map still finds ``tab.edges`` under
+    ``fem.split`` and ``tab.adjacency`` under ``fem.adjacency``, and the
+    full sorts (the arm a round takes where nothing is retained) there."""
+    from parmmg_tpu.ops import adapt
+    from parmmg_tpu.ops.topo_incr import topo_init
+    m, met = small["mesh"], small["met"]
+    out = adapt.fem_pass(jax.tree_util.tree_map(jnp.copy, m), jnp.copy(met),
+                         topo_init(m.capT))
+    jax.block_until_ready(out)
+    assert np.asarray(out[2]).tolist()[3:] == [2, 0]    # nothing retained
+    smap = devtime.scope_map("adapt.fem_pass")          # the last call's
+    assert smap.module == "jit_fem_pass_impl"
+    pairs = set(smap.phases.values())
+    assert set(FEM) <= {p for p, _ in pairs}
+    assert ("fem.split", "tab.edges") in pairs
+    assert ("fem.adjacency", "tab.adjacency") in pairs
+    assert not {("fem.split", "tab.adjacency"),
+                ("fem.adjacency", "tab.edges")} & pairs
+    assert {"fem.split", "fem.adjacency"} <= \
+        set(smap.counts["sorts_by_phase"]) <= set(FEM)
+
+
 def test_no_map_where_a_second_compile_would_cost_what_the_first_did(
         tmp_path):
     """No persistent cache and a program that took minutes to compile:

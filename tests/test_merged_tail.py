@@ -78,9 +78,10 @@ def run_tail(mesh, met):
     stats, tim = AdaptStats(), Timers()
     otrace.TRACER.configure(path=None)
     otrace.TRACER.reset()
-    mesh, _ = driver._merged_polish(mesh, met, info, None, stats, tim)
+    mesh, _, topo = driver._merged_polish(mesh, met, info, None, stats,
+                                          tim)
     mesh, met, stats = driver._finish_run(None, mesh, met, stats, info,
-                                          tim, None, None, None)
+                                          tim, None, None, None, topo=topo)
     recs = [r for r in otrace.TRACER.ring if r.get("kind") == "span"]
     waves = [[r["collapse"], r["swap"], r["moved"]] for r in recs
              if r["name"] == "polish wave"]

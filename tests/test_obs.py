@@ -644,6 +644,26 @@ def test_polish_waves_say_how_their_tables_were_made(grouped_job):
             r["inc"] for r in waves)
 
 
+def test_fem_rounds_say_how_their_tables_were_made(grouped_job):
+    """``tab``, ``inc`` on every ``fem round`` of a grouped job (PR 44):
+    the round's edge table and adjacency, and those of them taken off
+    the sorts the merged polish handed on: all, every round's first too.
+    ``tail.fem_tables`` / ``tail.fem_tables_merged`` are the rounds' own
+    sums, once a job."""
+    for which in ("cold", "warm"):
+        job = grouped_job[which]
+        recs, _ = _tree(job["records"])
+        rounds = [r for r in recs if r["name"] == "fem round"]
+        assert rounds and all({"tab", "inc"} <= set(r) for r in rounds)
+        assert all((r["tab"], r["inc"]) == (2, 2) for r in rounds)
+        assert {"tail.fem_tables", "tail.fem_tables_merged"} <= \
+            set(job["counters"])
+        assert job["counters"]["tail.fem_tables"] == sum(
+            r["tab"] for r in rounds)
+        assert job["counters"]["tail.fem_tables_merged"] == sum(
+            r["inc"] for r in rounds)
+
+
 def test_tail_rows_are_counted_once_a_job(grouped_job):
     """``tail.rows_live`` / ``tail.rows_cap``: the mesh the merged tail
     is about to run on, which is the last merge's, at the capacity
@@ -766,6 +786,10 @@ def test_the_capture_is_digested_into_one_device_phases_event(grouped_job):
             part["outside"] == pytest.approx(part["total"])
     assert set(ev["fem"]["phases"]) == {"fem.split", "fem.bdytags",
                                         "fem.adjacency"}
+    # with the polish's state carried the round's tables are the merged
+    # polish's own derivations, still under the round's stages
+    assert set(ev["fem"]["tables"]["tab.edges"]) == {"fem.split"}
+    assert set(ev["fem"]["tables"]["tab.adjacency"]) == {"fem.adjacency"}
     assert ev["digest_s"] > 0
 
 
