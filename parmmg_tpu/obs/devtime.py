@@ -98,8 +98,9 @@ class ScopeMap(NamedTuple):
                  / ``call`` and what never executes left out), ``scoped``
                  those of them under a phase, ``sorts`` the ``sort``
                  instructions among them, ``sorts_by_phase``; where the
-                 program's capacities are known, ``scalar_gathers`` and
-                 ``scalar_gathers_by_phase`` (:func:`map_from_text`);
+                 program's capacities are known, ``scalar_gathers``,
+                 ``perm_gathers`` and their ``_by_phase``
+                 (:func:`map_from_text`);
     ``control``  the control-flow instructions: their events span their
                  bodies', so no sum counts them;
     ``module``   the name of the program's module events in a capture.
@@ -191,14 +192,21 @@ def map_from_text(text: str, capP: int | None = None,
     an index out of a per-vertex vector at a tet table's width or over
     (operand of rank 1 and at most ``capP + 1`` elements, result of at
     least ``capT`` elements), the dear kind of fetch on the chip
-    (``ops/rowpack``; PERF.md section 5, PR 42); and
-    ``scalar_gathers_by_phase``."""
+    (``ops/rowpack``; PERF.md section 5, PR 42), and ``perm_gathers``:
+    those whose result has at least ``capT`` rows and whose table has
+    exactly as many rows as the result, a table as long as its index
+    (``x[order]``, ``x[partner]``: a fetch through a permutation, which
+    has no cheap form on the chip and which a sort that carries ``x`` as
+    an operand, or a shift, does without; ``ops/edges.sort_carry``, PR
+    45), fusions inside fusions included (what this compiler makes of
+    ``.at[order].set``); each with its ``_by_phase``."""
     module, entry, comps = parse_hlo(text)
     phases: dict = {}
     control = set()
     counts = {"ops": 0, "scoped": 0, "sorts": 0, "sorts_by_phase": {}}
     if capP is not None and capT is not None:
-        counts.update(scalar_gathers=0, scalar_gathers_by_phase={})
+        counts.update(scalar_gathers=0, scalar_gathers_by_phase={},
+                      perm_gathers=0, perm_gathers_by_phase={})
     dims_of = {i.name: i.dims for body in comps.values() for i in body}
 
     def count(key, phase):
@@ -211,6 +219,21 @@ def map_from_text(text: str, capP: int | None = None,
         return i.opcode == "gather" and i.dims is not None \
             and table is not None and len(table) == 1 \
             and table[0] <= capP + 1 and math.prod(i.dims) >= capT
+
+    def perm_gather(i) -> bool:
+        table = dims_of.get(i.operands[0]) if i.operands else None
+        return i.opcode == "gather" and bool(i.dims) and bool(table) \
+            and i.dims[0] >= capT and table[0] == i.dims[0]
+
+    def nested(fused, depth=0) -> list:
+        """``fused`` and what the fusions among them fuse: this
+        compiler writes a scatter through a permutation as a sort of the
+        indices and a fusion of fusions that fetches the updates through
+        the sorted permutation."""
+        inner = [i for f in fused if f.opcode == "fusion"
+                 for c in f.callees for i in comps.get(c, ())]
+        return fused + (nested(inner, depth + 1)
+                        if inner and depth < 8 else [])
 
     def walk(comp, inherited, depth=0):
         for instr in comps.get(comp, ()):
@@ -237,6 +260,9 @@ def map_from_text(text: str, capP: int | None = None,
                 for i in [instr] + fused:
                     if scalar_gather(i):
                         count("scalar_gathers", phase)
+                for i in [instr] + nested(fused):
+                    if perm_gather(i):
+                        count("perm_gathers", phase)
 
     if entry is not None:
         walk(entry, (None, None))

@@ -21,87 +21,89 @@ def _face_keys(mesh: Mesh):
     """Sorted-triple face keys as 3 int32 columns, invalid tets last.
 
     Pure int32 (no int64 emulation on TPU): multi-column keys are matched
-    with ``jnp.lexsort`` + column-wise equality instead of one packed key.
-    Returns (cols [F,3], tetid [F], faceid [F]).
+    with a multi-column sort + column-wise equality instead of one packed
+    key.  Returns cols [F,3]; slot i is face i % 4 of tet i // 4.
     """
     capT = mesh.capT
     fv = tet_face_vertices(mesh.tet).reshape(capT * 4, 3)       # [F,3]
     fv = jnp.sort(fv, axis=1)
     invalid = ~jnp.repeat(mesh.tmask, 4)
     big = jnp.iinfo(jnp.int32).max
-    fv = jnp.where(invalid[:, None], big, fv)
-    tetid = jnp.repeat(jnp.arange(capT, dtype=jnp.int32), 4)
-    faceid = jnp.tile(jnp.arange(4, dtype=jnp.int32), capT)
-    return fv, tetid, faceid
+    return jnp.where(invalid[:, None], big, fv)
 
 
 def face_sort(mesh: Mesh):
     """THE face-sort pass, shared by ``build_adjacency`` and the direct
     swap23 pairing (``ops.swap.swap23_wave(..., facesort=True)``).
 
-    Returns sorted-order face records ``(t, f, partner, matched,
-    valid_s)``: per sorted slot the tet id, local face id, the sorted-slot
-    index of the twin slot (self if unmatched), whether a twin exists, and
-    whether the slot belongs to a live tet.  Matched twins are adjacent in
-    sorted order, so ``(t[i], f[i]) <-> (t[partner[i]], f[partner[i]])``
-    IS the face-pair table — consumers that only need the pairing (swap23
+    Returns sorted-order face records ``(t, f, tp, fp, matched,
+    valid_s)``: per sorted slot the tet id and local face id, those of
+    the twin slot (its own if unmatched), whether a twin exists, and
+    whether the slot belongs to a live tet.  Matched twins are adjacent
+    in sorted order, so ``(t[i], f[i]) <-> (tp[i], fp[i])`` IS the
+    face-pair table — consumers that only need the pairing (swap23
     candidate selection) read it here without materializing the [capT,4]
-    ``adja`` matrix.
+    ``adja`` matrix.  The sort hands back its own sorted key columns
+    (``edges.sort_carry``) and a twin is read by a shift: nothing is
+    fetched through the permutation.  The sort itself is cheap on the
+    chip (PERF.md section 5: a block's sorts are 2 % of it).
     """
-    from .edges import PACK_LIMIT
+    from .edges import PACK_LIMIT, sort_carry
     with otrace.scope("tab.adjacency"):
-        capT = mesh.capT
-        big = jnp.iinfo(jnp.int32).max
-        cols, tetid, faceid = _face_keys(mesh)
+        cols = _face_keys(mesh)
         if mesh.capP <= PACK_LIMIT:
             # pack the two minor columns into one int32 (ids < capP <=
-            # sqrt(2^31)): the 3-pass lexsort becomes 2 passes — face
-            # matching is one of the measured per-wave hot spots
-            invalid = cols[:, 0] == big
-            w = jnp.where(invalid, big,
-                          cols[:, 1] * mesh.capP + cols[:, 2])
-            order = jnp.lexsort((w, cols[:, 0]))
-            return face_records_from_sorted(mesh, order, cols[order, 0],
-                                            w[order])
-        order = jnp.lexsort((cols[:, 2], cols[:, 1], cols[:, 0]))
-        k = cols[order]
-        t = tetid[order]
-        f = faceid[order]
-        return _pair_records(capT, k, t, f, big)
+            # sqrt(2^31)): two key columns instead of three
+            order, (k0, kw), _ = sort_carry(
+                (cols[:, 0], pack_minor(cols, mesh.capP)))
+            return face_records_from_sorted(order, k0, kw)
+        order, k, _ = sort_carry((cols[:, 0], cols[:, 1], cols[:, 2]))
+        return _pair_records(k, order)
 
 
-def face_records_from_sorted(mesh: Mesh, order: jax.Array,
+def pack_minor(cols, capP: int):
+    """The two minor columns of ``_face_keys`` packed into one int32
+    (INT32_MAX on invalid slots); needs ``capP <= PACK_LIMIT``."""
+    big = jnp.iinfo(jnp.int32).max
+    return jnp.where(cols[:, 0] == big, big, cols[:, 1] * capP + cols[:, 2])
+
+
+def face_records_from_sorted(order: jax.Array,
                              k0: jax.Array, kw: jax.Array):
     """``face_sort``'s record tuple from a precomputed PACKED face sort:
     ``order`` is the stable sort permutation over the 4*capT face slots,
     ``k0``/``kw`` the ascending (major vertex, packed minor pair) key
-    columns — exactly what the packed lexsort produces.  Factored so the
+    columns — exactly what the packed sort produces.  Factored so the
     incremental path (ops/topo_incr) feeds its band-merged sort through
     the SAME twin-pairing epilogue.  ``t = order // 4`` / ``f = order %
-    4`` reproduce the tetid/faceid gathers bit-for-bit (slot layout:
-    tet-major).  Requires ``capP <= PACK_LIMIT``."""
-    big = jnp.iinfo(jnp.int32).max
-    k = jnp.stack([k0, kw], axis=1)
-    order = order.astype(jnp.int32)
-    t = order // 4
-    f = order % 4
-    return _pair_records(mesh.capT, k, t, f, big)
+    4``: the slot layout is tet-major.  Requires ``capP <=
+    PACK_LIMIT``."""
+    return _pair_records((k0, kw), order.astype(jnp.int32))
 
 
-def _pair_records(capT: int, k, t, f, big):
-    """Twin pairing over sorted face keys (shared epilogue): matched
-    twins are adjacent in sorted order."""
+def twin(x, same_next, same_prev):
+    """``x`` of each sorted slot's twin, its own where it has none: the
+    twin is the NEXT slot where ``same_next`` and the previous one where
+    ``same_prev``, so it is read by a shift, not fetched by index."""
+    up = jnp.concatenate([x[1:], x[-1:]])
+    dn = jnp.concatenate([x[:1], x[:-1]])
+    return jnp.where(same_next, up, jnp.where(same_prev, dn, x))
+
+
+def _pair_records(k, order):
+    """Twin pairing over sorted face key columns ``k`` (shared epilogue):
+    matched twins are adjacent in sorted order.  A slot is face
+    ``order % 4`` of tet ``order // 4``, its twin's the same of the
+    twin's slot."""
     from .edges import segment_first
-    first = segment_first(tuple(k[:, j] for j in range(k.shape[1])))
-    eq_next = ~first[1:] & (k[:-1, 0] != big)
+    big = jnp.iinfo(jnp.int32).max
+    first = segment_first(k)
+    eq_next = ~first[1:] & (k[0][:-1] != big)
     same_next = jnp.concatenate([eq_next, jnp.array([False])])
     same_prev = jnp.concatenate([jnp.array([False]), eq_next])
-    # partner index in sorted order (self if unmatched)
-    idx = jnp.arange(capT * 4)
-    partner = jnp.where(same_next, idx + 1, jnp.where(same_prev, idx - 1, idx))
-    matched = same_next | same_prev
-    valid_s = k[:, 0] != big
-    return t, f, partner, matched, valid_s
+    other = twin(order, same_next, same_prev)
+    return (order // 4, order % 4, other // 4, other % 4,
+            same_next | same_prev, k[0] != big)
 
 
 def bdy_tags_from_sort(mesh: Mesh, t, f, matched, valid_s):
@@ -122,27 +124,24 @@ def build_adjacency(mesh: Mesh) -> Mesh:
 
     In a conforming mesh every interior face appears exactly twice. After
     sorting face keys, twins are neighbors in sorted order; the pairing is
-    scattered back as ``adja[t,f] = 4*t' + f'``.
+    put back in slot order as ``adja[t,f] = 4*t' + f'``.
     """
     with otrace.scope("tab.adjacency"):
-        t, f, partner, matched, _ = face_sort(mesh)
-        return adjacency_from_records(mesh, t, f, partner, matched)
+        t, f, tp, fp, matched, _ = face_sort(mesh)
+        return adjacency_from_records(mesh, t, f, tp, fp, matched)
 
 
-def adjacency_from_records(mesh: Mesh, t, f, partner, matched) -> Mesh:
-    """``build_adjacency``'s scatter epilogue from face-sort records —
-    shared with the incremental path (ops/topo_incr), which feeds it
-    band-merged records."""
+def adjacency_from_records(mesh: Mesh, t, f, tp, fp, matched) -> Mesh:
+    """``build_adjacency``'s epilogue from face-sort records (the twins
+    back in slot order, ``edges.unsort``) — shared with the incremental
+    path (ops/topo_incr), which feeds it band-merged records."""
+    from .edges import unsort
     capT = mesh.capT
-    adj_val = jnp.where(matched, 4 * t[partner] + f[partner], -1)
+    adj_val = jnp.where(matched, 4 * tp + fp, -1)
 
-    adja = jnp.full((capT, 4), -1, jnp.int32)
-    # (t, f) is a permutation of all slots: unique_indices lets the TPU
-    # scatter run fully parallel (duplicate-tolerant scatter measured ~2x
-    # slower at these shapes, scripts/tpu_microbench.py)
-    adja = adja.at[t, f].set(adj_val.astype(jnp.int32),
-                             unique_indices=True)
-    adja = jnp.where(mesh.tmask[:, None], adja, -1)
+    # slot 4 * t + f runs over a permutation of all slots
+    (adja,) = unsort(4 * t + f, (adj_val.astype(jnp.int32),))
+    adja = jnp.where(mesh.tmask[:, None], adja.reshape(capT, 4), -1)
 
     # boundary faces: valid tet, face has no twin
     is_bdy = (adja < 0) & mesh.tmask[:, None]

@@ -500,33 +500,30 @@ def _tag_joins_core(new_tet, ftag, fref, etag, donor, recv, capP):
     # code recovered only the MG_BDY bit via the next build_adjacency and
     # silently dropped fref/REQ/REF bits).
     from ..core.mesh import tet_face_vertices
-    from .edges import PACK_LIMIT, segmented_or, segmented_max
+    from .edges import (PACK_LIMIT, segment_first, segmented_or,
+                        segmented_max, sort_carry)
     F4 = n * 4
     fvn = jnp.sort(tet_face_vertices(new_tet).reshape(F4, 3), axis=1)
     donor_f = jnp.repeat(donor, 4)
     recv_f = jnp.repeat(recv, 4)
     rel_f = donor_f | recv_f
     i32max = jnp.iinfo(jnp.int32).max
+    # the donors' tags and refs ride in the sort (edges.sort_carry): no
+    # stage here fetches through the permutation it has just made
+    dtag_in = jnp.where(donor_f, ftag.reshape(F4), 0)
+    dref_in = jnp.where(donor_f, fref.reshape(F4), 0)
     if capP <= PACK_LIMIT:
-        w_f = jnp.where(rel_f, fvn[:, 1] * capP + fvn[:, 2], i32max)
-        k0_f = jnp.where(rel_f, fvn[:, 0], i32max)
-        order_f = jnp.lexsort((w_f, k0_f))
-        k0s, k1s = k0_f[order_f], w_f[order_f]
-        first_f = jnp.concatenate(
-            [jnp.array([True]), (k0s[1:] != k0s[:-1]) | (k1s[1:] != k1s[:-1])])
+        keys_f = (jnp.where(rel_f, fvn[:, 0], i32max),
+                  jnp.where(rel_f, fvn[:, 1] * capP + fvn[:, 2], i32max))
     else:
-        c0 = jnp.where(rel_f, fvn[:, 0], i32max)
-        c1 = jnp.where(rel_f, fvn[:, 1], i32max)
-        c2 = jnp.where(rel_f, fvn[:, 2], i32max)
-        order_f = jnp.lexsort((c2, c1, c0))
-        k0s, k1s, k2s = c0[order_f], c1[order_f], c2[order_f]
-        first_f = jnp.concatenate(
-            [jnp.array([True]), (k0s[1:] != k0s[:-1]) |
-             (k1s[1:] != k1s[:-1]) | (k2s[1:] != k2s[:-1])])
+        keys_f = tuple(jnp.where(rel_f, fvn[:, j], i32max)
+                       for j in range(3))
+    order_f, ks_f, (dtag_f, dref_f) = sort_carry(keys_f,
+                                                 (dtag_in, dref_in))
+    first_f = segment_first(ks_f)
     seg_f = jax.lax.associative_scan(
         jnp.maximum, jnp.where(first_f, jnp.arange(F4), 0))
     is_last_f = jnp.concatenate([first_f[1:], jnp.array([True])])
-    dtag_f = jnp.where(donor_f[order_f], ftag.reshape(F4)[order_f], 0)
     or_f = segmented_or(first_f, dtag_f)
     tot_tag = jnp.zeros(F4, jnp.uint32).at[
         jnp.where(is_last_f, seg_f, F4)].set(
@@ -534,7 +531,6 @@ def _tag_joins_core(new_tet, ftag, fref, etag, donor, recv, capP):
     add_tag_s = tot_tag[seg_f]
     add_tag = jnp.zeros(F4, jnp.uint32).at[order_f].set(
         add_tag_s, unique_indices=True).reshape(n, 4)
-    dref_f = jnp.where(donor_f[order_f], fref.reshape(F4)[order_f], 0)
     mx_f = segmented_max(first_f, dref_f)
     tot_ref = jnp.zeros(F4, jnp.int32).at[
         jnp.where(is_last_f, seg_f, F4)].set(
@@ -559,10 +555,10 @@ def _tag_joins_core(new_tet, ftag, fref, etag, donor, recv, capP):
     alive_s = jnp.repeat(recv, 6)
     donor_s = jnp.repeat(donor, 6)
     rel = alive_s | donor_s
-    order, _, _, first = sort_pairs(ka, kb, rel, capP)
+    order, _, _, first, (dtag,) = sort_pairs(
+        ka, kb, rel, capP, (jnp.where(donor_s, etag.reshape(n * 6), 0),))
     seg = jax.lax.associative_scan(
         jnp.maximum, jnp.where(first, jnp.arange(n * 6), 0))
-    dtag = jnp.where(donor_s[order], etag.reshape(n * 6)[order], 0)
     # segment OR of donor tags, then broadcast the segment total back to
     # every member (the OR-scan total sits at the LAST member)
     or_fwd = segmented_or(first, dtag)

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from ..core.mesh import Mesh, tet_edge_vertices
 from ..core.constants import IARE
 from ..obs import trace as otrace
+from ..utils import placement
 
 _INT32_MAX = 2147483647
 
@@ -61,32 +62,71 @@ def free_rows(mask: jax.Array, K: int):
     return rows, jnp.sum(~mask, dtype=jnp.int32)
 
 
-def sort_pairs(a: jax.Array, b: jax.Array, valid: jax.Array, capP: int):
+def sort_carry(keys, payloads=()):
+    """ONE stable ``lax.sort`` over the key columns, most significant
+    first, that hands back what it sorted: ``(order, sorted keys, sorted
+    payloads)``, tuples as given.  ``order`` is what ``jnp.argsort`` /
+    ``jnp.lexsort`` return (they ARE this sort, with the sorted keys
+    thrown away), so every result equals the argsort-and-fetch
+    formulation to the bit on any backend.
+
+    The sorted keys are operands of the sort already and cost nothing;
+    a payload column rides as one more operand.  On the chip that is
+    0.07 ms at 6 x capT where the fetch through the permutation (a
+    gather out of a table as long as its index) is 1.7-4.4 ms.  XLA:CPU
+    pays the other way (an operand costs its sort a quarter, 18 ms at
+    that width, the fetch under 1 ms) but meets 2 to 14 such sorts a
+    job, most at band width: one path (PERF.md section 5, "a
+    permutation's two ways")."""
+    nk = len(keys)
+    iota = jnp.arange(keys[0].shape[0], dtype=jnp.int32)
+    out = jax.lax.sort((*keys, iota, *payloads), num_keys=nk,
+                       is_stable=True)
+    return out[nk], tuple(out[:nk]), tuple(out[nk + 1:])
+
+
+def unsort(order, cols):
+    """The way back: ``cols`` (int32, in sorted order) in slot order,
+    ``out[order[i]] = col[i]`` for a permutation ``order`` of all slots.
+    Placed on a TPU it is a sort keyed on ``order`` that carries the
+    columns (0.42 ms for two at 6 x capT, where the scatter is 3.6: the
+    compiler sorts the indices anyway and then fetches the rows through
+    them); elsewhere ONE packed scatter (XLA:CPU: 1.4 ms against a sort
+    of 86; PERF.md section 5, "a permutation's two ways")."""
+    if placement.placed_on_tpu():
+        return tuple(jax.lax.sort((order, *cols), num_keys=1)[1:])
+    pay = jnp.stack(cols, axis=1)
+    back = jnp.zeros_like(pay).at[order].set(pay, unique_indices=True)
+    return tuple(back[:, j] for j in range(len(cols)))
+
+
+def sort_pairs(a: jax.Array, b: jax.Array, valid: jax.Array, capP: int,
+               payloads=()):
     """Sort (a, b) id pairs ascending, invalid slots last.
 
-    Returns (order, ka, kb, first): the sort permutation, the sorted key
-    columns (INT32_MAX on invalid slots), and the unique-segment heads.
+    Returns (order, ka, kb, first, pays): the sort permutation, the
+    sorted key columns (INT32_MAX on invalid slots), the unique-segment
+    heads, and ``payloads`` in sorted order (:func:`sort_carry`: nothing
+    is fetched back through ``order`` on the chip).
     When ids fit (capP <= PACK_LIMIT — always true for ParMmg-sized
     shards, the reference targets ~30k-element groups) both keys pack
-    into ONE int32 so the TPU runs a single O(n log^2 n) sort instead of
-    two stable lexsort passes — the sorts are the measured hot spot of
-    every wave.
+    into ONE int32 key column.  The sort itself is cheap on the chip (a
+    block's 58 sorts are 2 % of it); what a table costs is the gathers
+    and scatters round it (PERF.md section 5).
     """
     if capP <= PACK_LIMIT:
         key = jnp.where(valid, a * capP + b, _INT32_MAX)
-        order = jnp.argsort(key)
-        ks = key[order]
+        order, (ks,), pays = sort_carry((key,), payloads)
         first = segment_first((ks,))
         inv = ks == _INT32_MAX
         ka = jnp.where(inv, _INT32_MAX, ks // capP)
         kb = jnp.where(inv, _INT32_MAX, ks % capP)
-        return order, ka, kb, first
+        return order, ka, kb, first, pays
     aa = jnp.where(valid, a, _INT32_MAX)
     bb = jnp.where(valid, b, _INT32_MAX)
-    order = jnp.lexsort((bb, aa))
-    ka, kb = aa[order], bb[order]
+    order, (ka, kb), pays = sort_carry((aa, bb), payloads)
     first = segment_first((ka, kb))
-    return order, ka, kb, first
+    return order, ka, kb, first, pays
 
 
 def segment_first(words) -> jax.Array:
@@ -164,32 +204,38 @@ def unique_edges(mesh: Mesh, shell_slots: int = 3) -> EdgeTable:
         a = jnp.minimum(ev[:, 0], ev[:, 1])
         b = jnp.maximum(ev[:, 0], ev[:, 1])
         valid = jnp.repeat(mesh.tmask, 6)
-        order, ka, kb, first = sort_pairs(a, b, valid, mesh.capP)
-        return _edges_epilogue(mesh, order, ka, kb, first, shell_slots)
+        order, ka, kb, first, (tags,) = sort_pairs(
+            a, b, valid, mesh.capP, (mesh.etag.reshape(n6),))
+        return _edges_epilogue(mesh, order, ka, kb, first, shell_slots,
+                               tags)
 
 
 def unique_edges_from_sorted(mesh: Mesh, order: jax.Array, ks: jax.Array,
-                             shell_slots: int = 0) -> EdgeTable:
+                             shell_slots: int = 0, tags=None) -> EdgeTable:
     """EdgeTable from a precomputed PACKED edge sort: ``order`` is the
     stable sort permutation over the 6*capT slot keys and ``ks`` the
     ascending packed keys (a*capP+b, INT32_MAX on invalid slots) —
     exactly what ``sort_pairs``' packed branch produces.  This is the
     epilogue of :func:`unique_edges` factored out so the incremental
     path (ops/topo_incr) can feed a band-merged sort through the SAME
-    code: tag payloads are re-gathered from the CURRENT mesh here, so
-    the retained state never carries tags.  Requires
-    ``capP <= PACK_LIMIT``."""
+    code.  ``tags``: the CURRENT mesh's edge tags in sorted order where
+    the caller has them (a full sort carried them); the retained state
+    carries none by design, so None fetches them through ``order`` (a
+    merged sort has no sort to ride in).  Requires ``capP <=
+    PACK_LIMIT``."""
     first = segment_first((ks,))
     inv = ks == _INT32_MAX
     ka = jnp.where(inv, _INT32_MAX, ks // mesh.capP)
     kb = jnp.where(inv, _INT32_MAX, ks % mesh.capP)
-    return _edges_epilogue(mesh, order, ka, kb, first, shell_slots)
+    return _edges_epilogue(mesh, order, ka, kb, first, shell_slots, tags)
 
 
 def _edges_epilogue(mesh: Mesh, order, ka, kb, first,
-                    shell_slots: int) -> EdgeTable:
-    """Shared unique_edges epilogue: segment scan + scatters from the
-    sorted key columns (bit-neutral factoring of the original body)."""
+                    shell_slots: int, tags=None) -> EdgeTable:
+    """Shared unique_edges epilogue: the segment scans, the way back to
+    slot order and the shell scatter, from the sorted key columns.
+    ``tags``: the slots' edge tags in sorted order where the sort carried
+    them (``unique_edges``); None fetches them through ``order``."""
     capT = mesh.capT
     n6 = capT * 6
     valid_s = ka != _INT32_MAX          # sorted-order validity, no gather
@@ -197,7 +243,9 @@ def _edges_epilogue(mesh: Mesh, order, ka, kb, first,
     # ONE tuple-carry scan produces the segment head AND the running
     # etag-OR together (two separate scans were a measured cost).
     pos = jnp.arange(n6)
-    tags = jnp.where(valid_s, mesh.etag.reshape(n6)[order], 0)
+    if tags is None:
+        tags = mesh.etag.reshape(n6)[order]
+    tags = jnp.where(valid_s, tags, 0)
 
     def seg_comb2(pa, pb):
         fa, ha, va = pa
@@ -214,23 +262,25 @@ def _edges_epilogue(mesh: Mesh, order, ka, kb, first,
     emask = first & valid_s
     ev_u = jnp.stack([ka, kb], axis=1)
     # per-unique-edge values (full OR of tags; shell count = last rank+1)
-    # land at the head slot with ONE packed 2-column scatter
-    head_pay = jnp.stack([or_scan.astype(jnp.int32),
-                          (rank + 1).astype(jnp.int32)], axis=1)
-    head_tbl = jnp.zeros((n6, 2), jnp.int32).at[
-        jnp.where(is_last, eid_sorted, n6)].set(
-        head_pay, mode="drop", unique_indices=True)
-    etag = head_tbl[:, 0].astype(jnp.uint32)
-    nshell = head_tbl[:, 1]
+    # stand at the segment's LAST slot: a reverse segmented scan hands
+    # them to its head (a drop scatter to the head was 3.6 ms a table on
+    # the chip, the scan 0.39; on XLA:CPU they cost the same)
+    def seg_last(pa, pb):
+        # reverse scan: pa is the element further RIGHT
+        la, va, na = pa
+        lb, vb, nb = pb
+        return la | lb, jnp.where(lb, vb, va), jnp.where(lb, nb, na)
+
+    _, tot_or, tot_n = jax.lax.associative_scan(
+        seg_last, (is_last, or_scan, (rank + 1).astype(jnp.int32)),
+        reverse=True)
+    etag = jnp.where(first, tot_or, 0).astype(jnp.uint32)
+    nshell = jnp.where(first, tot_n, 0)
     # per (tet, local edge) slot: unique edge id + rank within the shell
     # (stable lexsort keeps equal keys in slot order = ascending tet id),
-    # scattered back through the permutation in ONE packed scatter
-    back_pay = jnp.stack([eid_sorted.astype(jnp.int32),
-                          rank.astype(jnp.int32)], axis=1)
-    back = jnp.zeros((n6, 2), jnp.int32).at[order].set(
-        back_pay, unique_indices=True)
-    edge_id = back[:, 0].reshape(capT, 6)
-    shell_rank = back[:, 1].reshape(capT, 6)
+    # back in slot order through the permutation
+    edge_id, shell_rank = (c.reshape(capT, 6) for c in unsort(
+        order, (eid_sorted.astype(jnp.int32), rank.astype(jnp.int32))))
     # first-S shell tet ids per edge (3 for the 3-2 swap; 6-7 for the
     # generalized ring swaps): rank within segment
     if shell_slots > 0:

@@ -360,8 +360,10 @@ def swap_edges_wave(mesh: Mesh, met: jax.Array, enable32: bool = True,
                                       kmax])
                 vv = jnp.concatenate([et.emask, base22])
                 n_all = Efull + E
-                order, _, _, first = sort_pairs(aa, bb, vv, capP)
-                is_edge = (order < Efull) & vv[order]
+                order, ka_s, _, first, _ = sort_pairs(aa, bb, vv, capP)
+                # a valid slot's sorted key is under INT32_MAX
+                is_edge = (order < Efull) & \
+                    (ka_s != jnp.iinfo(jnp.int32).max)
                 has_edge = segmented_or(first, is_edge.astype(jnp.uint32))
                 is_last = jnp.concatenate([first[1:], jnp.array([True])])
                 seg = jax.lax.associative_scan(
@@ -521,9 +523,10 @@ def swap_edges_wave(mesh: Mesh, met: jax.Array, enable32: bool = True,
         # only the first winner per key (sort is ~free on this device).
         from .edges import sort_pairs as _sp
         win22 = win & base22
-        order_d, _, _, first_d = _sp(jnp.minimum(x0, x1),
-                                     jnp.maximum(x0, x1), win22, capP)
-        dup_sorted = win22[order_d] & ~first_d
+        order_d, ka_d, _, first_d, _ = _sp(
+            jnp.minimum(x0, x1), jnp.maximum(x0, x1), win22, capP)
+        # a winner's sorted key is under INT32_MAX: no fetch of win22
+        dup_sorted = (ka_d != jnp.iinfo(jnp.int32).max) & ~first_d
         dup = jnp.zeros(E, bool).at[order_d].set(dup_sorted,
                                                  unique_indices=True)
         win = win & ~dup
@@ -623,10 +626,8 @@ def _pair_fields_facesort(mesh: Mesh, q_tet, capT):
     cand_full)."""
     from .adjacency import face_sort, bdy_tags_from_sort
     from .edges import scatter_argmax2
-    t, f, partner, matched, valid_s = face_sort(mesh)
+    t, f, tp, fp, matched, valid_s = face_sort(mesh)
     mesh = bdy_tags_from_sort(mesh, t, f, matched, valid_s)
-    tp = t[partner]
-    fp = f[partner]
     own_s = matched & (t < tp) & (mesh.ftag[t, f] == 0) & \
         (mesh.ftag[tp, fp] == 0)
     q2 = q_tet[tp]
@@ -741,9 +742,10 @@ def swap23_wave(mesh: Mesh, met: jax.Array,
     # edge (a,b) (a "lens" of two face-pairs between the same apexes)
     # would put four tets on each (x,a,b) face; keep the first per key
     from .edges import sort_pairs as _sp23
-    order_d, _, _, first_d = _sp23(jnp.minimum(a, b), jnp.maximum(a, b),
-                                   win, capP)
-    dup_sorted = win[order_d] & ~first_d
+    order_d, ka_d, _, first_d, _ = _sp23(
+        jnp.minimum(a, b), jnp.maximum(a, b), win, capP)
+    # a winner's sorted key is under INT32_MAX: no fetch of win
+    dup_sorted = (ka_d != jnp.iinfo(jnp.int32).max) & ~first_d
     win = win & ~jnp.zeros(F, bool).at[order_d].set(
         dup_sorted, unique_indices=True)
     # slot-reusing allocation from the free pool (edges.free_rows):

@@ -371,7 +371,8 @@ def incr_unique_edges(mesh: Mesh, topo: TopoState, incr,
     is a fifth smaller and runs the same steps).
     Returns (EdgeTable, new TopoState), and with ``told`` a third
     result: did the table come off the retained sort (merge or reuse)."""
-    from .edges import PACK_LIMIT, unique_edges, unique_edges_from_sorted
+    from .edges import (PACK_LIMIT, sort_carry, unique_edges,
+                        unique_edges_from_sorted)
     with otrace.scope("tab.edges"):
         capT = mesh.capT
         n6 = capT * 6
@@ -385,6 +386,7 @@ def incr_unique_edges(mesh: Mesh, topo: TopoState, incr,
         rungs = _rungs(band, capT)
         nd = jnp.sum(topo.edirty, dtype=jnp.int32)
         use_band = jnp.asarray(incr) & topo.eok & (nd <= rungs[-1])
+        etag6 = mesh.etag.reshape(n6)
 
         def _full(_):
             ev = tet_edge_vertices(mesh.tet).reshape(n6, 2)
@@ -392,8 +394,9 @@ def incr_unique_edges(mesh: Mesh, topo: TopoState, incr,
             b = jnp.maximum(ev[:, 0], ev[:, 1])
             valid = jnp.repeat(mesh.tmask, 6)
             key = jnp.where(valid, a * mesh.capP + b, _INT32_MAX)
-            order = jnp.argsort(key).astype(jnp.int32)
-            return key[order], order
+            # the full sort carries the tags; the state keeps none
+            order, (ks,), (tags,) = sort_carry((key,), (etag6,))
+            return ks, order, tags
 
         def _band(_):
             def _reuse(_):
@@ -413,12 +416,14 @@ def incr_unique_edges(mesh: Mesh, topo: TopoState, incr,
                         (topo.ekey,), topo.eslot, sd, (bkey,), bslot, rolled)
                     return ks, order
                 return _merge
-            return jax.lax.cond(nd == 0, _reuse,
-                                _narrowest(nd, rungs, _merge_at), None)
+            ks, order = jax.lax.cond(nd == 0, _reuse,
+                                     _narrowest(nd, rungs, _merge_at), None)
+            # a merged sort has no sort for the tags to ride in
+            return ks, order, etag6[order]
 
-        ks, order = jax.lax.cond(use_band, _band, _full, None)
+        ks, order, tags = jax.lax.cond(use_band, _band, _full, None)
         et = unique_edges_from_sorted(mesh, order, ks,
-                                      shell_slots=shell_slots)
+                                      shell_slots=shell_slots, tags=tags)
         topo = topo._replace(ekey=ks, eslot=order,
                              eok=jnp.ones((), bool),
                              edirty=jnp.zeros(capT, bool))
@@ -434,9 +439,10 @@ def incr_build_adjacency(mesh: Mesh, topo: TopoState, incr,
     SAME pairing epilogue).  Consumes ``fdirty``.  ``band``, ``told``,
     ``rolled``: as :func:`incr_unique_edges`.  Returns (mesh with adja/ftag, new
     TopoState[, off the retained sort?])."""
-    from .edges import PACK_LIMIT
+    from .edges import PACK_LIMIT, sort_carry
     from .adjacency import (_face_keys, adjacency_from_records,
-                            build_adjacency, face_records_from_sorted)
+                            build_adjacency, face_records_from_sorted,
+                            pack_minor)
     with otrace.scope("tab.adjacency"):
         capT = mesh.capT
         if mesh.capP > PACK_LIMIT:
@@ -449,12 +455,10 @@ def incr_build_adjacency(mesh: Mesh, topo: TopoState, incr,
         use_band = jnp.asarray(incr) & topo.fok & (nd <= rungs[-1])
 
         def _full(_):
-            cols, _, _ = _face_keys(mesh)
-            invalid = cols[:, 0] == _INT32_MAX
-            w = jnp.where(invalid, _INT32_MAX,
-                          cols[:, 1] * mesh.capP + cols[:, 2])
-            order = jnp.lexsort((w, cols[:, 0])).astype(jnp.int32)
-            return cols[order, 0], w[order], order
+            cols = _face_keys(mesh)
+            order, (k0, kw), _ = sort_carry(
+                (cols[:, 0], pack_minor(cols, mesh.capP)))
+            return k0, kw, order
 
         def _band(_):
             def _reuse(_):
@@ -475,9 +479,8 @@ def incr_build_adjacency(mesh: Mesh, topo: TopoState, incr,
                                 _narrowest(nd, rungs, _merge_at), None)
 
         k0, kw, order = jax.lax.cond(use_band, _band, _full, None)
-        t, f, partner, matched, valid_s = face_records_from_sorted(
-            mesh, order, k0, kw)
-        mesh = adjacency_from_records(mesh, t, f, partner, matched)
+        t, f, tp, fp, matched, _ = face_records_from_sorted(order, k0, kw)
+        mesh = adjacency_from_records(mesh, t, f, tp, fp, matched)
         topo = topo._replace(fk0=k0, fkw=kw, fslot=order,
                              fok=jnp.ones((), bool),
                              fdirty=jnp.zeros(capT, bool))

@@ -336,7 +336,7 @@ def _etag_rewrite(mesh: Mesh, rec: _Records, bits_rec):
     aa = jnp.concatenate([ka, don_a])
     bb = jnp.concatenate([kb, don_b])
     vvv = jnp.concatenate([alive6, don_v])
-    order_j, _, _, first_j = sort_pairs(aa, bb, vvv, capP)
+    order_j, _, _, first_j, _ = sort_pairs(aa, bb, vvv, capP)
     seg_j = jax.lax.associative_scan(
         jnp.maximum, jnp.where(first_j, jnp.arange(n_all), 0))
     dbits = jnp.where((order_j >= capT * 6) & vvv[order_j],

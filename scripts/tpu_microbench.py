@@ -17,7 +17,9 @@ Primitives measured at bench-like shapes (capT=73728, capE=6*capT):
 
 Sections (arguments; ``main`` alone by default): ``main`` the list above,
 ``payload`` the scatter's and the gather's cost by payload width,
-``gathers`` the two prices of a gather at the cells' own shapes (PR 42).
+``gathers`` the two prices of a gather at the cells' own shapes (PR 42),
+``perms`` a fetch or a scatter through a permutation the program has just
+sorted against the sort that carries the payload itself (PR 45).
 
 Run ON TPU (no JAX_PLATFORMS override):  python scripts/tpu_microbench.py
 Run on CPU for comparison:               JAX_PLATFORMS=cpu python ...
@@ -301,8 +303,194 @@ def gather_prices():
     case("tet_k4_pt", lambda i, x: tT[x], idx_pt, rows=T)
 
 
+PERM_REPS = int(os.environ.get("MB_PERM_REPS", "50"))
+# run only the perms cases whose name starts with this
+PERM_ONLY = os.environ.get("MB_PERM_ONLY", "")
+
+
+def perm_prices():
+    """What a table maker pays to read, in sorted order, what it has just
+    sorted (PR 45): ``argsort`` and a fetch through the permutation
+    against ONE ``lax.sort`` that carries the payload as an operand; a
+    sorted neighbour by ``x[partner]`` against two shifts; the way back
+    (``.at[order].set``) against a second sort keyed on the permutation;
+    the head scatter against a reverse segmented scan.  Widths are the
+    cells' own: 258,708 (6 x capT, the edge table), 172,472 (4 x capT,
+    the faces) and 64,680 (the collapse's band, 6 x 10,780).  Each case
+    is PERM_REPS repetitions chained through its input in one
+    ``fori_loop``; ``chain`` is the loop alone.
+
+    Read on the chip and on its host in PR 45 (PERF.md section 5, "a
+    permutation's two ways"): on the chip a payload operand costs the
+    sort 0.07 ms and the fetch 1.7-4.4, the way back by a sort 0.42 and
+    by the scatter 3.6; on XLA:CPU an operand costs the sort a quarter
+    and a fetch or a scatter next to nothing.  The chip needs about 11
+    minutes for the three widths at 30 repetitions."""
+    global K
+    K = PERM_REPS
+    jax.config.update("jax_enable_compilation_cache", False)
+    P, T = CELL_P, CELL_T
+    print(f"\nperm prices (backend={jax.default_backend()} capP={P} "
+          f"capT={T} reps={K})")
+    rng = np.random.default_rng(0)
+    i32, u32 = jnp.int32, jnp.uint32
+    big = np.iinfo(np.int32).max
+    sort = jax.lax.sort
+
+    def fold(*cols):
+        w = None
+        for c in cols:
+            c = c.astype(u32) if c.dtype != u32 else c
+            w = c if w is None else w ^ c
+        return w
+
+    def case(name, step, x):
+        """``step(x)`` returns the columns a formulation hands on; they
+        are folded into one word a row that feeds ``x`` back."""
+        if not name.startswith(PERM_ONLY):
+            return
+
+        def body(i, x):
+            w = fold(*step(x))
+            # 0xFFFFFFFF about never folds out: x stays what it was, and
+            # the compiler cannot know
+            return jnp.where(w == u32(0xFFFFFFFF), x + 1, x)
+        timed(name, loop(body), x)
+
+    def edge_keys(n):
+        """Packed edge keys as a cycle's table meets them: each key about
+        five times (a shell), a third of the slots dead (INT32_MAX)."""
+        k = rng.integers(0, P * P, n // 5)[rng.integers(0, n // 5, n)]
+        k = np.where(rng.random(n) < 0.33, big, k)
+        return jnp.asarray(k, i32)
+
+    for n in (6 * T, 4 * T, 64680):
+        key = edge_keys(n)
+        tag = jnp.asarray(rng.integers(0, 2 ** 16, n), u32)
+        tag2 = jnp.asarray(rng.integers(0, 2 ** 16, n), i32)
+        iota = jnp.arange(n, dtype=i32)
+        case(f"chain_{n}", lambda k: (k,), key)
+        case(f"argsort_{n}", lambda k: (jnp.argsort(k),), key)
+
+        def old1(k):
+            order = jnp.argsort(k)
+            return order, k[order]
+        case(f"argsort+1f_{n}", old1, key)
+
+        def old2(k):
+            order = jnp.argsort(k)
+            return order, k[order], tag[order]
+        case(f"argsort+2f_{n}", old2, key)
+
+        def old3(k):
+            order = jnp.argsort(k)
+            return order, k[order], tag[order], tag2[order]
+        case(f"argsort+3f_{n}", old3, key)
+        case(f"sort_ki_{n}", lambda k: sort(
+            (k, iota), num_keys=1, is_stable=True), key)
+        case(f"sort_kit_{n}", lambda k: sort(
+            (k, iota, tag), num_keys=1, is_stable=True), key)
+        case(f"sort_kitt_{n}", lambda k: sort(
+            (k, iota, tag, tag2), num_keys=1, is_stable=True), key)
+
+        # two key columns (the faces; the unpacked edge branch)
+        c0 = jnp.asarray(rng.integers(0, P, n), i32)
+
+        def lex_old(w):
+            order = jnp.lexsort((w, c0))
+            return order, c0[order], w[order]
+        case(f"lexsort+2f_{n}", lex_old, key)
+        case(f"sort2_{n}", lambda w: sort(
+            (c0, w, iota), num_keys=2, is_stable=True), key)
+
+        # a sorted neighbour: x[partner] against the shifts
+        first = np.ones(n, bool)
+        first[1:] = rng.random(n - 1) < 0.5
+        eq_next = jnp.asarray(~first[1:])
+        same_next = jnp.concatenate([eq_next, jnp.array([False])])
+        same_prev = jnp.concatenate([jnp.array([False]), eq_next])
+        partner = jnp.where(same_next, iota + 1,
+                            jnp.where(same_prev, iota - 1, iota))
+        f = jnp.asarray(rng.integers(0, 4, n), i32)
+
+        def twin_old(t):
+            return t[partner], f[partner]
+
+        def twin_new(t):
+            def nb(x):
+                up = jnp.concatenate([x[1:], x[-1:]])
+                dn = jnp.concatenate([x[:1], x[:-1]])
+                return jnp.where(same_next, up,
+                                 jnp.where(same_prev, dn, x))
+            return nb(t), nb(f)
+        case(f"twin_fetch_{n}", twin_old, key)
+        case(f"twin_shift_{n}", twin_new, key)
+
+        # the way back: a permutation scatter of two columns against the
+        # sort keyed on the permutation
+        order = jnp.asarray(rng.permutation(n), i32)
+
+        def back_old(p):
+            pay = jnp.stack([p, tag2], axis=1)
+            b = jnp.zeros((n, 2), i32).at[order].set(
+                pay, unique_indices=True)
+            return b[:, 0], b[:, 1]
+
+        def back_new(p):
+            _, b0, b1 = sort((order, p, tag2), num_keys=1)
+            return b0, b1
+
+        def back1_old(p):
+            return (jnp.zeros(n, i32).at[order].set(
+                p, unique_indices=True),)
+
+        def back1_new(p):
+            return (sort((order, p), num_keys=1)[1],)
+        case(f"back_scatter2_{n}", back_old, key)
+        case(f"back_sort2_{n}", back_new, key)
+        case(f"back_scatter1_{n}", back1_old, key)
+        case(f"back_sort1_{n}", back1_new, key)
+
+        # the head table: a segment's total (at its last member) written
+        # at its head, by a drop scatter against a reverse segmented scan
+        firstj = jnp.asarray(first)
+        is_last = jnp.concatenate([firstj[1:], jnp.array([True])])
+        seg_head = jax.lax.associative_scan(
+            jnp.maximum, jnp.where(firstj, iota, 0))
+
+        def head_old(v):
+            pay = jnp.stack([v, tag2], axis=1)
+            h = jnp.zeros((n, 2), i32).at[
+                jnp.where(is_last, seg_head, n)].set(
+                pay, mode="drop", unique_indices=True)
+            return h[:, 0], h[:, 1]
+
+        def head_new(v):
+            def comb(pa, pb):
+                # reverse scan: pa is the element further RIGHT
+                fa, va, wa = pa
+                fb, vb, wb = pb
+                return (fa | fb, jnp.where(fb, vb, va),
+                        jnp.where(fb, wb, wa))
+            _, h0, h1 = jax.lax.associative_scan(
+                comb, (is_last, v, tag2), reverse=True)
+            return jnp.where(firstj, h0, 0), jnp.where(firstj, h1, 0)
+        case(f"head_scatter_{n}", head_old, key)
+        case(f"head_rscan_{n}", head_new, key)
+
+        def fwd_scan3(v):
+            def comb(pa, pb):
+                fa, ha, va = pa
+                fb, hb, vb = pb
+                return (fa | fb, jnp.where(fb, hb, jnp.maximum(ha, hb)),
+                        jnp.where(fb, vb, va | vb))
+            return jax.lax.associative_scan(
+                comb, (firstj, jnp.where(firstj, iota, 0), v))[1:]
+        case(f"fwd_scan3_{n}", fwd_scan3, key)
+
+
 SECTIONS = {"main": main, "payload": payload_scaling,
-            "gathers": gather_prices}
+            "gathers": gather_prices, "perms": perm_prices}
 
 if __name__ == "__main__":
     for section in sys.argv[1:] or ["main"]:

@@ -121,12 +121,15 @@ def test_adapt_block_compiles_for_v5e(one_chip, no_cache, monkeypatch):
     from parmmg_tpu.parallel import groups
     from parmmg_tpu.parallel.distribute import split_to_shards
     from parmmg_tpu.utils.fixtures import cube_mesh
-    # code that asks jax.default_backend() sees the CPU here; the two
-    # such trace-time defaults on this path are steered to their tpu
-    # values: swap23 pairs off the face sort, and the surface scatters
-    # run over lists (ops/surflist)
+    # code that asks jax.default_backend() sees the CPU here; the
+    # trace-time defaults on this path are steered to their tpu values:
+    # swap23 pairs off the face sort, the surface scatters run over
+    # lists (ops/surflist), and the table makers' sorts carry their
+    # payloads (ops/edges.sort_carry)
+    from parmmg_tpu.utils import placement
     monkeypatch.setenv("PARMMG_SWAP_FACESORT", "1")
     monkeypatch.setattr(groups, "placed_on_tpu", lambda: True)
+    monkeypatch.setattr(placement, "placed_on_tpu", lambda: True)
     vert, tet = cube_mesh(2)
     m = make_mesh(vert, tet, capP=4 * len(vert), capT=4 * len(tet))
     m = analyze_mesh(m).mesh

@@ -266,6 +266,92 @@ def test_without_the_capacities_nothing_is_counted_as_a_scalar_gather(
     assert "scalar_gathers" not in handmade.counts
 
 
+# a fetch through a permutation, as the TPU's compiler writes it (a fusion
+# of a table and an index vector as long as the table) and as XLA:CPU does
+PERMS = '''HloModule jit_run, is_scheduled=true
+
+%fused_computation.1 (param_0.1: s32[258708,2], param_1.1: s32[258708,1]) -> s32[258708,2] {
+  %param_0.1 = s32[258708,2]{1,0:T(8,128)} parameter(0)
+  %param_1.1 = s32[258708,1]{0,1:T(1024)} parameter(1)
+  ROOT %gather.1 = s32[258708,2]{1,0:T(8,128)} gather(%param_0.1, %param_1.1), offset_dims={1}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1,2}
+}
+
+%fused_computation.2 (param_0.2: f32[8516,4], param_1.2: s32[258708,1]) -> f32[258708,4] {
+  %param_0.2 = f32[8516,4]{1,0:T(8,128)} parameter(0)
+  %param_1.2 = s32[258708,1]{0,1:T(1024)} parameter(1)
+  ROOT %gather.2 = f32[258708,4]{1,0:T(8,128)} gather(%param_0.2, %param_1.2), offset_dims={1}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1,4}
+}
+
+ENTRY %main.5 (keys: s32[258708,2], xyzh: f32[8516,4], order: s32[258708,1], t: s32[172472], partner: s32[172472,1], top: s32[5389], rank: s32[5389,1]) -> (s32[258708,2], f32[258708,4], s32[172472], s32[5389]) {
+  %keys = s32[258708,2]{1,0} parameter(0)
+  %xyzh = f32[8516,4]{1,0} parameter(1)
+  %order = s32[258708,1]{1,0} parameter(2)
+  %t = s32[172472]{0} parameter(3)
+  %partner = s32[172472,1]{1,0} parameter(4)
+  %top = s32[5389]{0} parameter(5)
+  %rank = s32[5389,1]{1,0} parameter(6)
+  %fusion.1 = s32[258708,2]{1,0} fusion(%keys, %order), kind=kCustom, calls=%fused_computation.1, metadata={op_name="jit(run)/while/body/cyc.table/tab.edges/gather"}
+  %fusion.2 = f32[258708,4]{1,0} fusion(%xyzh, %order), kind=kCustom, calls=%fused_computation.2, metadata={op_name="jit(run)/while/body/cyc.table/gather"}
+  %gather.3 = s32[172472]{0} gather(s32[172472]{0} %t, s32[172472,1]{1,0} %partner), offset_dims={}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1}, metadata={op_name="jit(run)/while/body/cyc.adjacency/tab.adjacency/gather"}
+  %gather.4 = s32[5389]{0} gather(s32[5389]{0} %top, s32[5389,1]{1,0} %rank), offset_dims={}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1}, metadata={op_name="jit(run)/while/body/cyc.swap23/gather"}
+  ROOT %out = (s32[258708,2]{1,0}, f32[258708,4]{1,0}, s32[172472]{0}, s32[5389]{0}) tuple(%fusion.1, %fusion.2, %gather.3, %gather.4)
+}
+'''
+
+
+def test_perm_gathers_are_the_tables_as_long_as_their_index():
+    """A fetch through a permutation counts: a table with as many rows
+    as the result, at a tet table's width or over, fused or bare, rows
+    of one word or of two.  A row out of ``[capP, 4]`` does not (the
+    cheap kind), nor a K-wide dedup's fetch."""
+    counts = devtime.map_from_text(PERMS, capP=8516, capT=43118).counts
+    assert counts["perm_gathers"] == 2
+    assert counts["perm_gathers_by_phase"] == {"cyc.table": 1,
+                                               "cyc.adjacency": 1}
+    assert counts["scalar_gathers"] == 0 and counts["ops"] == 4
+    # the other text's one: ``perm[ends]``, both 258,708 long; its
+    # ``perm[tets]`` is a table longer than its index
+    counts = devtime.map_from_text(GATHERS, capP=8516, capT=43118).counts
+    assert counts["perm_gathers_by_phase"] == {"cyc.table": 1}
+    for capT, left in ((5389, 3), (172472, 2), (172473, 1), (258709, 0)):
+        assert devtime.map_from_text(PERMS, 8516, capT).counts[
+            "perm_gathers"] == left
+
+
+# ``.at[order].set(rows)`` as the TPU's compiler writes it: a sort of the
+# indices, then a fusion of a fusion that fetches the rows through them
+NESTED = '''HloModule jit_run, is_scheduled=true
+
+%fused_computation.7.clone (param_0.7: s32[258708,2], param_1.7: s32[258708]) -> s32[258708,2] {
+  %param_0.7 = s32[258708,2]{1,0:T(8,128)} parameter(0)
+  %param_1.7 = s32[258708]{0:T(1024)} parameter(1)
+  ROOT %gather.7 = s32[258708,2]{1,0:T(8,128)} gather(%param_0.7, %param_1.7), offset_dims={1}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1,2}
+}
+
+%fused_computation.70 (param_0.70: s32[258708,2], param_1.70: s32[258708]) -> s32[258708,2] {
+  %param_0.70 = s32[258708,2]{1,0:T(8,128)} parameter(0)
+  %param_1.70 = s32[258708]{0:T(1024)} parameter(1)
+  ROOT %fusion.7 = s32[258708,2]{1,0:T(8,128)} fusion(%param_0.70, %param_1.70), kind=kLoop, calls=%fused_computation.7.clone
+}
+
+ENTRY %main.3 (rows: s32[258708,2], sorted: s32[258708]) -> s32[258708,2] {
+  %rows = s32[258708,2]{1,0} parameter(0)
+  %sorted = s32[258708]{0} parameter(1)
+  ROOT %fusion.70 = s32[258708,2]{1,0} fusion(%rows, %sorted), kind=kCustom, calls=%fused_computation.70, metadata={op_name="jit(run)/while/body/cyc.table/tab.edges/scatter"}
+}
+'''
+
+
+def test_a_permutation_scatters_fetch_inside_nested_fusions_counts():
+    counts = devtime.map_from_text(NESTED, capP=8516, capT=43118).counts
+    assert counts["perm_gathers_by_phase"] == {"cyc.table": 1}
+    assert counts["ops"] == 1 and counts["scalar_gathers"] == 0
+
+
+def test_without_the_capacities_nothing_is_counted_as_a_perm_gather():
+    assert "perm_gathers" not in devtime.map_from_text(PERMS).counts
+
+
 # ---------------------------------------------------------------------------
 # by_phase
 # ---------------------------------------------------------------------------

@@ -83,7 +83,7 @@ def test_sort_pairs_vs_numpy(rng, packed):
     a = rng.integers(0, 40, n).astype(np.int32)
     b = rng.integers(0, 40, n).astype(np.int32)
     valid = rng.random(n) < 0.8
-    order, ka, kb, first = sort_pairs(
+    order, ka, kb, first, _ = sort_pairs(
         jnp.asarray(a), jnp.asarray(b), jnp.asarray(valid),
         40 if packed else PACK_LIMIT + 1)
     aa = np.where(valid, a, I32_MAX)
