@@ -54,9 +54,9 @@ Q_REGULAR = 1.0 / (6.0 * 2.0 ** 0.5 * 6.0 ** 1.5)
 # CPU tests pin 2e-5 against the jnp formula in interpret mode)
 KERNEL_RTOL = 1e-3
 # Pallas kernels the iso grouped block dispatches on tpu (the quality
-# kernels sit in ops/quality.tet_quality, outside the block)
-KERNELS = ("edge_length_iso", "score_count", "score3_count",
-           "merge_prefix")
+# kernels sit in ops/quality.tet_quality, outside the block; the prefix
+# sum's only caller is the host tail's merge, ops/topo_incr)
+KERNELS = ("edge_length_iso", "score_count", "score3_count")
 
 
 def say(*a):
@@ -248,7 +248,6 @@ def group_block_kernels() -> dict:
     import jax
     import jax.numpy as jnp
     from parmmg_tpu.core.mesh import make_mesh
-    from parmmg_tpu.ops.topo_incr import topo_init
     from parmmg_tpu.parallel import groups
     from parmmg_tpu.parallel.distribute import split_to_shards
     from parmmg_tpu.utils.compilecache import LEDGER
@@ -262,8 +261,6 @@ def group_block_kernels() -> dict:
     stacked, met_s = split_to_shards(
         m, jnp.ones(m.capP, m.vert.dtype), np.zeros(len(tet), np.int32), 1)
     args = (stacked, met_s, jnp.int32(0), jnp.ones(1, bool),
-            jnp.asarray(False),
-            topo_init(stacked.tet.shape[1], stack=1),
             jnp.asarray(True), jnp.asarray(True))
     leaves, treedef = jax.tree_util.tree_flatten(args)
     key = LEDGER._entries["groups.adapt_block"].last_key

@@ -117,20 +117,6 @@ KNOBS: dict[str, Knob] = {
         "flag", "",
         "1 = skip the device analysis-refresh path and always use the "
         "host fallback"),
-    "PARMMG_INCR_BAND": Knob(
-        "int", "",
-        "override the incremental-topology dirty-band width in tets "
-        "(ops/topo_incr.incr_band_width; tests/tuning); empty = one "
-        "geo-ladder rung of capT//16, floor 1024"),
-    "PARMMG_INCR_TOPO": Knob(
-        "flag", "",
-        "incremental topology maintenance: merge each wave's dirty-tet "
-        "band into the retained sorted edge/face tables instead of "
-        "re-sorting all 6*capT/4*capT slot keys per derivation "
-        "(ops/topo_incr.py; overflow lax.cond-falls back to the full "
-        "rebuild, bit-identical by the stable-sort merge proof); "
-        "threaded as a traced scalar so toggling mints zero compile "
-        "families; 0/unset = legacy full rebuilds"),
     "PARMMG_MH_CACHE_DIR": Knob(
         "path", "",
         "shared persistent compile-cache dir for multi-host pod "
@@ -251,8 +237,10 @@ KNOBS: dict[str, Knob] = {
         "pair swap23 candidates directly off the face-sort records, "
         "skipping the cycle-interior build_adjacency rebuild "
         "(ops/swap.py); bit-identical pairing by the argmin/argmax2 "
-        "tie-break equivalence; unset = on for TPU, off elsewhere "
-        "(the CPU sort costs more than the rebuild it replaces); "
+        "tie-break equivalence; unset = on where the program is placed "
+        "on a TPU, off elsewhere, so off for the tail a TPU process "
+        "stages on its host (the CPU sort costs more than the rebuild "
+        "it replaces); "
         "1/0 force either path on any backend"),
     "PARMMG_TEST_CACHE": Knob(
         "flag", "",

@@ -34,12 +34,12 @@ from .swap import swap_edges_wave, swap23_wave
 from .smooth import smooth_wave
 
 
-# columns of a cycle's counts row that say what the surface machinery
-# did (adapt_cycle_impl), the column the incremental topology engine
-# appends after them, and the one that holds the live updates the cycle's
-# surface lists held (ops/surflist; 0 where the scatters ran full width)
+# a cycle's counts row (adapt_cycle_impl): its width, the columns that
+# say what the surface machinery did, and the one that holds the live
+# updates the cycle's surface lists held (ops/surflist; 0 where the
+# scatters ran full width)
+CYCLE_COLS = 11
 SURF_COLS = {"bsplit": 8, "hveto": 9, "bmoved": 10}
-DIRTY_COL = 11
 LISTED_COL = 7
 
 
@@ -150,7 +150,7 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
                      hausd: float | None = None,
                      budget_div: int = 8,
                      prescreen: bool = True, active=None,
-                     topo=None, incr=None, surf_list: bool | None = None):
+                     surf_list: bool | None = None):
     """One adaptation cycle: split -> collapse -> [swap] -> [smooth].
 
     Pure jittable function (jitted wrapper below) — also the compile-check
@@ -183,9 +183,9 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     ``deferred`` = top-K budget cuts of viable candidates, encoded as
     2 bits: bit 0 = an INSERTION wave (split/collapse) deferred,
     bit 1 = a SWAP wave deferred; it has no reader (ROADMAP D4), and
-    the row keeps its layout because ``SURF_COLS``, ``LISTED_COL`` and
-    ``DIRTY_COL`` index it.  ``listed`` (``LISTED_COL``): the live
-    updates the cycle's surface lists held.
+    the row keeps its layout because ``SURF_COLS`` and ``LISTED_COL``
+    index it.  ``listed`` (``LISTED_COL``): the live updates the cycle's
+    surface lists held.
 
     ``surf_list``: whether the surface scatters (vertex normals, ridge
     tangents, boundary tags, the smoother's surface sums and second
@@ -204,56 +204,27 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
     a zero-op state is byte-identity, so returning the input IS the
     recompute).  ``active=None`` compiles the unconditional body — the
     whole-mesh path is untouched.
-
-    ``topo``/``incr``: the incremental topology engine (ops/topo_incr).
-    ``topo`` is a TopoState carrying the retained edge/face sorts and
-    dirty masks across cycles; ``incr`` the traced PARMMG_INCR_TOPO
-    scalar.  When threaded, the cycle derives its edge table and
-    adjacency through the band-merge path (bit-identical to the legacy
-    rebuilds — off position, overflow and cold state all take the exact
-    full sort), marks the tets each wave touched (unconditionally, so
-    both knob arms report identical counts), the counts row gains a
-    column (``counts[DIRTY_COL]`` = dirty tets at cycle start), and the return becomes
-    a 4-tuple ``(mesh, met, counts, topo)``.  ``topo=None`` is the
-    untouched legacy path (no dirty column, 3-tuple).
     """
     from .adjacency import boundary_edge_tags
-    if topo is not None:
-        from .topo_incr import (incr_unique_edges, incr_build_adjacency,
-                                mark_dirty)
-        if incr is None:
-            incr = jnp.zeros((), bool)
     if active is not None:
         def _run(ops):
-            m, k, tp = ops
-            out = adapt_cycle_impl(
+            m, k = ops
+            return adapt_cycle_impl(
                 m, k, wave, do_swap=do_swap, do_smooth=do_smooth,
                 smooth_waves=smooth_waves, do_insert=do_insert,
                 hausd=hausd, budget_div=budget_div,
-                prescreen=prescreen, topo=tp, incr=incr,
-                surf_list=surf_list)
-            return out if tp is not None else out + (tp,)
+                prescreen=prescreen, surf_list=surf_list)
 
         def _skip(ops):
-            m, k, tp = ops
-            nc = DIRTY_COL if tp is None else DIRTY_COL + 1
-            counts = jnp.zeros(nc, jnp.int32).at[5].set(
+            m, k = ops
+            counts = jnp.zeros(CYCLE_COLS, jnp.int32).at[5].set(
                 jnp.sum(m.tmask, dtype=jnp.int32))
-            if tp is not None:
-                # an idle slot's retained tables stay valid; report its
-                # pending dirty count for the occupancy trajectory
-                counts = counts.at[DIRTY_COL].set(
-                    jnp.sum(tp.edirty, dtype=jnp.int32))
-            return m, k, counts, tp
-        m, k, counts, tp = jax.lax.cond(active, _run, _skip,
-                                        (mesh, met, topo))
-        return (m, k, counts) if topo is None else (m, k, counts, tp)
+            return m, k, counts
+        return jax.lax.cond(active, _run, _skip, (mesh, met))
     from . import surflist
     lists = surflist.Tally(surf_list)
     defer = jnp.zeros((), bool)
     defer_sw = jnp.zeros((), bool)
-    nd0 = (jnp.zeros((), jnp.int32) if topo is None
-           else jnp.sum(topo.edirty, dtype=jnp.int32))
     if do_insert:
         # ONE edge table + metric lengths serve both split and collapse
         # (the tables are a measured wave hot spot); the collapse defers
@@ -262,11 +233,7 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
         # slim table: split/collapse never read shell3 (only the swap
         # kernels, which build their own) — skips a [6*capT] scatter
         with otrace.scope("cyc.table"):
-            if topo is not None:
-                et, topo = incr_unique_edges(mesh, topo, incr,
-                                             shell_slots=0)
-            else:
-                et = unique_edges(mesh, shell_slots=0)
+            et = unique_edges(mesh, shell_slots=0)
             lens = edge_lengths(mesh, et, met)
         # ridge tangents once per cycle too (same sharing rationale;
         # collapse only consults non-stale candidates, whose tangent
@@ -290,8 +257,6 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
                              budget_div=budget_div,
                              et=et, lens=lens, vtan=vtan0, vn=vn0,
                              prescreen=prescreen)
-            if topo is not None:
-                topo = mark_dirty(topo, mesh.tet, mesh.tmask, res.mesh)
         mesh, met = res.mesh, res.met
         nsplit, overflow = res.nsplit, res.overflow
         nbsplit = res.nbdy
@@ -303,11 +268,6 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
                                 et=et, lens=lens,
                                 stale_tets=res.modified, vtan=vtan0,
                                 vn=vn0)
-            if topo is not None:
-                # boundary_edge_tags below touches only tags, which the
-                # retained sorts never carry — marking against col.mesh
-                # is exact (ops/topo_incr module docstring)
-                topo = mark_dirty(topo, mesh.tet, mesh.tmask, col.mesh)
         defer = defer | col.deferred
         # collapse rewires the surface (dying tets' face tags transfer to
         # the surviving neighbors); re-propagate MG_BDY from faces to
@@ -337,49 +297,35 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
 
     nswap = jnp.zeros((), jnp.int32)
 
-    def _swap(ops):
-        mesh, topo = ops
+    def _swap(mesh):
         from .swap import swap_facesort_enabled
         with otrace.scope("cyc.swap_edges"):
             sew = swap_edges_wave(mesh, met, hausd=hausd,
                                   budget_div=budget_div)  # 3-2 + 2-2
-            if topo is not None:
-                topo = mark_dirty(topo, mesh.tet, mesh.tmask, sew.mesh)
         with otrace.scope("cyc.swap23"):
             if swap_facesort_enabled():
                 # swap23 pairs directly off the face sort (bit-identical
                 # to the adja path — ops/swap._pair_fields_facesort); the
                 # [capT,4] adja materialization + compare leaves the
                 # cycle interior, the rebuild at the end restores the
-                # adja contract.  (This mid-cycle face sort is NOT
-                # band-maintained — scope cut: the facesort swap23
-                # derives its pairing internally.)
+                # adja contract
                 s23 = swap23_wave(sew.mesh, met, budget_div=budget_div,
                                   facesort=True)
-                pre = sew.mesh
             else:
                 # consumed by swap23
-                if topo is not None:
-                    mesh, topo = incr_build_adjacency(sew.mesh, topo,
-                                                      incr)
-                else:
-                    mesh = build_adjacency(sew.mesh)
+                mesh = build_adjacency(sew.mesh)
                 s23 = swap23_wave(mesh, met, budget_div=budget_div)
-                pre = mesh
-            if topo is not None:
-                topo = mark_dirty(topo, pre.tet, pre.tmask, s23.mesh)
-        return (s23.mesh, topo, sew.nswap + s23.nswap,
+        return (s23.mesh, sew.nswap + s23.nswap,
                 sew.deferred | s23.deferred)
 
     if isinstance(do_swap, (bool, np.bool_)):
         if do_swap:
-            mesh, topo, nswap, defer_sw = _swap((mesh, topo))
+            mesh, nswap, defer_sw = _swap(mesh)
     else:
         # traced switch: the swap arm sits in the one compiled program
         # and a cycle that is not swap-inclusive skips it at run time
-        mesh, topo, nswap, defer_sw = jax.lax.cond(
-            do_swap, _swap, lambda ops: ops + (nswap, defer_sw),
-            (mesh, topo))
+        mesh, nswap, defer_sw = jax.lax.cond(
+            do_swap, _swap, lambda m: (m, nswap, defer_sw), mesh)
 
     nmoved = jnp.zeros((2,), jnp.int32)      # [all, of them surface]
     if do_smooth:
@@ -391,22 +337,15 @@ def adapt_cycle_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
             nmoved = nmoved + jnp.stack([sm.nmoved, sm.nbdy])
 
     with otrace.scope("cyc.adjacency"):
-        if topo is not None:
-            mesh, topo = incr_build_adjacency(mesh, topo, incr)
-        else:
-            mesh = build_adjacency(mesh)
+        mesh = build_adjacency(mesh)
 
-    row = [nsplit, ncol, nswap, nmoved[0],
-           overflow.astype(jnp.int32),
-           jnp.sum(mesh.tmask, dtype=jnp.int32),
-           defer.astype(jnp.int32) + 2 * defer_sw.astype(jnp.int32),
-           jnp.asarray(lists.listed, jnp.int32),
-           nbsplit, nhveto, nmoved[1]]
-    if topo is None:
-        return mesh, met, jnp.stack(row)
-    # counts[DIRTY_COL]: dirty tets pending at cycle START — the dirty-band
-    # occupancy trajectory the grouped drivers surface in sched_extra
-    return mesh, met, jnp.stack(row + [nd0]), topo
+    return mesh, met, jnp.stack([
+        nsplit, ncol, nswap, nmoved[0],
+        overflow.astype(jnp.int32),
+        jnp.sum(mesh.tmask, dtype=jnp.int32),
+        defer.astype(jnp.int32) + 2 * defer_sw.astype(jnp.int32),
+        jnp.asarray(lists.listed, jnp.int32),
+        nbsplit, nhveto, nmoved[1]])
 
 
 from ..utils.compilecache import governed as _governed  # noqa: E402
@@ -435,7 +374,8 @@ def fem_pass_impl(mesh: Mesh, met: jax.Array, topo=None):
     full sort where nothing is retained or the rows outnumber the widest
     band.  Bit-identical either way (that module's docstring).  Without
     a state the round sorts both in full: the whole-mesh path's, which
-    runs on the device, where the retained sort loses (ROADMAP D1).
+    runs on the device, where the retained sort loses (PERF.md section 6,
+    PR 38).
 
     Returns (mesh, met, counts[3] = [nsplit, overflow, bsplit]); the
     candidates are interior edges, so ``bsplit`` (splits of boundary
@@ -445,13 +385,13 @@ def fem_pass_impl(mesh: Mesh, met: jax.Array, topo=None):
     from .adjacency import boundary_edge_tags
     et = None           # the split wave then builds its own
     if topo is not None:
-        from .topo_incr import (mark_dirty, polish_bands,
-                                polish_build_adjacency, polish_unique_edges)
+        from .topo_incr import (incr_build_adjacency, incr_unique_edges,
+                                mark_dirty, polish_bands)
         band = polish_bands(mesh.capT)
     with otrace.scope("fem.split"):
         if topo is not None:
             # shell_slots: what split_wave's own ``unique_edges`` asks for
-            et, topo, emerged = polish_unique_edges(
+            et, topo, emerged = incr_unique_edges(
                 mesh, topo, shell_slots=3, band=band)
         res = split_wave(mesh, met, fem_only=True, budget_div=2, et=et)
         if topo is not None:
@@ -462,8 +402,8 @@ def fem_pass_impl(mesh: Mesh, met: jax.Array, topo=None):
         if topo is None:
             mesh = build_adjacency(mesh)
         else:
-            mesh, topo, fmerged = polish_build_adjacency(mesh, topo,
-                                                         band=band)
+            mesh, topo, fmerged = incr_build_adjacency(mesh, topo,
+                                                       band=band)
     row = [res.nsplit, res.overflow.astype(jnp.int32), res.nbdy]
     if topo is None:
         return mesh, res.met, jnp.stack(row)
@@ -585,20 +525,20 @@ def sliver_polish_impl(mesh: Mesh, met: jax.Array, wave: jax.Array,
         def dirtied(tp, before, after):
             return tp
     else:
-        from .topo_incr import (mark_dirty, polish_bands,
-                                polish_build_adjacency, polish_unique_edges)
+        from .topo_incr import (incr_build_adjacency, incr_unique_edges,
+                                mark_dirty, polish_bands)
         band = polish_bands(mesh.capT)
 
         def derived(tables, merged):
             return tables + jnp.stack([1, merged.astype(jnp.int32)])
 
         def edge_table(m, tp, tables, slots):
-            et, tp, merged = polish_unique_edges(m, tp, shell_slots=slots,
-                                                 band=band)
+            et, tp, merged = incr_unique_edges(m, tp, shell_slots=slots,
+                                               band=band)
             return et, tp, derived(tables, merged)
 
         def adjacency(m, tp, tables):
-            m, tp, merged = polish_build_adjacency(m, tp, band=band)
+            m, tp, merged = incr_build_adjacency(m, tp, band=band)
             return m, tp, derived(tables, merged)
 
         def dirtied(tp, before, after):

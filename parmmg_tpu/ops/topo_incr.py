@@ -1,27 +1,28 @@
-"""Incremental topology maintenance: dirty-band edge-table / adjacency.
+"""The merged tail's table engine: edge table and adjacency off retained sorts.
 
 The sort-based topology primitives (ops/edges.unique_edges,
-ops/adjacency.build_adjacency) re-sort ALL 6*capT / 4*capT slot keys
-every cycle even when a wave commits ~30 winners — the decay regime every
-long-running adaptation ends in (BENCH_r05: ~590 ms of a ~1.2 s cycle).
-The reference never does this: Mmg maintains its edge/tetra hash tables
-incrementally across operator applications (MMG3D_hashTetra,
-hash_pmmg.c).  This module is the sort-idiom analogue:
+ops/adjacency.build_adjacency) sort ALL 6*capT / 4*capT slot keys at
+every derivation, also when the stages since the last one rewrote a few
+hundred rows of a merged mesh: the regime the host tail (the merged
+polish, then the fem rounds) lives in.  The reference never does this:
+Mmg maintains its edge/tetra hash tables incrementally across operator
+applications (MMG3D_hashTetra, hash_pmmg.c).  This module is the
+sort-idiom analogue:
 
-* each wave's *dirty tet set* (rows it created, killed or re-verticed) is
+* the *dirty tet set* (rows created, killed or re-verticed) is
   accumulated as a [capT] bool mask — exact by construction, computed as
-  an elementwise diff of (tet, tmask) across the wave, the ONLY inputs
-  the slot keys depend on;
+  an elementwise diff of (tet, tmask) across a stage (``mark_dirty``),
+  the ONLY inputs the slot keys depend on;
 * at the next table derivation the dirty tets' slots are re-keyed into a
-  fixed-width band (``incr_band_width`` — one ``compilecache.bucket``
-  geo-ladder rung per capT, so band handling mints zero compile
-  families) and merged into the RETAINED sorted key table:
+  fixed-width band (the caller's, a function of the capacity alone:
+  ``polish_bands``) and merged into the RETAINED sorted key table:
   survivors compact by rank (prefix sum), band entries binary-search
   their insertion position (lexicographic lower bound over the dense
   survivor table), and ONE packed scatter materializes the merged order
   — O(T log B) instead of the O(12T log 12T) full sort;
-* overflow (more dirty tets than the band) ``lax.cond``-falls back to
-  the full rebuild, so exactness is by construction, never sampled.
+* no dirty tet at all hands the retained sort back as it is; more dirty
+  tets than the widest band, or no retained sort yet, ``lax.cond``-fall
+  back to the full sort, so exactness is by construction, never sampled.
 
 Exactness argument (the bit-parity proof the tests pin):
 ``jnp.argsort``/``jnp.lexsort`` are STABLE, so the full sort's order is
@@ -34,28 +35,32 @@ and merges them under the SAME (key..., slot) lexicographic order; slot
 indices are unique, so the merged permutation is the unique sorted
 order, i.e. bit-identical to a fresh stable sort.  Tag payloads (etag)
 are NOT retained — the shared epilogue re-gathers them from the current
-mesh, so mid-cycle tag updates (boundary_edge_tags) need no dirty marks.
+mesh, so tag updates between two derivations (boundary_edge_tags) need
+no dirty marks.
 
-The per-slot state (``TopoState``) rides the grouped paths' group axis
-and the serve pool's slot axis; the knob (``PARMMG_INCR_TOPO``) is a
-TRACED scalar everywhere, so toggling it mints zero new compile
-families (the hotloop_knob_gate contract).  The prefix-sum backbone of
-the merge lowers to a Pallas kernel on TPU
-(ops/pallas_kernels.merge_prefix_pallas, 8x128-tiled, SMEM carry); the
-CPU reference is ``jnp.cumsum`` — integer adds, bit-identical.
+Who carries it: the merged tail, on the host, threads one ``TopoState``
+through all its consumers of whole-mesh tables with no knob: the merged
+polish from wave to wave (driver._merged_polish ->
+ops/adapt.sliver_polish_impl), which hands the state it ends with to
+driver._finish_run, whose fem rounds (ops/adapt.fem_pass_impl) derive
+their edge table and adjacency off it and hand it from round to round.
+A derivation merges at the narrowest of the caller's bands that holds
+its dirty set and says whether its table came off the retained sort.
+Whatever rewrites rows between two consumers keeps the state true or
+drops it: the numpy repair is diffed like a stage (``mark_dirty``), a
+regrow permutes the rows and changes the capacity, so the state starts
+again (``topo_init``).
 
-The merged tail (on the host) carries one ``TopoState`` through all its
-consumers of whole-mesh tables with no knob: the merged polish from wave
-to wave (driver._merged_polish -> ops/adapt.sliver_polish_impl), which
-hands the state it ends with to driver._finish_run, whose fem rounds
-(ops/adapt.fem_pass_impl) derive their edge table and adjacency off it
-and hand it from round to round.  There ``incr`` is a constant true, the
-band has two rungs (``polish_bands``: a derivation merges at the
-narrowest that holds its dirty set) and ``told`` says which tables came
-off the retained sort.  Whatever rewrites rows between two consumers
-keeps the state true or drops it: the numpy repair is diffed like a
-stage (``mark_dirty``), a regrow permutes the rows and changes the
-capacity, so the state starts again (``topo_init``).
+Who does not: the cycle blocks (parallel/groups, parallel/dist) sort
+their tables in full.  The chip read both arms in the grouped block
+(PERF.md section 6, PR 38): with the merge ``block_device_ms`` 303.5 ->
+338.8 (iso) and 308.8 -> 348.9 (aniso), the late quiet blocks it was
+built for 0.238 -> 0.282 s; PR 46 took that arm out.
+
+The prefix-sum backbone of the merge is ``jnp.cumsum`` — integer adds —
+and where the Pallas kernels are on lowers on a TPU to
+ops/pallas_kernels.merge_prefix_pallas (8x128-tiled, SMEM carry),
+bit-identical.
 """
 from __future__ import annotations
 
@@ -69,29 +74,6 @@ from ..core.mesh import Mesh, tet_edge_vertices, tet_face_vertices
 from ..obs import trace as otrace
 
 _INT32_MAX = 2147483647
-
-
-def incr_topo_enabled() -> bool:
-    """PARMMG_INCR_TOPO=1 enables the incremental maintenance path
-    (default off: the exact legacy full-rebuild path).  Read per pass
-    and threaded as a traced scalar — same compiled programs either
-    way."""
-    import os
-    return os.environ.get("PARMMG_INCR_TOPO", "0") == "1"
-
-
-def incr_band_width(capT: int) -> int:
-    """Dirty-band width in TETS for a given capacity: one
-    ``compilecache.bucket`` geo-ladder rung of capT//16 (floor 1024,
-    capped at capT), so every capT maps to ONE static band shape — band
-    sizing can never mint a new compile family.  PARMMG_INCR_BAND
-    overrides (tests / tuning)."""
-    import os
-    v = os.environ.get("PARMMG_INCR_BAND", "")
-    if v:
-        return max(1, min(int(v), capT))
-    from ..utils.compilecache import bucket
-    return bucket(max(1, capT // 16), floor=1024, scheme="geo", cap=capT)
 
 
 def polish_bands(capT: int) -> tuple[int, ...]:
@@ -113,10 +95,10 @@ class TopoState(NamedTuple):
     permutation = original slot ids) retained from the last edge-table
     derivation; ``fk0``/``fkw``/``fslot`` the same for the 2-column face
     sort.  ``eok``/``fok`` gate reuse (False = no retained table — full
-    rebuild regardless of the knob).  ``edirty``/``fdirty`` accumulate
-    the tets touched since the LAST derivation of each table (the edge
-    and face tables are consumed at different points of a cycle, so the
-    masks reset independently)."""
+    rebuild).  ``edirty``/``fdirty`` accumulate the tets touched since
+    the LAST derivation of each table (the edge and face tables are
+    consumed at different points of a wave, so the masks reset
+    independently)."""
     ekey: jax.Array     # [6*capT] int32 sorted packed edge keys
     eslot: jax.Array    # [6*capT] int32 edge sort permutation
     eok: jax.Array      # [] bool
@@ -128,35 +110,14 @@ class TopoState(NamedTuple):
     fdirty: jax.Array   # [capT] bool
 
 
-def topo_init(capT: int, stack: int | None = None) -> TopoState:
-    """All-zeros state (ok=False: first derivation is a full rebuild).
-    ``stack`` prepends a group axis (the lax.map layout)."""
-    def z(shape, dt):
-        s = shape if stack is None else (stack,) + shape
-        return jnp.zeros(s, dt)
+def topo_init(capT: int) -> TopoState:
+    """All-zeros state (ok=False: first derivation is a full rebuild)."""
+    z = jnp.zeros
     return TopoState(
         ekey=z((6 * capT,), jnp.int32), eslot=z((6 * capT,), jnp.int32),
         eok=z((), bool), edirty=z((capT,), bool),
         fk0=z((4 * capT,), jnp.int32), fkw=z((4 * capT,), jnp.int32),
         fslot=z((4 * capT,), jnp.int32), fok=z((), bool),
-        fdirty=z((capT,), bool))
-
-
-def topo_init_np(nslots: int, capT: int) -> TopoState:
-    """Host-numpy stacked state [nslots, ...] for the chunked grouped
-    path and the serve pool (mutated in place by drain writebacks —
-    the idempotent-writeback contract covers it: rows only change when
-    a chunk's drain commits, so a faulted dispatch replays from the
-    retained table bit-for-bit)."""
-    import numpy as np
-
-    def z(shape, dt):
-        return np.zeros((nslots,) + shape, dt)
-    return TopoState(
-        ekey=z((6 * capT,), np.int32), eslot=z((6 * capT,), np.int32),
-        eok=z((), bool), edirty=z((capT,), bool),
-        fk0=z((4 * capT,), np.int32), fkw=z((4 * capT,), np.int32),
-        fslot=z((4 * capT,), np.int32), fok=z((), bool),
         fdirty=z((capT,), bool))
 
 
@@ -197,13 +158,13 @@ def _prefix_i32(x: jax.Array) -> jax.Array:
     return ref(x)
 
 
-def _lower_bound(qkeys, qslot, keys, slot, rolled: bool = False):
+def _lower_bound(qkeys, qslot, keys, slot):
     """Lexicographic lower bound of each (qkeys..., qslot) query in the
     dense ascending (keys..., slot) table: the first index whose entry
-    compares >= the query.  Static ``bit_length`` iteration count —
-    O(log n) gathers per query, no data-dependent control flow.
-    ``rolled`` runs the iterations as a ``fori_loop`` where the blocks
-    unroll them: the same steps, a program a step long."""
+    compares >= the query.  Static ``bit_length`` iteration count, run
+    as a ``fori_loop`` (a program a step long: the polish holds ten
+    merges) — O(log n) gathers per query, no data-dependent control
+    flow."""
     n = slot.shape[0]
 
     def step(_, lohi):
@@ -222,12 +183,8 @@ def _lower_bound(qkeys, qslot, keys, slot, rolled: bool = False):
 
     lohi = (jnp.zeros(qslot.shape, jnp.int32),
             jnp.full(qslot.shape, n, jnp.int32))
-    steps = max(1, int(n).bit_length())
-    if rolled:
-        return jax.lax.fori_loop(0, steps, step, lohi)[0]
-    for i in range(steps):
-        lohi = step(i, lohi)
-    return lohi[0]
+    return jax.lax.fori_loop(0, max(1, int(n).bit_length()), step,
+                             lohi)[0]
 
 
 def band_order(bkeys, bslot):
@@ -237,9 +194,8 @@ def band_order(bkeys, bslot):
     return jnp.lexsort((bslot,) + tuple(bkeys)[::-1])
 
 
-def merge_sorted_band(keys, slot, sd, bkeys, bslot, rolled: bool = False):
-    """Merge a re-keyed dirty band into a retained stable sort
-    (``rolled``: :func:`_lower_bound`'s).
+def merge_sorted_band(keys, slot, sd, bkeys, bslot):
+    """Merge a re-keyed dirty band into a retained stable sort.
 
     ``keys`` (tuple of [n] int32 columns) + ``slot`` [n] are the
     retained sorted table (ascending by (keys..., slot) — what a stable
@@ -274,7 +230,7 @@ def merge_sorted_band(keys, slot, sd, bkeys, bslot, rolled: bool = False):
     border = band_order(bkeys, bslot)
     bks = [bk[border] for bk in bkeys]
     bs = bslot[border]
-    pos = _lower_bound(bks, bs, skeys, sslot, rolled)         # [m]
+    pos = _lower_bound(bks, bs, skeys, sslot)                 # [m]
     # survivor shift = inclusive prefix of the insertion histogram
     # (pad entries are parked at bin n and excluded from the prefix)
     real = bs != _INT32_MAX
@@ -335,14 +291,6 @@ def face_band_records(mesh: Mesh, dt: jax.Array):
 # table derivations (band-merged or full, one lax.cond each)
 # ---------------------------------------------------------------------------
 
-def _rungs(band, capT: int) -> tuple[int, ...]:
-    """The band widths a derivation may merge at, ascending: the
-    caller's (one width or several), else ``incr_band_width``."""
-    if band is None:
-        return (incr_band_width(capT),)
-    return (band,) if isinstance(band, int) else tuple(band)
-
-
 def _narrowest(nd, rungs, merge_at):
     """The merge at the narrowest rung that holds ``nd`` dirty tets
     (``nd`` <= the widest: the caller's gate).  One rung is that merge
@@ -354,23 +302,21 @@ def _narrowest(nd, rungs, merge_at):
         which, [merge_at(b) for b in rungs], None)
 
 
-def incr_unique_edges(mesh: Mesh, topo: TopoState, incr,
-                      shell_slots: int = 0,
-                      band: int | tuple[int, ...] | None = None,
-                      told: bool = False, rolled: bool = False):
-    """EdgeTable via the retained sort: band-merge when the knob is on,
-    the state is valid and the dirty set fits the band; otherwise the
-    full packed sort (bit-identical to ops/edges.unique_edges either
-    way — both feed the SAME shared epilogue).  Consumes ``edirty``.
-    ``band``: the band's width in tets where the caller sizes it, or
-    several ascending widths, of which a derivation merges at the
-    narrowest that holds its dirty set (the merged polish:
-    ``polish_bands``); None is ``incr_band_width``.  ``rolled``: the
-    merge's binary search as a loop, not unrolled (a host program that
-    every process compiles in its set-up holds ten merges: rolled it
-    is a fifth smaller and runs the same steps).
-    Returns (EdgeTable, new TopoState), and with ``told`` a third
-    result: did the table come off the retained sort (merge or reuse)."""
+# A jitted function a kind of table, because a polish wave derives an
+# edge table in three places and an adjacency in two, and then traces,
+# lowers and holds each kind once
+@partial(jax.jit, static_argnames=("shell_slots", "band"))
+def incr_unique_edges(mesh: Mesh, topo: TopoState, *,
+                      shell_slots: int = 0, band: tuple[int, ...]):
+    """EdgeTable via the retained sort: band-merge when the state is
+    valid and the dirty set fits the band; otherwise the full packed
+    sort (bit-identical to ops/edges.unique_edges either way — both
+    feed the SAME shared epilogue).  Consumes ``edirty``.
+    ``band``: the band's widths in tets, ascending, of which a
+    derivation merges at the narrowest that holds its dirty set
+    (``polish_bands``).
+    Returns (EdgeTable, new TopoState, did the table come off the
+    retained sort: merge or reuse)."""
     from .edges import (PACK_LIMIT, sort_carry, unique_edges,
                         unique_edges_from_sorted)
     with otrace.scope("tab.edges"):
@@ -382,10 +328,9 @@ def incr_unique_edges(mesh: Mesh, topo: TopoState, incr,
             et = unique_edges(mesh, shell_slots=shell_slots)
             topo = topo._replace(eok=jnp.zeros((), bool),
                                  edirty=jnp.zeros(capT, bool))
-            return (et, topo, jnp.zeros((), bool)) if told else (et, topo)
-        rungs = _rungs(band, capT)
+            return et, topo, jnp.zeros((), bool)
         nd = jnp.sum(topo.edirty, dtype=jnp.int32)
-        use_band = jnp.asarray(incr) & topo.eok & (nd <= rungs[-1])
+        use_band = topo.eok & (nd <= band[-1])
         etag6 = mesh.etag.reshape(n6)
 
         def _full(_):
@@ -402,8 +347,7 @@ def incr_unique_edges(mesh: Mesh, topo: TopoState, incr,
             def _reuse(_):
                 # zero dirty tets since the last derivation: the retained
                 # sort IS the fresh sort (keys depend only on tet/tmask) —
-                # the decay-regime steady state, and the generalization of
-                # the old all-or-nothing et-cache to adjacency too
+                # the round that finds no candidate, the late quiet wave
                 return topo.ekey, topo.eslot
 
             def _merge_at(B):
@@ -413,11 +357,11 @@ def incr_unique_edges(mesh: Mesh, topo: TopoState, incr,
                                      fill_value=capT)[0].astype(jnp.int32)
                     bkey, bslot = edge_band_records(mesh, dt)
                     (ks,), order = merge_sorted_band(
-                        (topo.ekey,), topo.eslot, sd, (bkey,), bslot, rolled)
+                        (topo.ekey,), topo.eslot, sd, (bkey,), bslot)
                     return ks, order
                 return _merge
             ks, order = jax.lax.cond(nd == 0, _reuse,
-                                     _narrowest(nd, rungs, _merge_at), None)
+                                     _narrowest(nd, band, _merge_at), None)
             # a merged sort has no sort for the tags to ride in
             return ks, order, etag6[order]
 
@@ -427,18 +371,18 @@ def incr_unique_edges(mesh: Mesh, topo: TopoState, incr,
         topo = topo._replace(ekey=ks, eslot=order,
                              eok=jnp.ones((), bool),
                              edirty=jnp.zeros(capT, bool))
-        return (et, topo, use_band) if told else (et, topo)
+        return et, topo, use_band
 
 
-def incr_build_adjacency(mesh: Mesh, topo: TopoState, incr,
-                         band: int | tuple[int, ...] | None = None,
-                         told: bool = False, rolled: bool = False):
+@partial(jax.jit, static_argnames=("band",))
+def incr_build_adjacency(mesh: Mesh, topo: TopoState, *,
+                         band: tuple[int, ...]):
     """Adjacency (and boundary tags) via the retained face sort — the
     incremental form of ops/adjacency.build_adjacency, re-deriving
     twins only where the band touched (merged face records feed the
-    SAME pairing epilogue).  Consumes ``fdirty``.  ``band``, ``told``,
-    ``rolled``: as :func:`incr_unique_edges`.  Returns (mesh with adja/ftag, new
-    TopoState[, off the retained sort?])."""
+    SAME pairing epilogue).  Consumes ``fdirty``.  ``band``: as
+    :func:`incr_unique_edges`.  Returns (mesh with adja/ftag, new
+    TopoState, off the retained sort?)."""
     from .edges import PACK_LIMIT, sort_carry
     from .adjacency import (_face_keys, adjacency_from_records,
                             build_adjacency, face_records_from_sorted,
@@ -449,10 +393,9 @@ def incr_build_adjacency(mesh: Mesh, topo: TopoState, incr,
             mesh = build_adjacency(mesh)
             topo = topo._replace(fok=jnp.zeros((), bool),
                                  fdirty=jnp.zeros(capT, bool))
-            return (mesh, topo, jnp.zeros((), bool)) if told else (mesh, topo)
-        rungs = _rungs(band, capT)
+            return mesh, topo, jnp.zeros((), bool)
         nd = jnp.sum(topo.fdirty, dtype=jnp.int32)
-        use_band = jnp.asarray(incr) & topo.fok & (nd <= rungs[-1])
+        use_band = topo.fok & (nd <= band[-1])
 
         def _full(_):
             cols = _face_keys(mesh)
@@ -472,11 +415,11 @@ def incr_build_adjacency(mesh: Mesh, topo: TopoState, incr,
                     bk0, bkw, bslot = face_band_records(mesh, dt)
                     (k0, kw), order = merge_sorted_band(
                         (topo.fk0, topo.fkw), topo.fslot, sd, (bk0, bkw),
-                        bslot, rolled)
+                        bslot)
                     return k0, kw, order
                 return _merge
             return jax.lax.cond(nd == 0, _reuse,
-                                _narrowest(nd, rungs, _merge_at), None)
+                                _narrowest(nd, band, _merge_at), None)
 
         k0, kw, order = jax.lax.cond(use_band, _band, _full, None)
         t, f, tp, fp, matched, _ = face_records_from_sorted(order, k0, kw)
@@ -484,16 +427,4 @@ def incr_build_adjacency(mesh: Mesh, topo: TopoState, incr,
         topo = topo._replace(fk0=k0, fkw=kw, fslot=order,
                              fok=jnp.ones((), bool),
                              fdirty=jnp.zeros(capT, bool))
-        return (mesh, topo, use_band) if told else (mesh, topo)
-
-
-# the merged polish's derivations (ops/adapt.sliver_polish_impl with a
-# state): always on, told, the search rolled.  A jitted function a kind
-# of table, because a wave derives an edge table in three places and an
-# adjacency in two, and then traces, lowers and holds each kind once
-polish_unique_edges = jax.jit(
-    partial(incr_unique_edges, incr=True, told=True, rolled=True),
-    static_argnames=("shell_slots", "band"))
-polish_build_adjacency = jax.jit(
-    partial(incr_build_adjacency, incr=True, told=True, rolled=True),
-    static_argnames=("band",))
+        return mesh, topo, use_band

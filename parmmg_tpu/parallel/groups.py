@@ -167,14 +167,6 @@ def _group_block_program(nomove: bool, noinsert: bool, hausd):
     all-true mask when masking is off), so toggling it mints zero new
     compile families — the grouped_sched_gate contract.
 
-    ``incr``/``topo`` (PARMMG_INCR_TOPO, ops/topo_incr): per-slot
-    retained-sort + dirty-band state rides the group axis through the
-    SAME compiled program — the knob scalar and the state are ALWAYS
-    traced arguments, so toggling the incremental path mints zero new
-    compile families (the hotloop_knob_gate contract).  Quiet/pad slots
-    pass through the ``active`` lax.cond with their state untouched
-    (an idle slot's retained tables stay valid).
-
     Whether the cycle's surface scatters run over lists of their live
     updates (ops/surflist) is observed here, where the program is
     built, and is part of its key: they do where it is placed on a
@@ -191,21 +183,20 @@ def _group_block_program(nomove: bool, noinsert: bool, hausd):
     # add a few; growth past this is recompile churn
     @governed(BLOCK_ENTRY, budget=6)
     @jax.jit
-    def run(stacked, met_s, wave, active, incr, topo, sw, pr):
+    def run(stacked, met_s, wave, active, sw, pr):
         def body(args):
-            m, k, wave, act, inc, tp = args
+            m, k, wave, act = args
             # the cycle names its own phases (``cyc.*`` scopes: XLA op
             # metadata, which obs/devtime reads off the executable)
             return adapt_cycle_impl(
                 m, k, wave, do_swap=sw, do_smooth=not nomove,
                 do_insert=not noinsert, hausd=hausd, prescreen=pr,
-                active=act, topo=tp, incr=inc, surf_list=surf_list)
+                active=act, surf_list=surf_list)
 
         n_map = stacked.vert.shape[0]            # chunk or g_exec
         waves = jnp.full(n_map, wave, jnp.int32)
-        incs = jnp.full(n_map, incr, bool)
-        return jax.lax.map(                      # counts [G, 12]
-            body, (stacked, met_s, waves, active, incs, topo))
+        return jax.lax.map(                      # counts [G, 11]
+            body, (stacked, met_s, waves, active))
 
     _GROUP_BLOCK_CACHE[key] = run
     return run
@@ -256,21 +247,8 @@ def _pad_groups(tree, g_new: int):
     return jax.tree.map(pad, tree)
 
 
-def _pipeline_chunks(fn, stacked, met_s, wave, plans, tim, done=None,
-                     extra=(), topo=None):
+def _pipeline_chunks(fn, stacked, met_s, wave, plans, tim, done=None):
     """Double-buffered chunked dispatch over gathered group-index slices.
-
-    ``extra``: additional positional device scalars appended to each
-    ``fn`` dispatch after the active mask (the adapt block's traced
-    incremental-topology knob; empty for the polish block).
-
-    ``topo``: optional host-numpy TopoState [g_exec, ...]
-    (ops/topo_incr.topo_init_np) — the per-slot retained-table state of
-    the incremental topology engine.  Its rows ride the same gather /
-    dispatch / writeback path as the mesh state, and like it they only
-    mutate when a drain COMMITS, so the band state is covered by the
-    idempotent-writeback contract: a faulted dispatch's retry replays
-    from the retained table bit-for-bit.
 
     ``plans``: [(idx_exec [chunk], nreal)] from the quiet-group
     scheduler (parallel/sched.py); the SAME compiled [chunk, ...]
@@ -337,34 +315,28 @@ def _pipeline_chunks(fn, stacked, met_s, wave, plans, tim, done=None,
             # chunk of a shape the unchunked path has run takes the
             # executable that path built (compilecache, placement
             # variants)
-            sl, kl, tl = to_device(jax.tree.map(
-                lambda a: a[idx], (stacked, met_s, topo)))
+            sl, kl = to_device(jax.tree.map(
+                lambda a: a[idx], (stacked, met_s)))
             # device quiet mask: the repeat-padded tail rows compute
             # nothing (lax.cond identity) — their results were always
             # discarded at writeback (sched.pad_mask)
             act = jnp.asarray(pad_mask(len(idx), nreal))
         faultpoint("dispatch.chunk", key=str(pi))
         with otrace.span("grp dispatch chunk", chunk_index=pi):
-            if topo is None:
-                m, k, cnt = fn(sl, kl, wave, act, *extra)
-                tp = None
-            else:
-                m, k, cnt, tp = fn(sl, kl, wave, act, *extra, tl)
-        return (pi, idx, nreal, m, k, cnt, tp)
+            m, k, cnt = fn(sl, kl, wave, act)
+        return (pi, idx, nreal, m, k, cnt)
 
     # lint: ok(R2) — the pipeline's ONE designed sync point: chunked
     # mode keeps the pass state host-resident, so the drain downloads
-    # O(chunk) tables + [chunk,12] counters while chunk k+1 is
+    # O(chunk) tables + [chunk,11] counters while chunk k+1 is
     # already dispatched (PR-5 double buffering; segments timed)
     def drain(p):
-        pi, idx, nreal, m, k, cnt, tp = p
+        pi, idx, nreal, m, k, cnt = p
         with tim("compute"):
             jax.block_until_ready(cnt)
         with tim("download"):
             mh = jax.tree.map(lambda s: np.asarray(s), m)
             kh = np.asarray(k)
-            th = None if tp is None else \
-                jax.tree.map(lambda s: np.asarray(s), tp)
             out[pi] = np.asarray(cnt)[:nreal]
         with tim("writeback"):
             rows = idx[:nreal]
@@ -374,8 +346,6 @@ def _pipeline_chunks(fn, stacked, met_s, wave, plans, tim, done=None,
                 return d
             jax.tree.map(w, stacked, mh)
             met_s[rows] = kh[:nreal]
-            if th is not None:
-                jax.tree.map(w, topo, th)
         if done is not None:
             done[pi] = out[pi]
 
@@ -472,7 +442,7 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     skipped-group / saved-dispatch counters and the active-group
     trajectory, in ``stats.sched_extra``.
     """
-    from ..ops.adapt import (DIRTY_COL, LISTED_COL, SURF_COLS,
+    from ..ops.adapt import (CYCLE_COLS, LISTED_COL, SURF_COLS,
                              surface_scatter_width)
     from ..utils.timers import Timers
     from .distribute import (capacity_headroom, split_to_shards,
@@ -534,44 +504,21 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
         jax.tree.map(w, dst_tree, src_tree)
 
     # everything the pass commits to the device before its first block:
-    # the stacked state (unchunked), the scheduler's scalars, the
-    # incremental-topology state
-    from ..ops.topo_incr import incr_topo_enabled, topo_init, topo_init_np
-
-    def fresh_topo(capT):
-        """The incremental-topology state of a pass at group capacity
-        ``capT``: host numpy in chunk mode (uploaded with each chunk,
-        the same way every time), else COMMITTED to the device like the
-        stacked state beside it.  The block program hands it back
-        committed, and jax keys a lowering on that: a bare ``jnp.zeros``
-        state made the first dispatch of every pass lower, compile and
-        cache a second executable of the same jaxpr (PERF.md, PR 31)."""
-        if chunk:
-            return topo_init_np(g_exec, capT)
-        return to_device(topo_init(capT, stack=g_exec))
-
+    # the stacked state (unchunked: COMMITTED, because the block program
+    # hands it back committed and jax keys a lowering on that; PERF.md,
+    # PR 31) and the scheduler's scalars
     with otrace.span("grp upload", chunk=chunk or 0) as sp:
-        topo_s = fresh_topo(stacked.tet.shape[1])
         if not chunk:
             stacked, met_s = to_device((stacked, met_s))
             sp.set(bytes=sum(a.nbytes for a in
-                             jax.tree.leaves((stacked, met_s, topo_s))))
+                             jax.tree.leaves((stacked, met_s))))
         sched = QuietGroupScheduler(ngroups, g_exec, chunk)
-        # incremental topology engine (ops/topo_incr, PARMMG_INCR_TOPO):
-        # per-slot retained-table + dirty-band state rides the group axis —
-        # host-resident in chunk mode (rows committed by drain writebacks,
-        # same idempotent contract as the mesh state), device-resident
-        # otherwise (fresh_topo).  The knob is a traced scalar, always an
-        # argument of the compiled block (like the quiet mask): toggling
-        # it mints no new compile family.
-        inc = jnp.asarray(incr_topo_enabled())
     # pipeline segment timers on a LOCAL registry: folded into
     # stats.sched_extra and (prefixed) into the caller's Timers at the
     # end, so the driver report shows the transfer/compute split
     ltim = Timers()
     c = 0
     regrows = 0
-    dirty_traj: list[int] = []
     while c < cycles:
         swap, pre = block_schedule(c, cycles, noswap)
         step = _group_block(swap, pre, nomove, noinsert, hausd)
@@ -587,11 +534,10 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                             active=len(act)) as sp:
             if chunk:
                 parts = _pipeline_chunks(step, stacked, met_s, wave,
-                                         plans, ltim, extra=(inc,),
-                                         topo=topo_s)
+                                         plans, ltim)
                 sched.note_plan_pads(plans)
                 counts_act = np.concatenate(parts) if parts else \
-                    np.zeros((0, DIRTY_COL + 1), np.int32)
+                    np.zeros((0, CYCLE_COLS), np.int32)
                 if sched.enabled:
                     otrace.log(
                         2, f"  grp block {c}: active "
@@ -603,13 +549,13 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                 # converged groups here (lax.cond identity rows,
                 # sched.block_mask; bit-for-bit by the fixed point).
                 # The pull of the counters is the block's only sync
-                stacked, met_s, counts, topo_s = step(
+                stacked, met_s, counts = step(
                     stacked, met_s, wave,
-                    jnp.asarray(sched.block_mask(pre)), inc, topo_s)
-                counts_act = np.asarray(counts)         # [g_exec, 12]
+                    jnp.asarray(sched.block_mask(pre)))
+                counts_act = np.asarray(counts)         # [g_exec, 11]
             # quiet groups contribute exact zeros (that is what marked
             # them)
-            cs = counts_act.sum(axis=0, dtype=np.int64)         # [12]
+            cs = counts_act.sum(axis=0, dtype=np.int64)         # [11]
             # ONE host conversion for the block's counters (counts_act
             # is already host numpy — the drain pulled it)
             tot = cs.tolist()                           # python ints
@@ -634,9 +580,6 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
             # from dispatch to counter pull
             ltim.add("compute", sp.dur)
         sched.record_block(act, counts_act, swap_inc, pre)
-        # dirty tets pending at the cycle's start, summed over groups —
-        # the band-occupancy trajectory (sched_extra)
-        dirty_traj.append(tot[DIRTY_COL])
         if stats is not None:
             stats.nsplit += tot[0]
             stats.ncollapse += tot[1]
@@ -686,10 +629,6 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                     met_s = _padP(met_s)
                 else:
                     stacked, met_s = grow_shards(stacked, met_s, newP, newT)
-                # regrow permutes tet slots (compact) and changes capT: the
-                # retained sorts are stale at the new capacity — re-init
-                # (ok=False => next derivation is a full rebuild, exact)
-                topo_s = fresh_topo(stacked.tet.shape[1])
                 regrows += 1
                 # the wave top-K budgets scale with capT: every quiet proof
                 # is stale at the new capacity — reactivate the full set
@@ -848,11 +787,6 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                 round(overhead, 4))
         se.setdefault("active_groups_per_block", []).extend(
             sched.active_per_block)
-        if dirty_traj:
-            # per-cycle dirty-band occupancy (the dirty column summed over
-            # groups): shows when the incremental path engages and how
-            # small the decay-regime bands get
-            se.setdefault("incr_dirty_per_cycle", []).extend(dirty_traj)
         if pol_traj:
             se.setdefault("polish_active_per_wave", []).extend(pol_traj)
         for k, v in ltim.acc.items():

@@ -80,11 +80,6 @@ class Bucket:
         self.slots = [Slot() for _ in range(nslots)]
         self.stacked = None
         self.met = None
-        # per-slot incremental-topology state (ops/topo_incr.TopoState,
-        # host numpy [nslots, ...]); lazily allocated at first dispatch.
-        # All-zero rows = ok=False = full rebuild on first derivation,
-        # so slot recycling resets topo exactly like mesh state
-        self.topo = None
 
     def free_slot(self) -> int | None:
         for i, s in enumerate(self.slots):
@@ -206,9 +201,6 @@ class SlotPool:
                 return a
             jax.tree.map(z, b.stacked)
             b.met[i] = 0
-        if b.topo is not None:
-            import jax
-            jax.tree.map(lambda a: a.__setitem__(i, 0), b.topo)
 
     def release(self, tenant: str) -> None:
         """Free a tenant's slot (slot recycling): the row is zeroed
@@ -260,12 +252,6 @@ class SlotPool:
                 b.met = np.concatenate(
                     [b.met, np.zeros((add,) + b.met.shape[1:],
                                      b.met.dtype)])
-            if b.topo is not None:
-                import jax
-                b.topo = jax.tree.map(
-                    lambda a: np.concatenate(
-                        [a, np.zeros((add,) + a.shape[1:], a.dtype)]),
-                    b.topo)
             b.nslots = want
         elif want < b.nslots:
             keep = b.nslots
@@ -279,11 +265,6 @@ class SlotPool:
                         lambda a: np.ascontiguousarray(a[:keep]),
                         b.stacked)
                     b.met = np.ascontiguousarray(b.met[:keep])
-                if b.topo is not None:
-                    import jax
-                    b.topo = jax.tree.map(
-                        lambda a: np.ascontiguousarray(a[:keep]),
-                        b.topo)
                 b.nslots = keep
         return b.nslots
 
@@ -331,10 +312,6 @@ class SlotPool:
         for f in MESH_FIELDS:
             getattr(b.stacked, f)[i] = np.asarray(getattr(stacked1, f)[0])
         b.met[i] = np.asarray(met1[0])
-        if b.topo is not None:
-            # stale retained-table state must not leak across tenants:
-            # zero = ok=False = full rebuild at the first derivation
-            jax.tree.map(lambda a: a.__setitem__(i, 0), b.topo)
         b.slots[i].loaded = True
 
     def slot_state(self, tenant: str):
@@ -400,32 +377,23 @@ class SlotPool:
         already COMMITTED during the fast path (the ``done`` contract
         of ``_pipeline_chunks``) keep their results — their slots
         advanced, and re-dispatching them would apply the cycle wave
-        twice.  Returns [(slot index, the cycle's counts row [12])] for
+        twice.  Returns [(slot index, the cycle's counts row [11])] for
         slots that ran; faulting slots are accounted via
         :meth:`_note_slot_fault` (retried next step, or quarantined
         into ``done``)."""
         from ..obs import trace as otrace
         from ..obs.metrics import REGISTRY
-        import jax.numpy as jnp
         from ..parallel.groups import _pipeline_chunks
         from ..parallel.sched import chunk_plans
         from ..resilience.faults import FAULTS, faultpoint
-        from ..ops.topo_incr import incr_topo_enabled, topo_init_np
         plans = chunk_plans(np.asarray(ids), self.chunk)
-        # the incremental-topology enable rides along as a traced scalar
-        # (the hotloop_knob_gate contract): same compiled program either
-        # way
-        inc = jnp.asarray(incr_topo_enabled())
-        if b.topo is None:
-            b.topo = topo_init_np(b.nslots, b.capT)
         committed: dict = {}
         try:
             if FAULTS.armed():
                 for i in ids:
                     faultpoint("serve.slot_step", key=b.slots[i].tenant)
             parts = _pipeline_chunks(fn, b.stacked, b.met, wave, plans,
-                                     self.timers, done=committed,
-                                     extra=(inc,), topo=b.topo)
+                                     self.timers, done=committed)
             self.dispatches += len(plans)
             REGISTRY.counter("serve.dispatches").inc(len(plans))
             return list(zip(ids, np.concatenate(parts)))
@@ -449,8 +417,7 @@ class SlotPool:
                     faultpoint("serve.slot_step", key=s.tenant)
                     plans1 = chunk_plans(np.asarray([i]), self.chunk)
                     parts1 = _pipeline_chunks(fn, b.stacked, b.met,
-                                              wave, plans1, self.timers,
-                                              extra=(inc,), topo=b.topo)
+                                              wave, plans1, self.timers)
                     self.dispatches += len(plans1)
                     REGISTRY.counter("serve.dispatches").inc(len(plans1))
                     out.append((i, np.concatenate(parts1)[0]))
@@ -524,11 +491,6 @@ class SlotPool:
         nb.stacked.npoin[j] = npoin
         nb.stacked.nelem[j] = nelem
         nb.met[j] = padP(met_row)
-        if nb.topo is not None:
-            # retained tables do not transfer across capacity rungs
-            # (band/table widths are capT-static): reset to full-rebuild
-            import jax
-            jax.tree.map(lambda a: a.__setitem__(j, 0), nb.topo)
         # hand the slot over: bookkeeping moves, old slot recycles
         nb.slots[j] = dataclasses.replace(s, regrows=s.regrows + 1)
         self._zero_row(b, i)
@@ -607,7 +569,7 @@ class SlotPool:
                         b, fn, jnp.asarray(c, jnp.int32), ids, done)
                     for i, crow in rows:
                         s = b.slots[i]
-                        cs = crow.astype(np.int64)           # [12]
+                        cs = crow.astype(np.int64)           # [11]
                         st = s.stats
                         st.nsplit += int(cs[0])
                         st.ncollapse += int(cs[1])

@@ -10,9 +10,6 @@ escalation-ladder step (resilience/recover.py):
    families — resilience is host bookkeeping, never a new program;
 2. **transient dispatch fault** (``dispatch.chunk:nth-1``): the chunk
    retries and the run recovers bit-for-bit (ladder step ``retry``);
-   also armed UNDER ``PARMMG_INCR_TOPO=1`` — retained TopoState rows
-   mutate only at drain writeback, so the faulted chunk replays from
-   the retained sorted tables bit-for-bit;
 3. **retry-budget exhaustion** (``dispatch.chunk`` every hit,
    ``PARMMG_RETRY_MAX=1``): the driver degrades to ``PMMG_LOWFAILURE``
    and the staged output is still a conforming mesh (ladder terminal
@@ -202,25 +199,6 @@ def main() -> int:
           "fault actually injected")
     check(delta(c0, "resilience.retry") >= 1, "retry rung recorded")
     check("retry" in ladder_steps_since(mark), "ladder event emitted")
-
-    # ---- 2b. incremental topology under chunk faults -------------------
-    # PARMMG_INCR_TOPO threads retained sorted tables (TopoState rows)
-    # through the chunked dispatches; rows mutate ONLY at drain
-    # writeback (the idempotent-writeback contract), so a faulted
-    # dispatch must replay from the retained table bit-for-bit
-    print("--- chaos gate: incremental topology (PARMMG_INCR_TOPO)")
-    with env(PARMMG_INCR_TOPO="1"):
-        inc = run_grouped()
-    check(inc == base,
-          "incremental-topology run bit-identical to knob-off baseline")
-    c0 = counters()
-    with env(PARMMG_INCR_TOPO="1",
-             PARMMG_FAULT="dispatch.chunk:nth-1", PARMMG_RETRY_MAX="2"):
-        got = run_grouped()
-    check(got == base, "faulted chunk under the incremental path "
-                       "replayed from the retained tables bit-for-bit")
-    check(delta(c0, "resilience.faults_injected") >= 1,
-          "incr-path fault actually injected")
 
     # ---- 3. retry exhaustion -> LOWFAILURE + conforming mesh -----------
     print("--- chaos gate: dispatch.chunk retry exhaustion")

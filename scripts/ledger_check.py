@@ -157,21 +157,14 @@ def grouped_sched_gate() -> int:
 
 def hotloop_knob_gate() -> int:
     """Hot-loop knob compile-family gate (the cycle-cost demolition
-    attacks, README "Hot-loop cycle costs"): flipping the incremental
-    topology engine, the facesort swap pairing, the donor-band collapse
-    apply, the Pallas scoring prep or the Pallas sort engine may not
-    mint a single new ``groups.*``
-    compile family in a warm process.  Two distinct mechanisms back
-    this: PARMMG_INCR_TOPO is a TRACED device
-    scalar of the compiled block (like the quiet mask — toggling
-    changes an input value, never the program; the incremental path's
-    band/table shapes are capT-static ladder rungs, so the knob-on arm
-    adds no shape families either), while the facesort / band / score /
-    sort knobs are trace-time reads whose both settings produce
-    bit-identical
-    results, so the warm ``_GROUP_BLOCK_CACHE`` program from the first
-    run legitimately serves the flipped runs (a stale entry is only a
-    perf choice, never a correctness one)."""
+    attacks, README "Hot-loop cycle costs"): flipping the facesort swap
+    pairing, the donor-band collapse apply, the Pallas scoring prep or
+    the Pallas sort engine may not mint a single new ``groups.*``
+    compile family in a warm process.  The knobs are trace-time reads
+    whose both settings produce bit-identical results, so the warm
+    ``_GROUP_BLOCK_CACHE`` program from the first run legitimately
+    serves the flipped runs (a stale entry is only a perf choice, never
+    a correctness one)."""
     import jax.numpy as jnp
     from parmmg_tpu.core.mesh import make_mesh
     from parmmg_tpu.ops.analysis import analyze_mesh
@@ -182,7 +175,7 @@ def hotloop_knob_gate() -> int:
     from parmmg_tpu.utils.fixtures import cube_mesh
 
     KNOBS = ("PARMMG_SWAP_FACESORT", "PARMMG_COLLAPSE_BAND",
-             "PARMMG_PALLAS_SCORE", "PARMMG_INCR_TOPO")
+             "PARMMG_PALLAS_SCORE")
 
     def run(setting: str):
         for k in KNOBS:
@@ -216,7 +209,7 @@ def hotloop_knob_gate() -> int:
     assert v0.get("groups.adapt_block", 0) >= 1, \
         "hot-loop knob scenario no longer exercises groups.adapt_block"
     print("--- hot-loop knob scenario "
-          "(facesort/band/score/sort/incr topo)")
+          "(facesort/band/score/sort)")
     if v1 != v0:
         print("HOT-LOOP KNOB COMPILE-FAMILY REGRESSIONS (knobs-on run "
               f"added variants vs knobs-off): {v0} -> {v1}",
@@ -230,8 +223,7 @@ def hotloop_knob_gate() -> int:
             print(f"  {v}", file=sys.stderr)
         return 1
     print(f"hot-loop knobs OK: zero new compile families ({v1}; "
-          "facesort, collapse band, pallas score, incr topo, "
-          "pallas sort)")
+          "facesort, collapse band, pallas score, pallas sort)")
     return 0
 
 
@@ -403,9 +395,9 @@ def main() -> int:
     # quiet-group scheduler gate: compaction must reuse the compiled
     # [chunk, ...] group program — zero new families with it enabled
     rc = max(rc, grouped_sched_gate())
-    # hot-loop knob gate: facesort/band/score/incr-topo toggles add zero
-    # groups.* families in a warm process (traced-scalar + warm-cache
-    # contracts — see hotloop_knob_gate)
+    # hot-loop knob gate: facesort/band/score toggles add zero
+    # groups.* families in a warm process (the warm-cache contract —
+    # see hotloop_knob_gate)
     rc = max(rc, hotloop_knob_gate())
     # serving gate: a warm multi-tenant pool adds zero groups.*
     # families vs the batch grouped path (and matches it bit-for-bit)

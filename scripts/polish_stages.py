@@ -173,14 +173,14 @@ def table_programs(capT: int):
     def edges(slots):
         def merged(m, tp):
             nd = jnp.sum(tp.edirty, dtype=jnp.int32)
-            return ti.polish_unique_edges(m, tp, shell_slots=slots,
-                                          band=band) + (nd,)
+            return ti.incr_unique_edges(m, tp, shell_slots=slots,
+                                        band=band) + (nd,)
         return jax.jit(merged), jax.jit(
             lambda m: unique_edges(m, shell_slots=slots))
 
     def faces(m, tp):
         nd = jnp.sum(tp.fdirty, dtype=jnp.int32)
-        return ti.polish_build_adjacency(m, tp, band=band) + (nd,)
+        return ti.incr_build_adjacency(m, tp, band=band) + (nd,)
 
     mark = jax.jit(lambda tp, before, after: ti.mark_dirty(
         tp, before.tet, before.tmask, after))

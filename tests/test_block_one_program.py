@@ -6,7 +6,9 @@ grouped pass made its incremental-topology state that way beside a
 committed stacked state, and the block program hands the state back
 committed: dispatch 0 of every pass ran one executable and every later
 dispatch another, same jaxpr, each minutes of compile and 115 MB of
-cache at the benchmark's capacity (PERF.md, PR 31).  Here on the CPU, on
+cache at the benchmark's capacity (PERF.md, PR 31).  The state left the
+blocks with PR 46; what a pass hands its first block is still committed,
+and still ONE tree.  Here on the CPU, on
 ``cube_mesh(3)`` in two groups: the executables the compile ledger
 counts for ``groups.adapt_block``, the job's output against the
 parent's, and the detector that names such a variant wherever a
@@ -183,17 +185,14 @@ def test_the_job_equals_the_parents(runs):
     assert runs["job"]["result"] == PARENT_JOB
 
 
-def test_the_upload_span_counts_the_topology_state(runs):
-    """A pass commits two trees, the topology state and the stacked mesh
-    with its metric, and ``grp upload``'s ``bytes`` hold both: the state
-    is, a group, 6 + 6 + 4 + 4 + 4 int32 rows and 1 + 1 bool rows of
-    ``capT``, and two flags."""
+def test_the_upload_span_counts_the_one_tree_a_pass_commits(runs):
+    """A pass commits ONE tree, the stacked mesh with its metric (no
+    table state beside it since PR 46), and ``grp upload``'s ``bytes``
+    hold it."""
     run = runs["pass"]
-    (up,), (split,) = run["uploads"], run["splits"]
-    capT = split["capT"]
-    topo, state = run["committed"]
-    assert topo == 2 * (24 * capT * 4 + 2 * capT + 2)
-    assert up["bytes"] == topo + state
+    (up,) = run["uploads"]
+    (state,) = run["committed"]
+    assert up["bytes"] == state > 0
 
 
 def test_no_placement_variant_in_a_grouped_job(runs):

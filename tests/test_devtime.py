@@ -36,7 +36,6 @@ def small():
     """Two groups of ``cube_mesh(2)`` and the arguments of a block."""
     from parmmg_tpu.core.mesh import make_mesh
     from parmmg_tpu.ops.analysis import analyze_mesh
-    from parmmg_tpu.ops.topo_incr import topo_init
     from parmmg_tpu.parallel.distribute import split_to_shards
     from parmmg_tpu.utils.fixtures import cube_mesh
     vert, tet = cube_mesh(2)
@@ -46,7 +45,6 @@ def small():
     part = (vert[tet].mean(axis=1)[:, 0] > 0.5).astype(np.int32)
     stacked, met_s = split_to_shards(m, met, part, 2)
     args = (stacked, met_s, jnp.asarray(0, jnp.int32), jnp.ones(2, bool),
-            jnp.asarray(False), topo_init(stacked.tet.shape[1], stack=2),
             jnp.asarray(True), jnp.asarray(True))
     return {"mesh": m, "met": met, "block_args": args}
 
@@ -537,7 +535,7 @@ def ran(small):
         c0 = counters()
         out = run(*args)
         kept1 = LEDGER.lowered_keys(BLOCK_ENTRY)
-        out = run(out[0], out[1], *args[2:5], out[3], *args[6:])
+        out = run(out[0], out[1], *args[2:])
         jax.block_until_ready(out)
         kept2 = LEDGER.lowered_keys(BLOCK_ENTRY)
         calls = LEDGER.snapshot()[BLOCK_ENTRY]["calls"]
@@ -553,7 +551,8 @@ def ran(small):
         groups._GROUP_BLOCK_CACHE.clear()
         groups._GROUP_BLOCK_CACHE.update(saved)
     return {"kept": (kept1, kept2), "calls": calls, "prog": prog,
-            "warm": warm, "cold": cold, "counters": (c0, c1, c2, c3)}
+            "warm": warm, "cold": cold, "counters": (c0, c1, c2, c3),
+            "run": run, "out": out}
 
 
 def _inc(ran, i, j, name):
@@ -598,6 +597,32 @@ def test_scope_map_puts_most_instructions_under_a_phase(ran):
     seen = {p for p, _ in ran["warm"].phases.values()}
     assert set(CYC) <= seen
     assert {t for _, t in ran["warm"].phases.values()} >= set(TAB)
+
+
+def test_a_block_takes_mesh_metric_and_switches_and_hands_back_three(ran):
+    """Six arguments, three results and an eleven-column row a group:
+    the block carries no table state from cycle to cycle (PR 46)."""
+    import inspect
+    assert list(inspect.signature(ran["run"].__wrapped__).parameters) == [
+        "stacked", "met_s", "wave", "active", "sw", "pr"]
+    assert len(ran["out"]) == 3
+    assert ran["out"][2].shape == (2, 11)
+
+
+def test_a_block_sorts_its_tables_in_full(lowered):
+    """Under ``tab.edges`` / ``tab.adjacency`` a block's program holds
+    the full sort's straight line: no branch between a sort and a merge
+    and no search loop (what ``ops/topo_incr`` puts under the same
+    scopes in the host's polish, which the second assert sees)."""
+    import re
+
+    def nested(text):
+        under = re.findall(r'"[^"]*?tab\.(?:edges|adjacency)/([^"]*)"', text)
+        return {seg for path in under for seg in path.split("/")
+                if seg in ("cond", "while")}
+    assert "tab.edges" in lowered["block"][1]
+    assert nested(lowered["block"][1]) == set()
+    assert nested(lowered["polish"][1]) == {"cond", "while"}
 
 
 def test_a_later_call_keeps_no_second_signature(ran):
@@ -674,7 +699,7 @@ def test_a_fem_round_has_a_map_of_its_own(small):
 
 def test_a_fem_round_with_a_state_keeps_its_stages_and_tables(small):
     """With the polish's ``TopoState`` handed on (PR 44) the round's two
-    tables are ``polish_unique_edges`` / ``polish_build_adjacency``, jitted
+    tables are ``incr_unique_edges`` / ``incr_build_adjacency``, jitted
     functions of their own: the map still finds ``tab.edges`` under
     ``fem.split`` and ``tab.adjacency`` under ``fem.adjacency``, and the
     full sorts (the arm a round takes where nothing is retained) there."""

@@ -108,9 +108,9 @@ def fresh_sorts(mesh):
     """(the mesh with its adjacency, a state that holds both its sorts
     and no dirty row): what one derivation of each table leaves."""
     band = ti.polish_bands(mesh.capT)
-    _, topo, _ = ti.polish_unique_edges(mesh, ti.topo_init(mesh.capT),
-                                        shell_slots=3, band=band)
-    mesh, topo, _ = ti.polish_build_adjacency(mesh, topo, band=band)
+    _, topo, _ = ti.incr_unique_edges(mesh, ti.topo_init(mesh.capT),
+                                      shell_slots=3, band=band)
+    mesh, topo, _ = ti.incr_build_adjacency(mesh, topo, band=band)
     assert bool(topo.eok) and bool(topo.fok)
     assert not bool(jnp.any(topo.edirty) | jnp.any(topo.fdirty))
     return mesh, topo

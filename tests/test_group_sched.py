@@ -407,14 +407,9 @@ def test_sched_saves_dispatches_and_quiet_fixed_point(monkeypatch):
     kcalm = met_s[1:2]
     step = _group_block(True, False, True, False, None)
     on = jnp.ones(1, bool)
-    from parmmg_tpu.ops.topo_incr import topo_init
-    inc = jnp.asarray(False)
-    tp = topo_init(calm.tet.shape[1], stack=1)
-    m1, k1, c1, tp = step(calm, kcalm, jnp.asarray(0, jnp.int32), on,
-                          inc, tp)
+    m1, k1, c1 = step(calm, kcalm, jnp.asarray(0, jnp.int32), on)
     assert int(np.asarray(c1)[..., :5].sum()) == 0, np.asarray(c1)
-    m2_, k2, c2, _ = step(m1, k1, jnp.asarray(1, jnp.int32), on,
-                          inc, tp)
+    m2_, k2, c2 = step(m1, k1, jnp.asarray(1, jnp.int32), on)
     assert int(np.asarray(c2)[..., :5].sum()) == 0
     for f in MESH_FIELDS:
         a, b = np.asarray(getattr(m1, f)), np.asarray(getattr(m2_, f))

@@ -153,9 +153,9 @@ BANDS = (8, 64)
 def _tables():
     def both(mesh, topo):
         et, topo, emerged = ti.incr_unique_edges(
-            mesh, topo, True, shell_slots=3, band=BANDS, told=True)
+            mesh, topo, shell_slots=3, band=BANDS)
         mesh, topo, fmerged = ti.incr_build_adjacency(
-            mesh, topo, True, band=BANDS, told=True)
+            mesh, topo, band=BANDS)
         return et, mesh, topo, emerged, fmerged
     return jax.jit(both), jax.jit(
         lambda m: (unique_edges(m), build_adjacency(m)))
@@ -187,8 +187,7 @@ def test_bands_of_two_rungs_merge_at_the_narrowest_that_holds(killed,
     assert not bool(jnp.any(topo.edirty) | jnp.any(topo.fdirty))
 
 
-def test_the_polish_asks_for_bands_of_its_capacity_alone(monkeypatch):
-    monkeypatch.setenv("PARMMG_INCR_BAND", "7")     # the blocks' override
+def test_the_polish_asks_for_bands_of_its_capacity_alone():
     assert ti.polish_bands(47895) == (2993, 11973)  # iso-growth's merged
     assert ti.polish_bands(18540) == (1158, 4635)   # aniso-coarsen's
     assert ti.polish_bands(2250) == (1024,)         # a toy: one rung

@@ -1,12 +1,12 @@
-"""Incremental topology engine tests (PARMMG_INCR_TOPO, ops/topo_incr).
+"""The host tail's table engine (ops/topo_incr): the merge and its arms.
 
-Tier-1 (fast, host-only) coverage: the dirty-band width ladder, the
-tombstone-merge against a fresh stable sort (the module's exactness
-proof, fuzzed with dead tets and tombstones), the overflow fallback
-(PARMMG_INCR_BAND forced below the dirty count), the nd==0 wholesale
-reuse, and the Pallas prefix-sum kernel in interpret mode.  The slow
-marks re-run the bit-parity claim through the full grouped pass —
-polish included — knob on vs off, plus a forced-Pallas arm.
+Tier-1 (fast, host-only) coverage: the tombstone-merge against a fresh
+stable sort (the module's exactness proof, fuzzed with dead tets and
+tombstones, with the jnp prefix sum and with the Pallas kernel
+interpreted), the overflow fallback (a band narrower than the dirty
+set), the nd==0 wholesale reuse, the Pallas prefix-sum kernel in
+interpret mode, and that the knobs the cycle blocks once read are gone
+(PR 46: a block sorts its tables in full).
 """
 import os
 from functools import partial
@@ -17,9 +17,7 @@ import jax.numpy as jnp
 import pytest
 
 from parmmg_tpu.core.mesh import MESH_FIELDS, make_mesh
-from parmmg_tpu.ops.topo_incr import (_INT32_MAX, incr_band_width,
-                                      incr_build_adjacency,
-                                      incr_topo_enabled,
+from parmmg_tpu.ops.topo_incr import (_INT32_MAX, incr_build_adjacency,
                                       incr_unique_edges,
                                       merge_sorted_band, topo_init)
 from parmmg_tpu.utils.fixtures import cube_mesh
@@ -37,42 +35,6 @@ def _assert_mesh_equal(a, b, label=""):
     for f in MESH_FIELDS:
         av, bv = np.asarray(getattr(a, f)), np.asarray(getattr(b, f))
         assert (av == bv).all(), f"{label}: mesh field {f} differs"
-
-
-# ---- band width ladder ------------------------------------------------------
-
-def test_incr_band_width_ladder(monkeypatch):
-    from parmmg_tpu.utils.compilecache import bucket
-    monkeypatch.delenv("PARMMG_INCR_BAND", raising=False)
-    # the band width IS a rung of the shared geo bucket ladder — band
-    # sizing can never mint a new shape family
-    for capT in (64, 1024, 9216, 98304, 1 << 20):
-        B = incr_band_width(capT)
-        assert B == bucket(max(1, capT // 16), floor=1024, scheme="geo",
-                           cap=capT)
-        assert 1 <= B <= capT
-    # tiny meshes: the ladder reaches capT (band == full width)
-    assert incr_band_width(64) == 64
-    # big meshes: strict compaction
-    assert incr_band_width(1 << 20) < (1 << 20)
-    # monotone in capT (no oscillating families across regrows)
-    widths = [incr_band_width(c) for c in range(64, 40000, 64)]
-    assert all(a <= b for a, b in zip(widths, widths[1:]))
-    # the override clamps into [1, capT]
-    monkeypatch.setenv("PARMMG_INCR_BAND", "7")
-    assert incr_band_width(9216) == 7
-    monkeypatch.setenv("PARMMG_INCR_BAND", "999999")
-    assert incr_band_width(64) == 64
-
-
-def test_incr_knob_defaults_off(monkeypatch):
-    monkeypatch.delenv("PARMMG_INCR_TOPO", raising=False)
-    assert incr_topo_enabled() is False, \
-        "PARMMG_INCR_TOPO must default off (exact legacy path)"
-    monkeypatch.setenv("PARMMG_INCR_TOPO", "1")
-    assert incr_topo_enabled() is True
-    monkeypatch.setenv("PARMMG_INCR_TOPO", "0")
-    assert incr_topo_enabled() is False
 
 
 # ---- tombstone merge vs fresh stable sort -----------------------------------
@@ -104,11 +66,21 @@ def _merge_case(rng, ncols, n, slots_per_tet=3):
     return old, new, order, dirty_slot, bkeys, bslot
 
 
+@pytest.mark.parametrize("pallas", [None, "1"], ids=["cumsum", "pallas"])
 @pytest.mark.parametrize("ncols", [1, 2])
-def test_merge_sorted_band_bit_equals_stable_sort(ncols):
+def test_merge_sorted_band_bit_equals_stable_sort(ncols, pallas,
+                                                  monkeypatch):
+    """``pallas``: PARMMG_TPU_PALLAS=1 puts the interpreted prefix-sum
+    kernel inside the merge, which must leave it bit-equal."""
     rng = np.random.default_rng(1234 + ncols)
-    merge = jax.jit(merge_sorted_band)
-    for trial in range(25):
+    if pallas is None:
+        monkeypatch.delenv("PARMMG_TPU_PALLAS", raising=False)
+    else:
+        monkeypatch.setenv("PARMMG_TPU_PALLAS", pallas)
+    # a function of this case's own: the prefix sum's dispatch reads the
+    # setting when the merge is traced, and jit keeps a trace by function
+    merge = jax.jit(lambda *args: merge_sorted_band(*args))
+    for trial in range(25 if pallas is None else 6):
         n = int(rng.integers(6, 120)) // 3 * 3 or 3
         old, new, order, dmask, bkeys, bslot = _merge_case(rng, ncols, n)
         ks = [jnp.asarray(old[order, j]) for j in range(ncols)]
@@ -124,36 +96,48 @@ def test_merge_sorted_band_bit_equals_stable_sort(ncols):
         for j in range(ncols):
             assert (np.asarray(mk[j]) == new[ref, j]).all(), \
                 f"trial {trial}: merged key col {j} differs"
+    # off a TPU the forced merge ran the kernel interpreted, the other
+    # one ``jnp.cumsum``
+    jaxpr = str(jax.make_jaxpr(lambda *args: merge_sorted_band(*args))(
+        ks, jnp.asarray(order.astype(np.int32)), sd,
+        [jnp.asarray(bkeys[:, j]) for j in range(ncols)],
+        jnp.asarray(bslot)))
+    assert ("interpret=True" in jaxpr) == (pallas is not None)
+    assert ("cumsum" in jaxpr) == (pallas is None)
 
 
 # ---- overflow fallback + nd==0 reuse on a real mesh -------------------------
 
-def test_incr_overflow_falls_back_exact(monkeypatch):
+@pytest.mark.parametrize("table", ["edges", "adjacency"])
+def test_incr_overflow_falls_back_exact(table):
     """More dirty tets than the band: the lax.cond fallback must yield
     the same table a full rebuild does (exactness by construction)."""
+    from parmmg_tpu.ops.adjacency import build_adjacency
     from parmmg_tpu.ops.edges import unique_edges
-    monkeypatch.setenv("PARMMG_INCR_BAND", "2")     # force overflow
     m = _cube(2)
-    on = jnp.ones((), bool)
-
-    def derive(mesh, topo):
-        et, topo = incr_unique_edges(mesh, topo, on, shell_slots=0)
-        return et, topo
-    jderive = jax.jit(derive)
-    et0, topo = jderive(m, topo_init(m.capT))
+    if table == "edges":
+        derive = partial(incr_unique_edges, shell_slots=0, band=(2,))
+        ref = jax.jit(partial(unique_edges, shell_slots=0))(m)
+    else:
+        derive = partial(incr_build_adjacency, band=(2,))
+        ref = jax.jit(build_adjacency)(m)
+    out0, topo, off0 = derive(m, topo_init(m.capT))
     # dirty MANY tets (all live ones) without changing the mesh: the
     # band (width 2) overflows, the full rebuild re-derives the table
     topo_d = topo._replace(
         edirty=jnp.asarray(np.asarray(m.tmask)),
         fdirty=jnp.asarray(np.asarray(m.tmask)))
-    et1, topo1 = jderive(m, topo_d)
-    ref = jax.jit(partial(unique_edges, shell_slots=0))(m)
-    for a, b, c in zip(jax.tree.leaves(et1), jax.tree.leaves(ref),
-                       jax.tree.leaves(et0)):
+    out1, topo1, off1 = derive(m, topo_d)
+    # neither came off a retained sort: none yet, then too many rows
+    assert not bool(off0) and not bool(off1)
+    for a, b, c in zip(jax.tree.leaves(out1), jax.tree.leaves(ref),
+                       jax.tree.leaves(out0)):
         assert (np.asarray(a) == np.asarray(b)).all()
         assert (np.asarray(a) == np.asarray(c)).all()
     # the fallback refreshed the retained state: dirty cleared, ok set
-    assert bool(topo1.eok) and int(np.asarray(topo1.edirty).sum()) == 0
+    ok, dirty = ((topo1.eok, topo1.edirty) if table == "edges"
+                 else (topo1.fok, topo1.fdirty))
+    assert bool(ok) and int(np.asarray(dirty).sum()) == 0
 
 
 def test_incr_nd0_reuses_retained_table():
@@ -162,16 +146,17 @@ def test_incr_nd0_reuses_retained_table():
     sort — bit-identical to the legacy derivations."""
     from parmmg_tpu.ops.adjacency import build_adjacency
     from parmmg_tpu.ops.edges import unique_edges
+    from parmmg_tpu.ops.topo_incr import polish_bands
     m = _cube(2)
-    on = jnp.ones((), bool)
-    jedge = jax.jit(lambda mm, t: incr_unique_edges(mm, t, on,
-                                                    shell_slots=0))
-    jadj = jax.jit(lambda mm, t: incr_build_adjacency(mm, t, on))
-    et0, topo = jedge(m, topo_init(m.capT))
-    m1, topo = jadj(m, topo)
+    band = polish_bands(m.capT)
+    et0, topo, off = incr_unique_edges(m, topo_init(m.capT),
+                                       shell_slots=0, band=band)
+    m1, topo, foff = incr_build_adjacency(m, topo, band=band)
+    assert not bool(off) and not bool(foff)
     # second derivation, nothing dirty: the nd==0 reuse arm
-    et1, _ = jedge(m, topo)
-    m2, _ = jadj(m, topo)
+    et1, _, off = incr_unique_edges(m, topo, shell_slots=0, band=band)
+    m2, _, foff = incr_build_adjacency(m, topo, band=band)
+    assert bool(off) and bool(foff)
     ref_et = jax.jit(partial(unique_edges, shell_slots=0))(m)
     ref_m = jax.jit(build_adjacency)(m)
     for a, b in zip(jax.tree.leaves(et1), jax.tree.leaves(ref_et)):
@@ -193,60 +178,23 @@ def test_merge_prefix_pallas_interpret_parity():
         assert (np.asarray(got) == np.asarray(ref)).all(), n
 
 
-# ---- slow: full grouped bit-parity, knob on vs off --------------------------
+# ---- the blocks' arm is gone (PR 46) ---------------------------------------
 
-@pytest.mark.slow
-def test_grouped_incr_knob_parity(monkeypatch):
-    """PARMMG_INCR_TOPO on/off through the full grouped pass — waves,
-    cycle blocks, regrows AND the sliver polish phase — is bit-for-bit
-    identical, with identical op counters."""
-    from parmmg_tpu.ops.adapt import AdaptStats
-    from parmmg_tpu.ops.analysis import analyze_mesh
-    from parmmg_tpu.parallel.groups import grouped_adapt
-    vert, tet = cube_mesh(2)
-    outs = []
-    for env in ("0", "1"):
-        monkeypatch.setenv("PARMMG_INCR_TOPO", env)
-        m = make_mesh(vert, tet, capP=4 * len(vert), capT=4 * len(tet))
-        m = analyze_mesh(m).mesh
-        met = jnp.full(m.capP, 0.35, m.vert.dtype)
-        st = AdaptStats()
-        mo, ko = grouped_adapt(m, met, 16, niter=2, cycles=3, stats=st)
-        outs.append((mo, ko, st))
-    (m0, k0, s0), (m1, k1, s1) = outs
-    _assert_mesh_equal(m0, m1, "incr grouped")
-    assert (np.asarray(k0) == np.asarray(k1)).all()
-    assert (s0.nsplit, s0.ncollapse, s0.nswap, s0.nmoved) == \
-        (s1.nsplit, s1.ncollapse, s1.nswap, s1.nmoved)
-    assert s0.cycles == s1.cycles
-    # the knob-on run recorded its dirty-band trajectory
-    assert "incr_dirty_per_cycle" in s1.sched_extra
-    assert len(s1.sched_extra["incr_dirty_per_cycle"]) > 0
-
-
-@pytest.mark.slow
-def test_incr_forced_pallas_parity(monkeypatch):
-    """PARMMG_TPU_PALLAS=1 (interpret-mode merge_prefix inside the
-    band merge) leaves the incremental derivations bit-identical."""
-    from parmmg_tpu.ops.adapt import adapt_cycle_impl
-    m = _cube(2)
-    met = jnp.full(m.capP, 0.5, m.vert.dtype)
-    on = jnp.ones((), bool)
-    outs = []
-    for env in (None, "1"):
-        if env is None:
-            monkeypatch.delenv("PARMMG_TPU_PALLAS", raising=False)
-        else:
-            monkeypatch.setenv("PARMMG_TPU_PALLAS", env)
-        # fresh trace per arm: the dispatch reads the env at trace time
-        step = jax.jit(lambda mm, kk, ww, tt: adapt_cycle_impl(
-            mm, kk, ww, topo=tt, incr=on))
-        mm, kk, tt = m, met, topo_init(m.capT)
-        for cyc in range(3):
-            mm, kk, cnt, tt = step(mm, kk, jnp.asarray(cyc, jnp.int32),
-                                   tt)
-        outs.append((mm, kk, cnt))
-    (ma, ka, ca), (mb, kb, cb) = outs
-    _assert_mesh_equal(ma, mb, "incr forced-pallas")
-    assert (np.asarray(ka) == np.asarray(kb)).all()
-    assert (np.asarray(ca) == np.asarray(cb)).all()
+def test_no_incr_knob_is_declared_or_read():
+    """Two settings chose and sized the cycle blocks' merge arm (the
+    topology knob and its band): the registry declares neither and no
+    file of the package, the scripts or the smoke names them."""
+    from parmmg_tpu.api.knobs import KNOBS
+    assert not [k for k in KNOBS if k.startswith("PARMMG_INCR")]
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    named = []
+    for top in ("parmmg_tpu", "scripts", "chip_smoke.py"):
+        path = os.path.join(root, top)
+        files = [path] if os.path.isfile(path) else [
+            os.path.join(d, f) for d, _, fs in os.walk(path)
+            for f in fs if f.endswith((".py", ".sh"))]
+        for f in files:
+            with open(f, encoding="utf-8") as fh:
+                if "PARMMG_" + "INCR" in fh.read():
+                    named.append(os.path.relpath(f, root))
+    assert named == []
