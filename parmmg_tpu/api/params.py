@@ -48,6 +48,9 @@ class IParam(enum.IntEnum):
     opnbdy = 28
     contiguousMode = 29     # force / don't force the groups' contiguity
     nDevices = 30           # ranks: one a device (-ndev); the repo's own
+    groupCapacity = 31      # largest group capT a job may compile a block
+    #                         for (0: the ladder has no ceiling); the
+    #                         repo's own
 
 
 class DParam(enum.IntEnum):
@@ -85,6 +88,12 @@ class Info:
     contiguous_mode: bool = False
     grps_ratio: float = C.GRPS_RATIO
     target_mesh_size: int = C.TARGET_MESH_SIZE_SENTINEL
+    # the ceiling the grouped path's re-cuts work under: the largest tet
+    # capacity of a group a job may compile a cycle block for (a rung of
+    # ``compilecache.bucket``'s ladder, e.g. 43118).  A cut whose
+    # capacity would pass it takes more groups instead, and a full group
+    # is never regrown past it (parallel/groups.py).  0: no ceiling
+    group_capacity: int = 0
     metis_ratio: int = C.RATIO_MMG_METIS_SENTINEL
     api_mode: int = C.APIDISTRIB_FACES
     compute_glonum: bool = False
@@ -173,6 +182,7 @@ class Info:
             # to PMMG_Init_parMesh; a library on one host has none, so
             # the count is a parameter (checked at run(): check_devices)
             IParam.nDevices: ("n_devices", int),
+            IParam.groupCapacity: ("group_capacity", int),
         }
         if key not in m:
             raise KeyError(f"unsupported iparam {key}")

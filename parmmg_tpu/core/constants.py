@@ -93,6 +93,22 @@ ALPHA_TET = 36.0 * np.sqrt(12.0)
 # Minimal acceptable quality for an operator to be applied (Mmg uses a
 # relative criterion; we keep an absolute floor plus no-worsening rules).
 QUAL_FLOOR = 1e-9
+# What a child of a sizing split must keep in a tet that holds a frozen
+# group seam edge (ops/split.py; every other tet keeps QUAL_FLOOR, as
+# upstream has no such floor).  A midpoint split halves a tet's quality
+# at worst, and a tet on a frozen edge whose other edges the size map
+# wants several times shorter is halved cycle after cycle: 0.56 ->
+# 0.04 in five cycles, 1e-7 by the end of two passes of a job that grows
+# its mesh 4.8x, thousands of tets under the tail's 1e-3 (ROADMAP B1).
+# Under this floor the edge waits: the seam moves between passes and a
+# later pass splits it.  Five times the floor the tail repairs to, so
+# that one collapse (which may take a ball to 0.3 of its worst) stays
+# above it.  Euclidean, like the tail's floor, so it holds under a
+# scalar size map only: a tet that is well shaped in a tensor metric
+# stretched 100:1 reads under it, and a job with a tensor metric keeps
+# QUAL_FLOOR everywhere (a floor in the job's own metric is owed there,
+# ROADMAP B1)
+SPLIT_CHILD_FLOOR = 5e-3
 
 # Default Hausdorff / gradation values (Mmg defaults, forwarded per group by
 # PMMG_Set_dparameter, API_functions_pmmg.c:735)

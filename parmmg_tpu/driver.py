@@ -371,7 +371,8 @@ def _run_phases(pm) -> tuple[Mesh, object, AdaptStats]:
                         nomove=info.nomove, hausd=hausd,
                         ifc_layers=info.ifc_layers, timers=tim,
                         resume=getattr(info, "resume", False),
-                        contiguous=info.contiguous_mode)
+                        contiguous=info.contiguous_mode,
+                        cap_max=info.group_capacity)
             except MemoryError:
                 mesh, met = backup
                 stats.status = C.PMMG_LOWFAILURE

@@ -71,8 +71,25 @@ def sequential_repair(vert, tet, tmask, vtag, vmask, tref, ftag, etag,
         for v in tet[t_i]:
             inc[int(v)].add(int(t_i))
 
+    def rewrite(t, row):
+        """Give slot ``t`` the vertices ``row``, and keep ``inc`` true:
+        a tet leaves the set of every vertex it loses.  (A stale entry
+        made a later collapse count a tet that no longer held ``rm``
+        among the dying ones and open a hole: ROADMAP B1.)"""
+        for v in tet[t]:
+            inc[int(v)].discard(t)
+        tet[t] = row
+        tmask[t] = True
+        for v in row:
+            inc[int(v)].add(t)
+
+    def kill(t):
+        for v in tet[t]:
+            inc[int(v)].discard(t)
+        tmask[t] = False
+
     def ball(v):
-        return [t for t in inc[v] if tmask[t]]
+        return list(inc[v])
 
     def ball_q(ts):
         if not ts:
@@ -208,10 +225,9 @@ def sequential_repair(vert, tet, tmask, vtag, vmask, tref, ftag, etag,
                         if (u == a2 and v == b2) or (u == b2 and v == a2):
                             etag[t2][e2] |= etag[t][e]
         for t in dying:
-            tmask[t] = False
+            kill(t)
         for t, row in zip(moved, rows):
-            tet[t] = row
-            inc[int(kp)].add(t)
+            rewrite(t, row)
         vmask[rm] = False           # no orphan live vertices
         return True
 
@@ -225,8 +241,8 @@ def sequential_repair(vert, tet, tmask, vtag, vmask, tref, ftag, etag,
         tv = tet[t]
         for f in range(4):
             tri = [int(tv[i]) for i in IDIR[f]]
-            commons = (inc[tri[0]] & inc[tri[1]] & inc[tri[2]])
-            commons = [c for c in commons if tmask[c] and c != t]
+            commons = [c for c in (inc[tri[0]] & inc[tri[1]] & inc[tri[2]])
+                       if c != t]
             if len(commons) != 1:
                 continue
             t2 = commons[0]
@@ -248,18 +264,14 @@ def sequential_repair(vert, tet, tmask, vtag, vmask, tref, ftag, etag,
             if not len(dead):
                 continue
             free = int(dead[0])
-            tet[t] = rows[0]
-            tet[t2] = rows[1]
-            tet[free] = rows[2]
-            tmask[free] = True
+            rewrite(t, rows[0])
+            rewrite(t2, rows[1])
+            rewrite(free, rows[2])      # (a dead slot is in no set)
             # the resurrected slot must not inherit a prior tenant's tags
             ftag[free] = 0
             etag[free] = 0
             fref[free] = 0
             tref[free] = tref[t]
-            for row, ti in ((rows[0], t), (rows[1], t2), (rows[2], free)):
-                for v in row:
-                    inc[int(v)].add(int(ti))
             return True
         return False
 
@@ -270,7 +282,7 @@ def sequential_repair(vert, tet, tmask, vtag, vmask, tref, ftag, etag,
         tv = tet[t]
         for i, j in IARE:
             a, b = int(tv[i]), int(tv[j])
-            shell = [c for c in (inc[a] & inc[b]) if tmask[c]]
+            shell = list(inc[a] & inc[b])
             if len(shell) != 3:
                 continue
             if not all(_untagged(c) for c in shell):
@@ -289,12 +301,9 @@ def sequential_repair(vert, tet, tmask, vtag, vmask, tref, ftag, etag,
                 qn = _qual(vert[rows])
                 if (qn > 0).all() and qn.min() > old_min * 1.02:
                     t1, t2, t3 = shell
-                    tet[t1] = rows[0]
-                    tet[t2] = rows[1]
-                    tmask[t3] = False
-                    for row, ti in ((rows[0], t1), (rows[1], t2)):
-                        for v in row:
-                            inc[int(v)].add(int(ti))
+                    rewrite(t1, rows[0])
+                    rewrite(t2, rows[1])
+                    kill(t3)
                     return True
         return False
 

@@ -191,8 +191,19 @@ def split_wave(mesh: Mesh, met: jax.Array, lmax: float = LLONG,
 
     def _act(_):
         from .quality import quality_from_points
-        from ..core.constants import QUAL_FLOOR
+        from ..core.constants import QUAL_FLOOR, SPLIT_CHILD_FLOOR
         from .edges import topk_prep, wave_budget
+        # what a child must keep: the floor of degeneracy, and in a
+        # sizing split under a scalar size map SPLIT_CHILD_FLOOR where
+        # the tet holds a frozen seam edge (a fem split is owed whatever
+        # its children are; the floor is Euclidean, so a tensor metric,
+        # in which a well-shaped tet may read far under it, keeps
+        # QUAL_FLOOR everywhere)
+        child_floor = QUAL_FLOOR
+        seam_floor = not fem_only and met.ndim == 1
+        if seam_floor:
+            on_seam = jnp.any((mesh.etag & MG_PARBDY) != 0, axis=1)
+            child_floor = jnp.where(on_seam, SPLIT_CHILD_FLOOR, QUAL_FLOOR)
         capE = et.ev.shape[0]
         ar0 = jnp.arange(capT)
         s, t = claim_channels(lens, cand)                 # sort-free priority
@@ -230,7 +241,7 @@ def split_wave(mesh: Mesh, met: jax.Array, lmax: float = LLONG,
         static = isinstance(prescreen, (bool, np.bool_))
         if not static or prescreen:
             q_par = quality_from_points(mesh.vert[mesh.tet])
-            keep = q_par > 2.0 * QUAL_FLOOR
+            keep = q_par > 2.0 * child_floor
             if not static:          # traced switch: one compiled program
                 keep = keep | ~prescreen
             nominate = nominate & keep[:, None]
@@ -292,7 +303,8 @@ def split_wave(mesh: Mesh, met: jax.Array, lmax: float = LLONG,
         pts0 = mesh.vert[rows0]                           # [KH,4,3]
         q1 = quality_from_points(pts0.at[arK, jl].set(mid_row))
         q2 = quality_from_points(pts0.at[arK, il].set(mid_row))
-        rowbad = hv0 & ~((q1 > QUAL_FLOOR) & (q2 > QUAL_FLOOR))
+        floor0 = child_floor[hc] if seam_floor else child_floor
+        rowbad = hv0 & ~((q1 > floor0) & (q2 > floor0))
         veto_e = jnp.zeros(capE + 1, bool).at[
             jnp.where(rowbad, e0, capE)].max(rowbad, mode="drop")[:capE]
 

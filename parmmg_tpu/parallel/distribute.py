@@ -195,6 +195,13 @@ def split_to_shards(mesh: Mesh, met, part: np.ndarray, nparts: int,
     face_is_ifc = np.zeros(n * 4, bool)
     face_is_ifc[ifc_faces] = True
     face_is_ifc = face_is_ifc.reshape(n, 4)
+    # seam edges, the edges of the interface faces, as packed vertex
+    # pairs: EVERY slot of one is frozen, in every tet of its shell (see
+    # where the shards' edge tags are set)
+    nv = np.int64(len(vert))
+    tri = faces[ifc_faces].astype(np.int64)
+    seam_edges = np.unique(np.concatenate(
+        [tri[:, i] * nv + tri[:, j] for i, j in ((0, 1), (0, 2), (1, 2))]))
 
     # a regular surface vertex on a seam keeps the normal of its WHOLE
     # fan (Mmg's xPoint n1, which the reference agrees on across ranks,
@@ -240,10 +247,15 @@ def split_to_shards(mesh: Mesh, met, part: np.ndarray, nparts: int,
         user_req_f = (sftag[: len(ltet)] & MG_REQ) != 0
         sftag[: len(ltet)][lf_ifc] |= PARBDY_TAGS
         sftag[: len(ltet)][lf_ifc & user_req_f] &= ~np.uint32(MG_NOSURF)
-        e_ifc_m = np.zeros((len(ltet), 6), bool)
-        for f in range(4):
-            for e in FACE_EDGES[f]:
-                e_ifc_m[:, e] |= lf_ifc[:, f]
+        # a seam edge is frozen in EVERY slot of its shell, not only in
+        # the tets that own one of its seam faces: a swap routes a new
+        # tet's edge tag from ONE old slot of that edge, and where that
+        # slot was a bare one of a tet beside the seam, a tet that came to
+        # own the seam face carried the edge unfrozen, the split took it,
+        # and the seam's two sides no longer matched (ROADMAP B1, B17)
+        ea, eb = ltet_g[:, IARE[:, 0]], ltet_g[:, IARE[:, 1]]
+        e_ifc_m = np.isin(np.minimum(ea, eb).astype(np.int64) * nv
+                          + np.maximum(ea, eb), seam_edges)
         # an interface edge that was ALSO true boundary keeps that fact
         # through the freeze via MG_PARBDYBDY (tag_pmmg.c PARBDYBDY
         # role); a user-required edge keeps REQ by NOT carrying NOSURF
@@ -292,6 +304,8 @@ def _weld_close_pairs(vert, tet, vtag, met, tref, ftag, etag,
     Returns (tet, vkeep, tkeep) — updated connectivity plus vertex/tet
     keep masks.
     """
+    # the host repair's Euclidean quality (numpy, 1 on the regular tet)
+    from ..ops.repair import _qual as tet_quality
     n = len(vert)
     if met is None:
         return tet, np.ones(n, bool), np.ones(len(tet), bool)
@@ -369,11 +383,19 @@ def _weld_close_pairs(vert, tet, vtag, met, tref, ftag, etag,
                 moved.append(t_i)
         if len({int(tref[t_i]) for t_i in ball}) > 1:
             return False
-        for t_i in moved:
-            row = np.where(tet[t_i] == rm, kp, tet[t_i])
-            p = vert[row]
-            if np.dot(p[1] - p[0], np.cross(p[2] - p[0], p[3] - p[0])) \
-                    <= 1e-30:
+        if moved:
+            # the collapse's own gate (ops/collapse.py, MMG5_colver's
+            # calnew / calold): a weld may not leave a rewritten tet at
+            # under 0.3 of the worst it found among them.  A positive
+            # volume alone let a weld flatten a tet to 1e-7 of the
+            # regular one's quality, 12 of them in one merge of 240k
+            # tets, which the tail then had to repair one by one
+            # (ROADMAP B1)
+            # lint: ok(R2) — moved is a python list of host tet ids
+            rows = tet[np.asarray(moved)]
+            q_old = tet_quality(vert[rows])
+            q_new = tet_quality(vert[np.where(rows == rm, kp, rows)])
+            if q_new.min() <= max(0.3 * q_old.min(), 0.0):
                 return False
         for t_i in dying:
             tkeep[t_i] = False

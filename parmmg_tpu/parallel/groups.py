@@ -54,6 +54,21 @@ def how_many_groups(ne: int, target: int) -> int:
     return max(1, min((ne + target - 1) // target, C.REMESHER_NGRPS_MAX))
 
 
+def fresh_groups(ne: int, target: int, cap_max: int = 0,
+                 cap_mult: float = 3.0) -> int:
+    """Groups of a job's first cut: ``how_many_groups``, and under a
+    ceiling ``cap_max`` on a group's tet capacity
+    (``IParam.groupCapacity``) as many more as bring the capacity a
+    fresh even cut takes (``distribute.shard_capacity``) down to it:
+    more groups of a shape that compiles, not a bigger group."""
+    from .distribute import shard_capacity
+    n = how_many_groups(ne, target)
+    while cap_max > 0 and n < C.REMESHER_NGRPS_MAX and \
+            shard_capacity(1, -(-ne // n), cap_mult)[1] > cap_max:
+        n += 1
+    return n
+
+
 def fresh_cut(vert_h: np.ndarray, tet_h: np.ndarray, ngroups: int,
               contiguous: bool = False) -> np.ndarray:
     """The cut of a pass that was handed none: ``ngroups`` even parts
@@ -247,6 +262,18 @@ def _pad_groups(tree, g_new: int):
     return jax.tree.map(pad, tree)
 
 
+def _tile_rows(tree, rows: int) -> list:
+    """A stacked pytree of ``k * rows`` groups as ``k`` tiles of
+    ``rows``: what one block program of ``rows`` rows is dispatched
+    over, a tile a dispatch.  A stack of ``rows`` groups is its own
+    only tile, untouched."""
+    n = jax.tree.leaves(tree)[0].shape[0]
+    if n == rows:
+        return [tree]
+    return [jax.tree.map(lambda a: a[t:t + rows], tree)
+            for t in range(0, n, rows)]
+
+
 def _pipeline_chunks(fn, stacked, met_s, wave, plans, tim, done=None):
     """Double-buffered chunked dispatch over gathered group-index slices.
 
@@ -404,19 +431,45 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                        polish: bool = False, cap_mult: float = 3.0,
                        timers=None, ckpt_tag: str | None = None,
                        ckpt_it: int = 0, cap_state: list | None = None,
-                       contiguous: bool = False):
+                       contiguous: bool = False, target: int = 0,
+                       cap_max: int = 0):
     """One outer pass: split into groups, run adapt cycles with lax.map
     over the group axis, merge.  Returns (mesh, met, part_of_merged).
 
     ``contiguous``: what a pass that is handed no ``part`` asks of its
     own cut (``fresh_cut``).
 
-    ``cap_state``: a 1-element mutable list carried across the passes
-    of one run (the ``regrow_state`` idiom of dist.run_adapt_cycles):
-    the group (capP, capT) the previous pass ended with.  The split
-    keeps that shape while the new groups fit in it with slack
-    (distribute.split_to_shards ``reuse_caps``), so a later pass runs
-    the block programs the first one compiled.
+    ``cap_state``: a mutable list carried across the passes of one run
+    (the ``regrow_state`` idiom of dist.run_adapt_cycles): the group
+    (capP, capT) the previous pass ended with, then the rows R of the
+    job's first cut.  The split keeps that shape while the new groups
+    fit in it with slack (distribute.split_to_shards ``reuse_caps``),
+    and a block is ``ceil(G / R)`` dispatches of the ONE ``(R, capP,
+    capT)`` program over tiles of R device-resident rows, rows past G
+    dead (:func:`_tile_rows`), so a later pass and a cut of another
+    count run the block program the first one compiled.
+
+    ``target``: tets a group is cut for (``-mesh-size``; 0: the count is
+    the caller's and stands).  ``cap_max``: the ceiling on a group's tet
+    capacity (``IParam.groupCapacity``; 0: none, the ladder is open and
+    a job runs as it always did: a full group is regrown to the next
+    rung, ``groups.regrows``, and the count stands).  **Under a ceiling
+    the count follows the mesh**: the next rung of the capacity ladder
+    is a block compile of minutes on a TPU where it compiles at all
+    (ROADMAP B11), so a job that states the rung it may compile never
+    leaves it.  Its first cut takes more groups (:func:`fresh_groups`);
+    a block that filled a group which is over ``target`` (a mesh that
+    has outgrown its count), where the regrown rung would pass the
+    ceiling, is answered with MORE GROUPS OF THE SAME SHAPE, not a
+    bigger group: merge, cut every group over ``target`` inside itself
+    (``partition.refine_cut``), split on the kept capacity, upload, void
+    the quiet proofs, run the cycle again (a ``grp recut`` span,
+    ``groups.recuts``); a displaced cut whose rung would pass it is
+    re-cut the same way between the passes (:func:`_recut_outgrown`);
+    and a cut or a regrow that would still pass it raises
+    ``MemoryError``.  A pass answers six full groups, re-cuts and
+    regrows together, then raises ``MemoryError``, the driver's
+    LOWFAILURE.
 
     The per-group program is the SAME adapt_cycle_impl as the whole-mesh
     path (frozen MG_PARBDY group seams make it correct); the map axis
@@ -442,13 +495,16 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     skipped-group / saved-dispatch counters and the active-group
     trajectory, in ``stats.sched_extra``.
     """
+    from types import SimpleNamespace
     from ..ops.adapt import (CYCLE_COLS, LISTED_COL, SURF_COLS,
                              surface_scatter_width)
     from ..utils.timers import Timers
     from .distribute import (capacity_headroom, split_to_shards,
-                             merge_shards, grow_shards)
+                             merge_shards, grow_shards, regrown_capacity)
+    from .partition import refine_cut
     from .sched import QuietGroupScheduler
     from ..core.mesh import mesh_to_host
+    from ..obs.metrics import REGISTRY
 
     # The split is staged on the host CPU backend (host_staging): it
     # runs a per-shard adjacency program and stacks the result, a
@@ -459,71 +515,153 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     # in-flight chunk occupies device memory — the zaldy_pmmg.c memory
     # philosophy at chip scale, for a state that does not fit the
     # device.  Unchunked, the stacked state is committed to the device
-    # once and stays there for the whole pass.
+    # once a cut, in tiles, and stays there until the cut's merge.
     from ..utils.placement import host_staging, to_device
-    chunk = group_chunk(ngroups)
-    g_exec = ngroups            # chunk mode pads it to whole chunks
-    with otrace.span("grp split", groups=ngroups) as sp:
-        vert_h, tet_h, _, _, _ = mesh_to_host(mesh)
-        if part is None:
-            part = fresh_cut(vert_h, tet_h, ngroups, contiguous)
-        # what the cut asks of the pass (seams, junctions, pieces), as
-        # the split counts it on its way
-        cut = {}
-        with host_staging():
-            stacked, met_s = split_to_shards(
-                mesh, met, part, ngroups, cap_mult=cap_mult,
-                reuse_caps=cap_state[0] if cap_state else None, cut=cut)
+    # the rows of the pass as its newest cut staged them: ``tiles``
+    # (unchunked: [(stacked, met_s)] of ``rows`` rows each, on the
+    # device) or ``host`` (chunked: one (stacked, met_s) of g_exec rows)
+    if cap_state is not None and not cap_state:
+        cap_state[:] = [None, None]
+    st = SimpleNamespace(sched=QuietGroupScheduler(0, 0, 0),
+                         rows=cap_state[1] if cap_state else None)
+
+    def stage(mesh, met, part, ngroups, caps):
+        """Split ``mesh`` along ``part`` and commit the rows: the start
+        of the pass and of every re-cut inside it."""
+        chunk = group_chunk(ngroups)
+        g_exec = ngroups        # padded to whole chunks or tiles
+        with otrace.span("grp split", groups=ngroups) as sp:
+            if part is None:
+                vert_h, tet_h, _, _, _ = mesh_to_host(mesh)
+                part = fresh_cut(vert_h, tet_h, ngroups, contiguous)
+            # what the cut asks of the pass (seams, junctions, pieces),
+            # as the split counts it on its way
+            cut = {}
+            with host_staging():
+                stacked, met_s = split_to_shards(
+                    mesh, met, part, ngroups, cap_mult=cap_mult,
+                    reuse_caps=caps, cut=cut)
+                if chunk:
+                    g_exec = -(-ngroups // chunk) * chunk
+                    # np.array (copy): np.asarray of a jax array can hand
+                    # back a READ-ONLY buffer, and the host state is
+                    # mutated in place by the per-chunk writebacks
+                    stacked = jax.tree.map(
+                        # lint: ok(R2) — chunked mode keeps the state in
+                        # host RAM by design (the split's arrays are there)
+                        lambda a: np.array(a),
+                        _pad_groups(stacked, g_exec))
+                    # lint: ok(R2) — the same copy, of the metric
+                    met_s = np.array(_pad_groups(met_s, g_exec))
+                else:
+                    # R: the rows of the job's first cut, which the one
+                    # block program was compiled for
+                    rows = st.rows = st.rows or ngroups
+                    g_exec = -(-ngroups // rows) * rows
+                    if g_exec > ngroups:
+                        stacked, met_s = _pad_groups((stacked, met_s),
+                                                     g_exec)
+            # how far the fullest group stands from the edge of the
+            # capacity a later split may keep (shard_capacity ``keep``):
+            # under 0 the next pass would leave this block program
+            most_verts, largest = cut.pop("maxP"), cut.pop("maxT")
+            capP, capT = stacked.vert.shape[1], stacked.tet.shape[1]
+            if 0 < cap_max < capT:
+                raise MemoryError(f"a cut of {ngroups} groups needs capT "
+                                  f"{capT}, over the ceiling {cap_max}")
+            headroom = capacity_headroom(most_verts, largest, capP, capT)
+            sp.set(capP=capP, capT=capT, largest=largest,
+                   headroom=headroom, **cut)
+        otrace.log(2, f"  grp split: {ngroups} groups, largest "
+                      f"{largest} tets, capacity (capP, capT) = "
+                      f"({capP}, {capT})", verbose=verbose)
+        # everything the cut commits to the device before its next
+        # block: the stacked state (unchunked: COMMITTED, because the
+        # block program hands it back committed and jax keys a lowering
+        # on that; PERF.md, PR 31) and the scheduler's scalars
+        with otrace.span("grp upload", chunk=chunk or 0) as sp:
             if chunk:
-                g_exec = -(-ngroups // chunk) * chunk
-                # np.array (copy): np.asarray of a jax array can hand
-                # back a READ-ONLY buffer, and the host state is mutated
-                # in place by the per-chunk writebacks
-                stacked = jax.tree.map(
-                    lambda a: np.array(a), _pad_groups(stacked, g_exec))
-                met_s = np.array(_pad_groups(met_s, g_exec))
-        # how far the fullest group stands from the edge of the capacity
-        # a later split may keep (shard_capacity ``keep``): under 0 the
-        # next pass would leave this block program
-        most_verts, largest = cut.pop("maxP"), cut.pop("maxT")
-        capP, capT = stacked.vert.shape[1], stacked.tet.shape[1]
-        sp.set(capP=capP, capT=capT, largest=largest,
-               headroom=capacity_headroom(most_verts, largest, capP, capT),
-               **cut)
-    otrace.log(2, f"  grp split: {ngroups} groups, largest "
-                  f"{largest} tets, capacity (capP, capT) = "
-                  f"({capP}, {capT})", verbose=verbose)
+                st.host, st.tiles = (stacked, met_s), []
+            else:
+                st.host = None
+                st.tiles = [to_device(t) for t in
+                            _tile_rows((stacked, met_s), rows)]
+                sp.set(bytes=sum(a.nbytes for a in
+                                 jax.tree.leaves(st.tiles)),
+                       tiles=len(st.tiles))
+            st.sched.on_recut(ngroups, g_exec, chunk,
+                              tiles=len(st.tiles) or 1)
+        st.ngroups, st.g_exec, st.chunk = ngroups, g_exec, chunk
+        st.capP, st.capT = capP, capT
+        st.largest, st.headroom = largest, headroom
+
+    # lint: ok(R2) — a cut's designed pull: ONE transfer of the stacked
+    # state for the host-staged merge (merge_shards slices it per shard,
+    # which on device arrays would be device programs plus a pull per
+    # field per shard)
+    def pull():
+        """The live rows of the newest cut on the host."""
+        with otrace.span("grp pull") as sp:
+            if st.chunk:
+                rows_h = st.host
+            else:
+                pulled = [jax.tree.map(np.asarray, t) for t in st.tiles]
+                rows_h = pulled[0] if len(pulled) == 1 else \
+                    jax.tree.map(lambda *xs: np.concatenate(xs), *pulled)
+            # dead pad rows hold nothing
+            rows_h = jax.tree.map(lambda a: a[:st.ngroups], rows_h)
+            sp.set(bytes=sum(a.nbytes for a in jax.tree.leaves(rows_h)))
+        return rows_h
+
+    def merge(stacked_h, met_h):
+        """One host mesh of the pulled rows, its metric and the row
+        each tet came from.  Staged on the host like the split:
+        merge_shards rebuilds adjacency at MERGED-mesh width, the widest
+        one-shot program of the pass; the merged mesh stays on the host
+        for the next split or the caller's merged-width tail."""
+        with otrace.span("grp merge") as sp, host_staging():
+            merged, met_m, part_m = merge_shards(stacked_h, met_h,
+                                                 return_part=True)
+            sp.set(ne=len(part_m), capT=merged.capT, capP=merged.capP)
+        return merged, met_m, part_m
+
+    # lint: ok(R2) — the pull of a block's counters, its designed sync
+    def run_tiles(fn, wave, mask):
+        """One dispatch of ``fn`` a tile, every tile before the first
+        counter is pulled (the pulls are the block's only sync); the
+        tiles' new state stays on the device.  Returns the counts rows
+        [g_exec, ...] on the host."""
+        rows = st.rows
+        out = [fn(sl, kl, wave, jnp.asarray(mask[t * rows:(t + 1) * rows]))
+               for t, (sl, kl) in enumerate(st.tiles)]
+        st.tiles = [(sl, kl) for sl, kl, _ in out]
+        return np.concatenate([np.asarray(cnt) for _, _, cnt in out])
+
+    stage(mesh, met, part, ngroups,
+          cap_state[0] if cap_state else None)
+    sched = st.sched
 
     def _assign(dst_tree, src_tree, g0):
         """Write a chunk's device results back into the host state
         (contiguous-slice legacy form; the scheduler path scatters by
         index list inside :func:`_pipeline_chunks`)."""
         def w(d, s):
-            d[g0:g0 + chunk] = np.asarray(s)
+            d[g0:g0 + st.chunk] = np.asarray(s)
             return d
         jax.tree.map(w, dst_tree, src_tree)
 
-    # everything the pass commits to the device before its first block:
-    # the stacked state (unchunked: COMMITTED, because the block program
-    # hands it back committed and jax keys a lowering on that; PERF.md,
-    # PR 31) and the scheduler's scalars
-    with otrace.span("grp upload", chunk=chunk or 0) as sp:
-        if not chunk:
-            stacked, met_s = to_device((stacked, met_s))
-            sp.set(bytes=sum(a.nbytes for a in
-                             jax.tree.leaves((stacked, met_s))))
-        sched = QuietGroupScheduler(ngroups, g_exec, chunk)
     # pipeline segment timers on a LOCAL registry: folded into
     # stats.sched_extra and (prefixed) into the caller's Timers at the
     # end, so the driver report shows the transfer/compute split
     ltim = Timers()
     c = 0
-    regrows = 0
+    overflows = 0       # full groups the pass answered: re-cuts, regrows
     while c < cycles:
         swap, pre = block_schedule(c, cycles, noswap)
         step = _group_block(swap, pre, nomove, noinsert, hausd)
         swap_inc = swap or noswap
         wave = jnp.asarray(c, jnp.int32)
+        chunk = st.chunk
         act, plans = sched.plan_block(pre)
         # one span a dispatched block, dispatch to counter pull, with
         # the operations it applied: the ratio of useful outcomes to
@@ -533,6 +671,7 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                 otrace.span("grp block", block=c,
                             active=len(act)) as sp:
             if chunk:
+                stacked, met_s = st.host
                 parts = _pipeline_chunks(step, stacked, met_s, wave,
                                          plans, ltim)
                 sched.note_plan_pads(plans)
@@ -541,18 +680,16 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                 if sched.enabled:
                     otrace.log(
                         2, f"  grp block {c}: active "
-                           f"{len(act)}/{g_exec} groups, {len(plans)} "
+                           f"{len(act)}/{st.g_exec} groups, {len(plans)} "
                            "dispatches", verbose=verbose)
             else:
                 # unchunked: compaction cannot change the dispatch
                 # shape — the device-resident quiet mask is what skips
                 # converged groups here (lax.cond identity rows,
-                # sched.block_mask; bit-for-bit by the fixed point).
-                # The pull of the counters is the block's only sync
-                stacked, met_s, counts = step(
-                    stacked, met_s, wave,
-                    jnp.asarray(sched.block_mask(pre)))
-                counts_act = np.asarray(counts)         # [g_exec, 11]
+                # sched.block_mask; bit-for-bit by the fixed point),
+                # and the dead rows that fill the last tile
+                counts_act = run_tiles(         # [g_exec, 11]
+                    step, wave, sched.block_mask(pre))
             # quiet groups contribute exact zeros (that is what marked
             # them)
             cs = counts_act.sum(axis=0, dtype=np.int64)         # [11]
@@ -565,16 +702,21 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
             # those scatters are at full width
             surf["listed"] = tot[LISTED_COL]
             list_full = surface_scatter_width(
-                stacked.tet.shape[1], not noinsert, not nomove, hausd
+                st.capT, not noinsert, not nomove, hausd
             ) * np.count_nonzero(counts_act[:, LISTED_COL])
             # quiet: the row executions the device mask skipped in this
             # dispatch (a job's sum of them is groups.cond_skipped)
             # prog: which of the block programs this process lowered
             # the dispatch ran (obs/devtime joins a capture's device ops
             # with that program's scope map)
+            # tiles, rows: the dispatches the block made and the rows
+            # of their stacks, the dead ones that fill the last tile
+            # included
             sp.set(split=tot[0], collapse=tot[1], swap=tot[2],
                    moved=tot[3], quiet=sched.cond_skipped - skipped0,
-                   prog=LEDGER.program_index(BLOCK_ENTRY), **surf)
+                   prog=LEDGER.program_index(BLOCK_ENTRY),
+                   tiles=len(plans) if chunk else len(st.tiles),
+                   rows=sum(len(idx) for idx, _ in plans), **surf)
         if not chunk:
             # "compute" as the chunk pipeline records it: the seconds
             # from dispatch to counter pull
@@ -589,21 +731,46 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
             stats.add_surface(**surf, list_full=list_full)
         otrace.log(3, f"  grp cycle {c}: split {tot[0]} "
                       f"collapse {tot[1]} swap {tot[2]} move "
-                      f"{tot[3]} over {ngroups} groups",
+                      f"{tot[3]} over {st.ngroups} groups",
                    verbose=verbose)
+        if tot[4] != 0 and overflows >= 6:
+            raise MemoryError("group capacity exhausted")
+        if tot[4] != 0 and 0 < cap_max < regrown_capacity(
+                st.capP, st.capT)[1] and \
+                0 < target < counts_act[:, 5].max():
+            # the ceiling refuses the next rung and the full group is
+            # over ``target`` (a row reports its live tets, cond-skipped
+            # or not), a group of a mesh that outgrew its count: more
+            # groups of the same shape.  The block's applied winners
+            # stand; the ones it dropped for want of rows run again, in
+            # the cut's groups
+            with otrace.span("grp recut", why="overflow",
+                             g0=st.ngroups) as sp:
+                merged, met_m, part_m = merge(*pull())
+                vert_h, tet_h, _, _, _ = mesh_to_host(merged)
+                part = refine_cut(vert_h, tet_h, part_m, target)
+                # lint: ok(R2) — part is the cut's host labels
+                ngroups1 = int(part.max()) + 1
+                stage(merged, met_m, part, ngroups1, (st.capP, st.capT))
+                sp.set(g1=st.ngroups, ne=len(part), largest=st.largest,
+                       headroom=st.headroom)
+            overflows += 1
+            REGISTRY.counter("groups.recuts").inc()
+            REGISTRY.counter("groups.recut_overflow").inc()
+            continue
         if tot[4] != 0:
-            if regrows >= 6:
-                raise MemoryError("group capacity exhausted")
             with otrace.span("grp regrow") as sp:
-                capP = stacked.vert.shape[1]
-                capT = stacked.tet.shape[1]
-                from .distribute import regrown_capacity
+                capP, capT = st.capP, st.capT
                 newP, newT = regrown_capacity(capP, capT)
                 sp.set(capT0=capT, capT1=newT)
+                if 0 < cap_max < newT:
+                    raise MemoryError("group capacity exhausted under "
+                                      f"the ceiling {cap_max}")
                 if chunk:
                     # host-resident grow (np.pad mirror of grow_shards —
                     # jnp.pad would re-materialize the state on device)
                     import dataclasses as _dc
+                    stacked, met_s = st.host
 
                     def _padP(x, fill=0):
                         pad = [(0, 0)] * x.ndim
@@ -626,10 +793,13 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                         adja=_padT(stacked.adja, -1),
                         ftag=_padT(stacked.ftag), fref=_padT(stacked.fref),
                         etag=_padT(stacked.etag))
-                    met_s = _padP(met_s)
+                    st.host = (stacked, _padP(met_s))
                 else:
-                    stacked, met_s = grow_shards(stacked, met_s, newP, newT)
-                regrows += 1
+                    st.tiles = [grow_shards(sl, kl, newP, newT)
+                                for sl, kl in st.tiles]
+                st.capP, st.capT = newP, newT
+                overflows += 1
+                REGISTRY.counter("groups.regrows").inc()
                 # the wave top-K budgets scale with capT: every quiet proof
                 # is stale at the new capacity — reactivate the full set
                 # (truncated winners must rerun)
@@ -638,6 +808,9 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
         c += 1
         if block_converged(cs, swap, noswap):
             break
+    ngroups, g_exec, chunk = st.ngroups, st.g_exec, st.chunk
+    if chunk:
+        stacked, met_s = st.host
     pol_traj: list[int] = []
     if polish and not (noinsert and noswap and nomove):
         # grouped bad-element pass: sliver_polish per group under the
@@ -725,11 +898,11 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
                 _assign(stacked, sl, g0)
                 met_s[g0:g0 + chunk] = np.asarray(kl)
         else:
+            live = np.arange(g_exec) < ngroups
             for w in range(4):
-                stacked, met_s, cnt = polish_block(
-                    stacked, met_s, jnp.asarray(2000 + w, jnp.int32),
-                    jnp.ones(g_exec, bool))
-                tot = np.asarray(cnt).sum(axis=0).tolist()
+                tot = run_tiles(
+                    polish_block, jnp.asarray(2000 + w, jnp.int32),
+                    live).sum(axis=0).tolist()
                 otrace.log(2, f"  grp polish {w}: collapse "
                               f"{tot[0]} swap {tot[1]} move "
                               f"{tot[2]}", verbose=verbose)
@@ -761,12 +934,13 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     # metrics spine: the pass's scheduler counters + pipeline segment
     # seconds land in the process registry regardless of whether the
     # caller threaded a stats/timers object through
-    from ..obs.metrics import REGISTRY
     REGISTRY.counter("groups.dispatches").inc(sched.dispatches)
     # rows those dispatches ran (a block runs every row of its stack,
     # the quiet ones as lax.cond identities): what a block's seconds
     # divide by
     REGISTRY.counter("groups.rows").inc(sched.rows)
+    # of them the dead rows that fill a block's last tile (or chunk)
+    REGISTRY.counter("groups.rows_dead").inc(sched.rows_dead)
     # group-slot executions the device-resident quiet mask cond-skipped
     # (unchunked quiet slots + padded tail rows of chunk plans)
     REGISTRY.counter("groups.cond_skipped").inc(sched.cond_skipped)
@@ -798,29 +972,46 @@ def grouped_adapt_pass(mesh: Mesh, met, ngroups: int, cycles: int = 12,
     # stacked state doubles as the merge-free distributed-file snapshot
     # of this pass (the reference's -distributed-output checkpoint
     # role).  ckpt_due-gated: free unless PARMMG_CKPT_DIR is armed.
+    stacked_h, met_h = pull()
     if ckpt_tag is not None:
         from ..resilience.checkpoint import ckpt_span, snapshot_stacked
         with ckpt_span(ckpt_it):
-            snapshot_stacked(ckpt_tag, ckpt_it, stacked, ngroups)
+            snapshot_stacked(ckpt_tag, ckpt_it, stacked_h, ngroups)
     if cap_state is not None:
-        cap_state[:] = [(stacked.vert.shape[1], stacked.tet.shape[1])]
-    # merge staged on the host like the split: merge_shards rebuilds
-    # adjacency at MERGED-mesh width, the widest one-shot program of the
-    # pass; the merged mesh stays on the host for the next split or the
-    # caller's merged-width tail
-    # lint: ok(R2) — the pass's designed end-of-pass pull: ONE
-    # transfer of the stacked state for the host-staged merge
-    # (merge_shards slices it per shard, which on device arrays would
-    # be device programs plus a pull per field per shard)
-    with otrace.span("grp pull") as sp:
-        stacked_h, met_h = jax.tree.map(np.asarray, (stacked, met_s))
-        sp.set(bytes=sum(a.nbytes for a in
-                         jax.tree.leaves((stacked_h, met_h))))
-    with otrace.span("grp merge") as sp, host_staging():
-        merged, met_m, part_m = merge_shards(stacked_h, met_h,
-                                             return_part=True)
-        sp.set(ne=len(part_m), capT=merged.capT, capP=merged.capP)
+        cap_state[:] = [(st.capP, st.capT), st.rows]
+    merged, met_m, part_m = merge(stacked_h, met_h)
     return merged, met_m, part_m
+
+
+def _recut_outgrown(vert_h, tet_h, part, target: int, caps,
+                    cap_max: int = 0):
+    """The cut the next pass splits by: ``part``, the displaced labels,
+    while the capacity their fullest group takes (``shard_capacity``'s
+    ``keep`` rule: the one the job compiled its block for where the
+    group fits it, every accepted job's case, else the lowest rung that
+    holds it) is one the job may compile a block for.  Where that rung
+    is refused (it passes the ceiling ``cap_max``,
+    ``IParam.groupCapacity``) the cut takes more groups of the same
+    shape instead: every group over ``target`` is cut inside itself
+    (``partition.refine_cut``), so last pass's seams, which the
+    displacement moved inside groups, stay there."""
+    from ..obs.metrics import REGISTRY
+    from .distribute import capacity_headroom, shard_capacity
+    from .partition import cut_sizes, refine_cut
+    if caps is None or cap_max <= 0:
+        return part
+    most_verts, largest = cut_sizes(tet_h, part)
+    if largest <= target or \
+            shard_capacity(most_verts, largest, keep=caps)[1] <= cap_max:
+        return part
+    with otrace.span("grp recut", why="pass",
+                     g0=int(part.max()) + 1) as sp:
+        part = refine_cut(vert_h, tet_h, part, target)
+        most_verts, largest = cut_sizes(tet_h, part)
+        sp.set(g1=int(part.max()) + 1, ne=len(part), largest=largest,
+               headroom=capacity_headroom(most_verts, largest, *caps))
+    REGISTRY.counter("groups.recuts").inc()
+    return part
 
 
 def grouped_adapt(mesh: Mesh, met, target_size: int, niter: int = 3,
@@ -829,7 +1020,7 @@ def grouped_adapt(mesh: Mesh, met, target_size: int, niter: int = 3,
                   nomove: bool = False, hausd: float | None = None,
                   ifc_layers: int = 2, timers=None,
                   resume: bool = False, ckpt_tag: str = "grouped",
-                  contiguous: bool = False):
+                  contiguous: bool = False, cap_max: int = 0):
     """The two-level outer loop on one device: grouped passes with
     interface displacement between them (the rank-level loop of
     libparmmg1.c:636-948 collapsed onto one device, groups as the only
@@ -856,7 +1047,7 @@ def grouped_adapt(mesh: Mesh, met, target_size: int, niter: int = 3,
     if resume or ckpt.ckpt_config()[0]:
         fp = ckpt.run_fingerprint(mesh, met, target_size, niter, cycles,
                                   noinsert, noswap, nomove, hausd,
-                                  ifc_layers, contiguous)
+                                  ifc_layers, contiguous, cap_max)
     if resume:
         found = ckpt.latest_pass_checkpoint(ckpt_tag, fingerprint=fp)
         if found is not None:
@@ -888,10 +1079,11 @@ def grouped_adapt(mesh: Mesh, met, target_size: int, niter: int = 3,
     for it in range(it0, max(1, niter)):
         with otrace.context(**{"pass": it}):
             ne = int(np.asarray(mesh.tmask).sum())
-            # a displaced partition fixes the group count (its labels
-            # index the previous split); fresh iterations re-derive it
+            # a displaced partition brings its count (its labels index
+            # the last pass's rows, re-cut where the mesh outgrew them:
+            # _recut_outgrown); a pass handed none derives its own
             ngroups = (int(part.max()) + 1) if part is not None \
-                else how_many_groups(ne, target_size)
+                else fresh_groups(ne, target_size, cap_max)
             if ngroups < 2:
                 from ..ops.adapt import adapt_mesh
                 mesh, met, st = adapt_mesh(
@@ -909,10 +1101,13 @@ def grouped_adapt(mesh: Mesh, met, target_size: int, niter: int = 3,
                 verbose=verbose, stats=stats, noinsert=noinsert,
                 noswap=noswap, nomove=nomove, hausd=hausd,
                 timers=timers, ckpt_tag=ckpt_tag, ckpt_it=it,
-                cap_state=cap_state, contiguous=contiguous)
+                cap_state=cap_state, contiguous=contiguous,
+                target=target_size, cap_max=cap_max)
+            # a pass that re-cut ends on another count than it began on
+            ngroups = int(part_m.max()) + 1
             if it + 1 < max(1, niter):
                 with otrace.span("grp displace", layers=ifc_layers) as sp:
-                    _, tet_h, _, _, _ = mesh_to_host(mesh)
+                    vert_h, tet_h, _, _, _ = mesh_to_host(mesh)
                     part = move_interfaces(tet_h, part_m, ngroups,
                                            nlayers=ifc_layers)
                     # what the displacement (and its fix_contiguity)
@@ -920,6 +1115,8 @@ def grouped_adapt(mesh: Mesh, met, target_size: int, niter: int = 3,
                     sp.set(moved=np.count_nonzero(part != part_m),
                            largest=np.bincount(part).max().tolist(),
                            mean=len(part) / ngroups)
+                part = _recut_outgrown(vert_h, tet_h, part, target_size,
+                                       cap_state[0], cap_max)
             else:
                 # the FINAL pass checkpoints too (part=None — there is
                 # no next pass to feed): a kill during the caller's

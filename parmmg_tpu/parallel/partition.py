@@ -387,6 +387,51 @@ def cut_pieces(tet: np.ndarray, part: np.ndarray, pairs=None) -> np.ndarray:
         lab = new
 
 
+def cut_sizes(tet: np.ndarray, part: np.ndarray) -> tuple[int, int]:
+    """(vertices, tets) of a cut's fullest part, each column's own
+    largest: what ``distribute.shard_capacity`` follows, counted without
+    splitting (the distinct (part, vertex) pairs)."""
+    nvert = int(tet.max()) + 1
+    pairs = np.unique(part.astype(np.int64)[:, None] * nvert + tet)
+    return (np.bincount(pairs // nvert).max().tolist(),
+            np.bincount(part).max().tolist())
+
+
+# lint: ok(R2) — host-by-contract (signature: np.ndarray): a cut is made
+# on the merged mesh's host arrays; nothing here can pull a device value
+def refine_cut(vert: np.ndarray, tet: np.ndarray, part: np.ndarray,
+               target: int) -> np.ndarray:
+    """More groups of the same shape for a mesh that outgrew its cut:
+    every part over ``target`` tets is cut INSIDE ITSELF, into
+    ``ceil(size / target)`` even pieces along the Morton curve of its
+    own tets' centroids, so that no part of the new cut is over ``target``
+    and every seam of the old one is still a seam (a displaced cut keeps
+    what the displacement was for: last pass's seams lie inside groups).
+    The first piece keeps its part's label and the others take new ones
+    at the end, parts at or under ``target`` are left as they are.
+    Stray blobs go to a neighbour (``fix_contiguity``) unless that puts
+    a part over ``target`` again: a group in two blobs costs nothing
+    (``groups.fresh_cut``), a group over the target is what the re-cut
+    is there to end."""
+    centroids = vert[tet].mean(axis=1)
+    sizes = np.bincount(part)
+    out = part.copy()
+    came_from = list(range(len(sizes)))      # a new label's old part
+    for g in np.flatnonzero(sizes > target):
+        idx = np.flatnonzero(part == g)
+        sub = morton_partition(centroids[idx], -(-len(idx) // target))
+        new = sub > 0
+        out[idx[new]] = len(came_from) + sub[new] - 1
+        came_from += [g] * int(sub.max())
+    fixed = fix_contiguity(tet, out)
+    # ...or hands a blob to a piece of ANOTHER old part, which would move
+    # an old seam
+    if np.bincount(fixed).max() <= target and \
+            np.array_equal(np.asarray(came_from)[fixed], part):
+        return fixed
+    return out
+
+
 def fix_contiguity(tet: np.ndarray, part: np.ndarray) -> np.ndarray:
     """Relabel all but the largest connected blob of each color into a
     neighboring color (reference PMMG_fix_contiguity semantics,

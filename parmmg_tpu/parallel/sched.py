@@ -161,27 +161,39 @@ class QuietGroupScheduler:
     dispatch shape and the scheduler only records the trajectory)."""
 
     def __init__(self, ngroups: int, g_exec: int, chunk: int,
-                 enabled: bool | None = None):
+                 enabled: bool | None = None, tiles: int = 1):
         if enabled is None:
             enabled = sched_enabled()
-        self.ngroups = int(ngroups)
-        self.g_exec = int(g_exec)
-        self.chunk = int(chunk)
-        # compaction needs per-chunk dispatches to have fewer of them
-        self.enabled = bool(enabled) and self.chunk > 0
-        # the device mask works at ANY chunking (including unchunked,
-        # where it is the only skip mechanism — module docstring)
-        self.mask_on = bool(enabled) and device_mask_enabled()
-        self.level = np.zeros(self.g_exec, np.int8)
-        self.level[self.ngroups:] = LEVEL_FULL     # dead pad groups
+        self._on = bool(enabled)
         self.dispatches = 0
         self.rows = 0           # rows of the dispatched stacks
+        self.rows_dead = 0      # of them, dead pad rows (born quiet)
+        self.on_recut(ngroups, g_exec, chunk, tiles)
         self.saved_dispatches = 0
         self.skipped_group_blocks = 0
         # group-slot executions skipped ON DEVICE by the lax.cond mask
         # (unchunked quiet slots + padded tail rows of chunk plans)
         self.cond_skipped = 0
         self.active_per_block: list[int] = []
+
+    def on_recut(self, ngroups: int, g_exec: int, chunk: int,
+                 tiles: int = 1) -> None:
+        """A new cut of the pass's mesh (the first one included): every
+        proof is void (``on_regrow``'s rule: the rows are other groups
+        now), the counters run on.  ``tiles``: the dispatches an
+        unchunked block makes, each over ``g_exec // tiles`` rows of the
+        one program the job's first cut compiled."""
+        self.ngroups = int(ngroups)
+        self.g_exec = int(g_exec)
+        self.chunk = int(chunk)
+        self.tiles = int(tiles)
+        # compaction needs per-chunk dispatches to have fewer of them
+        self.enabled = self._on and self.chunk > 0
+        # the device mask works at ANY chunking (including unchunked,
+        # where it is the only skip mechanism — module docstring)
+        self.mask_on = self._on and device_mask_enabled()
+        self.level = np.zeros(self.g_exec, np.int8)
+        self.level[self.ngroups:] = LEVEL_FULL     # dead pad groups
 
     # ---- block planning --------------------------------------------------
     def _skip_level(self, pres_all_on: bool) -> int:
@@ -207,14 +219,19 @@ class QuietGroupScheduler:
         if self.chunk:
             base = -(-self.g_exec // self.chunk)
             plans = chunk_plans(act, self.chunk) if len(act) else []
+            ndisp = len(plans)
         else:
-            base = 1
-            plans = [(act, len(act))] if len(act) else []
-        self.dispatches += len(plans)
+            # unchunked: every tile of the stack is dispatched, quiet
+            # and dead rows as lax.cond identities (block_mask)
+            base = ndisp = self.tiles
+            plans = [(act, len(act))]
+        self.dispatches += ndisp
         self.rows += sum(len(idx) for idx, _ in plans)
+        self.rows_dead += sum(np.count_nonzero(idx >= self.ngroups)
+                              for idx, _ in plans)
         # saved vs the always-dispatch baseline, which ships the dead
         # pad groups too — skipping those IS a real dispatch saving
-        self.saved_dispatches += base - len(plans)
+        self.saved_dispatches += base - ndisp
         # ...but the skipped-GROUP counter reports convergence, so it
         # counts REAL groups only (pads are dead at birth, not wins)
         n_real = np.count_nonzero(act < self.ngroups)

@@ -121,6 +121,28 @@ def test_the_middle_groups_two_seams_come_back_matched(loop, which):
     assert o["unmatched_interior"] == 0, o
 
 
+@pytest.mark.parametrize("which", [0, 1, 2])
+def test_the_count_stands_where_no_ceiling_refuses_the_next_rung(
+        loop, which):
+    """PR 47 lets the count follow the mesh where a group fills over
+    ``-mesh-size`` or a ceiling (``IParam.groupCapacity``) refuses the
+    rung a displaced cut would take.  These jobs state no ceiling and
+    fill no group: three groups in both passes, the labels the
+    displacement handed on, no re-cut, no regrow, and every block one
+    dispatch of three live rows, as before."""
+    job = loop[which]
+    assert not spans_named(job, "grp regrow")
+    assert not spans_named(job, "grp recut")
+    assert [s["groups"] for s in spans_named(job, "grp split")] == [3, 3]
+    for name in ("groups.recuts", "groups.recut_overflow",
+                 "groups.regrows", "groups.rows_dead"):
+        assert job["counters"].get(name, 0) == 0, name
+    blocks = spans_named(job, "grp block")
+    assert all((b["tiles"], b["rows"]) == (1, 3) for b in blocks)
+    assert job["counters"]["groups.dispatches"] == len(blocks)
+    assert job["counters"]["groups.rows"] == 3 * len(blocks)
+
+
 def test_the_first_readaptation_coarsens_behind_the_front(loop):
     """The front moved: where it was, the input is finer than the new
     map asks for, so collapses lead the splits (a growth job's mix is

@@ -473,9 +473,9 @@ def test_a_block_traced_as_on_a_tpu_equals_the_parents(monkeypatch, cell):
     """The block program with its payloads carried in the sorts, its
     swap23 paired off the face sort and its surface scatters listed (what
     ``placed_on_tpu`` turns on) hands back the arrays the parent's
-    full-width program did: ``tests/test_rowpack.py``'s hashes, with the
-    one column of the counts row that says the lists engaged (7,
-    ``listed``: 0 at full width) left out."""
+    full-width program did: ``tests/test_rowpack.py``'s hashes (the edge
+    tags apart, as there), with the one column of the counts row that
+    says the lists engaged (7, ``listed``: 0 at full width) left out."""
     import test_rowpack
     digest = test_rowpack.digest
 
@@ -489,4 +489,6 @@ def test_a_block_traced_as_on_a_tpu_equals_the_parents(monkeypatch, cell):
     monkeypatch.setattr(placement, "placed_on_tpu", lambda: True)
     monkeypatch.setattr(groups, "placed_on_tpu", lambda: True)
     monkeypatch.setattr(groups, "_GROUP_BLOCK_CACHE", {})
-    assert test_rowpack.two_cycles(cell) == test_rowpack.PARENT[cell]
+    got, etag = test_rowpack.two_cycles(cell)
+    assert got == test_rowpack.PARENT[cell]
+    assert etag == test_rowpack.ETAG[cell]
